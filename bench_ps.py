@@ -25,8 +25,6 @@ WORKER = """
 import os, sys
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
 os.environ["JAX_PLATFORMS"] = "cpu"
-import jax
-jax.config.update("jax_platforms", "cpu")
 import logging; logging.basicConfig(level=logging.INFO)
 from dtf_tpu.cli import run
 from dtf_tpu.config import Config
@@ -57,8 +55,10 @@ def run_once(wire: str, tmp: str, port: int, workers: int = 2,
     with open(script, "w") as f:
         f.write(WORKER)
     logdir = os.path.join(tmp, f"logs_{wire}_{workers}")
+    # the workers hold themselves to the CPU (WORKER above); saying so
+    # here too lets the launcher see that no chip is shared
     env = dict(os.environ, PYTHONPATH=repo, BENCH_WIRE=wire,
-               BENCH_STEPS=str(steps))
+               BENCH_STEPS=str(steps), JAX_PLATFORMS="cpu")
     proc = subprocess.run(
         [sys.executable, "-m", "dtf_tpu.cli.launch",
          "--num_processes", str(workers + 1),

@@ -69,15 +69,6 @@ import tempfile
 import time
 
 import jax
-
-# honor an explicit JAX_PLATFORMS even when a TPU plugin registered
-# itself (same dance as bench_lm.py / runtime/mesh.py)
-if os.environ.get("JAX_PLATFORMS"):
-    try:
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-    except Exception:
-        pass
-
 import jax.numpy as jnp
 import numpy as np
 

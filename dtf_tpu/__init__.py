@@ -26,36 +26,3 @@ Layering (SURVEY.md §7):
 
 __version__ = "0.1.0"
 
-
-def _install_shard_map_shim() -> None:
-    """jax-0.4.x compat: expose `jax.shard_map` with the modern keyword
-    surface on installs that only ship `jax.experimental.shard_map`.
-
-    The training stack calls `jax.shard_map(..., check_vma=...)` (the
-    jax>=0.5 API).  jax 0.4.37 has no top-level `jax.shard_map` and its
-    experimental function spells that keyword `check_rep` — without the
-    shim every shard_map-backed training test dies in AttributeError at
-    import-adjacent time (the ROADMAP-documented cause of the 109
-    standing tier-1 failures).  Installed only when absent, so a real
-    jax>=0.5 is untouched.
-    """
-    import jax
-
-    if hasattr(jax, "shard_map"):
-        return
-    try:
-        from jax.experimental.shard_map import shard_map as _shard_map
-    except ImportError:  # pragma: no cover - no known jax lacks both
-        return
-    import functools
-
-    @functools.wraps(_shard_map)
-    def shard_map(f, **kw):
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        return _shard_map(f, **kw)
-
-    jax.shard_map = shard_map
-
-
-_install_shard_map_shim()

@@ -8,8 +8,9 @@ each rank's TF_CONFIG had to be hardcoded (SURVEY §3.4, §7.9).  Here
 per-process identity is env config, so one parameterized command does
 it all:
 
-Local fan-out (all processes on this host — multi-chip hosts, or CPU
-mesh testing):
+Local fan-out (all processes on this host — CPU mesh testing; on a
+TPU host the fan-out is refused, because nothing here assigns chips and
+one process already drives every local chip):
 
     python -m dtf_tpu.cli.launch --num_processes 4 -- \
         python -m dtf_tpu.cli.cifar_main --distribution_strategy \
@@ -29,6 +30,7 @@ launcher exits non-zero.
 from __future__ import annotations
 
 import collections
+import glob
 import json
 import os
 import shlex
@@ -41,8 +43,7 @@ from typing import List, Optional
 # Heartbeat-file contract, duplicated from dtf_tpu/obs/watchdog.py ON
 # PURPOSE: the supervisor's own logic stays stdlib-only — the process
 # that kills and restarts broken ML ranks should not depend on the obs
-# package it supervises (the unavoidable cost of `-m dtf_tpu.cli.launch`
-# is the package-init shard_map shim's jax import, a fixed ~3 s).
+# package it supervises (`import dtf_tpu` itself imports nothing).
 # tests/test_obs.py asserts the two sides agree on the contract.
 HEARTBEAT_DIR_ENV = "DTF_HEARTBEAT_DIR"
 
@@ -73,6 +74,38 @@ EXIT_DEVICE_LOST = 76
 # — the grow-back probe consumes it at the next checkpoint boundary.
 ELASTIC_DEVICES_ENV = "DTF_ELASTIC_DEVICES"
 REJOIN_FILE = "elastic_rejoin.json"
+
+
+def local_tpu_chips() -> int:
+    """TPU chips this host exposes, counted from their device nodes
+    (``/dev/accel*``, or one numbered ``/dev/vfio`` group each) —
+    without importing JAX: a supervisor that initialised a backend
+    would hold the very chips its children need."""
+    return len(glob.glob("/dev/accel*")) + sum(
+        os.path.basename(p).isdigit() for p in glob.glob("/dev/vfio/*"))
+
+
+def refuse_shared_chips(children: int, env: dict, what: str) -> None:
+    """A chip belongs to one process.  Nothing here assigns chips to
+    children: each of ``children`` local processes started with ``env``
+    would initialise every chip the host exposes, and all but the
+    first would fail or hang.  Refuse that before spawning anything.
+    Children held to the CPU (``JAX_PLATFORMS`` without ``tpu`` — the
+    virtual-device mesh) share nothing and pass."""
+    if children <= 1:
+        return
+    platforms = env.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return
+    chips = local_tpu_chips()
+    if chips:
+        raise RuntimeError(
+            f"{what}: {children} local processes on a host with {chips} "
+            f"TPU chip(s) would each claim the same chips (no chip "
+            f"assignment is implemented; one process must own each "
+            f"chip).  Run ONE process per host — it drives every local "
+            f"chip — or set JAX_PLATFORMS=cpu for a virtual-device CPU "
+            f"mesh")
 
 
 def classify_exit(rc: int) -> str:
@@ -447,6 +480,7 @@ def launch_local(cmd: List[str], num_processes: int, coordinator: str,
 
     Every decision lands in ``<log_dir>/supervisor_events.jsonl``.
     """
+    refuse_shared_chips(num_processes, os.environ, "launch")
     os.makedirs(log_dir, exist_ok=True)
     # run-scoped trace id, minted ONCE for the whole supervised job and
     # handed to every rank (and every restart attempt) through

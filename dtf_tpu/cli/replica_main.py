@@ -155,6 +155,8 @@ def main(argv=None) -> int:
     cfg = parse_flags(argv, defaults=REPLICA_DEFAULTS)
     from dtf_tpu import chaos
     from dtf_tpu.obs import trace
+    from dtf_tpu.runtime import compile_cache
+    compile_cache.configure()
     trace.maybe_configure(cfg)
     chaos.maybe_configure(cfg)   # slow_replica / heartbeat_stall
     return run_replica(cfg, random_init=random_init)

@@ -133,7 +133,9 @@ def run(cfg: Config) -> dict:
     crash budget."""
     from dtf_tpu import chaos
     from dtf_tpu.obs import trace
+    from dtf_tpu.runtime import compile_cache
     from dtf_tpu.train import preemption
+    compile_cache.configure()
     trace.maybe_configure(cfg)
     # run-scoped trace id: the launcher mints one (DTF_TRACE_ID) so
     # every rank's records — steps, checkpoints, eval, data service,
@@ -323,6 +325,11 @@ def _run(cfg: Config) -> dict:
         bn_axis=DATA_AXIS if cfg.sync_bn else None, seq_axis=seq_axis,
         model_axis=model_axis, expert_axis=expert_axis, pipe_axis=pipe_axis,
         **model_kw)
+    if spec.is_sequence and spec.seq_len > model.max_seq_len:
+        # --seq_len past the presets' 2048-row position table: grow the
+        # table with it (never shrink — serving restores checkpoints
+        # into the preset's shape)
+        model = model.clone(max_seq_len=spec.seq_len)
 
     import functools
     param_spec_fn = None

@@ -114,6 +114,18 @@ def build_serving_engine(cfg, random_init: bool = False,
     return model, engine
 
 
+def synthetic_prompts(cfg, vocab: int) -> list:
+    """The demo's traffic: ``serve_requests`` prompts of varied length
+    (1 … ``serve_prompt_len`` random token ids) — a pure function of
+    ``cfg.seed``, so a caller can rebuild exactly what was served."""
+    rng = np.random.default_rng(cfg.seed)
+    prompts = []
+    for _ in range(cfg.serve_requests):
+        plen = int(rng.integers(1, cfg.serve_prompt_len + 1))
+        prompts.append(rng.integers(0, vocab, (plen,)).astype(np.int32))
+    return prompts
+
+
 def serve(cfg, random_init: bool = False) -> dict:
     """Build model + params + engine from a Config; run the synthetic
     traffic demo; return the stats dict.  Library entry for tests."""
@@ -152,8 +164,6 @@ def serve(cfg, random_init: bool = False) -> dict:
         pass
 
     from dtf_tpu.serve.engine import Backpressure
-    rng = np.random.default_rng(cfg.seed)
-    vocab = model.vocab_size
     handles = []
     shed_by_drain = 0
     streamed_tokens = 0
@@ -177,9 +187,7 @@ def serve(cfg, random_init: bool = False) -> dict:
         # each consumed through its token STREAM by a client thread
         with cf.ThreadPoolExecutor(max_workers=8) as ex:
             consumers = []
-            for _ in range(cfg.serve_requests):
-                plen = int(rng.integers(1, cfg.serve_prompt_len + 1))
-                prompt = rng.integers(0, vocab, (plen,)).astype(np.int32)
+            for prompt in synthetic_prompts(cfg, model.vocab_size):
                 try:
                     h = engine.submit(
                         prompt, max_new_tokens=cfg.serve_max_new_tokens,
@@ -192,8 +200,7 @@ def serve(cfg, random_init: bool = False) -> dict:
                 handles.append(h)
                 consumers.append(ex.submit(_consume, h))
             streamed_tokens = sum(c.result() for c in consumers)
-        for h in handles:
-            h.result(timeout=600)
+        results = [h.result(timeout=600) for h in handles]
         wall = time.time() - t0
         engine.stop()  # drain=True: waits out queued + in-flight work
     finally:
@@ -201,6 +208,16 @@ def serve(cfg, random_init: bool = False) -> dict:
             signal.signal(signal.SIGTERM, old_handler)
         if metrics_server is not None:
             metrics_server.shutdown()
+    # nothing here cancels a request, so a cancelled or empty result
+    # means the engine thread died under it (engine._loop delivers
+    # cancellations so clients do not hang) — that is a failed run, not
+    # a run with zero throughput
+    dead = [r.request_id for r in results if r.cancelled or not r.tokens]
+    if dead or engine.error is not None:
+        raise RuntimeError(
+            f"serve: {len(dead)} of {len(results)} requests came back "
+            f"cancelled or empty (ids {dead[:8]}) — engine thread died: "
+            f"{engine.error!r}") from engine.error
     if drained["signaled"]:
         log.info("serve: drained after SIGTERM (%d in-flight finished, "
                  "%d shed) — exiting 0", len(handles), shed_by_drain)
@@ -227,6 +244,9 @@ def serve(cfg, random_init: bool = False) -> dict:
         "tp": cfg.serve_tp,
     }
     log.info("Serve stats: %s", out)
+    # what was generated, in submission order — for callers that check
+    # the answers (chip_smoke.py replays them on the reference path)
+    out["completions"] = [list(r.tokens) for r in results]
     return out
 
 
@@ -241,6 +261,8 @@ def main(argv=None) -> dict:
     if random_init:
         argv.remove("--serve_random_init")
     cfg = parse_flags(argv, defaults=SERVE_DEFAULTS)
+    from dtf_tpu.runtime import compile_cache
+    compile_cache.configure()
     # --trace_dir: serve batch-form/decode spans + shed anomalies
     from dtf_tpu.obs import trace
     trace.maybe_configure(cfg)

@@ -168,7 +168,7 @@ def ledger_rows(merged: List[dict]) -> List[dict]:
     """The MFU/cost ledger as machine-readable rows from
     ledger_exec/ledger_summary events — latest record per (rank,
     executable) wins (a re-compile or a later summary supersedes).
-    One dict per (rank, exec): flops/bytes/count/mean_s/
+    One dict per (rank, exec): flops/bytes/kernels/count/mean_s/
     achieved_tflops/mfu/hbm_frac (missing fields None).  This is the
     join surface the capacity simulator's calibration reads — the
     human table in :func:`print_ledger` renders the same rows."""
@@ -177,7 +177,8 @@ def ledger_rows(merged: List[dict]) -> List[dict]:
         if rec.get("name") == "ledger_exec":
             key = (str(rec.get("rank", "?")), rec.get("exec", "?"))
             rows.setdefault(key, {}).update(
-                flops=rec.get("flops"), bytes=rec.get("bytes"))
+                flops=rec.get("flops"), bytes=rec.get("bytes"),
+                kernels=rec.get("kernels"))
         elif rec.get("name") == "ledger_summary":
             key = (str(rec.get("rank", "?")), rec.get("exec", "?"))
             rows.setdefault(key, {}).update(

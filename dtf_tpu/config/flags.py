@@ -411,7 +411,7 @@ class Config:
     # defaults when --plan is given — conflicts are loud errors.
     plan: str = ""
     # mesh descriptor the planner costs against: "" = the live runtime
-    # topology, a preset (cpu | v4-8 | 4x4), or an explicit
+    # topology, a preset (cpu | v4-8 | v5e-4 | 4x4), or an explicit
     # "hosts=4,devices=4,hbm=32g,flops=140t,intra=100g,inter=25g"
     plan_mesh: str = ""
     # ranked-lattice memoization sidecar (plan/cache.py): a JSON file

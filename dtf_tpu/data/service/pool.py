@@ -89,8 +89,10 @@ def _worker_main(payload: dict, out_q) -> None:
     (shard, k) tag plus cumulative cache counters; backpressure is the
     bounded queue."""
     # keep the spawned child off any accelerator: readers are pure
-    # numpy/PIL/libjpeg and must never grab a TPU chip from the parent
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # numpy/PIL/libjpeg and must never grab a TPU chip from the parent.
+    # Forced, not setdefault: the parent that owns the chip is exactly
+    # the one whose environment says JAX_PLATFORMS=tpu
+    os.environ["JAX_PLATFORMS"] = "cpu"
     try:
         from dtf_tpu.data.service.reader import make_reader
         readers = {}

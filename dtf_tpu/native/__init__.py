@@ -1,27 +1,60 @@
 """ctypes bindings for the C++ data runtime (libdtf_native.so).
 
-Build with `make -C dtf_tpu/native`.  Every consumer degrades to the
-pure-Python implementation when the library is absent, so the build is
-an optimization, not a requirement.  ctypes foreign calls release the
-GIL, so Python worker threads get true decode parallelism.
+The library is a build artifact git does not carry, so the first
+:func:`load` of a process builds it from ``dtf_native.cpp`` /
+``ps_store.cpp`` with ``make -C dtf_tpu/native`` (a no-op when it is
+up to date).  Where that build cannot run (no compiler, no
+libjpeg-turbo) load() says so once and returns None: every consumer
+then degrades to the pure-Python implementation, or, where it needs
+the native code, fails naming the build command.  ctypes foreign calls
+release the GIL, so Python worker threads get true decode parallelism.
 """
 
 from __future__ import annotations
 
 import ctypes
+import fcntl
+import logging
 import os
+import subprocess
 from typing import Optional
 
-_LIB_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                         "libdtf_native.so")
+log = logging.getLogger("dtf_tpu")
+
+_DIR = os.path.dirname(os.path.abspath(__file__))
+_LIB_PATH = os.path.join(_DIR, "libdtf_native.so")
 _lib: Optional[ctypes.CDLL] = None
+_build_tried = False
+
+
+def _build() -> None:
+    """``make -C dtf_tpu/native``, once per process.  A file lock
+    serializes it across processes: input-service reader workers reach
+    their first load() at the same moment."""
+    global _build_tried
+    if _build_tried:
+        return
+    _build_tried = True
+    try:
+        with open(os.path.join(_DIR, ".build.lock"), "w") as lock:
+            fcntl.flock(lock, fcntl.LOCK_EX)
+            subprocess.run(["make", "-C", _DIR], check=True,
+                           capture_output=True, timeout=300)
+    except (OSError, subprocess.SubprocessError) as e:
+        stderr = getattr(e, "stderr", None) or b""
+        log.warning("native: `make -C %s` failed (%s: %s)%s", _DIR,
+                    type(e).__name__, e,
+                    "\n" + stderr.decode(errors="replace")[-400:]
+                    if stderr else "")
 
 
 def load() -> Optional[ctypes.CDLL]:
-    """Returns the loaded library, or None when not built."""
+    """Returns the loaded library (built first if need be), or None
+    when it cannot be built here."""
     global _lib
     if _lib is not None:
         return _lib
+    _build()
     if not os.path.exists(_LIB_PATH):
         return None
     lib = ctypes.CDLL(_LIB_PATH)

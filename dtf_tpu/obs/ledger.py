@@ -45,6 +45,7 @@ from __future__ import annotations
 import logging
 import math
 import os
+import re
 import threading
 from typing import Dict, Optional
 
@@ -113,6 +114,25 @@ def cost_of(compiled) -> tuple:
             float(ca.get("bytes accessed", 0.0) or 0.0))
 
 
+_PALLAS_OP = re.compile(r'op_name="[^"]*?([\w.]+)/pallas_call')
+
+
+def pallas_kernels(compiled) -> Dict[str, int]:
+    """{kernel name: call sites} of the Mosaic (Pallas TPU) kernels in
+    a compiled executable, read from its optimized HLO — what the
+    device will run, not what a flag asked for: a reference
+    formulation (blockwise, gather) or interpret mode lowers to plain
+    HLO and shows up here as absent."""
+    counts: Dict[str, int] = {}
+    for line in compiled.as_text().splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        m = _PALLAS_OP.search(line)
+        name = m.group(1) if m else "pallas_call"
+        counts[name] = counts.get(name, 0) + 1
+    return counts
+
+
 class Ledger:
     """Per-executable cost ledger over one metrics registry.
 
@@ -138,27 +158,39 @@ class Ledger:
         one, pass the counts directly.  Re-registering the same name
         (gather-path chunk window variants) updates the counts and
         keeps the accumulated timing."""
+        kernels: Dict[str, int] = {}
         if compiled is not None:
             try:
                 flops, bytes_accessed = cost_of(compiled)
+                kernels = pallas_kernels(compiled)
             except Exception as e:  # noqa: BLE001 — a backend without
-                # cost_analysis must not take down the step it measures
-                log.debug("ledger: cost_analysis unavailable for %s (%s)",
-                          name, e)
+                # cost_analysis must not take down the step it measures,
+                # but a step that runs without an entry must be visible
+                log.warning("ledger: no entry for %s — cost_analysis "
+                            "failed: %s: %s", name, type(e).__name__, e)
                 return
         with self._mu:
             e = self._execs.get(name)
             if e is None:
                 e = self._execs[name] = {"flops": 0.0, "bytes": 0.0,
-                                         "count": 0, "total_s": 0.0}
+                                         "count": 0, "total_s": 0.0,
+                                         "kernels": {}}
             e["flops"] = float(flops)
             e["bytes"] = float(bytes_accessed)
+            # a name shared by several bodies (a chunk shape's first and
+            # continuation bodies) accumulates the kernels of all of them
+            e["kernels"].update(kernels)
+            kernels = dict(e["kernels"])
+        if compiled is not None:
+            log.info("ledger: %s compiled — %.4g flops, %.4g bytes, "
+                     "pallas kernels %s", name, flops, bytes_accessed,
+                     kernels or "none")
         self.registry.gauge(f"ledger_{name}_flops",
                             unit="flops").set(flops)
         self.registry.gauge(f"ledger_{name}_bytes",
                             unit="bytes").set(bytes_accessed)
         trace.event("ledger_exec", exec=name, flops=float(flops),
-                    bytes=float(bytes_accessed),
+                    bytes=float(bytes_accessed), kernels=kernels,
                     peak_tflops=(self.peak_flops / 1e12
                                  if self.peak_flops else None),
                     peak_hbm_gbps=(self.peak_hbm / 1e9
@@ -192,8 +224,9 @@ class Ledger:
                 nbytes / mean / self.peak_hbm)
 
     def summary(self) -> Dict[str, dict]:
-        """{exec: {flops, bytes, count, mean_s, achieved_tflops, mfu,
-        hbm_frac}} — mfu/hbm_frac None when the peak is unknown."""
+        """{exec: {flops, bytes, kernels, count, mean_s,
+        achieved_tflops, mfu, hbm_frac}} — mfu/hbm_frac None when the
+        peak is unknown."""
         out: Dict[str, dict] = {}
         with self._mu:
             items = sorted(self._execs.items())
@@ -202,6 +235,7 @@ class Ledger:
             achieved = e["flops"] / mean if mean > 0 else 0.0
             out[name] = {
                 "flops": e["flops"], "bytes": e["bytes"],
+                "kernels": dict(e["kernels"]),
                 "count": e["count"], "mean_s": mean,
                 "achieved_tflops": achieved / 1e12,
                 "mfu": (achieved / self.peak_flops

@@ -202,6 +202,7 @@ def _pallas_forward(q, k, v, scale, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     o, lse = out
     return o, lse[..., 0]
@@ -516,6 +517,7 @@ def _pallas_backward(q, k, v, o, lse, do, scale, causal, block_q, block_k,
                             pltpu.VMEM((block_k, d), jnp.float32),
                             pltpu.VMEM((block_k, d), jnp.float32)],
             interpret=interpret,
+            name="flash_bwd_fused",
         )(q, k, v, do, lse3, delta)
         return dq, dk, dv
 
@@ -537,6 +539,7 @@ def _pallas_backward(q, k, v, o, lse, do, scale, causal, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse3, delta)
 
     dk, dv = pl.pallas_call(
@@ -562,6 +565,7 @@ def _pallas_backward(q, k, v, o, lse, do, scale, causal, block_q, block_k,
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dkdv",
     )(q, k, v, do, lse3, delta)
     return dq, dk, dv
 

@@ -231,16 +231,26 @@ def paged_flash_decode(q, pool_k, pool_v, block_table, index, *,
     m_pages = block_table.shape[1]
     scale = float(scale) if scale is not None else 1.0 / (d ** 0.5)
     qh = jnp.swapaxes(q, 1, 2)                       # [B, H, S, D]
+    # The kernel sees the pools head-major, [P, H, page, Dh]: a head's
+    # page is then a (page, Dh) block over the array's LAST TWO dims —
+    # the only placement the TPU lowering accepts for a sub-(8, 128)
+    # extent (a (page, None, Dh) block on [P, page, H, Dh] leaves the
+    # squeezed head second-to-last and is refused, at any H or Dh).
+    # The transpose is logical: XLA's TPU layout for a [P, page, H, Dh]
+    # array with small H already stores (page, Dh) minor-most (checked
+    # in the compiled HLO at 6 and 3 heads × 128 — a bitcast, no copy);
+    # where it does not, XLA inserts the copy and the result is the same.
+    pool_spec = pl.BlockSpec(
+        (None, None, page_size, d),
+        lambda b_, h_, j, tbl, idx: (tbl[b_, j], h_, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, h, m_pages),
         in_specs=[
             pl.BlockSpec((None, None, s, d),
                          lambda b_, h_, j, tbl, idx: (b_, h_, 0, 0)),
-            pl.BlockSpec((None, page_size, None, d),
-                         lambda b_, h_, j, tbl, idx: (tbl[b_, j], 0, h_, 0)),
-            pl.BlockSpec((None, page_size, None, d),
-                         lambda b_, h_, j, tbl, idx: (tbl[b_, j], 0, h_, 0)),
+            pool_spec,
+            pool_spec,
         ],
         out_specs=pl.BlockSpec((None, None, s, d),
                                lambda b_, h_, j, tbl, idx: (b_, h_, 0, 0)),
@@ -256,8 +266,9 @@ def paged_flash_decode(q, pool_k, pool_v, block_table, index, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
         interpret=interpret,
+        name="paged_flash_decode",
     )(jnp.asarray(block_table, jnp.int32), jnp.asarray(index, jnp.int32),
-      qh, pool_k, pool_v)
+      qh, jnp.swapaxes(pool_k, 1, 2), jnp.swapaxes(pool_v, 1, 2))
     return jnp.swapaxes(out, 1, 2)
 
 

@@ -68,8 +68,8 @@ class MeshSpec:
 
 # Presets.  "cpu" is sized for the 8-virtual-device test mesh on a dev
 # box (flops deliberately conservative — the calibration probe replaces
-# it with a measurement); the TPU entries model one v4 host and the
-# docs' worked 4-host × 4-device pod.
+# it with a measurement); the TPU entries model one v4 host, one v5e
+# host and the docs' worked 4-host × 4-device pod.
 PRESETS: Dict[str, MeshSpec] = {
     "cpu": MeshSpec("cpu", num_hosts=1, devices_per_host=8,
                     hbm_bytes=4 * GiB, device_flops=8e9,
@@ -79,11 +79,24 @@ PRESETS: Dict[str, MeshSpec] = {
     "v4-8": MeshSpec("v4-8", num_hosts=1, devices_per_host=4,
                      hbm_bytes=32 * GiB, device_flops=1.4e14,
                      intra_bw=1e11, inter_bw=2.5e10),
+    # one v5e host, 4 chips (2x2): 16 GiB HBM/chip, ~50% of 197
+    # TFLOP/s bf16 peak, ICI 1,600 Gbit/s per chip taken at a quarter
+    # for an effective ring-allreduce rate.  Not yet anchored to a
+    # measurement on the chip (ROADMAP S3)
+    "v5e-4": MeshSpec("v5e-4", num_hosts=1, devices_per_host=4,
+                      hbm_bytes=16 * GiB, device_flops=1.0e14,
+                      intra_bw=5e10, inter_bw=2.5e10),
     # the README/DESIGN worked example: 4 hosts × 4 chips over DCN
     "4x4": MeshSpec("4x4", num_hosts=4, devices_per_host=4,
                     hbm_bytes=32 * GiB, device_flops=1.4e14,
                     intra_bw=1e11, inter_bw=2.5e10),
 }
+
+# jax ``device_kind`` (lower-cased substring) → the preset whose
+# per-device rates describe it.  The live descriptor refuses a TPU
+# kind that is not here: rates invented for an unknown chip rank plans
+# wrongly without anyone noticing.
+_KIND_PRESET = {"v4": "v4-8", "v5 lite": "v5e-4", "v5e": "v5e-4"}
 
 _SUFFIX = {"k": 1e3, "m": 1e6, "g": 1e9, "t": 1e12, "p": 1e15}
 # byte quantities use binary multipliers, so the documented descriptor
@@ -104,11 +117,15 @@ def mesh_spec(spec: str = "", *, live_devices: Optional[int] = None
               ) -> MeshSpec:
     """Resolve a ``--plan_mesh`` value.
 
-    "" (default)    — describe the live runtime: CPU preset resized to
-                      the actual jax topology (process count × local
-                      devices), so plans search the mesh a run would
-                      actually get.
-    preset name     — one of PRESETS (``cpu``, ``v4-8``, ``4x4``).
+    "" (default)    — describe the live runtime: the actual jax
+                      topology (process count × local devices), so
+                      plans search the mesh a run would actually get.
+                      On CPU the ``cpu`` preset's rates; on a TPU the
+                      HBM the device reports and the rates of the
+                      preset for its ``device_kind`` (an unlisted kind
+                      is an error — pass an explicit descriptor).
+    preset name     — one of PRESETS (``cpu``, ``v4-8``, ``v5e-4``,
+                      ``4x4``).
     "k=v,…" string  — explicit descriptor, e.g.
                       ``hosts=4,devices=4,hbm=32g,flops=140t,intra=100g,inter=25g``
                       (numbers take k/m/g/t suffixes — binary for hbm
@@ -122,11 +139,22 @@ def mesh_spec(spec: str = "", *, live_devices: Optional[int] = None
     if not spec:
         from dtf_tpu.runtime.mesh import topology
         topo = topology()
-        # the live platform picks the per-device numbers: a TPU box
-        # gets the v4 preset's HBM/FLOPs/ICI — keeping the cpu
-        # preset's 4 GiB on a real 32 GiB chip would reject plans
-        # that comfortably fit
-        base = PRESETS["v4-8" if topo["platform"] == "tpu" else "cpu"]
+        base = PRESETS["cpu"]
+        if topo["platform"] == "tpu":
+            kind = topo["device_kind"].lower()
+            preset = next((p for k, p in _KIND_PRESET.items()
+                           if k in kind), None)
+            if preset is None:
+                raise ValueError(
+                    f"no rate preset for TPU device_kind "
+                    f"{topo['device_kind']!r} (have "
+                    f"{sorted(_KIND_PRESET)}); describe the mesh "
+                    f"explicitly: --plan_mesh 'hosts=..,devices=..,"
+                    f"hbm=..,flops=..,intra=..,inter=..'")
+            base = PRESETS[preset]
+            if topo["hbm_bytes"]:
+                base = dataclasses.replace(
+                    base, hbm_bytes=int(topo["hbm_bytes"]))
         local = (live_devices if live_devices is not None
                  else topo["devices_per_host"])
         return dataclasses.replace(base, name="runtime",

@@ -28,15 +28,6 @@ from typing import Optional, Sequence
 
 import jax
 import numpy as np
-
-# Some TPU platform plugins register themselves even when JAX_PLATFORMS
-# asks for cpu; honor the user's env var explicitly (needed for the
-# virtual-device CPU-mesh workflow on a machine with a TPU attached).
-if os.environ.get("JAX_PLATFORMS"):
-    try:
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-    except Exception:  # backend already initialized — leave it be
-        pass
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dtf_tpu.config import Config
@@ -162,6 +153,14 @@ def initialize(cfg: Config) -> MeshRuntime:
     _maybe_init_distributed(cfg)
     strategy = cfg.distribution_strategy
     devices = jax.devices()
+    if strategy == "tpu" and devices[0].platform != "tpu":
+        # with no chip visible JAX warns and hands back the CPU; a run
+        # that asked for the TPU by name must not train there
+        raise RuntimeError(
+            f"--distribution_strategy tpu: JAX found no TPU (platform "
+            f"{devices[0].platform!r}, JAX_PLATFORMS="
+            f"{os.environ.get('JAX_PLATFORMS', '')!r}); use 'mirrored' to "
+            f"run on whatever devices are attached")
 
     if strategy in ("off", "one_device"):
         devices = devices[:1]
@@ -196,10 +195,16 @@ def topology() -> dict:
     :func:`_maybe_init_distributed` first (runner._run does), or
     ``process_count()`` reports 1 and the later distributed
     rendezvous refuses an already-initialized backend."""
+    dev = jax.devices()[0]
+    # bytes_limit is what the allocator will actually hand out; the CPU
+    # backend reports no memory stats
+    stats = dev.memory_stats() or {}
     return {
         "num_hosts": jax.process_count(),
         "devices_per_host": jax.local_device_count(),
-        "platform": jax.devices()[0].platform,
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
+        "hbm_bytes": stats.get("bytes_limit"),
     }
 
 

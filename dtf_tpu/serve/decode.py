@@ -387,9 +387,12 @@ class Decoder:
         try:
             compiled = jitfn.lower(*args).compile()
         except Exception as e:  # noqa: BLE001 — observability must
-            # never take down the decode path it measures
-            log.debug("decoder: AOT compile failed for %s (%s) — "
-                      "falling back to the jit path", name, e)
+            # never take down the decode path it measures; the jit path
+            # then meets the same compiler and fails for real if the
+            # body itself is at fault
+            log.warning("decoder: AOT compile failed for %s (%s: %s) — "
+                        "falling back to the jit path, no ledger entry",
+                        name, type(e).__name__, e)
             return None
         if self.ledger is not None:
             self.ledger.register(name, compiled=compiled)

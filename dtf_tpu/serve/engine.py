@@ -109,6 +109,30 @@ from dtf_tpu.serve.decode import Decoder
 log = logging.getLogger("dtf_tpu")
 
 
+# default chunked-prefill unit, in pages (64 tokens at the default page
+# size, and a page multiple at ANY page size)
+DEFAULT_PREFILL_PAGES = 4
+
+
+def chunk_plan(plen: int, prefill_chunk: int, page_size: int,
+               start: int = 0):
+    """[(start, len), ...] page-aligned chunks covering [start, plen)
+    of a prompt (``start`` — the first position NOT covered by shared
+    prefix pages — must be page-aligned).  Full ``prefill_chunk``-token
+    chunks, then one final chunk padded to the page size (so the final
+    chunk always contains the last real prompt token — the sampled
+    position).  prefill_chunk == 0: the whole remainder is one
+    page-aligned chunk."""
+    chunk = prefill_chunk or -(-(plen - start) // page_size) * page_size
+    plan = []
+    while plen - start > chunk:
+        plan.append((start, chunk))
+        start += chunk
+    rem = plen - start
+    plan.append((start, -(-rem // page_size) * page_size))
+    return plan
+
+
 class Backpressure(RuntimeError):
     """Request shed: the queue is full.  ``retry_after`` (seconds) is
     the engine's estimate of when capacity frees up."""
@@ -526,11 +550,11 @@ class ServeEngine:
         self.ledger = Ledger(self.metrics)
         if self.paged:
             self.page_size = int(kv_page_size)
-            # None = default (4 pages — 64 tokens at the default page
-            # size, and a page multiple at ANY page size); 0 = whole-
-            # prompt single chunks
-            self.prefill_chunk = (4 * self.page_size if prefill_chunk
-                                  is None else int(prefill_chunk))
+            # None = default (DEFAULT_PREFILL_PAGES); 0 = whole-prompt
+            # single chunks
+            self.prefill_chunk = (DEFAULT_PREFILL_PAGES * self.page_size
+                                  if prefill_chunk is None
+                                  else int(prefill_chunk))
             if self.prefill_chunk and self.prefill_chunk % self.page_size:
                 raise ValueError(
                     f"prefill_chunk ({self.prefill_chunk}) must be a "
@@ -659,6 +683,9 @@ class ServeEngine:
         self._m_cancelled = self.metrics.counter("serve_cancelled_total",
                                                  unit="requests")
         self._heartbeat = heartbeat
+        # the exception that killed the engine thread, if one did —
+        # callers holding cancelled results read the cause here
+        self.error: Optional[BaseException] = None
         self._last_step_t: Optional[float] = None
         self._prefill_rr = -1           # round-robin cursor (chunk sched)
         self.max_concurrent = 0         # peak simultaneously-active slots
@@ -901,6 +928,10 @@ class ServeEngine:
             # cancelled there) or raise here — never enqueue onto a
             # stopped engine, where nothing would ever deliver it
             if self._stop.is_set():
+                if self.error is not None:
+                    raise RuntimeError(
+                        f"engine is stopped — engine thread died: "
+                        f"{self.error!r}") from self.error
                 raise RuntimeError("engine is stopped")
             if self._draining:
                 # SIGTERM drain: admissions stop the moment the signal
@@ -967,9 +998,10 @@ class ServeEngine:
         try:
             self._loop_body()
             self._drain_migration_jobs()
-        except Exception:
+        except Exception as e:
             # a dead engine thread must not strand clients blocked in
             # result(): fail loudly and deliver cancellations
+            self.error = e
             log.exception("serve engine thread died — cancelling all "
                           "in-flight and queued requests")
             with self._cond:
@@ -1177,22 +1209,7 @@ class ServeEngine:
         return shared, need, cow
 
     def _chunk_plan(self, plen: int, start: int = 0):
-        """[(start, len), ...] page-aligned chunks covering
-        [start, plen) of the prompt (``start`` — the first position
-        NOT covered by shared prefix pages — must be page-aligned).
-        Full ``prefill_chunk``-token chunks, then one final chunk padded
-        to the page size (so the final chunk always contains the last
-        real prompt token — the sampled position).  prefill_chunk == 0:
-        the whole remainder is one page-aligned chunk."""
-        chunk = self.prefill_chunk or -(-(plen - start) //
-                                        self.page_size) * self.page_size
-        plan = []
-        while plen - start > chunk:
-            plan.append((start, chunk))
-            start += chunk
-        rem = plen - start
-        plan.append((start, -(-rem // self.page_size) * self.page_size))
-        return plan
+        return chunk_plan(plen, self.prefill_chunk, self.page_size, start)
 
     def _admit(self, slot_idx: int, handle: _Handle, grant):
         req = handle.request

@@ -2169,6 +2169,8 @@ def replica_spawner(cmd: List[str], rendezvous_dir: str,
         os.path.abspath(__file__))))
     cwd = os.path.abspath(cwd) if cwd else repo_root
 
+    from dtf_tpu.cli.launch import refuse_shared_chips
+
     def spawn(replica_id: int, generation: int) -> subprocess.Popen:
         env = dict(os.environ)
         env["DTF_PROCESS_ID"] = str(replica_id)
@@ -2177,6 +2179,9 @@ def replica_spawner(cmd: List[str], rendezvous_dir: str,
         env["PYTHONPATH"] = (repo_root + os.pathsep
                              + env.get("PYTHONPATH", ""))
         env.update(env_extra or {})
+        # replicas choose no device (each takes chip 0): a second
+        # replica process on a TPU host is refused, not left to hang
+        refuse_shared_chips(replica_id + 1, env, "replica tier")
         ckpt = (checkpoint_map or {}).get(replica_id, "")
         if ckpt:
             env["DTF_SERVE_CHECKPOINT"] = ckpt

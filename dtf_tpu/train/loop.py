@@ -988,13 +988,15 @@ class Trainer:
         counts with the MFU ledger.  Returns the compiled executable —
         the SAME program the jit path would run (donation included), so
         cost analysis is free rather than a second compile.  Any
-        failure degrades to the plain jit path with no registration:
-        observability must never change whether a run trains."""
+        failure degrades to the plain jit path with no registration
+        (observability must never change whether a run trains), and
+        says so: a step that trains without an MFU entry is a finding."""
         try:
             compiled = self.train_step.lower(state, *sharded).compile()
         except Exception as e:  # noqa: BLE001 — see docstring
-            log.debug("ledger: train-step AOT compile unavailable (%s) "
-                      "— using the jit path, no MFU entry", e)
+            log.warning("ledger: train-step AOT compile failed (%s: %s) "
+                        "— using the jit path, no MFU entry",
+                        type(e).__name__, e)
             return self.train_step
         ledger.register("train_step", compiled=compiled)
         return compiled
@@ -1291,8 +1293,6 @@ class Trainer:
                         raise
                     global_step += 1
                     if global_step % cfg.log_steps == 0:
-                        # device_get (host copy): block_until_ready can
-                        # return early on some remote platforms
                         # dtflint: sync-point (log-cadence host copy —
                         # the ledger's log_window wall time accounts it)
                         loss_val = jax.device_get(metrics["loss"])
@@ -1437,8 +1437,7 @@ class Trainer:
         for cb in callbacks:
             _call(cb, "on_train_end", {"state": state, "history": history})
         if metrics is not None:
-            # host copy: the only reliable completion sync on platforms
-            # where block_until_ready returns early
+            # host copy of the last loss: the completion barrier
             # dtflint: sync-point (final completion barrier, post-loop)
             jax.device_get(metrics["loss"])
         log.info("train wall time: %.1fs (%d steps)",

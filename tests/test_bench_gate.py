@@ -25,9 +25,10 @@ def _art(path, metrics, bars=None):
 
 
 def test_committed_history_passes_and_smoke_contract():
-    """The repo's own BENCH_r*.json must pass the gate (the ci_check
+    """The repo's own BENCH artifacts must pass the gate (the ci_check
     stage-10 precondition), and the full --smoke contract holds:
-    history green, 2x-degraded artifact caught."""
+    history green, 2x-degraded artifact caught — also for a family
+    that holds a single artifact."""
     history = bench_gate.default_history()
     assert len(history) >= 2
     assert bench_gate.gate(history, history[-1]) == 0
@@ -144,12 +145,28 @@ def test_no_history_and_no_metrics_are_loud(tmp_path):
     assert bench_gate.gate([lone], str(empty)) == 2
 
 
-def test_wrapped_parsed_artifacts_extract_nested_metrics():
-    """The committed {"parsed": ...} wrappers with nested lm /
-    input_pipeline sub-benches all extract, first-occurrence wins
-    (input_pipeline's "default" arm does not clobber the headline)."""
-    metrics, bars = bench_gate.load_artifact(
-        os.path.join(REPO, "BENCH_r05.json"))
+def test_wrapped_parsed_artifacts_extract_nested_metrics(tmp_path):
+    """A driver-written {"parsed": ...} wrapper with nested lm /
+    input_pipeline sub-benches extracts everything, first occurrence
+    wins (input_pipeline's "default" arm does not clobber the
+    headline)."""
+    wrapped = tmp_path / "BENCH_r01.json"
+    wrapped.write_text(json.dumps({"n": 1, "rc": 0, "parsed": {
+        "metric": "resnet50_images_per_sec_per_chip", "value": 2000.0,
+        "value_min": 1990.0, "value_max": 2010.0,
+        "unit": "images/sec/chip",
+        "input_pipeline": {
+            "metric": "imagenet_input_pipeline_images_per_sec_per_host",
+            "value": 277.6, "value_min": 191.1,
+            "unit": "images/sec/host",
+            "default": {
+                "metric":
+                    "imagenet_input_pipeline_images_per_sec_per_host",
+                "value": 285.7, "unit": "images/sec/host"}},
+        "lm": {"metric": "lm_tokens_per_sec_per_chip",
+               "value": 100000.0, "tps_min": 99000.0,
+               "tps_max": 101000.0, "unit": "tokens/sec/chip"}}}))
+    metrics, bars = bench_gate.load_artifact(str(wrapped))
     assert "resnet50_images_per_sec_per_chip" in metrics
     assert "lm_tokens_per_sec_per_chip" in metrics
     assert "imagenet_input_pipeline_images_per_sec_per_host" in metrics

@@ -154,6 +154,27 @@ def test_mesh_spec_presets_and_descriptor():
         mesh_spec("hbm=0")
 
 
+def test_live_mesh_spec_describes_the_chip_it_finds(monkeypatch):
+    """On a TPU the live descriptor takes HBM from the device and the
+    rates from the preset for its device_kind; a kind with no preset is
+    refused rather than described as some other chip."""
+    from dtf_tpu.runtime import mesh as mesh_mod
+
+    def topo(kind):
+        return lambda: {"num_hosts": 1, "devices_per_host": 4,
+                        "platform": "tpu", "device_kind": kind,
+                        "hbm_bytes": 16_909_336_064}
+
+    monkeypatch.setattr(mesh_mod, "topology", topo("TPU v5 lite"))
+    m = mesh_spec("")
+    assert (m.num_hosts, m.devices_per_host) == (1, 4)
+    assert m.hbm_bytes == 16_909_336_064
+    assert m.device_flops == PRESETS["v5e-4"].device_flops
+    monkeypatch.setattr(mesh_mod, "topology", topo("TPU v9000"))
+    with pytest.raises(ValueError, match="no rate preset"):
+        mesh_spec("")
+
+
 def test_axis_bandwidth_tiers():
     m = PRESETS["4x4"]  # 4 hosts × 4 devices
     assert m.axis_bandwidth(1, 4) == m.intra_bw    # span fits one host

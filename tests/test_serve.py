@@ -320,6 +320,35 @@ def test_bridge_missing_checkpoint_fails_loudly(tmp_path):
         load_inference_variables(model_dir=str(tmp_path / "nope"))
 
 
+def test_serve_fails_when_the_engine_thread_dies(model_and_params,
+                                                 monkeypatch):
+    """A decode failure kills the engine thread, which hands every
+    client a cancelled, empty result so nobody hangs — serve() must
+    turn that into a failed run carrying the cause, not into stats of
+    zeros and exit code 0."""
+    from dtf_tpu.cli import serve_main
+    from dtf_tpu.config import parse_flags
+
+    model, params = model_and_params
+
+    def rigged_engine(cfg, random_init=False, replica_rank=None):
+        engine = ServeEngine(model, params, max_batch=2, max_seq_len=SEQ,
+                             kv_page_size=4, max_delay_s=0.0)
+
+        def boom(*a, **kw):
+            raise FloatingPointError("injected decode failure")
+        engine.decoder.decode_step = boom
+        return model, engine
+
+    monkeypatch.setattr(serve_main, "build_serving_engine", rigged_engine)
+    cfg = parse_flags(["--serve_requests", "3", "--serve_prompt_len", "4",
+                       "--serve_max_new_tokens", "4"],
+                      defaults=serve_main.SERVE_DEFAULTS)
+    with pytest.raises(RuntimeError, match="engine thread died") as exc:
+        serve_main.serve(cfg)
+    assert isinstance(exc.value.__cause__, FloatingPointError)
+
+
 @pytest.mark.slow
 def test_serve_main_random_init_demo(tmp_path, monkeypatch):
     """The CLI entry end-to-end on a tiny config: synthetic traffic

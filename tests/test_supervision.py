@@ -56,6 +56,35 @@ def test_startup_grace_spares_slow_starter(tmp_path):
     assert rc == 0
 
 
+def test_local_fanout_refuses_to_share_tpu_chips(tmp_path, monkeypatch):
+    """Nothing assigns chips to local children, so on a host that
+    exposes TPU chips a fan-out whose children could see them is
+    refused before anything is spawned; children held to the CPU (the
+    virtual-device mesh) and single-process launches pass."""
+    import pytest
+
+    from dtf_tpu.cli import launch
+
+    monkeypatch.setattr(launch, "local_tpu_chips", lambda: 4)
+    for platforms in ("tpu,cpu", ""):
+        with pytest.raises(RuntimeError, match="same chips"):
+            launch.refuse_shared_chips(2, {"JAX_PLATFORMS": platforms},
+                                       "launch")
+    launch.refuse_shared_chips(2, {"JAX_PLATFORMS": "cpu"}, "launch")
+    launch.refuse_shared_chips(1, {"JAX_PLATFORMS": "tpu"}, "launch")
+    # the launcher itself, and the replica tier's spawner
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    with pytest.raises(RuntimeError, match="same chips"):
+        launch_local([sys.executable, "-c", "pass"], 2, "localhost:1",
+                     str(tmp_path), None)
+    assert not list(tmp_path.iterdir())
+    from dtf_tpu.serve.router import replica_spawner
+    spawn = replica_spawner([sys.executable, "-c", "pass"], str(tmp_path))
+    spawn(0, 0).wait(timeout=60)
+    with pytest.raises(RuntimeError, match="same chips"):
+        spawn(1, 0)
+
+
 def test_hosts_mode_rejects_supervision_flags():
     import pytest
     with pytest.raises(ValueError, match="supervise"):

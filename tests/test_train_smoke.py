@@ -75,9 +75,13 @@ def test_mirrored_8_devices():
     check_stats(run(base_cfg(distribution_strategy="mirrored")))
 
 
-@pytest.mark.slow  # alias of the mirrored strategy path (tier-1)
-def test_tpu_strategy_alias():
-    check_stats(run(base_cfg(distribution_strategy="tpu")))
+def test_tpu_strategy_refuses_the_cpu():
+    """'tpu' names the device, not just the mirrored layout: with no
+    chip visible the run fails instead of training on the CPU JAX fell
+    back to (the mesh mapping it shares with 'mirrored' is covered by
+    the mirrored cells above)."""
+    with pytest.raises(RuntimeError, match="found no TPU"):
+        run(base_cfg(distribution_strategy="tpu"))
 
 
 @pytest.mark.slow

@@ -178,6 +178,16 @@ def test_lm_train_smoke_single(tiny_transformer_registry):
     assert np.isfinite(stats["loss"])
 
 
+def test_lm_seq_len_past_the_preset_position_table(
+        tiny_transformer_registry):
+    """--seq_len beyond the preset's position table (16 rows here, 2048
+    in the registry) grows the table with it; before, the run died
+    slicing 32 positions out of 16 — which is what `--seq_len 8192`
+    did to transformer_tpu on the chip."""
+    stats = run(base_cfg(distribution_strategy="off", seq_len=32))
+    assert np.isfinite(stats["loss"])
+
+
 def test_lm_train_data_parallel(tiny_transformer_registry):
     stats = run(base_cfg(distribution_strategy="mirrored", num_devices=4))
     assert np.isfinite(stats["loss"])
