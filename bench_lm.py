@@ -77,8 +77,8 @@ R2_GPIPE_SPEEDUP = 1.62
 
 SEQ = 2048
 VOCAB = 32_768
-# flagship model dims — build_trainer, the mfu_model formula, and
-# bench_profile_lm all derive from these
+# flagship model dims — build_trainer and the mfu_model formula derive
+# from these
 D_MODEL = 768
 LAYERS = 12
 D_FF = 3072
@@ -111,9 +111,8 @@ def build_trainer(batch: int, remat: bool, seq: int = SEQ,
     # benchmark purity default: the reference's own
     # --report_accuracy_metrics false (common.py:277-278) — the
     # in-step argmax otherwise reads the full [B·S, 32k] f32 logits
-    # every step (measured 3-7 ms of a 246 ms step;
-    # bench_profile_lm.py carries the number).  Loss is still computed
-    # and synced.
+    # every step (measured 3-7 ms of a 246 ms step).  Loss is still
+    # computed and synced.
     cfg = Config(model="transformer", dataset="lm", dtype="bf16",
                  batch_size=batch, distribution_strategy="tpu",
                  optimizer="adamw", skip_eval=True, train_steps=1,
@@ -191,12 +190,12 @@ def train_bench(remat: bool, warmup: int = 3, iters: int = 10,
                       if peak else None)
             # true model flops: XLA's count excludes the Pallas
             # attention kernels, and 6N ignores attention entirely —
-            # at long sequence the S² attention term DOMINATES (same
-            # formula as bench_profile_lm: causal halves the live
-            # blocks, backward does 2.5x forward — the 3.5x total
-            # counts ONE forced softmax recompute as model flops; see
-            # module docstring for the convention).  heads·d_head =
-            # d_model, so the term is head-layout-independent.
+            # at long sequence the S² attention term DOMINATES (causal
+            # halves the live blocks, backward does 2.5x forward — the
+            # 3.5x total counts ONE forced softmax recompute as model
+            # flops; see module docstring for the convention).
+            # heads·d_head = d_model, so the term is
+            # head-layout-independent.
             # matmul_params: N minus the two lookup tables; LN/bias
             # params (<0.1% of N) intentionally stay in the count.
             matmul_params = n_params - (VOCAB + seq) * D_MODEL

@@ -16,13 +16,16 @@ batch is (ShardReader.batch is pure in position).  ``start_step=n``
 therefore replays the exact mid-epoch suffix of the stream — the piece
 that makes killed-at-K resume bit-exact on imagenet.
 
-Supervision: the pool owns its workers.  A worker that dies (chaos
-``reader_crash@batch:N``, a real OOM-kill) is respawned at its recorded
-per-shard positions with a fresh queue; determinism guarantees the
-respawned worker recomputes exactly the batches the dead one would
-have produced, so the merged stream is unchanged.  Respawns are
-budgeted (a deterministically-crashing reader must fail loudly, not
-spin), counted on the obs registry, and traced.
+Supervision: the pool owns its workers.  Each is the ONLY writer of a
+one-way pipe (the parent closes its copy of the send end once the
+worker has started), so its death (chaos ``reader_crash@batch:N``, a
+real OOM-kill; between messages or inside one) is end-of-file on the
+parent's read: no read of the consumer can outlive the worker.  It is
+respawned at its recorded per-shard positions with a fresh pipe;
+determinism guarantees the respawned worker recomputes exactly the
+batches the dead one would have produced, so the merged stream is
+unchanged.  Respawns are budgeted (a deterministically-crashing reader
+must fail loudly, not spin), counted on the obs registry, and traced.
 
 Observability: ``data_reader_lag_s`` (time the consumer blocked waiting
 for the next batch) and ``data_cache_hit_ratio`` land on the default
@@ -37,7 +40,7 @@ import atexit
 import logging
 import multiprocessing as mp
 import os
-import queue as queue_mod
+import queue
 import threading
 import time
 from typing import Dict, List, Optional, Tuple
@@ -49,7 +52,7 @@ from dtf_tpu.obs import trace
 
 log = logging.getLogger("dtf_tpu")
 
-# queue item tags (first tuple element is the shard id for batches)
+# message tag (a batch's first tuple element is its shard id)
 _ERROR = "__error__"
 
 
@@ -82,41 +85,46 @@ def shard_positions(step: int, num_shards: int) -> List[int]:
             for s in range(num_shards)]
 
 
-def _worker_main(payload: dict, out_q) -> None:
+def _send_all(out: queue.Queue, conn) -> None:
+    """Worker's sender thread: pickling and the pipe write overlap the
+    next batch's decode.  A send fails only when the consumer is gone;
+    the process ends there rather than fill its queue and block."""
+    try:
+        while True:
+            conn.send(out.get())
+            out.task_done()
+    finally:
+        os._exit(1)
+
+
+def _worker_main(payload: dict, conn) -> None:
     """Shard-worker process body: build this worker's ShardReaders and
     produce batches round-robin over its shards, ascending k per shard,
     forever (training streams are infinite).  Every item carries its
     (shard, k) tag plus cumulative cache counters; backpressure is the
-    bounded queue."""
+    bounded queue behind the pipe."""
     # keep the spawned child off any accelerator: readers are pure
     # numpy/PIL/libjpeg and must never grab a TPU chip from the parent.
     # Forced, not setdefault: the parent that owns the chip is exactly
     # the one whose environment says JAX_PLATFORMS=tpu
     os.environ["JAX_PLATFORMS"] = "cpu"
+    out = queue.Queue(maxsize=2 * len(payload["shards"]) + 2)
+    threading.Thread(target=_send_all, args=(out, conn), daemon=True).start()
     try:
         from dtf_tpu.data.service.reader import make_reader
-        readers = {}
-        for s in payload["shards"]:
-            readers[s] = make_reader(
-                payload["data_dir"], s, payload["num_shards"],
-                payload["batch_size"], seed=payload["seed"],
-                process_id=payload["process_id"],
-                process_count=payload["process_count"],
-                wire=payload["wire"], cache_dir=payload["cache_dir"],
-                cache_limit_bytes=payload["cache_limit_bytes"])
+        readers = {s: make_reader(shard=s, **payload["reader"])
+                   for s in payload["shards"]}
         ks = dict(payload["start_ks"])
         while True:
             for s in payload["shards"]:
                 images, labels = readers[s].batch(ks[s])
                 hits, lookups = readers[s].cache_stats()
-                out_q.put((s, ks[s], images, labels, hits, lookups))
+                out.put((s, ks[s], images, labels, hits, lookups))
                 ks[s] += 1
     except Exception as e:  # noqa: BLE001 — surfaced in the parent
         import traceback
-        try:
-            out_q.put((_ERROR, repr(e), traceback.format_exc()))
-        except Exception:  # noqa: BLE001 — queue torn down under us
-            pass
+        out.put((_ERROR, repr(e), traceback.format_exc()))
+        out.join()  # sent before this process exits
 
 
 class ServiceStream:
@@ -165,7 +173,9 @@ class ServiceStream:
         # next shard-local batch each shard owes the merged stream
         self._need: Dict[int, int] = dict(
             enumerate(shard_positions(start_step, num_shards)))
-        self._payload_base = dict(
+        # make_reader's arguments, the shard apart: one statement of
+        # them for the inline readers and for every worker's
+        self._reader_args = dict(
             data_dir=data_dir, num_shards=self.num_shards,
             batch_size=int(batch_size), seed=int(seed),
             process_id=int(process_id), process_count=int(process_count),
@@ -186,26 +196,20 @@ class ServiceStream:
             lag_watchdog = ReaderLagWatchdog()
         self._lag_watchdog = lag_watchdog
         # (hits, lookups) high-water per shard — cumulative counters
-        # ride every queue item; the ratio aggregates across shards
+        # ride every batch; the ratio aggregates across shards
         self._cache_stats: Dict[int, Tuple[int, int]] = {}
 
         if self.num_workers == 0:
             from dtf_tpu.data.service.reader import make_reader
-            self._readers = {
-                s: make_reader(data_dir, s, self.num_shards,
-                               int(batch_size), seed=int(seed),
-                               process_id=int(process_id),
-                               process_count=int(process_count),
-                               wire=wire, cache_dir=cache_dir,
-                               cache_limit_bytes=int(cache_limit_bytes))
-                for s in range(self.num_shards)}
+            self._readers = {s: make_reader(shard=s, **self._reader_args)
+                             for s in range(self.num_shards)}
         else:
             self._ctx = mp.get_context("spawn")
             self._owner = {s: s % self.num_workers
                            for s in range(self.num_shards)}
-            self._procs: List[Optional[mp.process.BaseProcess]] = \
-                [None] * self.num_workers
-            self._queues: List[Optional[object]] = [None] * self.num_workers
+            # per worker: its process, and the receive end of its pipe
+            self._procs: list = [None] * self.num_workers
+            self._conns: list = [None] * self.num_workers
             # parent-side reorder buffer: {(shard, k): (images, labels)}
             self._buf: Dict[Tuple[int, int], Tuple[np.ndarray,
                                                    np.ndarray]] = {}
@@ -219,14 +223,15 @@ class ServiceStream:
 
     def _spawn(self, w: int) -> None:
         shards = self._worker_shards(w)
-        payload = dict(self._payload_base, shards=shards,
+        payload = dict(reader=self._reader_args, shards=shards,
                        start_ks={s: self._need[s] for s in shards})
-        q = self._ctx.Queue(maxsize=2 * len(shards) + 2)
-        p = self._ctx.Process(target=_worker_main, args=(payload, q),
+        recv, send = self._ctx.Pipe(duplex=False)
+        p = self._ctx.Process(target=_worker_main, args=(payload, send),
                               daemon=True, name=f"dtf-data-worker-{w}")
         p.start()
+        send.close()  # the worker is the sole writer from here on
         self._procs[w] = p
-        self._queues[w] = q
+        self._conns[w] = recv
 
     def _respawn(self, w: int, reason: str) -> None:
         self.respawns += 1
@@ -241,20 +246,12 @@ class ServiceStream:
         shards = self._worker_shards(w)
         # drop the dead worker's buffered batches: the respawned worker
         # recomputes them identically from its recorded positions, and
-        # a half-delivered queue must not leave gaps behind kept items
+        # a half-delivered pipe must not leave gaps behind kept items
         for key in [key for key in self._buf if key[0] in shards]:
             del self._buf[key]
-        try:
-            p.kill()
-        except Exception:  # noqa: BLE001 — already dead
-            pass
+        p.kill()  # it may have lost its pipe and lived; no-op on the dead
         p.join(timeout=5.0)
-        q = self._queues[w]
-        try:
-            q.close()
-            q.cancel_join_thread()
-        except Exception:  # noqa: BLE001
-            pass
+        self._conns[w].close()
         log.warning("data service: worker %d died (%s, exit %s) — "
                     "respawning at positions %s", w, reason, exitcode,
                     {s: self._need[s] for s in shards})
@@ -278,15 +275,18 @@ class ServiceStream:
             item = self._buf.pop((s, k), None)
             if item is not None:
                 return item
+            conn = self._conns[w]
             try:
-                got = self._queues[w].get(timeout=self.GET_TIMEOUT_S)
-            except queue_mod.Empty:
-                p = self._procs[w]
-                if not p.is_alive():
-                    self._respawn(w, "worker process dead")
-                continue
-            except Exception as e:  # noqa: BLE001 — torn pickle mid-kill
-                self._respawn(w, f"queue read failed: {e!r}")
+                if not conn.poll(self.GET_TIMEOUT_S):
+                    # alive and slow is not a death
+                    if not self._procs[w].is_alive():
+                        self._respawn(w, "worker process dead")
+                    continue
+                got = conn.recv()
+            except (EOFError, OSError) as e:
+                # how a death is seen: the sole writer is gone, so the
+                # read ends — between messages or inside one
+                self._respawn(w, f"worker's pipe closed: {e!r}")
                 continue
             if got[0] == _ERROR:
                 # a reader exception is deterministic (corrupt shard,
@@ -367,24 +367,14 @@ class ServiceStream:
                 r.close()
         else:
             for p in self._procs:
-                if p is not None:
-                    try:
-                        p.terminate()
-                    except Exception:  # noqa: BLE001
-                        pass
+                p.terminate()
             for p in self._procs:
-                if p is not None:
+                p.join(timeout=5.0)
+                if p.is_alive():
+                    p.kill()
                     p.join(timeout=5.0)
-                    if p.is_alive():
-                        p.kill()
-                        p.join(timeout=5.0)
-            for q in self._queues:
-                if q is not None:
-                    try:
-                        q.close()
-                        q.cancel_join_thread()
-                    except Exception:  # noqa: BLE001
-                        pass
+            for conn in self._conns:
+                conn.close()
             atexit.unregister(self.close)
 
 

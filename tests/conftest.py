@@ -27,10 +27,55 @@ import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
+import faulthandler  # noqa: E402
+import signal  # noqa: E402
 import sys  # noqa: E402
 import threading  # noqa: E402
 
 import pytest  # noqa: E402
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+from tools.dtflint.markers import DEFAULT_CEILING_S  # noqa: E402
+
+# --- a clock of its own for every test ------------------------------------
+# Under ``--dist loadfile`` a test that blocks for ever takes the rest of
+# its file and the whole run's clock with it, and the log shows only dots.
+# The limits derive from the marker audit's per-test ceiling: a test may
+# run to 6x what the audit would flag (30x when marked slow) before it is
+# failed here with every thread's stack.
+
+TEST_LIMIT_S = 6 * DEFAULT_CEILING_S
+SLOW_TEST_LIMIT_S = 30 * DEFAULT_CEILING_S
+
+
+@pytest.fixture(autouse=True)
+def _test_clock(request):
+    """Fail a test that outlives its limit, with a dump of all threads.
+
+    SIGALRM lands on the main thread, which is where pytest (and every
+    xdist worker) runs the test, so it interrupts the blocking read or
+    lock wait the test hangs in; the other tests of the file then run."""
+    if (not hasattr(signal, "setitimer")
+            or threading.current_thread() is not threading.main_thread()):
+        yield
+        return
+    limit = (SLOW_TEST_LIMIT_S if request.node.get_closest_marker("slow")
+             else TEST_LIMIT_S)
+
+    def on_alarm(signum, frame):
+        faulthandler.dump_traceback(file=sys.__stderr__, all_threads=True)
+        pytest.fail(f"{request.node.nodeid} still running after its "
+                    f"{limit:g} s limit (thread stacks on stderr)",
+                    pytrace=False)
+
+    old = signal.signal(signal.SIGALRM, on_alarm)
+    signal.setitimer(signal.ITIMER_REAL, limit)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, old)
+
 
 # --- thread sanitizer: record where every NON-DAEMON thread started -------
 # The serving tier spawns a lot of threads (router dispatcher/prober/

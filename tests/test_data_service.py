@@ -245,21 +245,86 @@ def test_reader_crash_writes_supervisor_event_with_positions(
     assert "ts" in r and r["respawns"] >= 1
 
 
+def _wait_blocked_in_pipe_write(pid, timeout_s=5.0):
+    """True once some thread of ``pid`` sleeps in the kernel's
+    pipe_write; False when the kernel does not say (no /proc wchan) —
+    the caller has then simply waited ``timeout_s``."""
+    deadline = time.monotonic() + timeout_s
+    while time.monotonic() < deadline:
+        for task in os.listdir(f"/proc/{pid}/task"):
+            try:
+                with open(f"/proc/{pid}/task/{task}/wchan") as f:
+                    if "pipe_write" in f.read():
+                        return True
+            except OSError:
+                pass
+        time.sleep(0.05)
+    return False
+
+
+@pytest.mark.parametrize("how", ["terminate", "kill"])
+def test_worker_death_mid_write_respawns(shards_dir, how):
+    """The invariant, not the luck: a worker killed while a batch is
+    half-written into its pipe — the consumer not reading, so the torn
+    message SITS there — is seen as dead at the next read, respawned
+    once, and the stream carries on bit-exactly.  A batch of 8 is
+    1.2 MB of pixels, twenty pipe buffers: a worker nobody reads from
+    is always stopped in the middle of one."""
+    want = _collect(ServiceStream(shards_dir, 8, seed=7, num_shards=2), 6)
+    s = ServiceStream(shards_dir, 8, seed=7, num_shards=2, num_workers=1)
+    try:
+        got = [next(s), next(s)]
+        worker = s._procs[0]
+        _wait_blocked_in_pipe_write(worker.pid)
+        getattr(worker, how)()
+        worker.join(timeout=5.0)
+        assert not worker.is_alive()
+        t0 = time.monotonic()
+        got.append(next(s))
+        # detection is the next read, not a poll that times out first;
+        # the rest is the respawned worker's start-up
+        assert time.monotonic() - t0 < 60 * s.GET_TIMEOUT_S
+        got += [next(s) for _ in range(3)]
+    finally:
+        s.close()
+    _streams_equal(got, want)
+    assert s.respawns == 1
+
+
 def test_reader_crash_inline_is_harmless(shards_dir):
     chaos.configure("reader_crash@batch:2")
     want = _collect(ServiceStream(shards_dir, 4, seed=7, num_shards=2), 4)
     assert len(want) == 4  # no worker process to kill; stream proceeds
 
 
+def _write_torn_shard(root):
+    _write_shards(root, num_files=1, per_file=8)
+    path = os.path.join(root, "train-00000-of-01024")
+    blob = open(path, "rb").read()
+    open(path, "wb").write(blob[:-9])  # torn final record
+    return root
+
+
 def test_worker_error_surfaces_loudly(tmp_path):
     """A deterministic reader failure (corrupt shard) must raise in the
     consumer, not burn the respawn budget silently."""
-    _write_shards(str(tmp_path), num_files=1, per_file=8)
-    path = os.path.join(str(tmp_path), "train-00000-of-01024")
-    blob = open(path, "rb").read()
-    open(path, "wb").write(blob[:-9])  # torn final record
     with pytest.raises(OSError, match="truncated"):
-        ServiceStream(str(tmp_path), 4, num_shards=1, num_workers=0)
+        ServiceStream(_write_torn_shard(str(tmp_path)), 4, num_shards=1,
+                      num_workers=0)
+
+
+def test_worker_error_reaches_the_consumer(tmp_path):
+    """The same failure inside a worker process: its message comes
+    through the pipe before the process exits, and the consumer raises
+    it at once instead of respawning a reader that fails every time."""
+    s = ServiceStream(_write_torn_shard(str(tmp_path)), 4, num_shards=1,
+                      num_workers=1)
+    try:
+        with pytest.raises(RuntimeError, match="worker 0 failed.*truncated"):
+            next(s)
+        assert s.respawns == 0
+    finally:
+        s.close()
 
 
 # ---------------------------------------------------------------------------

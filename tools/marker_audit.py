@@ -5,10 +5,11 @@ THIN SHIM: the logic moved into the project-wide static-analysis suite
 (tools/dtflint, rule ``test-marker``) so CI runs ONE analysis
 entrypoint; this CLI remains for muscle memory and scripts.  Semantics
 are unchanged: tier-1 runs `-m 'not slow'` under a hard wall-clock
-budget (ROADMAP: 870 s), which only holds if every genuinely heavy
-test carries the `slow` marker.  The conftest hook dumps per-test call
-durations to ``tests/.last_durations.json``; exit 1 (listing
-offenders) when any UNMARKED test took longer than the ceiling.
+limit (1,470 s over six xdist workers at PR 24; ROADMAP D8), which only
+holds if every genuinely heavy test carries the `slow` marker.  The
+conftest hook dumps per-test call durations to
+``tests/.last_durations.json``; exit 1 (listing offenders) when any
+UNMARKED test took longer than the ceiling.
 
     python tools/marker_audit.py [--ceiling 20] [--path tests/.last_durations.json]
 """
