@@ -164,9 +164,10 @@ class CausalSelfAttention(nn.Module):
                                         use_pallas=self.use_pallas)
                 else:
                     # paged_attention_auto: the Pallas flash-decode
-                    # kernel on TPU (default-on — pages read through
-                    # the block table in-kernel, no gathered window,
-                    # window trim fused as a dynamic page skip), the
+                    # kernel on TPU (default-on — each row's live pages
+                    # streamed from the pool as stored, ids from the
+                    # block table in-kernel; no gathered window, the
+                    # loop ends at the row's own last page), the
                     # gather oracle elsewhere.  window_pages (STATIC,
                     # decode.py computes it from the chunk's start)
                     # trims the GATHER path to the pages the chunk can

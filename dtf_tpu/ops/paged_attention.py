@@ -38,15 +38,27 @@ Two formulations of attention-over-pages coexist:
       mask, dense softmax.  Portable, the CPU-default oracle.  Pays the
       PR-3 gather tax (~3% of contiguous step time) plus, for prefill
       chunks, a host-side STATIC window trim (one compile per window).
-  kernel (``paged_flash_decode``)   — a Pallas kernel that reads KV
-      pages THROUGH the block table in-kernel (scalar-prefetched, so
-      each page's DMA source address is computed before the body runs):
-      no gathered window ever materializes, and the window trim is
-      FUSED — pages past ``index + S − 1`` are skipped by a dynamic
-      ``pl.when`` predicate, so one compile covers every chunk index
-      where the gather path needed one per static window.  Online-
-      softmax carry in VMEM scratch (ops.blockwise math, the same rule
-      the flash kernels use).
+  kernel (``paged_flash_decode``)   — a Pallas kernel that streams, per
+      row, only the pages that row HAS.  The pools stay in HBM exactly
+      as stored, ``[P, page, H, Dh]``: a page with all its heads is one
+      contiguous region (64 KB at 16 × 128 bf16), copied whole by a
+      manual DMA whose source page id comes from the scalar-prefetched
+      block table.  The grid is the rows; inside a grid point a loop of
+      ``ceil((index + S) / (pages_per_block · page))`` trips copies
+      blocks of pages into a double buffer (the next block's copies in
+      flight during this block's math) and folds each block into the
+      online-softmax carry (ops.blockwise math, the same rule the flash
+      kernels use).  A page past the row's length costs nothing — no
+      grid step, no DMA, no predicate — so the work is the live KV, not
+      the table's capacity, and one compile covers every chunk index
+      where the gather path needed one per static window.  No
+      transposed, gathered or relaid-out copy of a pool exists.
+
+      A block in the stored order is a ``[T · H, Dh]`` matrix whose row
+      ``t · H + h`` is token t of head h: each head's ``[T, Dh]`` rows
+      are pulled out with a sublane-strided load and the heads take
+      turns.  How many pages make a block, and how many heads share a
+      grid point, follows from the static shapes (``_plan``).
 
 ``paged_attention_auto`` dispatches between them: the kernel by default
 on TPU, the gather oracle elsewhere; ``use_pallas="interpret"`` runs
@@ -167,137 +179,268 @@ def paged_attention(q, pool_k, pool_v, block_table, index):
 # Pallas paged flash-decode kernel
 # ---------------------------------------------------------------------------
 
-def _paged_decode_kernel(tbl_ref, idx_ref, q_ref, k_ref, v_ref, o_ref,
-                         oacc_ref, m_ref, l_ref, *, scale, page_size):
-    """Grid (B, H, M): one (row, head) pair streams its pages.
+# VMEM a call plans its scratch within: two K and two V page blocks (the
+# double buffer) in one half, the f32 carry and the q/out blocks of a
+# head group in the other.  A quarter of the v5e's 16 MiB default scoped
+# limit, so Mosaic's temporaries (a score tile, a head's rows) fit
+# beside it.
+_VMEM_BUDGET = 4 * 2 ** 20
 
-    ``tbl_ref`` [B, M] and ``idx_ref`` [B] are scalar-prefetched: the
-    pool in_specs' index maps read ``tbl_ref[b, j]`` to pick the DMA
-    source page BEFORE the body runs — the gather never exists as an
-    array.  The online-softmax carry (un-normalized o in f32, running
-    max m, denominator l — ops.blockwise math, shared with the flash
-    kernels) lives in VMEM scratch across the sequential page
-    dimension.  Pages whose first position lies past ``index + S − 1``
-    are skipped by a DYNAMIC predicate — the window trim the gather
-    path did with a static slice, fused, so one compile covers every
-    chunk index.  Within a live page the causal mask is positional:
-    key position ``j·page + t`` is admitted iff ≤ ``index + i`` (the
-    query's global position) — exactly the gather oracle's mask."""
+
+def _tiled_heads(h, itemsize):
+    """The least head count >= ``h`` that a ``[page, H, Dh]`` page holds
+    in whole tiles, so that it is one DMA and its ``[page * H, Dh]``
+    reading is free: H rows pack ``4 // itemsize`` to a 32-bit sublane
+    word; 1, 2, 4 or a multiple of 8 words tile."""
+    pack = 4 // itemsize
+    words = -(-h // pack)
+    words = -(-words // 8) * 8 if words > 4 else 1 << (words - 1).bit_length()
+    return words * pack
+
+
+def _plan(s, h, d, page_size, m_pages, itemsize):
+    """Static tiling from what a trace can see: ``(pages_per_block,
+    heads_per_group)``.
+
+    ``pages_per_block``: the largest power of two whose K+V double
+    buffer takes half the budget, at most the table's width.
+    ``heads_per_group``: the most heads (a divisor of H, in whole
+    32-bit words of packed rows) whose carry and q/out blocks fit the
+    other half.  All of them at a decode step; a 256-token chunk of 16
+    heads takes 2, and the grid's second axis walks the groups, each
+    streaming the row's pages again — a chunk reuses every page S
+    times, so the repeat is cheap exactly where a split is needed."""
+    page_bytes = page_size * h * d * itemsize
+    ppb = 1
+    while (ppb * 2 <= m_pages
+           and 4 * (ppb * 2) * page_bytes <= _VMEM_BUDGET // 2):
+        ppb *= 2
+    pack = 4 // itemsize
+    # per head: f32 o [S, D], m and l [S, 1] (a lane tile each), and
+    # the pipeline's two q and two out blocks
+    head_bytes = s * (d * 4 + 2 * 128 * 4 + 4 * d * itemsize)
+    hg = h
+    while hg > pack and (hg * head_bytes > _VMEM_BUDGET // 2
+                         or h % hg or hg % pack):
+        hg -= 1
+    return ppb, hg
+
+
+def _head_rows(flat_ref, head, h, t):
+    """Rows of head ``head`` (32-bit pools) or of the head pair ``head``,
+    ``head + 1`` (bf16) out of a ``[t * h, d]`` block whose row
+    ``tok * h + hd`` is token ``tok`` of head ``hd`` — the pool's stored
+    order.  A sublane-strided load; bf16 packs two rows to a 32-bit
+    word, so a pair comes as one strided load of words, split by shift
+    and mask (a bf16 is the top half of the f32 with the same bits)."""
+    if flat_ref.dtype.itemsize == 4:
+        return [flat_ref[pl.ds(head, t, stride=h), :]]
+    words = flat_ref.bitcast(jnp.uint32)[pl.ds(head // 2, t, stride=h // 2), :]
+    lo = pltpu.bitcast(words << 16, jnp.float32)
+    hi = pltpu.bitcast(words & jnp.uint32(0xFFFF0000), jnp.float32)
+    return [lo.astype(flat_ref.dtype), hi.astype(flat_ref.dtype)]
+
+
+def _paged_decode_kernel(tbl_ref, idx_ref, q_ref, k_hbm, v_hbm, o_ref,
+                         kbuf, vbuf, sem, oacc_ref, m_ref, l_ref, *, scale):
+    """Grid (B, head groups): one row streams ITS pages, block by block.
+
+    ``tbl_ref`` [B, M] and ``idx_ref`` [B] are scalar-prefetched (SMEM);
+    ``k_hbm``/``v_hbm`` are the whole pools in HBM, as stored
+    ``[P, page, H, Dh]``; ``kbuf``/``vbuf`` ``[2, ppb, page, H, Dh]`` are
+    the double buffers; ``q_ref``/``o_ref`` [G, S, Dh] the group's
+    heads.  The loop runs ``ceil((index + S) / (ppb·page))``
+    times: block ``i + 1``'s page copies are started before block
+    ``i``'s math, a live page is one contiguous DMA per pool with its id
+    read from the table, and a page past the row's length is neither
+    copied nor waited for — its buffer slot is zeroed instead, so the
+    masked positions multiply finite values (0 × NaN is NaN).  A block
+    lies in VMEM in the stored order, a ``[T·H, Dh]`` matrix; each
+    head's ``[T, Dh]`` rows are pulled out with a strided load and the
+    heads take turns (rolled loops throughout: 24 layers of this compile
+    in every serve body).  The online-softmax carry (ops.blockwise:
+    un-normalized o in f32, running max m, denominator l) lives in VMEM
+    scratch across the blocks.  The causal mask is positional, exactly
+    the gather oracle's: key position ``p`` is admitted iff
+    ``p <= index + i`` for query ``i``."""
     b = pl.program_id(0)
-    j = pl.program_id(2)
-    s = q_ref.shape[0]
-
-    @pl.when(j == 0)
-    def _init():
-        oacc_ref[...] = jnp.zeros_like(oacc_ref)
-        m_ref[...] = jnp.full_like(m_ref, bw.NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
+    g = pl.program_id(1)
+    _, ppb, page_size, h, d = kbuf.shape
+    heads, s, _ = q_ref.shape
+    t = ppb * page_size
+    m_pages = tbl_ref.shape[1]
+    pack = 4 // kbuf.dtype.itemsize
     idx = idx_ref[b]
-    live = j * page_size <= idx + s - 1
+    n_live = jnp.minimum(idx + s, m_pages * page_size)
+    n_blocks = pl.cdiv(n_live, t)
 
-    @pl.when(live)
-    def _accumulate():
-        kpos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)
+    def for_pages(lo, hi, fn):
+        def body(p, carry):
+            fn(p)
+            return carry
+        jax.lax.fori_loop(lo, hi, body, 0)
+
+    def live_pages(blk):
+        return jnp.clip(pl.cdiv(n_live, page_size) - blk * ppb, 0, ppb)
+
+    def copies(blk, slot, p):
+        pid = tbl_ref[b, blk * ppb + p]
+        return (pltpu.make_async_copy(k_hbm.at[pid], kbuf.at[slot, p],
+                                      sem.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[pid], vbuf.at[slot, p],
+                                      sem.at[1, slot]))
+
+    def start(blk, slot):
+        def copy(p):
+            for c in copies(blk, slot, p):
+                c.start()
+
+        def zero(p):
+            kbuf[slot, p] = jnp.zeros(kbuf.shape[2:], kbuf.dtype)
+            vbuf[slot, p] = jnp.zeros(vbuf.shape[2:], vbuf.dtype)
+
+        n = live_pages(blk)
+        for_pages(0, n, copy)
+        for_pages(n, ppb, zero)
+
+    def wait(blk, slot):
+        def done(p):
+            for c in copies(blk, slot, p):
+                c.wait()
+        for_pages(0, live_pages(blk), done)
+
+    oacc_ref[...] = jnp.zeros_like(oacc_ref)
+    m_ref[...] = jnp.full_like(m_ref, bw.NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    start(0, 0)
+
+    def block(blk, carry):
+        slot = blk % 2
+
+        @pl.when(blk + 1 < n_blocks)
+        def _prefetch():
+            start(blk + 1, 1 - slot)
+
+        wait(blk, slot)
+        kflat = kbuf.at[slot].reshape(t * h, d)
+        vflat = vbuf.at[slot].reshape(t * h, d)
+        kpos = blk * t + jax.lax.broadcasted_iota(jnp.int32, (1, t), 1)
         qpos = idx + jax.lax.broadcasted_iota(jnp.int32, (s, 1), 0)
         bias = jnp.where(kpos <= qpos, 0.0, bw.NEG_INF)
-        o, m, l = bw.block_accumulate(
-            oacc_ref[...], m_ref[...][:, 0], l_ref[...][:, 0],
-            q_ref[...], k_ref[...], v_ref[...], scale, bias)
-        oacc_ref[...] = o
-        m_ref[...] = m[:, None]
-        l_ref[...] = l[:, None]
 
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _finalize():
-        o_ref[...] = bw.finalize(
-            oacc_ref[...], l_ref[...][:, 0]).astype(o_ref.dtype)
+        def head_words(i, c):
+            ks = _head_rows(kflat, g * heads + i * pack, h, t)
+            vs = _head_rows(vflat, g * heads + i * pack, h, t)
+            for j, (k, v) in enumerate(zip(ks, vs)):
+                u = i * pack + j
+                o, m, l = bw.block_accumulate(
+                    oacc_ref[u], m_ref[u][:, 0], l_ref[u][:, 0],
+                    q_ref[u], k, v, scale, bias)
+                oacc_ref[u] = o
+                m_ref[u] = m[:, None]
+                l_ref[u] = l[:, None]
+            return c
+
+        return jax.lax.fori_loop(0, heads // pack, head_words, carry)
+
+    jax.lax.fori_loop(0, n_blocks, block, 0)
+    o_ref[...] = bw.finalize(
+        oacc_ref[...], l_ref[...][..., 0]).astype(o_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_flash_decode(q, pool_k, pool_v, block_table, index, *,
                        scale=None, interpret: bool = False):
     """Attention of a chunk of queries over a slot's paged KV history,
-    reading pages through the block table IN-KERNEL.
+    streaming each row's LIVE pages out of the pool as it is stored.
 
     Same contract as :func:`paged_attention` (write-then-attend; q
     [B, S, H, Dh], pools [P, page_size, H, Dh], block_table [B, M],
-    index [B] int32) — the kernel is the hardware-speed formulation:
-    no materialized gathered window, fused window trim (dead pages
-    skipped dynamically), one compile per chunk SHAPE instead of one
-    per static window."""
+    index [B] int32).  The pools stay in HBM in their stored layout —
+    no transposed or gathered copy exists; the work of a row is
+    proportional to its own length, not to the table's width; one
+    compile covers every chunk index.  Tiling follows the static shapes
+    (:func:`_plan`).  Jitted, so that the layers of a model, which call
+    it at one shape, share one trace and one lowering of the kernel:
+    traced per layer it was 25 s of every serve process's set-up."""
     b, s, h, d = q.shape
     page_size = pool_k.shape[1]
     m_pages = block_table.shape[1]
     scale = float(scale) if scale is not None else 1.0 / (d ** 0.5)
-    qh = jnp.swapaxes(q, 1, 2)                       # [B, H, S, D]
-    # The kernel sees the pools head-major, [P, H, page, Dh]: a head's
-    # page is then a (page, Dh) block over the array's LAST TWO dims —
-    # the only placement the TPU lowering accepts for a sub-(8, 128)
-    # extent (a (page, None, Dh) block on [P, page, H, Dh] leaves the
-    # squeezed head second-to-last and is refused, at any H or Dh).
-    # The transpose is logical: XLA's TPU layout for a [P, page, H, Dh]
-    # array with small H already stores (page, Dh) minor-most (checked
-    # in the compiled HLO at 6 and 3 heads × 128 — a bitcast, no copy);
-    # where it does not, XLA inserts the copy and the result is the same.
-    pool_spec = pl.BlockSpec(
-        (None, None, page_size, d),
-        lambda b_, h_, j, tbl, idx: (tbl[b_, j], h_, 0, 0))
+    if pool_k.dtype not in (jnp.bfloat16, jnp.float32):
+        raise ValueError(f"paged_flash_decode reads bf16 or f32 pools, "
+                         f"not {pool_k.dtype} (see _head_rows)")
+    hp = _tiled_heads(h, pool_k.dtype.itemsize)
+    if hp != h:
+        # a head count the TPU's (sublane, lane) tiling cannot hold
+        # whole (6 or 3 bf16 heads: ``transformer_tpu``): zero heads
+        # fill the tile.  This one case copies the pools, every call
+        zeros = ((0, 0), (0, 0), (0, hp - h), (0, 0))
+        return paged_flash_decode(
+            jnp.pad(q, zeros), jnp.pad(pool_k, zeros), jnp.pad(pool_v, zeros),
+            block_table, index, scale=scale, interpret=interpret)[:, :, :h]
+    ppb, hg = _plan(s, h, d, page_size, m_pages, pool_k.dtype.itemsize)
+    qh = jnp.swapaxes(q, 1, 2)                       # [B, H, S, D], q alone
+    qo_spec = pl.BlockSpec((None, hg, s, d),
+                           lambda b_, g_, tbl, idx: (b_, g_, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, h, m_pages),
-        in_specs=[
-            pl.BlockSpec((None, None, s, d),
-                         lambda b_, h_, j, tbl, idx: (b_, h_, 0, 0)),
-            pool_spec,
-            pool_spec,
-        ],
-        out_specs=pl.BlockSpec((None, None, s, d),
-                               lambda b_, h_, j, tbl, idx: (b_, h_, 0, 0)),
+        grid=(b, h // hg),
+        in_specs=[qo_spec,
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=qo_spec,
         scratch_shapes=[
-            pltpu.VMEM((s, d), jnp.float32),
-            pltpu.VMEM((s, 1), jnp.float32),
-            pltpu.VMEM((s, 1), jnp.float32),
+            pltpu.VMEM((2, ppb, page_size, h, d), pool_k.dtype),
+            pltpu.VMEM((2, ppb, page_size, h, d), pool_v.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((hg, s, d), jnp.float32),
+            pltpu.VMEM((hg, s, 1), jnp.float32),
+            pltpu.VMEM((hg, s, 1), jnp.float32),
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, scale=scale,
-                          page_size=page_size),
+        functools.partial(_paged_decode_kernel, scale=scale),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qh.shape, q.dtype),
         interpret=interpret,
         name="paged_flash_decode",
     )(jnp.asarray(block_table, jnp.int32), jnp.asarray(index, jnp.int32),
-      qh, jnp.swapaxes(pool_k, 1, 2), jnp.swapaxes(pool_v, 1, 2))
+      qh, pool_k, pool_v)
     return jnp.swapaxes(out, 1, 2)
 
 
 def paged_flash_decode_reference(q, pool_k, pool_v, block_table, index, *,
                                  scale=None):
-    """Plain-JAX page-by-page accumulation — the kernel's portable
+    """Plain-JAX block-by-block accumulation — the kernel's portable
     oracle, the same role ops.blockwise plays for the flash kernels:
-    identical math (bw.block_accumulate per page, sequential page
-    order).  Dead pages are accumulated under a fully-masked bias
-    rather than skipped — numerically inert by the NEG_INF
-    construction (p underflows to exactly 0, corr is exactly 1) — so
-    the only divergence from the kernel is XLA's batched-vs-per-
-    program einsum reduction order: float-ulp level, pinned by the
-    tests at 1e-6 alongside argmax equality."""
+    identical math (bw.block_accumulate per block of the kernel's own
+    ``pages_per_block``, in page order).  Dead pages are accumulated
+    under a fully-masked bias rather than skipped — numerically inert
+    by the NEG_INF construction (p underflows to exactly 0, corr is
+    exactly 1) — so the only divergence from the kernel is the order in
+    which XLA and the kernel's matmuls sum a row: float-ulp level,
+    pinned by the tests at 1e-6 alongside argmax equality."""
     b, s, h, d = q.shape
     page_size = pool_k.shape[1]
     m_pages = block_table.shape[1]
     scale = float(scale) if scale is not None else 1.0 / (d ** 0.5)
+    ppb, _ = _plan(s, _tiled_heads(h, pool_k.dtype.itemsize), d, page_size,
+                   m_pages, pool_k.dtype.itemsize)
+    t = ppb * page_size
+    table = jnp.pad(block_table, ((0, 0), (0, -m_pages % ppb)))
     qh = jnp.swapaxes(q, 1, 2)                       # [B, H, S, D]
     o = jnp.zeros(qh.shape, jnp.float32)
     m = jnp.full((b, h, s), bw.NEG_INF, jnp.float32)
     l = jnp.zeros((b, h, s), jnp.float32)
     qpos = index[:, None, None, None] + jnp.arange(
         s, dtype=jnp.int32)[None, None, :, None]     # [B, 1, S, 1]
-    for j in range(m_pages):
-        k = jnp.swapaxes(pool_k[block_table[:, j]], 1, 2)  # [B, H, P, D]
-        v = jnp.swapaxes(pool_v[block_table[:, j]], 1, 2)
-        kpos = (j * page_size + jnp.arange(page_size, dtype=jnp.int32)
-                )[None, None, None, :]               # [1, 1, 1, P]
+    for j in range(0, table.shape[1], ppb):
+        pages = table[:, j:j + ppb]                  # [B, ppb]
+        k = jnp.swapaxes(pool_k[pages].reshape(b, t, h, d), 1, 2)
+        v = jnp.swapaxes(pool_v[pages].reshape(b, t, h, d), 1, 2)
+        kpos = (j * page_size + jnp.arange(t, dtype=jnp.int32)
+                )[None, None, None, :]               # [1, 1, 1, T]
         bias = jnp.where(kpos <= qpos, 0.0, bw.NEG_INF)
         o, m, l = bw.block_accumulate(o, m, l, qh, k, v, scale, bias)
     return jnp.swapaxes(bw.finalize(o, l).astype(q.dtype), 1, 2)
@@ -311,8 +454,8 @@ def paged_attention_auto(q, pool_k, pool_v, block_table, index, *,
     gather elsewhere); True = kernel; "interpret" = kernel through the
     Pallas interpreter (CPU kernel validation); False = gather.
     ``window_pages`` (static) trims the GATHER path's window exactly as
-    before; the kernel ignores it — its dynamic live predicate skips
-    the same pages without a per-window recompile."""
+    before; the kernel ignores it — its loop stops at the row's own
+    last page without a per-window recompile."""
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
     if use_pallas:

@@ -227,7 +227,8 @@ class Decoder:
             # TRACED.  Gather path: window_pages = the chunk's visible
             # pages → one compile per (chunk shape, window), buying the
             # O(prompt²/2) static trim.  Kernel path: the kernel trims
-            # dynamically (pl.when dead-page skip), so prefill_chunk
+            # dynamically (its loop over a row's pages ends at the
+            # row's own length), so prefill_chunk
             # passes window_pages=None and the body compiles ONCE per
             # chunk shape — the per-chunk-index compile storm is gone,
             # not just the gather

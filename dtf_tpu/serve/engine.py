@@ -645,6 +645,14 @@ class ServeEngine:
         self._m_tp_ways.set(getattr(self.decoder, "tp", 1))
         self._m_step_time = self.metrics.histogram("serve_decode_step_s",
                                                    unit="s")
+        # pages the paged decode kernel is asked to attend in a step:
+        # ceil((index + 1) / page) summed over the rows, idle rows
+        # (index 0) included — they read the scratch page.  Over
+        # max_batch x pages_per_slot it is the share of the block
+        # table that is live, and times the page's bytes the KV a step
+        # has to read
+        self._m_live_pages = self.metrics.histogram(
+            "serve_decode_live_pages", unit="pages")
         # prefix sharing: pages shared instead of allocated, COW
         # copies, and the live shared-holder count
         self._m_prefix_hits = self.metrics.counter(
@@ -1360,6 +1368,9 @@ class ServeEngine:
                     and s.handle.request.trace_id]
             if tids:
                 attrs["traces"] = tids
+        if tables is not None:
+            self._m_live_pages.observe(
+                int((index // self.page_size + 1).sum()))
         pre_compiled = self.decoder.compiled_count
         with trace.span("serve_decode", **attrs):
             out, self._cache, _ = self.decoder.decode_step(

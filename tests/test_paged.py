@@ -259,6 +259,25 @@ def test_long_prompt_prefills_in_chunks_while_decoding(model_and_params):
         eng.stop(drain=False)
 
 
+def test_decode_steps_record_their_live_pages(model_and_params):
+    """``serve_decode_live_pages``: one sample per decode step, the
+    pages the step's rows hold — ceil((index + 1) / page) over all
+    ``max_batch`` rows, an idle row counting its scratch page.  One
+    request alone in a 4-row batch: a 5-token prompt (PAGE 4) decodes 5
+    tokens at index 5..9, i.e. 2, 2, 2, 3, 3 pages, plus 3 idle rows."""
+    model, params = model_and_params
+    eng = paged_engine(model, params, max_batch=4)
+    try:
+        prompt = np.arange(1, 6, dtype=np.int32)
+        eng.submit(prompt, max_new_tokens=6).result(timeout=300)
+        h = eng.metrics.get("serve_decode_live_pages")
+        assert h.count == eng.metrics.get("serve_decode_step_s").count == 5
+        assert (h.percentile(0), h.percentile(100)) == (2 + 3, 3 + 3)
+        assert h.mean == pytest.approx((2 + 2 + 2 + 3 + 3) / 5 + 3)
+    finally:
+        eng.stop(drain=False)
+
+
 def test_begin_drain_racing_inflight_prefill_chunk(model_and_params):
     """begin_drain() landing BETWEEN a request's prefill chunks (the
     SIGTERM-mid-prefill race): the drain must finish that request —

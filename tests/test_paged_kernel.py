@@ -1,14 +1,15 @@
 """Pallas paged flash-decode kernel: interpret-mode validation on CPU.
 
-The kernel reads KV pages THROUGH the block table in-kernel (scalar-
-prefetched index maps), so no gathered window ever materializes and the
-window trim is a fused dynamic predicate.  Tier-1 pins, per the same
-contract the flash kernels use (ops/flash_attention.py):
+The kernel streams each row's LIVE pages out of the pool as it is
+stored ([P, page, H, Dh], in HBM): blocks of whole pages by manual DMA
+into a double buffer, page ids from the scalar-prefetched block table,
+a loop whose trip count is the row's own length.  Tier-1 pins, per the
+same contract the flash kernels use (ops/flash_attention.py):
 
-  - kernel ≡ blockwise reference BIT-exact (identical accumulation
-    order, identical math — any drift is a kernel bug);
-  - kernel ≡ the `paged_attention` gather oracle to float ulps
-    (batched-vs-per-program einsum reduction order differs) with
+  - kernel ≡ blockwise reference to float ulps (identical accumulation
+    order — the reference takes the kernel's own block size — and
+    identical math; only the order inside one matmul's sum differs);
+  - kernel ≡ the `paged_attention` gather oracle to float ulps with
     argmax equality — the sampling-visible quantity;
   - the dispatch (`paged_attention_auto`) routes kernel-on-TPU /
     gather-elsewhere, with "interpret" forcing the kernel through the
@@ -18,7 +19,13 @@ contract the flash kernels use (ops/flash_attention.py):
 
 Geometry matrix: index values 1 / page−1 / page / 3·page+7 — the same
 page-boundary edges the paged gather tests pin — at decode (S=1) and
-chunk (S=page-multiple) query shapes.
+chunk (S=page-multiple) query shapes; then what a streaming kernel can
+get wrong: lengths around a BLOCK boundary, a full row beside an idle
+one, page ids in any order, a page two rows share, chunks that start
+inside a block, and NaN in every page no row should read (the
+interpreter hands out NaN-filled scratch, so a stale buffer shows).
+f32 and bf16 pools (bf16 packs two heads' rows to a word), one and
+several head groups, head counts that tile and that do not.
 """
 
 import numpy as np
@@ -111,6 +118,153 @@ def test_kernel_mixed_row_lengths_and_idle_rows():
         pa.paged_flash_decode(q, pk, pv, tbl, idx, interpret=True))
     oracle = np.asarray(pa.paged_attention(q, pk, pv, tbl, idx))
     np.testing.assert_allclose(kern, oracle, rtol=1e-6, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# what a kernel that streams blocks of live pages can get wrong
+# ---------------------------------------------------------------------------
+
+def _geometry(seed, lens, s, *, heads=H, page=PAGE, m=M, pool=None,
+              dtype=jnp.float32):
+    """Rows of the given cached lengths (``index``), each with its own
+    pages in shuffled order: (q, pool_k, pool_v, table, index)."""
+    rng = np.random.default_rng(seed)
+    b = len(lens)
+    pool = pool or 1 + b * m
+    q = jnp.asarray(rng.standard_normal((b, s, heads, D)), dtype)
+    pk = jnp.asarray(rng.standard_normal((pool, page, heads, D)), dtype)
+    pv = jnp.asarray(rng.standard_normal((pool, page, heads, D)), dtype)
+    tbl = rng.permutation(np.arange(1, 1 + b * m)).reshape(b, m)
+    return (q, pk, pv, jnp.asarray(tbl, jnp.int32),
+            jnp.asarray(lens, jnp.int32))
+
+
+def _pages_per_block(monkeypatch, args, ppb):
+    """Make the plan take ``ppb`` pages a block for these shapes: the
+    budget is the plan's one knob, so the test turns that."""
+    q, pk = args[0], args[1]
+    (_, s, h, d), page, itemsize = q.shape, pk.shape[1], pk.dtype.itemsize
+    h = pa._tiled_heads(h, itemsize)
+    monkeypatch.setattr(pa, "_VMEM_BUDGET",
+                        2 * 4 * ppb * page * h * d * itemsize)
+    plan = pa._plan(s, h, d, page, args[3].shape[1], itemsize)
+    assert plan[0] == ppb, plan
+    return plan
+
+
+def _check(args, rtol=1e-6, atol=1e-6):
+    """Kernel against the gather oracle AND the blockwise reference."""
+    kern = np.asarray(pa.paged_flash_decode(*args, interpret=True),
+                      np.float32)
+    oracle = np.asarray(pa.paged_attention(*args), np.float32)
+    ref = np.asarray(pa.paged_flash_decode_reference(*args), np.float32)
+    assert np.isfinite(kern).all()
+    np.testing.assert_allclose(kern, oracle, rtol=rtol, atol=atol)
+    np.testing.assert_allclose(kern, ref, rtol=rtol, atol=atol)
+    return kern
+
+
+@pytest.mark.parametrize("ppb", [2, 4])
+@pytest.mark.parametrize("edge", [-1, 0, 1])
+def test_kernel_lengths_around_a_block_boundary(monkeypatch, ppb, edge):
+    """Keys one under, on and one over a block of ``ppb`` pages: the
+    trip count steps from 1 to 2 exactly there, and the first page of
+    the second block holds one live key."""
+    keys = ppb * PAGE + edge
+    args = _geometry(50 + edge, [keys - 1, 2 * ppb * PAGE - 1 + edge], 1,
+                     m=16)
+    _pages_per_block(monkeypatch, args, ppb)
+    _check(args)
+
+
+def test_kernel_full_row_beside_an_idle_row(monkeypatch):
+    """A 2,048-token row (all 128 pages of 16, 16 blocks of 8) beside
+    an idle row (all-zeros table, index 0: position 0 of the scratch
+    page, as the engine passes it)."""
+    args = list(_geometry(3, [2047, 0], 1, page=16, m=128))
+    args[3] = args[3].at[1].set(0)
+    _pages_per_block(monkeypatch, args, 8)
+    _check(args)
+
+
+@pytest.mark.parametrize("order", ["descending", "strided"])
+def test_kernel_page_ids_in_any_order(monkeypatch, order):
+    """Page ids descending, and every third page of a pool three times
+    the rows' need: the kernel reads ids from the table, never
+    neighbours in the pool."""
+    lens = [45, 20, 33]
+    args = list(_geometry(8, lens, 1, pool=1 + 9 * M))
+    ids = np.arange(1, 1 + 3 * M)
+    ids = ids[::-1] if order == "descending" else 3 * ids - 1
+    args[3] = jnp.asarray(ids.reshape(3, M), jnp.int32)
+    _pages_per_block(monkeypatch, args, 2)
+    _check(args)
+
+
+def test_kernel_page_shared_by_two_rows(monkeypatch):
+    """Prefix sharing: rows 0 and 1 hold the same two first pages and
+    differ after; both read them, at different lengths."""
+    args = list(_geometry(21, [40, 19, 30], 1))
+    args[3] = args[3].at[1, :2].set(args[3][0, :2])
+    _pages_per_block(monkeypatch, args, 2)
+    kern = _check(args)
+    assert not np.allclose(kern[0], kern[1])
+
+
+@pytest.mark.parametrize("pages,start_page", [(2, 1), (2, 3), (2, 7),
+                                              (4, 1), (4, 2), (4, 6)])
+def test_kernel_chunk_starting_inside_a_block(monkeypatch, pages, start_page):
+    """Continuation chunks of 2 and 4 pages whose start is no multiple
+    of the 4-page block (one is, for contrast), causal inside the
+    chunk.  The small budget also splits the 8 heads into groups: the
+    grid's second axis, each group streaming the pages again."""
+    s = pages * PAGE
+    args = _geometry(pages * 10 + start_page, [start_page * PAGE] * 2, s,
+                     heads=8, m=16)
+    _, heads_per_group = _pages_per_block(monkeypatch, args, 4)
+    assert heads_per_group < 8
+    _check(args)
+
+
+@pytest.mark.parametrize("s", [1, 4 * PAGE])
+def test_kernel_ignores_nan_in_pages_past_a_rows_length(monkeypatch, s):
+    """Every pool page no row may read — the scratch page, the table's
+    entries past each row's length, the unused rest of the pool — is
+    NaN.  The output is finite and bit-equal to the clean pool's: a
+    loop one block too long, a dead page copied, or a stale half of the
+    double buffer would each bring a NaN to a matmul (0 x NaN = NaN)."""
+    lens = [1, 2 * PAGE - 1, 4 * PAGE, 7 * PAGE + 3]
+    args = list(_geometry(77, lens, s, heads=8, m=16, pool=80))
+    _pages_per_block(monkeypatch, args, 2)
+    clean = _check(args)
+    tbl = np.asarray(args[3])
+    live = {int(p) for row, n in zip(tbl, lens)
+            for p in row[:-(-(n + s) // PAGE)]}
+    dead = np.array(sorted(set(range(80)) - live))
+    assert 0 in dead and len(dead) > 80 - 4 * 16
+    args[1] = args[1].at[dead].set(jnp.nan)
+    args[2] = args[2].at[dead].set(jnp.nan)
+    poisoned = np.asarray(pa.paged_flash_decode(*args, interpret=True))
+    np.testing.assert_array_equal(poisoned, clean)
+
+
+@pytest.mark.parametrize("heads,s", [(4, 1), (4, 64), (6, 1), (3, 1),
+                                     (6, 32)])
+def test_kernel_bf16_pools_and_untiled_head_counts(monkeypatch, heads, s):
+    """bf16 pools pack two heads' rows to a 32-bit word (the strided
+    head load splits them by shift and mask); 6 and 3 heads do not fill
+    a tile and are padded with zero heads.  bf16 resolution: one ulp of
+    an O(1) output."""
+    args = _geometry(heads + s, [PAGE + 5, 0, 4 * PAGE - 1], s, heads=heads,
+                     m=16, dtype=jnp.bfloat16)
+    _pages_per_block(monkeypatch, args, 2)
+    _check(args, rtol=2 ** -7, atol=2 ** -7)
+
+
+@pytest.mark.parametrize("heads", [6, 3])
+def test_kernel_untiled_head_counts_f32(heads):
+    """f32 pools, 6 and 3 heads (8 and 4 after padding), decode."""
+    _check(_geometry(heads, [1, PAGE, 3 * PAGE + 7], 1, heads=heads))
 
 
 def test_auto_dispatch_routes_by_flag(monkeypatch):

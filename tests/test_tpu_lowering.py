@@ -6,15 +6,22 @@ Pallas→Mosaic lowering rules without a TPU: block shapes the TPU
 lowering refuses (a squeezed dim second-to-last, a block that does not
 tile (8, 128)) fail HERE instead of at the first decode step on the
 chip.  Interpret-mode tests cannot see this class of error: the
-interpreter accepts any block shape.
+interpreter accepts any block shape.  What only Mosaic's own compiler
+refuses (a DMA of 6 bf16 heads "not aligned to tiling (8)") shows one
+step later: the ``v5e`` fixture describes a chip that is not attached
+and the paged kernel is compiled for it.
 
 Shapes are ``transformer_tpu``'s (6 heads × 128, bf16; 3 heads under
-``--serve_tp 2``), the default ``kv_page_size`` 16 over a one-slot-deep
-pool, and the sequence lengths on either side of the fused/split flash
+``--serve_tp 2``) over a one-slot-deep pool, and the benchmark's
+Cerebras-GPT-1.3B (16 heads × 128; 8 under ``--serve_tp 2``; 48 slots,
+pool 1,921 pages, chunks of 128 and 256), the default ``kv_page_size``
+16, and the sequence lengths on either side of the fused/split flash
 backward switch.
 """
 
+import functools
 import importlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -36,16 +43,78 @@ def _lower_for_tpu(fn, *shapes):
     return text
 
 
+BENCH_POOL = 1921                # Cerebras-GPT-1.3B cells: 30,720 tokens + scratch
+
+
+def _paged_shapes(heads, batch, s, pool):
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    return (((batch, s, heads, D), bf16), ((pool, PAGE, heads, D), bf16),
+            ((pool, PAGE, heads, D), bf16), ((batch, M), i32), ((batch,), i32))
+
+
 @pytest.mark.parametrize("heads", [6, 3])
 @pytest.mark.parametrize("batch,s", [(8, 1), (1, 16), (1, 64)])
 def test_paged_decode_kernel_lowers_for_tpu(heads, batch, s):
     """Decode (S = 1, every slot) and continuation prefill chunks
     (S = 16 … 64, one slot), full and TP-local head counts."""
-    bf16, i32 = jnp.bfloat16, jnp.int32
-    _lower_for_tpu(
-        pa.paged_flash_decode,
-        ((batch, s, heads, D), bf16), ((POOL, PAGE, heads, D), bf16),
-        ((POOL, PAGE, heads, D), bf16), ((batch, M), i32), ((batch,), i32))
+    _lower_for_tpu(pa.paged_flash_decode,
+                   *_paged_shapes(heads, batch, s, POOL))
+
+
+@pytest.mark.parametrize("heads", [16, 8])
+@pytest.mark.parametrize("batch,s", [(48, 1), (1, 128), (1, 256)])
+def test_paged_attention_reads_the_pool_as_stored(heads, batch, s):
+    """The benchmark's decode body and both continuation chunks, full
+    and TP-local heads, through ``paged_attention_auto``: the kernel
+    lowers, and nothing but the kernel touches a pool-shaped value — no
+    transpose, copy, pad or gather of 126 MB a layer a call."""
+    text = _lower_for_tpu(
+        functools.partial(pa.paged_attention_auto, use_pallas=True),
+        *_paged_shapes(heads, batch, s, BENCH_POOL))
+    # what a line that names a pool-shaped value may be: a function's
+    # signature, the call into the jitted kernel wrapper, the kernel
+    allowed = ("func.func ", "call @paged_flash_decode",
+               "stablehlo.custom_call @tpu_custom_call")
+    pool_lines = [line.strip() for line in text.splitlines()
+                  if f"tensor<{BENCH_POOL}x" in line]
+    assert any(allowed[2] in line for line in pool_lines)
+    assert [line for line in pool_lines
+            if not any(a in line for a in allowed)] == []
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One described (not attached) v5e chip to compile for."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("heads,batch,s,pool", [
+    (16, 48, 1, BENCH_POOL), (16, 1, 256, BENCH_POOL), (8, 1, 128, BENCH_POOL),
+    (6, 8, 1, POOL), (3, 1, 64, POOL)])
+def test_paged_decode_kernel_compiles_for_v5e(v5e, heads, batch, s, pool):
+    """Mosaic's compiler accepts the kernel: page DMAs in the stored
+    layout, the flat reading of a block, strided head loads, VMEM within
+    the scoped limit.  At head counts that tile (16, 8) the compiled
+    program holds no copy of a pool; at 6 and 3 the pools are padded
+    (the one documented copy) and the kernel still compiles."""
+    args = [jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+            for shape, dt in _paged_shapes(heads, batch, s, pool)]
+    text = jax.jit(pa.paged_flash_decode).lower(*args).compile().as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text
+    # the instructions whose result has a pool's shape
+    makers = set(re.findall(
+        rf"= bf16\[{pool},{PAGE},\d+,{D}\]\S* ([\w-]+)\(", text))
+    if heads in (6, 3):
+        assert "pad" in makers, makers
+    else:
+        assert makers <= {"parameter"}, makers
 
 
 @pytest.mark.parametrize("heads", [6, 3])
