@@ -5,11 +5,14 @@ every stamp taken on the client's side.
 
 Set-up: weights on the device from the seed in one jitted call; one
 warm-up request per prompt length of the mix (so every prefill body and
-the decode body are compiled or loaded); the agreement sample held to the
-plain reference; then the load runs for ``ramp_s`` before the window
-opens.  The load keeps running after the window until every request that
-fell due inside it has finished (at most ``drain_s``), so the tail sees
-the same system as the head.
+the decode body are compiled or loaded), with token ids from a stream of
+their own, so that no request of the load finds a warm-up prompt in the
+engine's prefix registry; the agreement sample — the tokens served and
+the logits the engine's own bodies give when they are replayed — held
+to the plain reference the configuration names; then the load runs for
+``ramp_s`` before the window opens.  The load keeps running after the
+window until every request that fell due inside it has finished (at most
+``drain_s``), so the tail sees the same system as the head.
 """
 
 from __future__ import annotations
@@ -18,11 +21,14 @@ import os
 import queue
 import threading
 import time
+import types
 
 import numpy as np
 
+from benchmark import families
 from benchmark.lib import stats, traffic
-from benchmark.lib.runtime import RunContext, TracedWindow, memory_peak_bytes
+from benchmark.lib.runtime import (RunContext, Ticker, TracedWindow,
+                                   memory_peak_bytes)
 
 
 class _Sent:
@@ -115,29 +121,31 @@ QUANTILES = (50, 90, 95, 99)
 
 
 def _histogram_read(engine, names):
-    """count and percentiles of what each histogram observed since its
-    reset, through its public API (same interpolation as lib/stats.py)."""
+    """count, mean and percentiles of what each histogram observed since
+    its reset, through its public API (same interpolation as
+    lib/stats.py)."""
     out = {}
     for n in names:
         h = engine.metrics.get(n)
-        out[n] = {"count": h.count,
+        out[n] = {"count": h.count, "mean": h.mean,
                   "q": {q: h.percentile(q) for q in QUANTILES}}
     return out
 
 
-HISTOGRAMS = ("serve_decode_step_s", "serve_queue_wait_s")
+# serve_decode_live_pages: a sample per decode step, the pages its rows'
+# contexts occupy
+HISTOGRAMS = ("serve_decode_step_s", "serve_queue_wait_s",
+              "serve_decode_live_pages")
 
 
-def setup(ctx: RunContext):
-    """Weights, engine, warm-up of every body the mix needs, agreement
-    with the plain reference.  Returns (engine, mix, vocab, agreement)."""
+def model_and_sample(ctx: RunContext):
+    """The cell's model with weights from the seed, its settings, and one
+    warm-up prompt per prompt length of the mix; ``sample`` is the places
+    of the prompts the agreement check reads."""
     import jax
     import jax.numpy as jnp
 
     from dtf_tpu.models import build_model
-    from dtf_tpu.serve.engine import ServeEngine
-
-    from benchmark.lib import reference_gpt2
 
     cell, wl, mix = ctx.cell, ctx.cell.workload, dict(ctx.cell.traffic)
     toy = ctx.toy or {}
@@ -154,34 +162,105 @@ def setup(ctx: RunContext):
     params = jax.jit(model.clone(use_pallas=False).init)(
         jax.random.key(ctx.key_seed),
         jnp.zeros((1, engine_kw["kv_page_size"]), jnp.int32))["params"]
-    engine = ServeEngine(model, params, seed=ctx.key_seed, **engine_kw)
-
-    # one request per prompt length: every prefill body and the decode body
     agree = dict(wl["agreement"], **toy.get("agreement", {}))
-    rng = np.random.default_rng(ctx.seed)
+    # one prompt per prompt length of the mix, token ids from a stream that
+    # is not the load's
+    rng = np.random.default_rng([ctx.seed, 1])
     lengths = mix["prompt_len"].get("snap_to") or [mix["prompt_len"]["max"]]
     prompts = [rng.integers(0, vocab, size=int(n), dtype=np.int32)
                for n in lengths]
-    handles = [engine.submit(p, max_new_tokens=int(agree["new_tokens"]))
-               for p in prompts]
+    sample = [i for i, n in enumerate(lengths)
+              if int(n) in agree["prompt_lens"]]
+    return types.SimpleNamespace(
+        model=model, params=params, vocab=vocab, mix=mix, engine_kw=engine_kw,
+        agree=agree, prompts=prompts, sample=sample)
+
+
+def replay_logits(engine, prompts, served) -> list:
+    """The logits the engine's own compiled bodies give where they chose
+    each served token, a [tokens, vocab] array a prompt: every prompt
+    prefilled again in the engine's chunk plan, then all decoded in
+    lockstep with the served tokens fed back.  The bodies return these
+    logits beside the token; the engine drops them and ``ServeResult``
+    carries none, so the idle engine runs this on its own thread, cache
+    and pages (``run_on_engine``), and gets the pages back after."""
+    from dtf_tpu.serve.engine import chunk_plan
+    dec, page = engine.decoder, engine.page_size
+    n, slots, budget = len(prompts), engine.max_batch, len(served[0])
+    assert n <= slots and all(len(t) == budget for t in served)
+
+    def job():
+        tables = np.zeros((slots, dec.pages_per_slot), np.int32)
+        held = []
+        for r, (p, t) in enumerate(zip(prompts, served)):
+            pages = engine.pool.alloc(-(-(len(p) + len(t)) // page))
+            if pages is None:
+                raise RuntimeError("no free pages for the replay")
+            held.extend(pages)
+            tables[r, :len(pages)] = pages
+        cache = engine._cache
+        out = [np.zeros((budget, dec.model.vocab_size), np.float32)
+               for _ in served]
+        for r, p in enumerate(prompts):
+            for start, clen in chunk_plan(len(p), engine.prefill_chunk,
+                                          page):
+                chunk = np.zeros((clen,), np.int32)
+                real = p[start:start + clen]
+                chunk[:len(real)] = real
+                _, cache, last = dec.prefill_chunk(
+                    cache, chunk, tables[r], start, len(real) - 1, 0.0,
+                    seed=0)
+            out[r][0] = np.asarray(last, np.float32)
+        index = np.zeros((slots,), np.int32)
+        index[:n] = [len(p) for p in prompts]
+        for j in range(1, budget):
+            tokens = np.zeros((slots,), np.int32)
+            tokens[:n] = [t[j - 1] for t in served]
+            _, cache, step = dec.decode_step(
+                cache, tokens, index, np.zeros((slots,), np.float32),
+                seeds=np.zeros((slots,), np.uint32), block_tables=tables)
+            step = np.asarray(step[:n], np.float32)
+            for r in range(n):
+                out[r][j] = step[r]
+            index[:n] += 1
+        engine._cache = cache
+        engine.pool.free(held)
+        return out
+    return engine.run_on_engine(job, timeout=600)
+
+
+def setup(ctx: RunContext):
+    """Weights, engine, warm-up of every body the mix needs, agreement
+    with the plain reference.  Returns (engine, mix, vocab, agreement)."""
+    from dtf_tpu.serve.engine import ServeEngine
+
+    cell, m = ctx.cell, model_and_sample(ctx)
+    engine = ServeEngine(m.model, m.params, seed=ctx.key_seed, **m.engine_kw)
+
+    # one request per prompt length: every prefill body and the decode body
+    handles = [engine.submit(p, max_new_tokens=int(m.agree["new_tokens"]))
+               for p in m.prompts]
     served = [list(h.result(timeout=1100).tokens) for h in handles]
     if engine.error is not None:
         raise RuntimeError("engine thread died in warm-up") from engine.error
     t_warm = time.monotonic()
-    sample = [i for i, n in enumerate(lengths)
-              if int(n) in agree["prompt_lens"]]
-    agreement = reference_gpt2.served_tokens_agree(
-        params, [prompts[i] for i in sample], [served[i] for i in sample],
-        float(agree["logit_rtol"]))
+    reference = families.load_reference(cell.config, cell.root)
+    sample = [m.prompts[i] for i in m.sample]
+    sample_served = [served[i] for i in m.sample]
+    agreement = reference.served_tokens_agree(
+        m.params, sample, sample_served, float(m.agree["logit_rtol"]),
+        replay_logits(engine, sample, sample_served),
+        float(m.agree["logit_rms_limit"]))
     ctx.note(phase="warm",
              prefill_bodies=traffic.prefill_bodies(
-                 mix, engine_kw["prefill_chunk"], engine_kw["kv_page_size"]),
+                 m.mix, m.engine_kw["prefill_chunk"],
+                 m.engine_kw["kv_page_size"]),
              compiled_bodies=engine.decoder.compiled_count,
              compiles_total=ctx.compiles.total,
              compile_cache_hits=ctx.compiles.hits,
              warm_s=t_warm - ctx.t_process,
              agreement_s=time.monotonic() - t_warm, agreement=agreement)
-    return engine, mix, vocab, agreement
+    return engine, m.mix, m.vocab, agreement
 
 
 def measure(ctx: RunContext, engine, mix: dict, vocab: int, seconds: float,
@@ -197,10 +276,12 @@ def measure(ctx: RunContext, engine, mix: dict, vocab: int, seconds: float,
     engine.reset_measurement()
     _reset_histograms(engine, HISTOGRAMS)
     t_open, wall_open = time.monotonic(), time.time()
+    ticker = Ticker()
     time.sleep(seconds / 2)
     outstanding_mid = engine.outstanding
     time.sleep(max(0.0, t_open + seconds - time.monotonic()))
     t_close, wall_close = time.monotonic(), time.time()
+    ticker_late_max_s = ticker.close()
     histograms = _histogram_read(engine, HISTOGRAMS)
     decode_steps = histograms["serve_decode_step_s"]["count"]
     if traced is not None:
@@ -250,16 +331,23 @@ def measure(ctx: RunContext, engine, mix: dict, vocab: int, seconds: float,
         reasons.append(f"{short} finished requests did not return their "
                        f"full token budget")
     compiles_in_window = ctx.compiles.between(t_open, t_close)
+    compiled_in_window = ctx.compiles.names_between(t_open, t_close)
     if compiles_in_window:
         reasons.append(f"{compiles_in_window} compilations inside the "
-                       f"window")
+                       f"window: {compiled_in_window}")
     if load.exhausted:
         reasons.append("the prepared requests ran out before the run "
                        "ended; raise prepare_per_s in the traffic file")
+    # what the clients saw, for the readers of the cells whose tails are
+    # per-layer metrics (BENCHMARK.json says in which cells they are judged)
+    client = {"ttft_ms": {q: 1e3 * stats.percentile(ttfts, q)
+                          for q in (50, 90, 99)} if ttfts else None,
+              "gap_ms": {q: 1e3 * stats.percentile(gaps, q)
+                         for q in (50, 95, 99)} if gaps else None}
     end_to_end = {"serve_tok_s": tokens_in_window / window_s}
     if mix["arrivals"] != "closed" and ttfts and gaps:
-        end_to_end["ttft_p90_ms"] = 1e3 * stats.percentile(ttfts, 90)
-        end_to_end["gap_p95_ms"] = 1e3 * stats.percentile(gaps, 95)
+        end_to_end["ttft_p90_ms"] = client["ttft_ms"][90]
+        end_to_end["gap_p95_ms"] = client["gap_ms"][95]
     note = dict(
         window_s=window_s, setup_s=t_open - ctx.t_process,
         rate_per_s=mix.get("rate_per_s"), requests_in_window=len(mine),
@@ -267,17 +355,18 @@ def measure(ctx: RunContext, engine, mix: dict, vocab: int, seconds: float,
         shed=shed, unserved=unserved, unfinished_at_halt=unfinished,
         short=short,
         tokens_in_window=tokens_in_window, gaps=len(gaps),
-        ttft_ms={q: 1e3 * stats.percentile(ttfts, q)
-                 for q in (50, 90, 99)} if ttfts else None,
-        gap_ms={q: 1e3 * stats.percentile(gaps, q)
-                for q in (50, 95, 99)} if gaps else None,
+        ttft_ms=client["ttft_ms"], gap_ms=client["gap_ms"],
         generator_late_ms_max=1e3 * max(lateness) if lateness else None,
+        gap_max_ms=1e3 * max(gaps) if gaps else None,
+        ticker_late_max_ms=1e3 * ticker_late_max_s,
         decode_steps=decode_steps,
         decode_step_ms_median=1e3 * histograms["serve_decode_step_s"]["q"][50],
         pool_pages_high_water=pool_high_water,
         outstanding_mid=outstanding_mid, outstanding_close=outstanding_close,
         max_concurrent=engine.max_concurrent,
         compiles_in_window=compiles_in_window,
+        compiled_in_window=compiled_in_window,
+        live_pages_per_step=histograms["serve_decode_live_pages"]["mean"],
         offered_per_s=len(mine) / window_s, requests_total=len(sent),
         serve_tok_s=end_to_end["serve_tok_s"])
     ctx.note(phase="serve_window", **note)
@@ -290,7 +379,7 @@ def measure(ctx: RunContext, engine, mix: dict, vocab: int, seconds: float,
             "setup_s": t_open - ctx.t_process, "end_to_end": end_to_end,
             "note": note,
             "readers": {"decode_steps": decode_steps, "window_s": window_s,
-                        "histograms": histograms,
+                        "histograms": histograms, "client": client,
                         "window_wall": (wall_open, wall_close)}}
 
 

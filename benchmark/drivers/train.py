@@ -18,7 +18,7 @@ import math
 import os
 import time
 
-from benchmark.lib import costs, peaks
+from benchmark.lib import peaks
 from benchmark.lib.runtime import RunContext, TracedWindow, memory_peak_bytes
 
 
@@ -151,10 +151,11 @@ def run(ctx: RunContext) -> dict:
         reasons.append(f"the loss did not fall below its first value: "
                        f"first {losses[:1]}, window {in_window[-3:]}")
     if compiles_in_window:
-        reasons.append(f"{compiles_in_window} compilations inside the "
-                       f"window")
+        reasons.append(
+            f"{compiles_in_window} compilations inside the window: "
+            f"{ctx.compiles.names_between(clock.t_open, clock.t_close)}")
     device_kind = jax.devices()[0].device_kind
-    flops = costs.SAMPLE_FLOPS[cell.config["family"]](cell.config, traffic)
+    flops = cell.family.train_flops_per_sample(cell.config, traffic)
     step_walls = [(t1 - t0) / (s1 - s0) for (t0, s0), (t1, s1)
                   in zip(clock.boundaries, clock.boundaries[1:])]
     ctx.note(phase="train_window", steps=steps, window_s=window_s,

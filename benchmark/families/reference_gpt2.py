@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from benchmark.lib.agreement import tokens_agree
+
 
 def _layer_norm(x, p, eps=1e-6):
     mean = x.mean(-1, keepdims=True)
@@ -57,35 +59,7 @@ def forward(params, tokens):
         return x @ head["kernel"] + head["bias"]
 
 
-def served_tokens_agree(params, prompts, served, rtol: float) -> dict:
-    """Teacher-force each (prompt, served tokens) pair through the
-    reference and hold every served token to it: the token served is the
-    reference's choice, or tied with it within ``2 * rtol`` of the logit
-    scale (the system computes in bf16; ``rtol`` is what that may move a
-    logit by, as ``chip_smoke.py`` sets it).  A wrong page, mask or
-    position moves logits by their whole spread, far outside a tie.
-
-    Sequences are padded to one length and run as one batch: one program.
-    """
-    n = len(prompts)
-    total = max(len(p) + len(t) for p, t in zip(prompts, served))
-    batch = np.zeros((n, total), np.int32)
-    for r, (p, t) in enumerate(zip(prompts, served)):
-        batch[r, :len(p)] = p
-        batch[r, len(p):len(p) + len(t)] = t
-    logits = np.asarray(jax.jit(forward)(params, jnp.asarray(batch)))
-    scale = float(np.abs(logits).max())
-    worst, compared, identical = 0.0, 0, 0
-    for r, (p, t) in enumerate(zip(prompts, served)):
-        # the logits at position len(p)-1+j chose served token j
-        rows = logits[r, len(p) - 1:len(p) - 1 + len(t)]
-        chosen = rows[np.arange(len(t)), np.asarray(t)]
-        gap = rows.max(-1) - chosen
-        worst = max(worst, float(gap.max()))
-        compared += len(t)
-        identical += int((gap == 0).sum())
-    finite = bool(np.isfinite(logits).all())
-    return {"ok": finite and worst <= 2 * rtol * scale,
-            "tokens_compared": compared, "greedy_identical": identical,
-            "worst_gap": worst, "logit_scale": scale,
-            "allowed_gap": 2 * rtol * scale}
+def served_tokens_agree(params, prompts, served, rtol: float,
+                        program_logits=None, logit_rms_limit=None) -> dict:
+    return tokens_agree(forward, params, prompts, served, rtol,
+                        program_logits, logit_rms_limit)

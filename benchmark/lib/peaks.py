@@ -26,3 +26,11 @@ def peaks_for(device_kind: str) -> dict:
             f"with its source to benchmark/lib/peaks.py (have "
             f"{sorted(PEAKS)})")
     return PEAKS[device_kind]
+
+
+def least_seconds(device_kind: str, flops: float, nbytes: float) -> float:
+    """The least time the chip could take for this work: the larger of
+    FLOPs over peak FLOP/s and bytes over peak bytes/s (the roofline)."""
+    peak = peaks_for(device_kind)
+    return max(flops / peak["bf16_flops_per_s"],
+               nbytes / peak["hbm_bytes_per_s"])

@@ -6,10 +6,13 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import logging
 import os
 import threading
 import time
 from typing import Optional
+
+from benchmark import families
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -38,6 +41,8 @@ class Cell:
     traffic: dict       # benchmark/traffic/<traffic>.json
     workload: dict      # benchmark/workloads/<cell>.json
     per_layer: list     # names of the per-layer metrics the cell reports
+    family: object      # benchmark/families/<config's family>.py
+    root: str           # the checkout the files came from
 
 
 def load_cell(benchmark: dict, name: str, root: str = ROOT) -> Cell:
@@ -54,15 +59,20 @@ def load_cell(benchmark: dict, name: str, root: str = ROOT) -> Cell:
                if c["name"] == entry["config"])
     per_layer = [m["name"] for m in benchmark["per_layer"]
                  if "workloads" not in m or name in m["workloads"]]
+    config = load_json(os.path.join(root, cfg["file"]))
+    workload = load_json(os.path.join(bench_dir, "workloads",
+                                      name + ".json"))
+    family = families.load(config["family"], root)
+    if config.get("reference") is None and workload["driver"] == "serve":
+        raise ValueError(
+            f"cell {name!r} serves {cfg['file']}, which names no plain "
+            f"reference to hold the served tokens to")
     return Cell(
         name=name, chips=entry["chips"], config_name=cfg["name"],
-        config=load_json(os.path.join(root, cfg["file"])),
-        traffic_name=entry["traffic"],
+        config=config, traffic_name=entry["traffic"],
         traffic=load_json(os.path.join(bench_dir, "traffic",
                                        entry["traffic"] + ".json")),
-        workload=load_json(os.path.join(bench_dir, "workloads",
-                                        name + ".json")),
-        per_layer=per_layer)
+        workload=workload, per_layer=per_layer, family=family, root=root)
 
 
 @dataclasses.dataclass
@@ -91,16 +101,25 @@ class RunContext:
 class CompileWatch:
     """Stamps every request to compile a program (a hit of the persistent
     cache included: a hit still means a shape that was not warmed up), so
-    that a driver can count those that fell inside its window."""
+    that a driver can count those that fell inside its window, and keeps
+    each program's name from JAX's own "Compiling <name> ..." log line,
+    so that a run that is not ``correct`` for it says which."""
 
     EVENT = "/jax/compilation_cache/compile_requests_use_cache"
+    LOGGER = "jax._src.interpreters.pxla"
 
     def __init__(self):
         import jax
         self._lock = threading.Lock()
         self._stamps = []
+        self._named = []        # (monotonic time, program name)
         self.hits = 0
         jax.monitoring.register_event_listener(self._on_event)
+        # JAX logs the line at DEBUG unless jax_log_compiles is on; this
+        # filter lets that one line through to nobody but this object
+        logger = logging.getLogger(self.LOGGER)
+        logger.setLevel(logging.DEBUG)
+        logger.addFilter(self._on_log)
 
     def _on_event(self, event, **_):
         if event == self.EVENT:
@@ -110,6 +129,12 @@ class CompileWatch:
             with self._lock:
                 self.hits += 1
 
+    def _on_log(self, record) -> bool:
+        if str(record.msg).startswith("Compiling %s"):
+            with self._lock:
+                self._named.append((time.monotonic(), str(record.args[0])))
+        return record.levelno > logging.DEBUG
+
     @property
     def total(self) -> int:
         with self._lock:
@@ -118,6 +143,38 @@ class CompileWatch:
     def between(self, t0: float, t1: float) -> int:
         with self._lock:
             return sum(t0 <= t <= t1 for t in self._stamps)
+
+    def names_between(self, t0: float, t1: float) -> list:
+        with self._lock:
+            return [n for t, n in self._named if t0 <= t <= t1]
+
+
+class Ticker(threading.Thread):
+    """A thread that asks to sleep ``every_s`` again and again and keeps
+    the longest it overslept.  A window whose engine stood still says here
+    whether the whole process did (the host took its cores: this thread was
+    late too) or only the engine's thread (a call into the runtime or the
+    device: this thread was on time).  ``/proc``'s schedstat and steal time
+    read 0 on the chip's machine (my chip run, PR 26), so they cannot."""
+
+    def __init__(self, every_s: float = 0.05):
+        super().__init__(daemon=True, name="bench-ticker")
+        self.every_s = every_s
+        self.late_max_s = 0.0
+        self._halt = threading.Event()
+        self.start()
+
+    def run(self):
+        while not self._halt.is_set():
+            t = time.monotonic()
+            self._halt.wait(self.every_s)
+            self.late_max_s = max(self.late_max_s,
+                                  time.monotonic() - t - self.every_s)
+
+    def close(self) -> float:
+        self._halt.set()
+        self.join(timeout=5)
+        return self.late_max_s
 
 
 def require_tpu(chips: int) -> dict:

@@ -55,23 +55,37 @@ def request_sizes(mix: dict, n: int, rng) -> np.ndarray:
     return np.stack([prompts, outputs], axis=1)
 
 
+def _prepared_blocks(mix: dict, length_s: float) -> List[int]:
+    """How many requests a closed-loop phase of this length prepares, as
+    the sizes of the blocks they are drawn in.  ``prepare_per_s`` bounds
+    how many requests the phase can use up.  Where the file names a
+    ``prepare_block_per_s``, the requests are drawn block by block at that
+    rate from the phase's one sequential stream: raising ``prepare_per_s``
+    then appends blocks and leaves every earlier request as it was (the
+    first block is what the file prepared when its rate was the block's)."""
+    n = int(np.ceil(float(mix["prepare_per_s"]) * length_s))
+    block = int(np.ceil(float(mix.get("prepare_block_per_s",
+                                      mix["prepare_per_s"])) * length_s))
+    return [min(block, n - done) for done in range(0, n, block)]
+
+
 def phase_draw(mix: dict, phase: int, length_s: float):
     """(sizes [n, 2], gaps [n]) of one phase of the load (ramp, window,
     tail), from the mix's base seed and the phase's number alone — never
     from the run's seed.  Open loop: exponential gaps at ``rate_per_s``
     (Poisson arrivals) until the phase is full, so every seed offers the
-    same number of requests in the phase.  Closed loop: no schedule;
-    ``prepare_per_s`` bounds how many requests the phase can use up."""
+    same number of requests in the phase.  Closed loop: no schedule; the
+    sizes of ``_prepared_blocks``, block after block."""
     rng = np.random.default_rng([int(mix["base_seed"]), phase])
     if mix["arrivals"] == "closed":
-        n = int(np.ceil(float(mix["prepare_per_s"]) * length_s))
-        gaps = np.zeros((n,), np.float64)
-    elif mix["arrivals"] == "poisson":
-        draw = rng.exponential(1.0 / float(mix["rate_per_s"]),
-                               size=int(4 * mix["rate_per_s"] * length_s) + 16)
-        gaps = draw[:int(np.searchsorted(np.cumsum(draw), length_s))]
-    else:
+        sizes = np.concatenate([request_sizes(mix, n, rng)
+                                for n in _prepared_blocks(mix, length_s)])
+        return sizes, np.zeros((len(sizes),), np.float64)
+    if mix["arrivals"] != "poisson":
         raise ValueError(f"unknown arrivals {mix['arrivals']!r}")
+    draw = rng.exponential(1.0 / float(mix["rate_per_s"]),
+                           size=int(4 * mix["rate_per_s"] * length_s) + 16)
+    gaps = draw[:int(np.searchsorted(np.cumsum(draw), length_s))]
     return request_sizes(mix, len(gaps), rng), gaps
 
 
@@ -79,18 +93,25 @@ def make_requests(mix: dict, seed: int, phases_s: List[float],
                   vocab_size: int) -> List[Request]:
     """The requests of a run whose load has phases of these lengths (ramp,
     window, tail): the mix's schedule, with token ids from the run's
-    seed."""
+    seed, in the order the load thread sends them.  Open loop: by due
+    time.  Closed loop: the clients take the list in order, whatever the
+    clock says, so every phase's first block comes before any phase's
+    further blocks — a system no faster than ``prepare_block_per_s``
+    meets the requests it met before the further blocks were added."""
     rng = np.random.default_rng(int(seed))
-    out, t0 = [], 0.0
+    first, further, t0 = [], [], 0.0
     for k, length_s in enumerate(phases_s):
         sizes, gaps = phase_draw(mix, k, float(length_s))
-        due = t0 + np.cumsum(gaps)
-        for (plen, budget), t in zip(sizes, due):
-            prompt = rng.integers(0, vocab_size, size=int(plen),
-                                  dtype=np.int32)
-            out.append(Request(float(t), prompt, int(budget)))
+        rows = [(float(t), int(plen), int(budget))
+                for (plen, budget), t in zip(sizes, t0 + np.cumsum(gaps))]
+        cut = (_prepared_blocks(mix, float(length_s))[0]
+               if mix["arrivals"] == "closed" else len(rows))
+        first += rows[:cut]
+        further += rows[cut:]
         t0 += float(length_s)
-    return out
+    return [Request(t, rng.integers(0, vocab_size, size=plen,
+                                    dtype=np.int32), budget)
+            for t, plen, budget in first + further]
 
 
 def prefill_bodies(mix: dict, prefill_chunk: int, page_size: int) -> list:

@@ -3,11 +3,11 @@
 stat ``per_step_s``: seconds per step (``per`` names the driver's count to
 divide by: "steps" or "decode_steps").
 stat ``roofline``: the least time the chip could take for one step's calls
-(``cost`` names a function of benchmark/lib/costs.py STEP_COSTS giving
-FLOPs and bytes; the larger of FLOPs/peak and bytes/peak) over the time
+(``cost`` names a function of the family's STEP_COSTS giving FLOPs and
+bytes; the larger of FLOPs/peak and bytes/peak) over the time
 they took (0..1)."""
 
-from benchmark.lib import costs, peaks
+from benchmark.lib import peaks
 
 
 def read(args, run):
@@ -21,10 +21,8 @@ def read(args, run):
     if args["stat"] == "per_step_s":
         return total / count
     if args["stat"] == "roofline":
-        flops, nbytes = costs.STEP_COSTS[args["cost"]](
+        flops, nbytes = run.cell.family.STEP_COSTS[args["cost"]](
             run.cell.config, run.cell.traffic, run.cell.chips)
-        peak = peaks.peaks_for(run.device_kind)
-        least = max(flops / peak["bf16_flops_per_s"],
-                    nbytes / peak["hbm_bytes_per_s"])
-        return least * count / total
+        return peaks.least_seconds(run.device_kind, flops,
+                                   nbytes) * count / total
     raise ValueError(f"trace_kernel: unknown stat {args['stat']!r}")

@@ -3,10 +3,11 @@
     python3 -m benchmark.rehearse --workload <cell> [--trace 1] [--seconds 3]
 
 It finds wrong paths, arguments and control flow before a chip call is
-spent on them.  Nothing it prints is a result: the platform is "cpu", the
-values carry the suffix ``.toy``, the last line starts with ``REHEARSAL``
-and ``lib/contract.py`` refuses it by design.  No number from here is ever
-written under a device metric's name.
+spent on them.  The toy sizes are the family's own (``TOY`` in
+``benchmark/families/<family>.py``).  Nothing it prints is a result: the
+platform is "cpu", the values carry the suffix ``.toy``, the last line
+starts with ``REHEARSAL`` and ``lib/contract.py`` refuses it by design.
+No number from here is ever written under a device metric's name.
 """
 
 from __future__ import annotations
@@ -18,31 +19,6 @@ import shutil
 import sys
 import time
 
-TOY = {
-    "train": {
-        "gpt2": {"model_kwargs": {"num_layers": 2, "d_model": 128,
-                                  "num_heads": 1, "d_ff": 256,
-                                  "max_seq_len": 128},
-                 "batch_size": 8, "seq_len": 128, "log_steps": 2},
-        "resnet50": {"batch_size": 4, "log_steps": 1},
-    },
-    "serve": {
-        "gpt2": {"model_kwargs": {"num_layers": 2, "d_model": 128,
-                                  "num_heads": 1, "d_ff": 256,
-                                  "max_seq_len": 256},
-                 "vocab_size": 512,
-                 "engine": {"max_batch": 4, "max_seq_len": 256,
-                            "kv_pool_pages": 65, "prefill_chunk": 64},
-                 "traffic": {"ramp_s": 1, "drain_s": 10, "rate_per_s": 3.0,
-                             "clients": 4, "prepare_per_s": 200.0,
-                             "prompt_len": {"median": 48, "sigma": 0.5,
-                                            "min": 16, "max": 160,
-                                            "snap_to": [16, 48, 96, 160]},
-                             "output_len": {"median": 6, "sigma": 0.4,
-                                            "min": 3, "max": 12}}},
-    },
-}
-
 
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
@@ -50,13 +26,16 @@ def main(argv=None) -> int:
     p.add_argument("--seed", type=int, default=2_400_000_011)
     p.add_argument("--seconds", type=float, default=3.0)
     p.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    p.add_argument("--root", default=None,
+                   help="another checkout's BENCHMARK.json and data files")
     args = p.parse_args(argv if argv is not None else sys.argv[1:])
     t_process = time.monotonic()
 
-    from benchmark.lib.runtime import (BENCH_DIR, CompileWatch, RunContext,
-                                       load_benchmark, load_cell)
-    benchmark = load_benchmark()
-    cell = load_cell(benchmark, args.workload)
+    from benchmark.lib.runtime import (BENCH_DIR, ROOT, CompileWatch,
+                                       RunContext, load_benchmark, load_cell)
+    root = args.root or ROOT
+    benchmark = load_benchmark(root)
+    cell = load_cell(benchmark, args.workload, root=root)
     # before jax is imported: the CPU, with as many virtual devices as the
     # cell has chips
     os.environ["JAX_PLATFORMS"] = "cpu"
@@ -69,10 +48,8 @@ def main(argv=None) -> int:
     import jax
 
     from benchmark.lib import contract
-    toy = dict(TOY[cell.workload["driver"]][cell.config["family"]])
-    toy["distribution_strategy"] = "mirrored"
-    if cell.workload["driver"] == "serve":
-        toy["agreement"] = {"prompt_lens": [16, 48, 96, 160]}
+    toy = dict(cell.family.TOY[cell.workload["driver"]],
+               distribution_strategy="mirrored")
     out_dir = os.path.join(BENCH_DIR, "out", "rehearsal", cell.name)
     shutil.rmtree(out_dir, ignore_errors=True)
     os.makedirs(out_dir)

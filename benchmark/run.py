@@ -65,6 +65,16 @@ def per_layer_metrics(benchmark, cell, device_kind, result, out_dir):
     return metrics, device, breakdown
 
 
+def end_to_end_metrics(benchmark, cell_name, values):
+    """The driver hands over every end-to-end number it takes; the line
+    carries those BENCHMARK.json judges in this cell (one the driver did
+    not take is missing from the line, and the contract check says so)."""
+    from benchmark.lib import contract
+    return {n: {"value": values[n], "unit": unit} for n, unit in
+            contract.declared_metrics(benchmark, cell_name, False).items()
+            if n in values}
+
+
 def main(argv=None) -> int:
     args = parse_args(argv if argv is not None else sys.argv[1:])
     from benchmark.lib import contract
@@ -94,7 +104,6 @@ def main(argv=None) -> int:
     result = driver.run(ctx)
     ctx.note(phase="memory", device0=memory_stats_of_device0())
 
-    units = {m["name"]: m["unit"] for m in benchmark["end_to_end"]}
     device["memory_peak_bytes"] = result["memory_peak_bytes"]
     line = {"correct": result["correct"], "attempted": result["attempted"],
             "failed": result["failed"]}
@@ -104,10 +113,9 @@ def main(argv=None) -> int:
         device.update(extra)
         line.update(metrics=metrics, device=device, breakdown=breakdown)
     else:
-        values = dict(result["end_to_end"], setup_s=result["setup_s"])
-        line.update(
-            metrics={n: {"value": v, "unit": units.get(n, "undeclared")}
-                     for n, v in values.items()},
+        line.update(metrics=end_to_end_metrics(
+            benchmark, cell.name,
+            dict(result["end_to_end"], setup_s=result["setup_s"])),
             device=device)
     if result["reasons"]:
         print(f"benchmark: not correct: {result['reasons']}",

@@ -1,5 +1,11 @@
 """The knee of an open-loop cell, found once: one process, one engine, one
-warm-up, then the cell's mix offered at each rate in turn.
+warm-up, then the cell's mix offered at each rate in turn, each rate with
+token ids from a seed of its own (``--seed`` + its place in the list).
+With one seed for all, a rate's prompts are the last rate's over again:
+the engine's prefix registry hits, the shared pages move a prompt's chunk
+plan off the grid the warm-up compiled, and chunk bodies
+(``jit(_chunk_impl)``) that random prompts never reach are compiled inside
+the window (PERF.md section 6, PR 26).
 
     python3 -m benchmark.sweep --workload <cell> --rates 2,4,6,8,10 --seconds 30 --seed 7
 
@@ -19,6 +25,7 @@ import time
 _T_PROCESS = time.monotonic()
 
 import argparse  # noqa: E402
+import dataclasses  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
@@ -46,9 +53,10 @@ def main(argv=None) -> int:
                      compiles=CompileWatch())
     engine, mix, vocab, _ = serve.setup(ctx)
     knee = None
-    for rate in (float(r) for r in args.rates.split(",")):
-        result = serve.measure(ctx, engine, dict(mix, rate_per_s=rate),
-                               vocab, args.seconds)
+    for i, rate in enumerate(float(r) for r in args.rates.split(",")):
+        result = serve.measure(dataclasses.replace(ctx, seed=args.seed + i),
+                               engine, dict(mix, rate_per_s=rate), vocab,
+                               args.seconds)
         note = result["note"]
         kept_up = (note["tokens_in_window"]
                    >= 0.97 * note["tokens_offered_in_window"]

@@ -8,7 +8,9 @@ import math
 import os
 import re
 import shutil
+import subprocess
 import sys
+import time
 
 import numpy as np
 import pytest
@@ -18,12 +20,16 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmark.lib import contract, costs, peaks, stats, traffic, xplane  # noqa: E402
+from benchmark import families  # noqa: E402
+from benchmark.lib import (agreement, contract, costs, peaks, stats,  # noqa: E402
+                           traffic, xplane)
 from benchmark.lib.runtime import load_benchmark, load_cell, load_json  # noqa: E402
 from benchmark.readers import ReaderInput, read_metric  # noqa: E402
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 BENCH = load_benchmark()
+GPT2 = families.load("gpt2", ROOT)
+RESNET50 = families.load("resnet50", ROOT)
 CELLS = [w["name"] for w in BENCH["workloads"]]
 END_TO_END = {m["name"] for m in BENCH["end_to_end"]}
 
@@ -110,9 +116,13 @@ def test_a_trace_without_the_device_line_is_an_error_not_a_zero(trace):
 
 # ---------------------------------------------------------- contract.py --
 def good_line(cell, traced):
+    return good_line_of(BENCH, cell, traced)
+
+
+def good_line_of(bench, cell, traced):
     metrics = {n: {"value": 12.5, "unit": u} for n, u in
-               contract.declared_metrics(BENCH, cell, traced).items()}
-    chips = next(w["chips"] for w in BENCH["workloads"] if w["name"] == cell)
+               contract.declared_metrics(bench, cell, traced).items()}
+    chips = next(w["chips"] for w in bench["workloads"] if w["name"] == cell)
     device = {"platform": "tpu", "kind": "TPU v5 lite", "count": chips,
               "memory_peak_bytes": 13958643712}
     line = {"correct": True, "attempted": 400, "failed": 0,
@@ -194,9 +204,9 @@ REFUSED = {
 @pytest.mark.parametrize("case", sorted(REFUSED))
 def test_contract_refuses(case):
     edit, traced = REFUSED[case]
-    line = good_line("gpt13b-serve-steady", traced)
+    line = good_line("gpt13b-serve-loaded", traced)
     edit(line)
-    assert contract.check_line(line, BENCH, "gpt13b-serve-steady", traced)
+    assert contract.check_line(line, BENCH, "gpt13b-serve-loaded", traced)
 
 
 def test_contract_refuses_what_is_not_an_object():
@@ -249,14 +259,51 @@ def test_same_seed_same_requests_other_seed_same_schedule(name):
                                                    rel=0.4)
 
 
-def test_prefill_bodies_follow_the_engines_chunk_plan():
+@pytest.mark.parametrize("name", REQUEST_MIXES)
+def test_prefill_bodies_follow_the_engines_chunk_plan(name):
     from dtf_tpu.serve.engine import chunk_plan
-    mix = _mix(REQUEST_MIXES[0])
+    mix = _mix(name)
     want = set()
     for plen in mix["prompt_len"]["snap_to"]:
         for start, clen in chunk_plan(plen, 256, 16):
             want.add((clen, start == 0))
     assert traffic.prefill_bodies(mix, 256, 16) == sorted(want)
+
+
+# chat-closed-48.json as it was before PR 26 raised prepare_per_s to 64:
+# what the batch cell's ledger lines were measured on
+CLOSED_48_AT_16 = dict(_mix("chat-closed-48"), prepare_per_s=16.0)
+CLOSED_48_AT_16.pop("prepare_block_per_s")
+
+
+@pytest.mark.parametrize("length_s", [20.0, 51.0, 15.0, 7.3])
+def test_closed_mix_keeps_the_block_it_had_at_16_a_second(length_s):
+    mix = _mix("chat-closed-48")
+    assert mix["prepare_per_s"] == 64.0
+    assert mix["prepare_block_per_s"] == CLOSED_48_AT_16["prepare_per_s"]
+    n_old = math.ceil(16.0 * length_s)
+    for phase in range(3):
+        old, _ = traffic.phase_draw(CLOSED_48_AT_16, phase, length_s)
+        new, gaps = traffic.phase_draw(mix, phase, length_s)
+        assert len(old) == n_old and len(new) == math.ceil(64.0 * length_s)
+        assert len(gaps) == len(new)
+        assert np.array_equal(new[:n_old], old)     # size for size
+        assert not np.array_equal(new[n_old:2 * n_old][:len(old)], old)
+
+
+def test_closed_clients_meet_the_old_requests_first_then_the_further():
+    """The clients take the list in order: a system at the old file's speed
+    is sent the requests the old file sent it, token for token."""
+    phases = [20.0, 51.0, 15.0]
+    old = traffic.make_requests(CLOSED_48_AT_16, 2_400_000_011, phases, 50257)
+    new = traffic.make_requests(_mix("chat-closed-48"), 2_400_000_011,
+                                phases, 50257)
+    assert len(old) == 320 + 816 + 240 and len(new) == 4 * len(old)
+    assert _key(new[:len(old)]) == _key(old)
+    sizes = lambda rs: sorted((len(r.prompt), r.max_new_tokens) for r in rs)
+    drawn = np.concatenate([traffic.phase_draw(_mix("chat-closed-48"), k, s)[0]
+                            for k, s in enumerate(phases)])
+    assert sizes(new) == sorted(map(tuple, drawn.tolist()))
 
 
 # ------------------------------------------------- the files, by name ----
@@ -314,19 +361,21 @@ def test_benchmark_json_keeps_to_the_contracts_shapes():
 # ------------------------------------------------------------- costs.py --
 def test_gpt_flops_per_token_match_the_hand_count():
     cfg = load_cell(BENCH, "gpt13b-train-zero-x4")
-    per_token = costs.gpt_train_flops_per_sample(
+    assert cfg.family is GPT2
+    per_token = GPT2.train_flops_per_sample(
         cfg.config, cfg.traffic) / cfg.traffic["seq_len"]
     # 6 x 1.311e9 matmul parameters + 3 x 24 layers x 2 x 2048 x 2048
     assert per_token == pytest.approx(8.5e9, rel=0.05)
-    assert costs.gpt_matmul_params(cfg.config) == 24 * (
+    assert GPT2.gpt_matmul_params(cfg.config) == 24 * (
         4 * 2048 ** 2 + 2 * 2048 * 8192) + 2048 * 50257
 
 
 def test_resnet50_flops_per_image_match_the_hand_count():
     cfg = load_cell(BENCH, "resnet50-train")
-    fwd = costs.resnet50_forward_flops_per_image(cfg.config)
+    assert cfg.family is RESNET50
+    fwd = RESNET50.resnet50_forward_flops_per_image(cfg.config)
     assert fwd == pytest.approx(2 * 4.09e9, rel=0.03)   # 4.1 GMACs, v1.5
-    assert costs.resnet50_train_flops_per_sample(
+    assert RESNET50.train_flops_per_sample(
         cfg.config, cfg.traffic) == pytest.approx(2.4e10, rel=0.05)
 
 
@@ -339,7 +388,7 @@ def test_flash_costs_and_the_roofline_reader():
     pf, pb = costs.paged_decode(10_000, 16, 128)
     assert pb == 2 * 10_000 * 16 * 128 * 2 and pf == 2 * pb / 2
     cell = load_cell(BENCH, "gpt13b-train-zero-x4")
-    flops, _ = costs.flash_train_step(cell.config, cell.traffic, 4)
+    flops, _ = GPT2.STEP_COSTS["flash_train_step"](cell.config, cell.traffic, 4)
     assert flops == 24 * 3.5 * f
     # a kernel that took exactly its compute-bound least time reads 100 %
     least = flops / peaks.peaks_for("TPU v5 lite")["bf16_flops_per_s"]
@@ -364,39 +413,381 @@ def test_percentile_is_numpys_linear_one(q):
     assert stats.percentile(data, q) == pytest.approx(np.percentile(data, q))
 
 
-# ----------------------------- a later PR adds files and entries only ----
-def test_readme_examples_load_through_the_harness(tmp_path):
-    """The README's worked examples — a configuration, a traffic mix, a
-    cell and a per-layer metric — written as new files into a copy of the
-    benchmark and found by name, with no file of the harness edited."""
-    readme = open(os.path.join(ROOT, "benchmark", "README.md")).read()
-    blocks = re.findall(r"`([\w./\-]+\.json)`[^\n]*\n+```json\n(.*?)```",
-                        readme, re.S)
-    files = {path: json.loads(body) for path, body in blocks}
-    assert {"BENCHMARK.json"} < set(files) and len(files) >= 5
+# ------------------------------------- paged_decode_roofline's reader ----
+def _paged_roofline_run(cell_name, kernel_ns, histogram):
+    cell = load_cell(BENCH, cell_name)
+    trace = _trace([["fusion.9", 0, 4e6],
+                    ["paged_flash_decode.7", 5e6, kernel_ns]])
+    return ReaderInput(cell=cell, device_kind="TPU v5 lite",
+                       reduction=xplane.reduce_trace(trace),
+                       driver={"decode_steps": 10,
+                               "histograms": {"serve_decode_live_pages":
+                                              histogram}})
+
+
+@pytest.mark.parametrize("cell_name", next(
+    m["workloads"] for m in BENCH["per_layer"]
+    if m["name"] == "paged_decode_roofline"))
+def test_paged_decode_roofline_reads_100_at_the_byte_floor(cell_name):
+    spec = load_json(os.path.join(ROOT, "benchmark", "layer_metrics",
+                                  "paged_decode_roofline.json"))
+    # 10 decode steps of 1,283 live pages each; a page of 16 tokens is
+    # 16 x 196,608 bytes of K and V over the 24 layers
+    nbytes = 10 * 1283 * 16 * 196_608
+    floor_ns = 1e9 * nbytes / 819e9
+    hist = {"count": 10, "mean": 1283.0, "q": {}}
+    at_floor = _paged_roofline_run(cell_name, floor_ns, hist)
+    assert read_metric(spec, at_floor) == pytest.approx(100.0)
+    twice = _paged_roofline_run(cell_name, 2 * floor_ns, hist)
+    assert read_metric(spec, twice) == pytest.approx(50.0)
+    # the count is the family's: bytes bound, 2 FLOPs a byte
+    flops, counted = GPT2.COUNTED_COSTS["paged_decode_context"](
+        at_floor.cell.config, 10 * 1283 * 16)
+    assert counted == nbytes and flops == nbytes
+    assert flops / 197e12 < counted / 819e9
+
+
+@pytest.mark.parametrize("histogram", [
+    None, {"count": 0, "mean": 0.0, "q": {}}], ids=["absent", "no_samples"])
+def test_paged_decode_roofline_reads_nothing_without_samples(histogram):
+    spec = load_json(os.path.join(ROOT, "benchmark", "layer_metrics",
+                                  "paged_decode_roofline.json"))
+    run = _paged_roofline_run("gpt13b-serve-batch", 1e6, histogram)
+    if histogram is None:
+        run.driver["histograms"].clear()
+    assert read_metric(spec, run) is None
+
+
+# ---------------------- tails recorded per layer where they are not judged --
+@pytest.mark.parametrize("metric,series,q", [
+    ("ttft_p90_ms.longprompt", "ttft_ms", 90),
+    ("gap_p95_ms.longprompt", "gap_ms", 95)])
+def test_client_reader_reads_what_the_clients_saw(metric, series, q):
+    spec = load_json(os.path.join(ROOT, "benchmark", "layer_metrics",
+                                  metric + ".json"))
+    cell = load_cell(BENCH, "gpt13b-serve-longprompt")
+    client = {"ttft_ms": {50: 230.0, 90: 356.0, 99: 700.0},
+              "gap_ms": {50: 18.0, 95: 36.7, 99: 41.0}}
+
+    def run_with(c):
+        return ReaderInput(cell=cell, device_kind="TPU v5 lite",
+                           reduction=None, driver={"client": c})
+    assert read_metric(spec, run_with(client)) == client[series][q]
+    # a window in which no request got a token: nothing to read
+    assert read_metric(spec, run_with(dict(client, **{series: None}))) is None
+    # the tail is judged end to end in the loaded cell alone, and every
+    # per-layer metric of this cell moves a metric the cell's line carries
+    e2e = contract.declared_metrics(BENCH, cell.name, False)
+    assert metric.split(".")[0] not in e2e
+    assert metric.split(".")[0] in contract.declared_metrics(
+        BENCH, "gpt13b-serve-loaded", False)
+
+
+@pytest.mark.parametrize("cell,judged", [
+    ("gpt13b-serve-loaded",
+     {"serve_tok_s", "ttft_p90_ms", "gap_p95_ms", "setup_s"}),
+    ("gpt13b-serve-longprompt", {"serve_tok_s", "setup_s"}),
+    ("gpt13b-serve-batch", {"serve_tok_s", "setup_s"})])
+def test_the_line_carries_the_numbers_judged_in_its_cell(cell, judged):
+    from benchmark.run import end_to_end_metrics
+    taken = {"serve_tok_s": 48.5, "ttft_p90_ms": 356.0, "gap_p95_ms": 36.7,
+             "setup_s": 70.0}
+    metrics = end_to_end_metrics(BENCH, cell, taken)
+    assert set(metrics) == judged
+    assert metrics["serve_tok_s"] == {"value": 48.5, "unit": "tokens/s"}
+    line = dict(good_line(cell, False), metrics=metrics)
+    assert contract.check_line(line, BENCH, cell, False) == []
+    # a number the driver did not take is missing, which the contract refuses
+    taken.pop("serve_tok_s")
+    line["metrics"] = end_to_end_metrics(BENCH, cell, taken)
+    assert any("serve_tok_s" in f for f in
+               contract.check_line(line, BENCH, cell, False))
+
+
+# ------------------------------------------------ a family is its files --
+HARNESS_DIRS = ("", "drivers", "readers", "lib")
+
+
+def _harness_files():
+    bench = os.path.join(ROOT, "benchmark")
+    for sub in HARNESS_DIRS:
+        for name in sorted(os.listdir(os.path.join(bench, sub))):
+            if name.endswith(".py"):
+                yield os.path.join(bench, sub, name)
+
+
+def test_no_harness_file_names_a_family_or_imports_a_reference():
+    """run.py, rehearse.py, sweep.py, sets.py, drivers/, readers/ and lib/
+    find a family by the name in the configuration and nothing else; only a
+    family's own files import a ``reference_*`` module."""
+    family_names = [f[:-3] for f in os.listdir(os.path.join(
+        ROOT, "benchmark", "families"))
+        if f.endswith(".py") and not f.startswith(("_", "reference_"))]
+    assert {"gpt2", "resnet50"} <= set(family_names)
+    banned = re.compile(
+        r"reference_\w+|SAMPLE_FLOPS|\b(TOY|STEP_COSTS)\s*=|(?<!\.)\b(TOY|STEP_COSTS)\[|"
+        + "|".join(rf"[\"']{re.escape(n)}[\"']" for n in family_names))
+    hits = []
+    for path in _harness_files():
+        for n, line in enumerate(open(path), 1):
+            if banned.search(line.split("#")[0]):
+                hits.append(f"{os.path.relpath(path, ROOT)}:{n}: "
+                            f"{line.strip()}")
+    assert not hits, "\n".join(hits)
+    for root, _, names in os.walk(os.path.join(ROOT, "benchmark")):
+        for name in names:
+            if not name.endswith(".py") or root.endswith("families"):
+                continue
+            text = open(os.path.join(root, name)).read()
+            assert not re.search(r"^\s*(from|import)\s.*reference_", text,
+                                 re.M), name
+
+
+def _copy_of_the_data(tmp_path, bench, files=()):
+    """A copy of the benchmark's data files (no harness code) with
+    ``files`` written in as new ones and ``bench`` as its BENCHMARK.json."""
     root = str(tmp_path)
-    for sub in ("configs", "traffic", "workloads", "layer_metrics"):
+    for sub in ("configs", "traffic", "workloads", "layer_metrics",
+                "families"):
         shutil.copytree(os.path.join(ROOT, "benchmark", sub),
-                        os.path.join(root, "benchmark", sub))
-    bench = copy.deepcopy(BENCH)
-    for key, entries in files.pop("BENCHMARK.json").items():
-        bench[key].extend(entries)
-    for path, body in files.items():
+                        os.path.join(root, "benchmark", sub),
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    for path, body in files:
         assert not os.path.exists(os.path.join(root, path)), path
         with open(os.path.join(root, path), "w") as f:
-            json.dump(body, f)
-    new_cell = bench["workloads"][-1]["name"]
-    cell = load_cell(bench, new_cell, root=root)
-    assert cell.config_name == bench["configs"][-1]["name"]
-    new_metric = bench["per_layer"][-1]
+            f.write(body)
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f)
+    return root
+
+
+def test_a_family_without_a_reference_cannot_be_served(tmp_path):
+    bench = copy.deepcopy(BENCH)
+    bench["workloads"].append({"name": "resnet50-serve", "chips": 1,
+                               "config": "resnet50-v1.5",
+                               "traffic": "chat-poisson-loaded", "why": "x"})
+    served = open(os.path.join(ROOT, "benchmark", "workloads",
+                               "gpt13b-serve-loaded.json")).read()
+    root = _copy_of_the_data(
+        tmp_path, bench, [("benchmark/workloads/resnet50-serve.json", served)])
+    with pytest.raises(ValueError, match="names no plain reference"):
+        load_cell(bench, "resnet50-serve", root=root)
+    assert load_cell(bench, "resnet50-train",
+                     root=root).config["reference"] is None
+
+
+REFERENCE_FILES = {
+    "whole": "def forward(params, tokens):\n    return tokens\n\n\n"
+             "def served_tokens_agree(*a):\n    return {'ok': True}\n",
+    "no_comparison": "def forward(params, tokens):\n    return tokens\n",
+    "missing": None,
+}
+
+
+@pytest.mark.parametrize("case", sorted(REFERENCE_FILES))
+def test_the_reference_is_the_file_the_configuration_names(tmp_path, case):
+    """One source of the path: the configuration's ``reference``.  A file
+    that is not there, or lacks ``forward`` or ``served_tokens_agree``, is
+    an error when the driver loads it."""
+    body = REFERENCE_FILES[case]
+    named = "benchmark/families/reference_other.py"
+    root = _copy_of_the_data(tmp_path, BENCH,
+                             [(named, body)] if body else [])
+    path = os.path.join(root, "benchmark", "configs", "cerebras-gpt-1.3b.json")
+    config = dict(load_json(path), reference=named)
+    with open(path, "w") as f:
+        json.dump(config, f)
+    cell = load_cell(BENCH, "gpt13b-serve-batch", root=root)
+    assert cell.config["reference"] == named
+    if case == "whole":
+        module = families.load_reference(cell.config, root)
+        assert module.__file__ == os.path.join(root, named)
+        assert module.served_tokens_agree() == {"ok": True}
+    else:
+        with pytest.raises(FileNotFoundError if body is None
+                           else AttributeError):
+            families.load_reference(cell.config, root)
+
+
+def test_a_family_file_without_the_interface_is_refused(tmp_path):
+    root = _copy_of_the_data(tmp_path, BENCH, [
+        ("benchmark/families/halfdone.py", "TOY = {}\n"),
+        ("benchmark/families/costless.py",
+         "TOY = {}\n\n\ndef train_flops_per_sample(config, traffic):\n"
+         "    return 1.0\n")])
+    with pytest.raises(AttributeError, match="train_flops_per_sample"):
+        families.load("halfdone", root)
+    with pytest.raises(FileNotFoundError):
+        families.load("never_written", root)
+    # cost functions are named by layer_metrics files: none named, none owed
+    costless = families.load("costless", root)
+    assert costless.STEP_COSTS == {} and costless.COUNTED_COSTS == {}
+
+
+# ----------------------------- a later PR adds files and entries only ----
+def _readme_example():
+    """(files, BENCHMARK.json entries) of benchmark/README.md's worked
+    example: every block that follows a path in backquotes."""
+    readme = open(os.path.join(ROOT, "benchmark", "README.md")).read()
+    blocks = re.findall(
+        r"`([\w./\-]+\.(?:json|py))`[^\n]*\n+```(?:json|python)\n(.*?)```",
+        readme, re.S)
+    files = dict(blocks)
+    assert len(files) == len(blocks)
+    entries = json.loads(files.pop("BENCHMARK.json"))
+    return sorted(files.items()), entries
+
+
+def _bench_with(entries):
+    bench = copy.deepcopy(BENCH)
+    for key, more in entries.items():
+        bench[key].extend(more)
+    new_cells = [w["name"] for w in entries["workloads"]]
+    for m in bench["end_to_end"]:       # "a new cell adds its name there"
+        if "workloads" in m:
+            m["workloads"] = m["workloads"] + new_cells
+    return bench
+
+
+def test_readme_examples_load_through_the_harness(tmp_path):
+    """The README's worked examples — a family with its reference, a
+    configuration, two traffic mixes, two cells and a per-layer metric —
+    written as new files into a copy of the benchmark's data and found by
+    name, with no file of the harness edited."""
+    files, entries = _readme_example()
+    assert len(files) >= 8
+    assert {os.path.dirname(p) for p, _ in files} == {
+        "benchmark/" + d for d in ("families", "configs", "traffic",
+                                   "workloads", "layer_metrics")}
+    bench = _bench_with(entries)
+    root = _copy_of_the_data(tmp_path, bench, files)
+    new_family = load_json(os.path.join(
+        root, entries["configs"][0]["file"]))["family"]
+    assert not os.path.exists(os.path.join(
+        ROOT, "benchmark", "families", new_family + ".py"))
+    for entry in entries["workloads"]:
+        cell = load_cell(bench, entry["name"], root=root)
+        assert cell.config_name == entries["configs"][0]["name"]
+        assert cell.family.__file__.startswith(root)
+        assert cell.family.TOY[cell.workload["driver"]]
+        flops = cell.family.train_flops_per_sample(
+            cell.config, {"seq_len": 1024})
+        assert flops == pytest.approx(6 * 5.87e8 * 1024, rel=0.01)
+    assert callable(families.load_reference(cell.config, root).forward)
+    new_metric = entries["per_layer"][-1]
     assert new_metric["name"] in cell.per_layer
     spec = load_json(os.path.join(root, "benchmark", "layer_metrics",
                                   new_metric["name"] + ".json"))
-    trace = _trace([["fusion.9", 0, 4e6], ["flash_fwd.1", 5e6, 2e6]])
+    trace = _trace([["fusion.9", 0, 4e6], ["convert_element_type.1", 5e6, 2e6]])
     run = ReaderInput(cell=cell, device_kind="TPU v5 lite",
                       reduction=xplane.reduce_trace(trace),
                       driver={"steps": 2, "decode_steps": 2})
     assert read_metric(spec, run) == pytest.approx(1.0)     # ms per step
     assert new_metric["name"] in contract.declared_metrics(
-        bench, new_cell, True)
-    assert math.isfinite(costs.gpt_forward_flops_per_token(cell.config, 2048))
+        bench, cell.name, True)
+    assert contract.check_line(good_line_of(bench, cell.name, False), bench,
+                               cell.name, False) == []
+
+
+@pytest.mark.parametrize("driver", ["serve", "train"])
+def test_a_new_family_rehearses_through_the_driver(tmp_path, driver):
+    """The README's made-up family, rehearsed at its own toy size on the
+    CPU through the harness as it stands: new files in the copy only."""
+    files, entries = _readme_example()
+    bench = _bench_with(entries)
+    root = _copy_of_the_data(tmp_path, bench, files)
+    name = next(w["name"] for w in entries["workloads"]
+                if load_json(os.path.join(root, "benchmark", "workloads",
+                                          w["name"] + ".json"))["driver"]
+                == driver)
+    done = subprocess.run(
+        [sys.executable, "-m", "benchmark.rehearse", "--workload", name,
+         "--root", root, "--seconds", "2"],
+        cwd=ROOT, capture_output=True, text=True, timeout=110,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert done.returncode == 0, done.stdout[-2000:] + done.stderr[-2000:]
+    last = done.stdout.strip().splitlines()[-1]
+    assert last.startswith("REHEARSAL")
+    said = json.loads(last[last.index("{"):])
+    assert said["line"]["correct"] is True and said["reasons"] == []
+    assert said["line"]["device"]["platform"] == "cpu"
+    assert said["contract_refuses_it_for"]      # never a result
+
+
+# ------------------------------------- the served-token check can fail ----
+def _toy_forward(params, tokens):
+    """logits[b, s] = params[tokens[b, s]]: a 'model' the check can be shown
+    on without a model."""
+    return params[tokens]
+
+
+def _toy_case(noise, seed=0):
+    import jax.numpy as jnp
+    rng = np.random.default_rng(seed)
+    table = rng.normal(size=(16, 64)).astype(np.float32)
+    prompts = [rng.integers(0, 16, size=n) for n in (5, 9)]
+    served = agreement.greedy_tokens(_toy_forward, jnp.asarray(table),
+                                     prompts, 4)
+    rows = agreement.rows_that_chose(_toy_forward, jnp.asarray(table),
+                                     prompts, served)
+    program = [r + noise * table.std() * rng.normal(size=r.shape)
+               for r in rows]
+    return jnp.asarray(table), prompts, served, program
+
+
+@pytest.mark.parametrize("noise,ok", [(0.0, True), (0.01, True),
+                                      (0.015, True), (0.026, False),
+                                      (0.05, False), (float("nan"), False)])
+def test_logit_rms_reads_the_noise_put_in(noise, ok):
+    """The number that tells precisions apart: the program's logits against
+    the reference's, root mean square over the reference's spread."""
+    table, prompts, served, program = _toy_case(noise)
+    said = agreement.tokens_agree(_toy_forward, table, prompts, served, 0.01,
+                                  program, 0.02)
+    assert said["ok"] is ok and said["tokens_compared"] == 8
+    assert said["worst_gap"] == 0.0 and said["greedy_identical"] == 8
+    if math.isfinite(noise):
+        assert said["logit_rms"] == pytest.approx(noise, rel=0.2, abs=1e-9)
+
+
+def test_a_served_token_outside_a_tie_is_refused_whatever_the_logits():
+    table, prompts, served, program = _toy_case(0.0)
+    rows = agreement.rows_that_chose(_toy_forward, table, prompts, served)
+    served[1][2] = int(np.argsort(rows[1][2])[-2])      # the runner-up
+    said = agreement.tokens_agree(_toy_forward, table, prompts, served, 0.01)
+    assert not said["ok"] and said["worst_gap"] > said["allowed_gap"]
+    assert "logit_rms" not in said
+
+
+def test_the_ticker_keeps_the_longest_it_overslept():
+    from benchmark.lib.runtime import Ticker
+    ticker = Ticker(every_s=0.01)
+    time.sleep(0.1)
+    late = ticker.close()
+    assert 0.0 <= late < 0.1 and not ticker.is_alive()
+
+
+def test_the_control_at_eight_bit_weights_is_refused_on_three_seeds(capsys):
+    """The control at the family's toy size, through the engine on the CPU:
+    the program (bf16 compute) passes on six seeds; the reference with its
+    weights rounded to 8 bits, put in the program's place, is refused on
+    every control seed by ``logit_rms`` — and by nothing else: its tokens
+    are the reference's or tied —, and at 4 bits by both numbers.  (The
+    cell's own size: PERF.md section 2.)"""
+    from benchmark import control
+    assert control.main(["--workload", "gpt13b-serve-batch", "--seeds",
+                         "11,12,13,14,15,16", "--control-seeds", "11,12,13",
+                         "--toy"]) == 0
+    lines = [json.loads(l) for l in capsys.readouterr().out.splitlines()
+             if l.startswith('{"control"')]
+    by = {w: [l for l in lines if l["who"] == w]
+          for w in ("program", "w8", "w4")}
+    assert [len(by[w]) for w in ("program", "w8", "w4")] == [6, 3, 3]
+    assert all(l["ok"] for l in by["program"])
+    limit = GPT2.TOY["serve"]["agreement"]["logit_rms_limit"]
+    assert all(l["logit_rms_limit"] == limit for l in lines)
+    assert all(not l["ok"] and l["logit_rms"] > 1.25 * limit
+               and l["gap"] < l["gap_limit"] for l in by["w8"])
+    assert all(not l["ok"] and l["logit_rms"] > 10 * limit
+               and l["gap"] > 2 * l["gap_limit"] for l in by["w4"])
+    assert max(l["logit_rms"] for l in by["program"]) < 0.8 * limit
