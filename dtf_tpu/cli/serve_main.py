@@ -64,10 +64,10 @@ def build_serving_engine(cfg, random_init: bool = False,
                                serving_memory_plan, serving_mesh)
     from dtf_tpu.serve.bridge import place_for_serving
 
-    if not cfg.model.startswith("transformer"):
+    if not cfg.model.startswith(("transformer", "routed_decoder")):
         raise ValueError(
-            f"serving is implemented for the plain transformer LM family, "
-            f"not {cfg.model!r}")
+            f"serving is implemented for the plain transformer LM family "
+            f"and the routed decoder, not {cfg.model!r}")
     model, _ = build_model(cfg.model, num_classes=cfg.num_classes,
                            dtype=cfg.compute_dtype)
     max_seq = cfg.serve_max_seq_len or model.max_seq_len
@@ -95,7 +95,8 @@ def build_serving_engine(cfg, random_init: bool = False,
                         max_seq_len=max_seq,
                         kv_page_size=cfg.kv_page_size,
                         kv_pool_pages=cfg.kv_pool_pages,
-                        model_parallelism=cfg.serve_tp)
+                        model_parallelism=cfg.serve_tp,
+                        params=variables["params"])
     engine = ServeEngine(
         model, variables["params"],
         max_batch=cfg.serve_max_batch, max_seq_len=max_seq,

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import functools
 
 from dtf_tpu.models import (moe, pipeline_lm, resnet, resnet_cifar,
-                            transformer, trivial)
+                            routed_decoder, transformer, trivial)
 
 # reference weight-decay constants
 L2_IMAGENET = 1e-4  # resnet_model.py:37
@@ -60,6 +60,9 @@ _REGISTRY = {
         functools.partial(moe.MoETransformerLM, num_layers=4, d_model=256,
                           num_heads=4, d_ff=1024, num_experts=4),
         32_768, 0.0),
+    # served-only decoder: per-layer window/full x rope/nope pattern,
+    # grouped-query heads, dropless routed experts, bf16 parameters
+    "routed_decoder": (routed_decoder.RoutedDecoderLM, 32_768, 0.0),
     # pipeline-stacked LM family (pipeline stages over 'model')
     "pipeline_transformer": (pipeline_lm.PipelinedTransformerLM,
                              32_768, 0.0),
@@ -101,6 +104,13 @@ def build_model(name: str, num_classes: int | None = None,
     elif name.startswith("transformer"):
         kw = dict(vocab_size=num_classes or default_classes, dtype=dtype,
                   seq_axis=seq_axis, model_axis=model_axis, **model_kw)
+    elif name.startswith("routed_decoder"):
+        # the layer pattern may come from a JSON file: lists to tuples
+        # (module fields are hashed)
+        kw = dict(vocab_size=num_classes or default_classes, dtype=dtype,
+                  model_axis=model_axis,
+                  **{k: tuple(v) if isinstance(v, list) else v
+                     for k, v in model_kw.items()})
     else:
         kw = dict(num_classes=num_classes or default_classes, dtype=dtype,
                   **model_kw)

@@ -52,7 +52,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from dtf_tpu.ops.flash_attention import flash_attention
-from dtf_tpu.ops.paged_attention import (cached_attention,
+from dtf_tpu.ops.paged_attention import (cached_attention, expand_kv_heads,
                                          paged_attention_auto, write_pages)
 from dtf_tpu.parallel.collectives import tp_psum, tp_region
 from dtf_tpu.parallel.ring_attention import ring_attention
@@ -80,6 +80,80 @@ def remat_policy(name: str):
 # dense fixed-window cache attention — shared with the paged gather
 # path, single-sourced in ops.paged_attention
 _cached_attention = cached_attention
+
+
+def paged_cache_attention(module, q, k, v, cache_index, block_table, *,
+                          flash_prefill: bool = False,
+                          window_pages: Optional[int] = None,
+                          window: Optional[int] = None):
+    """Write-then-attend against the shared page pool — what every
+    decoder family's attention does with the paged cache, called from
+    inside the attention module's ``@nn.compact`` body (``module`` owns
+    the two pool variables and names ``kv_page_size``, ``kv_pool_pages``,
+    ``use_pallas``).
+
+    q [B, S, Hq, Dh]; k, v [B, S, Hkv, Dh] with ``Hq`` a multiple of
+    ``Hkv`` (grouped-query heads: query head ``i`` reads KV head
+    ``i // (Hq // Hkv)``); k already carries its positions (a rotary
+    family rotates it before this call: the pool holds what is
+    attended).  ``window`` (static, tokens; None = the whole history) is
+    the layer's own attention window."""
+    s = q.shape[1]
+    # paged cache: one shared pool per K/V, sized by the module
+    # attrs (NOT by the init call's shapes — admission capacity
+    # is a pool property, not a per-slot reservation)
+    pool_shape = (module.kv_pool_pages, module.kv_page_size) + k.shape[2:]
+    paged_key = module.variable(
+        "cache", "paged_key", jnp.zeros, pool_shape, k.dtype)
+    paged_value = module.variable(
+        "cache", "paged_value", jnp.zeros, pool_shape, v.dtype)
+
+    def flash(k, v):
+        return flash_attention(q, *expand_kv_heads(k, v, q.shape[2]),
+                               causal=True, use_pallas=module.use_pallas)
+
+    if module.is_initializing():
+        # init trace: only the pool variables' shapes matter,
+        # but keep the math valid (plain causal attention)
+        return flash(k, v)
+    # write-then-attend, same ordering contract as the
+    # contiguous path.  Prefill chunks (S a page
+    # multiple; page-aligned starts by engine construction)
+    # scatter whole pages; decode steps (S = 1) scatter
+    # single token rows
+    aligned = s > 1 and s % module.kv_page_size == 0
+    paged_key.value = write_pages(
+        paged_key.value, k, block_table, cache_index,
+        page_aligned=aligned)
+    paged_value.value = write_pages(
+        paged_value.value, v, block_table, cache_index,
+        page_aligned=aligned)
+    if flash_prefill and (window is None or s <= window):
+        # first prefill chunk (cache_index == 0, engine
+        # invariant): there is no prefix to gather — the
+        # chunk IS the whole attended history, plain causal
+        # self-attention through the flash kernel at
+        # O(S·D) HBM traffic instead of an [S, L] gather
+        # (a chunk no longer than the window sees all of itself)
+        return flash(k, v)
+    # paged_attention_auto: the Pallas flash-decode
+    # kernel on TPU (default-on — each row's live pages
+    # streamed from the pool as stored, ids from the
+    # block table in-kernel; no gathered window, the
+    # loop ends at the row's own last page), the
+    # gather oracle elsewhere.  window_pages (STATIC,
+    # decode.py computes it from the chunk's start)
+    # trims the GATHER path to the pages the chunk can
+    # actually see: continuation-chunk attention costs
+    # O(S · progress), so total prefill work is
+    # O(prompt²/2) regardless of the pool's logical
+    # capacity.  None (the decode step) attends the
+    # full per-slot window — lengths vary per row
+    return paged_attention_auto(
+        q, paged_key.value, paged_value.value,
+        block_table, cache_index,
+        window_pages=window_pages,
+        use_pallas=module.use_pallas, window=window)
 
 
 class CausalSelfAttention(nn.Module):
@@ -132,60 +206,9 @@ class CausalSelfAttention(nn.Module):
             if cache_index is None or block_table is None:
                 raise ValueError("paged decode mode needs cache_index [B] "
                                  "and block_table [B, M], both int32")
-            # paged cache: one shared pool per K/V, sized by the module
-            # attrs (NOT by the init call's shapes — admission capacity
-            # is a pool property, not a per-slot reservation)
-            pool_shape = (self.kv_pool_pages, self.kv_page_size,
-                          heads, head_dim)
-            paged_key = self.variable(
-                "cache", "paged_key", jnp.zeros, pool_shape, k.dtype)
-            paged_value = self.variable(
-                "cache", "paged_value", jnp.zeros, pool_shape, v.dtype)
-            if not self.is_initializing():
-                # write-then-attend, same ordering contract as the
-                # contiguous path below.  Prefill chunks (S a page
-                # multiple; page-aligned starts by engine construction)
-                # scatter whole pages; decode steps (S = 1) scatter
-                # single token rows
-                aligned = s > 1 and s % self.kv_page_size == 0
-                paged_key.value = write_pages(
-                    paged_key.value, k, block_table, cache_index,
-                    page_aligned=aligned)
-                paged_value.value = write_pages(
-                    paged_value.value, v, block_table, cache_index,
-                    page_aligned=aligned)
-                if flash_prefill:
-                    # first prefill chunk (cache_index == 0, engine
-                    # invariant): there is no prefix to gather — the
-                    # chunk IS the whole attended history, plain causal
-                    # self-attention through the flash kernel at
-                    # O(S·D) HBM traffic instead of an [S, L] gather
-                    o = flash_attention(q, k, v, causal=True,
-                                        use_pallas=self.use_pallas)
-                else:
-                    # paged_attention_auto: the Pallas flash-decode
-                    # kernel on TPU (default-on — each row's live pages
-                    # streamed from the pool as stored, ids from the
-                    # block table in-kernel; no gathered window, the
-                    # loop ends at the row's own last page), the
-                    # gather oracle elsewhere.  window_pages (STATIC,
-                    # decode.py computes it from the chunk's start)
-                    # trims the GATHER path to the pages the chunk can
-                    # actually see: continuation-chunk attention costs
-                    # O(S · progress), so total prefill work is
-                    # O(prompt²/2) regardless of the pool's logical
-                    # capacity.  None (the decode step) attends the
-                    # full per-slot window — lengths vary per row
-                    o = paged_attention_auto(
-                        q, paged_key.value, paged_value.value,
-                        block_table, cache_index,
-                        window_pages=window_pages,
-                        use_pallas=self.use_pallas)
-            else:
-                # init trace: only the pool variables' shapes matter,
-                # but keep the math valid (plain causal attention)
-                o = flash_attention(q, k, v, causal=True,
-                                    use_pallas=self.use_pallas)
+            o = paged_cache_attention(
+                self, q, k, v, cache_index, block_table,
+                flash_prefill=flash_prefill, window_pages=window_pages)
         elif self.decode:
             if cache_index is None:
                 raise ValueError("decode mode needs cache_index [B] int32")

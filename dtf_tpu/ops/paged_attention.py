@@ -96,6 +96,18 @@ def cached_attention(q, k, v, mask):
     return o.astype(q.dtype)
 
 
+def expand_kv_heads(k, v, q_heads: int):
+    """K and V [B, L, Hkv, Dh] with every KV head repeated for the
+    ``q_heads // Hkv`` query heads that share it (grouped-query heads:
+    query head ``i`` reads KV head ``i // group``) — for the attention
+    formulations that want one KV head a query head.  Unchanged where
+    the counts are equal."""
+    group = q_heads // k.shape[2]
+    if group == 1:
+        return k, v
+    return jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+
+
 def write_pages(pool, new, block_table, index, page_aligned: bool = False):
     """Scatter a [B, S, H, Dh] chunk of K or V into the page pool.
 
@@ -128,8 +140,19 @@ def write_pages(pool, new, block_table, index, page_aligned: bool = False):
             pstart[:, None] + jnp.arange(n_pages, dtype=jnp.int32)[None, :],
             block_table.shape[1] - 1)
         page = jnp.take_along_axis(block_table, pidx, axis=1)  # [B, n]
-        return pool.at[page.reshape(-1)].set(
-            new.reshape(b * n_pages, page_size, h, dh))
+        pages = new.reshape(b * n_pages, page_size, h, dh)
+        if h * pool.dtype.itemsize < 32:
+            # fewer heads than a (sublane, lane) tile holds (4 bf16 KV
+            # heads under grouped queries): XLA lays the scatter out
+            # heads-major and then rewrites the WHOLE pool twice a layer
+            # to hand the kernel its stored layout back (23 of a 43 ms
+            # chunk, v5e, 12 layers of [2049, 64, 4, 128]).  Whole pages
+            # written in place, one by one, keep the pool where it is
+            for i, pid in enumerate(page.reshape(-1)):
+                pool = jax.lax.dynamic_update_slice(
+                    pool, pages[i][None], (pid, 0, 0, 0))
+            return pool
+        return pool.at[page.reshape(-1)].set(pages)
     pos = index[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
     pos = jnp.minimum(pos, capacity - 1)                     # [B, S]
     page = jnp.take_along_axis(block_table, pos // page_size, axis=1)
@@ -155,7 +178,7 @@ def gather_pages(pool, block_table):
     return pool[block_table].reshape(b, m * page_size, h, dh)
 
 
-def paged_attention(q, pool_k, pool_v, block_table, index):
+def paged_attention(q, pool_k, pool_v, block_table, index, *, window=None):
     """Attention of a chunk of queries over a slot's paged KV history.
 
     q [B, S, H, Dh] — S new queries per row, the row's global positions
@@ -164,15 +187,24 @@ def paged_attention(q, pool_k, pool_v, block_table, index):
     already be written into the pool (write-then-attend, exactly the
     contiguous cache path's ordering), so query i sees logical
     positions j <= index + i: the just-written chunk causally, the
-    prefix fully, and never the unwritten tail (masked)."""
+    prefix fully, and never the unwritten tail (masked).
+
+    Grouped-query heads: q may carry ``G`` times the pools' heads; query
+    head ``i`` reads KV head ``i // G``.  ``window`` (static; None = all
+    of the history): query at position ``p`` sees keys ``p - window < j
+    <= p``, its own position counted."""
     k = gather_pages(pool_k, block_table)   # [B, L, H, Dh]
     v = gather_pages(pool_v, block_table)
+    k, v = expand_kv_heads(k, v, q.shape[2])
     s = q.shape[1]
     capacity = k.shape[1]
     jpos = jnp.arange(capacity, dtype=jnp.int32)[None, None, :]
     qpos = (index[:, None, None]
             + jnp.arange(s, dtype=jnp.int32)[None, :, None])
-    return cached_attention(q, k, v, jpos <= qpos)
+    mask = jpos <= qpos
+    if window is not None:
+        mask &= jpos > qpos - window
+    return cached_attention(q, k, v, mask)
 
 
 # ---------------------------------------------------------------------------
@@ -198,9 +230,17 @@ def _tiled_heads(h, itemsize):
     return words * pack
 
 
+def _head_bytes(s, d, itemsize):
+    """VMEM one head's ``s`` query rows take: f32 o [S, D], m and l
+    [S, 1] (a lane tile each), and the pipeline's two q and two out
+    blocks."""
+    return s * (d * 4 + 2 * 128 * 4 + 4 * d * itemsize)
+
+
 def _plan(s, h, d, page_size, m_pages, itemsize):
     """Static tiling from what a trace can see: ``(pages_per_block,
-    heads_per_group)``.
+    heads_per_group)``.  ``s`` is the query rows a KV head meets: the
+    chunk's length times the query heads that share the head.
 
     ``pages_per_block``: the largest power of two whose K+V double
     buffer takes half the budget, at most the table's width.
@@ -216,9 +256,7 @@ def _plan(s, h, d, page_size, m_pages, itemsize):
            and 4 * (ppb * 2) * page_bytes <= _VMEM_BUDGET // 2):
         ppb *= 2
     pack = 4 // itemsize
-    # per head: f32 o [S, D], m and l [S, 1] (a lane tile each), and
-    # the pipeline's two q and two out blocks
-    head_bytes = s * (d * 4 + 2 * 128 * 4 + 4 * d * itemsize)
+    head_bytes = _head_bytes(s, d, itemsize)
     hg = h
     while hg > pack and (hg * head_bytes > _VMEM_BUDGET // 2
                          or h % hg or hg % pack):
@@ -242,7 +280,8 @@ def _head_rows(flat_ref, head, h, t):
 
 
 def _paged_decode_kernel(tbl_ref, idx_ref, q_ref, k_hbm, v_hbm, o_ref,
-                         kbuf, vbuf, sem, oacc_ref, m_ref, l_ref, *, scale):
+                         kbuf, vbuf, sem, oacc_ref, m_ref, l_ref, *, scale,
+                         q_len=None, row_blocks=1, window=None):
     """Grid (B, head groups): one row streams ITS pages, block by block.
 
     ``tbl_ref`` [B, M] and ``idx_ref`` [B] are scalar-prefetched (SMEM);
@@ -262,7 +301,18 @@ def _paged_decode_kernel(tbl_ref, idx_ref, q_ref, k_hbm, v_hbm, o_ref,
     un-normalized o in f32, running max m, denominator l) lives in VMEM
     scratch across the blocks.  The causal mask is positional, exactly
     the gather oracle's: key position ``p`` is admitted iff
-    ``p <= index + i`` for query ``i``."""
+    ``p <= index + i`` for query ``i``.
+
+    Grouped-query heads (``q_len`` set): the ``G`` query heads that share
+    a KV head lie in ``q_ref`` as ``G * q_len`` rows of that head, query
+    head after query head, so row ``r`` is the query at ``r % q_len`` and
+    one stream of the pages serves all of them; where the rows of a head
+    outgrow the budget the grid's second axis also walks ``row_blocks``
+    blocks of them.  ``window`` (static): a query sees the last
+    ``window`` positions up to its own, and the loop starts at the
+    first block the chunk's first query can see, so a row costs
+    ``min(len, window + S)`` tokens and not ``len``.  All three unset is
+    the kernel of full heads over the whole history, unchanged."""
     b = pl.program_id(0)
     g = pl.program_id(1)
     _, ppb, page_size, h, d = kbuf.shape
@@ -271,8 +321,13 @@ def _paged_decode_kernel(tbl_ref, idx_ref, q_ref, k_hbm, v_hbm, o_ref,
     m_pages = tbl_ref.shape[1]
     pack = 4 // kbuf.dtype.itemsize
     idx = idx_ref[b]
-    n_live = jnp.minimum(idx + s, m_pages * page_size)
+    row0 = 0
+    if row_blocks > 1:
+        g, row0 = g // row_blocks, (g % row_blocks) * s
+    n_live = jnp.minimum(idx + (s if q_len is None else q_len),
+                         m_pages * page_size)
     n_blocks = pl.cdiv(n_live, t)
+    first = 0 if window is None else jnp.maximum(idx - window + 1, 0) // t
 
     def for_pages(lo, hi, fn):
         def body(p, carry):
@@ -312,7 +367,7 @@ def _paged_decode_kernel(tbl_ref, idx_ref, q_ref, k_hbm, v_hbm, o_ref,
     oacc_ref[...] = jnp.zeros_like(oacc_ref)
     m_ref[...] = jnp.full_like(m_ref, bw.NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
-    start(0, 0)
+    start(first, first % 2)
 
     def block(blk, carry):
         slot = blk % 2
@@ -325,8 +380,14 @@ def _paged_decode_kernel(tbl_ref, idx_ref, q_ref, k_hbm, v_hbm, o_ref,
         kflat = kbuf.at[slot].reshape(t * h, d)
         vflat = vbuf.at[slot].reshape(t * h, d)
         kpos = blk * t + jax.lax.broadcasted_iota(jnp.int32, (1, t), 1)
-        qpos = idx + jax.lax.broadcasted_iota(jnp.int32, (s, 1), 0)
-        bias = jnp.where(kpos <= qpos, 0.0, bw.NEG_INF)
+        qrow = jax.lax.broadcasted_iota(jnp.int32, (s, 1), 0)
+        if q_len is not None:
+            qrow = (row0 + qrow) % q_len
+        qpos = idx + qrow
+        seen = kpos <= qpos
+        if window is not None:
+            seen &= kpos > qpos - window
+        bias = jnp.where(seen, 0.0, bw.NEG_INF)
 
         def head_words(i, c):
             ks = _head_rows(kflat, g * heads + i * pack, h, t)
@@ -343,49 +404,73 @@ def _paged_decode_kernel(tbl_ref, idx_ref, q_ref, k_hbm, v_hbm, o_ref,
 
         return jax.lax.fori_loop(0, heads // pack, head_words, carry)
 
-    jax.lax.fori_loop(0, n_blocks, block, 0)
+    jax.lax.fori_loop(first, n_blocks, block, 0)
     o_ref[...] = bw.finalize(
         oacc_ref[...], l_ref[...][..., 0]).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "interpret", "window"))
 def paged_flash_decode(q, pool_k, pool_v, block_table, index, *,
-                       scale=None, interpret: bool = False):
+                       scale=None, interpret: bool = False, window=None):
     """Attention of a chunk of queries over a slot's paged KV history,
     streaming each row's LIVE pages out of the pool as it is stored.
 
     Same contract as :func:`paged_attention` (write-then-attend; q
     [B, S, H, Dh], pools [P, page_size, H, Dh], block_table [B, M],
-    index [B] int32).  The pools stay in HBM in their stored layout —
-    no transposed or gathered copy exists; the work of a row is
-    proportional to its own length, not to the table's width; one
-    compile covers every chunk index.  Tiling follows the static shapes
-    (:func:`_plan`).  Jitted, so that the layers of a model, which call
-    it at one shape, share one trace and one lowering of the kernel:
-    traced per layer it was 25 s of every serve process's set-up."""
-    b, s, h, d = q.shape
-    page_size = pool_k.shape[1]
+    index [B] int32; grouped-query heads where q has ``G`` times the
+    pools' heads; ``window`` static).  The pools stay in HBM in their
+    stored layout — no transposed or gathered copy exists; the work of
+    a row is proportional to its own length (to ``window + S`` under a
+    window), not to the table's width; one compile covers every chunk
+    index.  Tiling follows the static shapes (:func:`_plan`).  Jitted,
+    so that the layers of a model, which call it at one shape, share
+    one trace and one lowering of the kernel: traced per layer it was
+    25 s of every serve process's set-up."""
+    b, s, hq, d = q.shape
+    page_size, h = pool_k.shape[1], pool_k.shape[2]
     m_pages = block_table.shape[1]
     scale = float(scale) if scale is not None else 1.0 / (d ** 0.5)
     if pool_k.dtype not in (jnp.bfloat16, jnp.float32):
         raise ValueError(f"paged_flash_decode reads bf16 or f32 pools, "
                          f"not {pool_k.dtype} (see _head_rows)")
+    group, rem = divmod(hq, h)
+    if rem or group < 1:
+        raise ValueError(f"{hq} query heads do not share {h} KV heads")
     hp = _tiled_heads(h, pool_k.dtype.itemsize)
     if hp != h:
         # a head count the TPU's (sublane, lane) tiling cannot hold
         # whole (6 or 3 bf16 heads: ``transformer_tpu``): zero heads
         # fill the tile.  This one case copies the pools, every call
         zeros = ((0, 0), (0, 0), (0, hp - h), (0, 0))
+        qpad = ((0, 0), (0, 0), (0, (hp - h) * group), (0, 0))
         return paged_flash_decode(
-            jnp.pad(q, zeros), jnp.pad(pool_k, zeros), jnp.pad(pool_v, zeros),
-            block_table, index, scale=scale, interpret=interpret)[:, :, :h]
-    ppb, hg = _plan(s, h, d, page_size, m_pages, pool_k.dtype.itemsize)
+            jnp.pad(q, qpad), jnp.pad(pool_k, zeros), jnp.pad(pool_v, zeros),
+            block_table, index, scale=scale, interpret=interpret,
+            window=window)[:, :, :hq]
     qh = jnp.swapaxes(q, 1, 2)                       # [B, H, S, D], q alone
-    qo_spec = pl.BlockSpec((None, hg, s, d),
-                           lambda b_, g_, tbl, idx: (b_, g_, 0, 0))
+    rows, row_blocks = s, 1
+    if group > 1:
+        # the G query heads of a KV head as G * S rows of that head
+        qh = qh.reshape(b, h, group * s, d)
+        rows = group * s
+        pack = 4 // pool_k.dtype.itemsize
+        while (pack * _head_bytes(rows, d, pool_k.dtype.itemsize)
+               > _VMEM_BUDGET // 2 and rows % 16 == 0):
+            rows //= 2
+        row_blocks = group * s // rows
+    ppb, hg = _plan(rows, h, d, page_size, m_pages, pool_k.dtype.itemsize)
+    if row_blocks > 1:
+        qo_spec = pl.BlockSpec(
+            (None, hg, rows, d),
+            lambda b_, g_, tbl, idx: (b_, g_ // row_blocks,
+                                      g_ % row_blocks, 0))
+    else:
+        qo_spec = pl.BlockSpec((None, hg, rows, d),
+                               lambda b_, g_, tbl, idx: (b_, g_, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, h // hg),
+        grid=(b, h // hg * row_blocks),
         in_specs=[qo_spec,
                   pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],
@@ -394,19 +479,26 @@ def paged_flash_decode(q, pool_k, pool_v, block_table, index, *,
             pltpu.VMEM((2, ppb, page_size, h, d), pool_k.dtype),
             pltpu.VMEM((2, ppb, page_size, h, d), pool_v.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.VMEM((hg, s, d), jnp.float32),
-            pltpu.VMEM((hg, s, 1), jnp.float32),
-            pltpu.VMEM((hg, s, 1), jnp.float32),
+            pltpu.VMEM((hg, rows, d), jnp.float32),
+            pltpu.VMEM((hg, rows, 1), jnp.float32),
+            pltpu.VMEM((hg, rows, 1), jnp.float32),
         ],
     )
+    kernel_kw = {"scale": scale}
+    if group > 1:
+        kernel_kw.update(q_len=s, row_blocks=row_blocks)
+    if window is not None:
+        kernel_kw["window"] = int(window)
     out = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, scale=scale),
+        functools.partial(_paged_decode_kernel, **kernel_kw),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qh.shape, q.dtype),
         interpret=interpret,
         name="paged_flash_decode",
     )(jnp.asarray(block_table, jnp.int32), jnp.asarray(index, jnp.int32),
       qh, pool_k, pool_v)
+    if group > 1:
+        out = out.reshape(b, hq, s, d)
     return jnp.swapaxes(out, 1, 2)
 
 
@@ -447,7 +539,7 @@ def paged_flash_decode_reference(q, pool_k, pool_v, block_table, index, *,
 
 
 def paged_attention_auto(q, pool_k, pool_v, block_table, index, *,
-                         window_pages=None, use_pallas=None):
+                         window_pages=None, use_pallas=None, window=None):
     """Dispatch between the kernel and the gather oracle.
 
     ``use_pallas``: None = auto (kernel on TPU — the default-on flag —
@@ -455,12 +547,16 @@ def paged_attention_auto(q, pool_k, pool_v, block_table, index, *,
     Pallas interpreter (CPU kernel validation); False = gather.
     ``window_pages`` (static) trims the GATHER path's window exactly as
     before; the kernel ignores it — its loop stops at the row's own
-    last page without a per-window recompile."""
+    last page without a per-window recompile.  ``window`` (static) is
+    the layer's attention window in tokens, for both."""
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
     if use_pallas:
         return paged_flash_decode(q, pool_k, pool_v, block_table, index,
-                                  interpret=use_pallas == "interpret")
+                                  interpret=use_pallas == "interpret",
+                                  window=window)
     table = (block_table if window_pages is None
              else block_table[:, :window_pages])
-    return paged_attention(q, pool_k, pool_v, table, index)
+    if window is None:
+        return paged_attention(q, pool_k, pool_v, table, index)
+    return paged_attention(q, pool_k, pool_v, table, index, window=window)

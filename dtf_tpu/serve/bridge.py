@@ -140,8 +140,15 @@ def load_for_serving(model_dir: str = "", export_dir: str = "",
 def serving_memory_plan(model, *, num_slots: int, max_seq_len: int,
                         kv_page_size: int = 0,
                         kv_pool_pages: int = 0,
-                        model_parallelism: int = 1) -> dict:
+                        model_parallelism: int = 1, params=None) -> dict:
     """Byte accounting for a serving deployment: params + KV cache.
+
+    The geometry is the model's own: ``num_kv_heads`` and ``head_dim``
+    where it names them (grouped-query families cache fewer heads than
+    they query), else ``num_heads`` heads of ``d_model // num_heads``;
+    ``param_bytes`` is what the parameter tree holds in the dtype it is
+    held in (``params``: the tree or its shapes; None = the shapes of
+    the model's own init).
 
     The KV side is where the paged cache earns its keep: the contiguous
     layout reserves ``num_slots × max_seq_len`` token slots per layer
@@ -155,11 +162,20 @@ def serving_memory_plan(model, *, num_slots: int, max_seq_len: int,
     guess."""
     import numpy as np
 
-    head_dim = model.d_model // model.num_heads
+    kv_heads = getattr(model, "num_kv_heads", None) or model.num_heads
+    head_dim = (getattr(model, "head_dim", None)
+                or model.d_model // model.num_heads)
     # 2 arrays (K and V) per layer; cache dtype follows compute dtype
     # (np.dtype resolves jnp scalar types incl. bfloat16 via ml_dtypes)
     elem = np.dtype(model.dtype).itemsize
-    per_token = 2 * model.num_layers * model.num_heads * head_dim * elem
+    per_token = 2 * model.num_layers * kv_heads * head_dim * elem
+    if params is None:
+        params = jax.eval_shape(
+            model.init, jax.random.key(0),
+            jax.ShapeDtypeStruct((1, max(kv_page_size, 1)), "int32")
+        )["params"]
+    param_bytes = sum(int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
+                      for leaf in jax.tree_util.tree_leaves(params))
     pages_per_slot = -(-max_seq_len // max(kv_page_size, 1))
     full_pages = 1 + num_slots * pages_per_slot
     pool_pages = int(kv_pool_pages) or full_pages
@@ -167,6 +183,10 @@ def serving_memory_plan(model, *, num_slots: int, max_seq_len: int,
     paged_tokens = (pool_pages - 1) * kv_page_size if kv_page_size else 0
     mp = max(int(model_parallelism), 1)
     plan = {
+        "kv_heads": kv_heads,
+        "head_dim": head_dim,
+        "param_bytes": param_bytes,
+        "param_bytes_per_device": param_bytes // mp,
         "per_token_kv_bytes": per_token,
         "kv_bytes_contiguous": contiguous_tokens * per_token,
         "kv_bytes_paged": paged_tokens * per_token,
@@ -181,8 +201,10 @@ def serving_memory_plan(model, *, num_slots: int, max_seq_len: int,
             ((paged_tokens or contiguous_tokens) * per_token) // mp,
     }
     log.info(
-        "serving memory plan: %d slots x %d tokens; KV contiguous %.1f "
-        "MB%s%s", num_slots, max_seq_len,
+        "serving memory plan: %d slots x %d tokens; weights %.1f MB; "
+        "%d KV heads x %d, %d B/token; KV contiguous %.1f "
+        "MB%s%s", num_slots, max_seq_len, param_bytes / 2**20,
+        kv_heads, head_dim, per_token,
         plan["kv_bytes_contiguous"] / 2**20,
         (f", paged pool {plan['kv_bytes_paged'] / 2**20:.1f} MB "
          f"({pool_pages} pages x {kv_page_size} tokens)"

@@ -180,6 +180,11 @@ class Decoder:
         from dtf_tpu.runtime.mesh import MODEL_AXIS
 
         self.mesh = mesh
+        # what the model counted in the newest call of a compiled body
+        # (its "stats" collection: a small device array, or None for a
+        # model that sows none) — handed over with the call's result, so
+        # the engine can fetch it in the transfer it makes anyway
+        self.last_stats = None
         # MFU/cost ledger (obs/ledger.py): each compiled body (decode
         # step, prefill chunk per shape) registers its XLA flop/byte
         # counts at compile time — pulled from the AOT executable the
@@ -289,7 +294,7 @@ class Decoder:
                 {"params": params, "cache": cache}, tokens,
                 cache_index=index, block_table=block_table,
                 flash_prefill=flash_prefill, window_pages=window_pages,
-                mutable=["cache"])
+                mutable=["cache", "stats"])
         from jax.sharding import PartitionSpec as P
 
         cspec = jax.tree_util.tree_map(lambda _: self._cache_pspec(),
@@ -455,7 +460,7 @@ class Decoder:
         last = jax.lax.dynamic_slice_in_dim(
             logits[0], sample_pos, 1, axis=0)[0]           # [V]
         tok = _sample(last, temperature, key)
-        return tok, mut["cache"], last
+        return tok, mut["cache"], last, mut.get("stats")
 
     def _decode_paged_impl(self, params, cache, tokens, index,
                            block_tables, temperature, rowkeys):
@@ -467,7 +472,7 @@ class Decoder:
             params, cache, tokens, index, block_tables, False, None)
         last = logits[:, -1]                               # [B, V]
         toks = jax.vmap(_sample)(last, temperature, rowkeys)
-        return toks, mut["cache"], last
+        return toks, mut["cache"], last, mut.get("stats")
 
     # -- public API ----------------------------------------------------
     def prefill(self, cache, prompt, slot: int, temperature: float,
@@ -550,7 +555,8 @@ class Decoder:
                 fn = (lambda *a, _w=window, _f=(start == 0):
                       self._chunk(*a, _w, _f))
             self._execs[ekey] = fn
-        return fn(*dyn)
+        tok, cache, last, self.last_stats = fn(*dyn)
+        return tok, cache, last
 
     def decode_step(self, cache, tokens, index, temperature, key=None,
                     block_tables=None, seeds=None):
@@ -585,7 +591,8 @@ class Decoder:
                 fn = (self._aot("serve_decode_step", self._decode, dyn)
                       or self._decode)
                 self._execs["decode"] = fn
-            return fn(*dyn)
+            toks, cache, last, self.last_stats = fn(*dyn)
+            return toks, cache, last
         return self._decode(self.params, cache, tokens, index,
                             temperature, rowkeys)
 

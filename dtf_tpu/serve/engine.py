@@ -98,6 +98,7 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
 
 from dtf_tpu import chaos
@@ -1300,11 +1301,12 @@ class ServeEngine:
         pre_compiled = self.decoder.compiled_count
         with trace.span("serve_prefill_chunk", slot=slot_idx, start=start,
                         tokens=clen, last=is_last,
-                        **_tctx(req.trace_id, req.trace_parent)):
+                        **_tctx(req.trace_id, req.trace_parent)) as span:
             tok, self._cache, _ = self.decoder.prefill_chunk(
                 self._cache, slot.prompt_padded[start:start + clen],
                 slot.block_row, start, sample_pos, req.temperature,
                 seed=req.rng_seed)
+            self._model_counts(span)
         self._m_prefill_chunks.inc()
         slot.chunk_i += 1
         if is_last:
@@ -1372,14 +1374,14 @@ class ServeEngine:
             self._m_live_pages.observe(
                 int((index // self.page_size + 1).sum()))
         pre_compiled = self.decoder.compiled_count
-        with trace.span("serve_decode", **attrs):
+        with trace.span("serve_decode", **attrs) as span:
             out, self._cache, _ = self.decoder.decode_step(
                 self._cache, tokens, index, temps, seeds=seeds,
                 block_tables=tables)
             # dtflint: sync-point (the EOS/budget check needs the
             # sampled tokens on the host; the MFU ledger's
             # serve_decode_step wall time is honest BECAUSE this syncs)
-            out = np.asarray(out)
+            out = self._model_counts(span, out)
         step_dt = time.perf_counter() - now
         self._m_step_time.observe(step_dt)
         # MFU ledger: np.asarray(out) above synced the step, so this
@@ -1410,6 +1412,24 @@ class ServeEngine:
             if self._finished(s):
                 self._retire(i)
         self._last_step_t = time.perf_counter()
+
+    def _model_counts(self, span, out=None):
+        """``out`` on the host.  With tracing on, what the model counted
+        in the call that produced it (``Decoder.last_stats``: one small
+        vector named by the model's ``stats_names`` — assignments to
+        experts, experts touched, KV tokens read by layer kind; None for
+        a model that counts nothing) comes over in the same transfer and
+        becomes attributes of ``span``.  In a traced run this makes a
+        non-final prefill chunk wait for the device, which an untraced
+        one does not."""
+        stats = self.decoder.last_stats if trace.enabled() else None
+        if stats is None:
+            return None if out is None else np.asarray(out)
+        # dtflint: sync-point (traced runs only; see above)
+        out, counts = jax.device_get((out, stats["counts"]))
+        span.attrs.update(zip(self.decoder.model.stats_names,
+                              (int(c) for c in counts)))
+        return out
 
     @staticmethod
     def _finished(slot: _Slot) -> bool:

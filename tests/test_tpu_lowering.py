@@ -117,6 +117,46 @@ def test_paged_decode_kernel_compiles_for_v5e(v5e, heads, batch, s, pool):
         assert makers <= {"parameter"}, makers
 
 
+@pytest.mark.parametrize("window", [None, 4096], ids=["full", "window"])
+@pytest.mark.parametrize("batch,s", [(16, 1), (1, 512)],
+                         ids=["decode", "chunk"])
+def test_grouped_paged_kernel_compiles_for_v5e(v5e, batch, s, window):
+    """The routed decoder's shapes: 28 query heads over 4 KV heads of 128,
+    pages of 64 in a 131,072-token pool, 16,384-token rows (a 256-word
+    table row in scalar memory), a decode step of 16 rows and a 512-token
+    continuation chunk (3,584 query rows a KV head: walked in row
+    blocks), with and without the 4,096 window.  No copy of a pool."""
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    page, pool, m = 64, 2049, 256
+    shapes = (((batch, s, 28, D), bf16), ((pool, page, 4, D), bf16),
+              ((pool, page, 4, D), bf16), ((batch, m), i32), ((batch,), i32))
+    args = [jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+            for shape, dt in shapes]
+    text = jax.jit(functools.partial(pa.paged_flash_decode, window=window)
+                   ).lower(*args).compile().as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text
+    makers = set(re.findall(
+        rf"= bf16\[{pool},{page},\d+,{D}\]\S* ([\w-]+)\(", text))
+    assert makers <= {"parameter"}, makers
+
+
+@pytest.mark.parametrize("tokens", [16, 512], ids=["decode", "chunk"])
+def test_grouped_expert_matmuls_compile_for_v5e(v5e, tokens):
+    """The dropless expert layer's two grouped matmuls (the Pallas
+    megablox kernel) at 64 experts of 2560 x 768 in bf16: 96 pairs padded
+    to one tile of 128 rows, and 3,072 pairs."""
+    from dtf_tpu.models.routed_decoder import routed_experts
+    bf16 = jnp.bfloat16
+    shapes = (((tokens, 2560), bf16), ((tokens, 6), jnp.int32),
+              ((tokens, 6), jnp.float32), ((64, 2560, 1536), bf16),
+              ((64, 768, 2560), bf16))
+    args = [jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+            for shape, dt in shapes]
+    text = jax.jit(functools.partial(routed_experts, use_pallas=True)
+                   ).lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') >= 2
+
+
 @pytest.mark.parametrize("heads", [6, 3])
 @pytest.mark.parametrize("seq", [2048, 8192])
 def test_flash_fwd_bwd_lowers_for_tpu(heads, seq):
