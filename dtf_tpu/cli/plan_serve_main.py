@@ -324,7 +324,7 @@ def _record_calibration_run(args, trace_dir: str, *, tp: int = 1
     chunk = int(args.chunk_tokens) if args.chunk_tokens > 0 else 4 * ps
     slots = int(args.calibrate_slots)
     budget = int(args.calibrate_budget)
-    # pool sized to the contiguous-equivalent reservation: calibration
+    # pool sized to one full reservation per slot: calibration
     # measures the MODEL, not page starvation (pool what-ifs are the
     # simulator's job once calibrated)
     pool_usable = slots * (-(-int(args.seq) // ps))

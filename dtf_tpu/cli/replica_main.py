@@ -94,7 +94,7 @@ def run_replica(cfg, random_init: bool = False,
     # compile lazily; the router's health timeout absorbs those
     # shorter stalls.)
     import numpy as np
-    page = cfg.kv_page_size or 16
+    page = cfg.kv_page_size
     warm = np.full((min(page, engine.max_seq_len - 2),), 1, np.int32)
     engine.submit(warm, max_new_tokens=2).result(timeout=600)
     log.info("replica %d: warm (compile done)", replica_id)

@@ -174,7 +174,7 @@ def run_router(cfg, random_init: bool = False) -> dict:
         epoch=epoch or 0,
         role="leader",   # by construction: it holds the lease (HA) or
                          # is the only router (HA off)
-        page_size=cfg.kv_page_size or 16,
+        page_size=cfg.kv_page_size,
         placement=cfg.router_placement,
         deadline_s=cfg.router_deadline_s,
         admission_limit=cfg.router_admission,
@@ -245,7 +245,7 @@ def _drive_traffic(cfg, router) -> dict:
 
     rng = np.random.default_rng(cfg.seed)
     vocab = cfg.num_classes or 32_768
-    ps = cfg.kv_page_size or 16
+    ps = cfg.kv_page_size
     # shared-prefix traffic: a few "system prompts" (whole pages) with
     # per-request tails — the shape prefix-affine placement exists for
     n_groups = max(1, min(4, cfg.router_replicas))

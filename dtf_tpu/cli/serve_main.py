@@ -88,9 +88,7 @@ def build_serving_engine(cfg, random_init: bool = False,
                                      export_dir=cfg.export_dir, mesh=mesh,
                                      model_parallelism=cfg.serve_tp)
 
-    # paged KV cache by default (--kv_page_size 0 restores the
-    # contiguous per-slot layout); the memory plan makes pool sizing a
-    # logged decision
+    # the memory plan makes pool sizing a logged decision
     serving_memory_plan(model, num_slots=cfg.serve_max_batch,
                         max_seq_len=max_seq,
                         kv_page_size=cfg.kv_page_size,
@@ -102,13 +100,10 @@ def build_serving_engine(cfg, random_init: bool = False,
         max_batch=cfg.serve_max_batch, max_seq_len=max_seq,
         max_delay_s=cfg.serve_max_delay_ms / 1000.0,
         queue_size=cfg.serve_queue_size, seed=cfg.seed,
-        kv_page_size=cfg.kv_page_size or None,
+        kv_page_size=cfg.kv_page_size,
         kv_pool_pages=cfg.kv_pool_pages or None,
-        # Config.validate guarantees serve_prefill_chunk is None when
-        # the paged cache is off, so this never trips the engine's
-        # contradiction check
         prefill_chunk=cfg.serve_prefill_chunk,
-        prefix_sharing=cfg.serve_prefix_sharing and bool(cfg.kv_page_size),
+        prefix_sharing=cfg.serve_prefix_sharing,
         mesh=mesh,
         heartbeat=Heartbeat.from_env(rank=replica_rank,
                                      interval_s=cfg.heartbeat_secs))

@@ -281,13 +281,12 @@ class Config:
     serve_temperature: float = 0.0      # 0 = greedy
     serve_requests: int = 16            # synthetic-traffic demo request count
     serve_prompt_len: int = 8           # synthetic prompt length (max; varied)
-    # paged KV cache (serve/engine.py, ops/paged_attention.py): tokens
-    # per KV page; 0 = the legacy contiguous per-slot cache.  With
-    # paging, HBM admission is bounded by tokens in flight, not
-    # num_slots x max_seq_len
+    # KV page pool (serve/engine.py, ops/paged_attention.py): tokens
+    # per KV page (>= 1).  HBM admission is bounded by tokens in
+    # flight, not num_slots x max_seq_len
     kv_page_size: int = 16
-    # total pool pages INCLUDING the scratch page; 0 = the full
-    # contiguous-equivalent reservation (1 + slots x pages-per-slot).
+    # total pool pages INCLUDING the scratch page; 0 = one full
+    # max_seq_len reservation per slot (1 + slots x pages-per-slot).
     # Size it down (e.g. 50%) when mean request length << max_seq_len
     kv_pool_pages: int = 0
     # chunked-prefill unit in tokens (multiple of kv_page_size): long
@@ -561,29 +560,21 @@ class Config:
         if self.serve_max_batch < 1 or self.serve_queue_size < 1:
             raise ValueError(
                 "serve_max_batch and serve_queue_size must be >= 1")
-        if self.kv_page_size < 0 or self.kv_pool_pages < 0 or (
+        if self.kv_page_size < 1:
+            raise ValueError(
+                f"kv_page_size must be >= 1, got {self.kv_page_size}")
+        if self.kv_pool_pages < 0 or (
                 self.serve_prefill_chunk is not None
                 and self.serve_prefill_chunk < 0):
             raise ValueError(
-                "kv_page_size, kv_pool_pages and serve_prefill_chunk "
-                "must be >= 0 (0 disables each)")
-        if (self.kv_page_size and self.serve_prefill_chunk
+                "kv_pool_pages and serve_prefill_chunk must be >= 0")
+        if (self.serve_prefill_chunk
                 and self.serve_prefill_chunk % self.kv_page_size):
             raise ValueError(
                 f"serve_prefill_chunk ({self.serve_prefill_chunk}) must "
                 f"be a multiple of kv_page_size ({self.kv_page_size})")
-        if not self.kv_page_size and (
-                self.kv_pool_pages or self.serve_prefill_chunk is not None):
-            raise ValueError(
-                "kv_pool_pages / serve_prefill_chunk need the paged "
-                "cache (kv_page_size > 0)")
         if self.serve_tp < 1:
             raise ValueError(f"serve_tp must be >= 1, got {self.serve_tp}")
-        if self.serve_tp > 1 and not self.kv_page_size:
-            raise ValueError(
-                "serve_tp > 1 (tensor-parallel serving) needs the paged "
-                "KV cache (kv_page_size > 0) — the page pool is the "
-                "layout that shards")
         if self.router_replicas < 1:
             raise ValueError(
                 f"router_replicas must be >= 1, got {self.router_replicas}")

@@ -215,10 +215,10 @@ class GroupedQueryAttention(nn.Module):
             k = rotate_half_rope(k, positions, self.rope_theta)
         if self.decode:
             if self.kv_page_size is None:
-                raise ValueError("this family decodes through the paged "
-                                 "cache only (kv_page_size > 0)")
+                raise ValueError("decode mode needs kv_page_size and "
+                                 "kv_pool_pages")
             if cache_index is None or block_table is None:
-                raise ValueError("paged decode mode needs cache_index [B] "
+                raise ValueError("decode mode needs cache_index [B] "
                                  "and block_table [B, M], both int32")
             o = paged_cache_attention(
                 self, q, k, v, cache_index, block_table,

@@ -52,7 +52,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from dtf_tpu.ops.flash_attention import flash_attention
-from dtf_tpu.ops.paged_attention import (cached_attention, expand_kv_heads,
+from dtf_tpu.ops.paged_attention import (expand_kv_heads,
                                          paged_attention_auto, write_pages)
 from dtf_tpu.parallel.collectives import tp_psum, tp_region
 from dtf_tpu.parallel.ring_attention import ring_attention
@@ -75,11 +75,6 @@ def remat_policy(name: str):
             # would re-run the flash forward in the backward pass
             cp.save_only_these_names("attn_out", "flash_out", "flash_lse"))
     raise ValueError(f"unknown remat_policy {name!r}; choose 'dots'")
-
-
-# dense fixed-window cache attention — shared with the paged gather
-# path, single-sourced in ops.paged_attention
-_cached_attention = cached_attention
 
 
 def paged_cache_attention(module, q, k, v, cache_index, block_table, *,
@@ -116,11 +111,10 @@ def paged_cache_attention(module, q, k, v, cache_index, block_table, *,
         # init trace: only the pool variables' shapes matter,
         # but keep the math valid (plain causal attention)
         return flash(k, v)
-    # write-then-attend, same ordering contract as the
-    # contiguous path.  Prefill chunks (S a page
-    # multiple; page-aligned starts by engine construction)
-    # scatter whole pages; decode steps (S = 1) scatter
-    # single token rows
+    # write-then-attend (a query sees its own chunk's keys).
+    # Prefill chunks (S a page multiple; page-aligned starts by
+    # engine construction) scatter whole pages; decode steps
+    # (S = 1) scatter single token rows
     aligned = s > 1 and s % module.kv_page_size == 0
     paged_key.value = write_pages(
         paged_key.value, k, block_table, cache_index,
@@ -163,12 +157,11 @@ class CausalSelfAttention(nn.Module):
     model_axis: Optional[str] = None  # set when heads are mesh-sharded
     use_pallas: Any = None           # None=auto; False forces blockwise-JAX
     # serving: maintain a KV cache ('cache' collection) and attend
-    # incrementally — see TransformerLM.decode
-    decode: bool = False
-    # paged KV cache (decode only): the cache is a SHARED page pool
-    # [kv_pool_pages, kv_page_size, H, Dh] per K/V plus a caller-owned
-    # block table — see TransformerLM.kv_page_size and
+    # incrementally — see TransformerLM.decode.  The cache is a SHARED
+    # page pool [kv_pool_pages, kv_page_size, H, Dh] per K/V plus a
+    # caller-owned block table — see TransformerLM.kv_page_size and
     # ops.paged_attention for the layout/invariants
+    decode: bool = False
     kv_page_size: Optional[int] = None
     kv_pool_pages: Optional[int] = None
 
@@ -202,52 +195,13 @@ class CausalSelfAttention(nn.Module):
         qkv = nn.DenseGeneral((3, heads, head_dim), dtype=self.dtype,
                               name="qkv")(x)
         q, k, v = (qkv[..., i, :, :] for i in range(3))  # [B, S, Hloc, Dh]
-        if self.decode and self.kv_page_size is not None:
+        if self.decode:
             if cache_index is None or block_table is None:
-                raise ValueError("paged decode mode needs cache_index [B] "
+                raise ValueError("decode mode needs cache_index [B] "
                                  "and block_table [B, M], both int32")
             o = paged_cache_attention(
                 self, q, k, v, cache_index, block_table,
                 flash_prefill=flash_prefill, window_pages=window_pages)
-        elif self.decode:
-            if cache_index is None:
-                raise ValueError("decode mode needs cache_index [B] int32")
-            # cache capacity is fixed by the INIT call's sequence length
-            # (the serving engine initializes with [B, max_seq] dummies);
-            # subsequent applies write their S-token chunk at each row's
-            # cache_index and attend q over the prefix — one code path
-            # for prefill (S = padded prompt) and decode (S = 1)
-            cached_key = self.variable(
-                "cache", "cached_key", jnp.zeros,
-                (b, s, heads, head_dim), k.dtype)
-            cached_value = self.variable(
-                "cache", "cached_value", jnp.zeros,
-                (b, s, heads, head_dim), v.dtype)
-            if not self.is_initializing():
-                max_len = cached_key.value.shape[1]
-
-                def write(cache, new, idx):
-                    return jax.lax.dynamic_update_slice(
-                        cache, new, (idx, 0, 0))
-
-                cached_key.value = jax.vmap(write)(
-                    cached_key.value, k, cache_index)
-                cached_value.value = jax.vmap(write)(
-                    cached_value.value, v, cache_index)
-                # query i (global position idx+i) sees cache slots
-                # j <= idx+i: the just-written chunk causally, the
-                # prefix fully, and never the stale tail beyond idx+i
-                # (overwritten before it can enter the mask)
-                jpos = jnp.arange(max_len)[None, None, :]
-                qpos = (cache_index[:, None, None]
-                        + jnp.arange(s)[None, :, None])
-                o = _cached_attention(q, cached_key.value,
-                                      cached_value.value, jpos <= qpos)
-            else:
-                # init trace: only the cache variables' shapes matter,
-                # but keep the math valid (plain causal attention)
-                o = flash_attention(q, k, v, causal=True,
-                                    use_pallas=self.use_pallas)
         elif self.seq_axis is not None:
             # sequence-parallel: K/V rotate around the 'seq' ring; every
             # query still attends to the full global sequence
@@ -337,25 +291,22 @@ class TransformerLM(nn.Module):
     # None = save everything jax's autodiff wants (plain remat if
     # `remat`); "dots" = selective remat per the module docstring
     remat_policy: Optional[str] = None
-    # Serving mode (serve/decode.py drives this): every attention keeps
-    # a KV cache in the 'cache' collection, sized by the INIT call's
-    # sequence length, and __call__ takes `cache_index` [B] int32 — the
-    # per-row write offset (each request's current length, which is what
-    # makes slot-based continuous batching possible).  Composes with
-    # model_axis (serving tensor parallelism: heads + KV pool sharded
-    # over 'model', run inside shard_map — serve/decode.py Decoder);
-    # incompatible with seq_axis sharding and shard_vocab.
+    # Serving mode (serve/decode.py Decoder drives this): every
+    # attention keeps a SHARED [kv_pool_pages, kv_page_size, H, Dh] page
+    # pool per K/V in the 'cache' collection, and __call__ takes
+    # `cache_index` [B] int32 — the per-row write offset (each request's
+    # current length, which is what makes slot-based continuous batching
+    # possible) — and `block_table` [B, M] int32 (the engine-allocated
+    # page ids mapping each row's logical positions into the pool —
+    # ops.paged_attention has the layout and the scratch-page
+    # invariant), plus `flash_prefill` (static bool: the chunk starts at
+    # position 0, so attention runs causal-only through the flash kernel
+    # with no gather).  HBM scales with tokens in flight, not
+    # num_slots × max_seq_len.  Composes with model_axis (serving tensor
+    # parallelism: heads + KV pool sharded over 'model', run inside
+    # shard_map); incompatible with seq_axis sharding and shard_vocab.
+    # decode=True requires both page fields.
     decode: bool = False
-    # Paged KV cache (decode only; serve/decode.py Decoder drives it):
-    # instead of a per-slot [B, max_seq_len] slab, every attention keeps
-    # a SHARED [kv_pool_pages, kv_page_size, H, Dh] page pool per K/V,
-    # and __call__ additionally takes `block_table` [B, M] int32 (the
-    # engine-allocated page ids mapping each row's logical positions
-    # into the pool — ops.paged_attention has the layout and the
-    # scratch-page invariant) plus `flash_prefill` (static bool: the
-    # chunk starts at position 0, so attention runs causal-only through
-    # the flash kernel with no gather).  HBM then scales with tokens in
-    # flight, not num_slots × max_seq_len.
     kv_page_size: Optional[int] = None
     kv_pool_pages: Optional[int] = None
 
@@ -378,10 +329,10 @@ class TransformerLM(nn.Module):
                                  "shard_vocab (single-device serving)")
             if cache_index is None:
                 raise ValueError("decode mode needs cache_index [B] int32")
-            if (self.kv_page_size is None) != (self.kv_pool_pages is None):
+            if self.kv_page_size is None or self.kv_pool_pages is None:
                 raise ValueError(
-                    "kv_page_size and kv_pool_pages must be set together "
-                    "(both for the paged cache, neither for contiguous)")
+                    "decode mode needs kv_page_size and kv_pool_pages "
+                    "(the KV page pool's shape)")
             # per-row global positions; clamp so a padded prefill chunk
             # can't index past the table (those rows' logits are unused)
             pos_idx = jnp.minimum(

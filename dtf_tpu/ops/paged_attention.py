@@ -1,10 +1,11 @@
 """Paged KV-cache primitives: page-pool writes, block-table gathers,
 and gather-attention for serving decode.
 
-The contiguous serving cache (one [num_slots, max_seq_len, H, Dh] slab
-per layer) reserves worst-case HBM for every slot: a 4-token request
-holds the same memory as a max-length one.  The paged layout is the
-vLLM/PagedAttention discipline adapted to fixed-shape XLA:
+A serving cache of one [num_slots, max_seq_len, H, Dh] slab per layer
+would reserve worst-case HBM for every slot: a 4-token request would
+hold the same memory as a max-length one.  The paged layout — the only
+one the serving stack builds — is the vLLM/PagedAttention discipline
+adapted to fixed-shape XLA:
 
   page pool    — one [num_pages, page_size, H, Dh] array per layer per
                  K/V, shared by every slot.  Token at logical position
@@ -28,16 +29,15 @@ full ``max_pages_per_slot * page_size`` logical window and masks, so
 the decode step compiles exactly once regardless of pool occupancy.
 
 ``cached_attention`` (dense attention against a fixed-capacity KV
-window, f32 softmax) also lives here — it is the shared score/softmax
-math for both the contiguous cache path (models/transformer.py) and
-the paged gather path.
+window, f32 softmax) also lives here — it is the score/softmax math
+of the gather path and of the routed decoder's teacher-forced forward.
 
 Two formulations of attention-over-pages coexist:
 
   gather (``paged_attention``)      — materialize the gathered window,
-      mask, dense softmax.  Portable, the CPU-default oracle.  Pays the
-      PR-3 gather tax (~3% of contiguous step time) plus, for prefill
-      chunks, a host-side STATIC window trim (one compile per window).
+      mask, dense softmax.  Portable, the CPU-default oracle.  Pays a
+      gathered copy of the window plus, for prefill chunks, a
+      host-side STATIC window trim (one compile per window).
   kernel (``paged_flash_decode``)   — a Pallas kernel that streams, per
       row, only the pages that row HAS.  The pools stay in HBM exactly
       as stored, ``[P, page, H, Dh]``: a page with all its heads is one
@@ -184,10 +184,9 @@ def paged_attention(q, pool_k, pool_v, block_table, index, *, window=None):
     q [B, S, H, Dh] — S new queries per row, the row's global positions
     being ``index[b] + i``; pool_k/pool_v [P, page_size, H, Dh];
     block_table [B, M]; index [B] int32.  The chunk's own K/V must
-    already be written into the pool (write-then-attend, exactly the
-    contiguous cache path's ordering), so query i sees logical
-    positions j <= index + i: the just-written chunk causally, the
-    prefix fully, and never the unwritten tail (masked).
+    already be written into the pool (write-then-attend), so query i
+    sees logical positions j <= index + i: the just-written chunk
+    causally, the prefix fully, and never the unwritten tail (masked).
 
     Grouped-query heads: q may carry ``G`` times the pools' heads; query
     head ``i`` reads KV head ``i // G``.  ``window`` (static; None = all

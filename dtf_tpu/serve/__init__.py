@@ -5,7 +5,7 @@ from dtf_tpu.serve.bridge import (load_for_serving,       # noqa: F401
                                   load_inference_variables,
                                   place_for_serving,
                                   serving_memory_plan, serving_mesh)
-from dtf_tpu.serve.decode import (Decoder, init_cache,    # noqa: F401
+from dtf_tpu.serve.decode import (Decoder,                # noqa: F401
                                   init_paged_cache,
                                   make_decode_model,
                                   teacher_forced_logits)

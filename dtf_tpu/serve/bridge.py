@@ -138,7 +138,7 @@ def load_for_serving(model_dir: str = "", export_dir: str = "",
 
 
 def serving_memory_plan(model, *, num_slots: int, max_seq_len: int,
-                        kv_page_size: int = 0,
+                        kv_page_size: int,
                         kv_pool_pages: int = 0,
                         model_parallelism: int = 1, params=None) -> dict:
     """Byte accounting for a serving deployment: params + KV cache.
@@ -150,13 +150,10 @@ def serving_memory_plan(model, *, num_slots: int, max_seq_len: int,
     held in (``params``: the tree or its shapes; None = the shapes of
     the model's own init).
 
-    The KV side is where the paged cache earns its keep: the contiguous
-    layout reserves ``num_slots × max_seq_len`` token slots per layer
-    regardless of traffic, while the paged pool holds
-    ``(kv_pool_pages − 1) × kv_page_size`` tokens TOTAL — sized to the
-    expected tokens in flight, not the worst case.  ``kv_pool_pages``
-    of 0 = the full contiguous-equivalent reservation (plus the scratch
-    page).  Returns dict with ``kv_bytes_contiguous``,
+    The KV page pool holds ``(kv_pool_pages − 1) × kv_page_size``
+    tokens TOTAL — sized to the expected tokens in flight, not the
+    worst case.  ``kv_pool_pages`` of 0 = one full ``max_seq_len``
+    reservation per slot (plus the scratch page).  Returns dict with
     ``kv_bytes_paged``, ``kv_tokens_capacity`` and the layer geometry —
     serve_main logs it so pool sizing is a visible decision, not a
     guess."""
@@ -172,15 +169,13 @@ def serving_memory_plan(model, *, num_slots: int, max_seq_len: int,
     if params is None:
         params = jax.eval_shape(
             model.init, jax.random.key(0),
-            jax.ShapeDtypeStruct((1, max(kv_page_size, 1)), "int32")
+            jax.ShapeDtypeStruct((1, kv_page_size), "int32")
         )["params"]
     param_bytes = sum(int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
                       for leaf in jax.tree_util.tree_leaves(params))
-    pages_per_slot = -(-max_seq_len // max(kv_page_size, 1))
-    full_pages = 1 + num_slots * pages_per_slot
-    pool_pages = int(kv_pool_pages) or full_pages
-    contiguous_tokens = num_slots * max_seq_len
-    paged_tokens = (pool_pages - 1) * kv_page_size if kv_page_size else 0
+    pages_per_slot = -(-max_seq_len // kv_page_size)
+    pool_pages = int(kv_pool_pages) or 1 + num_slots * pages_per_slot
+    paged_tokens = (pool_pages - 1) * kv_page_size
     mp = max(int(model_parallelism), 1)
     plan = {
         "kv_heads": kv_heads,
@@ -188,27 +183,22 @@ def serving_memory_plan(model, *, num_slots: int, max_seq_len: int,
         "param_bytes": param_bytes,
         "param_bytes_per_device": param_bytes // mp,
         "per_token_kv_bytes": per_token,
-        "kv_bytes_contiguous": contiguous_tokens * per_token,
         "kv_bytes_paged": paged_tokens * per_token,
-        "kv_tokens_capacity": paged_tokens or contiguous_tokens,
-        "pages_per_slot": pages_per_slot if kv_page_size else 0,
-        "pool_pages": pool_pages if kv_page_size else 0,
+        "kv_tokens_capacity": paged_tokens,
+        "pages_per_slot": pages_per_slot,
+        "pool_pages": pool_pages,
         # TP shards the pool's HEAD dim: each of the mp chips holds
         # 1/mp of every page (and of the params) — the lever that
         # makes a too-big-for-one-chip model servable at all
         "model_parallelism": mp,
-        "kv_bytes_per_device":
-            ((paged_tokens or contiguous_tokens) * per_token) // mp,
+        "kv_bytes_per_device": (paged_tokens * per_token) // mp,
     }
     log.info(
         "serving memory plan: %d slots x %d tokens; weights %.1f MB; "
-        "%d KV heads x %d, %d B/token; KV contiguous %.1f "
-        "MB%s%s", num_slots, max_seq_len, param_bytes / 2**20,
-        kv_heads, head_dim, per_token,
-        plan["kv_bytes_contiguous"] / 2**20,
-        (f", paged pool {plan['kv_bytes_paged'] / 2**20:.1f} MB "
-         f"({pool_pages} pages x {kv_page_size} tokens)"
-         if kv_page_size else " (paged cache off)"),
+        "%d KV heads x %d, %d B/token; KV page pool %.1f MB "
+        "(%d pages x %d tokens)%s", num_slots, max_seq_len,
+        param_bytes / 2**20, kv_heads, head_dim, per_token,
+        plan["kv_bytes_paged"] / 2**20, pool_pages, kv_page_size,
         (f", TP={mp}: {plan['kv_bytes_per_device'] / 2**20:.1f} "
          f"MB KV/device" if mp > 1 else ""))
     return plan

@@ -4,59 +4,47 @@ The training forward is teacher-forced: logits for every position in
 one pass.  Serving needs the autoregressive form — one new token per
 step — without recomputing the whole prefix.  The model side lives in
 ``models/transformer.py`` (``decode=True``: every attention keeps a KV
-cache in the 'cache' collection and takes a per-row ``cache_index``);
-this module owns the jit-compiled step functions around it:
+page pool in the 'cache' collection and takes a per-row ``cache_index``
+and ``block_table``); this module owns the jit-compiled step functions
+around it:
 
-  - ``init_cache``      — zeros cache pytree with fixed [B, L] shapes
-  - ``prefill``         — write one padded prompt into one cache slot and
-                          sample the first generated token
-  - ``prefill_chunk``   — (paged mode) write one chunk of a prompt into
-                          the slot's pages; the final chunk also samples
+  - ``prefill_chunk``   — write one chunk of a prompt into the slot's
+                          pages; the final chunk also samples
   - ``decode_step``     — one token for every slot in the batch
   - ``teacher_forced_logits`` — the training-style forward, the oracle
                           the decode path is verified token-exact against
 
-Two cache layouts, selected by ``Decoder(kv_page_size=...)``:
-
-  contiguous (legacy) — [num_slots, max_seq_len, H, Dh] per layer;
-      prefill pads the prompt to max_seq_len and runs ONE dense
-      [L, L]-masked pass.  Simple, but every admit pays O(L²) attention
-      and every slot reserves worst-case HBM.
-  paged — a shared [pool_pages, page_size, H, Dh] pool per layer plus
-      per-slot block tables (ops.paged_attention).  Prefill runs in
-      page-aligned chunks: the FIRST chunk goes through the flash
-      kernel (pure causal self-attention, no gather), later chunks
-      gather the paged prefix.  Work scales with the PROMPT length, not
-      the cache capacity, and the engine can interleave decode steps
-      between chunks.  Compiles once per chunk length (the engine uses
-      one fixed chunk size, so in practice: first-chunk body, continue
-      body, and the short-prompt whole-pad shapes).
+The cache is a shared [pool_pages, page_size, H, Dh] pool per layer
+plus per-slot block tables (ops.paged_attention).  Prefill runs in
+page-aligned chunks: the FIRST chunk goes through the flash kernel
+(pure causal self-attention, no gather), later chunks attend the paged
+prefix.  Work scales with the PROMPT length, not the cache capacity,
+and the engine can interleave decode steps between chunks.  Compiles
+once per chunk length (the engine uses one fixed chunk size, so in
+practice: first-chunk body, continue body, and the short-prompt
+whole-pad shapes).
 
 Everything is shaped for slot-based continuous batching: ``cache_index``
 is [B], and the decode step compiles ONCE (fixed shapes; scalars like
-the slot id and prompt length are traced arrays, never Python ints).
+the chunk's start and sampled position are traced arrays, never Python
+ints).
 
 Sampling: greedy when temperature == 0, else softmax sampling at
-``logits / temperature`` — per-row, so one batch can mix both.
-
-Sampling RNG comes in two forms, and the distinction is a durability
-contract, not a convenience: the legacy ``key`` argument (a single
-PRNG key, split per row) makes a sampled token depend on engine-global
-step order — unreproducible after a failover — while the ``seed`` /
-``seeds`` form derives each sampled position's key as
-``fold_in(key(request_seed), position)``: a pure function of (request
-seed, position).  Two replicas holding identical params re-decoding
-the same request with the same wire-carried seed produce IDENTICAL
-sampled tokens, which is what lets the serving router re-dispatch a
-SAMPLED request token-exactly — the same failover contract greedy
-decode gets for free (serve/router.py).
+``logits / temperature`` — per-row, so one batch can mix both.  The
+key of every sampled position is ``fold_in(key(request_seed),
+position)``: a pure function of (request seed, position), never of
+engine-global step order.  Two replicas holding identical params
+re-decoding the same request with the same wire-carried seed produce
+IDENTICAL sampled tokens, which is what lets the serving router
+re-dispatch a SAMPLED request token-exactly — the same failover
+contract greedy decode gets for free (serve/router.py).
 """
 
 from __future__ import annotations
 
 import functools
 import logging
-from typing import Any, Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -65,8 +53,7 @@ import numpy as np
 log = logging.getLogger("dtf_tpu")
 
 
-def make_decode_model(model, kv_page_size=None, kv_pool_pages=None,
-                      model_axis=None):
+def make_decode_model(model, kv_page_size, kv_pool_pages, model_axis=None):
     """Clone a (training-configured) TransformerLM into decode mode.
 
     The seq axis is stripped (ring attention does not compose with the
@@ -76,8 +63,10 @@ def make_decode_model(model, kv_page_size=None, kv_pool_pages=None,
     the model inside shard_map with the KV pool's head dim sharded).
     Remat is stripped too — there is no backward pass to save memory
     for, and jax.checkpoint does not compose with the mutable cache.
-    ``kv_page_size``/``kv_pool_pages`` select the paged cache layout."""
-    kw = {"decode": True, "model_axis": model_axis}
+    ``kv_page_size``/``kv_pool_pages`` size every layer's page pool."""
+    kw = {"decode": True, "model_axis": model_axis,
+          "kv_page_size": int(kv_page_size),
+          "kv_pool_pages": int(kv_pool_pages)}
     if getattr(model, "seq_axis", None) is not None:
         kw["seq_axis"] = None
     if getattr(model, "shard_vocab", False):
@@ -86,24 +75,7 @@ def make_decode_model(model, kv_page_size=None, kv_pool_pages=None,
         kw["remat"] = False
     if getattr(model, "remat_policy", None) is not None:
         kw["remat_policy"] = None
-    if kv_page_size is not None:
-        kw["kv_page_size"] = int(kv_page_size)
-        kw["kv_pool_pages"] = int(kv_pool_pages)
     return model.clone(**kw)
-
-
-def init_cache(model, num_slots: int, max_seq_len: int):
-    """Zeros KV cache for ``num_slots`` sequences of ≤ ``max_seq_len``
-    tokens.  Shapes come from an eval_shape of the decode model's init
-    (no params are materialized); values are zeros by construction."""
-    decode_model = make_decode_model(model)
-    tokens = jax.ShapeDtypeStruct((num_slots, max_seq_len), jnp.int32)
-    idx = jax.ShapeDtypeStruct((num_slots,), jnp.int32)
-    shapes = jax.eval_shape(
-        functools.partial(decode_model.init, jax.random.key(0)),
-        tokens, cache_index=idx)["cache"]
-    return jax.tree_util.tree_map(
-        lambda s: jnp.zeros(s.shape, s.dtype), shapes)
 
 
 def paged_cache_shapes(model, kv_page_size: int, kv_pool_pages: int):
@@ -111,8 +83,7 @@ def paged_cache_shapes(model, kv_page_size: int, kv_pool_pages: int):
     [kv_pool_pages, kv_page_size, H, Dh] pool per layer per K/V, from
     an eval_shape of the paged decode model's init (no params — and no
     cache — materialized)."""
-    decode_model = make_decode_model(model, kv_page_size=kv_page_size,
-                                     kv_pool_pages=kv_pool_pages)
+    decode_model = make_decode_model(model, kv_page_size, kv_pool_pages)
     tokens = jax.ShapeDtypeStruct((1, kv_page_size), jnp.int32)
     idx = jax.ShapeDtypeStruct((1,), jnp.int32)
     table = jax.ShapeDtypeStruct((1, 1), jnp.int32)
@@ -153,28 +124,25 @@ _seed_row_keys = jax.jit(jax.vmap(position_key))
 
 
 class Decoder:
-    """Jitted prefill/decode pair bound to one model + param set.
+    """Jitted prefill-chunk/decode pair bound to one model + param set.
 
     ``params`` may include 'batch_stats' siblings conceptually, but the
     LM family is LN-only — only 'params' is applied.
 
-    ``kv_page_size`` selects the paged cache (None = contiguous):
-    ``kv_pool_pages`` TOTAL pool pages including the scratch page 0
-    (None = full reservation, 1 + num_slots × pages-per-slot — the
-    engine shrinks it to provision for tokens in flight).
+    ``kv_page_size`` is the page's tokens; ``kv_pool_pages`` the TOTAL
+    pool pages including the scratch page 0 (None = full reservation,
+    1 + num_slots × pages-per-slot — the engine shrinks it to provision
+    for tokens in flight).
 
     ``mesh`` selects TENSOR-PARALLEL decode: a runtime mesh whose
     'model' axis has size > 1.  Params shard per
     ``param_partition_specs`` (heads/ff column-parallel, out/fc2
     row-parallel) and each layer's KV page pool shards its HEAD dim —
     every apply runs inside shard_map, tokens/block tables replicated,
-    logits replicated out (the last block exits through tp_psum).
-    Paged cache only: the page pool is the layout built for
-    production serving, and sharding the contiguous per-slot slabs
-    would buy nothing the pool doesn't."""
+    logits replicated out (the last block exits through tp_psum)."""
 
     def __init__(self, model, params, *, num_slots: int, max_seq_len: int,
-                 kv_page_size: Optional[int] = None,
+                 kv_page_size: int,
                  kv_pool_pages: Optional[int] = None, mesh=None,
                  ledger=None):
         from dtf_tpu.runtime.mesh import MODEL_AXIS
@@ -199,70 +167,58 @@ class Decoder:
             raise ValueError(
                 f"max_seq_len {max_seq_len} exceeds the model's position "
                 f"table ({model.max_seq_len})")
-        self.paged = kv_page_size is not None
-        if self.tp > 1 and not self.paged:
-            raise ValueError(
-                "tensor-parallel decode needs the paged KV cache "
-                "(kv_page_size > 0) — the page pool is the layout that "
-                "shards")
         if self.tp > 1 and model.num_heads % self.tp:
             raise ValueError(
                 f"num_heads {model.num_heads} not divisible by the "
                 f"mesh's model axis ({self.tp})")
-        if self.paged:
-            self.page_size = int(kv_page_size)
-            if self.page_size < 1:
-                raise ValueError(f"kv_page_size must be >= 1, got "
-                                 f"{kv_page_size}")
-            self.pages_per_slot = -(-self.max_seq_len // self.page_size)
-            self.pool_pages = int(
-                kv_pool_pages or 1 + self.num_slots * self.pages_per_slot)
-            if self.pool_pages < 2:
-                raise ValueError(
-                    f"kv_pool_pages must be >= 2 (page 0 is the scratch "
-                    f"page), got {self.pool_pages}")
-            self.model = make_decode_model(
-                model, kv_page_size=self.page_size,
-                kv_pool_pages=self.pool_pages,
-                model_axis=self._model_axis)
-            if self.tp > 1:
-                params = self._shard_params(params)
-            # window_pages / flash_prefill are STATIC (they select the
-            # attention formulation and the gather extent); start is
-            # TRACED.  Gather path: window_pages = the chunk's visible
-            # pages → one compile per (chunk shape, window), buying the
-            # O(prompt²/2) static trim.  Kernel path: the kernel trims
-            # dynamically (its loop over a row's pages ends at the
-            # row's own length), so prefill_chunk
-            # passes window_pages=None and the body compiles ONCE per
-            # chunk shape — the per-chunk-index compile storm is gone,
-            # not just the gather
-            self._chunk = jax.jit(self._chunk_impl, donate_argnums=(1,),
-                                  static_argnums=(8, 9))
-            up = getattr(self.model, "use_pallas", None)
-            self._kernel_attn = bool(
-                up if up is not None
-                else jax.default_backend() == "tpu")
-            self._decode = jax.jit(self._decode_paged_impl,
-                                   donate_argnums=(1,))
-            # COW page copy (engine prefix sharing): one whole
-            # [page_size, H, Dh] row per layer per K/V — page dim is
-            # unsharded, so the copy is shard-local under TP too
-            self._copy_page = jax.jit(
-                lambda cache, src, dst: jax.tree_util.tree_map(
-                    lambda c: c.at[dst].set(c[src]), cache),
-                donate_argnums=(0,))
-            # migration import: write a host page payload (one
-            # [page_size, H, Dh] row per leaf) into pool page ``dst``
-            self._write_page = jax.jit(
-                lambda cache, dst, payload: jax.tree_util.tree_map(
-                    lambda c, p: c.at[dst].set(p.astype(c.dtype)),
-                    cache, payload),
-                donate_argnums=(0,))
-        else:
-            self.model = make_decode_model(model)
-            self._prefill = jax.jit(self._prefill_impl, donate_argnums=(1,))
-            self._decode = jax.jit(self._decode_impl, donate_argnums=(1,))
+        if kv_page_size is None or int(kv_page_size) < 1:
+            raise ValueError(f"kv_page_size must be >= 1, got "
+                             f"{kv_page_size}")
+        self.page_size = int(kv_page_size)
+        self.pages_per_slot = -(-self.max_seq_len // self.page_size)
+        self.pool_pages = int(
+            kv_pool_pages or 1 + self.num_slots * self.pages_per_slot)
+        if self.pool_pages < 2:
+            raise ValueError(
+                f"kv_pool_pages must be >= 2 (page 0 is the scratch "
+                f"page), got {self.pool_pages}")
+        self.model = make_decode_model(
+            model, self.page_size, self.pool_pages,
+            model_axis=self._model_axis)
+        if self.tp > 1:
+            params = self._shard_params(params)
+        # window_pages / flash_prefill are STATIC (they select the
+        # attention formulation and the gather extent); start is
+        # TRACED.  Gather path: window_pages = the chunk's visible
+        # pages → one compile per (chunk shape, window), buying the
+        # O(prompt²/2) static trim.  Kernel path: the kernel trims
+        # dynamically (its loop over a row's pages ends at the
+        # row's own length), so prefill_chunk
+        # passes window_pages=None and the body compiles ONCE per
+        # chunk shape — the per-chunk-index compile storm is gone,
+        # not just the gather
+        self._chunk = jax.jit(self._chunk_impl, donate_argnums=(1,),
+                              static_argnums=(8, 9))
+        up = getattr(self.model, "use_pallas", None)
+        self._kernel_attn = bool(
+            up if up is not None
+            else jax.default_backend() == "tpu")
+        self._decode = jax.jit(self._decode_paged_impl,
+                               donate_argnums=(1,))
+        # COW page copy (engine prefix sharing): one whole
+        # [page_size, H, Dh] row per layer per K/V — page dim is
+        # unsharded, so the copy is shard-local under TP too
+        self._copy_page = jax.jit(
+            lambda cache, src, dst: jax.tree_util.tree_map(
+                lambda c: c.at[dst].set(c[src]), cache),
+            donate_argnums=(0,))
+        # migration import: write a host page payload (one
+        # [page_size, H, Dh] row per leaf) into pool page ``dst``
+        self._write_page = jax.jit(
+            lambda cache, dst, payload: jax.tree_util.tree_map(
+                lambda c, p: c.at[dst].set(p.astype(c.dtype)),
+                cache, payload),
+            donate_argnums=(0,))
         self.params = params
 
     # -- tensor-parallel plumbing --------------------------------------
@@ -313,31 +269,29 @@ class Decoder:
             check_vma=False)(params, cache, tokens, index, block_table)
 
     def fresh_cache(self):
-        if self.paged:
-            if self.tp > 1:
-                # global-shaped zeros (full head count) created
-                # DIRECTLY sharded on the pool head dim via jit
-                # out_shardings — each device materializes only its
-                # own shard.  A replicated zeros-then-device_put would
-                # allocate the FULL pool on one chip first, the exact
-                # never-fits-on-one-chip trap the sharded params
-                # restore avoids.  Shapes come from a single-device
-                # clone because the TP model's init cannot trace
-                # outside shard_map (unbound axis)
-                from jax.sharding import NamedSharding
+        if self.tp > 1:
+            # global-shaped zeros (full head count) created
+            # DIRECTLY sharded on the pool head dim via jit
+            # out_shardings — each device materializes only its
+            # own shard.  A replicated zeros-then-device_put would
+            # allocate the FULL pool on one chip first, the exact
+            # never-fits-on-one-chip trap the sharded params
+            # restore avoids.  Shapes come from a single-device
+            # clone because the TP model's init cannot trace
+            # outside shard_map (unbound axis)
+            from jax.sharding import NamedSharding
 
-                base = self.model.clone(model_axis=None)
-                shapes = paged_cache_shapes(base, self.page_size,
-                                            self.pool_pages)
-                sharding = NamedSharding(self.mesh, self._cache_pspec())
-                return jax.jit(
-                    lambda: jax.tree_util.tree_map(
-                        lambda s: jnp.zeros(s.shape, s.dtype), shapes),
-                    out_shardings=jax.tree_util.tree_map(
-                        lambda _: sharding, shapes))()
-            return init_paged_cache(self.model, self.page_size,
-                                    self.pool_pages)
-        return init_cache(self.model, self.num_slots, self.max_seq_len)
+            base = self.model.clone(model_axis=None)
+            shapes = paged_cache_shapes(base, self.page_size,
+                                        self.pool_pages)
+            sharding = NamedSharding(self.mesh, self._cache_pspec())
+            return jax.jit(
+                lambda: jax.tree_util.tree_map(
+                    lambda s: jnp.zeros(s.shape, s.dtype), shapes),
+                out_shardings=jax.tree_util.tree_map(
+                    lambda _: sharding, shapes))()
+        return init_paged_cache(self.model, self.page_size,
+                                self.pool_pages)
 
     def copy_page(self, cache, src: int, dst: int):
         """Physically copy pool page ``src`` onto ``dst`` in every
@@ -356,8 +310,6 @@ class Decoder:
         device_get, no casts or layout changes: the bytes are exactly
         what the device holds, which is what the bit-identity contract
         on migrated pages is built on."""
-        if not self.paged:
-            raise RuntimeError("page migration needs the paged cache")
         return [np.asarray(jax.device_get(c[int(page)]))
                 for c in jax.tree_util.tree_leaves(cache)]
 
@@ -367,8 +319,6 @@ class Decoder:
         primitive.  The pool's page dim is unsharded under TP (the
         head dim shards), so a whole-page write lowers to shard-local
         updates, same as :meth:`copy_page`."""
-        if not self.paged:
-            raise RuntimeError("page migration needs the paged cache")
         treedef = jax.tree_util.tree_structure(cache)
         payload = jax.tree_util.tree_unflatten(
             treedef, [jnp.asarray(a) for a in leaves])
@@ -405,42 +355,6 @@ class Decoder:
         return compiled
 
     # -- jitted bodies -------------------------------------------------
-    def _prefill_impl(self, params, cache, tokens, slot, length,
-                      temperature, key):
-        """tokens [1, max_seq_len] (prompt padded with zeros), slot/
-        length scalar arrays.  Writes the slot's cache row, returns
-        (first generated token scalar, new cache, last-position logits).
-        """
-        row = jax.tree_util.tree_map(
-            lambda c: jax.lax.dynamic_slice_in_dim(c, slot, 1, axis=0),
-            cache)
-        logits, mut = self.model.apply(
-            {"params": params, "cache": row}, tokens,
-            cache_index=jnp.zeros((1,), jnp.int32), mutable=["cache"])
-        cache = jax.tree_util.tree_map(
-            lambda c, r: jax.lax.dynamic_update_slice_in_dim(
-                c, r, slot, axis=0),
-            cache, mut["cache"])
-        # next token comes from the last REAL prompt position
-        last = jax.lax.dynamic_slice_in_dim(
-            logits[0], length - 1, 1, axis=0)[0]          # [V]
-        tok = _sample(last, temperature, key)
-        return tok, cache, last
-
-    def _decode_impl(self, params, cache, tokens, index, temperature,
-                     rowkeys):
-        """tokens [B, 1] (the previous step's output per slot), index [B]
-        current lengths, temperature [B], rowkeys [B] per-row sampling
-        keys.  One step for every slot — inactive slots decode garbage
-        that the engine ignores."""
-        logits, mut = self.model.apply(
-            {"params": params, "cache": cache}, tokens,
-            cache_index=index, mutable=["cache"])
-        last = logits[:, -1]                               # [B, V]
-        toks = jax.vmap(_sample)(last, temperature, rowkeys)
-        return toks, mut["cache"], last
-
-    # -- paged jitted bodies -------------------------------------------
     def _chunk_impl(self, params, cache, tokens, block_row, sample_pos,
                     temperature, key, start, window_pages, flash_prefill):
         """One prefill chunk.  tokens [1, C] (page-aligned, tail-padded
@@ -467,7 +381,9 @@ class Decoder:
         """tokens [B, 1], index [B], block_tables [B, M] — rows not in
         decode phase carry an ALL-ZEROS block row, steering their
         garbage write/gather at the scratch page (ops.paged_attention).
-        ``rowkeys`` [B] are the per-row sampling keys."""
+        ``rowkeys`` [B] are the per-row sampling keys.  (The function's
+        name is the compiled program's — ``jit__decode_paged_impl`` in
+        profiles and compile-cache keys — and is kept for that.)"""
         logits, mut = self._apply_model(
             params, cache, tokens, index, block_tables, False, None)
         last = logits[:, -1]                               # [B, V]
@@ -475,41 +391,9 @@ class Decoder:
         return toks, mut["cache"], last, mut.get("stats")
 
     # -- public API ----------------------------------------------------
-    def prefill(self, cache, prompt, slot: int, temperature: float,
-                key=None, seed=None) -> Tuple[Any, Any, Any]:
-        """prompt: 1-D int32 (unpadded).  Returns (token, cache, logits)
-        with the first sampled token as a device scalar.  Contiguous
-        mode only — paged prefill goes through :meth:`prefill_chunk`.
-
-        Pass exactly one of ``key`` (a PRNG key — legacy, step-order-
-        dependent sampling) or ``seed`` (a per-request int: the sampled
-        token becomes a pure function of (seed, position) — the
-        failover-exactness form)."""
-        if self.paged:
-            raise RuntimeError("paged Decoder: use prefill_chunk")
-        prompt = np.asarray(prompt, np.int32)
-        if prompt.ndim != 1 or prompt.shape[0] == 0:
-            raise ValueError("prompt must be a non-empty 1-D token array")
-        length = int(prompt.shape[0])
-        if (key is None) == (seed is None):
-            raise ValueError("pass exactly one of key= or seed=")
-        if seed is not None:
-            key = position_key(int(seed), length - 1)
-        if length > self.max_seq_len:
-            raise ValueError(
-                f"prompt length {length} exceeds max_seq_len "
-                f"{self.max_seq_len}")
-        padded = np.zeros((1, self.max_seq_len), np.int32)
-        padded[0, :length] = prompt
-        return self._prefill(self.params, cache, jnp.asarray(padded),
-                             jnp.asarray(slot, jnp.int32),
-                             jnp.asarray(length, jnp.int32),
-                             jnp.asarray(temperature, jnp.float32), key)
-
     def prefill_chunk(self, cache, chunk, block_row, start: int,
-                      sample_pos: int, temperature: float, key=None,
-                      seed=None):
-        """One page-aligned prefill chunk for one slot (paged mode).
+                      sample_pos: int, temperature: float, seed: int):
+        """One page-aligned prefill chunk for one slot.
 
         chunk: 1-D int32, len(chunk) % page_size == 0 (engine-padded);
         block_row: [M] int32 page ids for the slot; start: the chunk's
@@ -517,15 +401,12 @@ class Decoder:
         the last REAL prompt token (engine passes 0 for non-final
         chunks and ignores the sampled token).  Returns (token, cache,
         logits) — the first-chunk (start == 0) body routes attention
-        through the flash kernel; continuation chunks gather the paged
-        prefix.  Exactly one of ``key``/``seed`` (see :meth:`prefill`);
-        the seed form keys the sample to the chunk's GLOBAL sampled
-        position, so every chunking of a prompt samples identically."""
+        through the flash kernel; continuation chunks attend the paged
+        prefix.  ``seed`` is the request's: the sample is keyed to the
+        chunk's GLOBAL sampled position, so every chunking of a prompt
+        samples identically."""
         chunk = np.asarray(chunk, np.int32).reshape(1, -1)
-        if (key is None) == (seed is None):
-            raise ValueError("pass exactly one of key= or seed=")
-        if seed is not None:
-            key = position_key(int(seed), int(start) + int(sample_pos))
+        key = position_key(int(seed), int(start) + int(sample_pos))
         if chunk.shape[1] % self.page_size or start % self.page_size:
             raise ValueError(
                 f"prefill chunk (len {chunk.shape[1]}, start {start}) "
@@ -558,43 +439,27 @@ class Decoder:
         tok, cache, last, self.last_stats = fn(*dyn)
         return tok, cache, last
 
-    def decode_step(self, cache, tokens, index, temperature, key=None,
-                    block_tables=None, seeds=None):
-        """tokens [B], index [B], temperature [B] → (tokens [B], cache,
-        logits [B, V]).  Paged mode additionally takes ``block_tables``
-        [B, M] (all-zeros rows for slots not decoding).
-
-        Exactly one of ``key`` (single PRNG key, split per row —
-        legacy) or ``seeds`` ([B] per-request ints: row b samples with
-        ``fold_in(key(seeds[b]), index[b])``, a pure function of the
-        request's seed and position — the failover-exactness form).
-        Both feed the SAME compiled body (a [B] key array), so the
-        choice never costs a recompile."""
+    def decode_step(self, cache, tokens, index, temperature, seeds,
+                    block_tables):
+        """tokens [B], index [B], temperature [B], seeds [B] per-request
+        ints, block_tables [B, M] (all-zeros rows for slots not
+        decoding) → (tokens [B], cache, logits [B, V]).  Row b samples
+        with ``fold_in(key(seeds[b]), index[b])``, a pure function of
+        the request's seed and position."""
         tokens = jnp.asarray(tokens, jnp.int32).reshape(-1, 1)
         index = jnp.asarray(index, jnp.int32)
         temperature = jnp.asarray(temperature, jnp.float32)
-        if (key is None) == (seeds is None):
-            raise ValueError("pass exactly one of key= or seeds=")
-        if seeds is not None:
-            rowkeys = _seed_row_keys(
-                jnp.asarray(seeds, jnp.uint32), index)
-        else:
-            rowkeys = jax.random.split(key, tokens.shape[0])
-        if self.paged:
-            if block_tables is None:
-                raise ValueError("paged decode_step needs block_tables")
-            dyn = (self.params, cache, tokens, index,
-                   jnp.asarray(block_tables, jnp.int32), temperature,
-                   rowkeys)
-            fn = self._execs.get("decode")
-            if fn is None:
-                fn = (self._aot("serve_decode_step", self._decode, dyn)
-                      or self._decode)
-                self._execs["decode"] = fn
-            toks, cache, last, self.last_stats = fn(*dyn)
-            return toks, cache, last
-        return self._decode(self.params, cache, tokens, index,
-                            temperature, rowkeys)
+        rowkeys = _seed_row_keys(jnp.asarray(seeds, jnp.uint32), index)
+        dyn = (self.params, cache, tokens, index,
+               jnp.asarray(block_tables, jnp.int32), temperature,
+               rowkeys)
+        fn = self._execs.get("decode")
+        if fn is None:
+            fn = (self._aot("serve_decode_step", self._decode, dyn)
+                  or self._decode)
+            self._execs["decode"] = fn
+        toks, cache, last, self.last_stats = fn(*dyn)
+        return toks, cache, last
 
 
 def teacher_forced_logits(model, params, tokens):

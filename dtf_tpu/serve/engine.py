@@ -21,15 +21,16 @@ with the standard production recipe:
       coexist, finish independently, and free their slot for the next
       queued request without draining the batch.
 
-With the PAGED KV cache (``kv_page_size``, the default) two more
-production levers land:
+The KV cache is PAGED (``kv_page_size`` tokens a page), which brings
+two more production levers:
 
   paged admission — HBM is a shared page pool (:class:`PagePool`), and
       a request is admitted when its worst-case page count
       (⌈(prompt + budget) / page_size⌉) is free — so concurrency is
       bounded by TOKENS IN FLIGHT, not num_slots × max_seq_len.  A
-      pool sized at 50% of the contiguous reservation serves the same
-      slot count whenever mean request length < 50% of max_seq_len.
+      pool sized at 50% of one full reservation per slot serves the
+      same slot count whenever mean request length < 50% of
+      max_seq_len.
       When the head of the queue cannot get pages it WAITS (FIFO —
       large requests are not starved by small ones slipping past);
       retiring slots free their pages for the next admit.
@@ -42,7 +43,7 @@ production levers land:
       through the flash kernel (no cache gather at all), so short
       prompts — the common case — never touch the gather path.
 
-With prefix sharing (on by default in paged mode) the pool pages are
+With prefix sharing (on by default) the pool pages are
 REFCOUNTED and a registry keyed by token-id hash maps every request's
 full prompt-prefix pages to their physical pages:
 
@@ -477,10 +478,9 @@ class _Slot:
     tokens: List[int]                   # generated so far
     last_token: int                     # next decode step's input
     index: int                          # current sequence length
+    pages: List[int]                    # pool pages owned by this slot
+    block_row: np.ndarray               # [M] int32 page ids
     phase: str = "decode"               # "prefill" until the prompt is in
-    # paged mode:
-    pages: Optional[List[int]] = None   # pool pages owned by this slot
-    block_row: Optional[np.ndarray] = None  # [M] int32 page ids
     prompt_padded: Optional[np.ndarray] = None  # page-aligned prompt
     chunk_plan: Optional[List] = None   # [(start, len), ...]
     chunk_i: int = 0                    # next chunk to run
@@ -493,19 +493,18 @@ class ServeEngine:
     its param pytree (from serve.bridge).  ``max_seq_len`` bounds
     prompt + generation per request and fixes the cache shapes.
 
-    ``kv_page_size`` selects the paged KV cache (the default; 0/None =
-    the contiguous per-slot layout).  ``kv_pool_pages`` sizes the
-    shared pool in TOTAL pages incl. the scratch page (0/None = the
-    full contiguous-equivalent reservation; size it down to provision
-    for actual tokens in flight).  ``prefill_chunk`` is the chunked-
-    prefill unit in tokens (multiple of the page size; 0 = whole
-    prompts prefill as one page-aligned chunk; None = the default,
-    4 pages).
+    ``kv_page_size`` is the KV page's tokens (>= 1).
+    ``kv_pool_pages`` sizes the shared pool in TOTAL pages incl. the
+    scratch page (0/None = one full ``max_seq_len`` reservation per
+    slot; size it down to provision for actual tokens in flight).
+    ``prefill_chunk`` is the chunked-prefill unit in tokens (multiple
+    of the page size; 0 = whole prompts prefill as one page-aligned
+    chunk; None = the default, 4 pages).
 
-    ``prefix_sharing`` (paged mode, default on) shares full
-    prompt-prefix pages across requests via the refcounted pool +
-    prefix registry (module docstring).  ``mesh`` selects
-    tensor-parallel decode (paged mode; serve/decode.py Decoder).
+    ``prefix_sharing`` (default on) shares full prompt-prefix pages
+    across requests via the refcounted pool + prefix registry (module
+    docstring).  ``mesh`` selects tensor-parallel decode
+    (serve/decode.py Decoder).
 
     ``heartbeat`` (obs.watchdog.Heartbeat) is beaten once per ENGINE
     ITERATION with step = completed-request count — serving liveness
@@ -532,59 +531,45 @@ class ServeEngine:
     def __init__(self, model, params, *, max_batch: int = 8,
                  max_seq_len: Optional[int] = None,
                  max_delay_s: float = 0.005, queue_size: int = 64,
-                 seed: int = 0, kv_page_size: Optional[int] = 16,
+                 seed: int = 0, kv_page_size: int = 16,
                  kv_pool_pages: Optional[int] = None,
                  prefill_chunk: Optional[int] = None,
                  prefix_sharing: bool = True, mesh=None,
                  heartbeat=None):
         if max_batch < 1 or queue_size < 1:
             raise ValueError("max_batch and queue_size must be >= 1")
+        if not kv_page_size or int(kv_page_size) < 1:
+            raise ValueError(f"kv_page_size must be >= 1, got "
+                             f"{kv_page_size!r}")
         self.max_batch = int(max_batch)
         self.max_seq_len = int(max_seq_len or model.max_seq_len)
         self.max_delay_s = float(max_delay_s)
         self.queue_size = int(queue_size)
-        self.paged = bool(kv_page_size)
         # metrics registry must exist before the decoder: the MFU/cost
         # ledger (obs/ledger.py) exports through it, and the decoder
         # registers each compiled body's XLA flop/byte counts there
         self.metrics = MetricsRegistry()
         self.ledger = Ledger(self.metrics)
-        if self.paged:
-            self.page_size = int(kv_page_size)
-            # None = default (DEFAULT_PREFILL_PAGES); 0 = whole-prompt
-            # single chunks
-            self.prefill_chunk = (DEFAULT_PREFILL_PAGES * self.page_size
-                                  if prefill_chunk is None
-                                  else int(prefill_chunk))
-            if self.prefill_chunk and self.prefill_chunk % self.page_size:
-                raise ValueError(
-                    f"prefill_chunk ({self.prefill_chunk}) must be a "
-                    f"multiple of kv_page_size ({self.page_size})")
-            self.decoder = Decoder(
-                model, params, num_slots=self.max_batch,
-                max_seq_len=self.max_seq_len,
-                kv_page_size=self.page_size,
-                kv_pool_pages=(int(kv_pool_pages) if kv_pool_pages
-                               else None), mesh=mesh,
-                ledger=self.ledger)
-            self.pool = PagePool(self.decoder.pool_pages)
-            self.prefix_sharing = bool(prefix_sharing)
-            self.registry = PrefixRegistry(self.page_size)
-        else:
-            if mesh is not None:
-                raise ValueError("tensor-parallel serving needs the "
-                                 "paged cache (kv_page_size > 0)")
-            self.prefix_sharing = False
-            self.registry = None
-            # None is the only "unset" value — an explicit chunk size
-            # (including 0) with the contiguous cache is a
-            # contradiction, rejected loudly regardless of its value
-            if kv_pool_pages or prefill_chunk is not None:
-                raise ValueError("kv_pool_pages / prefill_chunk need the "
-                                 "paged cache (kv_page_size > 0)")
-            self.decoder = Decoder(model, params, num_slots=self.max_batch,
-                                   max_seq_len=self.max_seq_len)
-            self.pool = None
+        self.page_size = int(kv_page_size)
+        # None = default (DEFAULT_PREFILL_PAGES); 0 = whole-prompt
+        # single chunks
+        self.prefill_chunk = (DEFAULT_PREFILL_PAGES * self.page_size
+                              if prefill_chunk is None
+                              else int(prefill_chunk))
+        if self.prefill_chunk and self.prefill_chunk % self.page_size:
+            raise ValueError(
+                f"prefill_chunk ({self.prefill_chunk}) must be a "
+                f"multiple of kv_page_size ({self.page_size})")
+        self.decoder = Decoder(
+            model, params, num_slots=self.max_batch,
+            max_seq_len=self.max_seq_len,
+            kv_page_size=self.page_size,
+            kv_pool_pages=(int(kv_pool_pages) if kv_pool_pages
+                           else None), mesh=mesh,
+            ledger=self.ledger)
+        self.pool = PagePool(self.decoder.pool_pages)
+        self.prefix_sharing = bool(prefix_sharing)
+        self.registry = PrefixRegistry(self.page_size)
         self._cache = self.decoder.fresh_cache()
         # base for per-request sampling seeds (requests that arrive
         # without one): a pure function of (engine seed, request id),
@@ -625,7 +610,7 @@ class ServeEngine:
             "serve_queue_depth_sampled", unit="requests")
         self._m_occ_sampled = self.metrics.histogram(
             "serve_slot_occupancy_sampled", unit="fraction")
-        # paged-cache operational signals: pool occupancy (gauge + per-
+        # page-pool operational signals: pool occupancy (gauge + per-
         # iteration samples), prefill chunks run, and the decode-step
         # GAP — wall time between consecutive decode steps while slots
         # are decoding.  The gap p99 is the head-of-line-blocking
@@ -729,8 +714,7 @@ class ServeEngine:
             self._m_decode_gap.reset()
             self._last_step_t = None
             self.max_concurrent = 0
-            if self.pool is not None:
-                self.pool.high_water = self.pool.used_pages
+            self.pool.high_water = self.pool.used_pages
             return len(self.completed)
 
     # -- KV-page migration surface (serve/migrate.py) ------------------
@@ -791,7 +775,7 @@ class ServeEngine:
         (pages, chained digests); release with
         :meth:`export_chain_end` (transfer done OR aborted — the hold
         must not outlive its transfer)."""
-        if not self.paged or not self.prefix_sharing:
+        if not self.prefix_sharing:
             return [], []
         prompt = np.asarray(prompt, np.int32).reshape(-1)
 
@@ -840,9 +824,8 @@ class ServeEngine:
         registry's holder — after import the pages are ordinary warm
         registry pages (refcount 1, evictable under pressure).
         Returns the number of pages imported."""
-        if not self.paged or not self.prefix_sharing:
-            raise RuntimeError("page import needs the paged cache with "
-                               "prefix sharing on")
+        if not self.prefix_sharing:
+            raise RuntimeError("page import needs prefix sharing on")
         prompt = np.asarray(prompt, np.int32).reshape(-1)
 
         def job():
@@ -912,15 +895,14 @@ class ServeEngine:
                 f"max_new_tokens ({max_new_tokens}) = {total} exceeds "
                 f"max_seq_len {self.max_seq_len}; shorten the prompt or "
                 f"lower the budget")
-        if self.paged:
-            need = -(-total // self.page_size)
-            if need > self.pool.usable_pages:
-                raise ValueError(
-                    f"oversized request for the page pool: needs {need} "
-                    f"pages of {self.page_size} tokens but the pool has "
-                    f"{self.pool.usable_pages} usable — it could never "
-                    f"be admitted; grow --kv_pool_pages or shrink the "
-                    f"request")
+        need = -(-total // self.page_size)
+        if need > self.pool.usable_pages:
+            raise ValueError(
+                f"oversized request for the page pool: needs {need} "
+                f"pages of {self.page_size} tokens but the pool has "
+                f"{self.pool.usable_pages} usable — it could never "
+                f"be admitted; grow --kv_pool_pages or shrink the "
+                f"request")
         if trace_id is None and trace.enabled():
             trace_id = trace.new_trace_id()
         req = ServeRequest(prompt=prompt, max_new_tokens=int(max_new_tokens),
@@ -1076,33 +1058,31 @@ class ServeEngine:
                 admitted = []
                 for i, slot in enumerate(self._slots):
                     if slot is None and self._pending:
-                        grant = None
-                        if self.paged:
-                            req = self._pending[0].request
-                            shared, need, cow = self._admission_plan(req)
-                            # hold the shared pages BEFORE any alloc/
-                            # eviction: a registry-only page this admit
-                            # is about to share must not be evicted out
-                            # from under it
-                            self.pool.share(shared)
+                        req = self._pending[0].request
+                        shared, need, cow = self._admission_plan(req)
+                        # hold the shared pages BEFORE any alloc/
+                        # eviction: a registry-only page this admit
+                        # is about to share must not be evicted out
+                        # from under it
+                        self.pool.share(shared)
+                        pages = self.pool.alloc(need)
+                        if pages is None:
+                            self._evict_for(need)
                             pages = self.pool.alloc(need)
-                            if pages is None:
-                                self._evict_for(need)
-                                pages = self.pool.alloc(need)
-                            if pages is None:
-                                # head-of-line FIFO wait: the next
-                                # retire frees pages; small requests do
-                                # NOT slip past a starved big one.
-                                # Un-hold the speculative shares (the
-                                # registry's own holder keeps them
-                                # warm for the retry)
-                                for p in self.pool.free(shared):
-                                    self.registry.drop_page(p)
-                                break
-                            if shared:
-                                self._m_prefix_hits.inc(len(shared))
-                            grant = (pages, shared, cow)
-                        admitted.append((i, self._pending.pop(0), grant))
+                        if pages is None:
+                            # head-of-line FIFO wait: the next
+                            # retire frees pages; small requests do
+                            # NOT slip past a starved big one.
+                            # Un-hold the speculative shares (the
+                            # registry's own holder keeps them
+                            # warm for the retry)
+                            for p in self.pool.free(shared):
+                                self.registry.drop_page(p)
+                            break
+                        if shared:
+                            self._m_prefix_hits.inc(len(shared))
+                        admitted.append((i, self._pending.pop(0),
+                                         (pages, shared, cow)))
                 pending_depth = len(self._pending)
                 self._m_queue_depth.set(pending_depth)
             if self._stop.is_set() and not any(
@@ -1110,8 +1090,8 @@ class ServeEngine:
                 return
             if admitted:
                 # batch formation: bind each admitted request to its
-                # slot (contiguous: full prefill here; paged: allocate +
-                # plan chunks, prefill advances below — interleaved).
+                # slot (pages granted above; plan chunks here, prefill
+                # advances below — interleaved with decode steps).
                 # The span carries the admitted requests' trace ids so
                 # `trace_main --request` finds the batch work a request
                 # rode in (a batch span serves MANY requests — a list,
@@ -1123,8 +1103,8 @@ class ServeEngine:
                     if tids:
                         attrs["traces"] = tids
                 with trace.span("serve_batch_form", **attrs):
-                    for i, handle, pages in admitted:
-                        self._admit(i, handle, pages)
+                    for i, handle, grant in admitted:
+                        self._admit(i, handle, grant)
                 self._m_admitted.inc(len(admitted))
             # cancellation sweep (running half): a cancelled slot
             # retires NOW — pages back to the pool, the slot to the
@@ -1150,16 +1130,14 @@ class ServeEngine:
                            for s in self._slots)
             self.max_concurrent = max(self.max_concurrent, active)
             self._m_occupancy.set(active / self.max_batch)
-            if self.paged:
-                self._m_pages_used.set(self.pool.used_pages)
-                self._m_shared.set(self.pool.shared_refs)
+            self._m_pages_used.set(self.pool.used_pages)
+            self._m_shared.set(self.pool.shared_refs)
             if active:
                 self._m_occ_sampled.observe(active / self.max_batch)
                 # pending_depth was read under the lock above — the
                 # list mutates under _cond, so len() here would race
                 self._m_queue_sampled.observe(pending_depth)
-                if self.paged:
-                    self._m_pages_sampled.observe(self.pool.used_pages)
+                self._m_pages_sampled.observe(self.pool.used_pages)
             if decoding:
                 self._step()
             else:
@@ -1223,30 +1201,15 @@ class ServeEngine:
     def _admit(self, slot_idx: int, handle: _Handle, grant):
         req = handle.request
         req.admit_time = time.time()
+        fresh, shared, cow = grant
         if req.trace_id is not None:
-            attrs = {}
-            if self.paged and grant is not None:
-                # prefix-share depth in TOKENS (pages are an engine
-                # detail; the capacity simulator replays recorded hits
-                # without knowing this engine's page size)
-                attrs["shared_tokens"] = len(grant[1]) * self.page_size
+            # prefix-share depth in TOKENS (pages are an engine
+            # detail; the capacity simulator replays recorded hits
+            # without knowing this engine's page size)
             trace.event("serve_admit", request=req.id, slot=slot_idx,
                         queue_wait_s=req.admit_time - req.submit_time,
-                        **attrs, **_tctx(req.trace_id, req.trace_parent))
-        if not self.paged:
-            tok, self._cache, _ = self.decoder.prefill(
-                self._cache, req.prompt, slot_idx, req.temperature,
-                seed=req.rng_seed)
-            first = int(tok)
-            req.first_token_time = time.time()
-            slot = _Slot(handle=handle, tokens=[first], last_token=first,
-                         index=int(req.prompt.size))
-            self._slots[slot_idx] = slot
-            handle._emit(first)
-            if self._finished(slot):
-                self._retire(slot_idx)
-            return
-        fresh, shared, cow = grant
+                        shared_tokens=len(shared) * self.page_size,
+                        **_tctx(req.trace_id, req.trace_parent))
         plen = int(req.prompt.size)
         ps = self.page_size
         fresh = list(fresh)
@@ -1349,20 +1312,17 @@ class ServeEngine:
         index = np.zeros((self.max_batch,), np.int32)
         temps = np.zeros((self.max_batch,), np.float32)
         seeds = np.zeros((self.max_batch,), np.uint32)
-        tables = None
-        if self.paged:
-            tables = np.zeros((self.max_batch,
-                               self.decoder.pages_per_slot), np.int32)
+        tables = np.zeros((self.max_batch, self.decoder.pages_per_slot),
+                          np.int32)
         for i, s in enumerate(self._slots):
             if s is not None and s.phase == "decode":
                 tokens[i] = s.last_token
                 index[i] = s.index
                 temps[i] = s.handle.request.temperature
                 seeds[i] = s.handle.request.rng_seed
-                if tables is not None:
-                    # prefilling / empty rows keep all-zeros rows →
-                    # their garbage goes to the scratch page
-                    tables[i] = s.block_row
+                # prefilling / empty rows keep all-zeros rows →
+                # their garbage goes to the scratch page
+                tables[i] = s.block_row
         attrs = {}
         if trace.enabled():
             tids = [s.handle.request.trace_id for s in self._slots
@@ -1370,9 +1330,8 @@ class ServeEngine:
                     and s.handle.request.trace_id]
             if tids:
                 attrs["traces"] = tids
-        if tables is not None:
-            self._m_live_pages.observe(
-                int((index // self.page_size + 1).sum()))
+        self._m_live_pages.observe(
+            int((index // self.page_size + 1).sum()))
         pre_compiled = self.decoder.compiled_count
         with trace.span("serve_decode", **attrs) as span:
             out, self._cache, _ = self.decoder.decode_step(
@@ -1455,14 +1414,12 @@ class ServeEngine:
     def _retire(self, slot_idx: int, cancelled: bool = False):
         slot = self._slots[slot_idx]
         self._slots[slot_idx] = None
-        if slot.pages:
-            # reclaim: each page loses this slot's holder; pages whose
-            # LAST holder left return to the free list, and their
-            # prefix-registry entries die with them (the physical page
-            # is about to hold someone else's KV)
-            for p in self.pool.free(slot.pages):
-                if self.registry is not None:
-                    self.registry.drop_page(p)
+        # reclaim: each page loses this slot's holder; pages whose
+        # LAST holder left return to the free list, and their
+        # prefix-registry entries die with them (the physical page
+        # is about to hold someone else's KV)
+        for p in self.pool.free(slot.pages):
+            self.registry.drop_page(p)
         req = slot.handle.request
         req.finish_time = time.time()
         result = ServeResult(

@@ -621,7 +621,7 @@ def test_serve_drain_sheds_new_finishes_inflight():
     params = model.init(jax.random.key(0),
                         jnp.zeros((1, 16), jnp.int32))["params"]
     engine = ServeEngine(model, params, max_batch=2, max_seq_len=16,
-                         max_delay_s=0.0, kv_page_size=None)
+                         max_delay_s=0.0)
     h = engine.submit(np.array([1, 2, 3], np.int32), max_new_tokens=4)
     engine.begin_drain()
     assert engine.draining

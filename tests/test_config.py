@@ -56,6 +56,13 @@ def test_bad_strategy():
         Config(distribution_strategy="nope")
 
 
+def test_kv_page_size_zero_is_refused_by_name():
+    """There is one KV cache; --kv_page_size 0 selects nothing."""
+    with pytest.raises(ValueError, match="kv_page_size"):
+        parse_flags(["--kv_page_size", "0"])
+    assert parse_flags(["--kv_pool_pages", "0"]).kv_pool_pages == 0
+
+
 def test_loss_scale_default_fp16():
     assert Config(dtype="fp16").loss_scale_value == 128.0
     assert Config(dtype="bf16").loss_scale_value == 1.0

@@ -179,13 +179,13 @@ def test_decoder_page_roundtrip_bit_identity(engine_pair):
         assert a.dtype == b.dtype and a.tobytes() == b.tobytes()
 
 
-def test_nonpaged_engine_has_no_migration_surface(model_and_params):
-    eng = make_engine(model_and_params, kv_page_size=0,
-                      kv_pool_pages=None)
+def test_engine_without_prefix_sharing_has_no_migration_surface(
+        model_and_params):
+    eng = make_engine(model_and_params, prefix_sharing=False)
     try:
         prompt = _prompt(2 * PS)
         assert eng.export_chain_begin(prompt) == ([], [])
-        with pytest.raises(RuntimeError, match="paged"):
+        with pytest.raises(RuntimeError, match="prefix sharing"):
             eng.import_chain(prompt, [[np.zeros(1, np.float32)]])
     finally:
         eng.stop()

@@ -74,6 +74,27 @@ def _oracle(model, params, prompt, n_new):
     return out
 
 
+def prefill_in_chunks(dec, cache, prompt, block_row, chunk=CHUNK):
+    """Write ``prompt`` into the pages of ``block_row`` the way the
+    engine does — full ``chunk``-token chunks, then a page-padded
+    remainder (mirrors ServeEngine._chunk_plan) — greedy.  Returns
+    (cache, logits at the prompt's last real position)."""
+    page, plen = dec.page_size, len(prompt)
+    plan, start = [], 0
+    while plen - start > chunk:
+        plan.append((start, chunk))
+        start += chunk
+    plan.append((start, -(-(plen - start) // page) * page))
+    padded = np.zeros((plan[-1][0] + plan[-1][1],), np.int32)
+    padded[:plen] = prompt
+    for ci, (start, clen) in enumerate(plan):
+        last = ci == len(plan) - 1
+        _, cache, logits = dec.prefill_chunk(
+            cache, padded[start:start + clen], block_row, start,
+            plen - 1 - start if last else 0, 0.0, seed=0)
+    return cache, logits
+
+
 # ---------------------------------------------------------------------------
 # decoder-level exactness across page geometries
 # ---------------------------------------------------------------------------
@@ -97,20 +118,7 @@ def test_paged_chunked_prefill_token_exact(model_and_params, plen):
     # slot 0 owns pages 1..pages_per_slot (engine normally allocates;
     # here we drive the decoder directly)
     block_row = np.arange(1, dec.pages_per_slot + 1, dtype=np.int32)
-    # chunk plan: full CHUNK chunks then a page-padded remainder —
-    # mirrors ServeEngine._chunk_plan
-    plan, start = [], 0
-    while plen - start > CHUNK:
-        plan.append((start, CHUNK))
-        start += CHUNK
-    plan.append((start, -(-(plen - start) // PAGE) * PAGE))
-    prompt_padded = np.zeros((plan[-1][0] + plan[-1][1],), np.int32)
-    prompt_padded[:plen] = toks[0, :plen]
-    for ci, (start, clen) in enumerate(plan):
-        last = ci == len(plan) - 1
-        tok, cache, logits = dec.prefill_chunk(
-            cache, prompt_padded[start:start + clen], block_row, start,
-            plen - 1 - start if last else 0, 0.0, jax.random.key(ci))
+    cache, logits = prefill_in_chunks(dec, cache, toks[0, :plen], block_row)
     assert int(np.argmax(np.asarray(logits))) == ref[0, plen - 1]
 
     # teacher-forced stepwise decode over the remaining positions; the
@@ -122,8 +130,7 @@ def test_paged_chunked_prefill_token_exact(model_and_params, plen):
     for t in range(plen, total):
         step = np.array([toks[0, t], 0], np.int32)
         _, cache, logits = dec.decode_step(
-            cache, step, index, temps, jax.random.key(100 + t),
-            block_tables=tables)
+            cache, step, index, temps, np.zeros((2,), np.uint32), tables)
         assert int(np.argmax(np.asarray(logits)[0])) == ref[0, t], t
         index[0] += 1
 
@@ -134,7 +141,7 @@ def test_paged_engine_greedy_matches_oracle(model_and_params, plen):
     prefill): greedy output equals the full-forward oracle at every
     page-geometry edge length."""
     model, params = model_and_params
-    # 50% of the contiguous-equivalent reservation
+    # 50% of one full reservation per slot
     full = 4 * (SEQ // PAGE)
     eng = paged_engine(model, params, kv_pool_pages=1 + full // 2)
     try:

@@ -433,19 +433,6 @@ def test_stream_timeout_raises(model_and_params):
         eng.stop(drain=False)
 
 
-def test_streaming_works_on_contiguous_cache(model_and_params):
-    """The legacy contiguous layout streams too (prefill emits the
-    first token, decode steps the rest)."""
-    model, params = model_and_params
-    eng = ServeEngine(model, params, max_batch=2, max_seq_len=SEQ,
-                      max_delay_s=0.0, kv_page_size=None)
-    try:
-        h = eng.submit(np.array([5, 6], np.int32), max_new_tokens=4)
-        assert list(h.stream(timeout=60)) == h.result(timeout=60).tokens
-    finally:
-        eng.stop(drain=False)
-
-
 def test_on_token_exception_does_not_kill_engine(model_and_params):
     """A raising client callback is logged and contained — the request
     still completes and the engine serves the next one."""

@@ -18,8 +18,10 @@ from dtf_tpu.serve import (Backpressure, Decoder, ServeEngine,
                            collect_stats, load_inference_variables,
                            place_for_serving)
 from dtf_tpu.serve.decode import teacher_forced_logits
+from test_paged import prefill_in_chunks
 
 VOCAB, SEQ = 64, 16
+PAGE = 4
 
 
 def tiny_model(**kw):
@@ -44,6 +46,12 @@ def model_and_params():
 # decode: token-exact vs teacher-forced
 # ---------------------------------------------------------------------------
 
+def _rows_of_pages(dec, batch):
+    """Block tables giving row i its own pages (page 0 is scratch)."""
+    m = dec.pages_per_slot
+    return 1 + np.arange(batch * m, dtype=np.int32).reshape(batch, m)
+
+
 @pytest.mark.parametrize("batch", [1, 4, 8])
 def test_decode_token_exact_vs_teacher_forced(model_and_params, batch):
     """Feeding the SAME token sequence through the cache path one token
@@ -56,45 +64,47 @@ def test_decode_token_exact_vs_teacher_forced(model_and_params, batch):
     ref = np.argmax(np.asarray(
         teacher_forced_logits(model, params, toks)), -1)
 
-    dec = Decoder(model, params, num_slots=batch, max_seq_len=SEQ)
+    dec = Decoder(model, params, num_slots=batch, max_seq_len=SEQ,
+                  kv_page_size=PAGE)
     cache = dec.fresh_cache()
+    tables = _rows_of_pages(dec, batch)
     got = np.zeros_like(ref)
-    # prefill each row's first token into its slot
+    # prefill each row's first token into its pages
     for i in range(batch):
-        _, cache, logits = dec.prefill(cache, toks[i, :1], i, 0.0,
-                                       jax.random.key(i))
+        cache, logits = prefill_in_chunks(dec, cache, toks[i, :1],
+                                          tables[i])
         got[i, 0] = int(np.argmax(np.asarray(logits)))
     index = np.ones((batch,), np.int32)
     temps = np.zeros((batch,), np.float32)
+    seeds = np.zeros((batch,), np.uint32)
     for t in range(1, toks.shape[1]):
         _, cache, logits = dec.decode_step(cache, toks[:, t], index,
-                                           temps, jax.random.key(100 + t))
+                                           temps, seeds, tables)
         got[:, t] = np.argmax(np.asarray(logits), -1)
         index += 1
     np.testing.assert_array_equal(ref, got)
 
 
 def test_decode_prefill_chunk_matches_stepwise(model_and_params):
-    """Prefilling a whole prompt in one chunk must leave the cache in
-    the same state as feeding it token by token: the next step's
-    logits agree."""
+    """Writing a prompt as page-aligned chunks must leave the pages in
+    the same state as feeding it token by token through decode_step:
+    the logits at its last position agree."""
     model, params = model_and_params
     rng = np.random.default_rng(7)
     prompt = rng.integers(0, VOCAB, (9,)).astype(np.int32)
 
-    dec = Decoder(model, params, num_slots=1, max_seq_len=SEQ)
-    # chunked prefill
-    c1 = dec.fresh_cache()
-    _, c1, chunk_logits = dec.prefill(c1, prompt, 0, 0.0,
-                                      jax.random.key(0))
+    dec = Decoder(model, params, num_slots=1, max_seq_len=SEQ,
+                  kv_page_size=PAGE)
+    tables = _rows_of_pages(dec, 1)
+    # chunked prefill: 4 + 4 + a page-padded 1
+    _, chunk_logits = prefill_in_chunks(dec, dec.fresh_cache(), prompt,
+                                        tables[0], chunk=PAGE)
     # stepwise
     c2 = dec.fresh_cache()
-    _, c2, step_logits = dec.prefill(c2, prompt[:1], 0, 0.0,
-                                     jax.random.key(0))
-    for t in range(1, len(prompt)):
+    for t in range(len(prompt)):
         _, c2, step_logits = dec.decode_step(
             c2, prompt[t:t + 1], np.array([t], np.int32),
-            np.zeros((1,), np.float32), jax.random.key(t))
+            np.zeros((1,), np.float32), np.zeros((1,), np.uint32), tables)
     np.testing.assert_allclose(np.asarray(chunk_logits),
                                np.asarray(step_logits[0]),
                                rtol=1e-5, atol=1e-5)
@@ -103,10 +113,34 @@ def test_decode_prefill_chunk_matches_stepwise(model_and_params):
 def test_decode_rejects_seq_sharded_config():
     """seq_axis (ring attention) still refuses decode; model_axis now
     composes — that path is tests/test_serve_tp.py's subject."""
-    model = tiny_model(seq_axis="seq", decode=True)
+    model = tiny_model(seq_axis="seq", decode=True, kv_page_size=PAGE,
+                       kv_pool_pages=5)
     with pytest.raises(ValueError, match="seq_axis"):
         model.init(jax.random.key(0), jnp.zeros((1, SEQ), jnp.int32),
+                   cache_index=jnp.zeros((1,), jnp.int32),
+                   block_table=jnp.zeros((1, 4), jnp.int32))
+
+
+def test_decode_model_needs_its_page_pool_shape():
+    """decode=True without kv_page_size/kv_pool_pages has no cache to
+    build: refused when the model is first traced, not served from
+    some other layout."""
+    model = tiny_model(decode=True)
+    with pytest.raises(ValueError, match="kv_page_size"):
+        model.init(jax.random.key(0), jnp.zeros((1, SEQ), jnp.int32),
                    cache_index=jnp.zeros((1,), jnp.int32))
+
+
+@pytest.mark.parametrize("build, page", [
+    (Decoder, 0), (ServeEngine, 0), (ServeEngine, None)],
+    ids=["decoder-0", "engine-0", "engine-none"])
+def test_kv_page_size_must_be_a_page(model_and_params, build, page):
+    """There is one KV cache: a page size of 0/None selects nothing and
+    is refused by name."""
+    model, params = model_and_params
+    kw = ({"num_slots": 2} if build is Decoder else {"max_batch": 2})
+    with pytest.raises(ValueError, match="kv_page_size"):
+        build(model, params, max_seq_len=SEQ, kv_page_size=page, **kw)
 
 
 # ---------------------------------------------------------------------------

@@ -149,13 +149,6 @@ def test_partition_specs_cover_every_leaf(model_and_params):
                     x, jax.sharding.PartitionSpec))))
 
 
-def test_tp_rejects_contiguous_cache(model_and_params, eight_devices):
-    model, params = model_and_params
-    with pytest.raises(ValueError, match="paged"):
-        Decoder(model, params, num_slots=2, max_seq_len=SEQ,
-                mesh=serving_mesh(2))
-
-
 def test_tp_rejects_indivisible_heads(eight_devices):
     model = tiny_model(num_heads=2, d_model=16, d_ff=32)
     params = model.init(jax.random.key(0),
@@ -163,14 +156,6 @@ def test_tp_rejects_indivisible_heads(eight_devices):
     with pytest.raises(ValueError, match="divisible"):
         Decoder(model, params, num_slots=2, max_seq_len=SEQ,
                 kv_page_size=PS, mesh=serving_mesh(4))
-
-
-def test_engine_rejects_mesh_without_paging(model_and_params,
-                                            eight_devices):
-    model, params = model_and_params
-    with pytest.raises(ValueError, match="paged"):
-        ServeEngine(model, params, max_batch=2, max_seq_len=SEQ,
-                    kv_page_size=None, mesh=serving_mesh(2))
 
 
 # ---------------------------------------------------------------------------

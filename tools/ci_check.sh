@@ -153,9 +153,12 @@ export JAX_PLATFORMS=${JAX_PLATFORMS:-cpu}
 
 if [ "${CI_CHECK_SKIP_TESTS:-0}" != "1" ]; then
     echo "== ci_check [1/17]: tier-1 test suite =="
-    timeout -k 10 870 python -m pytest tests/ -q -m 'not slow' \
+    # the shape of the driver's command (commands[0] of
+    # /root/TESTS_LAST_RUN.json): six xdist workers, a file to a
+    # worker, 1,470 s
+    timeout -k 10 1470 python -m pytest tests/ -q -m 'not slow' \
         --continue-on-collection-errors -p no:cacheprovider \
-        -p no:xdist -p no:randomly
+        -p xdist -n 6 --dist loadfile -p no:randomly
 else
     echo "== ci_check [1/17]: SKIPPED (CI_CHECK_SKIP_TESTS=1) =="
 fi
