@@ -6,7 +6,6 @@ from dtf_tpu.serve.bridge import (load_for_serving,       # noqa: F401
                                   place_for_serving,
                                   serving_memory_plan, serving_mesh)
 from dtf_tpu.serve.decode import (Decoder,                # noqa: F401
-                                  init_paged_cache,
                                   make_decode_model,
                                   teacher_forced_logits)
 from dtf_tpu.serve.engine import (Backpressure, PagePool,  # noqa: F401

@@ -157,6 +157,24 @@ def test_grouped_expert_matmuls_compile_for_v5e(v5e, tokens):
     assert text.count('custom_call_target="tpu_custom_call"') >= 2
 
 
+def test_serve_bodies_compiler_options_are_the_v5e_compilers(v5e):
+    """``serve/decode.py`` builds its two bodies with ``TPU_BODY_OPTIONS``.
+    The TPU's compiler knows every name: one it does not know raises, as
+    the control shows, and would do so at the first decode step on the
+    chip (what the options buy is a chip measurement: PERF.md §6 PR 31)."""
+    from dtf_tpu.serve.decode import TPU_BODY_OPTIONS
+    args = [jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=v5e)
+            for shape in ((48, 2048), (2048, 8192), (8192, 2048))]
+
+    def mlp(x, w1, w2):
+        return jax.nn.gelu(x @ w1) @ w2
+
+    jax.jit(mlp, compiler_options=TPU_BODY_OPTIONS).lower(*args).compile()
+    with pytest.raises(Exception, match="No such compile option"):
+        jax.jit(mlp, compiler_options={"xla_tpu_no_such_option": 1}).lower(
+            *args).compile()
+
+
 @pytest.mark.parametrize("heads", [6, 3])
 @pytest.mark.parametrize("seq", [2048, 8192])
 def test_flash_fwd_bwd_lowers_for_tpu(heads, seq):
