@@ -63,6 +63,20 @@ _REGISTRY = {
     # served-only decoder: per-layer window/full x rope/nope pattern,
     # grouped-query heads, dropless routed experts, bf16 parameters
     "routed_decoder": (routed_decoder.RoutedDecoderLM, 32_768, 0.0),
+    # the same module's other layer kinds at a small size: latent
+    # attention (one cached row a token), a leading dense layer, a shared
+    # expert beside sigmoid-routed experts
+    "routed_decoder_latent": (
+        functools.partial(
+            routed_decoder.RoutedDecoderLM, num_layers=4, d_model=512,
+            num_heads=8, q_lora_rank=192, kv_lora_rank=128,
+            qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=32,
+            rope_interleave=True, num_dense_layers=1, dense_width=1024,
+            num_experts=16, experts_per_token=4, expert_width=128,
+            shared_expert_width=128, routing="sigmoid_bias",
+            routed_scale=2.5, router_bias_stddev=0.05, activation="silu",
+            router_input="post_attention"),
+        32_768, 0.0),
     # pipeline-stacked LM family (pipeline stages over 'model')
     "pipeline_transformer": (pipeline_lm.PipelinedTransformerLM,
                              32_768, 0.0),

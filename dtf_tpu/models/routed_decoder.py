@@ -1,8 +1,17 @@
-"""Decoder-only LM whose layers follow a per-layer pattern and whose MLP
-is a dropless routed-expert layer — a family that is served
+"""Decoder-only LM whose layers follow a per-layer description and whose
+MLP is a dropless routed-expert layer — a family that is served
 (``serve/decode.py``, ``serve/engine.py``), not trained, in this repo.
 
-What a layer is comes from two tuples, one entry a layer:
+What a layer is comes from fields, all plain values a configuration file
+can carry.  Pre-norm RMSNorm blocks, no bias on any projection, an untied
+output head.  There is no position table: a position is the row's
+``cache_index`` plus the offset in the chunk, so ``max_seq_len`` bounds
+only what the serving engine admits.
+
+**Attention kind.**  ``kv_lora_rank`` None — whole heads: ``num_heads``
+query heads share ``num_kv_heads`` KV heads of ``head_dim`` (grouped-query
+attention: query head ``i`` reads KV head ``i // (num_heads //
+num_kv_heads)``), and two tuples, one entry a layer, say
 
   ``layer_window[l]``  True: the layer attends the last ``window``
                        positions up to the query's own; False: the whole
@@ -11,38 +20,52 @@ What a layer is comes from two tuples, one entry a layer:
                        on q and k, applied to k BEFORE it is written to
                        the cache; False: no positional signal at all.
 
-and its sizes from fields: ``num_heads`` query heads share
-``num_kv_heads`` KV heads of ``head_dim`` (grouped-query attention: query
-head ``i`` reads KV head ``i // (num_heads // num_kv_heads)``),
-``num_experts`` gated-ReLU experts of width ``expert_width`` of which every
-token takes its ``experts_per_token`` best.  Pre-norm RMSNorm blocks, no
-bias anywhere, an untied output head.  There is no position table: a
-position is the row's ``cache_index`` plus the offset in the chunk, so
-``max_seq_len`` bounds only what the serving engine admits.
+The cache is K and V pools ``[P, page, Hkv, Dh]``.  ``kv_lora_rank`` set —
+latent attention (:class:`LatentAttention`) in every layer, over the whole
+history: the cache is ONE pool ``[P, page, W]`` a layer whose row is a
+token's normed latent and its one rotary key (``kv_lora_rank +
+qk_rope_head_dim`` values in ``latent_row_lanes`` stored lanes), read once
+a call for scores and values; decode mode attends absorbed, every chunk
+(the first too) through the paged kernel.
+
+**MLP kind.**  The first ``num_dense_layers`` layers have a dense gated
+MLP of ``dense_width`` and no router.  The others route: ``num_experts``
+gated experts of ``expert_width`` (``activation`` relu | silu) of which
+every token takes its ``experts_per_token`` best, by ``routing``
+
+  ``softmax_topk``   the largest router logits, softmax over those;
+  ``sigmoid_bias``   scores ``sigmoid(logits)``; the choice is the largest
+                     of score + a learned bias, the weights the chosen
+                     scores WITHOUT it over their sum times ``routed_scale``
+
+from the norm ``router_input`` names (``pre_attention``: the layer's
+first norm, before attention runs; ``post_attention``: the second), and
+add a shared expert of ``shared_expert_width`` (0: none) for every token.
 
 The layer, for ``x [S, d]``:
 
-  1. ``h = RMSNorm(x)``
-  2. router on ``h`` (BEFORE attention): logits in f32, the
-     ``experts_per_token`` largest, softmax over those
-  3. ``x += attention(h)``   (pattern above; the paged cache is
+  1. ``h = RMSNorm(x)``                       (router here: pre_attention)
+  2. ``x += attention(h)``   (kind above; the paged cache is
      ``models.transformer.paged_cache_attention``, shared with
      ``CausalSelfAttention``)
-  4. ``x += sum_e w_e * down_e(relu(gate_e h2) * up_e h2)``,
-     ``h2 = RMSNorm(x)`` — every chosen (token, expert) pair is computed:
-     no capacity, nothing dropped (:func:`routed_experts`).
+  3. ``h2 = RMSNorm(x)``                      (router here: post_attention)
+  4. dense: ``x += down(act(gate h2) * up h2)``; routed: ``x +=
+     shared(h2) + sum_e w_e * down_e(act(gate_e h2) * up_e h2)`` — every
+     chosen (token, expert) pair is computed: no capacity, nothing dropped
+     (:func:`routed_experts`).
 
 Parameters live in ``param_dtype`` (bfloat16 for serving: an f32 copy of
 the experts would not fit beside the cache, and a per-step cast re-reads
-every weight); matmuls take their inputs in ``dtype`` and accumulate in
-f32; the residual stream, the norms, the router, softmax and the combine
-are f32.  The f32 stream is what keeps the routing stable: with a bf16
-stream the k-th and (k+1)-th router logits trade places against the f32
-reference often enough that those flips alone were 0.008-0.019 of the
-0.014-0.029 the served logits read against it (v5e, 12 seeds), and it
-costs [tokens, d_model] words a layer beside 755e6 bytes of experts.
+every weight; the score bias alone is float32); matmuls take their inputs
+in ``dtype`` and accumulate in f32; the residual stream, the norms, the
+router, softmax and the combine are f32.  The f32 stream is what keeps
+the routing stable: with a bf16 stream the k-th and (k+1)-th router
+logits trade places against the f32 reference often enough that those
+flips alone were 0.008-0.019 of the 0.014-0.029 the served logits read
+against it (v5e, 12 seeds, whole heads), and it costs [tokens, d_model]
+words a layer beside 755e6 bytes of experts.
 
-Every apply also yields five counts (``STATS``; summed over layers) in
+Every apply also yields counts (``stats_names``; summed over layers) in
 the ``"stats"`` collection when the caller makes it mutable: the serving
 engine puts them on its spans when tracing is on.
 """
@@ -58,9 +81,11 @@ import jax.numpy as jnp
 from dtf_tpu.models.transformer import paged_cache_attention
 from dtf_tpu.ops.paged_attention import cached_attention, expand_kv_heads
 
-# what ``"stats"/"counts"`` holds, in order
+# what ``"stats"/"counts"`` holds, in order: with whole heads, and with the
+# latent cache (its one row a token, summed over rows and layers)
 STATS = ("assignments", "experts_touched", "expert_load_max",
          "kv_tokens_read_global", "kv_tokens_read_window")
+LATENT_STATS = STATS[:3] + ("latent_tokens_read",)
 
 # grouped matmul tile (rows, contraction, columns): rows of one expert are
 # padded to a multiple of the first inside the kernel's own bookkeeping
@@ -90,22 +115,64 @@ def rotate_half_rope(x, positions, theta: float):
                            axis=-1).astype(x.dtype)
 
 
-def route(h, w_router, k: int):
+def interleaved_rope(x, positions, theta: float):
+    """Rotary positions over the last axis, interleaved pairing: the pair
+    is ``(x[2i], x[2i+1])``, turned by ``pos * theta**(-2i/D)``.  x
+    [B, S, ..., D], positions [B, S]; f32 inside."""
+    d = x.shape[-1]
+    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angle = positions.astype(jnp.float32)[..., None] * inv_freq  # [B,S,D/2]
+    angle = angle.reshape(angle.shape[:2] + (1,) * (x.ndim - 3) + (d // 2,))
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    x32 = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
+    x1, x2 = x32[..., 0], x32[..., 1]
+    return jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                     axis=-1).reshape(x.shape).astype(x.dtype)
+
+
+def route(h, w_router, k: int, score_bias=None, routed_scale: float = 1.0):
     """(expert ids [T, k], weights [T, k] f32) of the ``k`` largest router
     logits a token; softmax over the chosen (softmax over all, then
     renormalised over the chosen, is the same numbers).  f32 at full
     matmul precision: a rounding that flips the k-th choice moves the
-    token's output by a whole expert, not by an ulp."""
+    token's output by a whole expert, not by an ulp.
+
+    ``score_bias`` [E] set is the ``sigmoid_bias`` rule instead: scores
+    ``s = sigmoid(logits)``; the choice is the ``k`` largest of ``s +
+    score_bias``; the weights are the chosen experts' ``s`` WITHOUT the
+    bias, divided by their sum and multiplied by ``routed_scale``."""
     logits = jnp.einsum("td,de->te", h.astype(jnp.float32),
                         w_router.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
+    if score_bias is not None:
+        scores = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(scores + score_bias.astype(jnp.float32), k)
+        chosen = jnp.take_along_axis(scores, idx, axis=-1)
+        return idx, chosen / jnp.sum(chosen, -1, keepdims=True) * routed_scale
     vals, idx = jax.lax.top_k(logits, k)
     return idx, jax.nn.softmax(vals, axis=-1)
 
 
-def routed_experts(x, idx, weights, w_gate_up, w_down, *, use_pallas=None):
+ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu}
+
+
+def gated_mlp(x, w_gate_up, w_down, activation: str = "silu"):
+    """``down(act(gate x) * up x)`` for every row: x [T, d], w_gate_up
+    [d, 2f] (gate columns first), w_down [f, d]; f32 accumulation, f32
+    out.  The dense layers' MLP and the shared expert (XLA's matmuls)."""
+    f = w_down.shape[0]
+    h = jnp.einsum("td,df->tf", x, w_gate_up,
+                   preferred_element_type=jnp.float32)
+    h = (ACTIVATIONS[activation](h[:, :f]) * h[:, f:]).astype(x.dtype)
+    return jnp.einsum("tf,fd->td", h, w_down,
+                      preferred_element_type=jnp.float32)
+
+
+def routed_experts(x, idx, weights, w_gate_up, w_down, *, use_pallas=None,
+                   activation: str = "relu"):
     """The dropless expert layer: ``y[t] = sum_j weights[t, j] *
-    down_e(relu(gate_e x[t]) * up_e x[t])`` with ``e = idx[t, j]``.
+    down_e(act(gate_e x[t]) * up_e x[t])`` with ``e = idx[t, j]``
+    (``activation``: ``relu`` or ``silu``).
 
     x [T, d]; idx, weights [T, k]; w_gate_up [E, d, 2f] (gate columns
     first); w_down [E, f, d].  Returns (y [T, d] f32, rows per expert
@@ -120,7 +187,10 @@ def routed_experts(x, idx, weights, w_gate_up, w_down, *, use_pallas=None):
     on TPU, ``jax.lax.ragged_dot`` elsewhere), True, "interpret", False.
     On the v5e at 64 experts of 2560 x 768: 0.91 against 1.10 ms a layer
     at 16 tokens, 1.59 against 2.99 ms at 512 (Pallas against
-    ragged_dot)."""
+    ragged_dot); at 256 experts of 2048 x 768 and 8,192 pairs (32 rows an
+    expert) the row tile of 128 still wins: 4.41 / 4.60 / 4.86 / 5.49 ms a
+    layer at 128 / 64 / 32 / 16, and 1.7 ms at 192 pairs whatever the
+    tile."""
     t, k = idx.shape
     num_experts, _, f2 = w_gate_up.shape
     f = f2 // 2
@@ -150,7 +220,7 @@ def routed_experts(x, idx, weights, w_gate_up, w_down, *, use_pallas=None):
                                       preferred_element_type=jnp.float32)
 
     h = grouped(xs, w_gate_up)
-    h = (jax.nn.relu(h[:, :f]) * h[:, f:]).astype(x.dtype)
+    h = (ACTIVATIONS[activation](h[:, :f]) * h[:, f:]).astype(x.dtype)
     y = grouped(h, w_down)
     # back to the pairs' own order: a gather by the inverse permutation,
     # then the k weighted rows of a token are summed in f32
@@ -160,7 +230,8 @@ def routed_experts(x, idx, weights, w_gate_up, w_down, *, use_pallas=None):
     return jnp.sum(y * weights[..., None], axis=1), sizes
 
 
-def routed_experts_dense(x, idx, weights, w_gate_up, w_down):
+def routed_experts_dense(x, idx, weights, w_gate_up, w_down,
+                         activation: str = "relu"):
     """The oracle of :func:`routed_experts`: every expert on every token,
     masked by the routing weights."""
     num_experts, _, f2 = w_gate_up.shape
@@ -170,7 +241,7 @@ def routed_experts_dense(x, idx, weights, w_gate_up, w_down):
         jnp.arange(t)[:, None], idx].add(weights)
     h = jnp.einsum("td,edf->etf", x, w_gate_up,
                    preferred_element_type=jnp.float32)
-    h = (jax.nn.relu(h[..., :f]) * h[..., f:]).astype(x.dtype)
+    h = (ACTIVATIONS[activation](h[..., :f]) * h[..., f:]).astype(x.dtype)
     y = jnp.einsum("etf,efd->etd", h, w_down,
                    preferred_element_type=jnp.float32)
     return jnp.einsum("etd,te->td", y, full)
@@ -238,6 +309,116 @@ class GroupedQueryAttention(nn.Module):
                           preferred_element_type=jnp.float32)
 
 
+# lanes a latent cache row is stored in: the TPU tiles the last axis by
+# 128, so a row of 576 values occupies 640 in HBM however it is declared
+# (Mosaic refuses a page DMA of 576 lanes); the pad lanes hold zeros
+_LANES = 128
+
+
+def latent_row_lanes(kv_lora_rank: int, qk_rope_head_dim: int) -> int:
+    return -(-(kv_lora_rank + qk_rope_head_dim) // _LANES) * _LANES
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention.  A token's keys and values, for every
+    head, come from one latent ``c_kv`` [kv_lora_rank] (after its own
+    RMSNorm) through ``kv_b``, plus ONE rotary key ``k_rope``
+    [qk_rope_head_dim] that all heads share; queries come from a latent
+    ``c_q`` [q_lora_rank] likewise.  ``score_h(i, j) = (q_nope_h,i .
+    k_nope_h,j + q_rope_h,i . k_rope_j) / sqrt(nope + rope)``.
+
+    The cache holds, a token a layer, the row ``[c_kv | k_rope | 0]``
+    (bf16 in serving; ``latent_row_lanes`` lanes) and decode mode runs
+    ABSORBED: ``q~_h = q_nope_h kv_b[K, h]^T`` meets ``c_kv`` directly,
+    the values are ``c_kv`` itself and ``kv_b[V, h]`` is applied to the
+    attended sum — equal in exact arithmetic, 32 heads over one cached
+    row, ``kv_b`` used as held.  Outside decode mode (tests, the toy's
+    teacher-forced forward) K and V of every token are expanded from the
+    definition."""
+    num_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_theta: float
+    rope_interleave: bool
+    rms_eps: float
+    dtype: Any
+    param_dtype: Any
+    use_pallas: Any = None
+    decode: bool = False
+    kv_page_size: Optional[int] = None
+    kv_pool_pages: Optional[int] = None
+
+    @nn.compact
+    def __call__(self, h, positions, cache_index=None, block_table=None,
+                 window_pages: Optional[int] = None):
+        b, s, d = h.shape
+        hq, rq, r = self.num_heads, self.q_lora_rank, self.kv_lora_rank
+        dn, dr, dv = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                      self.v_head_dim)
+        ones, pdt = nn.initializers.ones, self.param_dtype
+        w_qa = self.param("q_a", _normal(0.02), (d, rq), pdt)
+        g_q = self.param("q_norm", ones, (rq,), pdt)
+        w_qb = self.param("q_b", _normal(0.02), (rq, hq * (dn + dr)), pdt)
+        w_kva = self.param("kv_a", _normal(0.02), (d, r + dr), pdt)
+        g_kv = self.param("kv_norm", ones, (r,), pdt)
+        w_kvb = self.param("kv_b", _normal(0.02), (r, hq * (dn + dv)), pdt)
+        w_out = self.param("out", _normal(0.02), (hq * dv, d), pdt)
+        rope = interleaved_rope if self.rope_interleave else rotate_half_rope
+
+        def mm(spec, x, w):
+            return jnp.einsum(spec, x.astype(self.dtype),
+                              w.astype(self.dtype),
+                              preferred_element_type=jnp.float32)
+        c_q = rms_norm(mm("bsd,dr->bsr", h, w_qa), g_q, self.rms_eps)
+        q = mm("bsr,rn->bsn", c_q, w_qb).reshape(b, s, hq, dn + dr)
+        q_nope = q[..., :dn].astype(self.dtype)
+        q_rope = rope(q[..., dn:], positions, self.rope_theta
+                      ).astype(self.dtype)
+        kv = mm("bsd,dr->bsr", h, w_kva)
+        c_kv = rms_norm(kv[..., :r], g_kv, self.rms_eps).astype(self.dtype)
+        k_rope = rope(kv[..., None, r:], positions, self.rope_theta
+                      )[:, :, 0].astype(self.dtype)
+        w_kvb = w_kvb.astype(self.dtype).reshape(r, hq, dn + dv)
+        scale = 1.0 / ((dn + dr) ** 0.5)
+        if self.decode:
+            if self.kv_page_size is None:
+                raise ValueError("decode mode needs kv_page_size and "
+                                 "kv_pool_pages")
+            if cache_index is None or block_table is None:
+                raise ValueError("decode mode needs cache_index [B] "
+                                 "and block_table [B, M], both int32")
+            pad = latent_row_lanes(r, dr) - r - dr
+            row = jnp.concatenate(
+                [c_kv, k_rope, jnp.zeros((b, s, pad), self.dtype)], -1)
+            q_abs = jnp.einsum("bshn,rhn->bshr", q_nope, w_kvb[..., :dn],
+                               preferred_element_type=jnp.float32
+                               ).astype(self.dtype)
+            q_abs = jnp.concatenate(
+                [q_abs, q_rope, jnp.zeros((b, s, hq, pad), self.dtype)], -1)
+            o = paged_cache_attention(
+                self, q_abs, row, None, cache_index, block_table,
+                window_pages=window_pages, scale=scale, value_lanes=r)
+            o = jnp.einsum("bshr,rhv->bshv", o, w_kvb[..., dn:],
+                           preferred_element_type=jnp.float32
+                           ).astype(self.dtype)
+        else:
+            # the whole sequence at once, from the definition
+            kv_all = jnp.einsum("bsr,rhn->bshn", c_kv, w_kvb,
+                                preferred_element_type=jnp.float32
+                                ).astype(self.dtype)
+            k = jnp.concatenate(
+                [kv_all[..., :dn],
+                 jnp.broadcast_to(k_rope[:, :, None], (b, s, hq, dr))], -1)
+            i = jnp.arange(s)
+            o = cached_attention(
+                jnp.concatenate([q_nope, q_rope], -1), k, kv_all[..., dn:],
+                jnp.broadcast_to(i[None, :] <= i[:, None], (b, s, s)))
+        return mm("bsn,nd->bsd", o.reshape(b, s, hq * dv), w_out)
+
+
 class RoutedBlock(nn.Module):
     num_heads: int
     num_kv_heads: int
@@ -254,6 +435,17 @@ class RoutedBlock(nn.Module):
     decode: bool = False
     kv_page_size: Optional[int] = None
     kv_pool_pages: Optional[int] = None
+    # what the layer is, beyond the defaults (see RoutedDecoderLM)
+    latent: Optional[Tuple[int, ...]] = None   # (q rank, kv rank, nope,
+    #                                             rope, v) or None
+    rope_interleave: bool = False
+    dense_width: Optional[int] = None          # set: a dense gated MLP
+    shared_expert_width: int = 0
+    routing: str = "softmax_topk"
+    routed_scale: float = 1.0
+    router_bias_stddev: float = 0.0
+    activation: str = "relu"
+    router_input: str = "pre_attention"
 
     @nn.compact
     def __call__(self, x, positions, cache_index=None, block_table=None,
@@ -261,31 +453,76 @@ class RoutedBlock(nn.Module):
                  window_pages: Optional[int] = None):
         b, s, d = x.shape
         e, f = self.num_experts, self.expert_width
-        ones = nn.initializers.ones
-        g1 = self.param("norm1", ones, (d,), self.param_dtype)
-        g2 = self.param("norm2", ones, (d,), self.param_dtype)
-        w_router = self.param("router", _normal(0.02), (d, e),
-                              self.param_dtype)
-        w_gate_up = self.param("gate_up", _normal(0.02), (e, d, 2 * f),
-                               self.param_dtype)
-        w_down = self.param("down", _normal(0.02), (e, f, d),
-                            self.param_dtype)
+        ones, pdt = nn.initializers.ones, self.param_dtype
+        g1 = self.param("norm1", ones, (d,), pdt)
+        g2 = self.param("norm2", ones, (d,), pdt)
+        routed = self.dense_width is None
+        if routed:
+            w_router = self.param("router", _normal(0.02), (d, e), pdt)
+            w_gate_up = self.param("gate_up", _normal(0.02), (e, d, 2 * f),
+                                   pdt)
+            w_down = self.param("down", _normal(0.02), (e, f, d), pdt)
+        if self.routing not in ("softmax_topk", "sigmoid_bias"):
+            raise ValueError(f"routing {self.routing!r}: softmax_topk or "
+                             f"sigmoid_bias")
+        score_bias = None
+        if routed and self.routing == "sigmoid_bias":
+            # f32 whatever param_dtype: it meets f32 scores
+            score_bias = self.param(
+                "router_bias", _normal(self.router_bias_stddev), (e,),
+                jnp.float32)
+
+        def choose(hh):
+            return route(hh.reshape(b * s, d), w_router,
+                         self.experts_per_token, score_bias,
+                         self.routed_scale)
         h = rms_norm(x, g1, self.rms_eps)
-        idx, weights = route(h.reshape(b * s, d), w_router,
-                             self.experts_per_token)
-        x = x + GroupedQueryAttention(
-            self.num_heads, self.num_kv_heads, self.head_dim, self.window,
-            self.rope_theta, self.dtype, self.param_dtype,
-            use_pallas=self.use_pallas, decode=self.decode,
-            kv_page_size=self.kv_page_size,
-            kv_pool_pages=self.kv_pool_pages, name="attn")(
-                h, positions, cache_index, block_table, flash_prefill,
-                window_pages)
+        if routed and self.router_input == "pre_attention":
+            idx, weights = choose(h)
+        if self.latent is None:
+            attn = GroupedQueryAttention(
+                self.num_heads, self.num_kv_heads, self.head_dim,
+                self.window, self.rope_theta, self.dtype, pdt,
+                use_pallas=self.use_pallas, decode=self.decode,
+                kv_page_size=self.kv_page_size,
+                kv_pool_pages=self.kv_pool_pages, name="attn")(
+                    h, positions, cache_index, block_table, flash_prefill,
+                    window_pages)
+        else:
+            attn = LatentAttention(
+                self.num_heads, *self.latent, self.rope_theta,
+                self.rope_interleave, self.rms_eps, self.dtype, pdt,
+                use_pallas=self.use_pallas, decode=self.decode,
+                kv_page_size=self.kv_page_size,
+                kv_pool_pages=self.kv_pool_pages, name="attn")(
+                    h, positions, cache_index, block_table, window_pages)
+        x = x + attn
         h2 = rms_norm(x, g2, self.rms_eps).reshape(b * s, d)
+        if not routed:
+            y = gated_mlp(
+                h2.astype(self.dtype),
+                self.param("dense_gate_up", _normal(0.02),
+                           (d, 2 * self.dense_width), pdt).astype(self.dtype),
+                self.param("dense_down", _normal(0.02),
+                           (self.dense_width, d), pdt).astype(self.dtype),
+                self.activation)
+            return x + y.reshape(b, s, d), None
+        if self.router_input != "pre_attention":
+            idx, weights = choose(h2)
         y, sizes = routed_experts(h2.astype(self.dtype), idx, weights,
                                   w_gate_up.astype(self.dtype),
                                   w_down.astype(self.dtype),
-                                  use_pallas=self.use_pallas)
+                                  use_pallas=self.use_pallas,
+                                  activation=self.activation)
+        if self.shared_expert_width:
+            fs = self.shared_expert_width
+            y = y + gated_mlp(
+                h2.astype(self.dtype),
+                self.param("shared_gate_up", _normal(0.02), (d, 2 * fs),
+                           pdt).astype(self.dtype),
+                self.param("shared_down", _normal(0.02), (fs, d),
+                           pdt).astype(self.dtype),
+                self.activation)
         return x + y.reshape(b, s, d), sizes
 
 
@@ -311,6 +548,31 @@ class RoutedDecoderLM(nn.Module):
     rope_theta: float = 10000.0
     rms_eps: float = 1e-6
     max_seq_len: int = 2048
+    # attention kind.  kv_lora_rank None: whole heads (num_kv_heads x
+    # head_dim, the two layer tuples above).  Set: latent attention
+    # (LatentAttention) in every layer, whole history, rotary on the
+    # qk_rope_head_dim part only; num_kv_heads, head_dim, window and the
+    # layer tuples then size nothing
+    q_lora_rank: Optional[int] = None
+    kv_lora_rank: Optional[int] = None
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    rope_interleave: bool = False
+    # MLP kind.  The first num_dense_layers layers have a dense gated MLP
+    # of dense_width and no router; the rest route (routing:
+    # softmax_topk | sigmoid_bias with a learned score bias and
+    # routed_scale) from the norm router_input names (pre_attention |
+    # post_attention) and add a shared expert of shared_expert_width (0:
+    # none) for every token; activation relu | silu for all of them
+    num_dense_layers: int = 0
+    dense_width: int = 0
+    shared_expert_width: int = 0
+    routing: str = "softmax_topk"
+    routed_scale: float = 1.0
+    router_bias_stddev: float = 0.0     # the score bias's initializer
+    activation: str = "relu"
+    router_input: str = "pre_attention"
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
     use_pallas: Any = None
@@ -320,10 +582,16 @@ class RoutedDecoderLM(nn.Module):
     # serve.decode.make_decode_model names it; this family has no
     # tensor-parallel layout yet
     model_axis: Optional[str] = None
-    stats_names = STATS         # no field: what "stats"/"counts" holds
+
+    @property
+    def stats_names(self):
+        """What ``"stats"/"counts"`` holds, in order."""
+        return STATS if self.kv_lora_rank is None else LATENT_STATS
 
     def layer_kinds(self):
         """[(window or None, rope_theta or None)] a layer."""
+        if self.kv_lora_rank is not None:
+            return [(None, float(self.rope_theta))] * self.num_layers
         lw, lr = tuple(self.layer_window), tuple(self.layer_rope)
         return [(int(self.window) if lw[i % len(lw)] else None,
                  float(self.rope_theta) if lr[i % len(lr)] else None)
@@ -337,7 +605,7 @@ class RoutedDecoderLM(nn.Module):
         if self.model_axis is not None:
             raise ValueError("the routed decoder has no tensor-parallel "
                              "layout (serve it on one device)")
-        if self.num_heads % self.num_kv_heads:
+        if self.kv_lora_rank is None and self.num_heads % self.num_kv_heads:
             raise ValueError(f"num_heads {self.num_heads} is no multiple of "
                              f"num_kv_heads {self.num_kv_heads}")
         b, s = tokens.shape
@@ -353,6 +621,12 @@ class RoutedDecoderLM(nn.Module):
         else:
             positions = jnp.broadcast_to(offset, (b, s))
         kinds = self.layer_kinds()
+        latent = None
+        if self.kv_lora_rank is not None:
+            latent = (self.q_lora_rank, self.kv_lora_rank,
+                      self.qk_nope_head_dim, self.qk_rope_head_dim,
+                      self.v_head_dim)
+        n_routed = len(kinds) - self.num_dense_layers
         touched = load_max = jnp.zeros((), jnp.int32)
         for i, (window, theta) in enumerate(kinds):
             x, sizes = RoutedBlock(
@@ -361,25 +635,39 @@ class RoutedDecoderLM(nn.Module):
                 window, theta, self.rms_eps, self.dtype, pdt,
                 use_pallas=self.use_pallas, decode=self.decode,
                 kv_page_size=self.kv_page_size,
-                kv_pool_pages=self.kv_pool_pages, name=f"layer{i}")(
+                kv_pool_pages=self.kv_pool_pages, latent=latent,
+                rope_interleave=self.rope_interleave,
+                dense_width=(self.dense_width
+                             if i < self.num_dense_layers else None),
+                shared_expert_width=self.shared_expert_width,
+                routing=self.routing, routed_scale=self.routed_scale,
+                router_bias_stddev=self.router_bias_stddev,
+                activation=self.activation, router_input=self.router_input,
+                name=f"layer{i}")(
                     x, positions, cache_index, block_table, flash_prefill,
                     window_pages)
-            touched += jnp.sum(sizes > 0, dtype=jnp.int32)
-            load_max += jnp.max(sizes)
-        # what the attention of this call has to read of K (and of V): a
-        # row's whole history in a full layer, the window's reach in a
-        # window layer
+            if sizes is not None:
+                touched += jnp.sum(sizes > 0, dtype=jnp.int32)
+                load_max += jnp.max(sizes)
+        # what the attention of this call has to read of the cache: a
+        # row's whole history in a full layer (K and V, or the one latent
+        # row a token), the window's reach in a window layer
         live = positions[:, -1] + 1
-        n_window = sum(w is not None for w, _ in kinds)
-        counts = jnp.stack([
-            jnp.asarray(b * s * self.experts_per_token * len(kinds),
-                        jnp.int32),
-            touched, load_max,
-            (len(kinds) - n_window) * jnp.sum(live),
-            n_window * jnp.sum(jnp.minimum(live, self.window + s - 1))])
+        assignments = jnp.asarray(
+            b * s * self.experts_per_token * n_routed, jnp.int32)
+        if latent is not None:
+            counts = jnp.stack([assignments, touched, load_max,
+                                len(kinds) * jnp.sum(live)])
+        else:
+            n_window = sum(w is not None for w, _ in kinds)
+            counts = jnp.stack([
+                assignments, touched, load_max,
+                (len(kinds) - n_window) * jnp.sum(live),
+                n_window * jnp.sum(jnp.minimum(live, self.window + s - 1))])
+        n_counts = len(self.stats_names)
         self.sow("stats", "counts", counts,
                  reduce_fn=lambda _, new: new,
-                 init_fn=lambda: jnp.zeros((len(STATS),), jnp.int32))
+                 init_fn=lambda: jnp.zeros((n_counts,), jnp.int32))
         x = rms_norm(x, self.param("norm_f", nn.initializers.ones,
                                    (self.d_model,), pdt), self.rms_eps)
         head = self.param("lm_head", _normal(0.02),

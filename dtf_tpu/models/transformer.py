@@ -80,12 +80,21 @@ def remat_policy(name: str):
 def paged_cache_attention(module, q, k, v, cache_index, block_table, *,
                           flash_prefill: bool = False,
                           window_pages: Optional[int] = None,
-                          window: Optional[int] = None):
+                          window: Optional[int] = None,
+                          scale: Optional[float] = None,
+                          value_lanes: Optional[int] = None):
     """Write-then-attend against the shared page pool — what every
     decoder family's attention does with the paged cache, called from
     inside the attention module's ``@nn.compact`` body (``module`` owns
-    the two pool variables and names ``kv_page_size``, ``kv_pool_pages``,
+    the pool variables and names ``kv_page_size``, ``kv_pool_pages``,
     ``use_pallas``).
+
+    ``v`` None is the LATENT cache: ``k`` [B, S, W] is the one row a
+    token that every query head attends (q [B, S, Hq, W], absorbed), its
+    first ``value_lanes`` lanes are the value, ``scale`` the score's; one
+    pool ``[P, page, W]``, written once and read once a call, every chunk
+    (the first too) through the paged kernel; returns
+    [B, S, Hq, value_lanes].
 
     q [B, S, Hq, Dh]; k, v [B, S, Hkv, Dh] with ``Hq`` a multiple of
     ``Hkv`` (grouped-query heads: query head ``i`` reads KV head
@@ -98,6 +107,19 @@ def paged_cache_attention(module, q, k, v, cache_index, block_table, *,
     # attrs (NOT by the init call's shapes — admission capacity
     # is a pool property, not a per-slot reservation)
     pool_shape = (module.kv_pool_pages, module.kv_page_size) + k.shape[2:]
+    aligned = s > 1 and s % module.kv_page_size == 0
+    if v is None:
+        paged_latent = module.variable(
+            "cache", "paged_latent", jnp.zeros, pool_shape, k.dtype)
+        if module.is_initializing():
+            return jnp.zeros(q.shape[:-1] + (value_lanes,), q.dtype)
+        paged_latent.value = write_pages(
+            paged_latent.value, k, block_table, cache_index,
+            page_aligned=aligned)
+        return paged_attention_auto(
+            q, paged_latent.value, None, block_table, cache_index,
+            window_pages=window_pages, use_pallas=module.use_pallas,
+            scale=scale, value_lanes=value_lanes)
     paged_key = module.variable(
         "cache", "paged_key", jnp.zeros, pool_shape, k.dtype)
     paged_value = module.variable(
@@ -115,7 +137,6 @@ def paged_cache_attention(module, q, k, v, cache_index, block_table, *,
     # Prefill chunks (S a page multiple; page-aligned starts by
     # engine construction) scatter whole pages; decode steps
     # (S = 1) scatter single token rows
-    aligned = s > 1 and s % module.kv_page_size == 0
     paged_key.value = write_pages(
         paged_key.value, k, block_table, cache_index,
         page_aligned=aligned)

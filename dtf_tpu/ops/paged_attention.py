@@ -109,7 +109,8 @@ def expand_kv_heads(k, v, q_heads: int):
 
 
 def write_pages(pool, new, block_table, index, page_aligned: bool = False):
-    """Scatter a [B, S, H, Dh] chunk of K or V into the page pool.
+    """Scatter a [B, S, H, Dh] chunk of K or V into the page pool (or a
+    [B, S, W] chunk of latent rows into a ``[P, page_size, W]`` pool).
 
     ``pool`` [P, page_size, H, Dh]; ``block_table`` [B, M] int32 page
     ids; ``index`` [B] int32 — the chunk's starting logical position
@@ -130,7 +131,7 @@ def write_pages(pool, new, block_table, index, page_aligned: bool = False):
     live-token overwrite that the mask could later admit unwritten.
     Rows whose block-table entries are all 0 write into the scratch
     page (see module docstring)."""
-    num_pages, page_size, h, dh = pool.shape
+    num_pages, page_size, *row = pool.shape
     b, s = new.shape[:2]
     capacity = block_table.shape[1] * page_size
     if page_aligned:
@@ -140,8 +141,8 @@ def write_pages(pool, new, block_table, index, page_aligned: bool = False):
             pstart[:, None] + jnp.arange(n_pages, dtype=jnp.int32)[None, :],
             block_table.shape[1] - 1)
         page = jnp.take_along_axis(block_table, pidx, axis=1)  # [B, n]
-        pages = new.reshape(b * n_pages, page_size, h, dh)
-        if h * pool.dtype.itemsize < 32:
+        pages = new.reshape(b * n_pages, page_size, *row)
+        if len(row) == 2 and row[0] * pool.dtype.itemsize < 32:
             # fewer heads than a (sublane, lane) tile holds (4 bf16 KV
             # heads under grouped queries): XLA lays the scatter out
             # heads-major and then rewrites the WHOLE pool twice a layer
@@ -157,9 +158,9 @@ def write_pages(pool, new, block_table, index, page_aligned: bool = False):
     pos = jnp.minimum(pos, capacity - 1)                     # [B, S]
     page = jnp.take_along_axis(block_table, pos // page_size, axis=1)
     flat = page * page_size + pos % page_size                # [B, S]
-    pool_flat = pool.reshape(num_pages * page_size, h, dh)
+    pool_flat = pool.reshape(num_pages * page_size, *row)
     pool_flat = pool_flat.at[flat.reshape(-1)].set(
-        new.reshape(b * s, h, dh))
+        new.reshape(b * s, *row))
     return pool_flat.reshape(pool.shape)
 
 
@@ -173,9 +174,9 @@ def gather_pages(pool, block_table):
     never individual tokens.  Unallocated entries gather the scratch
     page — callers mask those positions out (they are always ≥ the
     row's current length)."""
-    num_pages, page_size, h, dh = pool.shape
+    page_size = pool.shape[1]
     b, m = block_table.shape
-    return pool[block_table].reshape(b, m * page_size, h, dh)
+    return pool[block_table].reshape(b, m * page_size, *pool.shape[2:])
 
 
 def paged_attention(q, pool_k, pool_v, block_table, index, *, window=None):
@@ -195,15 +196,42 @@ def paged_attention(q, pool_k, pool_v, block_table, index, *, window=None):
     k = gather_pages(pool_k, block_table)   # [B, L, H, Dh]
     v = gather_pages(pool_v, block_table)
     k, v = expand_kv_heads(k, v, q.shape[2])
-    s = q.shape[1]
-    capacity = k.shape[1]
-    jpos = jnp.arange(capacity, dtype=jnp.int32)[None, None, :]
+    return cached_attention(q, k, v, _seen(q, k, index, window))
+
+
+def _seen(q, k, index, window=None):
+    """mask [B, S, L]: query ``i`` of row ``b`` (position ``index[b] +
+    i``) sees key position ``j`` iff ``j <= index[b] + i`` (and, under a
+    window, ``j > index[b] + i - window``)."""
+    jpos = jnp.arange(k.shape[1], dtype=jnp.int32)[None, None, :]
     qpos = (index[:, None, None]
-            + jnp.arange(s, dtype=jnp.int32)[None, :, None])
+            + jnp.arange(q.shape[1], dtype=jnp.int32)[None, :, None])
     mask = jpos <= qpos
     if window is not None:
         mask &= jpos > qpos - window
-    return cached_attention(q, k, v, mask)
+    return mask
+
+
+def latent_paged_attention(q, pool, block_table, index, *, value_lanes,
+                           scale):
+    """The gather oracle over a LATENT pool: one row of ``W`` values a
+    token, shared by every query head, whose first ``value_lanes`` lanes
+    are also the value.
+
+    q [B, S, H, W] (the absorbed queries: each head's query already
+    carried into the row's space); pool [P, page_size, W]; block_table
+    [B, M]; index [B].  ``score_h(i, j) = q_h,i . row_j * scale``, causal
+    over the whole history, f32 softmax; returns
+    ``sum_j p_h(i, j) row_j[:value_lanes]`` as [B, S, H, value_lanes] in
+    q's dtype.  Same write-then-attend contract as
+    :func:`paged_attention`."""
+    rows = gather_pages(pool, block_table).astype(jnp.float32)  # [B, L, W]
+    scores = jnp.einsum("bqhw,bkw->bhqk", q.astype(jnp.float32),
+                        rows) * scale
+    scores = jnp.where(_seen(q, rows, index)[:, None], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1)
+    o = jnp.einsum("bhqk,bkv->bqhv", probs, rows[..., :value_lanes])
+    return o.astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -311,10 +339,21 @@ def _paged_decode_kernel(tbl_ref, idx_ref, q_ref, k_hbm, v_hbm, o_ref,
     ``window`` positions up to its own, and the loop starts at the
     first block the chunk's first query can see, so a row costs
     ``min(len, window + S)`` tokens and not ``len``.  All three unset is
-    the kernel of full heads over the whole history, unchanged."""
+    the kernel of full heads over the whole history, unchanged.
+
+    Latent pool (``v_hbm`` and ``vbuf`` None; ``k_hbm`` ``[P, page, W]``,
+    ``kbuf`` ``[2, ppb, page, W]``): a token is ONE row of ``W`` values
+    that every query head scores against, and the row's first lanes (as
+    many as ``o_ref`` is wide) are the value, so a page is copied once
+    and read once, as a ``[T, W]`` matrix with no strided load.  The
+    query heads are the grouped form's rows over the one "KV head"."""
     b = pl.program_id(0)
     g = pl.program_id(1)
-    _, ppb, page_size, h, d = kbuf.shape
+    latent = v_hbm is None
+    if latent:
+        (_, ppb, page_size, d), h = kbuf.shape, 1
+    else:
+        _, ppb, page_size, h, d = kbuf.shape
     heads, s, _ = q_ref.shape
     t = ppb * page_size
     m_pages = tbl_ref.shape[1]
@@ -339,8 +378,11 @@ def _paged_decode_kernel(tbl_ref, idx_ref, q_ref, k_hbm, v_hbm, o_ref,
 
     def copies(blk, slot, p):
         pid = tbl_ref[b, blk * ppb + p]
-        return (pltpu.make_async_copy(k_hbm.at[pid], kbuf.at[slot, p],
-                                      sem.at[0, slot]),
+        k_copy = pltpu.make_async_copy(k_hbm.at[pid], kbuf.at[slot, p],
+                                       sem.at[0, slot])
+        if latent:
+            return (k_copy,)
+        return (k_copy,
                 pltpu.make_async_copy(v_hbm.at[pid], vbuf.at[slot, p],
                                       sem.at[1, slot]))
 
@@ -351,7 +393,8 @@ def _paged_decode_kernel(tbl_ref, idx_ref, q_ref, k_hbm, v_hbm, o_ref,
 
         def zero(p):
             kbuf[slot, p] = jnp.zeros(kbuf.shape[2:], kbuf.dtype)
-            vbuf[slot, p] = jnp.zeros(vbuf.shape[2:], vbuf.dtype)
+            if not latent:
+                vbuf[slot, p] = jnp.zeros(vbuf.shape[2:], vbuf.dtype)
 
         n = live_pages(blk)
         for_pages(0, n, copy)
@@ -377,7 +420,8 @@ def _paged_decode_kernel(tbl_ref, idx_ref, q_ref, k_hbm, v_hbm, o_ref,
 
         wait(blk, slot)
         kflat = kbuf.at[slot].reshape(t * h, d)
-        vflat = vbuf.at[slot].reshape(t * h, d)
+        if not latent:
+            vflat = vbuf.at[slot].reshape(t * h, d)
         kpos = blk * t + jax.lax.broadcasted_iota(jnp.int32, (1, t), 1)
         qrow = jax.lax.broadcasted_iota(jnp.int32, (s, 1), 0)
         if q_len is not None:
@@ -387,6 +431,17 @@ def _paged_decode_kernel(tbl_ref, idx_ref, q_ref, k_hbm, v_hbm, o_ref,
         if window is not None:
             seen &= kpos > qpos - window
         bias = jnp.where(seen, 0.0, bw.NEG_INF)
+        if latent:
+            # the block IS the keys of every query head, and its first
+            # lanes their values: read once, one pair of matmuls
+            rows = kflat[...]
+            o, m, l = bw.block_accumulate(
+                oacc_ref[0], m_ref[0][:, 0], l_ref[0][:, 0], q_ref[0],
+                rows, rows[:, :oacc_ref.shape[-1]], scale, bias)
+            oacc_ref[0] = o
+            m_ref[0] = m[:, None]
+            l_ref[0] = l[:, None]
+            return carry
 
         def head_words(i, c):
             ks = _head_rows(kflat, g * heads + i * pack, h, t)
@@ -408,10 +463,75 @@ def _paged_decode_kernel(tbl_ref, idx_ref, q_ref, k_hbm, v_hbm, o_ref,
         oacc_ref[...], l_ref[...][..., 0]).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("scale", "interpret", "window"))
+# a latent call's tiling: query rows a grid point holds (its f32 carry is
+# [rows, value lanes]), tokens a block streams (one buffer is [tokens, W])
+# and the f32 score tile [rows, tokens] the two make, which bounds them
+# together: beside a compiled body's own VMEM a (1024, 1024) tile does not
+# fit, (1024, 512) and (512, 1024) do.  Measured on the v5e at 32 heads over
+# rows of 640 lanes (PERF.md §6 PR 32): a decode step of 24 rows (32 query
+# rows each), 254k tokens, 0.87 / 0.65 / 0.58 / 0.61 ms at 256 / 512 / 1024
+# / 2048 tokens a block; a 1,024-token chunk over 16,384 cached 8.8 ms at
+# (512, 512), 8.5 at (512, 1024), 8.1 at (1024, 512)
+_LATENT_ROWS = 1024
+_LATENT_BLOCK_TOKENS = 1024
+_LATENT_SCORE_ELEMS = 512 * 1024
+
+
+def _latent_flash_decode(q, pool, block_table, index, *, scale, interpret,
+                         value_lanes):
+    """:func:`paged_flash_decode` over a latent pool ``[P, page, W]``: q
+    [B, S, H, W] -> [B, S, H, value_lanes].  The H query heads are
+    ``H * S`` rows over the one row a token; past ``_LATENT_ROWS`` the
+    grid's second axis walks blocks of them, each streaming the row's
+    pages again."""
+    b, s, hq, w = q.shape
+    page_size = pool.shape[1]
+    m_pages = block_table.shape[1]
+    qh = jnp.swapaxes(q, 1, 2).reshape(b, 1, hq * s, w)
+    rows = hq * s
+    while rows > _LATENT_ROWS and rows % 16 == 0:
+        rows //= 2
+    row_blocks = hq * s // rows
+    block_tokens = min(_LATENT_BLOCK_TOKENS, _LATENT_SCORE_ELEMS // rows)
+    ppb = 1
+    while ppb * 2 <= m_pages and ppb * 2 * page_size <= block_tokens:
+        ppb *= 2
+
+    def spec(lanes):
+        return pl.BlockSpec((None, 1, rows, lanes),
+                            lambda b_, g_, tbl, idx: (b_, 0, g_, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, row_blocks),
+        in_specs=[spec(w), pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=spec(value_lanes),
+        scratch_shapes=[
+            pltpu.VMEM((2, ppb, page_size, w), pool.dtype),
+            pltpu.SemaphoreType.DMA((1, 2)),
+            pltpu.VMEM((1, rows, value_lanes), jnp.float32),
+            pltpu.VMEM((1, rows, 1), jnp.float32),
+            pltpu.VMEM((1, rows, 1), jnp.float32),
+        ],
+    )
+
+    def kernel(tbl_ref, idx_ref, q_ref, k_hbm, o_ref, kbuf, sem, *carry):
+        _paged_decode_kernel(tbl_ref, idx_ref, q_ref, k_hbm, None, o_ref,
+                             kbuf, None, sem, *carry, scale=scale, q_len=s,
+                             row_blocks=row_blocks)
+    out = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, 1, hq * s, value_lanes), q.dtype),
+        interpret=interpret, name="paged_flash_decode",
+    )(jnp.asarray(block_table, jnp.int32), jnp.asarray(index, jnp.int32),
+      qh, pool)
+    return jnp.swapaxes(out.reshape(b, hq, s, value_lanes), 1, 2)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "window",
+                                             "value_lanes"))
 def paged_flash_decode(q, pool_k, pool_v, block_table, index, *,
-                       scale=None, interpret: bool = False, window=None):
+                       scale=None, interpret: bool = False, window=None,
+                       value_lanes=None):
     """Attention of a chunk of queries over a slot's paged KV history,
     streaming each row's LIVE pages out of the pool as it is stored.
 
@@ -425,14 +545,27 @@ def paged_flash_decode(q, pool_k, pool_v, block_table, index, *,
     index.  Tiling follows the static shapes (:func:`_plan`).  Jitted,
     so that the layers of a model, which call it at one shape, share
     one trace and one lowering of the kernel: traced per layer it was
-    25 s of every serve process's set-up."""
+    25 s of every serve process's set-up.
+
+    ``value_lanes`` set (and ``pool_v`` None): ``pool_k`` is a latent pool
+    ``[P, page_size, W]`` — one row a token for every query head, whose
+    first ``value_lanes`` lanes are the value — with
+    :func:`latent_paged_attention`'s contract (``scale`` is then required:
+    the row's width is not a head's)."""
+    if pool_k.dtype not in (jnp.bfloat16, jnp.float32):
+        raise ValueError(f"paged_flash_decode reads bf16 or f32 pools, "
+                         f"not {pool_k.dtype} (see _head_rows)")
+    if value_lanes is not None:
+        if window is not None or pool_v is not None:
+            raise ValueError("a latent pool is one pool and has no window "
+                             "form")
+        return _latent_flash_decode(
+            q, pool_k, block_table, index, scale=float(scale),
+            interpret=interpret, value_lanes=int(value_lanes))
     b, s, hq, d = q.shape
     page_size, h = pool_k.shape[1], pool_k.shape[2]
     m_pages = block_table.shape[1]
     scale = float(scale) if scale is not None else 1.0 / (d ** 0.5)
-    if pool_k.dtype not in (jnp.bfloat16, jnp.float32):
-        raise ValueError(f"paged_flash_decode reads bf16 or f32 pools, "
-                         f"not {pool_k.dtype} (see _head_rows)")
     group, rem = divmod(hq, h)
     if rem or group < 1:
         raise ValueError(f"{hq} query heads do not share {h} KV heads")
@@ -538,7 +671,8 @@ def paged_flash_decode_reference(q, pool_k, pool_v, block_table, index, *,
 
 
 def paged_attention_auto(q, pool_k, pool_v, block_table, index, *,
-                         window_pages=None, use_pallas=None, window=None):
+                         window_pages=None, use_pallas=None, window=None,
+                         scale=None, value_lanes=None):
     """Dispatch between the kernel and the gather oracle.
 
     ``use_pallas``: None = auto (kernel on TPU — the default-on flag —
@@ -547,9 +681,20 @@ def paged_attention_auto(q, pool_k, pool_v, block_table, index, *,
     ``window_pages`` (static) trims the GATHER path's window exactly as
     before; the kernel ignores it — its loop stops at the row's own
     last page without a per-window recompile.  ``window`` (static) is
-    the layer's attention window in tokens, for both."""
+    the layer's attention window in tokens, for both.  ``value_lanes``
+    set: ``pool_k`` is a latent pool and ``pool_v`` None (``scale`` and
+    ``value_lanes`` say what the row is; :func:`latent_paged_attention`)."""
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
+    if value_lanes is not None:
+        if use_pallas:
+            return paged_flash_decode(
+                q, pool_k, None, block_table, index, scale=scale,
+                interpret=use_pallas == "interpret", value_lanes=value_lanes)
+        table = (block_table if window_pages is None
+                 else block_table[:, :window_pages])
+        return latent_paged_attention(q, pool_k, table, index,
+                                      value_lanes=value_lanes, scale=scale)
     if use_pallas:
         return paged_flash_decode(q, pool_k, pool_v, block_table, index,
                                   interpret=use_pallas == "interpret",

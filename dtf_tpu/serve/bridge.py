@@ -143,12 +143,14 @@ def serving_memory_plan(model, *, num_slots: int, max_seq_len: int,
                         model_parallelism: int = 1, params=None) -> dict:
     """Byte accounting for a serving deployment: params + KV cache.
 
-    The geometry is the model's own: ``num_kv_heads`` and ``head_dim``
-    where it names them (grouped-query families cache fewer heads than
-    they query), else ``num_heads`` heads of ``d_model // num_heads``;
-    ``param_bytes`` is what the parameter tree holds in the dtype it is
-    held in (``params``: the tree or its shapes; None = the shapes of
-    the model's own init).
+    The cache's geometry is what the model's own paged init STORES
+    (``serve.decode.trace_paged_init``: one abstract trace, nothing
+    materialised): ``per_token_kv_bytes`` sums, over every pool of every
+    layer, one token's row — K and V of ``kv_heads x head_dim`` for whole
+    or grouped-query heads, one latent row (``kv_heads`` 1, ``head_dim``
+    its stored lanes) for latent attention.  ``param_bytes`` is what the
+    parameter tree holds in the dtype it is held in (``params``: the
+    tree or its shapes; None = the shapes of the model's own init).
 
     The KV page pool holds ``(kv_pool_pages − 1) × kv_page_size``
     tokens TOTAL — sized to the expected tokens in flight, not the
@@ -159,13 +161,15 @@ def serving_memory_plan(model, *, num_slots: int, max_seq_len: int,
     guess."""
     import numpy as np
 
-    kv_heads = getattr(model, "num_kv_heads", None) or model.num_heads
-    head_dim = (getattr(model, "head_dim", None)
-                or model.d_model // model.num_heads)
-    # 2 arrays (K and V) per layer; cache dtype follows compute dtype
-    # (np.dtype resolves jnp scalar types incl. bfloat16 via ml_dtypes)
-    elem = np.dtype(model.dtype).itemsize
-    per_token = 2 * model.num_layers * kv_heads * head_dim * elem
+    from dtf_tpu.serve.decode import trace_paged_init
+
+    pools = jax.tree_util.tree_leaves(
+        trace_paged_init(model, kv_page_size, 2)[0])
+    per_token = sum(int(np.prod(p.shape[2:])) * np.dtype(p.dtype).itemsize
+                    for p in pools)
+    # [P, page, H, Dh] a K or V pool; [P, page, W] a pool of latent rows
+    kv_heads = pools[0].shape[2] if pools[0].ndim == 4 else 1
+    head_dim = pools[0].shape[-1]
     if params is None:
         params = jax.eval_shape(
             model.init, jax.random.key(0),
@@ -195,9 +199,9 @@ def serving_memory_plan(model, *, num_slots: int, max_seq_len: int,
     }
     log.info(
         "serving memory plan: %d slots x %d tokens; weights %.1f MB; "
-        "%d KV heads x %d, %d B/token; KV page pool %.1f MB "
+        "%d pools of %d x %d a token, %d B/token; KV page pool %.1f MB "
         "(%d pages x %d tokens)%s", num_slots, max_seq_len,
-        param_bytes / 2**20, kv_heads, head_dim, per_token,
+        param_bytes / 2**20, len(pools), kv_heads, head_dim, per_token,
         plan["kv_bytes_paged"] / 2**20, pool_pages, kv_page_size,
         (f", TP={mp}: {plan['kv_bytes_per_device'] / 2**20:.1f} "
          f"MB KV/device" if mp > 1 else ""))

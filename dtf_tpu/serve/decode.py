@@ -14,8 +14,11 @@ around it:
   - ``teacher_forced_logits`` — the training-style forward, the oracle
                           the decode path is verified token-exact against
 
-The cache is a shared [pool_pages, page_size, H, Dh] pool per layer
-plus per-slot block tables (ops.paged_attention).  Prefill runs in
+The cache is a shared pool per layer — [pool_pages, page_size, H, Dh]
+for K and for V, or one [pool_pages, page_size, W] of latent rows, as the
+model's attention stores it — plus per-slot block tables
+(ops.paged_attention); this module treats it as a tree of leaves whose
+first axis is the page.  Prefill runs in
 page-aligned chunks: the FIRST chunk goes through the flash kernel
 (pure causal self-attention, no gather), later chunks attend the paged
 prefix.  Work scales with the PROMPT length, not the cache capacity,
@@ -82,7 +85,8 @@ def trace_paged_init(model, kv_page_size: int, kv_pool_pages: int):
     """ONE abstract trace of the paged decode model's init (no params —
     and no cache — materialized), for the two things it shows: the
     ShapeDtypeStruct pytree of the paged cache (a
-    [kv_pool_pages, kv_page_size, H, Dh] pool per layer per K/V), and
+    [kv_pool_pages, kv_page_size, H, Dh] pool per layer per K/V, or one
+    [kv_pool_pages, kv_page_size, W] of latent rows per layer), and
     ``owners`` — by path ("block0/attn/qkv"; "" is the model itself) the
     (class, dtype) of every module that ran."""
     import flax.linen as nn
@@ -224,15 +228,16 @@ class Decoder:
             else jax.default_backend() == "tpu")
         self._decode = jax.jit(self._decode_paged_impl,
                                donate_argnums=(1,), compiler_options=xla)
-        # COW page copy (engine prefix sharing): one whole
-        # [page_size, H, Dh] row per layer per K/V — page dim is
-        # unsharded, so the copy is shard-local under TP too
+        # COW page copy (engine prefix sharing): one whole page of
+        # every leaf ([page_size, H, Dh] per K/V, [page_size, W] of a
+        # latent pool) — page dim is unsharded, so the copy is
+        # shard-local under TP too
         self._copy_page = jax.jit(
             lambda cache, src, dst: jax.tree_util.tree_map(
                 lambda c: c.at[dst].set(c[src]), cache),
             donate_argnums=(0,))
-        # migration import: write a host page payload (one
-        # [page_size, H, Dh] row per leaf) into pool page ``dst``
+        # migration import: write a host page payload (one page per
+        # leaf, in the leaf's own shape) into pool page ``dst``
         self._write_page = jax.jit(
             lambda cache, dst, payload: jax.tree_util.tree_map(
                 lambda c, p: c.at[dst].set(p.astype(c.dtype)),
@@ -328,8 +333,10 @@ class Decoder:
     def read_page(self, cache, page: int):
         """Host copy of pool page ``page`` from every layer's K and V
         pool — the migration EXPORT primitive (serve/migrate.py).
-        Returns a flat LIST of [page_size, H, Dh] numpy leaves in
-        ``tree_leaves`` order (deterministic for a given model, so the
+        Returns a flat LIST of numpy leaves, each one page of its pool
+        (``[page_size, H, Dh]`` of K or V, ``[page_size, W]`` of a
+        latent pool), in ``tree_leaves`` order (deterministic for a given
+        model, so the
         sender's list zips onto the receiver's cache leaves).  Pure
         device_get, no casts or layout changes: the bytes are exactly
         what the device holds, which is what the bit-identity contract
@@ -343,7 +350,12 @@ class Decoder:
         primitive.  The pool's page dim is unsharded under TP (the
         head dim shards), so a whole-page write lowers to shard-local
         updates, same as :meth:`copy_page`."""
-        treedef = jax.tree_util.tree_structure(cache)
+        pools, treedef = jax.tree_util.tree_flatten(cache)
+        shapes = [tuple(np.shape(a)) for a in leaves]
+        if shapes != [c.shape[1:] for c in pools]:
+            raise ValueError(
+                f"page payload of leaves {shapes} does not fit this "
+                f"cache's pages {[c.shape[1:] for c in pools]}")
         payload = jax.tree_util.tree_unflatten(
             treedef, [jnp.asarray(a) for a in leaves])
         return self._write_page(cache, jnp.asarray(int(page), jnp.int32),

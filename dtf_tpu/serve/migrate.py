@@ -40,6 +40,12 @@ server socket and speaks two ops:
 
 VERIFICATION is layered, and each layer catches a different lie:
 
+  payload leaves   — one page of every pool of the sender's cache, each
+      in its pool's own shape (``[page, H, Dh]`` of K and of V a layer,
+      or one ``[page, W]`` of latent rows a layer): the importer
+      (``Decoder.write_page``) refuses a payload whose leaves are not
+      its own cache's pages — two replicas of different models, or of
+      different page sizes, cannot exchange pages.
   payload digest   — sha1 over every leaf's dtype/shape/bytes.  A
       mismatch is a TORN TRANSFER (bit rot, truncation, a bug):
       loud ``migration_torn`` anomaly + bounded re-fetch of that one

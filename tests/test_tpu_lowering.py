@@ -189,3 +189,110 @@ def test_flash_fwd_bwd_lowers_for_tpu(heads, seq):
                           shape, shape, shape)
     # fwd + fused bwd = 2 kernels; fwd + dq + dkdv = 3
     assert text.count("tpu_custom_call") == (2 if seq <= 4096 else 3)
+
+
+@pytest.mark.parametrize("batch,s", [(24, 1), (1, 1024)],
+                         ids=["decode", "chunk"])
+def test_latent_paged_kernel_compiles_for_v5e(v5e, batch, s):
+    """The latent pool's shapes: 32 query heads over ONE row of 640 stored
+    lanes a token (576 values; Mosaic refuses a page DMA of 576 lanes, and
+    the TPU's tiling stores 640 for them anyway), pages of 64 in a
+    393,216-token pool, 34,816-token rows (a 544-word table row), a decode
+    step of 24 rows and a 1,024-token chunk (32,768 query rows: walked in
+    row blocks).  One pool in, no copy of it."""
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    page, pool, m, w = 64, 6145, 544, 640
+    shapes = (((batch, s, 32, w), bf16), ((pool, page, w), bf16),
+              ((batch, m), i32), ((batch,), i32))
+    args = [jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+            for shape, dt in shapes]
+
+    def attend(q, rows, table, index):
+        return pa.paged_flash_decode(q, rows, None, table, index,
+                                     scale=192 ** -0.5, value_lanes=512)
+    text = jax.jit(attend).lower(*args).compile().as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text
+    makers = set(re.findall(
+        rf"= bf16\[{pool},{page},{w}\]\S* ([\w-]+)\(", text))
+    assert makers <= {"parameter"}, makers
+
+
+@pytest.mark.parametrize("tokens", [24, 1024], ids=["decode", "chunk"])
+def test_grouped_expert_matmuls_of_256_experts_compile_for_v5e(v5e, tokens):
+    """The expert layer at 256 experts of 2048 x 768 in bf16, gated SiLU,
+    top-8: 192 pairs (under a row an expert) and 8,192 pairs (32 rows an
+    expert) at the default row tile."""
+    from dtf_tpu.models.routed_decoder import routed_experts
+    bf16 = jnp.bfloat16
+    shapes = (((tokens, 2048), bf16), ((tokens, 8), jnp.int32),
+              ((tokens, 8), jnp.float32), ((256, 2048, 1536), bf16),
+              ((256, 768, 2048), bf16))
+    args = [jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+            for shape, dt in shapes]
+    text = jax.jit(functools.partial(routed_experts, use_pallas=True,
+                                     activation="silu")
+                   ).lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') >= 2
+
+
+@pytest.mark.parametrize("body", ["chunk", "decode"])
+def test_latent_serve_bodies_compile_for_v5e(v5e, body):
+    """The whole compiled body, not the kernel alone: beside the body's
+    own use of VMEM (XLA's weight prefetches under ``TPU_BODY_OPTIONS``)
+    the latent kernel's tile has less room than it has alone — a
+    (1024 rows, 1024 tokens) tile compiles standalone and runs out of
+    VMEM "allocating on stack" inside the 1,024-token chunk body, on the
+    chip and here alike (PR 32).  Shapes: the latent-attention decoder at
+    its benchmark widths (5 layers, 256 experts, vocabulary 129,280;
+    shapes only, nothing materialised), 24 slots of 34,816 tokens, pages
+    of 64 in a 393,216-token pool."""
+    from dtf_tpu.models import build_model
+    from dtf_tpu.serve import decode as sd
+
+    class ShapesOnly(sd.Decoder):
+        def _held(self, params):        # no arrays to cast
+            return params
+    i32, f32 = jnp.int32, jnp.float32
+    model, _ = build_model(
+        "routed_decoder", num_classes=129280, dtype=jnp.bfloat16,
+        num_layers=5, d_model=2048, num_heads=32, q_lora_rank=1536,
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, rope_theta=32e6, rope_interleave=True,
+        num_dense_layers=1, dense_width=7168, num_experts=256,
+        experts_per_token=8, expert_width=768, shared_expert_width=768,
+        routing="sigmoid_bias", routed_scale=2.5, activation="silu",
+        router_input="post_attention", max_seq_len=131072,
+        param_dtype="bfloat16")
+    params = jax.eval_shape(model.clone(use_pallas=False).init,
+                            jax.random.key(0), jnp.zeros((1, 64), i32)
+                            )["params"]
+    dec = ShapesOnly(model.clone(use_pallas=True), params, num_slots=24,
+                     max_seq_len=34816, kv_page_size=64, kv_pool_pages=6145)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+            tree)
+    cache, m = jax.eval_shape(dec.fresh_cache), dec.pages_per_slot
+    s = jax.ShapeDtypeStruct
+    if body == "chunk":
+        args = on_chip((params, cache, s((1, 1024), i32), s((1, m), i32),
+                        s((), i32), s((), f32),
+                        jax.eval_shape(lambda: sd.position_key(0, 0)),
+                        s((), i32)))
+        compiled = jax.jit(
+            dec._chunk_impl, donate_argnums=(1,), static_argnums=(8, 9),
+            compiler_options=sd.TPU_BODY_OPTIONS).lower(
+                *args, None, False).compile()
+    else:
+        keys = jax.eval_shape(lambda: sd._seed_row_keys(
+            jnp.zeros((24,), jnp.uint32), jnp.zeros((24,), i32)))
+        args = on_chip((params, cache, s((24, 1), i32), s((24,), i32),
+                        s((24, m), i32), s((24,), f32), keys))
+        compiled = jax.jit(
+            dec._decode_paged_impl, donate_argnums=(1,),
+            compiler_options=sd.TPU_BODY_OPTIONS).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("paged_flash_decode") >= 5        # a call a layer
+    # every pool is donated and updated in place: no second pool exists
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9
