@@ -55,9 +55,12 @@ Two formulations of attention-over-pages coexist:
       transposed, gathered or relaid-out copy of a pool exists.
 
       A block in the stored order is a ``[T · H, Dh]`` matrix whose row
-      ``t · H + h`` is token t of head h: each head's ``[T, Dh]`` rows
-      are pulled out with a sublane-strided load and the heads take
-      turns.  How many pages make a block, and how many heads share a
+      ``t · H + h`` is token t of head h.  Where a grid point holds few
+      query rows (a decode step) they score against the whole matrix
+      in one pair of matmuls, the other heads' columns masked; where it
+      holds many (a chunk) each head's ``[T, Dh]`` rows are pulled out
+      with a sublane-strided load and the heads take turns.  Which of
+      the two, how many pages make a block, and how many heads share a
       grid point, follows from the static shapes (``_plan``).
 
 ``paged_attention_auto`` dispatches between them: the kernel by default
@@ -264,10 +267,28 @@ def _head_bytes(s, d, itemsize):
     return s * (d * 4 + 2 * 128 * 4 + 4 * d * itemsize)
 
 
+# when a grid point scores a block all heads at once — one [R, T·H] score
+# tile whose other-head columns are masked, H times the FLOPs of the
+# head-by-head form on an MXU that a one-row matmul leaves idle: at most
+# this many query rows (every head's together) over at least this many
+# heads.  Kernel alone on the v5e, bf16, 24 chained calls (PERF.md §6 PR 33),
+# head by head -> all at once, ms a call: 16 heads, 48 rows of 20k tokens,
+# R = 16 / 128 / 256: 0.721 -> 0.294, 0.719 -> 0.376, 0.744 -> 0.536 (a
+# [256, 2048] f32 tile is 2 MiB beside a body's own VMEM: not taken);
+# 8 heads R = 8 / 128: 0.379 -> 0.180, 0.389 -> 0.253; but 4 heads
+# (28 query heads, pages of 64) R = 28: 0.271 -> 0.286 — a strided load of
+# 2-word stride is cheap and a fourfold tile is not.  The head count is the
+# measured bound; the row count is a VMEM guard that no caller reaches (a
+# decode step is R = H, and a chunk of 16 heads fails ``hg == h`` first),
+# timed with the kernel alone only: not a bound to tune for speed
+_ALL_HEADS_ROWS = 128
+_ALL_HEADS_MIN_HEADS = 8
+
+
 def _plan(s, h, d, page_size, m_pages, itemsize):
     """Static tiling from what a trace can see: ``(pages_per_block,
-    heads_per_group)``.  ``s`` is the query rows a KV head meets: the
-    chunk's length times the query heads that share the head.
+    heads_per_group, all_heads)``.  ``s`` is the query rows a KV head
+    meets: the chunk's length times the query heads that share the head.
 
     ``pages_per_block``: the largest power of two whose K+V double
     buffer takes half the budget, at most the table's width.
@@ -276,7 +297,11 @@ def _plan(s, h, d, page_size, m_pages, itemsize):
     other half.  All of them at a decode step; a 256-token chunk of 16
     heads takes 2, and the grid's second axis walks the groups, each
     streaming the row's pages again — a chunk reuses every page S
-    times, so the repeat is cheap exactly where a split is needed."""
+    times, so the repeat is cheap exactly where a split is needed.
+    ``all_heads``: every head is in the one grid point, they are many
+    and their rows together few (``_ALL_HEADS_MIN_HEADS``,
+    ``_ALL_HEADS_ROWS``), so a block is scored as the one ``[T·H, Dh]``
+    matrix it is stored as; otherwise head by head."""
     page_bytes = page_size * h * d * itemsize
     ppb = 1
     while (ppb * 2 <= m_pages
@@ -288,7 +313,34 @@ def _plan(s, h, d, page_size, m_pages, itemsize):
     while hg > pack and (hg * head_bytes > _VMEM_BUDGET // 2
                          or h % hg or hg % pack):
         hg -= 1
-    return ppb, hg
+    all_heads = (hg == h and h >= _ALL_HEADS_MIN_HEADS
+                 and h * s <= _ALL_HEADS_ROWS)
+    return ppb, hg, all_heads
+
+
+def _tiling(s, hq, h, d, page_size, m_pages, itemsize):
+    """``(rows, row_blocks) + _plan(rows, ...)`` of a call: S queries of
+    ``hq`` query heads over ``h`` KV heads (a count that tiles).  The
+    ``hq // h`` query heads of a KV head are ``rows`` rows of that head,
+    halved into ``row_blocks`` blocks where they outgrow the budget."""
+    rows, row_blocks = hq // h * s, 1
+    pack = 4 // itemsize
+    while (hq > h and rows % 16 == 0
+           and pack * _head_bytes(rows, d, itemsize) > _VMEM_BUDGET // 2):
+        rows //= 2
+        row_blocks *= 2
+    return (rows, row_blocks) + _plan(rows, h, d, page_size, m_pages,
+                                      itemsize)
+
+
+def decode_scores_all_heads(q_heads, kv_heads, head_dim, page_size, m_pages,
+                            itemsize) -> bool:
+    """Whether :func:`paged_flash_decode` scores a block all heads at
+    once (``_plan``) at a decode step — one query a row — of these
+    shapes: what the serving engine publishes about its decode body."""
+    h = _tiled_heads(kv_heads, itemsize)
+    return _tiling(1, q_heads // kv_heads * h, h, head_dim, page_size,
+                   m_pages, itemsize)[-1]
 
 
 def _head_rows(flat_ref, head, h, t):
@@ -308,7 +360,8 @@ def _head_rows(flat_ref, head, h, t):
 
 def _paged_decode_kernel(tbl_ref, idx_ref, q_ref, k_hbm, v_hbm, o_ref,
                          kbuf, vbuf, sem, oacc_ref, m_ref, l_ref, *, scale,
-                         q_len=None, row_blocks=1, window=None):
+                         q_len=None, row_blocks=1, window=None,
+                         head_rows=None):
     """Grid (B, head groups): one row streams ITS pages, block by block.
 
     ``tbl_ref`` [B, M] and ``idx_ref`` [B] are scalar-prefetched (SMEM);
@@ -340,6 +393,20 @@ def _paged_decode_kernel(tbl_ref, idx_ref, q_ref, k_hbm, v_hbm, o_ref,
     first block the chunk's first query can see, so a row costs
     ``min(len, window + S)`` tokens and not ``len``.  All three unset is
     the kernel of full heads over the whole history, unchanged.
+
+    All heads at once (``head_rows`` set, with ``q_len``): ``q_ref``
+    [1, R, Dh] holds every KV head's rows as ONE matrix, ``head_rows`` of
+    them a head (row ``r`` is KV head ``r // head_rows``, the query at
+    ``r % head_rows % q_len``), and a block is scored as the
+    ``[T·H, Dh]`` matrix it is: no strided load, one pair of matmuls.
+    Column ``c`` is token ``c // H`` of head ``c % H``; the bias is the
+    positional mask at ``c // H`` and ``NEG_INF`` in every other head's
+    column, whose probability is then exactly 0 and adds an exact 0 of
+    that head's value row.  Contract of this form: the live V rows of a
+    page a row may read are finite in EVERY head (0 x NaN is NaN in
+    ``p @ vflat``, so one head's non-finite value reaches all heads of
+    the row, where head by head it reached that head alone); dead pages
+    are zeroed before use, as in every form.
 
     Latent pool (``v_hbm`` and ``vbuf`` None; ``k_hbm`` ``[P, page, W]``,
     ``kbuf`` ``[2, ppb, page, W]``): a token is ONE row of ``W`` values
@@ -422,22 +489,32 @@ def _paged_decode_kernel(tbl_ref, idx_ref, q_ref, k_hbm, v_hbm, o_ref,
         kflat = kbuf.at[slot].reshape(t * h, d)
         if not latent:
             vflat = vbuf.at[slot].reshape(t * h, d)
-        kpos = blk * t + jax.lax.broadcasted_iota(jnp.int32, (1, t), 1)
+        kpos = blk * t
+        col = jax.lax.broadcasted_iota(
+            jnp.int32, (1, t * h if head_rows else t), 1)
+        kpos += jax.lax.div(col, h) if head_rows else col
         qrow = jax.lax.broadcasted_iota(jnp.int32, (s, 1), 0)
+        if head_rows:
+            own = jax.lax.rem(col, h) == jax.lax.div(qrow, head_rows)
+            qrow = jax.lax.rem(qrow, head_rows)
         if q_len is not None:
             qrow = (row0 + qrow) % q_len
         qpos = idx + qrow
         seen = kpos <= qpos
         if window is not None:
             seen &= kpos > qpos - window
+        if head_rows:
+            seen &= own
         bias = jnp.where(seen, 0.0, bw.NEG_INF)
-        if latent:
-            # the block IS the keys of every query head, and its first
-            # lanes their values: read once, one pair of matmuls
+        if latent or head_rows:
+            # the block IS the keys of every row of q (a latent block's
+            # first lanes their values too): read once, one pair of
+            # matmuls
             rows = kflat[...]
             o, m, l = bw.block_accumulate(
                 oacc_ref[0], m_ref[0][:, 0], l_ref[0][:, 0], q_ref[0],
-                rows, rows[:, :oacc_ref.shape[-1]], scale, bias)
+                rows, rows[:, :oacc_ref.shape[-1]] if latent else vflat[...],
+                scale, bias)
             oacc_ref[0] = o
             m_ref[0] = m[:, None]
             l_ref[0] = l[:, None]
@@ -542,7 +619,10 @@ def paged_flash_decode(q, pool_k, pool_v, block_table, index, *,
     stored layout — no transposed or gathered copy exists; the work of
     a row is proportional to its own length (to ``window + S`` under a
     window), not to the table's width; one compile covers every chunk
-    index.  Tiling follows the static shapes (:func:`_plan`).  Jitted,
+    index.  Tiling follows the static shapes (:func:`_plan`); where that
+    scores a block all heads at once (a decode step of 8 heads or more),
+    the written V rows of a page a row reads must be finite in every
+    head, not only in the head that reads them.  Jitted,
     so that the layers of a model, which call it at one shape, share
     one trace and one lowering of the kernel: traced per layer it was
     25 s of every serve process's set-up.
@@ -581,17 +661,21 @@ def paged_flash_decode(q, pool_k, pool_v, block_table, index, *,
             block_table, index, scale=scale, interpret=interpret,
             window=window)[:, :, :hq]
     qh = jnp.swapaxes(q, 1, 2)                       # [B, H, S, D], q alone
-    rows, row_blocks = s, 1
     if group > 1:
         # the G query heads of a KV head as G * S rows of that head
         qh = qh.reshape(b, h, group * s, d)
-        rows = group * s
-        pack = 4 // pool_k.dtype.itemsize
-        while (pack * _head_bytes(rows, d, pool_k.dtype.itemsize)
-               > _VMEM_BUDGET // 2 and rows % 16 == 0):
-            rows //= 2
-        row_blocks = group * s // rows
-    ppb, hg = _plan(rows, h, d, page_size, m_pages, pool_k.dtype.itemsize)
+    rows, row_blocks, ppb, hg, all_heads = _tiling(
+        s, hq, h, d, page_size, m_pages, pool_k.dtype.itemsize)
+    kernel_kw = {"scale": scale}
+    if all_heads:
+        # every head's rows as the one matrix a block is scored against
+        kernel_kw.update(q_len=s, head_rows=rows)
+        qh = qh.reshape(b, 1, h * rows, d)
+        hg, rows, groups = 1, h * rows, 1
+    else:
+        groups = h // hg * row_blocks
+        if group > 1:
+            kernel_kw.update(q_len=s, row_blocks=row_blocks)
     if row_blocks > 1:
         qo_spec = pl.BlockSpec(
             (None, hg, rows, d),
@@ -602,7 +686,7 @@ def paged_flash_decode(q, pool_k, pool_v, block_table, index, *,
                                lambda b_, g_, tbl, idx: (b_, g_, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, h // hg * row_blocks),
+        grid=(b, groups),
         in_specs=[qo_spec,
                   pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],
@@ -616,9 +700,6 @@ def paged_flash_decode(q, pool_k, pool_v, block_table, index, *,
             pltpu.VMEM((hg, rows, 1), jnp.float32),
         ],
     )
-    kernel_kw = {"scale": scale}
-    if group > 1:
-        kernel_kw.update(q_len=s, row_blocks=row_blocks)
     if window is not None:
         kernel_kw["window"] = int(window)
     out = pl.pallas_call(
@@ -629,7 +710,7 @@ def paged_flash_decode(q, pool_k, pool_v, block_table, index, *,
         name="paged_flash_decode",
     )(jnp.asarray(block_table, jnp.int32), jnp.asarray(index, jnp.int32),
       qh, pool_k, pool_v)
-    if group > 1:
+    if group > 1 or all_heads:
         out = out.reshape(b, hq, s, d)
     return jnp.swapaxes(out, 1, 2)
 
@@ -649,8 +730,8 @@ def paged_flash_decode_reference(q, pool_k, pool_v, block_table, index, *,
     page_size = pool_k.shape[1]
     m_pages = block_table.shape[1]
     scale = float(scale) if scale is not None else 1.0 / (d ** 0.5)
-    ppb, _ = _plan(s, _tiled_heads(h, pool_k.dtype.itemsize), d, page_size,
-                   m_pages, pool_k.dtype.itemsize)
+    ppb, _, _ = _plan(s, _tiled_heads(h, pool_k.dtype.itemsize), d, page_size,
+                      m_pages, pool_k.dtype.itemsize)
     t = ppb * page_size
     table = jnp.pad(block_table, ((0, 0), (0, -m_pages % ppb)))
     qh = jnp.swapaxes(q, 1, 2)                       # [B, H, S, D]

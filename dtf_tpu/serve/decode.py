@@ -322,6 +322,21 @@ class Decoder:
                 lambda _: sharding, shapes))()
         return zeros()
 
+    @functools.cached_property
+    def decode_all_heads(self) -> bool:
+        """Whether the decode body's paged kernel scores a stored block
+        all heads at once (``ops.paged_attention._plan``, from the same
+        shapes the body hands it): false head by head, and where no
+        such kernel runs — the gather path, latent pools only."""
+        from dtf_tpu.ops.paged_attention import decode_scores_all_heads
+        pools = [p for p in jax.tree_util.tree_leaves(self._init_trace[0])
+                 if p.ndim == 4]            # [P, page, H, Dh]; latent: 3
+        return bool(self._kernel_attn and pools) and all(
+            decode_scores_all_heads(
+                self.model.num_heads // self.tp, p.shape[2] // self.tp,
+                p.shape[3], self.page_size, self.pages_per_slot,
+                p.dtype.itemsize) for p in pools)
+
     def copy_page(self, cache, src: int, dst: int):
         """Physically copy pool page ``src`` onto ``dst`` in every
         layer's K and V pool — the engine's copy-on-write primitive

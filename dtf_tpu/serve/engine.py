@@ -631,6 +631,11 @@ class ServeEngine:
         self._m_tp_ways.set(getattr(self.decoder, "tp", 1))
         self._m_step_time = self.metrics.histogram("serve_decode_step_s",
                                                    unit="s")
+        # 1 where the decode body's paged kernel scores a block all
+        # heads at once, 0 head by head (Decoder.decode_all_heads);
+        # also on the serve_decode spans, for the reader of a trace
+        self.metrics.gauge("serve_paged_decode_allheads", unit="bool").set(
+            int(self.decoder.decode_all_heads))
         # pages the paged decode kernel is asked to attend in a step:
         # ceil((index + 1) / page) summed over the rows, idle rows
         # (index 0) included — they read the scratch page.  Over
@@ -1330,6 +1335,7 @@ class ServeEngine:
                     and s.handle.request.trace_id]
             if tids:
                 attrs["traces"] = tids
+            attrs["allheads"] = int(self.decoder.decode_all_heads)
         self._m_live_pages.observe(
             int((index // self.page_size + 1).sum()))
         pre_compiled = self.decoder.compiled_count

@@ -26,6 +26,15 @@ inside a block, and NaN in every page no row should read (the
 interpreter hands out NaN-filled scratch, so a stale buffer shows).
 f32 and bf16 pools (bf16 packs two heads' rows to a word), one and
 several head groups, head counts that tile and that do not.
+
+Two forms of the block's math share the kernel (``_plan`` names which):
+few query rows over many heads score the stored ``[T·H, Dh]`` block all
+heads at once, the other heads' columns masked; everything else goes
+head by head.  At this file's 4 and 8 heads a decode step (S = 1) takes
+the first at 8 and the second at 4, so the streaming cases above run
+each; the cases at the end pin the all-heads form itself — every head
+count, grouped queries, a window, each side of ``_plan``'s bounds, and
+what the mask alone must keep apart.
 """
 
 import numpy as np
@@ -152,10 +161,22 @@ def _pages_per_block(monkeypatch, args, ppb):
     return plan
 
 
+# the streaming cases run in both forms of the block's math: a decode step
+# goes head by head at 4 heads and all heads at once at 8 (``_plan``)
+both_forms = pytest.mark.parametrize("heads", [4, 8],
+                                     ids=["head_by_head", "all_heads"])
+
+
+def _kernel(*args, **kw):
+    """The kernel through the interpreter, traced anew: the jitted entry
+    would hand back the trace of an earlier test with these shapes,
+    whatever budget or bound this test has set since."""
+    return pa.paged_flash_decode.__wrapped__(*args, interpret=True, **kw)
+
+
 def _check(args, rtol=1e-6, atol=1e-6):
     """Kernel against the gather oracle AND the blockwise reference."""
-    kern = np.asarray(pa.paged_flash_decode(*args, interpret=True),
-                      np.float32)
+    kern = np.asarray(_kernel(*args), np.float32)
     oracle = np.asarray(pa.paged_attention(*args), np.float32)
     ref = np.asarray(pa.paged_flash_decode_reference(*args), np.float32)
     assert np.isfinite(kern).all()
@@ -164,49 +185,54 @@ def _check(args, rtol=1e-6, atol=1e-6):
     return kern
 
 
+@both_forms
 @pytest.mark.parametrize("ppb", [2, 4])
 @pytest.mark.parametrize("edge", [-1, 0, 1])
-def test_kernel_lengths_around_a_block_boundary(monkeypatch, ppb, edge):
+def test_kernel_lengths_around_a_block_boundary(monkeypatch, ppb, edge,
+                                                heads):
     """Keys one under, on and one over a block of ``ppb`` pages: the
     trip count steps from 1 to 2 exactly there, and the first page of
     the second block holds one live key."""
     keys = ppb * PAGE + edge
     args = _geometry(50 + edge, [keys - 1, 2 * ppb * PAGE - 1 + edge], 1,
-                     m=16)
-    _pages_per_block(monkeypatch, args, ppb)
+                     m=16, heads=heads)
+    assert _pages_per_block(monkeypatch, args, ppb)[2] is (heads == 8)
     _check(args)
 
 
-def test_kernel_full_row_beside_an_idle_row(monkeypatch):
+@both_forms
+def test_kernel_full_row_beside_an_idle_row(monkeypatch, heads):
     """A 2,048-token row (all 128 pages of 16, 16 blocks of 8) beside
     an idle row (all-zeros table, index 0: position 0 of the scratch
     page, as the engine passes it)."""
-    args = list(_geometry(3, [2047, 0], 1, page=16, m=128))
+    args = list(_geometry(3, [2047, 0], 1, page=16, m=128, heads=heads))
     args[3] = args[3].at[1].set(0)
-    _pages_per_block(monkeypatch, args, 8)
+    assert _pages_per_block(monkeypatch, args, 8)[2] is (heads == 8)
     _check(args)
 
 
+@both_forms
 @pytest.mark.parametrize("order", ["descending", "strided"])
-def test_kernel_page_ids_in_any_order(monkeypatch, order):
+def test_kernel_page_ids_in_any_order(monkeypatch, order, heads):
     """Page ids descending, and every third page of a pool three times
     the rows' need: the kernel reads ids from the table, never
     neighbours in the pool."""
     lens = [45, 20, 33]
-    args = list(_geometry(8, lens, 1, pool=1 + 9 * M))
+    args = list(_geometry(8, lens, 1, pool=1 + 9 * M, heads=heads))
     ids = np.arange(1, 1 + 3 * M)
     ids = ids[::-1] if order == "descending" else 3 * ids - 1
     args[3] = jnp.asarray(ids.reshape(3, M), jnp.int32)
-    _pages_per_block(monkeypatch, args, 2)
+    assert _pages_per_block(monkeypatch, args, 2)[2] is (heads == 8)
     _check(args)
 
 
-def test_kernel_page_shared_by_two_rows(monkeypatch):
+@both_forms
+def test_kernel_page_shared_by_two_rows(monkeypatch, heads):
     """Prefix sharing: rows 0 and 1 hold the same two first pages and
     differ after; both read them, at different lengths."""
-    args = list(_geometry(21, [40, 19, 30], 1))
+    args = list(_geometry(21, [40, 19, 30], 1, heads=heads))
     args[3] = args[3].at[1, :2].set(args[3][0, :2])
-    _pages_per_block(monkeypatch, args, 2)
+    assert _pages_per_block(monkeypatch, args, 2)[2] is (heads == 8)
     kern = _check(args)
     assert not np.allclose(kern[0], kern[1])
 
@@ -221,7 +247,7 @@ def test_kernel_chunk_starting_inside_a_block(monkeypatch, pages, start_page):
     s = pages * PAGE
     args = _geometry(pages * 10 + start_page, [start_page * PAGE] * 2, s,
                      heads=8, m=16)
-    _, heads_per_group = _pages_per_block(monkeypatch, args, 4)
+    _, heads_per_group, _ = _pages_per_block(monkeypatch, args, 4)
     assert heads_per_group < 8
     _check(args)
 
@@ -235,7 +261,8 @@ def test_kernel_ignores_nan_in_pages_past_a_rows_length(monkeypatch, s):
     double buffer would each bring a NaN to a matmul (0 x NaN = NaN)."""
     lens = [1, 2 * PAGE - 1, 4 * PAGE, 7 * PAGE + 3]
     args = list(_geometry(77, lens, s, heads=8, m=16, pool=80))
-    _pages_per_block(monkeypatch, args, 2)
+    # a decode step all heads at once, the chunk head by head
+    assert _pages_per_block(monkeypatch, args, 2)[2] is (s == 1)
     clean = _check(args)
     tbl = np.asarray(args[3])
     live = {int(p) for row, n in zip(tbl, lens)
@@ -244,7 +271,7 @@ def test_kernel_ignores_nan_in_pages_past_a_rows_length(monkeypatch, s):
     assert 0 in dead and len(dead) > 80 - 4 * 16
     args[1] = args[1].at[dead].set(jnp.nan)
     args[2] = args[2].at[dead].set(jnp.nan)
-    poisoned = np.asarray(pa.paged_flash_decode(*args, interpret=True))
+    poisoned = np.asarray(_kernel(*args))
     np.testing.assert_array_equal(poisoned, clean)
 
 
@@ -265,6 +292,146 @@ def test_kernel_bf16_pools_and_untiled_head_counts(monkeypatch, heads, s):
 def test_kernel_untiled_head_counts_f32(heads):
     """f32 pools, 6 and 3 heads (8 and 4 after padding), decode."""
     _check(_geometry(heads, [1, PAGE, 3 * PAGE + 7], 1, heads=heads))
+
+
+# ---------------------------------------------------------------------------
+# the all-heads form: one [R, T·H] score tile a block
+# ---------------------------------------------------------------------------
+
+def _form(args):
+    """Which form ``_plan`` names for this call's shapes."""
+    (_, s, hq, d), pk = args[0].shape, args[1]
+    h = pa._tiled_heads(pk.shape[2], pk.dtype.itemsize)
+    return pa._tiling(s, hq // pk.shape[2] * h, h, d, pk.shape[1],
+                      args[3].shape[1], pk.dtype.itemsize)[-1]
+
+
+def _grouped(args, hq):
+    """The same pools and table under ``hq`` query heads."""
+    q = args[0]
+    rng = np.random.default_rng(hq)
+    args = list(args)
+    args[0] = jnp.asarray(
+        rng.standard_normal(q.shape[:2] + (hq, q.shape[3])), q.dtype)
+    return args
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("hq,heads,window", [
+    (16, 16, None), (28, 4, None), (28, 4, 20), (16, 8, 20), (6, 6, None),
+    (3, 3, None)],
+    ids=["16", "28over4", "28over4_window", "16over8_window", "6", "3"])
+def test_all_heads_form_matches_the_oracles(monkeypatch, dtype, hq, heads,
+                                            window):
+    """A decode step in the all-heads form against the gather oracle (and
+    the blockwise reference where it has the case): 16 heads; grouped
+    queries, 7 to a KV head, with and without a window; 6 and 3 heads,
+    padded to 8 and 4.  Four heads take the form only with ``_plan``'s
+    head bound lowered (measured slower on the chip there): the math
+    holds at any count."""
+    monkeypatch.setattr(pa, "_ALL_HEADS_MIN_HEADS", 1)
+    lens = [1, 2 * PAGE - 1, 2 * PAGE, 7 * PAGE + 3, 0]
+    args = _grouped(_geometry(hq + heads, lens, 1, heads=heads, m=16,
+                              dtype=dtype), hq)
+    args[3] = args[3].at[-1].set(0)                 # an idle row
+    _pages_per_block(monkeypatch, args, 2)
+    assert _form(args)
+    tol = 1e-6 if dtype == jnp.float32 else 2 ** -7
+    if window is None and hq == heads:
+        _check(args, rtol=tol, atol=tol)
+        return
+    kern = np.asarray(_kernel(*args, window=window), np.float32)
+    oracle = np.asarray(pa.paged_attention(*args, window=window), np.float32)
+    np.testing.assert_allclose(kern, oracle, rtol=2 * tol, atol=2 * tol)
+
+
+@pytest.mark.parametrize("s,heads,all_heads", [
+    (1, 8, True), (1, 4, False), (8, 16, True), (16, 16, False)],
+    ids=["8heads", "4heads", "128rows", "256rows"])
+def test_plan_names_the_form_each_side_of_its_bounds(s, heads, all_heads):
+    """``_plan``'s rule reads the static shapes: at least 8 heads in the
+    grid point, at most 128 query rows between them.  One shape each side
+    of each bound, and the kernel is the oracle's on all four."""
+    args = _geometry(s * heads, [3 * PAGE + 5, PAGE, 0], s, heads=heads,
+                     m=16)
+    assert _form(args) is all_heads
+    assert pa.decode_scores_all_heads(heads, heads, D, PAGE, 16, 4) is (
+        heads >= 8)
+    _check(args)
+
+
+def test_plan_keeps_chunks_head_by_head():
+    """The benchmark's shapes: a decode step of 16 heads of 128 takes the
+    all-heads form, its 128- and 256-token chunks and the grouped decoder
+    (4 KV heads) do not."""
+    assert pa._tiling(1, 16, 16, 128, 16, 128, 2)[2:] == (8, 16, True)
+    assert pa._tiling(128, 16, 16, 128, 16, 128, 2)[2:] == (8, 4, False)
+    assert pa._tiling(256, 16, 16, 128, 16, 128, 2)[2:] == (8, 2, False)
+    assert pa._tiling(1, 28, 4, 128, 64, 256, 2) == (7, 1, 8, 4, False)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_all_heads_form_keeps_heads_apart_by_the_mask_alone(monkeypatch,
+                                                            dtype):
+    """Every other head's LIVE rows — of pages the row does read — made
+    1e4 times larger in K and V: head 5's output is bit-equal to the clean
+    pool's.  Another head's column is scored in the same matmul and
+    reaches the row only through a probability that must be exactly 0.
+    (Finite on purpose: 0 x NaN is NaN in the P·V matmul, as it is for a
+    masked position of the row's own head; a head whose live rows hold
+    NaN has poisoned the layer through its out-projection already.)"""
+    lens = [PAGE + 3, 5 * PAGE - 1, 0]
+    args = list(_geometry(5, lens, 1, heads=8, m=16, dtype=dtype))
+    _pages_per_block(monkeypatch, args, 2)
+    assert _form(args)
+    clean = np.asarray(_kernel(*args))
+    others = jnp.asarray([1e4] * 5 + [1.0] + [1e4] * 2, dtype)[:, None]
+    args[1], args[2] = args[1] * others, args[2] * others
+    loud = np.asarray(_kernel(*args))
+    assert np.isfinite(loud.astype(np.float32)).all()
+    np.testing.assert_array_equal(loud[:, :, 5], clean[:, :, 5])
+    assert not np.array_equal(loud[:, :, 4], clean[:, :, 4])
+
+
+def test_engine_publishes_the_form_its_decode_body_takes():
+    """``serve_paged_decode_allheads``: 1 for a decode body whose paged
+    kernel scores all heads at once — the dense block at 8 heads, grouped
+    queries over 8 KV heads — and 0 head by head (4 KV heads) or where no
+    kernel runs (the gather path)."""
+    from dtf_tpu.models import build_model
+
+    def gauge(model):
+        params = jax.eval_shape(
+            model.clone(use_pallas=False).init, jax.random.key(0),
+            jnp.zeros((1, PAGE), jnp.int32))["params"]
+        params = jax.tree_util.tree_map(
+            lambda a: jnp.zeros(a.shape, a.dtype), params)
+        eng = ServeEngine(model, params, max_batch=2, max_seq_len=32,
+                          kv_page_size=PAGE, max_delay_s=0.0)
+        try:
+            value = eng.metrics.get("serve_paged_decode_allheads").value
+            assert value == eng.decoder.decode_all_heads
+            return value
+        finally:
+            eng.stop(drain=False)
+
+    dense = TransformerLM(vocab_size=64, num_layers=1, d_model=64,
+                          num_heads=8, d_ff=64, max_seq_len=32,
+                          use_pallas="interpret")
+    assert gauge(dense) == 1
+    assert gauge(dense.clone(use_pallas=False)) == 0
+    assert gauge(dense.clone(num_heads=4)) == 0
+    routed = dict(num_layers=1, d_model=64, num_heads=16, head_dim=8,
+                  num_experts=4, experts_per_token=2, expert_width=16,
+                  window=16, layer_window=[False], layer_rope=[True],
+                  max_seq_len=32)
+    for kv_heads, want in ((8, 1), (4, 0)):
+        model, _ = build_model("routed_decoder", num_classes=64,
+                               dtype=jnp.float32, num_kv_heads=kv_heads,
+                               **routed)
+        assert gauge(model.clone(use_pallas="interpret")) == want
 
 
 def test_auto_dispatch_routes_by_flag(monkeypatch):

@@ -140,6 +140,41 @@ def test_grouped_paged_kernel_compiles_for_v5e(v5e, batch, s, window):
     assert makers <= {"parameter"}, makers
 
 
+@pytest.mark.parametrize("hq,heads,batch,page,pool,m,window,lowered_bound", [
+    (16, 16, 48, PAGE, BENCH_POOL, M, None, False),
+    (32, 8, 16, 64, 2049, 256, None, False),
+    (32, 8, 16, 64, 2049, 256, 4096, False),
+    (28, 4, 16, 64, 2049, 256, None, True),
+    (28, 4, 16, 64, 2049, 256, 4096, True)],
+    ids=["dense16", "32over8", "32over8_window", "28over4", "28over4_window"])
+def test_all_heads_decode_kernel_compiles_for_v5e(
+        v5e, monkeypatch, hq, heads, batch, page, pool, m, window,
+        lowered_bound):
+    """A decode step in the all-heads form — one [R, T·H] score tile a
+    block, integer division of a lane index by the head count in the mask
+    — at the dense cells' shape, at grouped queries over 8 KV heads, and at
+    the routed decoder's 28 over 4 (R = 28: no multiple of a sublane
+    tile), which ``_plan`` keeps head by head (measured: PERF.md §6 PR 33)
+    and which compiles all the same should its bound move."""
+    if lowered_bound:
+        monkeypatch.setattr(pa, "_ALL_HEADS_MIN_HEADS", 1)
+    assert pa.decode_scores_all_heads(hq, heads, D, page, m, 2)
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    shapes = (((batch, 1, hq, D), bf16), ((pool, page, heads, D), bf16),
+              ((pool, page, heads, D), bf16), ((batch, m), i32),
+              ((batch,), i32))
+    args = [jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+            for shape, dt in shapes]
+    # traced anew: the jitted entry may hold another test's trace
+    text = jax.jit(functools.partial(pa.paged_flash_decode.__wrapped__,
+                                     window=window)
+                   ).lower(*args).compile().as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text
+    makers = set(re.findall(
+        rf"= bf16\[{pool},{page},\d+,{D}\]\S* ([\w-]+)\(", text))
+    assert makers <= {"parameter"}, makers
+
+
 @pytest.mark.parametrize("tokens", [16, 512], ids=["decode", "chunk"])
 def test_grouped_expert_matmuls_compile_for_v5e(v5e, tokens):
     """The dropless expert layer's two grouped matmuls (the Pallas
@@ -235,6 +270,38 @@ def test_grouped_expert_matmuls_of_256_experts_compile_for_v5e(v5e, tokens):
     assert text.count('custom_call_target="tpu_custom_call"') >= 2
 
 
+def _shapes_only_decoder(model, params, **kw):
+    """A ``Decoder`` over a tree of shapes: nothing materialised, and what
+    it holds is ``_held``'s rule applied to the shapes."""
+    from dtf_tpu.serve import decode as sd
+
+    class ShapesOnly(sd.Decoder):
+        def _held(self, params):
+            return jax.eval_shape(super()._held, params)
+    return ShapesOnly(model.clone(use_pallas=True), params, **kw)
+
+
+def _on_chip(tree, v5e):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e), tree)
+
+
+def _compile_decode_body(dec, v5e):
+    """The decoder's whole decode body, every slot, compiled for the v5e
+    as ``Decoder`` builds it."""
+    from dtf_tpu.serve import decode as sd
+    s, i32, n = jax.ShapeDtypeStruct, jnp.int32, dec.num_slots
+    keys = jax.eval_shape(lambda: sd._seed_row_keys(
+        jnp.zeros((n,), jnp.uint32), jnp.zeros((n,), i32)))
+    args = _on_chip((dec.params, jax.eval_shape(dec.fresh_cache),
+                     s((n, 1), i32), s((n,), i32),
+                     s((n, dec.pages_per_slot), i32),
+                     s((n,), jnp.float32), keys), v5e)
+    return jax.jit(
+        dec._decode_paged_impl, donate_argnums=(1,),
+        compiler_options=sd.TPU_BODY_OPTIONS).lower(*args).compile()
+
+
 @pytest.mark.parametrize("body", ["chunk", "decode"])
 def test_latent_serve_bodies_compile_for_v5e(v5e, body):
     """The whole compiled body, not the kernel alone: beside the body's
@@ -248,10 +315,6 @@ def test_latent_serve_bodies_compile_for_v5e(v5e, body):
     of 64 in a 393,216-token pool."""
     from dtf_tpu.models import build_model
     from dtf_tpu.serve import decode as sd
-
-    class ShapesOnly(sd.Decoder):
-        def _held(self, params):        # no arrays to cast
-            return params
     i32, f32 = jnp.int32, jnp.float32
     model, _ = build_model(
         "routed_decoder", num_classes=129280, dtype=jnp.bfloat16,
@@ -266,33 +329,46 @@ def test_latent_serve_bodies_compile_for_v5e(v5e, body):
     params = jax.eval_shape(model.clone(use_pallas=False).init,
                             jax.random.key(0), jnp.zeros((1, 64), i32)
                             )["params"]
-    dec = ShapesOnly(model.clone(use_pallas=True), params, num_slots=24,
-                     max_seq_len=34816, kv_page_size=64, kv_pool_pages=6145)
-
-    def on_chip(tree):
-        return jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
-            tree)
-    cache, m = jax.eval_shape(dec.fresh_cache), dec.pages_per_slot
-    s = jax.ShapeDtypeStruct
+    dec = _shapes_only_decoder(model, params, num_slots=24,
+                               max_seq_len=34816, kv_page_size=64,
+                               kv_pool_pages=6145)
     if body == "chunk":
-        args = on_chip((params, cache, s((1, 1024), i32), s((1, m), i32),
-                        s((), i32), s((), f32),
-                        jax.eval_shape(lambda: sd.position_key(0, 0)),
-                        s((), i32)))
+        s, m = jax.ShapeDtypeStruct, dec.pages_per_slot
+        args = _on_chip((dec.params, jax.eval_shape(dec.fresh_cache),
+                         s((1, 1024), i32), s((1, m), i32), s((), i32),
+                         s((), f32),
+                         jax.eval_shape(lambda: sd.position_key(0, 0)),
+                         s((), i32)), v5e)
         compiled = jax.jit(
             dec._chunk_impl, donate_argnums=(1,), static_argnums=(8, 9),
             compiler_options=sd.TPU_BODY_OPTIONS).lower(
                 *args, None, False).compile()
     else:
-        keys = jax.eval_shape(lambda: sd._seed_row_keys(
-            jnp.zeros((24,), jnp.uint32), jnp.zeros((24,), i32)))
-        args = on_chip((params, cache, s((24, 1), i32), s((24,), i32),
-                        s((24, m), i32), s((24,), f32), keys))
-        compiled = jax.jit(
-            dec._decode_paged_impl, donate_argnums=(1,),
-            compiler_options=sd.TPU_BODY_OPTIONS).lower(*args).compile()
+        compiled = _compile_decode_body(dec, v5e)
     text = compiled.as_text()
     assert text.count("paged_flash_decode") >= 5        # a call a layer
     # every pool is donated and updated in place: no second pool exists
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9
+
+
+def test_dense_decode_body_compiles_for_v5e(v5e):
+    """The dense cells' whole decode body with ``TPU_BODY_OPTIONS``, not
+    the kernel alone (what a kernel may take of VMEM depends on the body
+    around it, PR 32): Cerebras-GPT-1.3B's 24 layers, 48 slots of 2,048
+    tokens, pages of 16 in a 30,720-token pool, weights as the Decoder
+    holds them; 24 calls of the all-heads form.  Shapes only."""
+    from dtf_tpu.models import build_model
+    model, _ = build_model("transformer", num_classes=50257,
+                           dtype=jnp.bfloat16, num_layers=24, d_model=2048,
+                           num_heads=16, d_ff=8192, max_seq_len=2048)
+    params = jax.eval_shape(model.clone(use_pallas=False).init,
+                            jax.random.key(0),
+                            jnp.zeros((1, PAGE), jnp.int32))["params"]
+    dec = _shapes_only_decoder(model, params, num_slots=48,
+                               max_seq_len=2048, kv_page_size=PAGE,
+                               kv_pool_pages=BENCH_POOL)
+    assert dec.decode_all_heads
+    compiled = _compile_decode_body(dec, v5e)
+    assert compiled.as_text().count("paged_flash_decode") >= 24
+    # the pools are donated and updated in place: no second pool exists
     assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9
