@@ -77,6 +77,21 @@ _REGISTRY = {
             routed_scale=2.5, router_bias_stddev=0.05, activation="silu",
             router_input="post_attention"),
         32_768, 0.0),
+    # and its mixer kinds at a small size: short-convolution layers whose
+    # running state rides the page table beside grouped-query layers with
+    # per-head norms and [k | v] pool rows, two dense layers, a tied head
+    "routed_decoder_state": (
+        functools.partial(
+            routed_decoder.RoutedDecoderLM, num_layers=8, d_model=512,
+            num_heads=8, num_kv_heads=2, head_dim=64,
+            layer_mixer=("short_conv", "short_conv", "attention",
+                         "short_conv"), qk_norm=True,
+            tie_head=True, layer_window=(False,), layer_rope=(True,),
+            num_dense_layers=2, dense_width=1024, num_experts=16,
+            experts_per_token=4, expert_width=128, routing="sigmoid_bias",
+            routing_sum_eps=1e-6, router_bias_stddev=0.05,
+            activation="silu", router_input="post_attention"),
+        32_768, 0.0),
     # pipeline-stacked LM family (pipeline stages over 'model')
     "pipeline_transformer": (pipeline_lm.PipelinedTransformerLM,
                              32_768, 0.0),

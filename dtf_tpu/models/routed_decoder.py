@@ -3,8 +3,9 @@ MLP is a dropless routed-expert layer — a family that is served
 (``serve/decode.py``, ``serve/engine.py``), not trained, in this repo.
 
 What a layer is comes from fields, all plain values a configuration file
-can carry.  Pre-norm RMSNorm blocks, no bias on any projection, an untied
-output head.  There is no position table: a position is the row's
+can carry.  Pre-norm RMSNorm blocks, no bias on any projection, an output
+head of its own (``tie_head``: the embedding matrix itself).  There is no
+position table: a position is the row's
 ``cache_index`` plus the offset in the chunk, so ``max_seq_len`` bounds
 only what the serving engine admits.
 
@@ -28,6 +29,27 @@ qk_rope_head_dim`` values in ``latent_row_lanes`` stored lanes), read once
 a call for scores and values; decode mode attends absorbed, every chunk
 (the first too) through the paged kernel.
 
+**Mixer kind.**  ``layer_mixer[l]`` (one entry a layer; shorter tuples
+repeat) is ``attention`` — the kind above — or ``short_conv``
+(:class:`ShortConv`): a double-gated depthwise causal convolution of
+``conv_taps`` taps, no attention and no pages.  What a request carries
+through such a layer is the last ``conv_taps - 1`` inputs of the filter,
+and it RIDES THE PAGE TABLE: one leaf ``[P, (conv_taps - 1) * d]`` a layer
+in the ``"cache"`` collection, indexed by page id like every pool, whose
+entry for page ``p`` is the running state at the newest token written in
+``p`` — a full page's entry is the snapshot at its end.  A call reads its
+carry from the entry of the page that holds position ``cache_index - 1``
+and writes the entry of every page it touches, so page copies, prefix
+sharing and migration carry the state as they carry K and V; what they
+cannot do is REPLAY a token on a copied page (the entry is already past
+it: ``carries_state``, which the engine reads).  Such a model's call
+takes ``last_pos`` [B]: the offset of each row's last real token, where
+a tail-padded final chunk's entry is taken.  K and V of a head exactly
+half a lane tile wide (64) share one pool row ``[k | v]``: the layout
+follows from ``head_dim`` and is nobody's to choose;
+``qk_norm``: RMSNorm over each head of q and of k, a learned scale each,
+before the rotation.
+
 **MLP kind.**  The first ``num_dense_layers`` layers have a dense gated
 MLP of ``dense_width`` and no router.  The others route: ``num_experts``
 gated experts of ``expert_width`` (``activation`` relu | silu) of which
@@ -36,7 +58,8 @@ every token takes its ``experts_per_token`` best, by ``routing``
   ``softmax_topk``   the largest router logits, softmax over those;
   ``sigmoid_bias``   scores ``sigmoid(logits)``; the choice is the largest
                      of score + a learned bias, the weights the chosen
-                     scores WITHOUT it over their sum times ``routed_scale``
+                     scores WITHOUT it over their sum (plus
+                     ``routing_sum_eps``) times ``routed_scale``
 
 from the norm ``router_input`` names (``pre_attention``: the layer's
 first norm, before attention runs; ``post_attention``: the second), and
@@ -47,7 +70,7 @@ The layer, for ``x [S, d]``:
   1. ``h = RMSNorm(x)``                       (router here: pre_attention)
   2. ``x += attention(h)``   (kind above; the paged cache is
      ``models.transformer.paged_cache_attention``, shared with
-     ``CausalSelfAttention``)
+     ``CausalSelfAttention``) or ``x += short_conv(h)``
   3. ``h2 = RMSNorm(x)``                      (router here: post_attention)
   4. dense: ``x += down(act(gate h2) * up h2)``; routed: ``x +=
      shared(h2) + sum_e w_e * down_e(act(gate_e h2) * up_e h2)`` — every
@@ -86,6 +109,11 @@ from dtf_tpu.ops.paged_attention import cached_attention, expand_kv_heads
 STATS = ("assignments", "experts_touched", "expert_load_max",
          "kv_tokens_read_global", "kv_tokens_read_window")
 LATENT_STATS = STATS[:3] + ("latent_tokens_read",)
+# with short-convolution layers beside attention: the tokens those layers
+# mixed, and the rows whose state entry went to a page of their own (not
+# the scratch page), both summed over the short-convolution layers
+STATE_STATS = STATS + ("conv_tokens", "state_rows_advanced")
+MIXERS = ("attention", "short_conv")
 
 # grouped matmul tile (rows, contraction, columns): rows of one expert are
 # padded to a multiple of the first inside the kernel's own bookkeeping
@@ -130,7 +158,8 @@ def interleaved_rope(x, positions, theta: float):
                      axis=-1).reshape(x.shape).astype(x.dtype)
 
 
-def route(h, w_router, k: int, score_bias=None, routed_scale: float = 1.0):
+def route(h, w_router, k: int, score_bias=None, routed_scale: float = 1.0,
+          sum_eps: float = 0.0):
     """(expert ids [T, k], weights [T, k] f32) of the ``k`` largest router
     logits a token; softmax over the chosen (softmax over all, then
     renormalised over the chosen, is the same numbers).  f32 at full
@@ -140,7 +169,8 @@ def route(h, w_router, k: int, score_bias=None, routed_scale: float = 1.0):
     ``score_bias`` [E] set is the ``sigmoid_bias`` rule instead: scores
     ``s = sigmoid(logits)``; the choice is the ``k`` largest of ``s +
     score_bias``; the weights are the chosen experts' ``s`` WITHOUT the
-    bias, divided by their sum and multiplied by ``routed_scale``."""
+    bias, divided by their sum (plus ``sum_eps``: a published rule adds
+    1e-6 there) and multiplied by ``routed_scale``."""
     logits = jnp.einsum("td,de->te", h.astype(jnp.float32),
                         w_router.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
@@ -148,7 +178,10 @@ def route(h, w_router, k: int, score_bias=None, routed_scale: float = 1.0):
         scores = jax.nn.sigmoid(logits)
         _, idx = jax.lax.top_k(scores + score_bias.astype(jnp.float32), k)
         chosen = jnp.take_along_axis(scores, idx, axis=-1)
-        return idx, chosen / jnp.sum(chosen, -1, keepdims=True) * routed_scale
+        total = jnp.sum(chosen, -1, keepdims=True)
+        if sum_eps:
+            total = total + sum_eps
+        return idx, chosen / total * routed_scale
     vals, idx = jax.lax.top_k(logits, k)
     return idx, jax.nn.softmax(vals, axis=-1)
 
@@ -263,6 +296,7 @@ class GroupedQueryAttention(nn.Module):
     decode: bool = False
     kv_page_size: Optional[int] = None
     kv_pool_pages: Optional[int] = None
+    qk_norm_eps: Optional[float] = None     # set: per-head norms of q, k
 
     @nn.compact
     def __call__(self, h, positions, cache_index=None, block_table=None,
@@ -281,6 +315,12 @@ class GroupedQueryAttention(nn.Module):
         q = qkv[..., :hq * dh].reshape(b, s, hq, dh)
         k = qkv[..., hq * dh:(hq + hkv) * dh].reshape(b, s, hkv, dh)
         v = qkv[..., (hq + hkv) * dh:].reshape(b, s, hkv, dh)
+        if self.qk_norm_eps is not None:
+            ones = nn.initializers.ones
+            q = rms_norm(q, self.param("q_norm", ones, (dh,),
+                                       self.param_dtype), self.qk_norm_eps)
+            k = rms_norm(k, self.param("k_norm", ones, (dh,),
+                                       self.param_dtype), self.qk_norm_eps)
         if self.rope_theta is not None:
             q = rotate_half_rope(q, positions, self.rope_theta)
             k = rotate_half_rope(k, positions, self.rope_theta)
@@ -294,7 +334,12 @@ class GroupedQueryAttention(nn.Module):
             o = paged_cache_attention(
                 self, q, k, v, cache_index, block_table,
                 flash_prefill=flash_prefill, window_pages=window_pages,
-                window=self.window)
+                window=self.window,
+                # a K or V row of half a lane tile is stored in a whole
+                # one: such heads keep [k | v] in ONE pool row, all of it
+                # payload (two pools of 64 lanes do not even lower on the
+                # TPU; narrower heads are sizes of the CPU's tests alone)
+                one_row=2 * dh == _LANES)
         else:
             # the whole sequence at once (tests, the toy): a plain mask
             kr, vr = expand_kv_heads(k, v, hq)
@@ -307,6 +352,96 @@ class GroupedQueryAttention(nn.Module):
         return jnp.einsum("bsn,nd->bsd", o.reshape(b, s, hq * dh),
                           w_out.astype(self.dtype),
                           preferred_element_type=jnp.float32)
+
+
+class ShortConv(nn.Module):
+    """The double-gated short convolution: ``[B | C | z] = h W_in``
+    (three blocks of ``d``); ``u = B * z``; ``c_t = sum_j w_j * u_{t - (L
+    - 1) + j}`` with ``w`` [d, L] one filter a channel (depthwise), causal,
+    ``u`` zero before the sequence; returns ``(C * c) W_out``.  No
+    activation and no bias.
+
+    The two gates and the tap sum are f32; ``u`` is rounded to ``dtype``
+    before the taps, in every form, because that is what the state holds:
+    a chunk's carry and a chunk's own tokens are the same numbers, and
+    every chunking of a prompt gives the same ``c``.
+
+    Decode mode (see the module's docstring): the state entry of a page is
+    ``[u_{t-L+2} | ... | u_t]`` (``(L - 1) * d`` values, oldest first) at
+    the newest token ``t`` written in it.  Returns (output, rows whose
+    entry went to a page other than the scratch page)."""
+    taps: int
+    dtype: Any
+    param_dtype: Any
+    decode: bool = False
+    kv_page_size: Optional[int] = None
+    kv_pool_pages: Optional[int] = None
+
+    @nn.compact
+    def __call__(self, h, cache_index=None, block_table=None, last_pos=None):
+        b, s, d = h.shape
+        keep = self.taps - 1
+        w_in = self.param("in_proj", _normal(0.02), (d, 3 * d),
+                          self.param_dtype)
+        w_out = self.param("out_proj", _normal(0.02), (d, d),
+                           self.param_dtype)
+        w = self.param("taps", _normal(0.02), (d, self.taps),
+                       self.param_dtype).astype(jnp.float32)
+        bcz = jnp.einsum("bsd,dn->bsn", h.astype(self.dtype),
+                         w_in.astype(self.dtype),
+                         preferred_element_type=jnp.float32)
+        gate_c = bcz[..., d:2 * d]
+        u = (bcz[..., :d] * bcz[..., 2 * d:]).astype(self.dtype)
+        carry = jnp.zeros((b, keep, d), self.dtype)
+        advanced = jnp.zeros((), jnp.int32)
+        if self.decode:
+            if self.kv_page_size is None:
+                raise ValueError("decode mode needs kv_page_size and "
+                                 "kv_pool_pages")
+            if cache_index is None or block_table is None:
+                raise ValueError("decode mode needs cache_index [B] "
+                                 "and block_table [B, M], both int32")
+            state = self.variable("cache", "conv_state", jnp.zeros,
+                                  (self.kv_pool_pages, keep * d), self.dtype)
+        if self.decode and not self.is_initializing():
+            page, m = self.kv_page_size, block_table.shape[1]
+            if s > 1 and s % page:
+                raise ValueError(
+                    f"a call of {s} tokens is neither one token nor whole "
+                    f"pages of {page}: a page it crossed would keep a "
+                    f"stale state entry")
+            before = jnp.take_along_axis(
+                block_table, (jnp.maximum(cache_index - 1, 0) // page
+                              )[:, None], axis=1)[:, 0]
+            carry = jnp.where((cache_index > 0)[:, None, None],
+                              state.value[before].reshape(b, keep, d), carry)
+        full = jnp.concatenate([carry, u], axis=1)          # [B, keep+S, d]
+        c = sum(w[:, j] * full[:, j:j + s].astype(jnp.float32)
+                for j in range(self.taps))
+        if self.decode and not self.is_initializing():
+            # the entry of every page of the call, taken at the page's
+            # last token or at the row's last real one, whichever is first
+            n = max(s // page, 1)
+            last = (jnp.full((b,), s - 1, jnp.int32) if last_pos is None
+                    else last_pos.astype(jnp.int32))
+            ends = jnp.minimum(
+                (jnp.arange(n, dtype=jnp.int32)[None, :] + 1) * min(page, s)
+                - 1, last[:, None])                         # [B, n]
+            rows = ends[:, :, None] + 1 + jnp.arange(keep,
+                                                     dtype=jnp.int32)
+            entries = jnp.take_along_axis(
+                full, rows.reshape(b, n * keep)[:, :, None], axis=1
+            ).reshape(b * n, keep * d)
+            where = jnp.minimum(
+                (cache_index[:, None] + ends) // page, m - 1)
+            pages = jnp.take_along_axis(block_table, where, axis=1)
+            state.value = state.value.at[pages.reshape(-1)].set(entries)
+            advanced = jnp.sum(pages[:, 0] != 0, dtype=jnp.int32)
+        y = jnp.einsum("bsd,dn->bsn",
+                       (gate_c * c).astype(self.dtype),
+                       w_out.astype(self.dtype),
+                       preferred_element_type=jnp.float32)
+        return y, advanced
 
 
 # lanes a latent cache row is stored in: the TPU tiles the last axis by
@@ -446,11 +581,17 @@ class RoutedBlock(nn.Module):
     router_bias_stddev: float = 0.0
     activation: str = "relu"
     router_input: str = "pre_attention"
+    mixer: str = "attention"                   # | short_conv
+    conv_taps: int = 3
+    qk_norm: bool = False
+    routing_sum_eps: float = 0.0
 
     @nn.compact
     def __call__(self, x, positions, cache_index=None, block_table=None,
                  flash_prefill: bool = False,
-                 window_pages: Optional[int] = None):
+                 window_pages: Optional[int] = None, last_pos=None):
+        """-> (x, rows an expert [E] or None for a dense layer, state rows
+        advanced or None for an attention layer)."""
         b, s, d = x.shape
         e, f = self.num_experts, self.expert_width
         ones, pdt = nn.initializers.ones, self.param_dtype
@@ -475,17 +616,26 @@ class RoutedBlock(nn.Module):
         def choose(hh):
             return route(hh.reshape(b * s, d), w_router,
                          self.experts_per_token, score_bias,
-                         self.routed_scale)
+                         self.routed_scale, self.routing_sum_eps)
         h = rms_norm(x, g1, self.rms_eps)
         if routed and self.router_input == "pre_attention":
             idx, weights = choose(h)
-        if self.latent is None:
+        advanced = None
+        if self.mixer == "short_conv":
+            attn, advanced = ShortConv(
+                self.conv_taps, self.dtype, pdt, decode=self.decode,
+                kv_page_size=self.kv_page_size,
+                kv_pool_pages=self.kv_pool_pages, name="conv")(
+                    h, cache_index, block_table, last_pos)
+        elif self.latent is None:
             attn = GroupedQueryAttention(
                 self.num_heads, self.num_kv_heads, self.head_dim,
                 self.window, self.rope_theta, self.dtype, pdt,
                 use_pallas=self.use_pallas, decode=self.decode,
                 kv_page_size=self.kv_page_size,
-                kv_pool_pages=self.kv_pool_pages, name="attn")(
+                kv_pool_pages=self.kv_pool_pages,
+                qk_norm_eps=self.rms_eps if self.qk_norm else None,
+                name="attn")(
                     h, positions, cache_index, block_table, flash_prefill,
                     window_pages)
         else:
@@ -506,7 +656,7 @@ class RoutedBlock(nn.Module):
                 self.param("dense_down", _normal(0.02),
                            (self.dense_width, d), pdt).astype(self.dtype),
                 self.activation)
-            return x + y.reshape(b, s, d), None
+            return x + y.reshape(b, s, d), None, advanced
         if self.router_input != "pre_attention":
             idx, weights = choose(h2)
         y, sizes = routed_experts(h2.astype(self.dtype), idx, weights,
@@ -523,7 +673,7 @@ class RoutedBlock(nn.Module):
                 self.param("shared_down", _normal(0.02), (fs, d),
                            pdt).astype(self.dtype),
                 self.activation)
-        return x + y.reshape(b, s, d), sizes
+        return x + y.reshape(b, s, d), sizes, advanced
 
 
 class RoutedDecoderLM(nn.Module):
@@ -573,6 +723,15 @@ class RoutedDecoderLM(nn.Module):
     router_bias_stddev: float = 0.0     # the score bias's initializer
     activation: str = "relu"
     router_input: str = "pre_attention"
+    routing_sum_eps: float = 0.0        # added to the chosen scores' sum
+    # mixer kind, one entry a layer (shorter tuples repeat): attention |
+    # short_conv (ShortConv, conv_taps taps; whole heads only).  qk_norm:
+    # RMSNorm a head of q and of k before the rotation.  tie_head: the
+    # head is the embedding
+    layer_mixer: Tuple[str, ...] = ("attention",)
+    conv_taps: int = 3
+    qk_norm: bool = False
+    tie_head: bool = False
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
     use_pallas: Any = None
@@ -586,7 +745,23 @@ class RoutedDecoderLM(nn.Module):
     @property
     def stats_names(self):
         """What ``"stats"/"counts"`` holds, in order."""
+        if self.carries_state:
+            return STATE_STATS
         return STATS if self.kv_lora_rank is None else LATENT_STATS
+
+    def layer_mixers(self):
+        """The mixer kind of every layer."""
+        lm = tuple(self.layer_mixer)
+        return [lm[i % len(lm)] for i in range(self.num_layers)]
+
+    @property
+    def carries_state(self) -> bool:
+        """Whether the cache holds running state beside pages of history
+        (a short-convolution layer): a state entry is already past its
+        page's newest token, so that token cannot be replayed on a copy of
+        the page, and a chunk's call has to be told its real length
+        (``last_pos``)."""
+        return "short_conv" in self.layer_mixers()
 
     def layer_kinds(self):
         """[(window or None, rope_theta or None)] a layer."""
@@ -600,7 +775,7 @@ class RoutedDecoderLM(nn.Module):
     @nn.compact
     def __call__(self, tokens, train: bool = False, cache_index=None,
                  block_table=None, flash_prefill: bool = False,
-                 window_pages: Optional[int] = None):
+                 window_pages: Optional[int] = None, last_pos=None):
         del train
         if self.model_axis is not None:
             raise ValueError("the routed decoder has no tensor-parallel "
@@ -608,6 +783,13 @@ class RoutedDecoderLM(nn.Module):
         if self.kv_lora_rank is None and self.num_heads % self.num_kv_heads:
             raise ValueError(f"num_heads {self.num_heads} is no multiple of "
                              f"num_kv_heads {self.num_kv_heads}")
+        mixers = self.layer_mixers()
+        if set(mixers) - set(MIXERS):
+            raise ValueError(f"layer_mixer {self.layer_mixer!r}: each one of "
+                             f"{MIXERS}")
+        if self.carries_state and self.kv_lora_rank is not None:
+            raise ValueError("short_conv layers go with whole heads, not "
+                             "with the latent cache")
         b, s = tokens.shape
         pdt = jnp.dtype(self.param_dtype)
         embed = self.param("embed", _normal(0.02),
@@ -627,9 +809,9 @@ class RoutedDecoderLM(nn.Module):
                       self.qk_nope_head_dim, self.qk_rope_head_dim,
                       self.v_head_dim)
         n_routed = len(kinds) - self.num_dense_layers
-        touched = load_max = jnp.zeros((), jnp.int32)
+        touched = load_max = advanced = jnp.zeros((), jnp.int32)
         for i, (window, theta) in enumerate(kinds):
-            x, sizes = RoutedBlock(
+            x, sizes, rows = RoutedBlock(
                 self.num_heads, self.num_kv_heads, self.head_dim,
                 self.num_experts, self.experts_per_token, self.expert_width,
                 window, theta, self.rms_eps, self.dtype, pdt,
@@ -643,12 +825,16 @@ class RoutedDecoderLM(nn.Module):
                 routing=self.routing, routed_scale=self.routed_scale,
                 router_bias_stddev=self.router_bias_stddev,
                 activation=self.activation, router_input=self.router_input,
-                name=f"layer{i}")(
+                mixer=mixers[i], conv_taps=self.conv_taps,
+                qk_norm=self.qk_norm,
+                routing_sum_eps=self.routing_sum_eps, name=f"layer{i}")(
                     x, positions, cache_index, block_table, flash_prefill,
-                    window_pages)
+                    window_pages, last_pos)
             if sizes is not None:
                 touched += jnp.sum(sizes > 0, dtype=jnp.int32)
                 load_max += jnp.max(sizes)
+            if rows is not None:
+                advanced += rows
         # what the attention of this call has to read of the cache: a
         # row's whole history in a full layer (K and V, or the one latent
         # row a token), the window's reach in a window layer
@@ -659,17 +845,27 @@ class RoutedDecoderLM(nn.Module):
             counts = jnp.stack([assignments, touched, load_max,
                                 len(kinds) * jnp.sum(live)])
         else:
-            n_window = sum(w is not None for w, _ in kinds)
-            counts = jnp.stack([
+            attends = [w for (w, _), m in zip(kinds, mixers)
+                       if m == "attention"]
+            n_window = sum(w is not None for w in attends)
+            counts = [
                 assignments, touched, load_max,
-                (len(kinds) - n_window) * jnp.sum(live),
-                n_window * jnp.sum(jnp.minimum(live, self.window + s - 1))])
+                (len(attends) - n_window) * jnp.sum(live),
+                n_window * jnp.sum(jnp.minimum(live, self.window + s - 1))]
+            if self.carries_state:
+                counts += [jnp.asarray(
+                    b * s * (len(kinds) - len(attends)), jnp.int32), advanced]
+            counts = jnp.stack(counts)
         n_counts = len(self.stats_names)
         self.sow("stats", "counts", counts,
                  reduce_fn=lambda _, new: new,
                  init_fn=lambda: jnp.zeros((n_counts,), jnp.int32))
         x = rms_norm(x, self.param("norm_f", nn.initializers.ones,
                                    (self.d_model,), pdt), self.rms_eps)
+        if self.tie_head:
+            return jnp.einsum("bsd,vd->bsv", x.astype(self.dtype),
+                              embed.astype(self.dtype),
+                              preferred_element_type=jnp.float32)
         head = self.param("lm_head", _normal(0.02),
                           (self.d_model, self.vocab_size), pdt)
         return jnp.einsum("bsd,dv->bsv", x.astype(self.dtype),

@@ -82,7 +82,8 @@ def paged_cache_attention(module, q, k, v, cache_index, block_table, *,
                           window_pages: Optional[int] = None,
                           window: Optional[int] = None,
                           scale: Optional[float] = None,
-                          value_lanes: Optional[int] = None):
+                          value_lanes: Optional[int] = None,
+                          one_row: bool = False):
     """Write-then-attend against the shared page pool — what every
     decoder family's attention does with the paged cache, called from
     inside the attention module's ``@nn.compact`` body (``module`` owns
@@ -101,7 +102,12 @@ def paged_cache_attention(module, q, k, v, cache_index, block_table, *,
     ``i // (Hq // Hkv)``); k already carries its positions (a rotary
     family rotates it before this call: the pool holds what is
     attended).  ``window`` (static, tokens; None = the whole history) is
-    the layer's own attention window."""
+    the layer's own attention window.
+
+    ``one_row``: K and V of a head share ONE pool row, ``[k | v]`` — one
+    pool ``[P, page, Hkv, 2 * Dh]`` a layer, for heads half a lane tile
+    wide (64: two pools of 64-lane rows would each be stored in 128 lanes,
+    twice the HBM and twice the DMA of every page)."""
     s = q.shape[1]
     # paged cache: one shared pool per K/V, sized by the module
     # attrs (NOT by the init call's shapes — admission capacity
@@ -120,15 +126,30 @@ def paged_cache_attention(module, q, k, v, cache_index, block_table, *,
             q, paged_latent.value, None, block_table, cache_index,
             window_pages=window_pages, use_pallas=module.use_pallas,
             scale=scale, value_lanes=value_lanes)
-    paged_key = module.variable(
-        "cache", "paged_key", jnp.zeros, pool_shape, k.dtype)
-    paged_value = module.variable(
-        "cache", "paged_value", jnp.zeros, pool_shape, v.dtype)
 
     def flash(k, v):
         return flash_attention(q, *expand_kv_heads(k, v, q.shape[2]),
                                causal=True, use_pallas=module.use_pallas)
 
+    if one_row:
+        paged_kv = module.variable(
+            "cache", "paged_kv", jnp.zeros,
+            pool_shape[:-1] + (2 * k.shape[-1],), k.dtype)
+        if module.is_initializing():
+            return flash(k, v)
+        paged_kv.value = write_pages(
+            paged_kv.value, jnp.concatenate([k, v], -1), block_table,
+            cache_index, page_aligned=aligned)
+        if flash_prefill and (window is None or s <= window):
+            return flash(k, v)
+        return paged_attention_auto(
+            q, paged_kv.value, None, block_table, cache_index,
+            window_pages=window_pages, use_pallas=module.use_pallas,
+            window=window)
+    paged_key = module.variable(
+        "cache", "paged_key", jnp.zeros, pool_shape, k.dtype)
+    paged_value = module.variable(
+        "cache", "paged_value", jnp.zeros, pool_shape, v.dtype)
     if module.is_initializing():
         # init trace: only the pool variables' shapes matter,
         # but keep the math valid (plain causal attention)

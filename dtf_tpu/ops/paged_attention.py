@@ -195,9 +195,16 @@ def paged_attention(q, pool_k, pool_v, block_table, index, *, window=None):
     Grouped-query heads: q may carry ``G`` times the pools' heads; query
     head ``i`` reads KV head ``i // G``.  ``window`` (static; None = all
     of the history): query at position ``p`` sees keys ``p - window < j
-    <= p``, its own position counted."""
+    <= p``, its own position counted.
+
+    ``pool_v`` None: ``pool_k`` [P, page_size, H, 2 * Dh] holds K and V of
+    a head in ONE row, ``[k | v]`` (heads exactly half a lane tile wide:
+    the routed decoder's grouped-query layers)."""
     k = gather_pages(pool_k, block_table)   # [B, L, H, Dh]
-    v = gather_pages(pool_v, block_table)
+    if pool_v is None:
+        k, v = k[..., :q.shape[-1]], k[..., q.shape[-1]:]
+    else:
+        v = gather_pages(pool_v, block_table)
     k, v = expand_kv_heads(k, v, q.shape[2])
     return cached_attention(q, k, v, _seen(q, k, index, window))
 
@@ -408,6 +415,14 @@ def _paged_decode_kernel(tbl_ref, idx_ref, q_ref, k_hbm, v_hbm, o_ref,
     the row, where head by head it reached that head alone); dead pages
     are zeroed before use, as in every form.
 
+    One pool of ``[k | v]`` rows (``v_hbm`` and ``vbuf`` None; ``k_hbm``
+    ``[P, page, H, D]``, ``kbuf`` five-dimensional as ever): a head's row
+    holds its key in the first ``D / 2`` lanes and its value in the rest,
+    the query's upper lanes are zeros, so ``q . row`` is ``q . k``, and
+    the value sum runs over the whole row — its upper lanes are the
+    attended value, its lower ones are dropped by the caller.  A page is
+    copied once and read once, in every form above.
+
     Latent pool (``v_hbm`` and ``vbuf`` None; ``k_hbm`` ``[P, page, W]``,
     ``kbuf`` ``[2, ppb, page, W]``): a token is ONE row of ``W`` values
     that every query head scores against, and the row's first lanes (as
@@ -416,7 +431,8 @@ def _paged_decode_kernel(tbl_ref, idx_ref, q_ref, k_hbm, v_hbm, o_ref,
     query heads are the grouped form's rows over the one "KV head"."""
     b = pl.program_id(0)
     g = pl.program_id(1)
-    latent = v_hbm is None
+    one_pool = v_hbm is None
+    latent = one_pool and len(kbuf.shape) == 4
     if latent:
         (_, ppb, page_size, d), h = kbuf.shape, 1
     else:
@@ -447,7 +463,7 @@ def _paged_decode_kernel(tbl_ref, idx_ref, q_ref, k_hbm, v_hbm, o_ref,
         pid = tbl_ref[b, blk * ppb + p]
         k_copy = pltpu.make_async_copy(k_hbm.at[pid], kbuf.at[slot, p],
                                        sem.at[0, slot])
-        if latent:
+        if one_pool:
             return (k_copy,)
         return (k_copy,
                 pltpu.make_async_copy(v_hbm.at[pid], vbuf.at[slot, p],
@@ -460,7 +476,7 @@ def _paged_decode_kernel(tbl_ref, idx_ref, q_ref, k_hbm, v_hbm, o_ref,
 
         def zero(p):
             kbuf[slot, p] = jnp.zeros(kbuf.shape[2:], kbuf.dtype)
-            if not latent:
+            if not one_pool:
                 vbuf[slot, p] = jnp.zeros(vbuf.shape[2:], vbuf.dtype)
 
         n = live_pages(blk)
@@ -487,7 +503,7 @@ def _paged_decode_kernel(tbl_ref, idx_ref, q_ref, k_hbm, v_hbm, o_ref,
 
         wait(blk, slot)
         kflat = kbuf.at[slot].reshape(t * h, d)
-        if not latent:
+        if not one_pool:
             vflat = vbuf.at[slot].reshape(t * h, d)
         kpos = blk * t
         col = jax.lax.broadcasted_iota(
@@ -513,7 +529,8 @@ def _paged_decode_kernel(tbl_ref, idx_ref, q_ref, k_hbm, v_hbm, o_ref,
             rows = kflat[...]
             o, m, l = bw.block_accumulate(
                 oacc_ref[0], m_ref[0][:, 0], l_ref[0][:, 0], q_ref[0],
-                rows, rows[:, :oacc_ref.shape[-1]] if latent else vflat[...],
+                rows,
+                rows[:, :oacc_ref.shape[-1]] if one_pool else vflat[...],
                 scale, bias)
             oacc_ref[0] = o
             m_ref[0] = m[:, None]
@@ -522,7 +539,8 @@ def _paged_decode_kernel(tbl_ref, idx_ref, q_ref, k_hbm, v_hbm, o_ref,
 
         def head_words(i, c):
             ks = _head_rows(kflat, g * heads + i * pack, h, t)
-            vs = _head_rows(vflat, g * heads + i * pack, h, t)
+            vs = ks if one_pool else _head_rows(vflat, g * heads + i * pack,
+                                                h, t)
             for j, (k, v) in enumerate(zip(ks, vs)):
                 u = i * pack + j
                 o, m, l = bw.block_accumulate(
@@ -631,7 +649,11 @@ def paged_flash_decode(q, pool_k, pool_v, block_table, index, *,
     ``[P, page_size, W]`` — one row a token for every query head, whose
     first ``value_lanes`` lanes are the value — with
     :func:`latent_paged_attention`'s contract (``scale`` is then required:
-    the row's width is not a head's)."""
+    the row's width is not a head's).
+
+    ``pool_v`` None without ``value_lanes``: ``pool_k`` [P, page_size, H,
+    2 * Dh] holds ``[k | v]`` of a head in one row (:func:`paged_attention`);
+    the pool is streamed once, for scores and values both."""
     if pool_k.dtype not in (jnp.bfloat16, jnp.float32):
         raise ValueError(f"paged_flash_decode reads bf16 or f32 pools, "
                          f"not {pool_k.dtype} (see _head_rows)")
@@ -642,6 +664,15 @@ def paged_flash_decode(q, pool_k, pool_v, block_table, index, *,
         return _latent_flash_decode(
             q, pool_k, block_table, index, scale=float(scale),
             interpret=interpret, value_lanes=int(value_lanes))
+    one_pool = pool_v is None
+    if one_pool:
+        dh = q.shape[-1]
+        if pool_k.shape[-1] != 2 * dh:
+            raise ValueError(f"a [k | v] pool's row is twice the head: "
+                             f"{pool_k.shape[-1]} lanes for heads of {dh}")
+        # zeros meet the row's value half: q . [k | v] is q . k
+        scale = float(scale) if scale is not None else 1.0 / (dh ** 0.5)
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, dh)))
     b, s, hq, d = q.shape
     page_size, h = pool_k.shape[1], pool_k.shape[2]
     m_pages = block_table.shape[1]
@@ -654,6 +685,9 @@ def paged_flash_decode(q, pool_k, pool_v, block_table, index, *,
         # a head count the TPU's (sublane, lane) tiling cannot hold
         # whole (6 or 3 bf16 heads: ``transformer_tpu``): zero heads
         # fill the tile.  This one case copies the pools, every call
+        if one_pool:
+            raise ValueError(f"a [k | v] pool of {h} heads does not tile "
+                             f"(1, 2, 4 or a multiple of 8 words of heads)")
         zeros = ((0, 0), (0, 0), (0, hp - h), (0, 0))
         qpad = ((0, 0), (0, 0), (0, (hp - h) * group), (0, 0))
         return paged_flash_decode(
@@ -684,17 +718,17 @@ def paged_flash_decode(q, pool_k, pool_v, block_table, index, *,
     else:
         qo_spec = pl.BlockSpec((None, hg, rows, d),
                                lambda b_, g_, tbl, idx: (b_, g_, 0, 0))
+    pools = (pool_k,) if one_pool else (pool_k, pool_v)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, groups),
-        in_specs=[qo_spec,
-                  pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec(memory_space=pl.ANY)],
+        in_specs=[qo_spec] + [pl.BlockSpec(memory_space=pl.ANY)
+                              for _ in pools],
         out_specs=qo_spec,
         scratch_shapes=[
-            pltpu.VMEM((2, ppb, page_size, h, d), pool_k.dtype),
-            pltpu.VMEM((2, ppb, page_size, h, d), pool_v.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((2, ppb, page_size, h, d), p.dtype) for p in pools
+        ] + [
+            pltpu.SemaphoreType.DMA((len(pools), 2)),
             pltpu.VMEM((hg, rows, d), jnp.float32),
             pltpu.VMEM((hg, rows, 1), jnp.float32),
             pltpu.VMEM((hg, rows, 1), jnp.float32),
@@ -702,17 +736,23 @@ def paged_flash_decode(q, pool_k, pool_v, block_table, index, *,
     )
     if window is not None:
         kernel_kw["window"] = int(window)
+    kernel = functools.partial(_paged_decode_kernel, **kernel_kw)
+    if one_pool:
+        def kernel(tbl_ref, idx_ref, q_ref, k_hbm, o_ref, kbuf, sem, *carry):
+            _paged_decode_kernel(tbl_ref, idx_ref, q_ref, k_hbm, None, o_ref,
+                                 kbuf, None, sem, *carry, **kernel_kw)
     out = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, **kernel_kw),
+        kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qh.shape, q.dtype),
         interpret=interpret,
         name="paged_flash_decode",
     )(jnp.asarray(block_table, jnp.int32), jnp.asarray(index, jnp.int32),
-      qh, pool_k, pool_v)
+      qh, *pools)
     if group > 1 or all_heads:
         out = out.reshape(b, hq, s, d)
-    return jnp.swapaxes(out, 1, 2)
+    out = jnp.swapaxes(out, 1, 2)
+    return out[..., d // 2:] if one_pool else out
 
 
 def paged_flash_decode_reference(q, pool_k, pool_v, block_table, index, *,
@@ -764,7 +804,9 @@ def paged_attention_auto(q, pool_k, pool_v, block_table, index, *,
     last page without a per-window recompile.  ``window`` (static) is
     the layer's attention window in tokens, for both.  ``value_lanes``
     set: ``pool_k`` is a latent pool and ``pool_v`` None (``scale`` and
-    ``value_lanes`` say what the row is; :func:`latent_paged_attention`)."""
+    ``value_lanes`` say what the row is; :func:`latent_paged_attention`);
+    ``pool_v`` None without it: a pool of ``[k | v]`` rows
+    (:func:`paged_attention`)."""
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
     if value_lanes is not None:

@@ -148,7 +148,13 @@ def serving_memory_plan(model, *, num_slots: int, max_seq_len: int,
     materialised): ``per_token_kv_bytes`` sums, over every pool of every
     layer, one token's row — K and V of ``kv_heads x head_dim`` for whole
     or grouped-query heads, one latent row (``kv_heads`` 1, ``head_dim``
-    its stored lanes) for latent attention.  ``param_bytes`` is what the
+    its stored lanes) for latent attention, one row of ``[k | v]`` a KV
+    head (``head_dim`` twice the head's) where K and V share a pool.  A
+    ``PAGE_STATE`` leaf (``serve.decode.CACHE_LEAF_KINDS`` names every
+    leaf's kind) is STATE A PAGE (a
+    short-convolution layer's running entry): ``state_bytes_per_page``
+    sums those, and ``state_bytes_paged`` is what they hold beside
+    ``kv_bytes_paged``.  ``param_bytes`` is what the
     parameter tree holds in the dtype it is held in (``params``: the
     tree or its shapes; None = the shapes of the model's own init).
 
@@ -161,14 +167,16 @@ def serving_memory_plan(model, *, num_slots: int, max_seq_len: int,
     guess."""
     import numpy as np
 
-    from dtf_tpu.serve.decode import trace_paged_init
+    from dtf_tpu.serve.decode import (KV_POOL, LATENT_POOL, cache_leaves,
+                                      state_bytes_per_page, trace_paged_init)
 
-    pools = jax.tree_util.tree_leaves(
-        trace_paged_init(model, kv_page_size, 2)[0])
+    shapes = trace_paged_init(model, kv_page_size, 2)[0]
+    kinds, pools = zip(*cache_leaves(shapes, KV_POOL, LATENT_POOL))
     per_token = sum(int(np.prod(p.shape[2:])) * np.dtype(p.dtype).itemsize
                     for p in pools)
+    per_page_state = state_bytes_per_page(shapes)
     # [P, page, H, Dh] a K or V pool; [P, page, W] a pool of latent rows
-    kv_heads = pools[0].shape[2] if pools[0].ndim == 4 else 1
+    kv_heads = pools[0].shape[2] if kinds[0] == KV_POOL else 1
     head_dim = pools[0].shape[-1]
     if params is None:
         params = jax.eval_shape(
@@ -188,6 +196,8 @@ def serving_memory_plan(model, *, num_slots: int, max_seq_len: int,
         "param_bytes_per_device": param_bytes // mp,
         "per_token_kv_bytes": per_token,
         "kv_bytes_paged": paged_tokens * per_token,
+        "state_bytes_per_page": per_page_state,
+        "state_bytes_paged": (pool_pages - 1) * per_page_state,
         "kv_tokens_capacity": paged_tokens,
         "pages_per_slot": pages_per_slot,
         "pool_pages": pool_pages,
@@ -200,9 +210,10 @@ def serving_memory_plan(model, *, num_slots: int, max_seq_len: int,
     log.info(
         "serving memory plan: %d slots x %d tokens; weights %.1f MB; "
         "%d pools of %d x %d a token, %d B/token; KV page pool %.1f MB "
-        "(%d pages x %d tokens)%s", num_slots, max_seq_len,
+        "(%d pages x %d tokens); state %d B/page%s", num_slots, max_seq_len,
         param_bytes / 2**20, len(pools), kv_heads, head_dim, per_token,
         plan["kv_bytes_paged"] / 2**20, pool_pages, kv_page_size,
+        per_page_state,
         (f", TP={mp}: {plan['kv_bytes_per_device'] / 2**20:.1f} "
          f"MB KV/device" if mp > 1 else ""))
     return plan

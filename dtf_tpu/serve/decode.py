@@ -15,8 +15,9 @@ around it:
                           the decode path is verified token-exact against
 
 The cache is a shared pool per layer — [pool_pages, page_size, H, Dh]
-for K and for V, or one [pool_pages, page_size, W] of latent rows, as the
-model's attention stores it — plus per-slot block tables
+for K and for V (or one of ``[k | v]`` rows), one [pool_pages, page_size,
+W] of latent rows, or one [pool_pages, W] of state entries a page, as the
+model's layers store it — plus per-slot block tables
 (ops.paged_attention); this module treats it as a tree of leaves whose
 first axis is the page.  Prefill runs in
 page-aligned chunks: the FIRST chunk goes through the flash kernel
@@ -47,6 +48,7 @@ from __future__ import annotations
 
 import functools
 import logging
+import math
 from typing import Optional
 
 import jax
@@ -81,12 +83,41 @@ def make_decode_model(model, kv_page_size, kv_pool_pages, model_axis=None):
     return model.clone(**kw)
 
 
+# What a cache leaf IS, by the name its layer declares it under
+# (``variable("cache", <name>, ...)``): the one place the serving layer
+# learns a leaf's layout.  Every leaf's first axis is the page id; a layer
+# that keeps a new kind of leaf adds its name here, and a name that is not
+# here is refused by the init trace — never guessed from a leaf's rank.
+KV_POOL, LATENT_POOL, PAGE_STATE = "kv_pool", "latent_pool", "page_state"
+CACHE_LEAF_KINDS = {
+    "paged_key": KV_POOL, "paged_value": KV_POOL,   # [P, page, H, Dh]
+    "paged_kv": KV_POOL,            # [P, page, H, 2 * Dh]: rows of [k | v]
+    "paged_latent": LATENT_POOL,    # [P, page, W]: one row a token
+    "conv_state": PAGE_STATE,       # [P, W]: one running entry a page
+}
+
+
+def cache_leaves(cache_shapes, *kinds) -> list:
+    """The cache's leaves of these kinds (all kinds where none is named),
+    in tree order, as (kind, leaf)."""
+    out = []
+    for path, leaf in jax.tree_util.tree_leaves_with_path(cache_shapes):
+        name = getattr(path[-1], "key", None)
+        if name not in CACHE_LEAF_KINDS:
+            raise ValueError(
+                f"cache leaf {jax.tree_util.keystr(path)} has a name no "
+                f"kind is known for (known: {sorted(CACHE_LEAF_KINDS)}): "
+                f"say what it is in serve/decode.py CACHE_LEAF_KINDS")
+        if not kinds or CACHE_LEAF_KINDS[name] in kinds:
+            out.append((CACHE_LEAF_KINDS[name], leaf))
+    return out
+
+
 def trace_paged_init(model, kv_page_size: int, kv_pool_pages: int):
     """ONE abstract trace of the paged decode model's init (no params —
     and no cache — materialized), for the two things it shows: the
-    ShapeDtypeStruct pytree of the paged cache (a
-    [kv_pool_pages, kv_page_size, H, Dh] pool per layer per K/V, or one
-    [kv_pool_pages, kv_page_size, W] of latent rows per layer), and
+    ShapeDtypeStruct pytree of the paged cache (every leaf of a kind
+    ``CACHE_LEAF_KINDS`` names: :func:`cache_leaves` reads them), and
     ``owners`` — by path ("block0/attn/qkv"; "" is the model itself) the
     (class, dtype) of every module that ran."""
     import flax.linen as nn
@@ -107,7 +138,16 @@ def trace_paged_init(model, kv_page_size: int, kv_pool_pages: int):
         shapes = jax.eval_shape(
             functools.partial(decode_model.init, jax.random.key(0)),
             tokens, cache_index=idx, block_table=table)["cache"]
+    cache_leaves(shapes)                # every leaf's kind is known
     return shapes, owners
+
+
+def state_bytes_per_page(cache_shapes) -> int:
+    """Bytes of running state a page carries beside its tokens: the
+    cache's ``PAGE_STATE`` leaves (a short-convolution layer's entry a
+    page; 0 for a cache of pools alone)."""
+    return sum(math.prod(p.shape[1:]) * jnp.dtype(p.dtype).itemsize
+               for _, p in cache_leaves(cache_shapes, PAGE_STATE))
 
 
 def _sample(logits, temperature, key):
@@ -263,18 +303,25 @@ class Decoder:
         return P(None, None, self._model_axis, None)
 
     def _apply_model(self, params, cache, tokens, index, block_table,
-                     flash_prefill, window_pages):
+                     flash_prefill, window_pages, last_pos=None):
         """model.apply with mutable cache — direct on one device,
         shard_mapped over the mesh under TP (tokens/index/tables
         replicated in, logits replicated out, cache specs on the pool
         head dim; flash_prefill/window_pages are trace-time statics
-        closed over)."""
+        closed over).  ``last_pos`` [B] goes to a model whose cache
+        carries state (``carries_state``) and to no other."""
+        if self.tp > 1 and last_pos is not None:
+            raise NotImplementedError(
+                "a cache that carries state a page has no tensor-parallel "
+                "layout yet: its entries would be taken at the padded end "
+                "of a final chunk")
         if self.tp == 1:
+            state_kw = {} if last_pos is None else {"last_pos": last_pos}
             return self.model.apply(
                 {"params": params, "cache": cache}, tokens,
                 cache_index=index, block_table=block_table,
                 flash_prefill=flash_prefill, window_pages=window_pages,
-                mutable=["cache", "stats"])
+                mutable=["cache", "stats"], **state_kw)
         from jax.sharding import PartitionSpec as P
 
         cspec = jax.tree_util.tree_map(lambda _: self._cache_pspec(),
@@ -323,14 +370,26 @@ class Decoder:
         return zeros()
 
     @functools.cached_property
+    def carries_state(self) -> bool:
+        """Whether the cache holds running state beside pages of history
+        (a ``PAGE_STATE`` leaf: a short-convolution layer's entry a page;
+        such a model's call takes ``last_pos``).  Such a cache rides
+        copies, sharing and migration like any other, but the newest token
+        of a page cannot be replayed on a copy of it."""
+        return bool(cache_leaves(self._init_trace[0], PAGE_STATE))
+
+    @property
+    def state_bytes_per_page(self) -> int:
+        return state_bytes_per_page(self._init_trace[0])
+
+    @functools.cached_property
     def decode_all_heads(self) -> bool:
         """Whether the decode body's paged kernel scores a stored block
         all heads at once (``ops.paged_attention._plan``, from the same
         shapes the body hands it): false head by head, and where no
         such kernel runs — the gather path, latent pools only."""
         from dtf_tpu.ops.paged_attention import decode_scores_all_heads
-        pools = [p for p in jax.tree_util.tree_leaves(self._init_trace[0])
-                 if p.ndim == 4]            # [P, page, H, Dh]; latent: 3
+        pools = [p for _, p in cache_leaves(self._init_trace[0], KV_POOL)]
         return bool(self._kernel_attn and pools) and all(
             decode_scores_all_heads(
                 self.model.num_heads // self.tp, p.shape[2] // self.tp,
@@ -410,9 +469,12 @@ class Decoder:
                     temperature, key, start, window_pages, flash_prefill):
         """One prefill chunk.  tokens [1, C] (page-aligned, tail-padded
         with zeros), block_row [1, M] the slot's page ids, sample_pos
-        scalar (offset WITHIN the chunk of the last real prompt token —
-        only read on the final chunk; earlier chunks' sampled token is
-        discarded by the engine).  ``start`` (the chunk's first logical
+        scalar (offset WITHIN the chunk of its last real prompt token, so
+        ``sample_pos + 1`` is the chunk's real length: ``C`` on every chunk
+        but a tail-padded final one; a non-final chunk's sampled token is
+        discarded by the engine, and a model whose cache carries state
+        takes its entries no later than this token).  ``start`` (the
+        chunk's first logical
         position) is a traced scalar; ``window_pages`` (pages covering
         [0, start + C), gather path — None under the kernel) and
         ``flash_prefill`` (start == 0: causal-only via the flash
@@ -421,7 +483,8 @@ class Decoder:
         logits, mut = self._apply_model(
             params, cache, tokens,
             jnp.broadcast_to(jnp.asarray(start, jnp.int32), (1,)),
-            block_row, flash_prefill, window_pages)
+            block_row, flash_prefill, window_pages,
+            jnp.reshape(sample_pos, (1,)) if self.carries_state else None)
         last = jax.lax.dynamic_slice_in_dim(
             logits[0], sample_pos, 1, axis=0)[0]           # [V]
         tok = _sample(last, temperature, key)
@@ -449,8 +512,10 @@ class Decoder:
         chunk: 1-D int32, len(chunk) % page_size == 0 (engine-padded);
         block_row: [M] int32 page ids for the slot; start: the chunk's
         first logical position; sample_pos: offset within the chunk of
-        the last REAL prompt token (engine passes 0 for non-final
-        chunks and ignores the sampled token).  Returns (token, cache,
+        its last REAL prompt token — ``sample_pos + 1`` is the chunk's
+        real length, ``len(chunk)`` on every chunk but a tail-padded final
+        one (the engine ignores a non-final chunk's sampled token).
+        Returns (token, cache,
         logits) — the first-chunk (start == 0) body routes attention
         through the flash kernel; continuation chunks attend the paged
         prefix.  ``seed`` is the request's: the sample is keyed to the

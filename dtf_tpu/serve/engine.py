@@ -620,6 +620,10 @@ class ServeEngine:
                                                 unit="pages")
         self._m_pages_sampled = self.metrics.histogram(
             "serve_kv_pages_used_sampled", unit="pages")
+        # what a page holds beside its tokens' K and V: the bytes of the
+        # running-state entries (0 for a model whose layers all attend)
+        self.metrics.gauge("serve_state_bytes_per_page", unit="bytes").set(
+            self.decoder.state_bytes_per_page)
         self._m_prefill_chunks = self.metrics.counter(
             "serve_prefill_chunks_total", unit="chunks")
         self._m_decode_gap = self.metrics.histogram("serve_decode_gap_s",
@@ -1188,13 +1192,18 @@ class ServeEngine:
                   if self.prefix_sharing else [])
         cow = bool(shared) and len(shared) * self.page_size >= int(
             req.prompt.size)
-        if cow and total_pages + 1 > self.pool.usable_pages:
+        if cow and (self.decoder.carries_state
+                    or total_pages + 1 > self.pool.usable_pages):
             # the COW target makes physical demand total_pages + 1 —
             # past the submit guard's total_pages <= usable bound, so
             # a request sized exactly to the pool would LIVELOCK here
             # (its own share holds the chain above eviction's
             # refcount-1 bar).  Degrade: drop the chain's last page
-            # and prefill it instead — demand is back to total_pages
+            # and prefill it instead — demand is back to total_pages.
+            # A cache that carries state degrades always: the copied
+            # page's state entry is already past the token the slot
+            # would replay, and the page before it holds the carry the
+            # prefill of the last page starts from
             shared = shared[:-1]
             cow = False
         need = total_pages - len(shared) + (1 if cow else 0)
@@ -1264,7 +1273,8 @@ class ServeEngine:
         start, clen = slot.chunk_plan[slot.chunk_i]
         is_last = slot.chunk_i == len(slot.chunk_plan) - 1
         plen = int(req.prompt.size)
-        sample_pos = plen - 1 - start if is_last else 0
+        # the chunk's last real token: its real length less one
+        sample_pos = plen - 1 - start if is_last else clen - 1
         t0 = time.perf_counter()
         pre_compiled = self.decoder.compiled_count
         with trace.span("serve_prefill_chunk", slot=slot_idx, start=start,

@@ -351,6 +351,64 @@ def test_latent_serve_bodies_compile_for_v5e(v5e, body):
     assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9
 
 
+@pytest.mark.parametrize("body", ["chunk_first", "chunk", "chunk_of_a_page",
+                                  "decode"])
+def test_state_carrying_serve_bodies_compile_for_v5e(v5e, body):
+    """The bodies of the decoder whose layers are short convolutions
+    beside grouped-query attention, at its benchmark widths (16 layers: 12
+    with a state entry a page and 4 with one pool of ``[k | v]`` rows of 8
+    heads x 128 lanes; 32 experts of 1792; a tied vocabulary of 65,536;
+    shapes only), 96 slots of 8,704 tokens, pages of 64 in a 327,680-token
+    pool: the first chunk through the flash kernel at heads of 64, a later
+    chunk — of 1,024 tokens, and of ONE page, which is what the
+    tail-padded final chunk of the agreement's 8,193-token prompt is — and
+    the decode step (all heads at once) through the paged kernel, the
+    pools AND the state leaves updated in place."""
+    from dtf_tpu.models import build_model
+    from dtf_tpu.serve import decode as sd
+    i32, f32 = jnp.int32, jnp.float32
+    period = ["short_conv", "short_conv", "attention", "short_conv"]
+    model, _ = build_model(
+        "routed_decoder", num_classes=65536, dtype=jnp.bfloat16,
+        num_layers=16, d_model=2048, num_heads=32, num_kv_heads=8,
+        head_dim=64, layer_mixer=period * 4, qk_norm=True,
+        tie_head=True, layer_window=[False], layer_rope=[True],
+        rope_theta=1e6, num_dense_layers=2, dense_width=7168,
+        num_experts=32, experts_per_token=4, expert_width=1792,
+        routing="sigmoid_bias", routing_sum_eps=1e-6, activation="silu",
+        router_input="post_attention", rms_eps=1e-5, max_seq_len=128000,
+        param_dtype="bfloat16")
+    params = jax.eval_shape(model.clone(use_pallas=False).init,
+                            jax.random.key(0), jnp.zeros((1, 64), i32)
+                            )["params"]
+    dec = _shapes_only_decoder(model, params, num_slots=96,
+                               max_seq_len=8704, kv_page_size=64,
+                               kv_pool_pages=5121)
+    assert dec.carries_state and dec.decode_all_heads
+    assert dec.state_bytes_per_page == 12 * 2 * 2048 * 2
+    if body == "decode":
+        compiled = _compile_decode_body(dec, v5e)
+    else:
+        s, m = jax.ShapeDtypeStruct, dec.pages_per_slot
+        args = _on_chip((dec.params, jax.eval_shape(dec.fresh_cache),
+                         s((1, 64 if body == "chunk_of_a_page" else 1024),
+                           i32), s((1, m), i32), s((), i32),
+                         s((), f32),
+                         jax.eval_shape(lambda: sd.position_key(0, 0)),
+                         s((), i32)), v5e)
+        compiled = jax.jit(
+            dec._chunk_impl, donate_argnums=(1,), static_argnums=(8, 9),
+            compiler_options=sd.TPU_BODY_OPTIONS).lower(
+                *args, None, body == "chunk_first").compile()
+    text = compiled.as_text()
+    if body == "chunk_first":
+        assert "flash_fwd" in text or text.count("tpu_custom_call") >= 4
+    else:
+        assert text.count("paged_flash_decode") >= 4    # a call a layer
+    # 3.19e9 B of pools and entries are donated and updated in place
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+
+
 def test_dense_decode_body_compiles_for_v5e(v5e):
     """The dense cells' whole decode body with ``TPU_BODY_OPTIONS``, not
     the kernel alone (what a kernel may take of VMEM depends on the body
