@@ -168,8 +168,8 @@ def ledger_rows(merged: List[dict]) -> List[dict]:
     """The MFU/cost ledger as machine-readable rows from
     ledger_exec/ledger_summary events — latest record per (rank,
     executable) wins (a re-compile or a later summary supersedes).
-    One dict per (rank, exec): flops/bytes/kernels/count/mean_s/
-    achieved_tflops/mfu/hbm_frac (missing fields None).  This is the
+    One dict per (rank, exec): flops/bytes/kernels/collectives/count/
+    mean_s/achieved_tflops/mfu/hbm_frac (missing fields None).  This is the
     join surface the capacity simulator's calibration reads — the
     human table in :func:`print_ledger` renders the same rows."""
     rows: Dict[tuple, dict] = {}
@@ -178,7 +178,8 @@ def ledger_rows(merged: List[dict]) -> List[dict]:
             key = (str(rec.get("rank", "?")), rec.get("exec", "?"))
             rows.setdefault(key, {}).update(
                 flops=rec.get("flops"), bytes=rec.get("bytes"),
-                kernels=rec.get("kernels"))
+                kernels=rec.get("kernels"),
+                collectives=rec.get("collectives"))
         elif rec.get("name") == "ledger_summary":
             key = (str(rec.get("rank", "?")), rec.get("exec", "?"))
             rows.setdefault(key, {}).update(

@@ -249,7 +249,7 @@ class Config:
     #       XLA's latency-hiding scheduler overlaps the collectives
     #       with compute); the grad-accumulation buffer shrinks by the
     #       data-parallel degree
-    #   3 = + sharded parameters: params live as 1/N flat slices and
+    #   3 = + sharded parameters: params live as 1/N column slices and
     #       are all-gathered per leaf at the top of each step — a model
     #       whose replicated state does not fit one device trains
     # Every stage is mathematically identical to plain DP (test-pinned

@@ -24,6 +24,9 @@ Prometheus endpoint (``--metrics_port``), exported post-run through
 ``BenchmarkFileLogger.log_registry``.  Registration and summaries also
 land in the trace stream (``ledger_exec`` / ``ledger_summary`` events),
 so ``trace_main --ledger`` renders the table from trace files alone.
+Beside the counts an entry names what the compiler left in the program:
+its Pallas kernels and its collectives (``reduce-scatter`` / ``all-reduce``
+/ ``all-gather`` call sites and operand bytes), logged once at compile.
 
 Peaks come from the device kind (the same public-spec tables bench.py
 and bench_profile.py carry); unknown kinds (CPU) export no mfu/hbm_frac
@@ -123,14 +126,60 @@ def pallas_kernels(compiled) -> Dict[str, int]:
     device will run, not what a flag asked for: a reference
     formulation (blockwise, gather) or interpret mode lowers to plain
     HLO and shows up here as absent."""
+    return _pallas_kernels(compiled.as_text())
+
+
+def _pallas_kernels(hlo: str) -> Dict[str, int]:
     counts: Dict[str, int] = {}
-    for line in compiled.as_text().splitlines():
+    for line in hlo.splitlines():
         if 'custom_call_target="tpu_custom_call"' not in line:
             continue
         m = _PALLAS_OP.search(line)
         name = m.group(1) if m else "pallas_call"
         counts[name] = counts.get(name, 0) + 1
     return counts
+
+
+COLLECTIVE_OPS = ("reduce-scatter", "all-reduce", "all-gather")
+_HLO_DEF = re.compile(r"^\s*(?:ROOT )?(%[\w.-]+) = (.*?) ([a-z][\w-]*)\((.*)$")
+_HLO_ARRAY = re.compile(r"\b([a-z]+)(\d+)?\[([\d,]*)\]")
+
+
+def _hlo_type_bytes(text: str) -> int:
+    """Bytes of an HLO type as printed (an array, or a tuple of them)."""
+    total = 0
+    for kind, bits, dims in _HLO_ARRAY.findall(text):
+        n = math.prod(int(d) for d in dims.split(",") if d)
+        total += n * (int(bits) // 8 if bits else 1)   # pred: one byte
+    return total
+
+
+def collectives(compiled) -> Dict[str, Dict[str, int]]:
+    """{op: {"ops": call sites, "bytes": bytes of their operands}} of
+    the cross-device collectives in a compiled executable, read from its
+    optimized HLO like ``pallas_kernels`` — what the device will run: a
+    ``psum_scatter`` the compiler decomposed shows up as an
+    ``all-reduce`` of the whole operand, not as the ``reduce-scatter``
+    the program asked for.  An async pair counts once, at its
+    ``-start``."""
+    return _collectives(compiled.as_text())
+
+
+def _collectives(hlo: str) -> Dict[str, Dict[str, int]]:
+    sizes: Dict[str, int] = {}      # a computation defines before it uses
+    out = {op: {"ops": 0, "bytes": 0} for op in COLLECTIVE_OPS}
+    for line in hlo.splitlines():
+        m = _HLO_DEF.match(line)
+        if not m:
+            continue
+        name, result, op, rest = m.groups()
+        sizes[name] = _hlo_type_bytes(result)
+        op = op.removesuffix("-start")
+        if op in COLLECTIVE_OPS:
+            operands = re.findall(r"%[\w.-]+", rest.split(")")[0])
+            out[op]["ops"] += 1
+            out[op]["bytes"] += sum(sizes.get(o, 0) for o in operands)
+    return out
 
 
 class Ledger:
@@ -159,10 +208,13 @@ class Ledger:
         (gather-path chunk window variants) updates the counts and
         keeps the accumulated timing."""
         kernels: Dict[str, int] = {}
+        comms: Dict[str, Dict[str, int]] = {}
         if compiled is not None:
             try:
                 flops, bytes_accessed = cost_of(compiled)
-                kernels = pallas_kernels(compiled)
+                hlo = compiled.as_text()    # megabytes: printed once
+                kernels = _pallas_kernels(hlo)
+                comms = _collectives(hlo)
             except Exception as e:  # noqa: BLE001 — a backend without
                 # cost_analysis must not take down the step it measures,
                 # but a step that runs without an entry must be visible
@@ -174,23 +226,28 @@ class Ledger:
             if e is None:
                 e = self._execs[name] = {"flops": 0.0, "bytes": 0.0,
                                          "count": 0, "total_s": 0.0,
-                                         "kernels": {}}
+                                         "kernels": {}, "collectives": {}}
             e["flops"] = float(flops)
             e["bytes"] = float(bytes_accessed)
             # a name shared by several bodies (a chunk shape's first and
             # continuation bodies) accumulates the kernels of all of them
             e["kernels"].update(kernels)
             kernels = dict(e["kernels"])
+            e["collectives"] = comms
         if compiled is not None:
             log.info("ledger: %s compiled — %.4g flops, %.4g bytes, "
-                     "pallas kernels %s", name, flops, bytes_accessed,
-                     kernels or "none")
+                     "pallas kernels %s, collectives %s", name, flops,
+                     bytes_accessed, kernels or "none",
+                     ", ".join(f"{c['ops']} {op} ({c['bytes']:.4g} B)"
+                               for op, c in comms.items() if c["ops"])
+                     or "none")
         self.registry.gauge(f"ledger_{name}_flops",
                             unit="flops").set(flops)
         self.registry.gauge(f"ledger_{name}_bytes",
                             unit="bytes").set(bytes_accessed)
         trace.event("ledger_exec", exec=name, flops=float(flops),
                     bytes=float(bytes_accessed), kernels=kernels,
+                    collectives=comms,
                     peak_tflops=(self.peak_flops / 1e12
                                  if self.peak_flops else None),
                     peak_hbm_gbps=(self.peak_hbm / 1e9
@@ -224,7 +281,7 @@ class Ledger:
                 nbytes / mean / self.peak_hbm)
 
     def summary(self) -> Dict[str, dict]:
-        """{exec: {flops, bytes, kernels, count, mean_s,
+        """{exec: {flops, bytes, kernels, collectives, count, mean_s,
         achieved_tflops, mfu, hbm_frac}} — mfu/hbm_frac None when the
         peak is unknown."""
         out: Dict[str, dict] = {}
@@ -236,6 +293,7 @@ class Ledger:
             out[name] = {
                 "flops": e["flops"], "bytes": e["bytes"],
                 "kernels": dict(e["kernels"]),
+                "collectives": dict(e["collectives"]),
                 "count": e["count"], "mean_s": mean,
                 "achieved_tflops": achieved / 1e12,
                 "mfu": (achieved / self.peak_flops

@@ -10,8 +10,9 @@ pre-staged and this module is the thin layer that binds them:
     ``canonical_state``), so a checkpoint is TOPOLOGY-FREE: restoring
     it onto an arbitrary surviving mesh is ``staged_state`` — each
     leaf re-slices through the train/zero.py layout contract
-    (``pad_flat`` zero-pads to the NEW nd·k, so a non-dividing new dp
-    costs pad rows that provably stay zero, not correctness).
+    (``as_view`` zero-pads to the NEW nd's tile grid, so a
+    non-dividing new dp costs padding that provably stays zero, not
+    correctness).
   - The data stream is a pure function of position (PR 6): per-shard
     data-service positions are derived from the restored step alone,
     and worker count is a non-identity — so the stream remaps to the
@@ -121,8 +122,9 @@ def check_reshardable(pspecs, leaves, mesh_shape: dict) -> List[str]:
     """Violation messages for leaves that CANNOT shard onto a mesh of
     ``mesh_shape`` — empty when the whole tree reshards.
 
-    The ZeRO flat-slice layout reshards onto ANY data-parallel degree
-    by construction (``pad_flat`` zero-pads to the new nd·k), so the
+    The ZeRO column-slice layout reshards onto ANY data-parallel degree
+    by construction (``as_view`` zero-pads to the new nd's tile grid:
+    a leaf's view, and with it its slices' shapes, follow nd), so the
     only real constraints are the leaves whose MODEL partition spec
     pins a tensor dimension to a mesh axis: expert leaves riding
     'data' need the new dp to divide their expert dimension, and

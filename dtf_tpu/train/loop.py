@@ -288,9 +288,10 @@ class Trainer:
         params = variables["params"]
         batch_stats = variables.get("batch_stats", {})
         if self.zero:
-            # optimizer state over PADDED FLAT leaves [nd·k] (per
-            # (data, model) coordinate when the param is model-sharded;
-            # locally-shaped for expert leaves — zero_lib.zero_leaf_spec).
+            # optimizer state over each leaf's column-sliced 2-D view
+            # [rows, nd·k] (a column block per (data, model) coordinate
+            # when the param is model-sharded; locally-shaped for expert
+            # leaves — zero_lib.slice_view / zero_leaf_spec).
             # Init under jit with sharded out_shardings so the full
             # state never materializes on one device (the transient
             # spike would OOM exactly the model sizes this targets)
@@ -313,8 +314,8 @@ class Trainer:
             # surviving mesh: leaves whose model spec pins a tensor dim
             # to a mesh axis (experts over 'data', TP/PP over 'model')
             # must refuse a non-dividing topology loudly — the ZeRO
-            # flat-slice layout itself reshards onto any nd by
-            # construction (pad_flat zero-pads to the new grid)
+            # slice layout itself reshards onto any nd by construction
+            # (as_view zero-pads to the new nd's tile grid)
             from dtf_tpu.train import elastic as elastic_lib
             problems = elastic_lib.check_reshardable(
                 pspecs, params, mesh_shape)
@@ -333,8 +334,9 @@ class Trainer:
                 msz = 1
                 for a in axes:
                     msz *= mesh_shape[a]
-                k = -(-(p.size // msz) // nd)
-                return jax.ShapeDtypeStruct((nd * msz * k,), p.dtype)
+                rows, k = zero_lib.slice_shape(
+                    zero_lib.local_shape(spec, p.shape, mesh_shape), nd)
+                return jax.ShapeDtypeStruct((rows, nd * msz * k), p.dtype)
 
             protos = jax.tree_util.tree_map(proto_leaf, pspecs, params,
                                             is_leaf=is_p)
@@ -428,8 +430,8 @@ class Trainer:
     # state — so a checkpoint saved at any ZeRO stage restores into any
     # other stage and into serving via the bridge's structure-free
     # loader.  The conversions are pure per-leaf reshapes/collectives
-    # (train/zero.py): gather-trim-reshape out, pad-flatten-slice back
-    # in.  Padding rows are zeros in every supported optimizer's state
+    # (train/zero.py): gather-trim-reshape out, pad-view-slice back
+    # in.  Padding elements are zeros in every supported optimizer's state
     # (optimizer.ZEROS_INIT_OPTIMIZERS), so dropping them on save and
     # re-creating them on restore is exact — the round trip is
     # bit-identical, which is what keeps killed-at-K resume trajectory-

@@ -7,15 +7,22 @@ stage-3 parameter store, and the canonical-checkpoint conversions:
   - a leaf already sharded over 'data' (MoE experts riding the batch
     axis) keeps its full LOCAL shape — each data shard holds distinct
     experts, there is nothing left to slice;
-  - every other leaf's ZeRO slice is a padded flat buffer: the LOCAL
-    (TP/PP) shard flattened, zero-padded to nd·k, and split into nd
-    chunks of k — PartitionSpec ('data',), composed with 'model' when
-    the param itself shards there (each (data, model) coordinate owns
-    one k-slice of its model shard).
+  - every other leaf's ZeRO slice is a block of COLUMNS of a 2-D view
+    of the LOCAL (TP/PP) shard (``slice_view``): the leaf's own last
+    dimension where it splits into whole (8, 128) tiles a data shard,
+    else the flattened leaf zero-padded at the tail and viewed
+    ``[-1, nd·128]`` — PartitionSpec (None, 'data'), composed with
+    'model' when the param itself shards there (each (data, model)
+    coordinate owns one column block of its model shard).  Columns,
+    not a contiguous 1/nd of the flat leaf, because the TPU compiler
+    keeps a ``psum_scatter`` along a minor dimension of whole tiles as
+    ONE reduce-scatter, and decomposes one along the major-most
+    dimension (a flat vector has no other) into an all-reduce of the
+    whole leaf and a slice (tests/test_tpu_lowering.py pins both).
 
 Everything here is a pure function of (PartitionSpec, leaf) and runs
 either inside ``shard_map`` (the collective forms) or as host-side
-shape math.  The padding rows are zeros at init and STAY zero under
+shape math.  The padding elements are zeros at init and STAY zero under
 every supported optimizer (zero grads in, zero updates out — see
 optimizer.ZEROS_INIT_OPTIMIZERS), which is what makes dropping and
 re-creating them across a checkpoint round-trip exact.
@@ -30,6 +37,8 @@ rather than a model claim.  Never use a comm_off result as state.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -42,7 +51,8 @@ from dtf_tpu.runtime.mesh import DATA_AXIS, MODEL_AXIS, SEQ_AXIS
 class Replicated:
     """Canonical-spec sentinel for leaves that are genuinely replicated
     in BOTH layouts (the optimizer step count): distinguishes them from
-    replicated *params*, whose ZeRO slice is a flat ('data',) buffer."""
+    replicated *params*, whose ZeRO slice is a (None, 'data') column
+    block."""
 
 
 REP = Replicated()
@@ -61,19 +71,63 @@ def zero_leaf_spec(spec):
     if DATA_AXIS in axes:
         return spec
     if MODEL_AXIS in axes:
-        return P((DATA_AXIS, MODEL_AXIS))
-    return P(DATA_AXIS)
+        return P(None, (DATA_AXIS, MODEL_AXIS))
+    return P(None, DATA_AXIS)
 
 
-def pad_flat(p, nd: int):
-    """Flatten and zero-pad to a multiple of ``nd`` (the slice grid);
-    padding lives at the tail and is trimmed off after gather."""
-    flat = p.reshape(-1)
-    k = -(-flat.size // nd)
-    pad = nd * k - flat.size
+# one f32 vreg tile: a data shard's column block is whole tiles of it
+SUBLANES, LANES = 8, 128
+
+
+def whole_rows(rows: int) -> int:
+    """``rows`` rounded up to q·2^k with q <= 128 and 2^k >= 8 (under
+    1.6 % more): row counts in eights, with no large prime factor — the
+    TPU compiler gives up on a reduce-scatter whose row count has one
+    (8·509 rows: an all-reduce and a slice again; 8·251: kept)."""
+    unit = SUBLANES
+    while unit * 128 < rows:
+        unit *= 2
+    return -(-rows // unit) * unit
+
+
+def slice_view(shape, nd: int) -> tuple:
+    """(rows, cols) of the 2-D view a local leaf of ``shape`` is sliced
+    in; a data shard owns ``cols // nd`` columns of every row.  The
+    leaf's own last dimension where ``nd·128`` divides it and the rows
+    above it are ``whole_rows`` (no padding, and the gather rebuilds
+    the leaf without a relayout); else the flat leaf padded to
+    ``whole_rows`` of ``nd·128``."""
+    size = math.prod(shape)
+    tile = nd * LANES
+    if len(shape) >= 2 and shape[-1] % tile == 0:
+        rows = size // shape[-1]
+        if rows == whole_rows(rows):
+            return rows, shape[-1]
+    return whole_rows(-(-size // tile)), tile
+
+
+def slice_shape(shape, nd: int) -> tuple:
+    """Shape of one data shard's ZeRO slice of a local leaf."""
+    rows, cols = slice_view(shape, nd)
+    return rows, cols // nd
+
+
+def as_view(p, nd: int):
+    """A local leaf in its ``slice_view``; the zero padding, where the
+    view needs any, lives at the flat tail and is trimmed off after
+    gather."""
+    rows, cols = slice_view(p.shape, nd)
+    pad = rows * cols - p.size
     if pad:
-        flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
-    return flat
+        p = jnp.concatenate([p.reshape(-1), jnp.zeros((pad,), p.dtype)])
+    return p.reshape(rows, cols)
+
+
+def own_columns(view, nd: int, idx):
+    """Data shard ``idx``'s column block of a leaf's view — the
+    elements ``psum_scatter`` along dim 1 delivers there."""
+    k = view.shape[1] // nd
+    return lax.dynamic_slice_in_dim(view, idx * k, k, axis=1)
 
 
 def local_shape(spec, shape, mesh_shape) -> tuple:
@@ -100,9 +154,7 @@ def slice_leaf(spec, p, nd: int, idx):
         return p
     if DATA_AXIS in spec_axes(spec):
         return p
-    flat = pad_flat(p, nd)
-    k = flat.shape[0] // nd
-    return lax.dynamic_slice_in_dim(flat, idx * k, k)
+    return own_columns(as_view(p, nd), nd, idx)
 
 
 def gather_leaf(spec, s, shape, dtype, nd: int, comm_off: bool = False):
@@ -114,13 +166,13 @@ def gather_leaf(spec, s, shape, dtype, nd: int, comm_off: bool = False):
     if DATA_AXIS in spec_axes(spec):
         return s.astype(dtype)
     if comm_off:
-        full = jnp.tile(s, nd)        # shape-right stand-in, no wire
+        full = jnp.tile(s, (1, nd))   # shape-right stand-in, no wire
     else:
-        full = lax.all_gather(s, DATA_AXIS, axis=0, tiled=True)
-    size = 1
-    for d in shape:
-        size *= d
-    return full[:size].reshape(shape).astype(dtype)
+        full = lax.all_gather(s, DATA_AXIS, axis=1, tiled=True)
+    size = math.prod(shape)
+    if full.size != size:
+        full = full.reshape(-1)[:size]
+    return full.reshape(shape).astype(dtype)
 
 
 def scatter_leaf(spec, g, nd: int, reduce_axes, mesh_shape,
@@ -150,12 +202,10 @@ def scatter_leaf(spec, g, nd: int, reduce_axes, mesh_shape,
             if a in sharded:
                 denom *= mesh_shape[a]
         return (g / denom).astype(jnp.float32)
-    flat = pad_flat(g.astype(wire), nd)
+    view = as_view(g.astype(wire), nd)
     if comm_off:
-        k = flat.shape[0] // nd
-        return (lax.dynamic_slice_in_dim(flat, idx * k, k)
-                .astype(jnp.float32) / nd)
-    s = lax.psum_scatter(flat, DATA_AXIS, scatter_dimension=0,
+        return own_columns(view, nd, idx).astype(jnp.float32) / nd
+    s = lax.psum_scatter(view, DATA_AXIS, scatter_dimension=1,
                          tiled=True).astype(jnp.float32) / nd
     return lax.pmean(s, SEQ_AXIS)
 
@@ -165,8 +215,7 @@ def slice_zeros(spec, p, nd: int):
     ``p`` — the stage-2 sharded grad-accumulation carry."""
     if not isinstance(spec, Replicated) and DATA_AXIS in spec_axes(spec):
         return jnp.zeros(p.shape, jnp.float32)
-    k = -(-p.size // nd)
-    return jnp.zeros((k,), jnp.float32)
+    return jnp.zeros(slice_shape(p.shape, nd), jnp.float32)
 
 
 def tree_map_specs(fn, specs, *trees):

@@ -90,7 +90,7 @@ def test_resume_preserves_tensor_parallel_sharding(tmp_path, eight_devices):
 
 @pytest.mark.slow
 def test_resume_zero_tp_composed(tmp_path, eight_devices):
-    """ZeRO×TP: flat ('data','model')-sliced optimizer state and
+    """ZeRO×TP: ('data','model')-column-sliced optimizer state and
     TP-sharded params round-trip through save+resume with their
     shardings intact."""
     import dtf_tpu.data.base as db
@@ -115,6 +115,72 @@ def test_resume_zero_tp_composed(tmp_path, eight_devices):
         # sliced opt state + TP params, then trains 2 more steps
         s2 = run(Config(**base, train_steps=4, resume=True))
         assert np.isfinite(s1["loss"]) and np.isfinite(s2["loss"])
+
+
+def test_zero3_checkpoint_written_under_the_flat_layout_resumes(
+        tmp_path, eight_devices):
+    """A ZeRO-3 checkpoint recorded from the parent of PR 35, whose live
+    slices were contiguous 1/nd runs of the flattened leaf
+    (tests/data/zero3_ckpt_flat_layout.py wrote it).  On disk it is the
+    canonical stage-0 form, which the slice layout never touches: the
+    column-sliced tree restores it to bit-equal parameters and optimizer
+    state (through ``staged_state`` and back), and trains on to the
+    parent's own loss at step 4."""
+    import functools
+    import json
+    import tarfile
+    from unittest import mock
+    import dtf_tpu.data.base as db
+    from dtf_tpu.models import registry
+    from dtf_tpu.models.transformer import TransformerLM
+    from dtf_tpu.train.checkpoint import load_train_checkpoint
+    here = os.path.dirname(os.path.abspath(__file__))
+    with tarfile.open(os.path.join(
+            here, "data", "zero3_ckpt_flat_layout.tar.gz")) as tar:
+        tar.extractall(tmp_path, filter="data")
+    with open(tmp_path / "parent_losses.json") as f:
+        parent = json.load(f)
+    stored = load_train_checkpoint(str(tmp_path))["params"]
+    lm_tiny = dataclasses.replace(db.LM, num_classes=64, seq_len=16,
+                                  num_train=64, num_eval=16)
+    tiny = functools.partial(TransformerLM, num_layers=1, d_model=16,
+                             num_heads=2, d_ff=32, max_seq_len=16,
+                             use_pallas=False)
+    base = dict(model="transformer", dataset="lm", batch_size=8,
+                use_synthetic_data=True, skip_eval=True, log_steps=1,
+                optimizer="adamw", num_devices=4, zero_stage=3,
+                distribution_strategy="mirrored", checkpoint_steps=2,
+                seed=7, model_dir=str(tmp_path))
+    with mock.patch.dict(db._SPECS, {"lm": lm_tiny}), \
+         mock.patch.dict(registry._REGISTRY,
+                         {"transformer": (tiny, 64, 0.0)}):
+        cfg = Config(**base, train_steps=4, resume=True)
+        rt = initialize(cfg)
+        rt.shard_seq = True
+        trainer = Trainer(cfg, rt, tiny(vocab_size=64), 0.0, lm_tiny)
+        tokens = np.zeros((8, 16), np.int32)
+        trainer.init_state(jax.random.key(7), (tokens, tokens))
+        ckpt = Checkpointer(str(tmp_path))
+        canon = ckpt.restore(trainer.canonical_template())
+        ckpt.close()
+        assert int(canon.step) == 2
+        staged = trainer.staged_state(canon)
+        for leaf in jax.tree_util.tree_leaves(staged.params):
+            assert leaf.ndim == 2                    # this tree's slices
+        back = jax.device_get(trainer.canonical_state(staged))
+        want = dict(jax.tree_util.tree_leaves_with_path(stored))
+        got = dict(jax.tree_util.tree_leaves_with_path(back.params))
+        assert set(got) == set(want) and len(got) >= 10
+        for path, a in want.items():
+            np.testing.assert_array_equal(
+                np.asarray(got[path]), np.asarray(a),
+                err_msg=jax.tree_util.keystr(path))
+        for a, b in zip(jax.tree_util.tree_leaves(jax.device_get(canon)),
+                        jax.tree_util.tree_leaves(back)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        out = run(cfg)
+    np.testing.assert_allclose(out["loss"], parent["loss_step4"], rtol=1e-5)
+    assert out["loss"] < parent["loss_step2"]
 
 
 def test_restore_none_when_empty(tmp_path):

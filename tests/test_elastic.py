@@ -281,8 +281,8 @@ def test_grow_back_on_reannounce(tmp_path):
 
 def test_check_reshardable_units():
     """Expert (data-sharded) leaves need the new dp to divide their
-    expert dim; TP leaves their model dim; replicated and ZeRO-flat
-    leaves always reshard (pad_flat pads to ANY nd)."""
+    expert dim; TP leaves their model dim; replicated and ZeRO-sliced
+    leaves always reshard (as_view pads to ANY nd's tile grid)."""
     sds = jax.ShapeDtypeStruct
     pspecs = {"expert": P("data"), "tp": P(None, "model"),
               "rep": P(), "sent": zero_lib.REP}
@@ -327,19 +327,72 @@ def test_zero3_reshard_across_non_dividing_dp(eight_devices):
     state from an nd=4 mesh re-slices onto nd=3 — a dp that divides
     almost NO leaf size, so every pad row is exercised — and the
     canonical form read back from the nd=3 layout is BIT-identical
-    (pad rows provably stay zero)."""
+    (the padding provably stays zero)."""
     t4, _, s4, _ = _zero3_trainer(4)
     canon = jax.device_get(t4.canonical_state(s4))
     t3, rt3, _, batch = _zero3_trainer(3)
     staged = t3.staged_state(canon)
     for leaf in jax.tree_util.tree_leaves(staged.params):
-        assert leaf.ndim == 1 and leaf.shape[0] % 3 == 0
+        assert leaf.ndim == 2 and leaf.shape[1] % (3 * 128) == 0
     back = jax.device_get(t3.canonical_state(staged))
     for a, b in zip(jax.tree_util.tree_leaves(canon),
                     jax.tree_util.tree_leaves(back)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     # and the resharded state trains
     state, metrics = t3.train_step(staged, *rt3.shard_batch(batch))
+    assert np.isfinite(float(jax.device_get(metrics["loss"])))
+
+
+def test_zero3_reshard_4_to_2_to_4_round_trips(eight_devices):
+    """Shrink and grow back: the column-slice view depends on nd (a
+    leaf is padded to whole rows of nd x 128), so a state from nd=4
+    re-slices onto nd=2 into other shapes — and back onto nd=4 into the
+    very slices it left, bit for bit; the canonical form is the same at
+    every hop and the shrunken state trains."""
+    import flax.linen as nn
+
+    class MLP(nn.Module):
+        # [192, 512]: leaf-shaped at both nd; [512, 24], [24, 10], the
+        # biases: flat views whose padding differs with nd
+        @nn.compact
+        def __call__(self, x, train=False):
+            x = nn.relu(nn.Dense(512)(x.reshape((x.shape[0], -1))))
+            return nn.Dense(10)(nn.relu(nn.Dense(24)(x)))
+
+    def trainer_at(nd):
+        cfg = Config(model="resnet20", dataset="cifar10", batch_size=8,
+                     train_steps=1, use_synthetic_data=True, skip_eval=True,
+                     model_dir="", skip_checkpoint=True, log_steps=1,
+                     distribution_strategy="mirrored", num_devices=nd,
+                     zero_stage=3)
+        rt = initialize(cfg)
+        trainer = Trainer(cfg, rt, MLP(), 0.0, TINY, schedule=lambda s: 0.1)
+        rng = np.random.default_rng(0)
+        batch = (rng.normal(0, 1, (8, 8, 8, 3)).astype(np.float32),
+                 rng.integers(0, 10, (8,)).astype(np.int32))
+        state = trainer.init_state(jax.random.key(0), batch)
+        return trainer, rt, state, batch
+
+    def same(a, b):
+        la, lb = (jax.tree_util.tree_leaves(jax.device_get(t))
+                  for t in (a, b))
+        assert len(la) == len(lb)
+        for x, y in zip(la, lb):
+            assert x.shape == y.shape
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+    t4, rt4, s4, batch = trainer_at(4)
+    s4, _ = t4.train_step(s4, *rt4.shard_batch(batch))   # momentum != 0
+    canon4 = jax.device_get(t4.canonical_state(s4))
+    t2, rt2, _, _ = trainer_at(2)
+    s2 = t2.staged_state(canon4)
+    shapes4 = [x.shape for x in jax.tree_util.tree_leaves(s4.params)]
+    shapes2 = [x.shape for x in jax.tree_util.tree_leaves(s2.params)]
+    assert shapes2 != shapes4                   # the view moved with nd
+    canon2 = jax.device_get(t2.canonical_state(s2))
+    same(canon2, canon4)
+    same(t4.staged_state(canon2), s4)           # and back: the same slices
+    _, metrics = t2.train_step(s2, *rt2.shard_batch(batch))
     assert np.isfinite(float(jax.device_get(metrics["loss"])))
 
 

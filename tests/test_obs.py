@@ -660,6 +660,115 @@ def test_ledger_mfu_crosschecked_against_cost_analysis(tmp_path,
     assert s["count"] == 1 and s["mfu"] == mfu_ledger
 
 
+# the optimized-HLO lines ``collectives`` reads, as the TPU's compiler and
+# the CPU's print them: a plain op, a tuple-shaped combined one, an async
+# pair (counted once, at its start), and lines that only look alike
+_HLO = """
+HloModule jit_step, entry_computation_layout={(f32[8,128]{1,0})->f32[8,128]{1,0}}
+
+%region_0.1 (a: f32[], b: f32[]) -> f32[] {
+  ROOT %add.1 = f32[] add(%a, %b)
+}
+
+ENTRY %main.1 (p0: f32[8,128], p1: bf16[4,256]) -> f32[8,128] {
+  %p0 = f32[8,128]{1,0:T(8,128)} parameter(0)
+  %p1 = bf16[4,256]{1,0:T(4,128)(2,1)} parameter(1)
+  %div.709 = f32[]{:T(128)} constant(1)
+  %div.711 = f32[]{:T(128)} constant(2)
+  %rs.1 = f32[8,32]{1,0:T(8,128)S(1)} reduce-scatter(%p0), channel_id=1, replica_groups={{0,1,2,3}}, dimensions={1}, to_apply=%region_0.1
+  %all-reduce.21 = (f32[]{:T(128)}, f32[]{:T(128)}) all-reduce(%div.709, %div.711), channel_id=2, replica_groups={{0,1,2,3}}, to_apply=%region_0.1
+  %ags.1 = (bf16[4,256]{1,0}, bf16[4,1024]{1,0}) all-gather-start(%p1), channel_id=3, dimensions={1}
+  %agd.1 = bf16[4,1024]{1,0} all-gather-done(%ags.1)
+  %ag.2 = bf16[4,1024]{1,0:T(4,128)(2,1)} all-gather(bf16[4,256]{1,0} %p1), channel_id=4, dimensions={1}
+  %ds.1 = f32[8,32]{1,0} dynamic-slice(%p0, %div.709, %div.709), dynamic_slice_sizes={8,32}
+  ROOT %fusion.1 = f32[8,128]{1,0} fusion(%rs.1, %agd.1), kind=kLoop, calls=%region_0.1, metadata={op_name="jit(step)/all-reduce(not one)"}
+}
+"""
+
+
+def test_ledger_counts_the_collectives_the_compiler_left(caplog):
+    """``collectives`` reads call sites and operand bytes off the optimized
+    HLO — one ``reduce-scatter`` of a 4 KiB operand, one ``all-reduce`` of
+    two scalars, two ``all-gather`` (an async pair once) of 2 KiB each —
+    and ``register`` puts them on the entry, the trace event and ONE log
+    line at compile."""
+    import logging
+    from dtf_tpu.obs.ledger import Ledger, collectives
+    from dtf_tpu.obs.registry import MetricsRegistry
+
+    class Compiled:
+        def as_text(self):
+            return _HLO
+
+        def cost_analysis(self):
+            return {"flops": 7.0, "bytes accessed": 9.0}
+
+    want = {"reduce-scatter": {"ops": 1, "bytes": 8 * 128 * 4},
+            "all-reduce": {"ops": 1, "bytes": 8},
+            "all-gather": {"ops": 2, "bytes": 2 * 4 * 256 * 2}}
+    assert collectives(Compiled()) == want
+    ledger = Ledger(MetricsRegistry())
+    with caplog.at_level(logging.INFO, logger="dtf_tpu"):
+        ledger.register("train_step", compiled=Compiled())
+    lines = [r.getMessage() for r in caplog.records
+             if "train_step compiled" in r.getMessage()]
+    assert len(lines) == 1
+    assert "1 reduce-scatter (4096 B), 1 all-reduce (8 B), " \
+           "2 all-gather (4096 B)" in lines[0]
+    assert ledger.summary()["train_step"]["collectives"] == want
+
+
+@pytest.mark.parametrize("stage", [0, 3])
+def test_ledger_collectives_of_a_compiled_toy_step(tmp_path, capsys, stage,
+                                                   eight_devices):
+    """The real thing on four virtual devices: the plain data-parallel
+    step all-reduces its gradients and holds no other collective; the
+    ZeRO-3 step gathers every leaf and all-reduces no gradient — and the
+    counts ride the ``ledger_exec`` trace event to ``trace_main --ledger
+    --json``."""
+    import jax
+    from dtf_tpu.models import build_model
+    from dtf_tpu.obs.ledger import Ledger
+    from dtf_tpu.obs.registry import MetricsRegistry
+    from dtf_tpu.runtime import initialize
+    from dtf_tpu.train import Trainer
+
+    cfg = base_cfg(train_steps=2, batch_size=8, num_devices=4,
+                   distribution_strategy="mirrored", zero_stage=stage)
+    rt = initialize(cfg)
+    model, l2 = build_model("resnet20", num_classes=10)
+    trainer = Trainer(cfg, rt, model, l2, TINY)
+    images = np.zeros((8, 8, 8, 3), np.float32)
+    labels = np.zeros((8,), np.int32)
+    state = trainer.init_state(jax.random.key(0), (images, labels))
+    n_leaves = len(jax.tree_util.tree_leaves(
+        trainer.canonical_state(state).params))
+    compiled = trainer.train_step.lower(
+        state, *rt.shard_batch((images, labels))).compile()
+    trace.configure(str(tmp_path))
+    Ledger(MetricsRegistry()).register("train_step", compiled=compiled)
+    trace.disable()
+    recs = trace.read_records(str(tmp_path / "trace_rank0.jsonl"))
+    [ev] = [r for r in recs if r.get("name") == "ledger_exec"]
+    got = ev["collectives"]
+    param_bytes = sum(
+        int(np.prod(p.shape)) * 4 for p in jax.tree_util.tree_leaves(
+            trainer.canonical_state(state).params))
+    if stage == 0:
+        assert got["all-gather"]["ops"] == got["reduce-scatter"]["ops"] == 0
+        assert got["all-reduce"]["bytes"] >= param_bytes
+    else:
+        assert got["all-gather"]["ops"] >= n_leaves
+        # the CPU's compiler may keep the scatter or decompose it: every
+        # gradient crosses in one or the other
+        assert (got["reduce-scatter"]["bytes"]
+                + got["all-reduce"]["bytes"]) >= param_bytes
+    capsys.readouterr()
+    assert trace_main([str(tmp_path), "--ledger", "--json"]) == 0
+    [row] = json.loads(capsys.readouterr().out)["ledger"]
+    assert row["exec"] == "train_step" and row["collectives"] == got
+
+
 @pytest.mark.slow  # near-twin of test_traced_smoke_train_reconciles_step_spans (tier-1)
 def test_traced_run_carries_run_trace_and_ledger(tmp_path, monkeypatch):
     """E2E: a traced smoke run's records all share ONE run-scoped

@@ -21,6 +21,7 @@ backward switch.
 
 import functools
 import importlib
+import math
 import re
 
 import jax
@@ -83,16 +84,21 @@ def test_paged_attention_reads_the_pool_as_stored(heads, batch, s):
 
 
 @pytest.fixture(scope="module")
-def v5e():
-    """One described (not attached) v5e chip to compile for."""
+def v5e_2x2():
+    """A described (not attached) host of four v5e chips."""
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def v5e(v5e_2x2):
+    """One of its chips to compile for."""
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 @pytest.mark.parametrize("heads,batch,s,pool", [
@@ -430,3 +436,113 @@ def test_dense_decode_body_compiles_for_v5e(v5e):
     assert compiled.as_text().count("paged_flash_decode") >= 24
     # the pools are donated and updated in place: no second pool exists
     assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9
+
+
+# ---------------------------------------------------------------------------
+# ZeRO's gradient scatter across the four chips (train/zero.py): what the
+# TPU compiler leaves of a ``psum_scatter`` depends on the operand's view
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def data4(v5e_2x2):
+    from dtf_tpu.runtime.mesh import make_mesh
+    return make_mesh(v5e_2x2.devices, data=4)
+
+
+def _compiled_collectives(compiled):
+    from dtf_tpu.obs.ledger import collectives
+    return {op: c for op, c in collectives(compiled).items() if c["ops"]}
+
+
+@pytest.mark.parametrize("shape,dim,kept", [
+    ((2048 * 8192,), 0, False),        # flat: what scatter_leaf did before
+    ((2048 * 8192 // 512, 512), 1, True),      # [rows, nd x 128]
+    ((2048, 8192), 1, True),           # leaf-shaped (fc1)
+    ((8192, 2048), 1, True),           # leaf-shaped (fc2)
+    ((8, 512), 1, True),               # a LayerNorm scale in its view
+    ((8 * 509, 512), 1, False),        # a large prime among the rows
+    ((4096, 512), 1, True),            # ... rounded by whole_rows
+    ((202752, 512), 1, True),          # the embedding and the head (415 MB)
+], ids=["flat", "rows_nd128", "leaf_fc1", "leaf_fc2", "tiny", "rows_8x509",
+        "rows_whole", "head"])
+def test_psum_scatter_survives_as_a_reduce_scatter(data4, shape, dim, kept):
+    """The rule ``zero.slice_view`` is built on, row by row: a scatter
+    along the major-most dimension (all a flat vector has) is decomposed
+    into an all-reduce of the whole operand and a slice; one along a minor
+    dimension of whole lane tiles, over ``whole_rows``, stays ONE
+    reduce-scatter.  A jax/libtpu that changes the rule fails here."""
+    from jax import lax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from dtf_tpu.train import zero as zero_lib
+    if kept:
+        assert shape[0] == zero_lib.whole_rows(shape[0])
+    elif len(shape) == 2:
+        assert shape[0] != zero_lib.whole_rows(shape[0])
+    out_spec = P(*([None] * dim + ["data"]))
+    fn = jax.shard_map(
+        lambda g: lax.psum_scatter(g[0], "data", scatter_dimension=dim,
+                                   tiled=True),
+        mesh=data4, in_specs=P("data"), out_specs=out_spec, check_vma=False)
+    arg = jax.ShapeDtypeStruct((4,) + shape, jnp.float32,
+                               sharding=NamedSharding(data4, P("data")))
+    got = _compiled_collectives(jax.jit(fn).lower(arg).compile())
+    nbytes = 4 * math.prod(shape)
+    want = "reduce-scatter" if kept else "all-reduce"
+    assert got == {want: {"ops": 1, "bytes": nbytes}}, got
+
+
+def test_zero3_step_scatters_every_leaf_and_all_reduces_none(data4):
+    """The whole ZeRO-3 train step of a toy ``transformer`` (matrices of
+    1 to 4 MB, bf16 compute, AdamW, the flash kernels: the x4 cell's)
+    compiled for the four chips: one reduce-scatter a sliced leaf, their
+    operands the f32 gradients leaf for leaf, and no all-reduce but the
+    scalars' (loss, metrics)."""
+    import dataclasses
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from dtf_tpu.config import Config
+    from dtf_tpu.data import get_dataset_spec
+    from dtf_tpu.models import build_model
+    from dtf_tpu.runtime.mesh import MeshRuntime
+    from dtf_tpu.train import Trainer
+    vocab, seq, batch = 1024, 256, 8
+    cfg = Config(model="transformer", dataset="lm", batch_size=batch,
+                 seq_len=seq, use_synthetic_data=True, skip_eval=True,
+                 skip_checkpoint=True, model_dir="", optimizer="adamw",
+                 dtype="bf16", zero_stage=3, num_devices=4,
+                 distribution_strategy="mirrored")
+    spec = dataclasses.replace(get_dataset_spec("lm"), num_classes=vocab,
+                               seq_len=seq)
+    model, l2 = build_model("transformer", num_classes=vocab,
+                            dtype=cfg.compute_dtype, num_layers=2,
+                            d_model=512, num_heads=4, d_ff=2048,
+                            max_seq_len=seq, use_pallas=True)
+    trainer = Trainer(cfg, MeshRuntime(mesh=data4, strategy="mirrored",
+                                       shard_seq=True), model, l2, spec)
+    tokens = np.zeros((batch, seq), np.int32)
+    # nothing can be placed on a described chip: shapes only
+    state = jax.eval_shape(
+        lambda key: trainer.init_state(key, (tokens, tokens)),
+        jax.random.key(0))
+    on = lambda sds, spec: jax.ShapeDtypeStruct(
+        sds.shape, sds.dtype, sharding=NamedSharding(data4, spec))
+    state = jax.tree_util.tree_map(
+        lambda spec, sds: on(sds, spec), trainer._state_specs, state,
+        is_leaf=lambda x: isinstance(x, P))
+    batch_sds = on(jax.ShapeDtypeStruct(tokens.shape, jnp.int32),
+                   P("data", "seq"))
+    compiled = trainer.train_step.lower(state, batch_sds,
+                                        batch_sds).compile()
+    assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
+    leaves = jax.tree_util.tree_leaves(trainer._zero_local_sds)
+    matrices = [sds for sds in leaves if sds.size * 4 >= 1 << 20]
+    assert len(matrices) >= 10
+    got = _compiled_collectives(compiled)
+    assert got["reduce-scatter"]["ops"] == len(leaves), got
+    # every gradient crosses once, in f32, padding included
+    from dtf_tpu.train import zero as zero_lib
+    padded = sum(4 * math.prod(zero_lib.slice_view(sds.shape, 4))
+                 for sds in leaves)
+    assert got["reduce-scatter"]["bytes"] == padded, got
+    assert got.get("all-reduce", {"bytes": 0})["bytes"] < 1024, got
+    assert got["all-gather"]["ops"] >= len(leaves), got

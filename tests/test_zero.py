@@ -83,17 +83,17 @@ def test_zero_with_clipping_matches(eight_devices):
 @pytest.mark.slow
 def test_zero_opt_state_is_sharded(eight_devices):
     """The point of the feature: optimizer slots live sliced over
-    'data' — each leaf's sharding names the data axis and its global
-    shape is the padded flat length."""
+    'data' — each leaf's sharding names the data axis on the columns
+    of its 2-D slice view."""
     s_zero, _ = _steps(zero=True, steps=1)
     leaves = jax.tree_util.tree_leaves(s_zero.opt_state)
     assert leaves, "optimizer state is empty?"
     for leaf in leaves:
         if leaf.ndim == 0:
             continue  # step counts etc. stay replicated
-        assert leaf.ndim == 1  # flat slices
-        assert leaf.sharding.spec == P(DATA_AXIS)
-        assert leaf.shape[0] % 4 == 0  # padded to the slice grid
+        assert leaf.ndim == 2  # [rows, nd·k] views
+        assert leaf.sharding.spec == P(None, DATA_AXIS)
+        assert leaf.shape[1] % (4 * 128) == 0  # whole lane tiles a shard
 
 
 TINY_LM = dataclasses.replace(data_base.LM, num_classes=64, seq_len=16,
@@ -163,9 +163,9 @@ def test_zero_tp_opt_state_shards_both_axes(tiny_transformer_registry):
                                (tokens, np.roll(tokens, -1, 1)))
     specs = {leaf.sharding.spec
              for leaf in jax.tree_util.tree_leaves(state.opt_state)
-             if leaf.ndim == 1}
-    assert P((DATA_AXIS, "model")) in specs  # TP leaves
-    assert P(DATA_AXIS) in specs  # replicated leaves
+             if leaf.ndim == 2}
+    assert P(None, (DATA_AXIS, "model")) in specs  # TP leaves
+    assert P(None, DATA_AXIS) in specs  # replicated leaves
     # and the composed step runs
     batch = rt.shard_batch((tokens, np.roll(tokens, -1, 1)))
     state, metrics = trainer.train_step(state, *batch)

@@ -14,6 +14,7 @@ restore with full-shaped params.
 
 import dataclasses
 
+import flax.linen as nn
 import jax
 import numpy as np
 import pytest
@@ -72,15 +73,16 @@ def _trainer(stage, num_devices=4):
 
 
 def test_zero3_params_are_sliced_and_canonical_roundtrips(eight_devices):
-    """The point of stage 3: params live as 1/nd flat slices over
-    'data'; the canonical conversion re-gathers full shapes and the
-    staged inverse reproduces the slices BIT-identically (what makes
-    the checkpoint matrix exact)."""
+    """The point of stage 3: params live as 1/nd column blocks of a
+    2-D view over 'data'; the canonical conversion re-gathers full
+    shapes and the staged inverse reproduces the slices BIT-identically
+    (what makes the checkpoint matrix exact)."""
     trainer, rt, state, batch = _trainer(3)
     for leaf in jax.tree_util.tree_leaves(state.params):
-        assert leaf.ndim == 1                       # flat slices
-        assert leaf.sharding.spec == P(DATA_AXIS)
-        assert leaf.shape[0] % 4 == 0               # padded to nd
+        assert leaf.ndim == 2                       # [rows, nd·k] views
+        assert leaf.sharding.spec == P(None, DATA_AXIS)
+        assert leaf.shape[0] % 8 == 0               # whole (8, 128)
+        assert leaf.shape[1] % (4 * 128) == 0       # tiles a data shard
     canon = trainer.canonical_state(state)
     # canonical params are the MODEL's shapes (conv kernels are 4-D)
     dims = {leaf.ndim
@@ -276,7 +278,7 @@ ZERO_WIRE_LOSS_RTOL = 5e-2
 @pytest.mark.slow  # long tolerance run; bf16-wire validation units stay tier-1
 def test_zero_wire_bf16_tracks_f32_within_tolerance(eight_devices):
     """--zero_wire bf16 halves the stage-2/3 scatter volume by casting
-    the padded flat grads to bf16 BEFORE psum_scatter (the slices and
+    the gradients' slice views to bf16 BEFORE psum_scatter (the slices and
     the cross-microbatch accumulation stay f32).  The trajectories must
     agree within the documented tolerance — and the wire dtype must
     actually reach the scatter (the trainer records it)."""
@@ -303,3 +305,173 @@ def test_zero_wire_bf16_tracks_f32_within_tolerance(eight_devices):
     f32 = losses("fp32")
     bf16 = losses("bf16")
     np.testing.assert_allclose(bf16, f32, rtol=ZERO_WIRE_LOSS_RTOL)
+
+
+# ---------------------------------------------------------------------------
+# the slice layout itself (train/zero.py): a leaf is sliced by COLUMNS of a
+# 2-D view of whole (8, 128) tiles a shard, so the scatter is one the TPU
+# compiler keeps (tests/test_tpu_lowering.py pins that half)
+# ---------------------------------------------------------------------------
+
+# leaf-shaped at every nd here / at nd <= 2 only / never (tail-padded flat
+# view): last dims that are and are not multiples of nd x 128, rows that do
+# and do not come in eights, more than two dims, one dim, tiny
+LEAF_SHAPES = [(16, 1024), (8, 256), (24, 128), (10, 7), (3, 3, 16, 512),
+               (3, 512), (5,), (4099,), (2, 4096), (131, 4001), (1032, 512)]
+
+
+def test_whole_rows_have_no_large_prime_factor():
+    """What ``whole_rows`` is for: row counts in eights whose odd part
+    is at most 128, at under 1.6 % (or 7 rows) of padding."""
+    from dtf_tpu.train.zero import whole_rows
+    for rows in (0, 1, 8, 9, 1024, 1025, 4072, 50257, 201028, 8 * 25129):
+        got = whole_rows(rows)
+        assert got >= rows and got % 8 == 0
+        assert got - rows < max(8, rows / 64)
+        odd = got
+        while odd and odd % 2 == 0:
+            odd //= 2
+        assert odd <= 128, (rows, got)
+        assert whole_rows(got) == got
+
+
+def _data_mesh(devices, nd):
+    from dtf_tpu.runtime.mesh import make_mesh
+    return make_mesh(devices[:nd], data=nd)
+
+
+@pytest.mark.parametrize("nd", [1, 2, 4])
+@pytest.mark.parametrize("shape", LEAF_SHAPES, ids=str)
+def test_slice_then_gather_is_the_identity(eight_devices, nd, shape):
+    """``gather_leaf ∘ slice_leaf`` rebuilds the leaf bit for bit (the
+    padding, where the view needs any, is trimmed), the nd slices
+    together are the leaf's ``as_view``, and ``slice_zeros`` has the
+    slice's shape."""
+    import jax.numpy as jnp
+    from jax import lax
+    from dtf_tpu.train import zero as zero_lib
+    mesh = _data_mesh(eight_devices, nd)
+    p = np.random.default_rng(0).normal(size=shape).astype(np.float32)
+    rows, cols = zero_lib.slice_view(shape, nd)
+    assert rows == zero_lib.whole_rows(rows) and cols % (nd * 128) == 0
+    assert rows * cols - p.size < 8 * cols + rows * cols // 64
+    above = p.size // shape[-1]
+    if len(shape) >= 2 and shape[-1] % (nd * 128) == 0 \
+            and above == zero_lib.whole_rows(above):
+        assert (rows, cols) == (above, shape[-1])           # leaf-shaped
+    else:
+        assert cols == nd * 128                             # flat, padded
+
+    def local(p):
+        s = zero_lib.slice_leaf(P(), p, nd, lax.axis_index(DATA_AXIS))
+        assert s.shape == zero_lib.slice_shape(shape, nd)
+        assert s.shape == zero_lib.slice_zeros(P(), p, nd).shape
+        return s, zero_lib.gather_leaf(P(), s, shape, jnp.float32, nd)
+
+    slices, back = jax.jit(jax.shard_map(
+        local, mesh=mesh, in_specs=(P(),),
+        out_specs=(zero_lib.zero_leaf_spec(P()), P()),
+        check_vma=False))(p)
+    np.testing.assert_array_equal(np.asarray(back), p)
+    np.testing.assert_array_equal(np.asarray(slices),
+                                  np.asarray(zero_lib.as_view(p, nd)))
+
+
+@pytest.mark.parametrize("wire", ["fp32", "bf16"])
+@pytest.mark.parametrize("nd", [1, 2, 4])
+@pytest.mark.parametrize("shape", [(16, 1024), (10, 7), (4099,)], ids=str)
+def test_scatter_is_the_mean_and_comm_off_cuts_the_same_elements(
+        eight_devices, nd, shape, wire):
+    """``scatter_leaf`` hands each data shard its column block of the
+    MEAN of the shards' gradients, as f32 whatever the wire; the
+    ``comm_off`` probe arm cuts the very same elements out of the
+    shard's own gradient (times 1/nd)."""
+    import jax.numpy as jnp
+    from dtf_tpu.runtime.mesh import SEQ_AXIS
+    from dtf_tpu.train import zero as zero_lib
+    from jax import lax
+    mesh = _data_mesh(eight_devices, nd)
+    wire_dt = jnp.bfloat16 if wire == "bf16" else jnp.float32
+    g = np.random.default_rng(1).normal(size=(nd,) + shape).astype(np.float32)
+
+    def local(g, comm_off):
+        return zero_lib.scatter_leaf(
+            P(), g[0], nd, (DATA_AXIS, SEQ_AXIS), dict(mesh.shape),
+            comm_off, lax.axis_index(DATA_AXIS), wire=wire_dt)
+
+    def scattered(g, comm_off):
+        out = jax.jit(jax.shard_map(
+            lambda g: local(g, comm_off), mesh=mesh,
+            in_specs=(P(DATA_AXIS),),
+            out_specs=zero_lib.zero_leaf_spec(P()), check_vma=False))(g)
+        assert out.dtype == jnp.float32
+        return np.asarray(out)
+
+    want = np.asarray(zero_lib.as_view(g.mean(0), nd))
+    tol = dict(rtol=2e-2, atol=2e-2) if wire == "bf16" else dict(
+        rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(scattered(g, False), want, **tol)
+    # every shard holding the SAME gradient: the mean is that gradient,
+    # and the stub's elements are exactly the scatter's, over nd
+    same = np.broadcast_to(g[:1], g.shape)
+    np.testing.assert_allclose(scattered(same, True) * nd,
+                               scattered(same, False), rtol=1e-6, atol=1e-6)
+
+
+class _MLP(nn.Module):
+    """Leaves of every kind the layout knows: [192, 512] leaf-shaped at
+    nd <= 4, [512, 24] flat and tail-padded, [24, 10] and the biases
+    tiny."""
+
+    @nn.compact
+    def __call__(self, x, train=False):
+        x = x.reshape((x.shape[0], -1))
+        x = nn.relu(nn.Dense(512)(x))
+        x = nn.relu(nn.Dense(24)(x))
+        return nn.Dense(10)(x)
+
+
+def _mlp_steps(stage, nd, accum, wire, steps=3):
+    cfg = _cfg("", stage, steps, checkpoint_steps=0, skip_checkpoint=True)
+    cfg = cfg.replace(num_devices=nd, grad_accum_steps=accum,
+                      zero_wire=wire if stage >= 2 else "fp32")
+    rt = initialize(cfg)
+    trainer = Trainer(cfg, rt, _MLP(), 1e-4, TINY,
+                      schedule=lambda s: 0.05)
+    rng = np.random.default_rng(2)
+    images = rng.normal(0, 1, (8, 8, 8, 3)).astype(np.float32)
+    labels = rng.integers(0, 10, (8,)).astype(np.int32)
+    state = trainer.init_state(jax.random.key(0), (images, labels))
+    batch = rt.shard_batch((images, labels))
+    losses = []
+    for _ in range(steps):
+        state, m = trainer.train_step(state, *batch)
+        losses.append(float(jax.device_get(m["loss"])))
+    params = jax.device_get(trainer.canonical_state(state).params)
+    return losses, dict(jax.tree_util.tree_leaves_with_path(params))
+
+
+@pytest.mark.parametrize("nd,accum,wire", [
+    (1, 1, "fp32"), (2, 2, "fp32"), (4, 1, "fp32"), (4, 2, "fp32"),
+    (2, 1, "bf16"), (4, 2, "bf16")])
+def test_stages_match_plain_dp_on_every_leaf_kind(eight_devices, nd, accum,
+                                                  wire):
+    """Stage 0 ≡ 1 ≡ 2 ≡ 3 after three steps — losses and every
+    parameter — over leaves that are and are not multiples of nd x 128
+    (tail padding trimmed after the gather), tiny ones, with and
+    without the sliced accumulation carry; the bf16 wire (stages 2, 3)
+    inside its documented tolerance."""
+    ref_losses, ref = _mlp_steps(0, nd, accum, "fp32")
+    assert ref_losses[-1] < ref_losses[0]
+    for stage in ((2, 3) if wire == "bf16" else (1, 2, 3)):
+        losses, params = _mlp_steps(stage, nd, accum, wire)
+        if wire == "bf16":
+            np.testing.assert_allclose(losses, ref_losses,
+                                       rtol=ZERO_WIRE_LOSS_RTOL)
+            continue
+        np.testing.assert_allclose(losses, ref_losses, rtol=1e-5)
+        for path, r in ref.items():
+            np.testing.assert_allclose(
+                np.asarray(params[path]), np.asarray(r), atol=2e-6,
+                rtol=1e-5, err_msg=f"stage {stage} "
+                                   f"{jax.tree_util.keystr(path)}")
