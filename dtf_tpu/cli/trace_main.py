@@ -3,7 +3,12 @@
 Reads the ``trace_rank*.jsonl`` files a traced run wrote (train, PS,
 or serve — any subsystem emitting through dtf_tpu.obs.trace), and
 prints per-span-name timing aggregates (count, total, mean, p50/p99,
-max), event counts, and every anomaly record.
+max), event counts, and every anomaly record.  A span that keeps laps
+(the serving engine's ``serve_iteration``) also gets one line a lap name:
+its total seconds, its share of the span's, and how many spans closed it;
+and one line for each whole-number attribute such spans carry (the turn's
+counts: rows by phase, admissions, retirements, queue depth, pages in use,
+and the ordinals of its launches): spans carrying it, total, mean, min, max.
 
 Usage:
   python -m dtf_tpu.cli.trace_main <trace_dir | trace.jsonl> [...]
@@ -217,6 +222,8 @@ def print_ledger(merged: List[dict]) -> bool:
 
 def summarize(files: List[str]) -> dict:
     spans: Dict[str, Histogram] = {}
+    laps: Dict[str, Dict[str, List[float]]] = {}   # span → lap → [s, spans]
+    counts: Dict[str, Dict[str, List[int]]] = {}    # span → attribute → values
     events: CCounter = CCounter()
     anomalies: List[dict] = []
     ranks = set()
@@ -232,6 +239,18 @@ def summarize(files: List[str]) -> dict:
                 if h is None:
                     h = spans[name] = Histogram(name, unit="s")
                 h.observe(float(rec.get("dur_s", 0.0)))
+                closed = set()
+                for lap, seconds in rec.get("laps", ()):
+                    row = laps.setdefault(name, {}).setdefault(lap,
+                                                               [0.0, 0])
+                    row[0] += float(seconds)
+                    row[1] += lap not in closed
+                    closed.add(lap)
+                if "laps" in rec:
+                    for key, v in rec.items():
+                        if type(v) is int and key != "rank":
+                            counts.setdefault(name, {}).setdefault(
+                                key, []).append(v)
                 if name == "step" and "step" in rec:
                     steps.add((rec.get("rank", 0), rec["step"]))
             elif kind == "event":
@@ -251,6 +270,18 @@ def summarize(files: List[str]) -> dict:
             "mean_s": s["mean"], "p50_s": s["p50"], "p99_s": s["p99"],
             "max_s": s["max"],
         }
+        if name in laps:
+            total = span_rows[name]["total_s"]
+            span_rows[name]["laps"] = {
+                lap: {"total_s": sec, "spans": n,
+                      "share": sec / total if total else 0.0}
+                for lap, (sec, n) in sorted(laps[name].items(),
+                                            key=lambda kv: -kv[1][0])}
+        if name in counts:
+            span_rows[name]["counts"] = {
+                key: {"spans": len(v), "total": sum(v),
+                      "mean": sum(v) / len(v), "min": min(v), "max": max(v)}
+                for key, v in counts[name].items()}
     return {
         "files": files,
         "ranks": sorted(ranks, key=str),
@@ -277,6 +308,13 @@ def print_summary(summary: dict, allowed=()) -> None:
             print(f"{name:<24}{r['count']:>8}{r['total_s']:>10.3f}"
                   f"{r['mean_s']:>10.4f}{r['p50_s']:>10.4f}"
                   f"{r['p99_s']:>10.4f}{r['max_s']:>10.4f}")
+            for lap, row in r.get("laps", {}).items():
+                print(f"  {'lap ' + lap:<22}{row['spans']:>8}"
+                      f"{row['total_s']:>10.3f}{row['share']:>10.1%}")
+            for key, row in r.get("counts", {}).items():
+                print(f"  {'count ' + key:<22}{row['spans']:>8}"
+                      f"  total {row['total']}  mean {row['mean']:.2f}"
+                      f"  min {row['min']}  max {row['max']}")
     if summary["events"]:
         print("events: " + ", ".join(f"{k}×{v}"
                                      for k, v in summary["events"].items()))

@@ -6,7 +6,10 @@ record kinds:
   span    — a timed region: {"kind":"span","name":...,"ts":<start>,
             "dur_s":...,"rank":...,"parent":...,  ...attrs}
             (written when the region EXITS, so a crash mid-span leaves
-            the enclosing spans visible up to the crash point)
+            the enclosing spans visible up to the crash point).  A span
+            opened with :func:`lap_span` also keeps LAPS: "laps":
+            [[name, seconds], ...], contiguous from ``ts``, in order,
+            adding up to ``dur_s`` — see :class:`_Span`
   event   — a point-in-time marker: {"kind":"event","name":...,
             "ts":..., ...attrs} (e.g. "heartbeat")
   anomaly — an event that means the run is unhealthy: same shape with
@@ -126,30 +129,67 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def lap(self, name):
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_tracer", "name", "attrs", "t0", "span_id")
+    """A timed region.  One that KEEPS LAPS (:func:`lap_span`) also cuts
+    itself into named pieces: ``lap(name)`` says "everything since the
+    last mark, or since the start, was ``name``".  The record then
+    carries ``"laps": [[name, seconds], ...]`` — contiguous from ``ts``,
+    in the order they were closed (a name may repeat), with what is left
+    at exit closed under ``rest``, so a reader rebuilds every lap's own
+    interval from ``ts`` and the laps add up to ``dur_s``.  Such a span
+    reads ONE clock: ``ts`` is ``time.time()`` at entry as on every span,
+    the laps and ``dur_s`` are ``time.perf_counter()`` offsets from that
+    entry.  While it is open it is its thread's lap-keeping span, which
+    the module's :func:`lap` marks from any callee."""
 
-    def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any]):
+    __slots__ = ("_tracer", "name", "attrs", "t0", "span_id",
+                 "_laps", "_mark", "_outer")
+
+    def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any],
+                 laps: bool = False):
         self._tracer = tracer
         self.name = name
         self.attrs = attrs
+        self._laps = [] if laps else None
 
     def __enter__(self):
         self.span_id = self._tracer._next_span_id()
         self._tracer._stack().append((self.name, self.span_id))
         self.t0 = time.time()
+        if self._laps is not None:
+            self._mark = time.perf_counter()
+            local = self._tracer._local
+            self._outer = getattr(local, "lap_span", None)
+            local.lap_span = self
         return self
 
+    def lap(self, name: str) -> None:
+        laps = self._laps
+        if laps is not None:
+            now = time.perf_counter()
+            laps.append([name, now - self._mark])
+            self._mark = now
+
     def __exit__(self, exc_type, exc, tb):
-        dur = time.time() - self.t0
+        if self._laps is None:
+            dur = time.time() - self.t0
+        else:
+            self.lap("rest")
+            self._tracer._local.lap_span = self._outer
+            dur = sum(seconds for _, seconds in self._laps)
         stack = self._tracer._stack()
         stack.pop()
         rec = {"kind": "span", "name": self.name, "ts": self.t0,
                "dur_s": dur, "span_id": self.span_id}
+        if self._laps is not None:
+            rec["laps"] = self._laps
         if stack:
             # parent name kept for the summarizer's nesting view;
             # parent_span is the id link the request timeline follows
@@ -215,6 +255,14 @@ class Tracer:
 
     def span(self, name: str, **attrs) -> _Span:
         return _Span(self, name, attrs)
+
+    def lap_span(self, name: str, **attrs) -> _Span:
+        return _Span(self, name, attrs, laps=True)
+
+    def lap(self, name: str) -> None:
+        span = getattr(self._local, "lap_span", None)
+        if span is not None:
+            span.lap(name)
 
     def event(self, name: str, **attrs) -> None:
         rec = {"kind": "event", "name": name, "ts": time.time()}
@@ -315,6 +363,22 @@ def span(name: str, **attrs):
     if t is None:
         return _NULL_SPAN
     return t.span(name, **attrs)
+
+
+def lap_span(name: str, **attrs):
+    """A span that keeps laps (:class:`_Span`) — no-op when disabled."""
+    t = _tracer
+    if t is None:
+        return _NULL_SPAN
+    return t.lap_span(name, **attrs)
+
+
+def lap(name: str) -> None:
+    """Close a lap on the lap-keeping span open on THIS thread, from
+    however deep in its callees; nothing where there is none."""
+    t = _tracer
+    if t is not None:
+        t.lap(name)
 
 
 def event(name: str, **attrs) -> None:

@@ -55,6 +55,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from dtf_tpu.obs import trace
+
 log = logging.getLogger("dtf_tpu")
 
 
@@ -566,6 +568,9 @@ class Decoder:
         index = jnp.asarray(index, jnp.int32)
         temperature = jnp.asarray(temperature, jnp.float32)
         rowkeys = _seed_row_keys(jnp.asarray(seeds, jnp.uint32), index)
+        # laps of the engine's serve_iteration span, where one is open
+        # on this thread: the arguments, then the body's call returning
+        trace.lap("launch_args")
         dyn = (self.params, cache, tokens, index,
                jnp.asarray(block_tables, jnp.int32), temperature,
                rowkeys)
@@ -575,6 +580,7 @@ class Decoder:
                   or self._decode)
             self._execs["decode"] = fn
         toks, cache, last, self.last_stats = fn(*dyn)
+        trace.lap("launch_call")
         return toks, cache, last
 
     # -- the held parameters -------------------------------------------

@@ -610,16 +610,15 @@ class ServeEngine:
             "serve_queue_depth_sampled", unit="requests")
         self._m_occ_sampled = self.metrics.histogram(
             "serve_slot_occupancy_sampled", unit="fraction")
-        # page-pool operational signals: pool occupancy (gauge + per-
-        # iteration samples), prefill chunks run, and the decode-step
+        # page-pool operational signals: pool occupancy (a gauge; traced
+        # runs have it an iteration, `pages_used` on the serve_iteration
+        # record), prefill chunks run, and the decode-step
         # GAP — wall time between consecutive decode steps while slots
         # are decoding.  The gap p99 is the head-of-line-blocking
         # number chunked prefill exists to bound (bench_serve.py reads
         # it for the chunked vs un-chunked comparison).
         self._m_pages_used = self.metrics.gauge("serve_kv_pages_used",
                                                 unit="pages")
-        self._m_pages_sampled = self.metrics.histogram(
-            "serve_kv_pages_used_sampled", unit="pages")
         # what a page holds beside its tokens' K and V: the bytes of the
         # running-state entries (0 for a model whose layers all attend)
         self.metrics.gauge("serve_state_bytes_per_page", unit="bytes").set(
@@ -690,6 +689,10 @@ class ServeEngine:
         # callers holding cancelled results read the cause here
         self.error: Optional[BaseException] = None
         self._last_step_t: Optional[float] = None
+        # decode steps and prefill chunks launched so far: the ordinals a
+        # traced turn's record carries (_iteration_counts)
+        self._step_launches = 0
+        self._chunk_launches = 0
         self._prefill_rr = -1           # round-robin cursor (chunk sched)
         self.max_concurrent = 0         # peak simultaneously-active slots
         self._ewma_latency = 0.25       # seed estimate for retry_after
@@ -1019,140 +1022,198 @@ class ServeEngine:
             self._drain_migration_jobs()
 
     def _loop_body(self):
+        # one `serve_iteration` span a turn, cut into the laps named in
+        # _iteration: what the engine thread did, in order, so that a
+        # reader of the device's timeline can say what the host was at
+        # in each of the device's idle gaps.  Tracing off: the shared
+        # no-op span, and trace.lap() is a None check
         while True:
-            if self._heartbeat is not None:
-                # serving liveness: the beat interval gate is inside
-                # beat(), so this is one clock read per iteration
-                self._heartbeat.beat(step=self._m_completed.value)
-            # migration jobs run HERE, on the engine thread, between
-            # iterations: exports/imports touch the pool, registry and
-            # cache, which are single-writer engine-thread state — a
-            # wire thread mutating them directly would race _retire
-            self._run_migration_jobs()
-            with self._cond:
-                # cancellation sweep (queued half): a cancelled request
-                # that never reached a slot resolves right here —
-                # before it can cost an admission's pages
-                cancelled_pending = [h for h in self._pending
-                                     if h._cancel.is_set()]
-                for handle in cancelled_pending:
-                    self._pending.remove(handle)
-                    self._finish_cancelled(handle)
-                if cancelled_pending:
-                    # the idle branch below may wait before the normal
-                    # gauge refresh runs — a cancelled-empty queue must
-                    # not report phantom depth in the meantime
-                    self._m_queue_depth.set(len(self._pending))
-                active = any(s is not None for s in self._slots)
-                if not self._pending and not active:
-                    if self._stop.is_set():
-                        return
-                    # idle: the next decode step's gap would span this
-                    # wait, which is queue emptiness, not head-of-line
-                    # blocking — don't let it poison the gap histogram
-                    self._last_step_t = None
-                    # empty queue: sleep until a submit (or stop) pokes us
-                    self._cond.wait(timeout=0.1)
-                    continue
-                if not active and self._pending and self.max_delay_s > 0:
-                    # fresh batch: hold the door up to max_delay after the
-                    # FIRST pending arrival so the batch can fill
-                    first = self._pending[0].request.submit_time
-                    while (len(self._pending) < self.max_batch
-                           and not self._stop.is_set()):
-                        remaining = first + self.max_delay_s - time.time()
-                        if remaining <= 0:
-                            break
-                        self._cond.wait(timeout=remaining)
-                admitted = []
-                for i, slot in enumerate(self._slots):
-                    if slot is None and self._pending:
-                        req = self._pending[0].request
-                        shared, need, cow = self._admission_plan(req)
-                        # hold the shared pages BEFORE any alloc/
-                        # eviction: a registry-only page this admit
-                        # is about to share must not be evicted out
-                        # from under it
-                        self.pool.share(shared)
-                        pages = self.pool.alloc(need)
-                        if pages is None:
-                            self._evict_for(need)
-                            pages = self.pool.alloc(need)
-                        if pages is None:
-                            # head-of-line FIFO wait: the next
-                            # retire frees pages; small requests do
-                            # NOT slip past a starved big one.
-                            # Un-hold the speculative shares (the
-                            # registry's own holder keeps them
-                            # warm for the retry)
-                            for p in self.pool.free(shared):
-                                self.registry.drop_page(p)
-                            break
-                        if shared:
-                            self._m_prefix_hits.inc(len(shared))
-                        admitted.append((i, self._pending.pop(0),
-                                         (pages, shared, cow)))
-                pending_depth = len(self._pending)
-                self._m_queue_depth.set(pending_depth)
-            if self._stop.is_set() and not any(
-                    s is not None for s in self._slots) and not admitted:
+            with trace.lap_span("serve_iteration") as it:
+                done = self._iteration(it)
+            if done:
                 return
-            if admitted:
-                # batch formation: bind each admitted request to its
-                # slot (pages granted above; plan chunks here, prefill
-                # advances below — interleaved with decode steps).
-                # The span carries the admitted requests' trace ids so
-                # `trace_main --request` finds the batch work a request
-                # rode in (a batch span serves MANY requests — a list,
-                # not a single ambient context)
-                attrs = {"admitted": len(admitted)}
-                if trace.enabled():
-                    tids = [h.request.trace_id for _, h, _ in admitted
-                            if h.request.trace_id]
-                    if tids:
-                        attrs["traces"] = tids
-                with trace.span("serve_batch_form", **attrs):
-                    for i, handle, grant in admitted:
-                        self._admit(i, handle, grant)
-                self._m_admitted.inc(len(admitted))
-            # cancellation sweep (running half): a cancelled slot
-            # retires NOW — pages back to the pool, the slot to the
-            # next queued request — instead of decoding out its budget
-            # into the stale-discard bin (slots are engine-thread
-            # state; no lock needed)
-            for i, s in enumerate(self._slots):
-                if s is not None and s.handle._cancel.is_set():
-                    self._retire(i, cancelled=True)
-            # chunked prefill: ONE chunk per iteration TOTAL (round-
-            # robin across prefilling slots), so the gap running
-            # decodes see is bounded by a single chunk's compute no
-            # matter how many prompts are prefilling concurrently
-            prefilling = [i for i, s in enumerate(self._slots)
-                          if s is not None and s.phase == "prefill"]
-            if prefilling:
-                nxt = next((i for i in prefilling
-                            if i > self._prefill_rr), prefilling[0])
-                self._advance_prefill(nxt)
-                self._prefill_rr = nxt
-            active = sum(s is not None for s in self._slots)
-            decoding = sum(s is not None and s.phase == "decode"
-                           for s in self._slots)
-            self.max_concurrent = max(self.max_concurrent, active)
-            self._m_occupancy.set(active / self.max_batch)
-            self._m_pages_used.set(self.pool.used_pages)
-            self._m_shared.set(self.pool.shared_refs)
-            if active:
-                self._m_occ_sampled.observe(active / self.max_batch)
-                # pending_depth was read under the lock above — the
-                # list mutates under _cond, so len() here would race
-                self._m_queue_sampled.observe(pending_depth)
-                self._m_pages_sampled.observe(self.pool.used_pages)
-            if decoding:
-                self._step()
-            else:
-                # no running decodes: the next decode-step gap is not a
-                # head-of-line measurement
+
+    def _iteration(self, it) -> bool:
+        """One turn of the engine thread; True when the loop is over.
+
+        ``it``, the turn's span, keeps its laps, closed by trace.lap in
+        loop order (a name may repeat): ``sweep`` (heartbeat, migration
+        jobs, a cancellation sweep), ``wait`` (nothing to do, or holding
+        the door for ``max_delay_s``), ``admit`` (plans, page grants,
+        binding), ``chunk_host`` / ``chunk_sync`` (_advance_prefill),
+        ``gauges``, then _step's ``build``, ``launch_args`` and
+        ``launch_call`` (closed by Decoder.decode_step), ``ready`` and
+        ``emit``.  Traced, it also carries the turn's counts
+        (_iteration_counts)."""
+        rec = trace.enabled()
+        if rec:
+            before = (self._m_completed.value, self._m_cancelled.value,
+                      self._step_launches, self._chunk_launches)
+        if self._heartbeat is not None:
+            # serving liveness: the beat interval gate is inside
+            # beat(), so this is one clock read per iteration
+            self._heartbeat.beat(step=self._m_completed.value)
+        # migration jobs run HERE, on the engine thread, between
+        # iterations: exports/imports touch the pool, registry and
+        # cache, which are single-writer engine-thread state — a
+        # wire thread mutating them directly would race _retire
+        self._run_migration_jobs()
+        with self._cond:
+            # cancellation sweep (queued half): a cancelled request
+            # that never reached a slot resolves right here —
+            # before it can cost an admission's pages
+            cancelled_pending = [h for h in self._pending
+                                 if h._cancel.is_set()]
+            for handle in cancelled_pending:
+                self._pending.remove(handle)
+                self._finish_cancelled(handle)
+            if cancelled_pending:
+                # the idle branch below may wait before the normal
+                # gauge refresh runs — a cancelled-empty queue must
+                # not report phantom depth in the meantime
+                self._m_queue_depth.set(len(self._pending))
+            active = any(s is not None for s in self._slots)
+            trace.lap("sweep")
+            if not self._pending and not active:
+                if self._stop.is_set():
+                    return True
+                # idle: the next decode step's gap would span this
+                # wait, which is queue emptiness, not head-of-line
+                # blocking — don't let it poison the gap histogram
                 self._last_step_t = None
+                # empty queue: sleep until a submit (or stop) pokes us
+                self._cond.wait(timeout=0.1)
+                trace.lap("wait")
+                if rec:
+                    self._iteration_counts(it, before, len(self._pending))
+                return False
+            if not active and self._pending and self.max_delay_s > 0:
+                # fresh batch: hold the door up to max_delay after the
+                # FIRST pending arrival so the batch can fill
+                first = self._pending[0].request.submit_time
+                while (len(self._pending) < self.max_batch
+                       and not self._stop.is_set()):
+                    remaining = first + self.max_delay_s - time.time()
+                    if remaining <= 0:
+                        break
+                    self._cond.wait(timeout=remaining)
+                trace.lap("wait")
+            admitted = []
+            for i, slot in enumerate(self._slots):
+                if slot is None and self._pending:
+                    req = self._pending[0].request
+                    shared, need, cow = self._admission_plan(req)
+                    # hold the shared pages BEFORE any alloc/
+                    # eviction: a registry-only page this admit
+                    # is about to share must not be evicted out
+                    # from under it
+                    self.pool.share(shared)
+                    pages = self.pool.alloc(need)
+                    if pages is None:
+                        self._evict_for(need)
+                        pages = self.pool.alloc(need)
+                    if pages is None:
+                        # head-of-line FIFO wait: the next
+                        # retire frees pages; small requests do
+                        # NOT slip past a starved big one.
+                        # Un-hold the speculative shares (the
+                        # registry's own holder keeps them
+                        # warm for the retry)
+                        for p in self.pool.free(shared):
+                            self.registry.drop_page(p)
+                        break
+                    if shared:
+                        self._m_prefix_hits.inc(len(shared))
+                    admitted.append((i, self._pending.pop(0),
+                                     (pages, shared, cow)))
+            pending_depth = len(self._pending)
+            self._m_queue_depth.set(pending_depth)
+        if self._stop.is_set() and not any(
+                s is not None for s in self._slots) and not admitted:
+            return True
+        if admitted:
+            # batch formation: bind each admitted request to its
+            # slot (pages granted above; plan chunks here, prefill
+            # advances below — interleaved with decode steps).
+            # The span carries the admitted requests' trace ids so
+            # `trace_main --request` finds the batch work a request
+            # rode in (a batch span serves MANY requests — a list,
+            # not a single ambient context)
+            attrs = {"admitted": len(admitted)}
+            if trace.enabled():
+                tids = [h.request.trace_id for _, h, _ in admitted
+                        if h.request.trace_id]
+                if tids:
+                    attrs["traces"] = tids
+            with trace.span("serve_batch_form", **attrs):
+                for i, handle, grant in admitted:
+                    self._admit(i, handle, grant)
+            self._m_admitted.inc(len(admitted))
+        trace.lap("admit")
+        # cancellation sweep (running half): a cancelled slot
+        # retires NOW — pages back to the pool, the slot to the
+        # next queued request — instead of decoding out its budget
+        # into the stale-discard bin (slots are engine-thread
+        # state; no lock needed)
+        for i, s in enumerate(self._slots):
+            if s is not None and s.handle._cancel.is_set():
+                self._retire(i, cancelled=True)
+        trace.lap("sweep")
+        # chunked prefill: ONE chunk per iteration TOTAL (round-
+        # robin across prefilling slots), so the gap running
+        # decodes see is bounded by a single chunk's compute no
+        # matter how many prompts are prefilling concurrently
+        prefilling = [i for i, s in enumerate(self._slots)
+                      if s is not None and s.phase == "prefill"]
+        if prefilling:
+            nxt = next((i for i in prefilling
+                        if i > self._prefill_rr), prefilling[0])
+            self._advance_prefill(nxt)
+            self._prefill_rr = nxt
+            trace.lap("chunk_host")
+        active = sum(s is not None for s in self._slots)
+        decoding = sum(s is not None and s.phase == "decode"
+                       for s in self._slots)
+        self.max_concurrent = max(self.max_concurrent, active)
+        self._m_occupancy.set(active / self.max_batch)
+        self._m_pages_used.set(self.pool.used_pages)
+        self._m_shared.set(self.pool.shared_refs)
+        if active:
+            self._m_occ_sampled.observe(active / self.max_batch)
+            # pending_depth was read under the lock above — the
+            # list mutates under _cond, so len() here would race
+            self._m_queue_sampled.observe(pending_depth)
+        trace.lap("gauges")
+        if decoding:
+            self._step()
+        else:
+            # no running decodes: the next decode-step gap is not a
+            # head-of-line measurement
+            self._last_step_t = None
+        if rec:
+            self._iteration_counts(
+                it, before, pending_depth, admitted=len(admitted),
+                decoding=decoding, prefilling=active - decoding)
+        return False
+
+    def _iteration_counts(self, it, before, pending, **counts):
+        """The ``serve_iteration`` record's counts (traced runs only):
+        requests ``retired`` and ``cancelled`` in this turn (``before``:
+        the two counters and the two ordinals at its start), the queue's
+        depth as read under
+        ``_cond``, the pool's pages in use, and the caller's rows by
+        phase.  ``step`` and ``chunk`` are the ordinals, since the engine
+        started, of the decode step and of the prefill chunk the turn
+        launched (absent where it launched none): a reader of the
+        device's timeline pairs the n-th run of a body with them."""
+        if self._step_launches != before[2]:
+            counts["step"] = self._step_launches
+        if self._chunk_launches != before[3]:
+            counts["chunk"] = self._chunk_launches
+        it.attrs.update(
+            counts, pending=pending, pages_used=self.pool.used_pages,
+            retired=self._m_completed.value - before[0],
+            cancelled=self._m_cancelled.value - before[1])
 
     def _evict_for(self, need: int):
         """Free registry-only pages (deepest entries first) until
@@ -1268,6 +1329,9 @@ class ServeEngine:
             prompt_padded=prompt_padded, chunk_plan=plan, chunk_i=0)
 
     def _advance_prefill(self, slot_idx: int):
+        """One chunk of one prefilling slot.  Laps of the turn's span:
+        ``chunk_host`` is the host's side (the caller closes the last
+        one), ``chunk_sync`` a wait for the chunk's result."""
         slot = self._slots[slot_idx]
         req = slot.handle.request
         start, clen = slot.chunk_plan[slot.chunk_i]
@@ -1277,6 +1341,7 @@ class ServeEngine:
         sample_pos = plen - 1 - start if is_last else clen - 1
         t0 = time.perf_counter()
         pre_compiled = self.decoder.compiled_count
+        self._chunk_launches += 1
         with trace.span("serve_prefill_chunk", slot=slot_idx, start=start,
                         tokens=clen, last=is_last,
                         **_tctx(req.trace_id, req.trace_parent)) as span:
@@ -1288,7 +1353,9 @@ class ServeEngine:
         self._m_prefill_chunks.inc()
         slot.chunk_i += 1
         if is_last:
+            trace.lap("chunk_host")
             first = int(tok)
+            trace.lap("chunk_sync")
             # the int(tok) sync above makes this the one chunk whose
             # wall time spans a real device sync — the only honest
             # sample the MFU ledger takes for the chunk executable
@@ -1320,6 +1387,10 @@ class ServeEngine:
                 self._retire(slot_idx)
 
     def _step(self):
+        """One decode step of every decoding row.  Laps of the turn's span:
+        ``build`` (the step's arrays), ``launch_args`` and ``launch_call``
+        (Decoder.decode_step closes them), ``ready`` (blocked on the
+        step's tokens: the device's time), ``emit`` (the walk after)."""
         now = time.perf_counter()
         if self._last_step_t is not None:
             self._m_decode_gap.observe(now - self._last_step_t)
@@ -1349,6 +1420,8 @@ class ServeEngine:
         self._m_live_pages.observe(
             int((index // self.page_size + 1).sum()))
         pre_compiled = self.decoder.compiled_count
+        self._step_launches += 1
+        trace.lap("build")
         with trace.span("serve_decode", **attrs) as span:
             out, self._cache, _ = self.decoder.decode_step(
                 self._cache, tokens, index, temps, seeds=seeds,
@@ -1357,6 +1430,7 @@ class ServeEngine:
             # sampled tokens on the host; the MFU ledger's
             # serve_decode_step wall time is honest BECAUSE this syncs)
             out = self._model_counts(span, out)
+            trace.lap("ready")
         step_dt = time.perf_counter() - now
         self._m_step_time.observe(step_dt)
         # MFU ledger: np.asarray(out) above synced the step, so this
@@ -1386,6 +1460,7 @@ class ServeEngine:
             s.handle._emit(tok)
             if self._finished(s):
                 self._retire(i)
+        trace.lap("emit")
         self._last_step_t = time.perf_counter()
 
     def _model_counts(self, span, out=None):
@@ -1396,12 +1471,18 @@ class ServeEngine:
         a model that counts nothing) comes over in the same transfer and
         becomes attributes of ``span``.  In a traced run this makes a
         non-final prefill chunk wait for the device, which an untraced
-        one does not."""
+        one does not: that wait is the turn's ``chunk_sync`` lap (a
+        step's is inside its ``ready``, which _step closes)."""
         stats = self.decoder.last_stats if trace.enabled() else None
         if stats is None:
             return None if out is None else np.asarray(out)
+        chunk = out is None
+        if chunk:
+            trace.lap("chunk_host")
         # dtflint: sync-point (traced runs only; see above)
         out, counts = jax.device_get((out, stats["counts"]))
+        if chunk:
+            trace.lap("chunk_sync")
         span.attrs.update(zip(self.decoder.model.stats_names,
                               (int(c) for c in counts)))
         return out

@@ -81,12 +81,23 @@ def test_span_emission_and_nesting(tmp_path):
     assert by_name["marker"]["kind"] == "event"
 
 
-def test_span_records_error_and_disabled_is_noop(tmp_path):
-    # disabled: the module API must be callable and free of effects
+def test_span_records_error_and_disabled_is_noop(tmp_path, monkeypatch):
+    # disabled: the module API must be callable and free of effects —
+    # one shared object whatever is asked for, and no clock read
     assert trace.get() is None
-    with trace.span("nothing"):
+
+    def no_clock():
+        raise AssertionError("a disabled tracer read a clock")
+    monkeypatch.setattr(trace.time, "time", no_clock)
+    monkeypatch.setattr(trace.time, "perf_counter", no_clock)
+    with trace.span("nothing") as plain:
         pass
+    with trace.lap_span("nothing") as lapped:
+        assert lapped.lap("piece") is None
+        assert trace.lap("piece") is None
+    assert plain is lapped is trace._NULL_SPAN
     trace.event("nothing")
+    monkeypatch.undo()
     t = trace.configure(str(tmp_path), rank=0)
     with pytest.raises(RuntimeError):
         with trace.span("boom"):
@@ -94,6 +105,67 @@ def test_span_records_error_and_disabled_is_noop(tmp_path):
     t.flush()
     recs = [r for r in trace.read_records(t.path) if r.get("name") == "boom"]
     assert recs and recs[0]["error"] == "RuntimeError"
+
+
+def _spans(tracer, name):
+    tracer.flush()
+    return [r for r in trace.read_records(tracer.path)
+            if r.get("kind") == "span" and r.get("name") == name]
+
+
+def test_laps_are_contiguous_ordered_and_add_up_to_the_span(tmp_path):
+    t = trace.configure(str(tmp_path), rank=0)
+    with trace.lap_span("turn", n=3) as sp:
+        time.sleep(0.002)
+        sp.lap("a")
+        time.sleep(0.001)
+        trace.lap("b")              # the module's: this thread's open span
+        sp.lap("a")                 # a name may repeat; order is kept
+        time.sleep(0.001)
+    with trace.span("plain"):
+        trace.lap("nobody")         # no lap-keeping span open: nothing
+    (rec,), (plain,) = _spans(t, "turn"), _spans(t, "plain")
+    assert [n for n, _ in rec["laps"]] == ["a", "b", "a", "rest"]
+    assert all(s >= 0 for _, s in rec["laps"])
+    assert rec["laps"][0][1] >= 0.002 and rec["laps"][1][1] >= 0.001
+    assert sum(s for _, s in rec["laps"]) == pytest.approx(rec["dur_s"],
+                                                           abs=1e-9)
+    assert rec["n"] == 3 and "laps" not in plain
+    # ts is the wall clock as on every span; laps lie inside it
+    assert abs(rec["ts"] - time.time()) < 60
+
+
+def test_a_callees_lap_lands_on_its_own_threads_open_span(tmp_path):
+    import threading
+    t = trace.configure(str(tmp_path), rank=0)
+    inner_open, outer_marked = threading.Event(), threading.Event()
+
+    def callee(name):
+        trace.lap(name)             # knows no span: the tracer finds it
+
+    def other_thread():
+        with trace.lap_span("theirs"):
+            callee("theirs_1")
+            inner_open.set()
+            outer_marked.wait(5)
+            callee("theirs_2")
+
+    th = threading.Thread(target=other_thread)
+    with trace.lap_span("mine"):
+        th.start()
+        inner_open.wait(5)
+        callee("mine_1")
+        with trace.lap_span("nested"):
+            callee("nested_1")      # the innermost lap-keeping span
+        callee("mine_2")            # ... and the outer one again after it
+        outer_marked.set()
+        th.join(5)
+    names = {n: [lap for lap, _ in _spans(t, n)[0]["laps"]]
+             for n in ("mine", "nested", "theirs")}
+    assert names == {"mine": ["mine_1", "mine_2", "rest"],
+                     "nested": ["nested_1", "rest"],
+                     "theirs": ["theirs_1", "theirs_2", "rest"]}
+    assert _spans(t, "nested")[0]["parent"] == "mine"
 
 
 def test_read_records_tolerates_torn_tail(tmp_path):
@@ -305,6 +377,40 @@ def test_trace_main_json_mode(tmp_path, capsys):
     summary = json.loads(capsys.readouterr().out)
     assert summary["spans"]["step"]["count"] == 4
     assert summary["events"] == {"heartbeat": 1, "trace_start": 1}
+
+
+def test_trace_main_shows_a_spans_laps_by_name(tmp_path, capsys):
+    t = trace.configure(str(tmp_path), rank=0)
+    for n in range(3):
+        with trace.lap_span("turn", decoding=n + 2, step=n + 7,
+                            note="words") as sp:
+            sp.lap("build")
+            time.sleep(0.002)
+            sp.lap("ready")
+            sp.lap("build")         # a second piece of the same name
+    with trace.span("plain"):
+        pass
+    t.flush()
+    trace.disable()
+    assert trace_main([str(tmp_path), "--json"]) == 0
+    spans = json.loads(capsys.readouterr().out)["spans"]
+    laps = spans["turn"]["laps"]
+    assert list(laps)[0] == "ready" and set(laps) == {"ready", "build",
+                                                      "rest"}
+    assert laps["build"]["spans"] == 3 and "laps" not in spans["plain"]
+    assert sum(r["total_s"] for r in laps.values()) == pytest.approx(
+        spans["turn"]["total_s"], rel=1e-6)
+    assert sum(r["share"] for r in laps.values()) == pytest.approx(1.0,
+                                                                   rel=1e-6)
+    # the whole-number attributes of a lap-keeping span: its counts
+    assert spans["turn"]["counts"] == {
+        "decoding": {"spans": 3, "total": 9, "mean": 3.0, "min": 2, "max": 4},
+        "step": {"spans": 3, "total": 24, "mean": 8.0, "min": 7, "max": 9}}
+    assert "counts" not in spans["plain"]
+    assert trace_main([str(tmp_path)]) == 0
+    out = capsys.readouterr().out
+    assert "lap ready" in out and "lap build" in out
+    assert "count decoding" in out and "min 7  max 9" in out
 
 
 def test_trace_main_ledger_json_machine_readable(tmp_path, capsys):

@@ -285,6 +285,84 @@ def test_decode_steps_record_their_live_pages(model_and_params):
         eng.stop(drain=False)
 
 
+def _served(model, params, trace_dir=None):
+    """Six requests through a 4-slot engine (so two wait for a slot and
+    chunks run beside decode steps): the tokens served and, traced, the
+    span records."""
+    from dtf_tpu.obs import trace
+    tracer = trace.configure(trace_dir) if trace_dir else None
+    try:
+        with paged_engine(model, params) as eng:
+            handles = [eng.submit(np.arange(1, n + 1, dtype=np.int32) % 60,
+                                  max_new_tokens=6, temperature=t)
+                       for n, t in ((19, 0.0), (3, 0.8), (4, 0.0), (1, 0.0),
+                                    (19, 0.8), (7, 0.0))]
+            tokens = [h.result(timeout=300).tokens for h in handles]
+    finally:
+        trace.disable()
+    records = trace.read_records(tracer.path) if tracer else []
+    return tokens, [r for r in records if r.get("kind") == "span"]
+
+
+def test_an_iteration_is_one_record_of_named_laps(model_and_params,
+                                                  tmp_path):
+    """Tracing on: one ``serve_iteration`` span a turn of the engine
+    thread, cut into laps in loop order with the turn's counts, the three
+    older spans its children and otherwise as they were — and the tokens
+    served those of an untraced engine."""
+    model, params = model_and_params
+    untraced, none = _served(model, params)
+    tokens, spans = _served(model, params, str(tmp_path))
+    assert tokens == untraced and not none
+    turns = [r for r in spans if r["name"] == "serve_iteration"]
+    by_id = {r["span_id"]: r for r in turns}
+    for r in turns:
+        assert sum(s for _, s in r["laps"]) == pytest.approx(r["dur_s"],
+                                                             abs=1e-9)
+    stepped = [r for r in turns if "step" in r]
+    assert [r["step"] for r in stepped] == list(range(1, len(stepped) + 1))
+    order = ["sweep", "admit", "sweep", "gauges", "build", "launch_args",
+             "launch_call", "ready", "emit", "rest"]
+    for r in stepped:
+        laps = [n for n, _ in r["laps"] if not n.startswith("chunk_")]
+        assert laps == order, laps
+    assert sum(r["decoding"] for r in stepped) == sum(
+        len(t) for t in tokens) - 6      # a prompt's first token: its chunk's
+    chunked = [r for r in turns if "chunk" in r]
+    assert [r["chunk"] for r in chunked] == list(range(1, len(chunked) + 1))
+    for r in chunked:                   # after the second sweep, before gauges
+        laps = [n for n, _ in r["laps"]]
+        assert laps[3] == "chunk_host" and laps[laps.index("gauges") - 1] \
+            == "chunk_host"
+        assert set(laps[3:laps.index("gauges")]) <= {"chunk_host",
+                                                     "chunk_sync"}
+    # a turn that only waited
+    assert any([n for n, _ in r["laps"]] == ["sweep", "wait", "rest"]
+               for r in turns)
+    assert sum(r.get("admitted", 0) for r in turns) == 6
+    assert sum(r["retired"] for r in turns if "retired" in r) == 6
+    assert all(r["cancelled"] == 0 and r["pages_used"] >= 0
+               and r["pending"] >= 0 for r in turns if "pending" in r)
+    # the three spans that were there: names, attributes, one a launch,
+    # each the child of the turn that made it
+    plain = {"kind", "name", "ts", "dur_s", "span_id", "parent",
+             "parent_span", "rank"}
+    attrs = {"serve_decode": {"allheads", "traces"},
+             "serve_prefill_chunk": {"slot", "start", "tokens", "last",
+                                     "trace"},
+             "serve_batch_form": {"admitted", "traces"}}
+    for name, want in attrs.items():
+        mine = [r for r in spans if r["name"] == name]
+        assert mine and all(set(r) - plain == want for r in mine), name
+        assert all(r["parent"] == "serve_iteration" and "laps" not in r
+                   and by_id[r["parent_span"]]["ts"] <= r["ts"]
+                   for r in mine)
+    assert len([r for r in spans if r["name"] == "serve_decode"]) \
+        == len(stepped)
+    assert len([r for r in spans if r["name"] == "serve_prefill_chunk"]) \
+        == len(chunked)
+
+
 def test_begin_drain_racing_inflight_prefill_chunk(model_and_params):
     """begin_drain() landing BETWEEN a request's prefill chunks (the
     SIGTERM-mid-prefill race): the drain must finish that request —
