@@ -176,6 +176,42 @@ def position_key(seed, position):
 _seed_row_keys = jax.jit(jax.vmap(position_key))
 
 
+def _pack_chunk_operands(chunk, block_row, sample_pos, start, seed,
+                         temperature):
+    """The one host array ``_chunk_operands`` splits: int32
+    ``[C + pages + 4]`` — the chunk's tokens [C], the slot's block row
+    [pages], then ``sample_pos``, ``start`` and the BITS of the request's
+    seed (uint32: seeds reach 2**32 − 1) and temperature (float32)."""
+    return np.concatenate([
+        chunk, block_row, np.array([sample_pos, start], np.int32),
+        np.array([seed], np.uint32).view(np.int32),
+        np.array([temperature], np.float32).view(np.int32)])
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _chunk_operands(packed, pages: int):
+    """Everything a prefill chunk's body takes besides the parameters and
+    the cache, from ONE host array (``_pack_chunk_operands``) in ONE cached
+    program.  Returns the body's operands in its own order and avals:
+    (tokens [1, C], block_row [1, pages], sample_pos, temperature,
+    ``position_key(seed, start + sample_pos)``, start).
+
+    Why one array and one program: on the chip a transfer costs the engine
+    thread 0.2–0.3 ms whatever its size, inside an executable's call as
+    outside it, and a small program's launch as much again — the key from
+    eager primitives (1.8 ms) and a ``jnp.asarray`` an operand (1.7 ms)
+    were 3.4 of the 4.8 ms a dense chunk's dispatch took, and this is 0.7
+    (PERF.md §6 PR 37).  Retraced by a chunk's SHAPE alone, with the body
+    and in the same warm-up; no value compiles."""
+    c = packed.shape[0] - pages - 4
+    sample_pos, start, seed, temperature = packed[c + pages:]
+    return (packed[None, :c], packed[None, c:c + pages], sample_pos,
+            jax.lax.bitcast_convert_type(temperature, jnp.float32),
+            position_key(jax.lax.bitcast_convert_type(seed, jnp.uint32),
+                         start + sample_pos),
+            start)
+
+
 # How the TPU's compiler is asked to build the two bodies.  Left alone it
 # prefetches a body's weights into VMEM with each prefetch cut in four
 # slices: 339 async copies a decode step of the 1.3B dense block, two
@@ -523,32 +559,29 @@ class Decoder:
         prefix.  ``seed`` is the request's: the sample is keyed to the
         chunk's GLOBAL sampled position, so every chunking of a prompt
         samples identically."""
-        chunk = np.asarray(chunk, np.int32).reshape(1, -1)
-        key = position_key(int(seed), int(start) + int(sample_pos))
-        if chunk.shape[1] % self.page_size or start % self.page_size:
+        chunk = np.asarray(chunk, np.int32).reshape(-1)
+        if chunk.size % self.page_size or start % self.page_size:
             raise ValueError(
-                f"prefill chunk (len {chunk.shape[1]}, start {start}) "
+                f"prefill chunk (len {chunk.size}, start {start}) "
                 f"must be page-aligned (kv_page_size {self.page_size}) — "
                 f"whole-page writes depend on it")
-        block_row = np.asarray(block_row, np.int32).reshape(1, -1)
+        block_row = np.asarray(block_row, np.int32).reshape(-1)
         # gather path: static window trim (one compile per window, the
         # O(prompt²/2) contract); kernel path: None — the kernel skips
         # dead pages dynamically, so every chunk index shares ONE
         # compile per chunk shape
         window = (None if self._kernel_attn
-                  else (int(start) + chunk.shape[1]) // self.page_size)
-        dyn = (self.params, cache, jnp.asarray(chunk),
-               jnp.asarray(block_row),
-               jnp.asarray(sample_pos, jnp.int32),
-               jnp.asarray(temperature, jnp.float32), key,
-               jnp.asarray(int(start), jnp.int32))
-        ekey = ("chunk", chunk.shape[1], window, start == 0)
+                  else (int(start) + chunk.size) // self.page_size)
+        dyn = (self.params, cache) + _chunk_operands(
+            _pack_chunk_operands(chunk, block_row, sample_pos, start, seed,
+                                 temperature), block_row.size)
+        ekey = ("chunk", chunk.size, window, start == 0)
         fn = self._execs.get(ekey)
         if fn is None:
             # ledger name is per chunk SHAPE: gather-path window
             # variants share it (latest compile's counts stand for the
             # family — obs/ledger.py documents the approximation)
-            fn = self._aot(f"serve_prefill_chunk_c{chunk.shape[1]}",
+            fn = self._aot(f"serve_prefill_chunk_c{chunk.size}",
                            self._chunk, dyn + (window, start == 0))
             if fn is None:
                 fn = (lambda *a, _w=window, _f=(start == 0):

@@ -91,6 +91,7 @@ class Pump:
                         .astype(np.int32) for i in range(6)]
         self._handles = []
         self._shed = 0
+        self._attempted = threading.Event()
         self._stop = threading.Event()
         self._thread = threading.Thread(target=self._run, daemon=True)
 
@@ -105,6 +106,7 @@ class Pump:
                                            max_new_tokens=self.budget)))
             except Backpressure:
                 self._shed += 1
+            self._attempted.set()
             i += 1
 
     def __enter__(self):
@@ -112,6 +114,10 @@ class Pump:
         return self
 
     def __exit__(self, *exc):
+        # a rollout can be over before the thread's first cadence tick
+        # (a resumed one takes ~60 ms; on a loaded host the tick comes
+        # later): stop on a condition, not on the clock
+        self._attempted.wait(timeout=5)
         self._stop.set()
         self._thread.join(timeout=5)
 
@@ -336,14 +342,24 @@ def test_resume_mid_rolling_finishes_forward(tmp_path):
     FORWARD — the remaining replica rolls, phase reaches DONE."""
     router, reps, hook, hook_calls = make_rollout_tier(tmp_path)
     try:
-        # replica 0 already on the new checkpoint, as the state claims
+        # replica 0 already on the new checkpoint, as the state claims.
+        # Swapped the way the dead router had swapped it (held, closed,
+        # re-announced): a bare hook() kills it under the router's feet,
+        # replica_healthy(0) stays True until the dead socket is noticed,
+        # and whether that falls inside the resume (-> "replica0_lost",
+        # ROLLED_BACK) is the host's load, not the code under test
+        router.hold_replica(0)
+        router.terminate_replica(0)
         hook(0, NEW_SAME)
+        router.allow_reconnect(0)
         path = _write_state(tmp_path, phase="ROLLING",
                             new_checkpoint=NEW_SAME, old_checkpoint=OLD,
                             canary=0, order=[0, 1], rolled=[0])
         t0 = time.monotonic()
         while not router.replica_healthy(0) and time.monotonic() - t0 < 5:
             time.sleep(0.02)
+        assert router.replica_healthy(0)
+        router.release_replica(0)
         with Pump(router) as pump:
             state = RolloutController.resume(
                 router, path, restart_hook=hook, warm_timeout_s=8.0,
