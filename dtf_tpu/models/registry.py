@@ -92,6 +92,24 @@ _REGISTRY = {
             routing_sum_eps=1e-6, router_bias_stddev=0.05,
             activation="silu", router_input="post_attention"),
         32_768, 0.0),
+    # and delta-rule linear-attention layers (a matrix of state a head on
+    # the page table) beside one latent layer in six with a direct query
+    # projection, a head norm and a head gate; group-limited routing over
+    # 16 experts of which this device holds 8
+    "routed_decoder_linear": (
+        functools.partial(
+            routed_decoder.RoutedDecoderLM, num_layers=8, d_model=512,
+            num_heads=8, layer_mixer=("linear_delta",) * 5 + ("attention",),
+            linear_heads=8, linear_head_dim=64, kv_lora_rank=128,
+            qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=32,
+            q_head_norm=True, attention_head_gate=True,
+            num_dense_layers=2, dense_width=1024, num_experts=16,
+            experts_per_token=4, expert_width=128, shared_expert_width=128,
+            routing="sigmoid_bias", routed_scale=2.5,
+            router_bias_stddev=0.05, route_groups=4, route_groups_kept=2,
+            experts_held=(0, 8), activation="silu",
+            router_input="post_attention"),
+        32_768, 0.0),
     # pipeline-stacked LM family (pipeline stages over 'model')
     "pipeline_transformer": (pipeline_lm.PipelinedTransformerLM,
                              32_768, 0.0),

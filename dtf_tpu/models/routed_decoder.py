@@ -22,12 +22,15 @@ num_kv_heads)``), and two tuples, one entry a layer, say
                        the cache; False: no positional signal at all.
 
 The cache is K and V pools ``[P, page, Hkv, Dh]``.  ``kv_lora_rank`` set —
-latent attention (:class:`LatentAttention`) in every layer, over the whole
-history: the cache is ONE pool ``[P, page, W]`` a layer whose row is a
+latent attention (:class:`LatentAttention`) in every ATTENTION layer, over
+the whole history: the cache is ONE pool ``[P, page, W]`` a layer whose row is a
 token's normed latent and its one rotary key (``kv_lora_rank +
 qk_rope_head_dim`` values in ``latent_row_lanes`` stored lanes), read once
 a call for scores and values; decode mode attends absorbed, every chunk
-(the first too) through the paged kernel.
+(the first too) through the paged kernel.  ``q_lora_rank`` None beside it
+projects the queries directly; ``q_head_norm`` norms each query head
+before the rotation; ``attention_head_gate`` scales a head's output by a
+sigmoid gate.
 
 **Mixer kind.**  ``layer_mixer[l]`` (one entry a layer; shorter tuples
 repeat) is ``attention`` — the kind above — or ``short_conv``
@@ -50,6 +53,25 @@ follows from ``head_dim`` and is nobody's to choose;
 ``qk_norm``: RMSNorm over each head of q and of k, a learned scale each,
 before the rotation.
 
+The third kind, ``linear_delta`` (:class:`LinearDelta`,
+``ops/linear_state.py``): delta-rule linear attention with a decay a
+channel — ``linear_heads`` heads whose state is a ``linear_head_dim x
+linear_head_dim`` MATRIX each, rewritten by every token, behind three
+short filters of ``linear_conv_taps`` taps.  Its state rides the page
+table by the same contract, in two leaves a layer: ``linear_state`` ``[P,
+H, D, D]`` (the matrices, in ``dtype``) and ``conv_state`` ``[P,
+(taps - 1) * 3 * H * D]``.  A matrix entry is a thousand times a filter
+entry, so a page must be LARGE for an entry a page to be affordable (pages
+of 512-2,048 tokens, where a page of tokens weighs what a state weighs):
+the serving engine's page size is the deployment's to choose and nothing
+here fixes it.  A decode step advances a row's matrices in the kernel
+``linear_state_decode`` (the pool aliased in place), a chunk of whole
+pages through the blocked form, the full forward outside decode mode
+through the token-by-token recurrence.  Mixers of different kinds stand
+beside EITHER attention kind: ``layer_mixer`` decides a layer,
+``kv_lora_rank`` what its attention layers are (``short_conv`` alone still
+wants whole heads).
+
 **MLP kind.**  The first ``num_dense_layers`` layers have a dense gated
 MLP of ``dense_width`` and no router.  The others route: ``num_experts``
 gated experts of ``expert_width`` (``activation`` relu | silu) of which
@@ -64,6 +86,17 @@ every token takes its ``experts_per_token`` best, by ``routing``
 from the norm ``router_input`` names (``pre_attention``: the layer's
 first norm, before attention runs; ``post_attention``: the second), and
 add a shared expert of ``shared_expert_width`` (0: none) for every token.
+``route_groups`` > 1 puts a GROUP LIMIT on the ``sigmoid_bias`` choice: the
+experts are that many runs of consecutive ids, a group's score is the sum
+of its 2 largest biased scores, and a token chooses its
+``experts_per_token`` within its ``route_groups_kept`` best groups.
+``experts_held`` (first id, count) tells the expert layer WHICH experts
+this device holds of the ``num_experts`` the router still chooses among
+(one chip's share of a layer that several chips hold together): its
+weights are those experts' alone, a chosen pair whose expert is absent is
+dropped before the grouped matmul and adds nothing, and nothing stands in
+for the absent devices or their exchange — the layer's output is this
+device's partial sum plus the shared expert.
 
 The layer, for ``x [S, d]``:
 
@@ -88,6 +121,13 @@ flips alone were 0.008-0.019 of the 0.014-0.029 the served logits read
 against it (v5e, 12 seeds, whole heads), and it costs [tokens, d_model]
 words a layer beside 755e6 bytes of experts.
 
+Under ``experts_held`` the count ``assignments`` is the pairs COMPUTED
+HERE (their expert is held), and ``experts_touched`` / ``expert_load_max``
+run over the held experts; a model with state counts the tokens its state
+layers mixed and the rows whose entries went to a page of their own
+(``conv_tokens`` or ``linear_tokens``, ``state_rows_advanced``), beside
+either attention kind's counts.
+
 Every apply also yields counts (``stats_names``; summed over layers) in
 the ``"stats"`` collection when the caller makes it mutable: the serving
 engine puts them on its spans when tracing is on.
@@ -95,6 +135,7 @@ engine puts them on its spans when tracing is on.
 
 from __future__ import annotations
 
+import math
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -102,6 +143,7 @@ import jax
 import jax.numpy as jnp
 
 from dtf_tpu.models.transformer import paged_cache_attention
+from dtf_tpu.ops import linear_state
 from dtf_tpu.ops.paged_attention import cached_attention, expand_kv_heads
 
 # what ``"stats"/"counts"`` holds, in order: with whole heads, and with the
@@ -113,7 +155,10 @@ LATENT_STATS = STATS[:3] + ("latent_tokens_read",)
 # mixed, and the rows whose state entry went to a page of their own (not
 # the scratch page), both summed over the short-convolution layers
 STATE_STATS = STATS + ("conv_tokens", "state_rows_advanced")
-MIXERS = ("attention", "short_conv")
+# with delta-rule linear-attention layers: the tokens those layers mixed
+# and the rows whose entries went to a page of their own, as above
+LINEAR_STATS = ("linear_tokens", "state_rows_advanced")
+MIXERS = ("attention", "short_conv", "linear_delta")
 
 # grouped matmul tile (rows, contraction, columns): rows of one expert are
 # padded to a multiple of the first inside the kernel's own bookkeeping
@@ -159,7 +204,7 @@ def interleaved_rope(x, positions, theta: float):
 
 
 def route(h, w_router, k: int, score_bias=None, routed_scale: float = 1.0,
-          sum_eps: float = 0.0):
+          sum_eps: float = 0.0, groups: int = 1, groups_kept: int = 1):
     """(expert ids [T, k], weights [T, k] f32) of the ``k`` largest router
     logits a token; softmax over the chosen (softmax over all, then
     renormalised over the chosen, is the same numbers).  f32 at full
@@ -170,13 +215,26 @@ def route(h, w_router, k: int, score_bias=None, routed_scale: float = 1.0,
     ``s = sigmoid(logits)``; the choice is the ``k`` largest of ``s +
     score_bias``; the weights are the chosen experts' ``s`` WITHOUT the
     bias, divided by their sum (plus ``sum_eps``: a published rule adds
-    1e-6 there) and multiplied by ``routed_scale``."""
+    1e-6 there) and multiplied by ``routed_scale``.  ``groups`` > 1 limits
+    that choice: the experts are ``groups`` runs of consecutive ids, a
+    group's score is the sum of its 2 largest ``s + score_bias``, and only
+    the experts of the ``groups_kept`` best groups can be chosen."""
     logits = jnp.einsum("td,de->te", h.astype(jnp.float32),
                         w_router.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
     if score_bias is not None:
         scores = jax.nn.sigmoid(logits)
-        _, idx = jax.lax.top_k(scores + score_bias.astype(jnp.float32), k)
+        biased = scores + score_bias.astype(jnp.float32)
+        if groups > 1:
+            t, e = biased.shape
+            best2, _ = jax.lax.top_k(biased.reshape(t, groups, e // groups),
+                                     2)
+            _, kept = jax.lax.top_k(jnp.sum(best2, -1), groups_kept)
+            open_ = jnp.zeros((t, groups), bool).at[
+                jnp.arange(t)[:, None], kept].set(True)
+            biased = jnp.where(jnp.repeat(open_, e // groups, axis=1),
+                               biased, -jnp.inf)
+        _, idx = jax.lax.top_k(biased, k)
         chosen = jnp.take_along_axis(scores, idx, axis=-1)
         total = jnp.sum(chosen, -1, keepdims=True)
         if sum_eps:
@@ -202,7 +260,7 @@ def gated_mlp(x, w_gate_up, w_down, activation: str = "silu"):
 
 
 def routed_experts(x, idx, weights, w_gate_up, w_down, *, use_pallas=None,
-                   activation: str = "relu"):
+                   activation: str = "relu", held=None):
     """The dropless expert layer: ``y[t] = sum_j weights[t, j] *
     down_e(act(gate_e x[t]) * up_e x[t])`` with ``e = idx[t, j]``
     (``activation``: ``relu`` or ``silu``).
@@ -223,16 +281,32 @@ def routed_experts(x, idx, weights, w_gate_up, w_down, *, use_pallas=None,
     ragged_dot); at 256 experts of 2048 x 768 and 8,192 pairs (32 rows an
     expert) the row tile of 128 still wins: 4.41 / 4.60 / 4.86 / 5.49 ms a
     layer at 128 / 64 / 32 / 16, and 1.7 ms at 192 pairs whatever the
-    tile."""
+    tile.
+
+    ``held`` (first id, count): the weights are those of the experts
+    ``first .. first + count - 1`` alone — this device's share of a layer
+    whose router still chooses among all of them.  A pair whose expert is
+    not held sorts behind every held one and lies outside every group of
+    the grouped products, which therefore never compute it; it adds
+    nothing to ``y``, and ``rows per expert`` [count] counts the pairs
+    computed here."""
     t, k = idx.shape
     num_experts, _, f2 = w_gate_up.shape
     f = f2 // 2
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
     flat = idx.reshape(-1)
+    if held is not None:
+        local = flat - held[0]
+        here = (local >= 0) & (local < num_experts)
+        flat = jnp.where(here, local, num_experts)
     order = jnp.argsort(flat)                   # pairs, expert by expert
     token = order // k
-    sizes = jnp.bincount(flat, length=num_experts).astype(jnp.int32)
+    if held is None:
+        sizes = jnp.bincount(flat, length=num_experts).astype(jnp.int32)
+    else:
+        sizes = jnp.bincount(flat, length=num_experts + 1
+                             )[:num_experts].astype(jnp.int32)
     xs = x[token]                               # [T·k, d]
 
     if use_pallas:
@@ -260,6 +334,9 @@ def routed_experts(x, idx, weights, w_gate_up, w_down, *, use_pallas=None,
     inverse = jnp.zeros_like(order).at[order].set(
         jnp.arange(t * k, dtype=order.dtype))
     y = y[inverse].reshape(t, k, -1)
+    if held is not None:
+        # a row no group covered holds whatever the product left there
+        y = jnp.where(here.reshape(t, k, 1), y, 0.0)
     return jnp.sum(y * weights[..., None], axis=1), sizes
 
 
@@ -354,6 +431,32 @@ class GroupedQueryAttention(nn.Module):
                           preferred_element_type=jnp.float32)
 
 
+def _carry_page(cache_index, block_table, page: int):
+    """[B] the page whose state entry is a row's carry: the one that holds
+    position ``cache_index - 1``."""
+    return jnp.take_along_axis(
+        block_table, (jnp.maximum(cache_index - 1, 0) // page)[:, None],
+        axis=1)[:, 0]
+
+
+def _entry_ends(last_pos, b: int, s: int, page: int):
+    """[B, n] the offset in a call of ``s`` tokens at which each of its
+    ``n`` pages' state entry is taken: the page's last token or the row's
+    last real one (``last_pos``; None: ``s - 1``), whichever is first."""
+    last = (jnp.full((b,), s - 1, jnp.int32) if last_pos is None
+            else last_pos.astype(jnp.int32))
+    return jnp.minimum(
+        (jnp.arange(max(s // page, 1), dtype=jnp.int32)[None, :] + 1)
+        * min(page, s) - 1, last[:, None])
+
+
+def _entry_pages(cache_index, ends, block_table, page: int):
+    """[B, n] the page ids those entries go to."""
+    where = jnp.minimum((cache_index[:, None] + ends) // page,
+                        block_table.shape[1] - 1)
+    return jnp.take_along_axis(block_table, where, axis=1)
+
+
 class ShortConv(nn.Module):
     """The double-gated short convolution: ``[B | C | z] = h W_in``
     (three blocks of ``d``); ``u = B * z``; ``c_t = sum_j w_j * u_{t - (L
@@ -404,15 +507,13 @@ class ShortConv(nn.Module):
             state = self.variable("cache", "conv_state", jnp.zeros,
                                   (self.kv_pool_pages, keep * d), self.dtype)
         if self.decode and not self.is_initializing():
-            page, m = self.kv_page_size, block_table.shape[1]
+            page = self.kv_page_size
             if s > 1 and s % page:
                 raise ValueError(
                     f"a call of {s} tokens is neither one token nor whole "
                     f"pages of {page}: a page it crossed would keep a "
                     f"stale state entry")
-            before = jnp.take_along_axis(
-                block_table, (jnp.maximum(cache_index - 1, 0) // page
-                              )[:, None], axis=1)[:, 0]
+            before = _carry_page(cache_index, block_table, page)
             carry = jnp.where((cache_index > 0)[:, None, None],
                               state.value[before].reshape(b, keep, d), carry)
         full = jnp.concatenate([carry, u], axis=1)          # [B, keep+S, d]
@@ -421,26 +522,193 @@ class ShortConv(nn.Module):
         if self.decode and not self.is_initializing():
             # the entry of every page of the call, taken at the page's
             # last token or at the row's last real one, whichever is first
-            n = max(s // page, 1)
-            last = (jnp.full((b,), s - 1, jnp.int32) if last_pos is None
-                    else last_pos.astype(jnp.int32))
-            ends = jnp.minimum(
-                (jnp.arange(n, dtype=jnp.int32)[None, :] + 1) * min(page, s)
-                - 1, last[:, None])                         # [B, n]
+            ends = _entry_ends(last_pos, b, s, page)
+            n = ends.shape[1]
             rows = ends[:, :, None] + 1 + jnp.arange(keep,
                                                      dtype=jnp.int32)
             entries = jnp.take_along_axis(
                 full, rows.reshape(b, n * keep)[:, :, None], axis=1
             ).reshape(b * n, keep * d)
-            where = jnp.minimum(
-                (cache_index[:, None] + ends) // page, m - 1)
-            pages = jnp.take_along_axis(block_table, where, axis=1)
+            pages = _entry_pages(cache_index, ends, block_table, page)
             state.value = state.value.at[pages.reshape(-1)].set(entries)
             advanced = jnp.sum(pages[:, 0] != 0, dtype=jnp.int32)
         y = jnp.einsum("bsd,dn->bsn",
                        (gate_c * c).astype(self.dtype),
                        w_out.astype(self.dtype),
                        preferred_element_type=jnp.float32)
+        return y, advanced
+
+
+def _log_uniform(low: float, high: float):
+    def init(key, shape, dtype=jnp.float32):
+        return jnp.log(jax.random.uniform(key, shape, jnp.float32, low,
+                                          high)).astype(dtype)
+    return init
+
+
+def _uniform(low: float, high: float):
+    def init(key, shape, dtype=jnp.float32):
+        return jax.random.uniform(key, shape, jnp.float32, low,
+                                  high).astype(dtype)
+    return init
+
+
+class LinearDelta(nn.Module):
+    """Delta-rule linear attention with a decay a channel
+    (``ops/linear_state.py``): ``heads`` heads of ``head_dim`` keys and
+    values, no positions, no pages of history — what a request carries
+    through the layer is a MATRIX a head and the last ``taps - 1`` inputs
+    of three short filters.
+
+    ``[q | k | v] = silu(conv(h W_qkv))``, ``conv`` a causal depthwise
+    filter of ``taps`` taps a channel, zeros before the sequence; a head's
+    ``q <- q / |q| * head_dim**-0.5``, ``k <- k / |k|``.  Log decay ``a =
+    decay_floor * sigmoid(exp(a_log) * (h W_decay + dt_bias))`` in
+    ``(decay_floor, 0)`` a channel, write strength ``beta = sigmoid(h
+    W_beta)`` a head; state and output as ``linear_state`` defines them;
+    then ``(RMSNorm_head(o) * sigmoid(h W_gate)) W_out``.
+
+    Matmuls take ``dtype`` inputs and accumulate in f32; the filters'
+    inputs are rounded to ``dtype`` before the taps in every form, because
+    that is what their state holds (as :class:`ShortConv`); the gates, the
+    L2 norms and the state's arithmetic are f32.
+
+    Decode mode: two leaves in the ``"cache"`` collection, each indexed by
+    page id and each holding, for page ``p``, the running state at the
+    newest token written in ``p`` — ``linear_state`` ``[P, heads, head_dim,
+    head_dim]`` (the matrices, transposed, in ``dtype``) and
+    ``conv_state`` ``[P, (taps - 1) * 3 * heads * head_dim]``.  One token
+    goes through ``linear_state_decode`` (the kernel) or its oracle, a
+    chunk of whole pages through the blocked form, with tokens past
+    ``last_pos`` made no-ops on the state (``beta`` = 0, ``a`` = 0).
+    Outside decode mode the token-by-token recurrence runs from zeros.
+    Returns (output, rows whose entries went to a page other than the
+    scratch page)."""
+    heads: int
+    head_dim: int
+    taps: int
+    decay_floor: float
+    rms_eps: float
+    dtype: Any
+    param_dtype: Any
+    use_pallas: Any = None
+    decode: bool = False
+    kv_page_size: Optional[int] = None
+    kv_pool_pages: Optional[int] = None
+
+    @nn.compact
+    def __call__(self, h, cache_index=None, block_table=None, last_pos=None):
+        b, s, d = h.shape
+        hn, dh, keep = self.heads, self.head_dim, self.taps - 1
+        n = hn * dh
+        pdt = self.param_dtype
+        w_qkv = self.param("qkv", _normal(0.02), (d, 3 * n), pdt)
+        w_decay = self.param("decay", _normal(0.02), (d, n), pdt)
+        w_gate = self.param("gate", _normal(0.02), (d, n), pdt)
+        w_beta = self.param("beta", _normal(0.02), (d, hn), pdt)
+        w_out = self.param("out", _normal(0.02), (n, d), pdt)
+        w = self.param("taps", _normal(0.3), (3 * n, self.taps),
+                       pdt).astype(jnp.float32)
+        # f32 whatever param_dtype: they meet f32 gates.  Time constants
+        # from a token to a few thousand, spread evenly in the logarithm
+        a_log = self.param("a_log", _log_uniform(1.0, 2.0), (hn,),
+                           jnp.float32)
+        dt_bias = self.param("dt_bias", _uniform(-8.0, 0.0), (n,),
+                             jnp.float32)
+        g_out = self.param("out_norm", nn.initializers.ones, (dh,), pdt)
+
+        def mm(x, w_):
+            return jnp.einsum("bsd,dn->bsn", x.astype(self.dtype),
+                              w_.astype(self.dtype),
+                              preferred_element_type=jnp.float32)
+        pre = mm(h, w_qkv).astype(self.dtype)
+        paged = self.decode and not self.is_initializing()
+        carry = jnp.zeros((b, keep, 3 * n), self.dtype)
+        if self.decode:
+            if self.kv_page_size is None:
+                raise ValueError("decode mode needs kv_page_size and "
+                                 "kv_pool_pages")
+            if cache_index is None or block_table is None:
+                raise ValueError("decode mode needs cache_index [B] "
+                                 "and block_table [B, M], both int32")
+            conv_state = self.variable(
+                "cache", "conv_state", jnp.zeros,
+                (self.kv_pool_pages, keep * 3 * n), self.dtype)
+            state = self.variable(
+                "cache", "linear_state", jnp.zeros,
+                (self.kv_pool_pages, hn, dh, dh), self.dtype)
+        if paged:
+            page = self.kv_page_size
+            if s > 1 and s % page:
+                raise ValueError(
+                    f"a call of {s} tokens is neither one token nor whole "
+                    f"pages of {page}: a page it crossed would keep a "
+                    f"stale state entry")
+            has_carry = (cache_index > 0)[:, None, None]
+            before = _carry_page(cache_index, block_table, page)
+            carry = jnp.where(
+                has_carry, conv_state.value[before].reshape(b, keep, 3 * n),
+                carry)
+        full = jnp.concatenate([carry, pre], axis=1)    # [B, keep+S, 3n]
+        qkv = jax.nn.silu(sum(w[:, j] * full[:, j:j + s].astype(jnp.float32)
+                              for j in range(self.taps)))
+        q, k, v = (qkv[..., i * n:(i + 1) * n].reshape(b, s, hn, dh)
+                   for i in range(3))
+
+        def unit(x):
+            return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True)
+                                     + 1e-6)
+        q, k = unit(q) * dh ** -0.5, unit(k)
+        a = self.decay_floor * jax.nn.sigmoid(
+            jnp.repeat(jnp.exp(a_log), dh) * (mm(h, w_decay) + dt_bias)
+        ).reshape(b, s, hn, dh)
+        beta = jax.nn.sigmoid(mm(h, w_beta))            # [B, S, H]
+        if paged and s > 1 and last_pos is not None:
+            real = (jnp.arange(s, dtype=jnp.int32)[None, :]
+                    <= last_pos[:, None])
+            a = jnp.where(real[..., None, None], a, 0.0)
+            beta = jnp.where(real[..., None], beta, 0.0)
+        advanced = jnp.zeros((), jnp.int32)
+        if not paged:
+            o, _ = linear_state.recurrent(q, k, v, a, beta)
+        else:
+            ends = _entry_ends(last_pos, b, s, page)
+            pages_n = ends.shape[1]
+            rows = ends[:, :, None] + 1 + jnp.arange(keep, dtype=jnp.int32)
+            entries = jnp.take_along_axis(
+                full, rows.reshape(b, pages_n * keep)[:, :, None], axis=1
+            ).reshape(b * pages_n, keep * 3 * n)
+            pages = _entry_pages(cache_index, ends, block_table, page)
+            conv_state.value = conv_state.value.at[pages.reshape(-1)].set(
+                entries)
+            advanced = jnp.sum(pages[:, 0] != 0, dtype=jnp.int32)
+            if s == 1:
+                use_pallas = self.use_pallas
+                if use_pallas is None:
+                    use_pallas = jax.default_backend() == "tpu"
+                one = (q[:, 0], k[:, 0], v[:, 0], a[:, 0], beta[:, 0],
+                       block_table, cache_index)
+                if use_pallas:
+                    o, state.value = linear_state.linear_state_decode(
+                        state.value, *one, page_size=page,
+                        interpret=use_pallas == "interpret")
+                else:
+                    o, state.value = linear_state.paged_step(
+                        state.value, *one, page_size=page)
+                o = o[:, None]
+            else:
+                start = jnp.where(has_carry[..., None],
+                                  state.value[before].astype(jnp.float32),
+                                  0.0)
+                o, states = linear_state.chunked(
+                    q, k, v, a, beta, start,
+                    block=math.gcd(page, linear_state.BLOCK),
+                    emit_every=page)
+                state.value = state.value.at[pages.reshape(-1)].set(
+                    states.reshape((b * pages_n,) + states.shape[2:]
+                                   ).astype(state.value.dtype))
+        o = rms_norm(o, g_out, self.rms_eps).reshape(b, s, n)
+        y = mm(o * jax.nn.sigmoid(mm(h, w_gate)), w_out)
         return y, advanced
 
 
@@ -469,9 +737,17 @@ class LatentAttention(nn.Module):
     attended sum — equal in exact arithmetic, 32 heads over one cached
     row, ``kv_b`` used as held.  Outside decode mode (tests, the toy's
     teacher-forced forward) K and V of every token are expanded from the
-    definition."""
+    definition.
+
+    ``q_lora_rank`` None: the queries come from ``h`` directly (one
+    projection ``q``, no query latent and no norm of one).
+    ``q_head_norm``: RMSNorm over the ``nope + rope`` values of each query
+    head, one learned scale, before the rotation (the key side's norm is
+    ``kv_norm``: a norm on expanded keys would forbid the absorbed form).
+    ``head_gate``: a head's attended output is scaled by ``sigmoid(h
+    W_gate)`` of that head before ``out``."""
     num_heads: int
-    q_lora_rank: int
+    q_lora_rank: Optional[int]
     kv_lora_rank: int
     qk_nope_head_dim: int
     qk_rope_head_dim: int
@@ -485,6 +761,8 @@ class LatentAttention(nn.Module):
     decode: bool = False
     kv_page_size: Optional[int] = None
     kv_pool_pages: Optional[int] = None
+    q_head_norm: bool = False
+    head_gate: bool = False
 
     @nn.compact
     def __call__(self, h, positions, cache_index=None, block_table=None,
@@ -494,9 +772,13 @@ class LatentAttention(nn.Module):
         dn, dr, dv = (self.qk_nope_head_dim, self.qk_rope_head_dim,
                       self.v_head_dim)
         ones, pdt = nn.initializers.ones, self.param_dtype
-        w_qa = self.param("q_a", _normal(0.02), (d, rq), pdt)
-        g_q = self.param("q_norm", ones, (rq,), pdt)
-        w_qb = self.param("q_b", _normal(0.02), (rq, hq * (dn + dr)), pdt)
+        if rq is None:
+            w_q = self.param("q", _normal(0.02), (d, hq * (dn + dr)), pdt)
+        else:
+            w_qa = self.param("q_a", _normal(0.02), (d, rq), pdt)
+            g_q = self.param("q_norm", ones, (rq,), pdt)
+            w_qb = self.param("q_b", _normal(0.02), (rq, hq * (dn + dr)),
+                              pdt)
         w_kva = self.param("kv_a", _normal(0.02), (d, r + dr), pdt)
         g_kv = self.param("kv_norm", ones, (r,), pdt)
         w_kvb = self.param("kv_b", _normal(0.02), (r, hq * (dn + dv)), pdt)
@@ -507,8 +789,14 @@ class LatentAttention(nn.Module):
             return jnp.einsum(spec, x.astype(self.dtype),
                               w.astype(self.dtype),
                               preferred_element_type=jnp.float32)
-        c_q = rms_norm(mm("bsd,dr->bsr", h, w_qa), g_q, self.rms_eps)
-        q = mm("bsr,rn->bsn", c_q, w_qb).reshape(b, s, hq, dn + dr)
+        if rq is None:
+            q = mm("bsd,dn->bsn", h, w_q).reshape(b, s, hq, dn + dr)
+        else:
+            c_q = rms_norm(mm("bsd,dr->bsr", h, w_qa), g_q, self.rms_eps)
+            q = mm("bsr,rn->bsn", c_q, w_qb).reshape(b, s, hq, dn + dr)
+        if self.q_head_norm:
+            q = rms_norm(q, self.param("q_head_norm", ones, (dn + dr,), pdt),
+                         self.rms_eps)
         q_nope = q[..., :dn].astype(self.dtype)
         q_rope = rope(q[..., dn:], positions, self.rope_theta
                       ).astype(self.dtype)
@@ -551,6 +839,11 @@ class LatentAttention(nn.Module):
             o = cached_attention(
                 jnp.concatenate([q_nope, q_rope], -1), k, kv_all[..., dn:],
                 jnp.broadcast_to(i[None, :] <= i[:, None], (b, s, s)))
+        if self.head_gate:
+            gate = jax.nn.sigmoid(mm(
+                "bsd,dh->bsh", h, self.param("gate", _normal(0.02), (d, hq),
+                                             pdt)))
+            o = (o * gate[..., None]).astype(self.dtype)
         return mm("bsn,nd->bsd", o.reshape(b, s, hq * dv), w_out)
 
 
@@ -585,24 +878,31 @@ class RoutedBlock(nn.Module):
     conv_taps: int = 3
     qk_norm: bool = False
     routing_sum_eps: float = 0.0
+    linear: Optional[Tuple] = None      # (heads, head dim, taps, decay floor)
+    q_head_norm: bool = False
+    attention_head_gate: bool = False
+    route_groups: int = 1
+    route_groups_kept: int = 1
+    experts_held: Optional[Tuple[int, int]] = None      # (first id, count)
 
     @nn.compact
     def __call__(self, x, positions, cache_index=None, block_table=None,
                  flash_prefill: bool = False,
                  window_pages: Optional[int] = None, last_pos=None):
-        """-> (x, rows an expert [E] or None for a dense layer, state rows
-        advanced or None for an attention layer)."""
+        """-> (x, rows an expert held here [E] or None for a dense layer,
+        state rows advanced or None for an attention layer)."""
         b, s, d = x.shape
         e, f = self.num_experts, self.expert_width
+        held = (e if self.experts_held is None else self.experts_held[1])
         ones, pdt = nn.initializers.ones, self.param_dtype
         g1 = self.param("norm1", ones, (d,), pdt)
         g2 = self.param("norm2", ones, (d,), pdt)
         routed = self.dense_width is None
         if routed:
             w_router = self.param("router", _normal(0.02), (d, e), pdt)
-            w_gate_up = self.param("gate_up", _normal(0.02), (e, d, 2 * f),
-                                   pdt)
-            w_down = self.param("down", _normal(0.02), (e, f, d), pdt)
+            w_gate_up = self.param("gate_up", _normal(0.02),
+                                   (held, d, 2 * f), pdt)
+            w_down = self.param("down", _normal(0.02), (held, f, d), pdt)
         if self.routing not in ("softmax_topk", "sigmoid_bias"):
             raise ValueError(f"routing {self.routing!r}: softmax_topk or "
                              f"sigmoid_bias")
@@ -616,7 +916,8 @@ class RoutedBlock(nn.Module):
         def choose(hh):
             return route(hh.reshape(b * s, d), w_router,
                          self.experts_per_token, score_bias,
-                         self.routed_scale, self.routing_sum_eps)
+                         self.routed_scale, self.routing_sum_eps,
+                         self.route_groups, self.route_groups_kept)
         h = rms_norm(x, g1, self.rms_eps)
         if routed and self.router_input == "pre_attention":
             idx, weights = choose(h)
@@ -626,6 +927,13 @@ class RoutedBlock(nn.Module):
                 self.conv_taps, self.dtype, pdt, decode=self.decode,
                 kv_page_size=self.kv_page_size,
                 kv_pool_pages=self.kv_pool_pages, name="conv")(
+                    h, cache_index, block_table, last_pos)
+        elif self.mixer == "linear_delta":
+            attn, advanced = LinearDelta(
+                *self.linear, self.rms_eps, self.dtype, pdt,
+                use_pallas=self.use_pallas, decode=self.decode,
+                kv_page_size=self.kv_page_size,
+                kv_pool_pages=self.kv_pool_pages, name="linear")(
                     h, cache_index, block_table, last_pos)
         elif self.latent is None:
             attn = GroupedQueryAttention(
@@ -644,7 +952,9 @@ class RoutedBlock(nn.Module):
                 self.rope_interleave, self.rms_eps, self.dtype, pdt,
                 use_pallas=self.use_pallas, decode=self.decode,
                 kv_page_size=self.kv_page_size,
-                kv_pool_pages=self.kv_pool_pages, name="attn")(
+                kv_pool_pages=self.kv_pool_pages,
+                q_head_norm=self.q_head_norm,
+                head_gate=self.attention_head_gate, name="attn")(
                     h, positions, cache_index, block_table, window_pages)
         x = x + attn
         h2 = rms_norm(x, g2, self.rms_eps).reshape(b * s, d)
@@ -663,7 +973,8 @@ class RoutedBlock(nn.Module):
                                   w_gate_up.astype(self.dtype),
                                   w_down.astype(self.dtype),
                                   use_pallas=self.use_pallas,
-                                  activation=self.activation)
+                                  activation=self.activation,
+                                  held=self.experts_held)
         if self.shared_expert_width:
             fs = self.shared_expert_width
             y = y + gated_mlp(
@@ -700,7 +1011,7 @@ class RoutedDecoderLM(nn.Module):
     max_seq_len: int = 2048
     # attention kind.  kv_lora_rank None: whole heads (num_kv_heads x
     # head_dim, the two layer tuples above).  Set: latent attention
-    # (LatentAttention) in every layer, whole history, rotary on the
+    # (LatentAttention) in every attention layer, whole history, rotary on the
     # qk_rope_head_dim part only; num_kv_heads, head_dim, window and the
     # layer tuples then size nothing
     q_lora_rank: Optional[int] = None
@@ -725,13 +1036,35 @@ class RoutedDecoderLM(nn.Module):
     router_input: str = "pre_attention"
     routing_sum_eps: float = 0.0        # added to the chosen scores' sum
     # mixer kind, one entry a layer (shorter tuples repeat): attention |
-    # short_conv (ShortConv, conv_taps taps; whole heads only).  qk_norm:
+    # short_conv (ShortConv, conv_taps taps; whole heads only) |
+    # linear_delta (LinearDelta, the fields below).  qk_norm:
     # RMSNorm a head of q and of k before the rotation.  tie_head: the
     # head is the embedding
     layer_mixer: Tuple[str, ...] = ("attention",)
     conv_taps: int = 3
     qk_norm: bool = False
     tie_head: bool = False
+    # linear_delta layers (LinearDelta): heads of head_dim keys and values,
+    # three short filters of linear_conv_taps, log decays in
+    # (linear_decay_floor, 0); the matrices are stored in dtype.  They go
+    # with either attention kind
+    linear_heads: int = 4
+    linear_head_dim: int = 64
+    linear_conv_taps: int = 4
+    linear_decay_floor: float = -5.0
+    # latent attention's options: q_lora_rank None (above) projects the
+    # queries directly; q_head_norm: RMSNorm a query head before the
+    # rotation; attention_head_gate: a sigmoid gate a head on the output
+    q_head_norm: bool = False
+    attention_head_gate: bool = False
+    # sigmoid_bias routing's group limit: the experts are route_groups runs
+    # of consecutive ids, a token chooses within its route_groups_kept best
+    route_groups: int = 1
+    route_groups_kept: int = 1
+    # (first id, count): the experts this device holds of the num_experts
+    # the router chooses among (None: all).  The others' part of a layer's
+    # sum is left out; nothing here stands in for it
+    experts_held: Optional[Tuple[int, int]] = None
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
     use_pallas: Any = None
@@ -745,9 +1078,10 @@ class RoutedDecoderLM(nn.Module):
     @property
     def stats_names(self):
         """What ``"stats"/"counts"`` holds, in order."""
-        if self.carries_state:
-            return STATE_STATS
-        return STATS if self.kv_lora_rank is None else LATENT_STATS
+        names = STATS if self.kv_lora_rank is None else LATENT_STATS
+        if "linear_delta" in self.layer_mixers():
+            return names + LINEAR_STATS
+        return STATE_STATS if self.carries_state else names
 
     def layer_mixers(self):
         """The mixer kind of every layer."""
@@ -757,11 +1091,11 @@ class RoutedDecoderLM(nn.Module):
     @property
     def carries_state(self) -> bool:
         """Whether the cache holds running state beside pages of history
-        (a short-convolution layer): a state entry is already past its
-        page's newest token, so that token cannot be replayed on a copy of
-        the page, and a chunk's call has to be told its real length
-        (``last_pos``)."""
-        return "short_conv" in self.layer_mixers()
+        (a short-convolution or a linear_delta layer): a state entry is
+        already past its page's newest token, so that token cannot be
+        replayed on a copy of the page, and a chunk's call has to be told
+        its real length (``last_pos``)."""
+        return bool(set(self.layer_mixers()) - {"attention"})
 
     def layer_kinds(self):
         """[(window or None, rope_theta or None)] a layer."""
@@ -787,9 +1121,14 @@ class RoutedDecoderLM(nn.Module):
         if set(mixers) - set(MIXERS):
             raise ValueError(f"layer_mixer {self.layer_mixer!r}: each one of "
                              f"{MIXERS}")
-        if self.carries_state and self.kv_lora_rank is not None:
+        if "short_conv" in mixers and self.kv_lora_rank is not None:
             raise ValueError("short_conv layers go with whole heads, not "
                              "with the latent cache")
+        if self.experts_held is not None and (
+                self.experts_held[0] < 0 or self.experts_held[1] < 1
+                or sum(self.experts_held) > self.num_experts):
+            raise ValueError(f"experts_held {self.experts_held!r} is no "
+                             f"block of the {self.num_experts} experts")
         b, s = tokens.shape
         pdt = jnp.dtype(self.param_dtype)
         embed = self.param("embed", _normal(0.02),
@@ -809,7 +1148,9 @@ class RoutedDecoderLM(nn.Module):
                       self.qk_nope_head_dim, self.qk_rope_head_dim,
                       self.v_head_dim)
         n_routed = len(kinds) - self.num_dense_layers
-        touched = load_max = advanced = jnp.zeros((), jnp.int32)
+        touched = load_max = advanced = computed = jnp.zeros((), jnp.int32)
+        linear = (self.linear_heads, self.linear_head_dim,
+                  self.linear_conv_taps, self.linear_decay_floor)
         for i, (window, theta) in enumerate(kinds):
             x, sizes, rows = RoutedBlock(
                 self.num_heads, self.num_kv_heads, self.head_dim,
@@ -827,12 +1168,19 @@ class RoutedDecoderLM(nn.Module):
                 activation=self.activation, router_input=self.router_input,
                 mixer=mixers[i], conv_taps=self.conv_taps,
                 qk_norm=self.qk_norm,
-                routing_sum_eps=self.routing_sum_eps, name=f"layer{i}")(
+                routing_sum_eps=self.routing_sum_eps, linear=linear,
+                q_head_norm=self.q_head_norm,
+                attention_head_gate=self.attention_head_gate,
+                route_groups=self.route_groups,
+                route_groups_kept=self.route_groups_kept,
+                experts_held=self.experts_held, name=f"layer{i}")(
                     x, positions, cache_index, block_table, flash_prefill,
                     window_pages, last_pos)
             if sizes is not None:
                 touched += jnp.sum(sizes > 0, dtype=jnp.int32)
                 load_max += jnp.max(sizes)
+                if self.experts_held is not None:
+                    computed += jnp.sum(sizes, dtype=jnp.int32)
             if rows is not None:
                 advanced += rows
         # what the attention of this call has to read of the cache: a
@@ -841,9 +1189,18 @@ class RoutedDecoderLM(nn.Module):
         live = positions[:, -1] + 1
         assignments = jnp.asarray(
             b * s * self.experts_per_token * n_routed, jnp.int32)
+        if self.experts_held is not None:
+            assignments = computed      # the pairs computed HERE
+        n_state = len(kinds) - mixers.count("attention")
         if latent is not None:
-            counts = jnp.stack([assignments, touched, load_max,
-                                len(kinds) * jnp.sum(live)])
+            counts = [assignments, touched, load_max,
+                      mixers.count("attention") * jnp.sum(live)]
+            if n_state:
+                # the tokens of the call that are not tail padding
+                real = (b * s if last_pos is None
+                        else jnp.sum(last_pos.astype(jnp.int32) + 1))
+                counts += [real * n_state, advanced]
+            counts = jnp.stack(counts)
         else:
             attends = [w for (w, _), m in zip(kinds, mixers)
                        if m == "attention"]

@@ -1,0 +1,275 @@
+"""Delta-rule linear attention with a decay a channel: the state of a head
+is a MATRIX, rewritten by every token.
+
+For one head, keys and queries of ``dk`` channels and values of ``dv``,
+``S_0 = 0``:
+
+    S_t = (I - beta_t k_t k_t^T) Diag(exp(a_t)) S_{t-1} + beta_t k_t v_t^T
+    o_t = S_t^T q_t
+
+with ``a_t`` [dk] <= 0 the log of the decay a channel and ``beta_t`` in
+[0, 1] the write strength.  A token with ``beta = 0`` and ``a = 0`` leaves
+the state as it was: that is how a tail-padded chunk pads.
+
+Every form below keeps the state TRANSPOSED, ``M = S^T`` ``[..., dv, dk]``:
+the decay scales the key channel, which is then the lane axis, so it
+broadcasts over rows and the kernel needs no transpose.  All arithmetic is
+float32 at full matmul precision; a pool stores ``M`` in its own dtype.
+
+Three forms, the same numbers (``tests/test_linear_state.py``):
+
+``recurrent``   the definition, a ``lax.scan`` over tokens;
+``chunked``     blocks of ``block`` tokens: inside a block the tokens meet
+                through ``[block, block]`` matrices, between blocks the
+                state is carried — a prefill chunk's form.  ``exp`` of a
+                block's summed log decay is taken relative to the block's
+                own middle, so that at a floor of -5 a token a block of 16
+                stays inside float32 (``exp(+-40)``) in both directions;
+``step``        one token; :func:`paged_step` runs it through a pool of
+                state entries indexed by page id, and
+                :func:`linear_state_decode` is that as a kernel.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+_HI = jax.lax.Precision.HIGHEST
+BLOCK = 16      # tokens a block of the chunked form: 16 x 5 = 80 < 87
+
+
+def step(state, q, k, v, a, beta):
+    """One token.  state [..., dv, dk] f32; q, k, a [..., dk]; v [..., dv];
+    beta [...].  Returns (o [..., dv], new state)."""
+    md = state * jnp.exp(a)[..., None, :]
+    u = jnp.einsum("...vk,...k->...v", md, k, precision=_HI)
+    new = md + (beta[..., None] * (v - u))[..., None] * k[..., None, :]
+    return jnp.einsum("...vk,...k->...v", new, q, precision=_HI), new
+
+
+def recurrent(q, k, v, a, beta, state=None):
+    """The definition.  q, k, a [B, S, H, dk]; v [B, S, H, dv]; beta
+    [B, S, H]; state [B, H, dv, dk] (None: zeros).  Returns (o
+    [B, S, H, dv], the state after the last token)."""
+    b, _, h, dk = q.shape
+    if state is None:
+        state = jnp.zeros((b, h, v.shape[-1], dk), jnp.float32)
+
+    def one(m, xs):
+        o, m = step(m, *xs)
+        return m, o
+    state, o = jax.lax.scan(
+        one, state, tuple(jnp.moveaxis(x.astype(jnp.float32), 1, 0)
+                          for x in (q, k, v, a, beta)))
+    return jnp.moveaxis(o, 0, 1), state
+
+
+def _unit_lower_inverse(low):
+    """``(I + low)^-1`` for strictly lower-triangular ``low`` [..., C, C]:
+    ``low`` is nilpotent, so the inverse is the finite product ``(I - L)(I
+    + L^2)(I + L^4)...`` — matrix products only."""
+    c = low.shape[-1]
+    eye = jnp.eye(c, dtype=low.dtype)
+    inv, power, n = eye - low, low, 2
+    while n < c:
+        power = jnp.matmul(power, power, precision=_HI)
+        inv = jnp.matmul(inv, eye + power, precision=_HI)
+        n *= 2
+    return inv
+
+
+def chunked(q, k, v, a, beta, state=None, *, block: int = BLOCK,
+            emit_every: int = 0):
+    """The blocked form of :func:`recurrent` for ``S`` a multiple of
+    ``block``.  ``emit_every`` (tokens, a multiple of ``block`` that
+    divides S; 0: S) also returns the state after every that many tokens.
+    Returns (o [B, S, H, dv], states [B, S // emit_every, H, dv, dk]); the
+    last of ``states`` is the state after the call."""
+    b, s, h, dk = q.shape
+    dv = v.shape[-1]
+    emit_every = emit_every or s
+    if s % block or emit_every % block or s % emit_every:
+        raise ValueError(f"{s} tokens in blocks of {block}, states every "
+                         f"{emit_every}")
+    n, c = s // block, block
+    if state is None:
+        state = jnp.zeros((b, h, dv, dk), jnp.float32)
+
+    def blocks(x):      # [B, S, H, ...] -> [N, B, H, C, ...]
+        x = x.astype(jnp.float32).reshape((b, n, c, h) + x.shape[3:])
+        return jnp.moveaxis(jnp.moveaxis(x, 3, 2), 1, 0)
+    q, k, v, a, beta = (blocks(x) for x in (q, k, v, a, beta))
+    run = jnp.cumsum(a, axis=3)                     # log decay, block start
+    mid = run - run[:, :, :, c // 2:c // 2 + 1]     # ... from the middle
+    k_up, k_down = k * jnp.exp(mid), k * jnp.exp(-mid)
+    lower = jnp.tril(jnp.ones((c, c), jnp.float32), -1)
+    meet = jnp.einsum("nbhik,nbhjk->nbhij", k_up, k_down, precision=_HI)
+    inv = _unit_lower_inverse(beta[..., None] * meet * lower)
+    gamma = jnp.exp(run)
+    w = jnp.matmul(inv, beta[..., None] * k * gamma, precision=_HI)
+    u0 = jnp.matmul(inv, beta[..., None] * v, precision=_HI)
+    seen = jnp.einsum("nbhik,nbhjk->nbhij", q * jnp.exp(mid), k_down,
+                      precision=_HI) * (lower + jnp.eye(c))
+    q_in = q * gamma
+    total = run[:, :, :, -1]                        # [N, B, H, dk]
+    k_out = k * jnp.exp(total[:, :, :, None] - run)
+
+    def one(m, xs):
+        w_, u0_, seen_, q_, k_, total_ = xs
+        u = u0_ - jnp.einsum("bhck,bhvk->bhcv", w_, m, precision=_HI)
+        o = (jnp.einsum("bhck,bhvk->bhcv", q_, m, precision=_HI)
+             + jnp.matmul(seen_, u, precision=_HI))
+        m = (m * jnp.exp(total_)[:, :, None, :]
+             + jnp.einsum("bhcv,bhck->bhvk", u, k_, precision=_HI))
+        return m, o
+
+    def page(m, xs):
+        m, o = jax.lax.scan(one, m, xs)
+        return m, (o, m)
+    per = emit_every // block
+    _, (o, states) = jax.lax.scan(
+        page, state, tuple(x.reshape((n // per, per) + x.shape[1:])
+                           for x in (w, u0, seen, q_in, k_out, total)))
+    o = o.reshape((n,) + o.shape[2:])               # [N, B, H, C, dv]
+    o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3).reshape(b, s, h, dv)
+    return o, jnp.moveaxis(states, 0, 1)
+
+
+def _pages(block_table, index, page_size: int):
+    """(page a row's carry is read from, page its entry goes to) for a
+    token at ``index`` [B]: the page of the position before it, and its
+    own — the same page except at a boundary."""
+    m = block_table.shape[1]
+    src = jnp.take_along_axis(
+        block_table, (jnp.maximum(index - 1, 0) // page_size)[:, None], 1)
+    dst = jnp.take_along_axis(
+        block_table, jnp.minimum(index // page_size, m - 1)[:, None], 1)
+    return src[:, 0], dst[:, 0]
+
+
+def paged_step(pool, q, k, v, a, beta, block_table, index, *,
+               page_size: int):
+    """One token a row through a pool of state entries ``[P, H, dv, dk]``
+    whose entry for page ``p`` is the state at the newest token written in
+    ``p``: a row's carry is the entry of the page that holds ``index - 1``
+    (zeros at ``index`` 0), its new state goes to the page that holds
+    ``index``.  q, k, a [B, H, dk]; v [B, H, dv]; beta [B, H].  Returns (o
+    [B, H, dv] f32, the pool).  The oracle of
+    :func:`linear_state_decode`."""
+    src, dst = _pages(block_table, index, page_size)
+    carry = jnp.where((index > 0)[:, None, None, None],
+                      pool[src].astype(jnp.float32), 0.0)
+    o, new = step(carry, *(x.astype(jnp.float32)
+                           for x in (q, k, v, a, beta)))
+    return o, pool.at[dst].set(new.astype(pool.dtype))
+
+
+def _decode_kernel(tbl_ref, idx_ref, q_ref, k_ref, kb_ref, alpha_ref, vt_ref,
+                   pool_hbm, ot_ref, pool_out, sbuf, obuf, sem_in, sem_out,
+                   *, page_size: int):
+    """Grid (B,): row ``b``'s state — every head's ``[dv, dk]`` entry, one
+    contiguous block of its page — is copied in, advanced one token and
+    copied out to the page that holds ``index``.  Row ``b + 1``'s copy-in
+    is started before row ``b``'s arithmetic and row ``b``'s copy-out is
+    waited for two rows later, so the copies' latency hides behind the
+    neighbours' work: the kernel's floor is the bytes of the state, read
+    once and written once."""
+    b, rows = pl.program_id(0), pl.num_programs(0)
+    m_pages = tbl_ref.shape[1]
+    heads = sbuf.shape[1]
+    slot = b % 2
+
+    def fetch(r, s):
+        page = tbl_ref[r, jnp.maximum(idx_ref[r] - 1, 0) // page_size]
+        return pltpu.make_async_copy(pool_hbm.at[page], sbuf.at[s],
+                                     sem_in.at[s])
+
+    def store(r, s):
+        page = tbl_ref[r, jnp.minimum(idx_ref[r] // page_size, m_pages - 1)]
+        return pltpu.make_async_copy(obuf.at[s], pool_out.at[page],
+                                     sem_out.at[s])
+
+    @pl.when(b == 0)
+    def _first():
+        fetch(0, 0).start()
+
+    @pl.when(b + 1 < rows)
+    def _next():
+        fetch(b + 1, 1 - slot).start()
+
+    fetch(b, slot).wait()
+
+    @pl.when(b >= 2)
+    def _reuse():
+        store(b - 2, slot).wait()
+
+    live = idx_ref[b] > 0
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, heads), 1)
+
+    def head(h, ot):
+        row = pl.ds(h, 1)
+        m = jnp.where(live, sbuf[slot, h].astype(jnp.float32), 0.0)
+        md = m * alpha_ref[row, :]
+        u = jnp.sum(md * k_ref[row, :], axis=1, keepdims=True)
+        mine = lane == h
+        v = jnp.sum(jnp.where(mine, vt_ref[...], 0.0), axis=1,
+                    keepdims=True)
+        new = md + (v - u) * kb_ref[row, :]
+        obuf[slot, h] = new.astype(obuf.dtype)
+        o = jnp.sum(new * q_ref[row, :], axis=1, keepdims=True)
+        return jnp.where(mine, o, ot)
+    ot_ref[...] = jax.lax.fori_loop(
+        0, heads, head, jnp.zeros(ot_ref.shape, jnp.float32))
+    store(b, slot).start()
+
+    @pl.when(b == rows - 1)
+    def _drain():
+        store(b, slot).wait()
+
+        @pl.when(b >= 1)
+        def _():
+            store(b - 1, 1 - slot).wait()
+
+
+@functools.partial(jax.jit, static_argnames=("page_size", "interpret"))
+def linear_state_decode(pool, q, k, v, a, beta, block_table, index, *,
+                        page_size: int, interpret: bool = False):
+    """:func:`paged_step` as a kernel, the pool updated IN PLACE (it is
+    aliased to the result; the serving body donates its cache).  Rows with an all-zero table read
+    and write the scratch page 0.  Jitted, so that the layers of a model
+    share one lowering."""
+    b, h, dk = q.shape
+    dv = v.shape[-1]
+    f32 = jnp.float32
+    q, k, v, a, beta = (x.astype(f32) for x in (q, k, v, a, beta))
+
+    def rows(lanes):
+        return pl.BlockSpec((None, h, lanes), lambda r, tbl, idx: (r, 0, 0))
+    vt_spec = pl.BlockSpec((None, dv, h), lambda r, tbl, idx: (r, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(b,),
+        in_specs=[rows(dk), rows(dk), rows(dk), rows(dk), vt_spec,
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=[vt_spec, pl.BlockSpec(memory_space=pl.ANY)],
+        scratch_shapes=[pltpu.VMEM((2, h, dv, dk), pool.dtype),
+                        pltpu.VMEM((2, h, dv, dk), pool.dtype),
+                        pltpu.SemaphoreType.DMA((2,)),
+                        pltpu.SemaphoreType.DMA((2,))])
+    ot, pool = pl.pallas_call(
+        functools.partial(_decode_kernel, page_size=page_size),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((b, dv, h), f32),
+                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
+        input_output_aliases={7: 1}, interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=32 * 2 ** 20),
+        name="linear_state_decode",
+    )(jnp.asarray(block_table, jnp.int32), jnp.asarray(index, jnp.int32),
+      q, k, beta[..., None] * k, jnp.exp(a), jnp.swapaxes(v, 1, 2), pool)
+    return jnp.swapaxes(ot, 1, 2), pool

@@ -586,8 +586,13 @@ def _latent_flash_decode(q, pool, block_table, index, *, scale, interpret,
     rows = hq * s
     while rows > _LATENT_ROWS and rows % 16 == 0:
         rows //= 2
-    row_blocks = hq * s // rows
     block_tokens = min(_LATENT_BLOCK_TOKENS, _LATENT_SCORE_ELEMS // rows)
+    # a page is copied whole: one longer than the block IS the block, and
+    # the rows a grid point holds give way to keep the score tile
+    if page_size > block_tokens:
+        while page_size * rows > _LATENT_SCORE_ELEMS and rows % 16 == 0:
+            rows //= 2
+    row_blocks = hq * s // rows
     ppb = 1
     while ppb * 2 <= m_pages and ppb * 2 * page_size <= block_tokens:
         ppb *= 2

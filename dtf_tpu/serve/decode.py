@@ -16,8 +16,8 @@ around it:
 
 The cache is a shared pool per layer — [pool_pages, page_size, H, Dh]
 for K and for V (or one of ``[k | v]`` rows), one [pool_pages, page_size,
-W] of latent rows, or one [pool_pages, W] of state entries a page, as the
-model's layers store it — plus per-slot block tables
+W] of latent rows, or one [pool_pages, ...] of state entries a page (a row
+of filter inputs, a matrix a head), as the model's layers store it — plus per-slot block tables
 (ops.paged_attention); this module treats it as a tree of leaves whose
 first axis is the page.  Prefill runs in
 page-aligned chunks: the FIRST chunk goes through the flash kernel
@@ -96,6 +96,10 @@ CACHE_LEAF_KINDS = {
     "paged_kv": KV_POOL,            # [P, page, H, 2 * Dh]: rows of [k | v]
     "paged_latent": LATENT_POOL,    # [P, page, W]: one row a token
     "conv_state": PAGE_STATE,       # [P, W]: one running entry a page
+    # [P, H, Dv, Dk]: a linear-attention layer's matrix a head, one entry a
+    # page like conv_state — copied, read, written and counted by its first
+    # axis alone, whatever its rank
+    "linear_state": PAGE_STATE,
 }
 
 
@@ -146,8 +150,9 @@ def trace_paged_init(model, kv_page_size: int, kv_pool_pages: int):
 
 def state_bytes_per_page(cache_shapes) -> int:
     """Bytes of running state a page carries beside its tokens: the
-    cache's ``PAGE_STATE`` leaves (a short-convolution layer's entry a
-    page; 0 for a cache of pools alone)."""
+    cache's ``PAGE_STATE`` leaves (a short-convolution layer's filter
+    inputs, a linear-attention layer's matrices; 0 for a cache of pools
+    alone)."""
     return sum(math.prod(p.shape[1:]) * jnp.dtype(p.dtype).itemsize
                for _, p in cache_leaves(cache_shapes, PAGE_STATE))
 

@@ -415,6 +415,82 @@ def test_state_carrying_serve_bodies_compile_for_v5e(v5e, body):
     assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_linear_state_decode_compiles_for_v5e(v5e, dtype):
+    """The state kernel at the benchmark's shapes: 96 rows of 32 heads of
+    128 x 128 over a pool of 385 pages (0.40e9 B in bfloat16), the pool
+    aliased to the result — one pool in, none made."""
+    from dtf_tpu.ops import linear_state
+    f32, i32 = jnp.float32, jnp.int32
+    b, h, d, pool, m = 96, 32, 128, 385, 12
+    shapes = (((pool, h, d, d), dtype),) + (((b, h, d), f32),) * 4 + (
+        ((b, h), f32), ((b, m), i32), ((b,), i32))
+    args = [jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+            for shape, dt in shapes]
+    compiled = jax.jit(
+        functools.partial(linear_state.linear_state_decode, page_size=1024),
+        donate_argnums=(0,)).lower(*args).compile()
+    assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e6
+
+
+@pytest.mark.parametrize("body", ["chunk_first", "chunk", "decode"])
+def test_linear_state_serve_bodies_compile_for_v5e(v5e, body):
+    """The bodies of the decoder whose layers are delta-rule linear
+    attention beside one latent layer in six, at its benchmark widths (8
+    layers: 7 with a matrix entry a page, 1 with a latent pool; 512
+    experts routed of which 128 are held; 39,296 vocabulary rows; shapes
+    only), 96 slots of 12,288 tokens, PAGES OF 1,024 in a 393,216-token
+    pool: a chunk of one page — the latent kernel's block of tokens is a
+    whole page, so the rows a grid point holds give way — and the decode
+    step through ``linear_state_decode`` in seven layers, every pool and
+    state leaf updated in place."""
+    from dtf_tpu.models import build_model
+    from dtf_tpu.serve import decode as sd
+    i32, f32 = jnp.int32, jnp.float32
+    model, _ = build_model(
+        "routed_decoder", num_classes=39296, dtype=jnp.bfloat16,
+        num_layers=8, d_model=2560, num_heads=32,
+        layer_mixer=["linear_delta"] * 5 + ["attention"]
+        + ["linear_delta"] * 2, linear_heads=32, linear_head_dim=128,
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, q_head_norm=True, attention_head_gate=True,
+        rope_theta=6e6, num_dense_layers=2, dense_width=6144,
+        num_experts=512, experts_per_token=8, expert_width=768,
+        shared_expert_width=768, routing="sigmoid_bias", routed_scale=2.5,
+        route_groups=8, route_groups_kept=4, experts_held=[0, 128],
+        activation="silu", router_input="post_attention",
+        max_seq_len=131072, param_dtype="bfloat16")
+    params = jax.eval_shape(model.clone(use_pallas=False).init,
+                            jax.random.key(0), jnp.zeros((1, 1024), i32)
+                            )["params"]
+    dec = _shapes_only_decoder(model, params, num_slots=96,
+                               max_seq_len=12288, kv_page_size=1024,
+                               kv_pool_pages=385)
+    assert dec.carries_state and not dec.decode_all_heads
+    assert dec.state_bytes_per_page == 7 * (32 * 128 * 128 + 9 * 4096) * 2
+    if body == "decode":
+        compiled = _compile_decode_body(dec, v5e)
+    else:
+        s, m = jax.ShapeDtypeStruct, dec.pages_per_slot
+        args = _on_chip((dec.params, jax.eval_shape(dec.fresh_cache),
+                         s((1, 1024), i32), s((1, m), i32), s((), i32),
+                         s((), f32),
+                         jax.eval_shape(lambda: sd.position_key(0, 0)),
+                         s((), i32)), v5e)
+        compiled = jax.jit(
+            dec._chunk_impl, donate_argnums=(1,), static_argnums=(8, 9),
+            compiler_options=sd.TPU_BODY_OPTIONS).lower(
+                *args, None, body == "chunk_first").compile()
+    text = compiled.as_text()
+    assert text.count("paged_flash_decode") >= 1        # the latent layer
+    if body == "decode":
+        assert text.count("linear_state_decode") >= 7   # a call a layer
+    # 3.53e9 B of pool and entries are donated and updated in place
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+
+
 def test_dense_decode_body_compiles_for_v5e(v5e):
     """The dense cells' whole decode body with ``TPU_BODY_OPTIONS``, not
     the kernel alone (what a kernel may take of VMEM depends on the body
