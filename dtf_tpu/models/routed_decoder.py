@@ -577,7 +577,11 @@ class LinearDelta(nn.Module):
     page id and each holding, for page ``p``, the running state at the
     newest token written in ``p`` — ``linear_state`` ``[P, heads, head_dim,
     head_dim]`` (the matrices, transposed, in ``dtype``) and
-    ``conv_state`` ``[P, (taps - 1) * 3 * heads * head_dim]``.  One token
+    ``conv_state`` ``[P, sublanes, (taps - 1) * 3 * heads * head_dim /
+    sublanes]``: the last ``taps - 1`` inputs ``[u_{t-2} | u_{t-1} | u_t]``,
+    oldest first, row-major, laid out as the page's own whole tiles
+    (``sublanes`` what one tile of ``dtype`` holds: 16 in bfloat16, 8 in
+    float32).  One token
     goes through ``linear_state_decode`` (the kernel) or its oracle, a
     chunk of whole pages through the blocked form, with tokens past
     ``last_pos`` made no-ops on the state (``beta`` = 0, ``a`` = 0).
@@ -631,9 +635,23 @@ class LinearDelta(nn.Module):
             if cache_index is None or block_table is None:
                 raise ValueError("decode mode needs cache_index [B] "
                                  "and block_table [B, M], both int32")
+            # a page's entry as that page's OWN whole tiles (as many rows
+            # as one tile of ``dtype`` holds sublanes): one contiguous
+            # block, which the TPU compiler's scatter writes in one op —
+            # a 2-D leaf's row of this width is strided through tiles 16
+            # pages share, and its scatter compiles to a serial loop of
+            # dynamic-update-slice, one trip a row
+            sublanes = 32 // jnp.dtype(self.dtype).itemsize
+            if keep * 3 * n % sublanes:
+                raise ValueError(
+                    f"the filter inputs' state entry of {keep} x 3 x {hn} "
+                    f"x {dh} = {keep * 3 * n} {jnp.dtype(self.dtype).name}"
+                    f" values does not divide into the {sublanes} "
+                    f"sublanes of one tile")
+            entry = (sublanes, keep * 3 * n // sublanes)
             conv_state = self.variable(
                 "cache", "conv_state", jnp.zeros,
-                (self.kv_pool_pages, keep * 3 * n), self.dtype)
+                (self.kv_pool_pages,) + entry, self.dtype)
             state = self.variable(
                 "cache", "linear_state", jnp.zeros,
                 (self.kv_pool_pages, hn, dh, dh), self.dtype)
@@ -677,7 +695,7 @@ class LinearDelta(nn.Module):
             rows = ends[:, :, None] + 1 + jnp.arange(keep, dtype=jnp.int32)
             entries = jnp.take_along_axis(
                 full, rows.reshape(b, pages_n * keep)[:, :, None], axis=1
-            ).reshape(b * pages_n, keep * 3 * n)
+            ).reshape((b * pages_n,) + entry)
             pages = _entry_pages(cache_index, ends, block_table, page)
             conv_state.value = conv_state.value.at[pages.reshape(-1)].set(
                 entries)

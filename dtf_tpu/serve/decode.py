@@ -95,7 +95,9 @@ CACHE_LEAF_KINDS = {
     "paged_key": KV_POOL, "paged_value": KV_POOL,   # [P, page, H, Dh]
     "paged_kv": KV_POOL,            # [P, page, H, 2 * Dh]: rows of [k | v]
     "paged_latent": LATENT_POOL,    # [P, page, W]: one row a token
-    "conv_state": PAGE_STATE,       # [P, W]: one running entry a page
+    # [P, W] (ShortConv) or [P, sublanes, W / sublanes] (LinearDelta: the
+    # entry as whole tiles of its own): one running entry a page
+    "conv_state": PAGE_STATE,
     # [P, H, Dv, Dk]: a linear-attention layer's matrix a head, one entry a
     # page like conv_state — copied, read, written and counted by its first
     # axis alone, whatever its rank

@@ -316,6 +316,109 @@ def test_idle_rows_move_nobodys_state(decoders):
         assert sorted(np.flatnonzero(moved).tolist()) == [0, 2]
 
 
+# ------------- (b') the filter inputs' entry: its values and their order --
+_LAYER = dict(heads=4, head_dim=8, taps=4, decay_floor=-5.0, rms_eps=1e-6,
+              dtype=jnp.float32, param_dtype=jnp.float32, use_pallas=False,
+              decode=True, kv_page_size=PAGE, kv_pool_pages=9)
+
+
+@pytest.fixture(scope="module")
+def layer():
+    """``LinearDelta`` alone over 9 pages of 16, its parameters, and the
+    filters' inputs ``u`` [2, 40, 96] of two rows of hidden states."""
+    mod = rd.LinearDelta(**_LAYER)
+    h = jnp.asarray(np.random.default_rng(6).normal(size=(2, 40, 64)),
+                    jnp.float32)
+    zeros = jnp.zeros((1,), jnp.int32)
+    variables = mod.init(jax.random.key(1), h[:1, :PAGE], zeros,
+                         jnp.zeros((1, 3), jnp.int32), zeros)
+    u = np.asarray(jnp.einsum("bsd,dn->bsn", h, variables["params"]["qkv"]))
+    return mod, variables, h, u
+
+
+def _flat_entry(u_row, t):
+    """The entry as the flat ``[P, W]`` leaf held it: ``[u_{t-2} | u_{t-1}
+    | u_t]``, oldest first, zeros before the sequence."""
+    return np.concatenate([u_row[j] if j >= 0 else np.zeros_like(u_row[0])
+                           for j in (t - 2, t - 1, t)])
+
+
+def _apply(mod, variables, cache, h, index, tables, last_pos):
+    _, mut = mod.apply(
+        {"params": variables["params"], "cache": cache}, h,
+        jnp.asarray(index, jnp.int32), jnp.asarray(tables, jnp.int32),
+        jnp.asarray(last_pos, jnp.int32), mutable=["cache"])
+    return mut["cache"]
+
+
+@pytest.mark.parametrize("case", ["chunk_across_two_pages", "padded_chunk",
+                                  "step", "page_first_token", "idle_row"])
+def test_an_entry_flattened_is_the_flat_layouts_entry(layer, case):
+    """The ``conv_state`` leaf is ``[P, sublanes, W / sublanes]``; page
+    ``p``'s entry, flattened row-major, is value for value what the flat
+    leaf held: after a chunk that crosses two pages (each page's entry
+    taken at its last token), after a tail-padded chunk (at the row's last
+    real token), after a step inside a page, after a step AT a page's first
+    token (the carry read from the page before, the entry written to the
+    new page), and for an idle row, whose entry goes to the scratch page
+    and to nobody's."""
+    mod, variables, h, u = layer
+    fresh = variables["cache"]
+    assert fresh["conv_state"].shape == (9, 8, 3 * 96 // 8)
+    want = {}
+    if case == "chunk_across_two_pages":
+        cache = _apply(mod, variables, fresh, h[:1, :2 * PAGE], [0],
+                       [[1, 2, 3]], [2 * PAGE - 1])
+        want = {1: _flat_entry(u[0], PAGE - 1),
+                2: _flat_entry(u[0], 2 * PAGE - 1)}
+    elif case == "padded_chunk":
+        cache = _apply(mod, variables, fresh, h[:1, :2 * PAGE], [0],
+                       [[1, 2, 3]], [PAGE + 4])
+        want = {1: _flat_entry(u[0], PAGE - 1),
+                2: _flat_entry(u[0], PAGE + 4)}
+    else:
+        # both rows prefill two pages; then one token
+        cache = fresh
+        for r, table in enumerate(([1, 2, 3], [4, 5, 6])):
+            cache = _apply(mod, variables, cache, h[r:r + 1, :2 * PAGE],
+                           [0], [table], [2 * PAGE - 1 if r == 0 else 20])
+        if case == "step":          # row 1 at position 21, inside page 5
+            cache = _apply(mod, variables, cache, h[:, 21:22], [0, 21],
+                           [[0, 0, 0], [4, 5, 6]], [0, 0])
+            want = {5: _flat_entry(u[1], 21), 0: _flat_entry(u[0, 21:], 0),
+                    2: _flat_entry(u[0], 2 * PAGE - 1)}
+        elif case == "page_first_token":    # row 0 enters page 3
+            cache = _apply(mod, variables, cache, h[:, 32:33], [32, 0],
+                           [[1, 2, 3], [0, 0, 0]], [0, 0])
+            want = {3: _flat_entry(u[0], 32),
+                    2: _flat_entry(u[0], 2 * PAGE - 1)}
+        else:                       # both rows idle: the scratch page alone
+            before = np.asarray(cache["conv_state"])
+            cache = _apply(mod, variables, cache, h[:, 21:22], [0, 0],
+                           np.zeros((2, 3), np.int32), [0, 0])
+            now = np.asarray(cache["conv_state"])
+            moved = (before != now).reshape(9, -1).any(-1)
+            assert np.flatnonzero(moved).tolist() == [0]
+            assert np.abs(now[0].reshape(-1)[:2 * 96]).max() == 0
+            assert np.abs(now[0].reshape(-1)[2 * 96:]).max() > 0
+            return
+    got = np.asarray(cache["conv_state"])
+    for page, entry in want.items():
+        np.testing.assert_allclose(got[page].reshape(-1), entry, rtol=1e-5,
+                                   atol=1e-6)
+
+
+def test_an_entry_that_is_no_whole_tiles_is_refused():
+    """3 x 1 x 4 float32 values a tap kept do not divide into a tile's 8
+    sublanes: the layer says so where it declares the leaf."""
+    mod = rd.LinearDelta(**dict(_LAYER, heads=1, head_dim=4, taps=2))
+    zeros = jnp.zeros((1,), jnp.int32)
+    with pytest.raises(ValueError, match="does not divide into the 8"):
+        jax.eval_shape(mod.init, jax.random.key(0),
+                       jnp.zeros((1, PAGE, 64)), zeros,
+                       jnp.zeros((1, 3), jnp.int32), zeros)
+
+
 # ------------------------------------- (c) the state rides the pages ----
 def test_a_shared_prefix_and_a_copied_page_carry_the_state(toy, reference,
                                                            decoders):
@@ -338,6 +441,9 @@ def test_a_shared_prefix_and_a_copied_page_carry_the_state(toy, reference,
     cache, last = _prefill(dec, cache, prompt, sharer, start=2 * PAGE)
     _close(last, want)
     cache = dec.copy_page(cache, 2, 11)
+    for name in ("linear_state", "conv_state"):
+        leaf = np.asarray(cache["layer0"]["linear"][name])
+        assert np.array_equal(leaf[11], leaf[2]) and leaf[2].any()
     copier = np.zeros_like(owner)
     copier[:3] = [1, 11, 12]
     cache, last = _prefill(dec, cache, prompt, copier, start=2 * PAGE)
@@ -368,9 +474,14 @@ def test_exported_pages_carry_the_state(toy, reference, decoders):
     for page, to in ((4, 7), (5, 8)):
         leaves = migrate.decode_page(migrate.encode_page(
             src.read_page(cache, page)))
-        assert sorted(a.ndim for a in leaves) == sorted(
-            [1, 3] * N_LINEAR + [2])
+        # a layer's filter inputs as whole tiles [8, 288 / 8], its matrices
+        # [4, 8, 8]; the latent layer's rows [page, 128]
+        assert sorted(a.shape for a in leaves) == sorted(
+            [(8, 36), (4, 8, 8)] * N_LINEAR + [(PAGE, 128)])
         there = dst.write_page(there, to, leaves)
+        # the wire form brought every leaf of the page bit for bit
+        for was, now in zip(leaves, dst.read_page(there, to)):
+            assert was.dtype == now.dtype and np.array_equal(was, now)
     _, last = _prefill(dst, there, prompt, moved, start=2 * PAGE)
     _close(last, want)
 
@@ -540,12 +651,46 @@ def test_serving_memory_plan_counts_the_matrix_entry(toy):
     assert plan["kv_heads"] == 1 and plan["head_dim"] == 128
 
 
+def test_the_cells_sizes_plan_7856128_bytes_of_state_a_page():
+    """At ``ling-serve-longgen``'s own sizes (its configuration's build
+    call and its engine: 32 heads of 128, 4 taps, seven linear layers,
+    bfloat16, 385 pages of 1,024; shapes only): the filter inputs' leaf is
+    ``[P, 16, 2304]``, whole bfloat16 tiles that pad nothing, and the
+    plan and the gauge's source both read 7,856,128 B a page."""
+    import json
+    from dtf_tpu.serve.decode import state_bytes_per_page, trace_paged_init
+
+    def load(*path):
+        with open(os.path.join(ROOT, "benchmark", *path)) as f:
+            return json.load(f)
+    config = load("configs", "ling-3.0-flash-vl.json")
+    eng = load("workloads", "ling-serve-longgen.json")["engine"]
+    model, _ = build_model(
+        config["build_model"]["name"], num_classes=config["num_classes"],
+        dtype=jnp.bfloat16, **config["build_model"]["kwargs"])
+    shapes = trace_paged_init(model, eng["kv_page_size"],
+                              eng["kv_pool_pages"])[0]
+    entries = [leaf for path, leaf in
+               jax.tree_util.tree_leaves_with_path(shapes)
+               if path[-1].key == "conv_state"]
+    assert [e.shape for e in entries] == [(385, 16, 2304)] * 7
+    assert all(e.dtype == jnp.bfloat16 for e in entries)
+    assert state_bytes_per_page(shapes) == 7_856_128
+    plan = serving_memory_plan(
+        model, num_slots=eng["max_batch"], max_seq_len=eng["max_seq_len"],
+        kv_page_size=eng["kv_page_size"],
+        kv_pool_pages=eng["kv_pool_pages"])
+    assert plan["state_bytes_per_page"] == 7_856_128
+    assert plan["state_bytes_paged"] == 384 * 7_856_128
+
+
 def test_every_cache_leaf_is_of_a_named_kind(decoders):
     dec = decoders[False]
     shapes = jax.eval_shape(dec.fresh_cache)
     state = cache_leaves(shapes, PAGE_STATE)
     assert len(state) == 2 * N_LINEAR
-    assert sorted({p.ndim for _, p in state}) == [2, 4]
+    # [P, 8, 288 / 8] filter inputs (whole float32 tiles) and [P, 4, 8, 8]
+    assert sorted({p.shape[1:] for _, p in state}) == [(4, 8, 8), (8, 36)]
     assert len(cache_leaves(shapes)) == 2 * N_LINEAR + 1
     assert dec.carries_state and not dec.decode_all_heads
 

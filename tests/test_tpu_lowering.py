@@ -435,6 +435,76 @@ def test_linear_state_decode_compiles_for_v5e(v5e, dtype):
     assert compiled.memory_analysis().temp_size_in_bytes < 1e6
 
 
+def _scatter_loops(text):
+    """(``while`` ops that came from a scatter, ``dynamic-update-slice``
+    ops of a 385-page leaf of filter inputs) in an optimized HLO text."""
+    loops = [ln for ln in text.splitlines()
+             if " while(" in ln and "/scatter" in ln]
+    updates = [ln for ln in text.splitlines()
+               if re.search(r"= bf16\[385,(36864|16,2304)\]\S* "
+                            r"dynamic-update-slice\(", ln)]
+    return len(loops), len(updates)
+
+
+def test_linear_delta_writes_its_filter_inputs_in_one_op(v5e):
+    """``LinearDelta``'s decode call at the benchmark's sizes (96 rows, 385
+    pages of 1,024, 32 heads of 128, 4 taps, width 2560, bfloat16, the
+    cache donated) compiled for the v5e: the 96 entries of the filter
+    inputs reach the ``[385, 16, 2304]`` leaf through the compiler's own
+    scatter, on the leaf in place — no ``while`` that came from a scatter,
+    no ``dynamic-update-slice`` on the leaf, no copy of the pool."""
+    from dtf_tpu.models import routed_decoder as rd
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    b, pool, m, d = 96, 385, 12, 2560
+    layer = rd.LinearDelta(
+        heads=32, head_dim=128, taps=4, decay_floor=-5.0, rms_eps=1e-6,
+        dtype=bf16, param_dtype=bf16, use_pallas=True, decode=True,
+        kv_page_size=1024, kv_pool_pages=pool)
+    shapes = (jax.ShapeDtypeStruct((b, 1, d), bf16),
+              jax.ShapeDtypeStruct((b,), i32),
+              jax.ShapeDtypeStruct((b, m), i32),
+              jax.ShapeDtypeStruct((b,), i32))
+    variables = jax.eval_shape(layer.init, jax.random.key(0), *shapes)
+    assert variables["cache"]["conv_state"].shape == (pool, 16, 2304)
+
+    def call(params, cache, *inputs):
+        (y, _), mut = layer.apply({"params": params, "cache": cache},
+                                  *inputs, mutable=["cache"])
+        return y, mut["cache"]
+    compiled = jax.jit(call, donate_argnums=(1,)).lower(*_on_chip(
+        (variables["params"], variables["cache"]) + shapes, v5e)).compile()
+    text = compiled.as_text()
+    assert _scatter_loops(text) == (0, 0)
+    assert re.search(r"= bf16\[385,16,2304\]\S* scatter\(", text)
+    assert "linear_state_decode" in text
+    # 0.43e9 B of entries and matrices updated in place
+    assert compiled.memory_analysis().temp_size_in_bytes < 16e6
+
+
+@pytest.mark.parametrize("pool,entry,looped", [
+    ((385, 36864), (36864,), True),     # the flat leaf LinearDelta had
+    ((385, 16, 2304), (16, 2304), False),      # ... as whole bf16 tiles
+    ((5121, 4096), (4096,), False),     # ShortConv's leaf (LFM2's cell)
+], ids=["flat_36864", "tiles_16x2304", "flat_4096"])
+def test_a_wide_flat_entry_scatters_in_a_serial_loop(v5e, pool, entry,
+                                                     looped):
+    """The rule ``LinearDelta``'s leaf is shaped by, row by row: 96
+    entries ``.at[pages].set`` into a donated pool, compiled alone for
+    the v5e.  A flat ``[P, 36864]`` bfloat16 leaf gives a ``while`` of one
+    ``dynamic-update-slice`` a row; the same bytes as ``[P, 16, 2304]``,
+    and a flat leaf of 4,096, give one scatter.  A jax/libtpu that moves
+    the threshold fails here."""
+    args = [jax.ShapeDtypeStruct(pool, jnp.bfloat16, sharding=v5e),
+            jax.ShapeDtypeStruct((96,) + entry, jnp.bfloat16, sharding=v5e),
+            jax.ShapeDtypeStruct((96,), jnp.int32, sharding=v5e)]
+    text = jax.jit(lambda p, e, i: p.at[i].set(e),
+                   donate_argnums=(0,)).lower(*args).compile().as_text()
+    loops = [ln for ln in text.splitlines() if " while(" in ln]
+    assert bool(loops) == looped, loops
+    assert ("dynamic-update-slice(" in text) == looped
+    assert (" scatter(" in text) != looped
+
+
 @pytest.mark.parametrize("body", ["chunk_first", "chunk", "decode"])
 def test_linear_state_serve_bodies_compile_for_v5e(v5e, body):
     """The bodies of the decoder whose layers are delta-rule linear
@@ -487,6 +557,8 @@ def test_linear_state_serve_bodies_compile_for_v5e(v5e, body):
     assert text.count("paged_flash_decode") >= 1        # the latent layer
     if body == "decode":
         assert text.count("linear_state_decode") >= 7   # a call a layer
+        # the filter inputs' 96 entries a layer: a scatter, not a loop
+        assert _scatter_loops(text) == (0, 0)
     # 3.53e9 B of pool and entries are donated and updated in place
     assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
 
