@@ -110,6 +110,17 @@ _REGISTRY = {
             experts_held=(0, 8), activation="silu",
             router_input="post_attention"),
         32_768, 0.0),
+    # and an all-dense stack whose attention layers read a window of exact
+    # keys beside one learned summary a chunk of every closed window, in the
+    # one K/V page pool under a compact table; unit-offset norms
+    "routed_decoder_summary": (
+        functools.partial(
+            routed_decoder.RoutedDecoderLM, num_layers=4, d_model=512,
+            num_heads=4, num_kv_heads=4, head_dim=128,
+            layer_window=(False,), layer_rope=(True,), rope_theta=1e5,
+            summary_window=64, summary_chunk=4, norm_unit_offset=True,
+            num_dense_layers=4, dense_width=1024, activation="silu"),
+        32_768, 0.0),
     # pipeline-stacked LM family (pipeline stages over 'model')
     "pipeline_transformer": (pipeline_lm.PipelinedTransformerLM,
                              32_768, 0.0),

@@ -3,7 +3,8 @@ MLP is a dropless routed-expert layer — a family that is served
 (``serve/decode.py``, ``serve/engine.py``), not trained, in this repo.
 
 What a layer is comes from fields, all plain values a configuration file
-can carry.  Pre-norm RMSNorm blocks, no bias on any projection, an output
+can carry.  Pre-norm RMSNorm blocks (``norm_unit_offset``: every norm
+scales by 1 + its learned vector), no bias on any projection, an output
 head of its own (``tie_head``: the embedding matrix itself).  There is no
 position table: a position is the row's
 ``cache_index`` plus the offset in the chunk, so ``max_seq_len`` bounds
@@ -31,6 +32,21 @@ a call for scores and values; decode mode attends absorbed, every chunk
 projects the queries directly; ``q_head_norm`` norms each query head
 before the rotation; ``attention_head_gate`` scales a head's output by a
 sigmoid gate.
+
+``summary_window`` set (whole heads, every layer of the ``full`` kind) — a
+WINDOW OF EXACT KEYS BESIDE SUMMARIES: a query attends the exact keys of
+its own window of that many positions, aligned to its multiples, and one
+learned summary a ``summary_chunk`` positions of every window before it
+(``ops/window_summary.py``: ``a_m = softmax_m(k_m . phi)``, ``k~ = sum a_m
+k_m + mu``, ``v~ = sum a_m v_m``, ``summary_phi`` and ``summary_mu`` [Hkv,
+Dh] a layer).  The summaries are rows of the SAME K and V pools and a
+row's table is COMPACT: the summaries' pages first, then the open window's,
+so the attended rows are a prefix of the table and the paged write, the
+paged kernel and a chunk's causal rule run on ``compact_index(position)``
+unchanged, while RoPE keeps the true position.  The caller closes a window
+(``RoutedDecoderLM.close_windows``, a program of its own: one
+``window_compact`` call a layer) before the first write into the next one;
+a row then grows by the summaries' pages a window, not by the window's.
 
 **Mixer kind.**  ``layer_mixer[l]`` (one entry a layer; shorter tuples
 repeat) is ``attention`` — the kind above — or ``short_conv``
@@ -143,7 +159,7 @@ import jax
 import jax.numpy as jnp
 
 from dtf_tpu.models.transformer import paged_cache_attention
-from dtf_tpu.ops import linear_state
+from dtf_tpu.ops import linear_state, window_summary
 from dtf_tpu.ops.paged_attention import cached_attention, expand_kv_heads
 
 # what ``"stats"/"counts"`` holds, in order: with whole heads, and with the
@@ -151,6 +167,11 @@ from dtf_tpu.ops.paged_attention import cached_attention, expand_kv_heads
 STATS = ("assignments", "experts_touched", "expert_load_max",
          "kv_tokens_read_global", "kv_tokens_read_window")
 LATENT_STATS = STATS[:3] + ("latent_tokens_read",)
+# with a window of exact keys beside summaries (``summary_window``): the
+# cache rows of each sort the call's queries attend, summed over rows and
+# layers (the windows closed before the call are the caller's to count: it
+# launches them)
+SUMMARY_STATS = STATS[:3] + ("kv_exact_rows_read", "kv_summary_rows_read")
 # with short-convolution layers beside attention: the tokens those layers
 # mixed, and the rows whose state entry went to a page of their own (not
 # the scratch page), both summed over the short-convolution layers
@@ -165,12 +186,16 @@ MIXERS = ("attention", "short_conv", "linear_delta")
 _GMM_ROWS = 128
 
 
-def rms_norm(x, scale, eps: float):
-    """``x / rms(x) * scale`` in f32, returned in x's dtype."""
+def rms_norm(x, scale, eps: float, unit_offset: bool = False):
+    """``x / rms(x) * scale`` in f32, returned in x's dtype;
+    ``unit_offset``: the learned vector is the scale's distance from 1
+    (``* (1 + scale)``, initialised at zeros)."""
     x32 = x.astype(jnp.float32)
     var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
-    return (x32 * jax.lax.rsqrt(var + eps)
-            * scale.astype(jnp.float32)).astype(x.dtype)
+    normed = x32 * jax.lax.rsqrt(var + eps)
+    scale = scale.astype(jnp.float32)
+    return (normed * (1.0 + scale if unit_offset else scale)
+            ).astype(x.dtype)
 
 
 def rotate_half_rope(x, positions, theta: float):
@@ -361,6 +386,20 @@ def _normal(stddev):
     return nn.initializers.normal(stddev)
 
 
+def _norm_init(unit_offset: bool):
+    """A norm's learned vector starts at the identity: ones, or zeros where
+    it is the scale's distance from 1."""
+    return nn.initializers.zeros if unit_offset else nn.initializers.ones
+
+
+def _clipped_normal(scale):
+    """``clip(N(0, 1), -1, 1) * scale``."""
+    def init(key, shape, dtype=jnp.float32):
+        return (jnp.clip(jax.random.normal(key, shape, jnp.float32), -1.0,
+                         1.0) * scale).astype(dtype)
+    return init
+
+
 class GroupedQueryAttention(nn.Module):
     num_heads: int
     num_kv_heads: int
@@ -374,6 +413,9 @@ class GroupedQueryAttention(nn.Module):
     kv_page_size: Optional[int] = None
     kv_pool_pages: Optional[int] = None
     qk_norm_eps: Optional[float] = None     # set: per-head norms of q, k
+    # (window, chunk): exact keys of the query's own aligned window beside
+    # one learned summary a chunk of every closed one (ops/window_summary)
+    summary: Optional[Tuple[int, int]] = None
 
     @nn.compact
     def __call__(self, h, positions, cache_index=None, block_table=None,
@@ -381,6 +423,16 @@ class GroupedQueryAttention(nn.Module):
                  window_pages: Optional[int] = None):
         b, s, d = h.shape
         hq, hkv, dh = self.num_heads, self.num_kv_heads, self.head_dim
+        if self.summary is not None:
+            if 2 * dh == _LANES or self.window is not None:
+                raise ValueError(
+                    "summaries go in K and V pools of their own beside the "
+                    "window's tokens: no [k | v] rows (head_dim 64) and no "
+                    "sliding window in such a layer")
+            init = _clipped_normal(dh ** -0.5)
+            phi = self.param("summary_phi", init, (hkv, dh),
+                             self.param_dtype)
+            mu = self.param("summary_mu", init, (hkv, dh), self.param_dtype)
         w_qkv = self.param("qkv", _normal(0.02), (d, (hq + 2 * hkv) * dh),
                            self.param_dtype)
         w_out = self.param("out", _normal(0.02), (hq * dh, d),
@@ -408,6 +460,11 @@ class GroupedQueryAttention(nn.Module):
             if cache_index is None or block_table is None:
                 raise ValueError("decode mode needs cache_index [B] "
                                  "and block_table [B, M], both int32")
+            if self.summary is not None:
+                # the attended rows are a prefix of the table in table
+                # order: summaries first, then the open window's tokens
+                cache_index = window_summary.compact_index(cache_index,
+                                                           *self.summary)
             o = paged_cache_attention(
                 self, q, k, v, cache_index, block_table,
                 flash_prefill=flash_prefill, window_pages=window_pages,
@@ -419,13 +476,29 @@ class GroupedQueryAttention(nn.Module):
                 one_row=2 * dh == _LANES)
         else:
             # the whole sequence at once (tests, the toy): a plain mask
+            summaries = None
+            if self.summary is not None and s >= self.summary[1]:
+                # a query's own aligned window exactly, and one summary a
+                # whole chunk of the windows before it
+                window, chunk = self.summary
+                whole = s // chunk * chunk
+                summaries = window_summary.chunk_summaries(
+                    k[:, :whole], v[:, :whole], phi, mu, chunk)
+                k = jnp.concatenate([k, summaries[0]], axis=1)
+                v = jnp.concatenate([v, summaries[1]], axis=1)
             kr, vr = expand_kv_heads(k, v, hq)
             i = jnp.arange(s)
             mask = i[None, :] <= i[:, None]
             if self.window is not None:
                 mask &= i[None, :] > i[:, None] - self.window
+            if summaries is not None:
+                first = i // window * window
+                mask = jnp.concatenate(
+                    [mask & (i[None, :] >= first[:, None]),
+                     jnp.arange(whole // chunk)[None, :] * chunk
+                     < first[:, None]], axis=1)
             o = cached_attention(q, kr, vr,
-                                 jnp.broadcast_to(mask, (b, s, s)))
+                                 jnp.broadcast_to(mask, (b,) + mask.shape))
         return jnp.einsum("bsn,nd->bsd", o.reshape(b, s, hq * dh),
                           w_out.astype(self.dtype),
                           preferred_element_type=jnp.float32)
@@ -902,6 +975,8 @@ class RoutedBlock(nn.Module):
     route_groups: int = 1
     route_groups_kept: int = 1
     experts_held: Optional[Tuple[int, int]] = None      # (first id, count)
+    summary: Optional[Tuple[int, int]] = None           # (window, chunk)
+    norm_unit_offset: bool = False
 
     @nn.compact
     def __call__(self, x, positions, cache_index=None, block_table=None,
@@ -912,9 +987,9 @@ class RoutedBlock(nn.Module):
         b, s, d = x.shape
         e, f = self.num_experts, self.expert_width
         held = (e if self.experts_held is None else self.experts_held[1])
-        ones, pdt = nn.initializers.ones, self.param_dtype
-        g1 = self.param("norm1", ones, (d,), pdt)
-        g2 = self.param("norm2", ones, (d,), pdt)
+        pdt, offset = self.param_dtype, self.norm_unit_offset
+        g1 = self.param("norm1", _norm_init(offset), (d,), pdt)
+        g2 = self.param("norm2", _norm_init(offset), (d,), pdt)
         routed = self.dense_width is None
         if routed:
             w_router = self.param("router", _normal(0.02), (d, e), pdt)
@@ -936,7 +1011,7 @@ class RoutedBlock(nn.Module):
                          self.experts_per_token, score_bias,
                          self.routed_scale, self.routing_sum_eps,
                          self.route_groups, self.route_groups_kept)
-        h = rms_norm(x, g1, self.rms_eps)
+        h = rms_norm(x, g1, self.rms_eps, offset)
         if routed and self.router_input == "pre_attention":
             idx, weights = choose(h)
         advanced = None
@@ -961,7 +1036,7 @@ class RoutedBlock(nn.Module):
                 kv_page_size=self.kv_page_size,
                 kv_pool_pages=self.kv_pool_pages,
                 qk_norm_eps=self.rms_eps if self.qk_norm else None,
-                name="attn")(
+                summary=self.summary, name="attn")(
                     h, positions, cache_index, block_table, flash_prefill,
                     window_pages)
         else:
@@ -975,7 +1050,7 @@ class RoutedBlock(nn.Module):
                 head_gate=self.attention_head_gate, name="attn")(
                     h, positions, cache_index, block_table, window_pages)
         x = x + attn
-        h2 = rms_norm(x, g2, self.rms_eps).reshape(b * s, d)
+        h2 = rms_norm(x, g2, self.rms_eps, offset).reshape(b * s, d)
         if not routed:
             y = gated_mlp(
                 h2.astype(self.dtype),
@@ -1083,6 +1158,15 @@ class RoutedDecoderLM(nn.Module):
     # the router chooses among (None: all).  The others' part of a layer's
     # sum is left out; nothing here stands in for it
     experts_held: Optional[Tuple[int, int]] = None
+    # summary_window set (whole heads, every attention layer): a query
+    # attends the exact keys of its own window of that many positions,
+    # aligned to its multiples, and one learned summary a summary_chunk
+    # positions of every window before it; the summaries live in the K and
+    # V pools and a row's table is compact (ops/window_summary.py).
+    # norm_unit_offset: every RMSNorm scales by 1 + its learned vector
+    summary_window: Optional[int] = None
+    summary_chunk: int = 16
+    norm_unit_offset: bool = False
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
     use_pallas: Any = None
@@ -1097,6 +1181,8 @@ class RoutedDecoderLM(nn.Module):
     def stats_names(self):
         """What ``"stats"/"counts"`` holds, in order."""
         names = STATS if self.kv_lora_rank is None else LATENT_STATS
+        if self.summary_window is not None:
+            return SUMMARY_STATS
         if "linear_delta" in self.layer_mixers():
             return names + LINEAR_STATS
         return STATE_STATS if self.carries_state else names
@@ -1124,6 +1210,32 @@ class RoutedDecoderLM(nn.Module):
                  float(self.rope_theta) if lr[i % len(lr)] else None)
                 for i in range(self.num_layers)]
 
+    def close_windows(self, params, cache, block_row, window):
+        """Close window ``window`` (a traced int32) of ONE row in every
+        layer of a ``summary_window`` model's paged cache (this is the
+        decode-mode clone; plain trees in, no ``apply``): the window's
+        pages, table entries ``[n * window, n * window + summary_window //
+        page)`` of ``block_row`` [M], are read and their chunks' summaries
+        written over the first ``n`` of them
+        (``ops.window_summary.compact_window``, one call a layer).  The
+        caller runs it before the first write into the next window.
+        Returns the cache."""
+        page, chunk = self.kv_page_size, self.summary_chunk
+        n = self.summary_window // chunk // page
+        pages = jax.lax.dynamic_slice(block_row, (n * window,),
+                                      (self.summary_window // page,))
+        cache = dict(cache)
+        for i in range(self.num_layers):
+            attn = params[f"layer{i}"]["attn"]
+            pools = cache[f"layer{i}"]["attn"]
+            k, v = window_summary.compact_window(
+                pools["paged_key"], pools["paged_value"], pages,
+                attn["summary_phi"], attn["summary_mu"], chunk=chunk,
+                use_pallas=self.use_pallas)
+            cache[f"layer{i}"] = {"attn": {"paged_key": k,
+                                           "paged_value": v}}
+        return cache
+
     @nn.compact
     def __call__(self, tokens, train: bool = False, cache_index=None,
                  block_table=None, flash_prefill: bool = False,
@@ -1142,6 +1254,18 @@ class RoutedDecoderLM(nn.Module):
         if "short_conv" in mixers and self.kv_lora_rank is not None:
             raise ValueError("short_conv layers go with whole heads, not "
                              "with the latent cache")
+        summary = None
+        if self.summary_window is not None:
+            if self.kv_lora_rank is not None or self.carries_state or any(
+                    w is not None for w, _ in self.layer_kinds()):
+                raise ValueError(
+                    "summary_window goes with whole heads in every layer: "
+                    "no latent cache, no state layer, no sliding window")
+            if self.summary_window % self.summary_chunk:
+                raise ValueError(
+                    f"summary_window {self.summary_window} is not whole "
+                    f"chunks of {self.summary_chunk}")
+            summary = (int(self.summary_window), int(self.summary_chunk))
         if self.experts_held is not None and (
                 self.experts_held[0] < 0 or self.experts_held[1] < 1
                 or sum(self.experts_held) > self.num_experts):
@@ -1191,7 +1315,8 @@ class RoutedDecoderLM(nn.Module):
                 attention_head_gate=self.attention_head_gate,
                 route_groups=self.route_groups,
                 route_groups_kept=self.route_groups_kept,
-                experts_held=self.experts_held, name=f"layer{i}")(
+                experts_held=self.experts_held, summary=summary,
+                norm_unit_offset=self.norm_unit_offset, name=f"layer{i}")(
                     x, positions, cache_index, block_table, flash_prefill,
                     window_pages, last_pos)
             if sizes is not None:
@@ -1219,6 +1344,14 @@ class RoutedDecoderLM(nn.Module):
                         else jnp.sum(last_pos.astype(jnp.int32) + 1))
                 counts += [real * n_state, advanced]
             counts = jnp.stack(counts)
+        elif summary is not None:
+            window, chunk = summary
+            closed = positions[:, -1] // window
+            exact = len(kinds) * jnp.sum(positions[:, -1] - closed * window
+                                         + 1)
+            summaries = len(kinds) * jnp.sum(closed) * (window // chunk)
+            counts = jnp.stack([assignments, touched, load_max, exact,
+                                summaries])
         else:
             attends = [w for (w, _), m in zip(kinds, mixers)
                        if m == "attention"]
@@ -1235,8 +1368,10 @@ class RoutedDecoderLM(nn.Module):
         self.sow("stats", "counts", counts,
                  reduce_fn=lambda _, new: new,
                  init_fn=lambda: jnp.zeros((n_counts,), jnp.int32))
-        x = rms_norm(x, self.param("norm_f", nn.initializers.ones,
-                                   (self.d_model,), pdt), self.rms_eps)
+        x = rms_norm(x, self.param("norm_f",
+                                   _norm_init(self.norm_unit_offset),
+                                   (self.d_model,), pdt),
+                     self.rms_eps, self.norm_unit_offset)
         if self.tie_head:
             return jnp.einsum("bsd,vd->bsv", x.astype(self.dtype),
                               embed.astype(self.dtype),

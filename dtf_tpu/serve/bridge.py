@@ -161,12 +161,16 @@ def serving_memory_plan(model, *, num_slots: int, max_seq_len: int,
     The KV page pool holds ``(kv_pool_pages − 1) × kv_page_size``
     tokens TOTAL — sized to the expected tokens in flight, not the
     worst case.  ``kv_pool_pages`` of 0 = one full ``max_seq_len``
-    reservation per slot (plus the scratch page).  Returns dict with
+    reservation per slot (plus the scratch page): ``pages_per_slot``,
+    which under a model that keeps one summary a chunk of its closed
+    windows (``summary_window``) is its own count and does not grow as
+    ``max_seq_len / kv_page_size``.  Returns dict with
     ``kv_bytes_paged``, ``kv_tokens_capacity`` and the layer geometry —
     serve_main logs it so pool sizing is a visible decision, not a
     guess."""
     import numpy as np
 
+    from dtf_tpu.ops import window_summary
     from dtf_tpu.serve.decode import (KV_POOL, LATENT_POOL, cache_leaves,
                                       state_bytes_per_page, trace_paged_init)
 
@@ -185,7 +189,10 @@ def serving_memory_plan(model, *, num_slots: int, max_seq_len: int,
         )["params"]
     param_bytes = sum(int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
                       for leaf in jax.tree_util.tree_leaves(params))
-    pages_per_slot = -(-max_seq_len // kv_page_size)
+    # what a full-length row holds: every page of its length, or the
+    # model's own count where closed windows shrink to their summaries
+    pages_per_slot = window_summary.row_pages(model, max_seq_len,
+                                              kv_page_size)
     pool_pages = int(kv_pool_pages) or 1 + num_slots * pages_per_slot
     paged_tokens = (pool_pages - 1) * kv_page_size
     mp = max(int(model_parallelism), 1)

@@ -56,6 +56,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from dtf_tpu.obs import trace
+from dtf_tpu.ops import window_summary
 
 log = logging.getLogger("dtf_tpu")
 
@@ -282,9 +283,29 @@ class Decoder:
             raise ValueError(f"kv_page_size must be >= 1, got "
                              f"{kv_page_size}")
         self.page_size = int(kv_page_size)
+        # a block table's width: what callers size their tables by.  A row
+        # uses its leading ``pages_for(length)`` entries, which under a
+        # compact table are far fewer
         self.pages_per_slot = -(-self.max_seq_len // self.page_size)
+        # (window, chunk) where the model keeps one summary a chunk of
+        # every closed window in the pools (ops/window_summary.py): the
+        # table is then compact, and a window is closed HERE, before the
+        # first write past it
+        window = getattr(model, "summary_window", None)
+        self.summary = (None if window is None
+                         else (int(window), int(model.summary_chunk)))
+        if self.summary is not None and self.tp > 1:
+            raise ValueError(
+                f"a summary_window model is served on one device: the "
+                f"window's close (ops/window_summary.py) is not sharded "
+                f"over the mesh's model axis ({self.tp})")
+        # closes launched: in all, and by the last prefill_chunk or
+        # decode_step alone (what the engine puts on that call's span)
+        self.windows_closed = 0
+        self.last_closed = 0
         self.pool_pages = int(
-            kv_pool_pages or 1 + self.num_slots * self.pages_per_slot)
+            kv_pool_pages or 1 + self.num_slots * window_summary.row_pages(
+                model, self.max_seq_len, self.page_size))
         if self.pool_pages < 2:
             raise ValueError(
                 f"kv_pool_pages must be >= 2 (page 0 is the scratch "
@@ -328,7 +349,63 @@ class Decoder:
                 lambda c, p: c.at[dst].set(p.astype(c.dtype)),
                 cache, payload),
             donate_argnums=(0,))
+        self._close = jax.jit(
+            lambda params, cache, block_row, window:
+            self.model.close_windows(params, cache, block_row, window),
+            donate_argnums=(1,))
         self.params = params                 # through the setter: _held
+
+    # -- what a row holds ----------------------------------------------
+    def pages_for(self, length: int) -> int:
+        """The most table entries (pages) a row of ``length`` positions
+        ever holds: ``ceil(length / page)``, or the model's own count
+        where closed windows shrink to their summaries."""
+        return window_summary.row_pages(self.model, length, self.page_size)
+
+    def table_index(self, index):
+        """The table row (``entry * page + offset``) that holds position
+        ``index`` (an int or an array): the position itself, or its compact
+        index under a model that keeps summaries."""
+        if self.summary is None:
+            return index
+        return window_summary.compact_index(index, *self.summary)
+
+    @property
+    def pages_reclaimed(self) -> int:
+        """Pages the windows closed so far gave back to their rows: a
+        closed window keeps its summaries' pages and the next one's tokens
+        take the others over."""
+        if self.summary is None:
+            return 0
+        window, chunk = self.summary
+        return self.windows_closed * (window - window // chunk
+                                      ) // self.page_size
+
+    def _close_windows(self, cache, starts, block_rows, so_far=None):
+        """Close the window behind every row whose call starts a new one
+        (``starts`` [B] positions, ``block_rows`` [B, M]): one launch of
+        the ``serve_close_window`` program a row, before the body's, each a
+        ``compact`` lap of the engine's turn (``so_far``: the lap that
+        takes the caller's time up to the first of them).  ``last_closed``
+        is what was launched here, the one count of it."""
+        window = self.summary[0]
+        rows = np.flatnonzero((starts > 0) & (starts % window == 0))
+        self.last_closed = int(rows.size)
+        if rows.size and so_far:
+            trace.lap(so_far)
+        for r in rows:
+            dyn = (self.params, cache,
+                   jnp.asarray(block_rows[r], jnp.int32),
+                   jnp.asarray(starts[r] // window - 1, jnp.int32))
+            fn = self._execs.get("close")
+            if fn is None:
+                fn = (self._aot("serve_close_window", self._close, dyn)
+                      or self._close)
+                self._execs["close"] = fn
+            cache = fn(*dyn)
+            self.windows_closed += 1
+            trace.lap("compact")
+        return cache
 
     # -- tensor-parallel plumbing --------------------------------------
     def _shard_params(self, params):
@@ -573,12 +650,22 @@ class Decoder:
                 f"must be page-aligned (kv_page_size {self.page_size}) — "
                 f"whole-page writes depend on it")
         block_row = np.asarray(block_row, np.int32).reshape(-1)
+        if self.summary is not None:
+            if start // self.summary[0] != (
+                    start + chunk.size - 1) // self.summary[0]:
+                raise ValueError(
+                    f"prefill chunk (len {chunk.size}, start {start}) "
+                    f"straddles a window of {self.summary[0]}: its keys "
+                    f"would lie on both sides of a close")
+            cache = self._close_windows(cache, np.array([start]),
+                                        block_row[None], "chunk_host")
         # gather path: static window trim (one compile per window, the
         # O(prompt²/2) contract); kernel path: None — the kernel skips
         # dead pages dynamically, so every chunk index shares ONE
         # compile per chunk shape
         window = (None if self._kernel_attn
-                  else (int(start) + chunk.size) // self.page_size)
+                  else (int(self.table_index(int(start))) + chunk.size)
+                  // self.page_size)
         dyn = (self.params, cache) + _chunk_operands(
             _pack_chunk_operands(chunk, block_row, sample_pos, start, seed,
                                  temperature), block_row.size)
@@ -604,13 +691,19 @@ class Decoder:
         decoding) → (tokens [B], cache, logits [B, V]).  Row b samples
         with ``fold_in(key(seeds[b]), index[b])``, a pure function of
         the request's seed and position."""
+        positions = index       # as handed in: the engine's are the host's
         tokens = jnp.asarray(tokens, jnp.int32).reshape(-1, 1)
         index = jnp.asarray(index, jnp.int32)
         temperature = jnp.asarray(temperature, jnp.float32)
         rowkeys = _seed_row_keys(jnp.asarray(seeds, jnp.uint32), index)
         # laps of the engine's serve_iteration span, where one is open
-        # on this thread: the arguments, then the body's call returning
+        # on this thread: the arguments, the closes of the rows that start
+        # a new window (``compact``, one a row), then the body's call
+        # returning
         trace.lap("launch_args")
+        if self.summary is not None:
+            cache = self._close_windows(cache, np.asarray(positions),
+                                        np.asarray(block_tables))
         dyn = (self.params, cache, tokens, index,
                jnp.asarray(block_tables, jnp.int32), temperature,
                rowkeys)

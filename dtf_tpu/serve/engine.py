@@ -567,8 +567,19 @@ class ServeEngine:
             kv_pool_pages=(int(kv_pool_pages) if kv_pool_pages
                            else None), mesh=mesh,
             ledger=self.ledger)
+        compact = self.decoder.summary      # (window, chunk) or None
+        if compact is not None and (not self.prefill_chunk
+                                    or compact[0] % self.prefill_chunk):
+            raise ValueError(
+                f"prefill_chunk ({self.prefill_chunk}) must divide the "
+                f"model's summary_window ({compact[0]}): a chunk may not "
+                f"straddle a window's close")
         self.pool = PagePool(self.decoder.pool_pages)
-        self.prefix_sharing = bool(prefix_sharing)
+        # a compact table's entry is not a function of the prompt's leading
+        # tokens alone (a closed window's first page holds its summaries,
+        # the others the next window's tokens): such a model shares no
+        # prefix pages
+        self.prefix_sharing = bool(prefix_sharing) and compact is None
         self.registry = PrefixRegistry(self.page_size)
         self._cache = self.decoder.fresh_cache()
         # base for per-request sampling seeds (requests that arrive
@@ -623,6 +634,10 @@ class ServeEngine:
         # running-state entries (0 for a model whose layers all attend)
         self.metrics.gauge("serve_state_bytes_per_page", unit="bytes").set(
             self.decoder.state_bytes_per_page)
+        # pages that closed windows gave back to their rows (a model that
+        # keeps summaries; 0 for every other)
+        self._m_pages_reclaimed = self.metrics.gauge(
+            "serve_pages_reclaimed_total", unit="pages")
         self._m_prefill_chunks = self.metrics.counter(
             "serve_prefill_chunks_total", unit="chunks")
         self._m_decode_gap = self.metrics.histogram("serve_decode_gap_s",
@@ -907,7 +922,7 @@ class ServeEngine:
                 f"max_new_tokens ({max_new_tokens}) = {total} exceeds "
                 f"max_seq_len {self.max_seq_len}; shorten the prompt or "
                 f"lower the budget")
-        need = -(-total // self.page_size)
+        need = self.decoder.pages_for(total)
         if need > self.pool.usable_pages:
             raise ValueError(
                 f"oversized request for the page pool: needs {need} "
@@ -1177,6 +1192,7 @@ class ServeEngine:
         self.max_concurrent = max(self.max_concurrent, active)
         self._m_occupancy.set(active / self.max_batch)
         self._m_pages_used.set(self.pool.used_pages)
+        self._m_pages_reclaimed.set(self.decoder.pages_reclaimed)
         self._m_shared.set(self.pool.shared_refs)
         if active:
             self._m_occ_sampled.observe(active / self.max_batch)
@@ -1234,8 +1250,8 @@ class ServeEngine:
         """Worst-case pages for a request: prompt + full budget.
         Reserving up front means a decode step can never OOM the pool
         mid-generation (no preemption machinery needed)."""
-        total = int(req.prompt.size) + int(req.max_new_tokens)
-        return -(-total // self.page_size)
+        return self.decoder.pages_for(
+            int(req.prompt.size) + int(req.max_new_tokens))
 
     def _admission_plan(self, req: ServeRequest):
         """(shared pages, fresh pages needed, cow) for one request —
@@ -1418,7 +1434,8 @@ class ServeEngine:
                 attrs["traces"] = tids
             attrs["allheads"] = int(self.decoder.decode_all_heads)
         self._m_live_pages.observe(
-            int((index // self.page_size + 1).sum()))
+            int((self.decoder.table_index(index) // self.page_size
+                 + 1).sum()))
         pre_compiled = self.decoder.compiled_count
         self._step_launches += 1
         trace.lap("build")
@@ -1485,6 +1502,9 @@ class ServeEngine:
             trace.lap("chunk_sync")
         span.attrs.update(zip(self.decoder.model.stats_names,
                               (int(c) for c in counts)))
+        if self.decoder.summary is not None:
+            # what the decoder launched before this call's body
+            span.attrs["windows_closed"] = self.decoder.last_closed
         return out
 
     @staticmethod

@@ -563,6 +563,115 @@ def test_linear_state_serve_bodies_compile_for_v5e(v5e, body):
     assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
 
 
+@pytest.fixture(scope="module")
+def summary_decoder():
+    """The decoder whose layers keep a window of exact keys beside one
+    summary a chunk, at its benchmark widths (8 dense layers of width 4096,
+    32 KV heads of 128, a window of 2,048 and chunks of 16; 320 byte ids;
+    shapes only), 28 slots of 32,768 positions, PAGES OF 128 in a pool of
+    660."""
+    from dtf_tpu.models import build_model
+    model, _ = build_model(
+        "routed_decoder", num_classes=320, dtype=jnp.bfloat16, num_layers=8,
+        d_model=4096, num_heads=32, num_kv_heads=32, head_dim=128,
+        layer_window=[False], layer_rope=[True], rope_theta=1e5,
+        rms_eps=1e-5, norm_unit_offset=True, summary_window=2048,
+        summary_chunk=16, num_dense_layers=8, dense_width=11008,
+        activation="silu", max_seq_len=32768, param_dtype="bfloat16")
+    params = jax.eval_shape(model.clone(use_pallas=False).init,
+                            jax.random.key(0),
+                            jnp.zeros((1, 128), jnp.int32))["params"]
+    return _shapes_only_decoder(model, params, num_slots=28,
+                                max_seq_len=32768, kv_page_size=128,
+                                kv_pool_pages=661)
+
+
+def _entry_ops(text):
+    """op -> count over the ENTRY computation of an optimized HLO text,
+    parameters, constants and tuple plumbing left out."""
+    entry = text[text.index("\nENTRY "):]
+    ops = re.findall(r"^\s*(?:ROOT )?%\S+ = .*?\s([a-z\-]+)\(",
+                     entry[:entry.index("\n}")], re.M)
+    skip = {"parameter", "constant", "get-tuple-element", "tuple", "bitcast"}
+    return {op: ops.count(op) for op in set(ops) - skip}
+
+
+def test_a_windows_close_is_one_kernel_call_a_layer(v5e, summary_decoder):
+    """``serve_close_window`` compiled for the v5e, the cache donated: the
+    window's 16 table entries sliced out of the row, then ONE
+    ``window_compact`` call a layer on the K and V pools in place — no
+    ``while``, no fusion, no copy of a pool (the gathered form below is
+    what it replaces)."""
+    dec = summary_decoder
+    s, i32 = jax.ShapeDtypeStruct, jnp.int32
+    compiled = dec._close.lower(*_on_chip(
+        (dec.params, jax.eval_shape(dec.fresh_cache),
+         s((dec.pages_per_slot,), i32), s((), i32)), v5e)).compile()
+    text = compiled.as_text()
+    ops = _entry_ops(text)
+    assert ops.pop("custom-call") == 8 == text.count(
+        'custom_call_target="tpu_custom_call"')
+    assert text.count("window_compact") >= 8
+    assert ops.pop("dynamic-slice") == 1
+    # the slice's start, clamped: scalar arithmetic on the window's number
+    assert set(ops) <= {"add", "compare", "select", "copy", "multiply",
+                        "clamp"} and sum(ops.values()) <= 6, ops
+    assert " while(" not in text and " fusion(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e6
+
+
+def test_a_gathered_close_is_a_loop_of_a_page_a_trip(v5e):
+    """The oracle's form of one layer's close at the same sizes —
+    ``pool[pages]``, the summaries, ``.at[pages[:1]].set`` — compiled for
+    the v5e: the gather of 16 whole pages is a ``while`` of one page a
+    trip, K and V each.  A jax/libtpu that lowers it to one op fails
+    here, and the kernel can then go."""
+    from dtf_tpu.ops import window_summary as ws
+    bf16 = jnp.bfloat16
+    args = [jax.ShapeDtypeStruct((661, 128, 32, 128), bf16, sharding=v5e)] \
+        * 2 + [jax.ShapeDtypeStruct((16,), jnp.int32, sharding=v5e)] \
+        + [jax.ShapeDtypeStruct((32, 128), bf16, sharding=v5e)] * 2
+    text = jax.jit(functools.partial(ws.compact_window_reference, chunk=16),
+                   donate_argnums=(0, 1)).lower(*args).compile().as_text()
+    loops = [ln for ln in text.splitlines() if " while(" in ln]
+    assert len(loops) == 2 and all("/gather" in ln for ln in loops)
+
+
+@pytest.mark.parametrize("body", ["chunk_first", "chunk", "decode"])
+def test_window_summary_serve_bodies_compile_for_v5e(v5e, summary_decoder,
+                                                     body):
+    """Its bodies: the decode step scores a stored block of 128 rows x 32
+    heads all heads at once over the COMPACT index, a continuation chunk of
+    1,024 streams the same prefix of the table head pair by head pair (a
+    chunk of 2,048 does not compile: the carry of two heads x 2,048 query
+    rows and the double buffer of 1 MiB pages take 16.9 MB of the 16 MB of
+    scoped VMEM), a first chunk takes ``flash_fwd``; the pools are updated
+    in place."""
+    from dtf_tpu.serve import decode as sd
+    dec = summary_decoder
+    i32, f32 = jnp.int32, jnp.float32
+    assert dec.decode_all_heads and not dec.carries_state
+    assert (dec.pages_per_slot, dec.pages_for(32768)) == (256, 31)
+    if body == "decode":
+        compiled = _compile_decode_body(dec, v5e)
+    else:
+        s, m = jax.ShapeDtypeStruct, dec.pages_per_slot
+        args = _on_chip((dec.params, jax.eval_shape(dec.fresh_cache),
+                         s((1, 1024), i32), s((1, m), i32), s((), i32),
+                         s((), f32),
+                         jax.eval_shape(lambda: sd.position_key(0, 0)),
+                         s((), i32)), v5e)
+        compiled = jax.jit(
+            dec._chunk_impl, donate_argnums=(1,), static_argnums=(8, 9),
+            compiler_options=sd.TPU_BODY_OPTIONS).lower(
+                *args, None, body == "chunk_first").compile()
+    text = compiled.as_text()
+    kernel = "flash_fwd" if body == "chunk_first" else "paged_flash_decode"
+    assert text.count(kernel) >= 8 and " while(" not in text
+    # 11.09e9 B of pools are donated and updated in place
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
+
+
 def test_dense_decode_body_compiles_for_v5e(v5e):
     """The dense cells' whole decode body with ``TPU_BODY_OPTIONS``, not
     the kernel alone (what a kernel may take of VMEM depends on the body
