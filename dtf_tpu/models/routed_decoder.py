@@ -181,9 +181,63 @@ STATE_STATS = STATS + ("conv_tokens", "state_rows_advanced")
 LINEAR_STATS = ("linear_tokens", "state_rows_advanced")
 MIXERS = ("attention", "short_conv", "linear_delta")
 
-# grouped matmul tile (rows, contraction, columns): rows of one expert are
-# padded to a multiple of the first inside the kernel's own bookkeeping
-_GMM_ROWS = 128
+# VMEM the grouped product's blocks may take: the compiler's scoped limit
+# on the v5e is 16 MiB — compiled for it, a tile of (64, 2048, 1792) is
+# 15.92 MiB of blocks and passes; (320, 896, 2048) is refused for 16.21 MiB,
+# which is what :func:`gmm_blocks_bytes` counts to the KiB.  A compiler
+# that counts more fails ``tests/test_tpu_lowering.py``'s compile for the
+# v5e of that tile, not a served chunk
+_GMM_VMEM = 16 * 2 ** 20 - 2 ** 16
+
+
+def gmm_blocks_bytes(tm: int, tk: int, tn: int) -> int:
+    """VMEM the grouped product's blocks take at a tile: the bf16 rows
+    ``[tm, tk]`` and weights ``[tk, tn]`` and the f32 result ``[tm, tn]``
+    double-buffered, the f32 accumulator, and the store's mask of the
+    group's own rows, a byte an element."""
+    return 2 * (tm * tk * 2 + tk * tn * 2 + tm * tn * 4) + tm * tn * 5
+
+
+# rows a group from which a call is arithmetic and not a weight stream alone
+# (LFM2's chunks of 1,024 tokens and more; no other cell's call reaches it)
+_GMM_ARITHMETIC_FROM = 128
+
+
+def gmm_tile(pairs: int, groups: int, k: int, n: int):
+    """The tile ``(tm, tk, tn)`` of the grouped product of ``pairs`` sorted
+    rows with ``groups`` weights of ``[k, n]``: a function of the call's
+    static shape.  The measurements are in :func:`routed_experts`'
+    docstring.
+
+    Under ``_GMM_ARITHMETIC_FROM`` rows a group the call streams weights
+    and the sweep found every tile within 2 % of every other: it keeps the
+    tile every call had before the rule, row tiles of 128 and the first of
+    1280 / 768 / 512 / 256 / 128 columns that divides ``n``, so such a
+    call's kernel is the one measured since PR 27.  From there on the
+    kernel's reads of its rows count — once a column tile, seven times at
+    512 of 3,584 columns — so ``tn`` is the widest multiple of 128 dividing
+    ``n`` whose blocks fit ``_GMM_VMEM``, beside row tiles of 64 where
+    those of 128 leave no room for it (no row tile from 64 to 512 beat
+    another by more than 4 % at one ``tn`` with the rows as they lie).
+    The contraction stays whole, so that a group of several row tiles
+    reads its weights once, and is halved only while no column tile fits
+    beside it."""
+    if pairs < _GMM_ARITHMETIC_FROM * groups:
+        return 128, k, next(c for c in (1280, 768, 512, 256, 128, n)
+                            if n % c == 0)
+    columns = ([c for c in range(n, 0, -128) if n % c == 0]
+               if n % 128 == 0 else [n])
+
+    def widest(tm, tk):
+        return next((c for c in columns
+                     if gmm_blocks_bytes(tm, tk, c) <= _GMM_VMEM), 0)
+
+    tk = k
+    while not widest(64, tk) and tk % 256 == 0:
+        tk //= 2                        # no column tile fits beside it
+    # row tiles of 128 unless those of 64 let a wider column tile fit
+    tm = 64 if widest(64, tk) > widest(128, tk) else 128
+    return tm, tk, widest(tm, tk) or columns[-1]
 
 
 def rms_norm(x, scale, eps: float, unit_offset: bool = False):
@@ -303,10 +357,54 @@ def routed_experts(x, idx, weights, w_gate_up, w_down, *, use_pallas=None,
     on TPU, ``jax.lax.ragged_dot`` elsewhere), True, "interpret", False.
     On the v5e at 64 experts of 2560 x 768: 0.91 against 1.10 ms a layer
     at 16 tokens, 1.59 against 2.99 ms at 512 (Pallas against
-    ragged_dot); at 256 experts of 2048 x 768 and 8,192 pairs (32 rows an
-    expert) the row tile of 128 still wins: 4.41 / 4.60 / 4.86 / 5.49 ms a
-    layer at 128 / 64 / 32 / 16, and 1.7 ms at 192 pairs whatever the
-    tile.
+    ragged_dot).
+
+    The kernel's tile is :func:`gmm_tile`'s, from the call's shape.
+    Measured on one v5e chip, 2026-09-30 (``tools/gmm_sweep.py``, its
+    lines kept in ``docs/pr42_gmm_sweep.jsonl``: ms a layer, the two
+    products alone on the host's clock, group sizes a uniform router's
+    draw); before = row tiles of 128 and the first of 1280/768/512 columns
+    that divides; best = the sweep's best tile of each product:
+
+    =======================  ======  ======  ====  =====================
+    experts x K x f          pairs   before  best  the rule's tile
+    =======================  ======  ======  ====  =====================
+    32 x 2048 x 1792            384  1.10    1.09  as before: 128, K, 512
+    (LFM2: 12, 128, 192,       4096  1.88    1.69  64, K, 1792 (gate/up),
+    256 rows an expert)        6144  2.16    1.95  128, K, 1024 (down):
+    ..                         8192  2.43    2.21  1.71, 1.95, 2.21 ms
+    64 x 2560 x 768              96  0.88    0.87  as before
+    (SmallThinker)             6144  1.77    1.71  as before
+    256 x 2048 x 768            192  1.92    1.85  as before
+    (JoyAI)                   16384  5.13    4.69  as before
+    128 held x 2560 x 768       768  1.71    1.71  as before
+    (Ling; a quarter held)     8192  2.34    2.33  as before
+    =======================  ======  ======  ====  =====================
+
+    The kernel reads its rows once a column tile, so a long chunk's rows
+    went through seven times at 512 of 3,584 columns; where a call streams
+    weights the tile only sets the number of grid steps — and the size of
+    the kernel's unrolled code, which every start of a body pays: with
+    the widest tile in every call LFM2's nine bodies loaded in 33 s for 23
+    and ``setup_s`` rose 13 %.  So a call under 128 rows a group keeps the
+    tile of before.  What that leaves: JoyAI's 256 groups at one column
+    tile a product for two and four (0.70 -> 0.63 ms the down product at
+    192 pairs, 2.06 -> 1.71 at 16,384; its cell served 3 % more in the one
+    pair run so) — not taken here: its ``setup_s`` was not read warm.
+
+    What the rows' tile cannot cure: with 256 rows an expert the chunk's
+    two products take 2.2 ms where FLOPs and bytes allow 0.92, because a
+    row tile of 128 (or 64) feeds the MXU that many rows a weight load and
+    every group's edge tiles are computed for both neighbours.  A row
+    tile a group (288 rows, every group starting at a multiple of it)
+    took the two products to 1.32 ms under a uniform draw (the layer 2.67
+    -> 2.11) — and lost under a served one: the busiest expert of a chunk
+    holds twice the mean, so no row tile fits the groups, the padded rows
+    cost the activation and the gathers their bytes (at the worst case's
+    rows the layer read 2.59 for 2.67), and the cell served 1 % fewer
+    tokens.  Row tiles of 64 or 128 beside groups padded to them gave
+    what the rows as they lie give (1.42 ms the gate/up product either
+    way), so they lie as sorted.
 
     ``held`` (first id, count): the weights are those of the experts
     ``first .. first + count - 1`` alone — this device's share of a layer
@@ -337,15 +435,15 @@ def routed_experts(x, idx, weights, w_gate_up, w_down, *, use_pallas=None,
     if use_pallas:
         from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
         interpret = use_pallas == "interpret"
-        pad = -(t * k) % _GMM_ROWS
-        xs = jnp.pad(xs, ((0, pad), (0, 0)))
+        xs = jnp.pad(xs, ((0, -(t * k) % 128), (0, 0)))     # whole row tiles
 
         def grouped(lhs, rhs):
-            kk, n = rhs.shape[1:]
-            tile = (_GMM_ROWS, kk, next(c for c in (1280, 768, 512, 256, 128,
-                                                    n) if n % c == 0))
-            return gmm(lhs, rhs, sizes, jnp.float32, tile,
-                       interpret=interpret)
+            tile = gmm_tile(t * k, *rhs.shape)
+            # the tile rides the op's name into the compiled body's text,
+            # where the cost ledger's entry reads its kernels from
+            with jax.named_scope("gmm_%dx%dx%d" % tile):
+                return gmm(lhs, rhs, sizes, jnp.float32, tile,
+                           interpret=interpret)
     else:
         def grouped(lhs, rhs):
             return jax.lax.ragged_dot(lhs, rhs, sizes,

@@ -117,7 +117,10 @@ def cost_of(compiled) -> tuple:
             float(ca.get("bytes accessed", 0.0) or 0.0))
 
 
-_PALLAS_OP = re.compile(r'op_name="[^"]*?([\w.]+)/pallas_call')
+# the name in front of the call; where the kernel's library wraps the call
+# in a jit of its own (megablox ``jit(gmm)``), the scope the caller named
+_PALLAS_OP = re.compile(
+    r'op_name="[^"]*?([\w.]+)(?:/jit\(\w+\))?/pallas_call')
 
 
 def pallas_kernels(compiled) -> Dict[str, int]:

@@ -720,6 +720,24 @@ def test_ledger_peak_tables_match_bench_scripts():
         _sys.path.remove(repo)
 
 
+@pytest.mark.parametrize("op_name,want", [
+    ("jit(_decode_impl)/layers_3/paged_flash_decode/pallas_call",
+     "paged_flash_decode"),
+    # megablox wraps its call in a jit of its own: the caller's scope, which
+    # carries the tile the expert layer chose, is the kernel's name
+    ("jit(_chunk_impl)/layers_2/gmm_288x1024x1792/jit(gmm)/pallas_call",
+     "gmm_288x1024x1792"),
+    ("jit(f)/jit(gmm)/pallas_call", "pallas_call"),
+])
+def test_ledger_names_a_kernel_by_the_scope_in_front_of_its_call(op_name,
+                                                                 want):
+    from dtf_tpu.obs.ledger import _pallas_kernels
+    hlo = (f'  %k.1 = f32[8,128] custom-call(%a), custom_call_target='
+           f'"tpu_custom_call", metadata={{op_name="{op_name}"}}\n'
+           '  %other = f32[8] custom-call(%a), custom_call_target="Sharding"')
+    assert _pallas_kernels(hlo) == {want: 1}
+
+
 def test_ledger_mfu_crosschecked_against_cost_analysis(tmp_path,
                                                        monkeypatch):
     """The acceptance bar: the ledger's MFU for the compiled train

@@ -198,6 +198,34 @@ def test_grouped_expert_matmuls_compile_for_v5e(v5e, tokens):
     assert text.count('custom_call_target="tpu_custom_call"') >= 2
 
 
+@pytest.mark.parametrize("tokens", [96, 2048], ids=["decode", "chunk"])
+def test_grouped_expert_matmuls_of_32_wide_experts_compile_for_v5e(v5e,
+                                                                   tokens):
+    """The expert layer at 32 experts of 2048 x 1792 in bf16, gated SiLU,
+    top-4, at the tiles the rule gives: a decode step's 384 pairs at
+    (128, K, 512) twice, a long chunk's 8,192 with the gate/up product's
+    3,584 columns in two tiles beside row tiles of 64 — 15.92 of the
+    compiler's 16 MiB of scoped VMEM — and the down product's in two of
+    1,024, each named in the compiled text for the cost ledger's entry."""
+    from dtf_tpu.models.routed_decoder import gmm_tile, routed_experts
+    from dtf_tpu.obs.ledger import pallas_kernels
+    bf16 = jnp.bfloat16
+    shapes = (((tokens, 2048), bf16), ((tokens, 4), jnp.int32),
+              ((tokens, 4), jnp.float32), ((32, 2048, 3584), bf16),
+              ((32, 1792, 2048), bf16))
+    args = [jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+            for shape, dt in shapes]
+    compiled = jax.jit(functools.partial(
+        routed_experts, use_pallas=True, activation="silu")
+        ).lower(*args).compile()
+    assert pallas_kernels(compiled) == {
+        "gmm_%dx%dx%d" % gmm_tile(tokens * 4, 32, kk, n): 1
+        for kk, n in ((2048, 3584), (1792, 2048))}
+    # the device's op line still names both calls gmm: the benchmark's
+    # moe_experts_ms* and moe_experts_roofline* read ^gmm(\.|$)
+    assert len(re.findall(r"%gmm(?:\.\d+)? = ", compiled.as_text())) == 2
+
+
 def test_serve_bodies_compiler_options_are_the_v5e_compilers(v5e):
     """``serve/decode.py`` builds its two bodies with ``TPU_BODY_OPTIONS``.
     The TPU's compiler knows every name: one it does not know raises, as
