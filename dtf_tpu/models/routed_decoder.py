@@ -81,9 +81,13 @@ entry, so a page must be LARGE for an entry a page to be affordable (pages
 of 512-2,048 tokens, where a page of tokens weighs what a state weighs):
 the serving engine's page size is the deployment's to choose and nothing
 here fixes it.  A decode step advances a row's matrices in the kernel
-``linear_state_decode`` (the pool aliased in place), a chunk of whole
-pages through the blocked form, the full forward outside decode mode
-through the token-by-token recurrence.  Mixers of different kinds stand
+``linear_state_decode`` (the pool aliased in place; it takes the token's
+``q``, ``k``, ``beta k``, ``exp(a)`` and ``v`` as ROWS ``[B, H, D]`` and
+returns ``o`` so: a head's two reductions are one MXU product of its
+matrix AS STORED against the bfloat16 pieces of ``exp(a) k`` and ``exp(a)
+q``, float32 arithmetic whose form follows the pool's dtype), a chunk of
+whole pages through the blocked form, the full forward outside decode
+mode through the token-by-token recurrence.  Mixers of different kinds stand
 beside EITHER attention kind: ``layer_mixer`` decides a layer,
 ``kv_lora_rank`` what its attention layers are (``short_conv`` alone still
 wants whole heads).

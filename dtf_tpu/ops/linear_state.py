@@ -28,11 +28,33 @@ Three forms, the same numbers (``tests/test_linear_state.py``):
 ``step``        one token; :func:`paged_step` runs it through a pool of
                 state entries indexed by page id, and
                 :func:`linear_state_decode` is that as a kernel.
+
+**The kernel's arithmetic.**  With ``M`` the entry AS STORED, ``alpha =
+exp(a)``, ``kb = beta k`` and ``w = v - u``, both reductions of ``step`` can
+be taken over ``M`` before the decay and before the write:
+
+    u = (M . alpha) k = M (alpha . k)
+    o = new q         = M (alpha . q) + w (kb . q),   new = M . alpha + w kb^T
+
+so a head's two reductions are ONE product on the MXU, of the token's rows
+``alpha . k`` and ``alpha . q`` against the head's matrix as the copy left
+it, and ``u``, ``v``, ``w`` and ``o`` are ROWS over ``dv``; the VPU keeps
+what is elementwise (upcast, decay, the rank-one write, round).  The
+product is float32 arithmetic, not bfloat16's: a float32 is cut into three
+bfloat16 pieces that sum to it exactly (:func:`_pieces`), a bfloat16 x
+bfloat16 product is exact in float32 and the MXU accumulates in float32,
+so ``M x`` of a bfloat16 ``M`` against the three pieces of ``x`` is what
+``Precision.HIGHEST`` gives at half its passes — the left operand has no
+residue.  **The form follows ``pool.dtype``**: a bfloat16 pool's matrix
+goes to the MXU as stored (kernel ``linear_state_decode_mxu1x3``); any
+other pool's is cut in three as well, nine exact products
+(``..._mxu3x3``).  No flag chooses.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -169,19 +191,36 @@ def paged_step(pool, q, k, v, a, beta, block_table, index, *,
     return o, pool.at[dst].set(new.astype(pool.dtype))
 
 
-def _decode_kernel(tbl_ref, idx_ref, q_ref, k_ref, kb_ref, alpha_ref, vt_ref,
-                   pool_hbm, ot_ref, pool_out, sbuf, obuf, sem_in, sem_out,
-                   *, page_size: int):
+def _pieces(x):
+    """Three bfloat16 arrays that sum to float32 ``x`` EXACTLY: its top
+    eight significant bits, the top eight of what is left, and the (at
+    most eight) left after that.  Cut by a mask on the bits and not by a
+    rounding: a compiler that allows itself excess precision drops an
+    ``f32 -> bf16 -> f32`` round trip, and the two lower pieces with it
+    (the TPU's does: the first sweep of PR 43 read bfloat16's error)."""
+    def top(t):
+        bits = jax.lax.bitcast_convert_type(t, jnp.uint32)
+        return jax.lax.bitcast_convert_type(
+            bits & jnp.uint32(0xFFFF0000), jnp.float32)
+    x = x.astype(jnp.float32)
+    p1 = top(x)
+    p2 = top(x - p1)
+    return tuple(p.astype(jnp.bfloat16) for p in (p1, p2, x - p1 - p2))
+
+
+def _row_pipeline(tbl_ref, idx_ref, pool_hbm, pool_out, sbuf, obuf, sem_in,
+                  sem_out, advance, *, page_size: int):
     """Grid (B,): row ``b``'s state — every head's ``[dv, dk]`` entry, one
-    contiguous block of its page — is copied in, advanced one token and
+    contiguous block of its page — is copied in to ``sbuf[slot]``,
+    ``advance(slot)`` leaves its successor in ``obuf[slot]``, and that is
     copied out to the page that holds ``index``.  Row ``b + 1``'s copy-in
     is started before row ``b``'s arithmetic and row ``b``'s copy-out is
     waited for two rows later, so the copies' latency hides behind the
     neighbours' work: the kernel's floor is the bytes of the state, read
-    once and written once."""
+    once and written once.  A row at ``index`` 0 has no carry: its
+    ``sbuf[slot]`` is zeroed before ``advance``."""
     b, rows = pl.program_id(0), pl.num_programs(0)
     m_pages = tbl_ref.shape[1]
-    heads = sbuf.shape[1]
     slot = b % 2
 
     def fetch(r, s):
@@ -208,23 +247,11 @@ def _decode_kernel(tbl_ref, idx_ref, q_ref, k_ref, kb_ref, alpha_ref, vt_ref,
     def _reuse():
         store(b - 2, slot).wait()
 
-    live = idx_ref[b] > 0
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, heads), 1)
+    @pl.when(idx_ref[b] <= 0)
+    def _no_carry():
+        sbuf[slot] = jnp.zeros(sbuf.shape[1:], sbuf.dtype)
 
-    def head(h, ot):
-        row = pl.ds(h, 1)
-        m = jnp.where(live, sbuf[slot, h].astype(jnp.float32), 0.0)
-        md = m * alpha_ref[row, :]
-        u = jnp.sum(md * k_ref[row, :], axis=1, keepdims=True)
-        mine = lane == h
-        v = jnp.sum(jnp.where(mine, vt_ref[...], 0.0), axis=1,
-                    keepdims=True)
-        new = md + (v - u) * kb_ref[row, :]
-        obuf[slot, h] = new.astype(obuf.dtype)
-        o = jnp.sum(new * q_ref[row, :], axis=1, keepdims=True)
-        return jnp.where(mine, o, ot)
-    ot_ref[...] = jax.lax.fori_loop(
-        0, heads, head, jnp.zeros(ot_ref.shape, jnp.float32))
+    advance(slot)
     store(b, slot).start()
 
     @pl.when(b == rows - 1)
@@ -236,40 +263,117 @@ def _decode_kernel(tbl_ref, idx_ref, q_ref, k_ref, kb_ref, alpha_ref, vt_ref,
             store(b - 1, 1 - slot).wait()
 
 
+def _state_call(kernel, pool, block_table, index, operands, in_specs,
+                out_spec, out_shape, *, name: str, interpret: bool = False):
+    """``kernel`` over a grid of rows, table and positions prefetched, the
+    pool left in HBM and aliased to the second result, two slots of a
+    row's matrices in and two out."""
+    entry = pool.shape[1:]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(out_shape[0],),
+        in_specs=list(in_specs) + [pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=[out_spec, pl.BlockSpec(memory_space=pl.ANY)],
+        scratch_shapes=[pltpu.VMEM((2,) + entry, pool.dtype),
+                        pltpu.VMEM((2,) + entry, pool.dtype),
+                        pltpu.SemaphoreType.DMA((2,)),
+                        pltpu.SemaphoreType.DMA((2,))])
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(out_shape, jnp.float32),
+                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
+        input_output_aliases={2 + len(operands): 1},
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=32 * 2 ** 20),
+        name=name,
+    )(jnp.asarray(block_table, jnp.int32), jnp.asarray(index, jnp.int32),
+      *operands, pool)
+
+
+_NT = (((1,), (1,)), ((), ()))      # [r, k] x [v, k] -> [r, v]
+
+
+def _decode_kernel(tbl_ref, idx_ref, q_ref, k_ref, kb_ref, alpha_ref, v_ref,
+                   pool_hbm, o_ref, pool_out, sbuf, obuf, sem_in, sem_out,
+                   *, page_size: int, split: bool):
+    """One row's heads, ``th`` (8: a vreg's sublanes) a tile.  A tile's
+    ``alpha . k`` and ``alpha . q`` are two ``[th, dk]`` vregs; their six
+    pieces stacked are the ``[6 th, dk]`` bfloat16 operand that meets EVERY
+    head of the tile (head ``j`` reads row ``j`` of each piece's block of
+    the result, already on sublane ``j`` of the tile's ``u`` and of its
+    ``M (alpha . q)``: no shuffle, and the MXU has rows to spare beside a
+    128-row weight load).  ``v``, ``w`` and ``o`` are then ONE ``[th, dv]``
+    tile each, ``w``'s tile is transposed ONCE for the tile's ``th``
+    columns, and each head's entry is decayed, written and rounded on the
+    VPU.  ``split``: the matrix is cut in three pieces too (a pool that is
+    not bfloat16).  48 lane reductions a head (PR 39's form) became one
+    weight load, an eighth of a transposition and 16 lane broadcasts: 0.699
+    -> 0.315 ms a call of 96 rows x 32 heads x 128 x 128 bfloat16, 35 ->
+    78 % of the bytes' roofline (``docs/pr43_state_kernel_sweep.jsonl``);
+    two tiles an iteration of the loop so that one's products run under
+    the other's writes (one: 0.336 ms; the per-head form of the same
+    arithmetic without unrolling: 1.40 ms, the MXU's latency a head)."""
+    heads, dv = sbuf.shape[1:3]
+    th = math.gcd(heads, 8)
+    tiles = 1 if heads // th % 2 else 2
+    sub = jax.lax.broadcasted_iota(jnp.int32, (th, dv), 0)
+    f32 = jnp.float32
+
+    def advance(slot):
+        def tile(t):
+            at = pl.multiple_of(t * th, th)
+            rows = pl.ds(at, th)
+            alpha, kb, q = alpha_ref[rows, :], kb_ref[rows, :], q_ref[rows, :]
+            lhs = jnp.concatenate(
+                _pieces(alpha * k_ref[rows, :]) + _pieces(alpha * q), 0)
+            u = p = jnp.zeros((th, dv), f32)
+            for j in range(th):
+                m = sbuf[slot, at + j]
+                res = sum(jax.lax.dot_general(lhs, part, _NT,
+                                              preferred_element_type=f32)
+                          for part in (_pieces(m) if split else (m,)))
+                res = res.reshape(6, th, dv)
+                u = jnp.where(sub == j, res[0] + res[1] + res[2], u)
+                p = jnp.where(sub == j, res[3] + res[4] + res[5], p)
+            w = v_ref[rows, :] - u
+            o_ref[rows, :] = p + w * jnp.sum(kb * q, axis=1, keepdims=True)
+            wt = jnp.transpose(w)                       # [dv, th]
+            for j in range(th):
+                row = pl.ds(at + j, 1)
+                new = (sbuf[slot, at + j].astype(f32) * alpha_ref[row, :]
+                       + wt[:, j:j + 1] * kb_ref[row, :])
+                obuf[slot, at + j] = new.astype(obuf.dtype)
+
+        def group(i, carry):
+            for j in range(tiles):
+                tile(i * tiles + j)
+            return carry
+        jax.lax.fori_loop(0, heads // (th * tiles), group, 0)
+    _row_pipeline(tbl_ref, idx_ref, pool_hbm, pool_out, sbuf, obuf, sem_in,
+                  sem_out, advance, page_size=page_size)
+
+
 @functools.partial(jax.jit, static_argnames=("page_size", "interpret"))
 def linear_state_decode(pool, q, k, v, a, beta, block_table, index, *,
                         page_size: int, interpret: bool = False):
     """:func:`paged_step` as a kernel, the pool updated IN PLACE (it is
-    aliased to the result; the serving body donates its cache).  Rows with an all-zero table read
-    and write the scratch page 0.  Jitted, so that the layers of a model
-    share one lowering."""
+    aliased to the result; the serving body donates its cache).  Rows with
+    an all-zero table read and write the scratch page 0.  Jitted, so that
+    the layers of a model share one lowering.  The kernel's name says which
+    form ``pool.dtype`` chose (the module's docstring)."""
     b, h, dk = q.shape
     dv = v.shape[-1]
     f32 = jnp.float32
     q, k, v, a, beta = (x.astype(f32) for x in (q, k, v, a, beta))
+    split = pool.dtype != jnp.bfloat16
 
     def rows(lanes):
         return pl.BlockSpec((None, h, lanes), lambda r, tbl, idx: (r, 0, 0))
-    vt_spec = pl.BlockSpec((None, dv, h), lambda r, tbl, idx: (r, 0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2, grid=(b,),
-        in_specs=[rows(dk), rows(dk), rows(dk), rows(dk), vt_spec,
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=[vt_spec, pl.BlockSpec(memory_space=pl.ANY)],
-        scratch_shapes=[pltpu.VMEM((2, h, dv, dk), pool.dtype),
-                        pltpu.VMEM((2, h, dv, dk), pool.dtype),
-                        pltpu.SemaphoreType.DMA((2,)),
-                        pltpu.SemaphoreType.DMA((2,))])
-    ot, pool = pl.pallas_call(
-        functools.partial(_decode_kernel, page_size=page_size),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((b, dv, h), f32),
-                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
-        input_output_aliases={7: 1}, interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=32 * 2 ** 20),
-        name="linear_state_decode",
-    )(jnp.asarray(block_table, jnp.int32), jnp.asarray(index, jnp.int32),
-      q, k, beta[..., None] * k, jnp.exp(a), jnp.swapaxes(v, 1, 2), pool)
-    return jnp.swapaxes(ot, 1, 2), pool
+    return _state_call(
+        functools.partial(_decode_kernel, page_size=page_size, split=split),
+        pool, block_table, index,
+        (q, k, beta[..., None] * k, jnp.exp(a), v),
+        [rows(dk)] * 4 + [rows(dv)], rows(dv), (b, h, dv),
+        name="linear_state_decode_mxu" + ("3x3" if split else "1x3"),
+        interpret=interpret)

@@ -203,6 +203,66 @@ def test_kernel_equals_the_step_in_place(dtype):
     assert written == [0, 3, 4, 7]      # scratch; 32 // 16 -> 3; 4; 7
 
 
+@pytest.mark.parametrize("case", ["no_carry", "page_boundary", "idle_row",
+                                  "decay_floor", "inside_a_page"])
+def test_the_bf16_pools_form_is_float32_arithmetic(case):
+    """The form a bfloat16 pool takes — the stored matrix against the three
+    bfloat16 pieces of ``alpha k`` and ``alpha q`` on the MXU — held to the
+    float32 statement: against ``paged_step`` evaluated in float64 (numpy)
+    on the same bfloat16 pool, ``o`` within the bound the float32 pool's
+    case holds, and every stored entry within ONE bfloat16 spacing of the
+    oracle's rounded entry (plus float32's own rounding of the terms it
+    adds, 2^-22 of them: it shows only where they cancel to less than a
+    spacing).  One bfloat16 pass (the pieces dropped) reads 1e-3 here.
+    16 heads of 32 x 32: two tiles of eight, the cell's loop.  Row 0 is
+    the case, row 1 an ordinary row beside it."""
+    rng = np.random.default_rng(43)
+    h, d = 16, 32
+    pool = jnp.asarray(rng.normal(size=(9, h, d, d)), jnp.bfloat16)
+    table0, index0 = {"no_carry": ([1, 2, 3], 0),
+                      "page_boundary": ([1, 2, 3], 2 * PAGE),
+                      "idle_row": ([0, 0, 0], 0),
+                      "decay_floor": ([1, 2, 3], PAGE + 3),
+                      "inside_a_page": ([1, 2, 3], 5)}[case]
+    tables = np.asarray([table0, [4, 5, 6]], np.int32)
+    index = np.asarray([index0, PAGE + 1], np.int32)
+    q, k, v, a, beta = (t[:, 0] for t in _draw(rng, 2, 1, h, d))
+    if case == "decay_floor":
+        a[0] = -5.0
+    got_o, got = ls.linear_state_decode(
+        pool, *(jnp.asarray(t) for t in (q, k, v, a, beta)),
+        jnp.asarray(tables), jnp.asarray(index), page_size=PAGE,
+        interpret=True)
+
+    q, k, v, a, beta = (np.asarray(t, np.float64)
+                        for t in (q, k, v, a, beta))
+    was = np.asarray(pool.astype(jnp.float32), np.float64)
+    rows = np.arange(2)
+    src = tables[rows, np.maximum(index - 1, 0) // PAGE]
+    dst = tables[rows, index // PAGE]
+    md = (np.where((index > 0)[:, None, None, None], was[src], 0.0)
+          * np.exp(a)[:, :, None, :])
+    u = np.einsum("bhvk,bhk->bhv", md, k)
+    rank = (beta[..., None] * (v - u))[..., None] * k[:, :, None, :]
+    want_o = np.einsum("bhvk,bhk->bhv", md + rank, q)
+    np.testing.assert_allclose(np.asarray(got_o, np.float64), want_o,
+                               rtol=1e-5, atol=1e-6)
+    got = np.asarray(got.astype(jnp.float32), np.float64)
+    want = np.asarray(jnp.asarray(md + rank, jnp.float32).astype(
+        jnp.bfloat16).astype(jnp.float32), np.float64)
+    spacing = 2.0 ** (np.floor(np.log2(np.maximum(np.abs(want), 1e-30)))
+                      - 7)
+    f32_rounding = 2.0 ** -22 * (np.abs(md) + np.abs(rank) + np.abs(
+        (beta[..., None] * np.einsum("bhvk,bhk->bhv", np.abs(md), np.abs(k))
+         )[..., None] * k[:, :, None, :]))
+    assert (np.abs(got[dst] - want) <= spacing + f32_rounding).all()
+    untouched = np.setdiff1d(np.arange(9), dst)
+    assert (got[untouched] == was[untouched]).all()
+    assert sorted(set(dst.tolist())) == {
+        "no_carry": [1, 5], "page_boundary": [3, 5], "idle_row": [0, 5],
+        "decay_floor": [2, 5], "inside_a_page": [1, 5]}[case]
+
+
 # ------------------ (b) the whole model through pages and entries ------
 def _prefill(dec, cache, prompt, table, start=0):
     for s, clen in chunk_plan(len(prompt), CHUNK, PAGE, start):

@@ -448,7 +448,9 @@ def test_state_carrying_serve_bodies_compile_for_v5e(v5e, body):
 def test_linear_state_decode_compiles_for_v5e(v5e, dtype):
     """The state kernel at the benchmark's shapes: 96 rows of 32 heads of
     128 x 128 over a pool of 385 pages (0.40e9 B in bfloat16), the pool
-    aliased to the result — one pool in, none made."""
+    aliased to the result — one pool in, none made.  The kernel's name
+    carries the form ``pool.dtype`` chose: the stored bfloat16 matrix
+    straight to the MXU, any other cut in three pieces first."""
     from dtf_tpu.ops import linear_state
     f32, i32 = jnp.float32, jnp.int32
     b, h, d, pool, m = 96, 32, 128, 385, 12
@@ -459,7 +461,12 @@ def test_linear_state_decode_compiles_for_v5e(v5e, dtype):
     compiled = jax.jit(
         functools.partial(linear_state.linear_state_decode, page_size=1024),
         donate_argnums=(0,)).lower(*args).compile()
-    assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
+    text = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text
+    mine, other = (("mxu1x3", "mxu3x3") if dtype == jnp.bfloat16
+                   else ("mxu3x3", "mxu1x3"))
+    assert f"linear_state_decode_{mine}" in text
+    assert f"linear_state_decode_{other}" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 1e6
 
 
@@ -585,6 +592,9 @@ def test_linear_state_serve_bodies_compile_for_v5e(v5e, body):
     assert text.count("paged_flash_decode") >= 1        # the latent layer
     if body == "decode":
         assert text.count("linear_state_decode") >= 7   # a call a layer
+        # ... in the form of a bfloat16 pool, every one of them
+        assert text.count("linear_state_decode_mxu1x3") >= 7
+        assert "linear_state_decode_mxu3x3" not in text
         # the filter inputs' 96 entries a layer: a scatter, not a loop
         assert _scatter_loops(text) == (0, 0)
     # 3.53e9 B of pool and entries are donated and updated in place
