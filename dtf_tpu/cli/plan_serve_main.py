@@ -1,7 +1,7 @@
 """Serving-capacity planner CLI — replay, rank, and calibrate fleet
 configs (the serving sibling of plan_main).
 
-Answer capacity what-ifs from a RECORDED trace (a traced bench_serve /
+Answer capacity what-ifs from a RECORDED trace (a traced serve_main /
 router run — ``--trace`` accepts the trace dir; service times come
 from the run's own ledger/span records):
 
@@ -21,7 +21,7 @@ source):
       --process burst --decode_step_ms 12 --prefill_chunk_ms 9 \
       --chips 16
 
-Calibration (the ci_check stage-11 contract, PR-5 ``--calibrate``
+Calibration (the ci_check stage-10 contract, PR-5 ``--calibrate``
 shape): record a LIVE traced engine run, reconstruct the workload and
 service profile from that trace alone, replay it through the
 simulator, and compare predicted tokens/s and p99 latency against the

@@ -96,7 +96,6 @@ def make_input_fns(cfg: Config, spec: DatasetSpec, global_batch: int):
             train_fn = lambda start_step=0: imagenet_input_fn(
                 cfg.data_dir, True, host_batch, seed=cfg.seed,
                 num_threads=cfg.datasets_num_private_threads,
-                fast_dct=cfg.input_fast_dct,
                 scaled_decode=cfg.input_scaled_decode,
                 wire=cfg.input_wire, start_step=start_step)
         fns = (

@@ -44,9 +44,9 @@ replica 1 died?".  Spans sort by their START time (``ts``), so a long
 span appears where it began, interleaved with what ran under it.
 Composes with ``--check``.
 
-``--check`` is the CI/bench contract: exit 0 only when the trace
+``--check`` is the CI contract: exit 0 only when the trace
 contains NO anomaly records (nan_loss, step_time_regression, ...), so a
-bench script can assert a run was clean with one command.
+script can assert a run was clean with one command.
 
 ``--allow <kind>`` (repeatable) declares EXPECTED anomalies: a chaos
 run asserts "the injected fault fired and nothing else broke" with

@@ -60,14 +60,10 @@ class Config:
     all_reduce_alg: Optional[str] = None  # --all_reduce_alg (cifar_main.py:104)  # dtflint: disable=flag-dead (declared reference-parity no-op: XLA picks the collective on TPU)
     num_packs: int = 1                  # --num_packs gradient packing  # dtflint: disable=flag-dead (declared reference-parity no-op: XLA fuses collectives)
     datasets_num_private_threads: Optional[int] = None  # input pipeline threads
-    # JDCT_IFAST decode in the native train pipeline: ±1-2 LSB vs the
-    # default ISLOW (augmentation-noise territory), measurably faster —
-    # a throughput opt-in, never a default
-    input_fast_dct: bool = False
     # DCT-space 1/2–1/8 scaled decode (libjpeg scale_denom) for train
     # crops >=2x the output size: skips most IDCT work on large crops.
     # Changes the downsampling filter chain (scaled decode + bilinear
-    # vs pure bilinear) — another throughput opt-in, never a default
+    # vs pure bilinear) — a throughput opt-in, never a default
     input_scaled_decode: bool = False
     # Host→device batch wire for the real-data pipelines.  "uint8"
     # (default, TPU-native): raw pixels over the wire — 4x fewer bytes
@@ -268,7 +264,7 @@ class Config:
     # reduce-scatter/all-gather probes plus a comm-stubbed twin of the
     # compiled step, and export train_zero_*_wall_s +
     # train_exposed_comm_frac gauges through the MFU ledger.  Costs one
-    # extra step compile — a bench/smoke lever, not a production
+    # extra step compile — a smoke lever, not a production
     # default
     zero_probe: bool = False
 
@@ -336,7 +332,7 @@ class Config:
     router_hedge_s: float = 0.0
     # placement policy: prefix-affine (route by chained prompt-page
     # digest to the replica whose PrefixRegistry is warm, least-loaded
-    # fallback) | least_loaded | random (the bench A/B arm)
+    # fallback) | least_loaded | random (the comparison arm)
     router_placement: str = "affinity"
     # disaggregation: replicas 0..N-1 form a prefill-specialized pool,
     # the rest a decode pool — cold prompts prefill in the first,
@@ -388,8 +384,8 @@ class Config:
     # slice of live greedy traffic mirrored to the canary (0, 1]
     rollout_mirror_fraction: float = 1.0
     # gate threshold on diverged/compared; 0.0 = token-exact (any
-    # single divergence rolls back — the bench_gate discipline:
-    # identical models compare EQUAL, so a mismatch is signal)
+    # single divergence rolls back: identical models compare EQUAL,
+    # so a mismatch is signal)
     rollout_max_divergence: float = 0.0
     # how long a restarted replica gets to warm + re-register before
     # the rollout declares the new checkpoint unserveable + rolls back

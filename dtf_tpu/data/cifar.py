@@ -51,7 +51,7 @@ def write_binary_file(path: str, images: np.ndarray,
                       labels: np.ndarray) -> None:
     """Write records in the CIFAR binary wire format: 1 label byte +
     3072 CHW image bytes each (cifar_preprocessing.py:30-33).  The
-    inverse of :func:`load_records`; used by tests and run_record.py to
+    inverse of :func:`load_records`; used by tests to
     synthesize datasets the production reader consumes."""
     images = np.asarray(images, np.uint8)
     labels = np.asarray(labels)

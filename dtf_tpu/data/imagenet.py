@@ -256,7 +256,6 @@ def imagenet_input_fn(data_dir: str, is_training: bool, batch_size: int,
                       process_id: Optional[int] = None,
                       process_count: Optional[int] = None,
                       drop_remainder: bool = True,
-                      fast_dct: bool = False,
                       scaled_decode: bool = False,
                       stats: Optional[dict] = None,
                       wire: str = "float32", start_step: int = 0) -> Iterator:
@@ -265,8 +264,8 @@ def imagenet_input_fn(data_dir: str, is_training: bool, batch_size: int,
 
     ``wire``: host→device batch format.  ``"float32"`` = mean-subtracted
     f32 (r1-r3 behavior); ``"uint8"`` = raw post-resize pixels rounded
-    half-up — 4x fewer bytes per batch (RUN_r03 measured the f32 wire
-    transfer-bound at 38 MB/batch) — with mean subtraction deferred to
+    half-up — 4x fewer bytes per batch (38 MB a float32 batch of 64)
+    — with mean subtraction deferred to
     the compiled step (data/normalize.py imagenet_mean_subtract).
 
     ``stats``: pass a dict to collect per-batch timing from the native
@@ -274,7 +273,7 @@ def imagenet_input_fn(data_dir: str, is_training: bool, batch_size: int,
     sampling), native_s (GIL-released fused C++ decode) and batches are
     accumulated in place.  The Python share serializes across worker
     threads, so py_s per batch is the Amdahl floor on multi-core
-    scaling (bench_input.py reports the derived ceiling).
+    scaling.
 
     Eval modes:
       - ``drop_remainder=False`` (config default): eval FILES are
@@ -328,7 +327,7 @@ def imagenet_input_fn(data_dir: str, is_training: bool, batch_size: int,
     out_q: queue.Queue = queue.Queue(maxsize=64)
     stop = threading.Event()
     # the lock is published through the stats dict so readers
-    # (bench_input) can snapshot consistently with the writers
+    # can snapshot consistently with the writers
     stats_lock = threading.Lock()
     if stats is not None:
         stats["lock"] = stats_lock
@@ -446,7 +445,7 @@ def imagenet_input_fn(data_dir: str, is_training: bool, batch_size: int,
                         nj.train_example_batch(
                             chunk, batch_seed, DEFAULT_IMAGE_SIZE,
                             DEFAULT_IMAGE_SIZE, CHANNEL_MEANS,
-                            num_threads=1, fast_dct=fast_dct,
+                            num_threads=1,
                             scaled_decode=scaled_decode,
                             out_u8=u8_native)
                     if u8 and not u8_native:
@@ -484,7 +483,7 @@ def imagenet_input_fn(data_dir: str, is_training: bool, batch_size: int,
                 images, ok = nj.decode_crop_resize_batch(
                     bufs, crops, flips, DEFAULT_IMAGE_SIZE,
                     DEFAULT_IMAGE_SIZE, CHANNEL_MEANS, num_threads=1,
-                    fast_dct=fast_dct, scaled_decode=scaled_decode,
+                    scaled_decode=scaled_decode,
                     out_u8=u8_native)
                 if u8 and not u8_native:
                     images = _meansub_to_u8(images, ok)

@@ -4,9 +4,8 @@ TPU-first placement of the reference's normalization ops: the reference
 runs mean subtraction / per-image standardization inside its C++ graph
 runtime (imagenet_preprocessing.py:397-430, cifar_preprocessing.py:98);
 the TPU-native home for that math is the chip.  Pipelines ship uint8
-HWC batches — 4x fewer host→device bytes than a float32 wire, the
-measured bottleneck of both r3 recorded runs (RUN_r03.json:
-38 MB/batch ImageNet transfer-bound at 28.6 img/s) — and the dataset's
+HWC batches — 4x fewer host→device bytes than a float32 wire (an
+ImageNet batch of 64 is 38 MB in float32) — and the dataset's
 normalization runs in f32 as the FIRST op inside the jitted train/eval
 step, where XLA fuses it into the consuming convolution's input.
 

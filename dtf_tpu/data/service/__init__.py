@@ -1,10 +1,10 @@
 """Host-side data service: multi-process sharded deterministic readers
 with a decode-once cache tier.
 
-Why this exists (BENCH_r05): one host core supplies ~278 images/s while
-a chip demands 2590 — ~9.3 cores per chip — and the remaining serial
-fraction of the legacy pipeline is GIL-held Python, so threads cannot
-close the gap.  This package scales decode across spawned PROCESSES and
+Why this exists: one host core decodes a fraction of the images a chip
+consumes (the rates are not measured on this installation), and the
+remaining serial fraction of the legacy pipeline is GIL-held Python, so
+threads cannot close the gap.  This package scales decode across spawned PROCESSES and
 makes every batch a pure function of position, which simultaneously
 closes the PR-4 correctness leftover: killed-at-K resume on imagenet is
 bit-exact, not best-effort re-keyed.

@@ -1,8 +1,8 @@
 """Multi-process shard worker pool + deterministic merged stream.
 
 The host-side data service: ``num_shards`` ShardReaders served by
-``num_workers`` SPAWNED processes (processes, not threads — the Amdahl
-serial fraction bench_input.py measures is GIL-held Python, so thread
+``num_workers`` SPAWNED processes (processes, not threads — the serial
+fraction of the threaded pipeline is GIL-held Python, so thread
 pools stop scaling at one core's worth of Python), merged into one
 stream whose order is a pure function of position:
 
@@ -184,7 +184,7 @@ class ServiceStream:
         self._close_lock = threading.Lock()
         self._closed = False
         self.respawns = 0
-        # obs wiring (default registry unless a bench injects its own)
+        # obs wiring (default registry unless a caller injects its own)
         if registry is None:
             from dtf_tpu.obs.registry import default_registry
             registry = default_registry()
@@ -346,7 +346,7 @@ class ServiceStream:
     def cache_stats(self) -> Tuple[int, int]:
         """Cumulative (hits, lookups) across every shard since the
         stream was built — snapshot before/after a window to get a
-        windowed ratio (the bench does)."""
+        windowed ratio."""
         return (sum(h for h, _ in self._cache_stats.values()),
                 sum(lk for _, lk in self._cache_stats.values()))
 

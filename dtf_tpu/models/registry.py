@@ -46,10 +46,8 @@ _REGISTRY = {
     # bill output_tiles × ceil(d/128) full passes (a 64-deep matmul
     # measures 0.7-1.3× the wall time of the 128-deep one at half the
     # FLOPs), so head-packing constructions cancel exactly, and 12
-    # heads compute 2× the softmax score elements.  Measured: flash
-    # f+b 5.0 vs 11.2 ms at the flagship shapes — 2.2×, +33%
-    # end-to-end tokens/s for this layout (bench_lm.py --variant
-    # dhead holds the reproducible probe)
+    # heads compute 2× the softmax score elements.  Its size end to
+    # end is not measured on this installation.
     "transformer_tpu": (
         functools.partial(transformer.TransformerLM, num_layers=12,
                           d_model=768, num_heads=6, d_ff=3072),

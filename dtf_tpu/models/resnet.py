@@ -153,53 +153,6 @@ class Conv1SpaceToDepth(nn.Module):
             padding="VALID", dimension_numbers=("NHWC", "HWIO", "NHWC"))
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def _conv_fp8_resid(x, w, strides, padding):
-    """Convolution whose backward reads an fp8(e4m3) copy of the input
-    activation instead of the bf16 original ("lower-precision activation
-    storage", docs/DESIGN.md byte-lever probe).  dx is exact (needs only
-    w and the cotangent); dW sees the quantized activations."""
-    return lax.conv_general_dilated(
-        x, w, strides, padding, dimension_numbers=("NHWC", "HWIO", "NHWC"))
-
-
-def _conv_fp8_fwd(x, w, strides, padding):
-    y = _conv_fp8_resid(x, w, strides, padding)
-    return y, (x.astype(jnp.float8_e4m3fn), w)
-
-
-def _conv_fp8_bwd(strides, padding, res, g):
-    x8, w = res
-    x = x8.astype(w.dtype)
-    _, vjp = jax.vjp(
-        lambda xx, ww: lax.conv_general_dilated(
-            xx, ww, strides, padding,
-            dimension_numbers=("NHWC", "HWIO", "NHWC")), x, w)
-    return vjp(g)
-
-
-_conv_fp8_resid.defvjp(_conv_fp8_fwd, _conv_fp8_bwd)
-
-
-class Fp8ResidConv(nn.Module):
-    """nn.Conv-compatible (no-bias, feature-last) conv storing its
-    backward activation residual in fp8.  Parameter tree path matches
-    nn.Conv ('kernel'), so the L2 rule and checkpoints line up."""
-    features: int
-    kernel_size: Sequence[int]
-    strides: Sequence[int] = (1, 1)
-    padding: str = "SAME"
-    dtype: Any = jnp.float32
-
-    @nn.compact
-    def __call__(self, x):
-        kh, kw = self.kernel_size
-        w = self.param("kernel", conv_init,
-                       (kh, kw, x.shape[-1], self.features), jnp.float32)
-        return _conv_fp8_resid(x, w.astype(self.dtype),
-                               tuple(self.strides), self.padding)
-
-
 class BottleneckBlock(nn.Module):
     """conv_block / identity_block of reference resnet_model.py:46-221."""
     filters: Sequence[int]
@@ -207,19 +160,15 @@ class BottleneckBlock(nn.Module):
     projection: bool = False
     dtype: Any = jnp.float32
     bn_axis: Any = None  # axis_name for cross-replica (sync) BN
-    fp8_residuals: bool = False  # byte-lever probe, see Fp8ResidConv
 
     @nn.compact
     def __call__(self, x, train: bool = True):
         f1, f2, f3 = self.filters
-        if self.fp8_residuals and train:
-            conv = partial(Fp8ResidConv, dtype=self.dtype)
-        else:
-            conv = partial(nn.Conv, use_bias=False, kernel_init=conv_init,
-                           dtype=self.dtype, param_dtype=jnp.float32)
+        conv = partial(nn.Conv, use_bias=False, kernel_init=conv_init,
+                       dtype=self.dtype, param_dtype=jnp.float32)
         # dtype=self.dtype keeps activations bf16 between convs (half the
-        # HBM traffic of fp32 BN I/O — the r1 bench's top time sink); the
-        # mean/var math itself is still fp32 (flax _compute_stats upcasts)
+        # HBM traffic of fp32 BN I/O); the mean/var math itself is still
+        # fp32 (flax _compute_stats upcasts)
         bn = partial(TaggedBatchNorm, use_running_average=not train,
                      axis_name=self.bn_axis,
                      momentum=BATCH_NORM_DECAY, epsilon=BATCH_NORM_EPSILON,
@@ -256,9 +205,6 @@ class ResNet50(nn.Module):
     # RESNET_REMAT_POLICY).  A bytes lever, not a memory one — the step
     # is HBM-bound.  Identical math either way.
     remat: bool = False
-    # store conv input residuals in fp8 for the backward wgrad (probe;
-    # changes dW numerics — see Fp8ResidConv)
-    fp8_residuals: bool = False
 
     @nn.compact
     def __call__(self, x, train: bool = True):
@@ -304,12 +250,10 @@ class ResNet50(nn.Module):
         for s, (filters, blocks, stride) in enumerate(stages, start=2):
             x = block_cls(filters, strides=stride, projection=True,
                           dtype=self.dtype, bn_axis=self.bn_axis,
-                          fp8_residuals=self.fp8_residuals,
                           name=f"stage{s}_block0")(x, train)
             for b in range(1, blocks):
                 x = block_cls(filters, dtype=self.dtype,
                               bn_axis=self.bn_axis,
-                              fp8_residuals=self.fp8_residuals,
                               name=f"stage{s}_block{b}")(x, train)
 
         x = jnp.mean(x, axis=(1, 2))

@@ -28,15 +28,12 @@ Design (TPU-first):
   - `remat_policy="dots"` is the selective variant: matmul outputs and
     the flash-attention output stay saved (no MXU work is recomputed),
     only LayerNorm/GELU/bias-add intermediates recompute in the
-    backward.  Measured on v5e (flagship recipe): a cheaper *memory*
-    lever than full remat — 131k vs 115k tokens/s at seq 2048 with
-    temp buffers 8.7 vs 6.0 GB (no-remat: 141k at 9.7 GB) — but NOT
-    faster than no-remat when memory fits: XLA:TPU materializes the
-    recomputed elementwise ops rather than fusing them into consuming
-    matmul operands.  On this chip the flagship fits un-remat'd through
-    seq 32768, so both remat flavors exist for larger batches, more
-    optimizer state, or smaller HBM (bench_lm `--variant remat_mem`
-    carries the frontier's buffer table).
+    backward.  A cheaper *memory* lever than full remat, and not a
+    speed lever when memory fits: XLA:TPU materializes the recomputed
+    elementwise ops rather than fusing them into consuming matmul
+    operands (its tokens/s are not measured on this installation).  Both
+    remat flavors exist for larger batches, more optimizer state, or
+    smaller HBM.
 
 Use `param_partition_specs(params)` for the per-leaf PartitionSpecs
 that shard a full (replicated-shape) param tree onto the 'model' axis.

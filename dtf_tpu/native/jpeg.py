@@ -99,7 +99,6 @@ def wire_u8_supported() -> bool:
 
 def decode_crop_resize_batch(bufs, crops, flips, out_h: int, out_w: int,
                              sub, num_threads: int = 4,
-                             fast_dct: bool = False,
                              scaled_decode: bool = False,
                              out_u8: bool = False):
     """The whole train-time augmentation for a batch in one C++ call:
@@ -108,9 +107,8 @@ def decode_crop_resize_batch(bufs, crops, flips, out_h: int, out_w: int,
     semantics) → channel-mean subtraction, across ``num_threads``
     GIL-free threads.
 
-    ``fast_dct`` selects libjpeg's JDCT_IFAST (±1-2 LSB vs the default
-    ISLOW, measurably faster IDCT) — augmentation-noise territory for
-    training, so it is a throughput opt-in, never a default.
+    The decode is libjpeg's exact JDCT_ISLOW: the C entry points keep a
+    ``fast_dct`` argument in their ABI and are passed 0.
 
     ``scaled_decode``: crops >=2x the output are decoded at the
     smallest N/8 resolution (libjpeg-turbo DCT-space scaling, N<=4)
@@ -152,12 +150,12 @@ def decode_crop_resize_batch(bufs, crops, flips, out_h: int, out_w: int,
         sub_arr.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
         _out_ptr(lib, out),
         statuses.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
-        num_threads, int(fast_dct), int(scaled_decode), *_u8_tail(lib, out_u8))
+        num_threads, 0, int(scaled_decode), *_u8_tail(lib, out_u8))
     return out, statuses == 0
 
 
 def train_example_batch(records, seed: int, out_h: int, out_w: int, sub,
-                        num_threads: int = 4, fast_dct: bool = False,
+                        num_threads: int = 4,
                         scaled_decode: bool = False,
                         out_u8: bool = False):
     """The whole train path for a batch of raw tf.train.Example
@@ -198,7 +196,7 @@ def train_example_batch(records, seed: int, out_h: int, out_w: int, sub,
         rec_ptrs, lens, n, ctypes.c_uint64(seed & (2**64 - 1)),
         out_h, out_w,
         sub_arr.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
-        int(fast_dct), int(scaled_decode), num_threads,
+        0, int(scaled_decode), num_threads,
         _out_ptr(lib, out),
         labels.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
         crops.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
@@ -209,8 +207,7 @@ def train_example_batch(records, seed: int, out_h: int, out_w: int, sub,
 
 
 def eval_batch(bufs, resize_min: int, out_h: int, out_w: int, sub,
-               num_threads: int = 4, fast_dct: bool = False,
-               out_u8: bool = False):
+               num_threads: int = 4, out_u8: bool = False):
     """Fused eval preprocessing for a batch: aspect-preserving resize to
     shorter-side ``resize_min`` + central [out_h, out_w] crop +
     channel-mean subtraction in one sampling pass over a decode window
@@ -239,5 +236,5 @@ def eval_batch(bufs, resize_min: int, out_h: int, out_w: int, sub,
         sub_arr.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
         _out_ptr(lib, out),
         statuses.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
-        num_threads, int(fast_dct), *_u8_tail(lib, out_u8))
+        num_threads, 0, *_u8_tail(lib, out_u8))
     return out, statuses == 0

@@ -1,10 +1,10 @@
 """Always-on MFU/cost ledger — per-executable FLOPs, bytes, and
 achieved-utilization gauges.
 
-bench_profile.py proved the attribution method offline: XLA's own
+The attribution method: XLA's own
 ``compiled.cost_analysis()`` (flops, bytes accessed) for exactly the
 program that runs, divided by measured wall time, against the chip's
-peak FLOP/s and HBM bandwidth.  This module makes the same accounting
+peak FLOP/s and HBM bandwidth.  This module keeps that accounting
 LIVE: the train loop and the serving decoder register each jitted
 executable at compile time (the AOT ``lower().compile()`` object they
 then EXECUTE — cost analysis is free, nothing compiles twice), feed it
@@ -28,16 +28,17 @@ Beside the counts an entry names what the compiler left in the program:
 its Pallas kernels and its collectives (``reduce-scatter`` / ``all-reduce``
 / ``all-gather`` call sites and operand bytes), logged once at compile.
 
-Peaks come from the device kind (the same public-spec tables bench.py
-and bench_profile.py carry); unknown kinds (CPU) export no mfu/hbm_frac
+Peaks come from the device kind (the public-spec tables below, the
+program's one copy); unknown kinds (CPU) export no mfu/hbm_frac
 rather than a made-up number.  ``DTF_PEAK_TFLOPS`` / ``DTF_PEAK_HBM_GBPS``
 override both — deterministic tests, and chips the table hasn't learned.
 
 Accuracy contract (documented tolerance): the train-step wall time is
 the log-window mean (sync-inclusive, measured across a device_get), so
-ledger MFU sits within ~20% of bench.py's sync-cancelled-window MFU —
 host dispatch overhead is IN the ledger's number, deliberately (it is
-utilization the run actually achieves, not the kernel's best case).
+utilization the run actually achieves, not the kernel's best case;
+tests/test_obs.py holds it within 20% of the same formula over the
+loop's own step time).
 Chunked-prefill entries are per chunk SHAPE; on the gather path several
 window variants share one name and the latest compile's counts stand
 for the family (serving's headline is the decode-step entry).
@@ -58,10 +59,9 @@ from dtf_tpu.obs.registry import MetricsRegistry, default_registry
 log = logging.getLogger("dtf_tpu")
 
 # Public-spec peaks by TPU generation, matched case-insensitively
-# against jax device_kind — the same numbers bench.py (bf16 TFLOP/s)
-# and bench_profile.py (HBM GB/s) carry; kept here as literals because
-# obs must import without the bench scripts on sys.path (parity pinned
-# by tests/test_obs.py).
+# against jax device_kind.  The program's one table: a program module
+# does not import benchmark/, so tests/test_obs.py holds these against
+# benchmark/lib/peaks.py for every kind that table lists.
 PEAK_BF16_TFLOPS = {
     "v6e": 918.0, "v6": 918.0,
     "v5p": 459.0,
@@ -110,7 +110,7 @@ def device_peaks() -> tuple:
 
 def cost_of(compiled) -> tuple:
     """(flops, bytes accessed) from a compiled executable's
-    cost_analysis — the bench_profile.py extraction, shared."""
+    cost_analysis."""
     ca = compiled.cost_analysis()
     ca = ca[0] if isinstance(ca, (list, tuple)) else (ca or {})
     return (float(ca.get("flops", 0.0) or 0.0),

@@ -28,12 +28,12 @@ standard flash trade: extra FLOPs for O(S²) less HBM traffic):
 
 `_blockwise_bwd` (plain JAX, same math) remains as the portable oracle
 both are tested against (fused ≡ split ≡ oracle,
-test_pallas_fused_bwd_matches_split).  Measured on one TPU v5 lite
-chip, [2, 8192, 8, 128] bf16 causal (r4 sync-cancelled protocol, split
-path): fwd ~2.5-3.0 ms, backward-only ~5.8-9.0 ms across sessions
-(bench_lm.py --variant flash; bwd does 2.5× the forward's FLOPs; the
-bwd dropped 25% when its kernels moved to f32-scratch accumulation
-with native-dtype output stores).  All kernels stream their long-axis
+test_pallas_fused_bwd_matches_split).  The backward does 2.5× the
+forward's FLOPs; its kernels accumulate in f32 scratch and store in the
+native dtype.  On the chip these kernels run in the
+`gpt13b-train-zero-x4` cell: `flash_attention_roofline` 48.48 %
+(ledger, PR 43); the kernels alone at other shapes are not measured on
+this installation.  All kernels stream their long-axis
 operands through VMEM one block per sequential grid step — carries
 live in VMEM scratch.
 
@@ -42,12 +42,12 @@ a mask-free accumulate (no iota/compare/select per element), and only
 straddling blocks pay the masking VPU work — measured ~10% off the
 fwd kernel at [16, 2048, 6, 128].
 
-The d_head-64 penalty (GPT-2's 12×64 layout runs ~2.2× slower f+b than
-the flagship's 6×128 at identical parameters) is intrinsic MXU
+The d_head-64 penalty (GPT-2's 12×64 layout against the flagship's
+6×128 at identical parameters) is intrinsic MXU
 geometry, not a kernel gap — matmul cost conserves output_tiles ×
 ceil(contraction/128) passes under every head-packing construction,
-and 2× heads means 2× softmax score elements.  `bench_lm.py --variant
-dhead` is the committed reproducible measurement.
+and 2× heads means 2× softmax score elements.  Its size is not
+measured on this installation.
 
 On non-TPU backends `flash_attention` transparently falls back to the
 differentiable `ops.blockwise.blockwise_attention` (same math), so the

@@ -18,12 +18,11 @@ data-dependent Python control flow under jit" rule); their results are
 masked out of the output buffer and receive zero cotangents.
 
 The runner auto-scales M to 4·pp when --num_microbatches is unset
-(halving to divide the per-shard batch).  Measured at pp=4 on the
-8-device CPU mesh, same global batch (bench_lm.py --variant gpipe):
-M=4 → M=16 is 1.56× step time — the bubble+placeholder-compute
-fraction goes from (7-4)/7 = 43% of ticks to (19-16)/19 = 16%.
-`pipeline_spmd_interleaved` (below) instead halves the bubble TIME at
-equal M: measured 1.45× at M=pp and 1.12× at M=4·pp.
+(halving to divide the per-shard batch).  At pp=4, M=4 → M=16 takes the
+bubble+placeholder-compute fraction from (7-4)/7 = 43% of ticks to
+(19-16)/19 = 16% (a count of ticks; step times are not measured on this
+installation).  `pipeline_spmd_interleaved` (below) instead halves the
+bubble TIME at equal M.
 """
 
 from __future__ import annotations

@@ -148,7 +148,7 @@ def best_from_ranked(ranked: List[RankedPlan], stats: ModelStats,
 
 def ranked_artifact(stats: ModelStats, mesh: MeshSpec, global_batch: int,
                     ranked: List[RankedPlan], top: int = 0) -> dict:
-    """The ranked-plan JSON artifact (plan_main --out / bench_plan.py):
+    """The ranked-plan JSON artifact (plan_main --out):
     workload + mesh + every (or top-N) ranked plan with its predicted
     cost, feasible plans first."""
     plans = ranked[:top] if top else ranked

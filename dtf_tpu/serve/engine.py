@@ -626,8 +626,7 @@ class ServeEngine:
         # record), prefill chunks run, and the decode-step
         # GAP — wall time between consecutive decode steps while slots
         # are decoding.  The gap p99 is the head-of-line-blocking
-        # number chunked prefill exists to bound (bench_serve.py reads
-        # it for the chunked vs un-chunked comparison).
+        # number chunked prefill exists to bound.
         self._m_pages_used = self.metrics.gauge("serve_kv_pages_used",
                                                 unit="pages")
         # what a page holds beside its tokens' K and V: the bytes of the

@@ -34,7 +34,7 @@ speaks a newline-delimited-JSON wire protocol over TCP:
     {"op":"drain"}                      stop admissions, finish in-flight
     {"op":"stats"}                      request a stats snapshot
     {"op":"reset_measurement"}          zero decode-gap/peak stats
-                                        (bench warmup exclusion)
+                                        (warmup exclusion)
     {"op":"migrate_in","xfer":X,"host":H,"port":P,"prompt":[...]}
                                         pull this prompt's KV page
                                         chain from the replica at H:P
@@ -432,8 +432,8 @@ class ReplicaServer:
             gap = metrics.get("serve_decode_gap_s")
             if gap is not None:
                 # per-replica decode-gap tail: the pool-role
-                # comparison number (bench_serve's disaggregated-vs-
-                # colocated bar reads it over the wire)
+                # comparison number (read over the wire through
+                # Router.replica_stats)
                 out["serve_decode_gap_p99"] = gap.percentile(99.0)
                 out["serve_decode_gap_count"] = gap.count
         return out

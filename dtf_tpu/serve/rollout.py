@@ -23,8 +23,8 @@ State machine (persisted after every mutation, atomic tmp+rename)::
       gateable quantity: the canary's answer is compared token-by-
       token against the old model's, and the gate passes only after
       ``canary_requests`` comparisons with the divergence rate inside
-      ``max_divergence`` (default 0.0 — token-exact, the bench_gate
-      posture: identical checkpoints must compare EQUAL, so any
+      ``max_divergence`` (default 0.0 — token-exact:
+      identical checkpoints must compare EQUAL, so any
       mismatch is a model difference, never noise).
   ROLLING  — the canary joins service (new version), then each
       remaining replica drains → restarts → warms → re-registers, one
@@ -357,9 +357,9 @@ class RolloutController:
             self.state.first_divergence_pos = int(
                 stats["first_divergence_pos"])
             if self.state.diverged and self.max_divergence == 0.0:
-                # token-exact gate: ONE divergence is a verdict (the
-                # same discipline bench_gate applies — an identical
-                # model compares equal, so any mismatch is signal)
+                # token-exact gate: ONE divergence is a verdict (an
+                # identical model compares equal, so any mismatch is
+                # signal)
                 return (f"canary_divergence(first_pos="
                         f"{self.state.first_divergence_pos})")
             if self.state.compared >= self.canary_requests:

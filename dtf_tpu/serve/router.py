@@ -15,7 +15,8 @@ reading ``log7.log``:
       already warm — a prefix hit there costs zero prefill pages,
       while scattering the same traffic re-prefills the prompt once
       per replica.  Fallback (and tie-break) is least-loaded; a
-      ``random`` policy exists for the bench A/B.
+      ``random`` policy exists as the comparison arm
+      (tests/test_router.py).
   health — per-replica liveness comes from the obs heartbeat files
       (``heartbeat_rank{K}.json``) the replica's ENGINE LOOP rewrites,
       read by a prober at a fixed tick — never from the socket, so a
@@ -89,7 +90,7 @@ router_smoke.py drives the matrix and pins token-exactness + zero
 lost requests (ci_check stage 9); tools/disagg_smoke.py pins the
 disaggregated tier token-exact against a colocated oracle;
 ``router_kill@req:N`` + ``lease_stall@<ticks>`` drive the HA matrix
-(tools/router_ha_smoke.py, ci_check stage 17).
+(tools/router_ha_smoke.py, ci_check stage 16).
 """
 
 from __future__ import annotations
@@ -578,7 +579,7 @@ class Router:
               adopt: bool = False) -> "Router":
         """Spawn replicas (proc mode), start the dispatcher + prober.
         ``wait_s`` > 0 blocks until every replica is healthy (raises
-        on timeout) — the smoke/bench posture; 0 returns immediately
+        on timeout) — the smokes' posture; 0 returns immediately
         and traffic queues until replicas register.
 
         ``adopt=True`` is the TAKEOVER posture (serve/ha.py): the tier
@@ -1207,8 +1208,7 @@ class Router:
             self._drop_shadow_locked(sh, reason)
 
     def kill_replica(self, replica_id: int) -> None:
-        """SIGKILL a replica (chaos drills, the bench's kill-under-load
-        scenario).  The death is then DETECTED like any other — probe/
+        """SIGKILL a replica (chaos drills).  The death is then DETECTED like any other — probe/
         conn-EOF/proc-poll — so the full failover + respawn machinery
         runs; nothing is short-circuited."""
         self._kill_replica(int(replica_id))
@@ -1538,7 +1538,7 @@ class Router:
                       error=error)
 
     def migration_stats(self) -> dict:
-        """The disagg smoke/bench's gate inputs."""
+        """The disagg smoke's gate inputs."""
         with self._mu:
             return {"migrated": self._m_migrations.value,
                     "failed": self._m_mig_failed.value,
@@ -2089,8 +2089,8 @@ class Router:
 
     def replica_stats(self, replica_id: int,
                       timeout: float = 5.0) -> Optional[dict]:
-        """Round-trip a stats snapshot from a replica's engine (the
-        bench reads prefix-registry hit counters through this)."""
+        """Round-trip a stats snapshot from a replica's engine (its
+        prefix-registry hit counters among them)."""
         rep = self._replicas[replica_id]
         tag = f"s{time.monotonic_ns()}"
         ev = threading.Event()

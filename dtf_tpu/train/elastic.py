@@ -32,7 +32,7 @@ burning the restart budget, a ``--min_devices`` floor refuses loudly,
 and a re-announced capacity (``elastic_rejoin.json``) grows the job
 back at a checkpoint boundary.
 
-The headline contract (tools/elastic_smoke.py, ci_check stage 15):
+The headline contract (tools/elastic_smoke.py, ci_check stage 14):
 train on N devices, lose a host at step K, resume on N/2 with the
 per-step loss trajectory BIT-IDENTICAL to an oracle launched fresh on
 N/2 from the same checkpoint — then grow back to N.

@@ -1007,7 +1007,7 @@ class Trainer:
     def _zero_overlap_probe(self, state: TrainState, batch, ledger,
                             window_step_s) -> None:
         """--zero_probe: turn the ZeRO-2/3 overlap claim into measured
-        numbers (obs/ledger + registry gauges, BENCH_zero's inputs).
+        numbers (obs/ledger + registry gauges, tools/zero_smoke.py's inputs).
 
         Three measurements, all on the live mesh after training:
           1. standalone per-leaf reduce-scatter / all-gather of the

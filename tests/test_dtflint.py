@@ -331,6 +331,86 @@ def test_flag_rules(tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# command-and-path closure
+# ---------------------------------------------------------------------------
+
+_LIVE = {"old_bench.py": "", "tools/old.sh": "", "tools/old_gate.py": "",
+         "pkg/__init__.py": "", "pkg/gone.py": ""}
+
+# case -> (files of the tree, the dead names the rule must report)
+CMD_DOC_CASES = {
+    "dead-python-in-a-docstring": (
+        {"dtf_tpu/a.py": '"""Run it::\n\n    python old_bench.py --x\n"""\n'},
+        ["python old_bench.py"]),
+    "dead-python-in-a-markdown-fence": (
+        {"README.md": "Measure:\n```bash\npython3 old_bench.py --out r.json\n```\n"},
+        ["python old_bench.py"]),
+    "dead-python-in-a-shell-script": (
+        {"tools/ci.sh": "set -e\npython old_bench.py --out \"$D/x.json\"\n"},
+        ["python old_bench.py"]),
+    "dead-module": (
+        {"pkg/__init__.py": "",
+         "dtf_tpu/a.py": "# regenerate with: python -m pkg.gone --all\n"},
+        ["python -m pkg.gone"]),
+    "dead-bash": (
+        {"tools/x.py": "", "README.md": "Then `bash tools/old.sh`.\n"},
+        ["sh tools/old.sh"]),
+    "dead-slash-path-in-a-comment": (
+        {"tools/b.py": "# the bars live in tools/old_gate.py\nX = 1\n"},
+        ["tools/old_gate.py"]),
+    "live-twins-are-silent": (
+        dict(_LIVE, **{
+            "dtf_tpu/a.py": '"""python old_bench.py; python -m pkg.gone"""\n'
+                            "# the bars live in tools/old_gate.py\n",
+            "tools/ci.sh": "python old_bench.py\nbash tools/old.sh\n",
+            "README.md": "```\npython3 old_bench.py\nsh tools/old.sh\n```\n"}),
+        []),
+    "installed-module-is-silent": (
+        {"README.md": "`python -m pytest tests/ -q`, `python3 -m http.server`\n"},
+        []),
+    "uninstalled-module-is-dead": (
+        {"README.md": "`python -m no_such_pkg_xyz.tool`\n"},
+        ["python -m no_such_pkg_xyz.tool"]),
+    "pattern-is-skipped": (
+        {"docs/DESIGN.md": "see docs/pr42_*.jsonl, tools/<name>_smoke.py and "
+                           "`python tools/{a,b}_smoke.py`\n",
+         "tools/x.py": ""},
+        []),
+    "ignored-directory-is-skipped": (
+        {".gitignore": "chiprun_out/\ntools/.cache.json\n",
+         "chiprun_out/.keep": "", "tools/x.py": "",
+         "README.md": "read chiprun_out/run1/trace.json and "
+                      "tools/.cache.json\n"},
+        []),
+    "bare-reference-file-name-is-silent": (
+        {"dtf_tpu/a.py": "# as resnet_cifar_main.py:104 does; state in "
+                         "rollout_state.json, see official/resnet/common.py\n"},
+        []),
+    "suppression-with-a-reason-is-honoured": (
+        {"dtf_tpu/a.py": "# python old_bench.py  # dtflint: disable=cmd-doc "
+                         "(history: names the tool a PR deleted)\nX = 1\n",
+         "tools/ci.sh": "# dtflint: disable=cmd-doc (kept to show the old "
+                        "call)\n# python old_bench.py\n"},
+        []),
+    "suppression-without-a-reason-is-not": (
+        {"tools/ci.sh": "python old_bench.py  # dtflint: disable=cmd-doc\n"},
+        ["python old_bench.py"]),
+}
+
+
+@pytest.mark.parametrize("case", list(CMD_DOC_CASES))
+def test_cmd_doc_rule(tmp_path, case):
+    files, want = CMD_DOC_CASES[case]
+    for rel, content in files.items():
+        _write(tmp_path, rel, content)
+    found = [f for f in dtflint.run_rules(_ctx(tmp_path))
+             if f.rule == "cmd-doc"]
+    assert len(found) == len(want), [str(f) for f in found]
+    for f, name in zip(found, want):
+        assert f"'{name}'" in f.message, str(f)
+
+
+# ---------------------------------------------------------------------------
 # test-marker (the folded-in marker audit)
 # ---------------------------------------------------------------------------
 

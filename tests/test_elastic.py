@@ -321,7 +321,7 @@ def _zero3_trainer(num_devices, batch=12):
     return trainer, rt, state, (images, labels)
 
 
-@pytest.mark.slow  # reshard-resume is pinned e2e every CI by elastic_smoke (stage 15)
+@pytest.mark.slow  # reshard-resume is pinned e2e every CI by elastic_smoke (stage 14)
 def test_zero3_reshard_across_non_dividing_dp(eight_devices):
     """The reshard headline at the layout level: a canonical (stage-0)
     state from an nd=4 mesh re-slices onto nd=3 — a dp that divides
@@ -450,7 +450,7 @@ def test_zero3_tp_composed_shrink(eight_devices):
 
 @pytest.mark.slow
 def test_elastic_smoke_tool():
-    """tools/elastic_smoke.py — the ci_check stage-15 contract — as a
+    """tools/elastic_smoke.py — the ci_check stage-14 contract — as a
     slow-marked test so the suite exercises it too."""
     import subprocess
     r = subprocess.run([sys.executable, "tools/elastic_smoke.py"],

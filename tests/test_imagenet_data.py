@@ -132,7 +132,7 @@ it.close()
 @pytest.mark.slow
 def test_two_process_decode_co_residency(tmp_path):
     """The multi-core feeding claim rests on serial_fraction ~ 0
-    measured on a 1-core host (BENCH_r04); this puts cross-PROCESS
+    measured on a 1-core host; this puts cross-PROCESS
     evidence behind the extrapolation: two decode pipelines co-resident
     on the same host and the same shard files split the core's
     throughput ~fairly, with no cross-process serialization collapse —
@@ -144,7 +144,7 @@ def test_two_process_decode_co_residency(tmp_path):
     import subprocess
     import sys as _sys
 
-    from bench_input import make_shards
+    from dtf_tpu.testing.shards import make_shards
 
     shards = tmp_path / "shards"
     shards.mkdir()

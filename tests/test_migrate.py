@@ -327,7 +327,7 @@ def test_fetch_chain_wire_token_exact_and_stall_chaos(model_and_params):
     """fetch_chain over a real socket: windowed pull, import, token
     exactness — with a page_fetch_stall chaos arm proving the stall
     delays but never corrupts the transfer.  (slow: the wire path is
-    also pinned every CI run by tools/disagg_smoke.py, stage 16.)"""
+    also pinned every CI run by tools/disagg_smoke.py, stage 15.)"""
     import tempfile
     src = make_engine(model_and_params)
     dst = make_engine(model_and_params)
@@ -364,7 +364,7 @@ def test_router_disagg_migrates_and_rehomes(model_and_params):
     prefills in the prefill pool, its chain migrates to the decode
     pool, and the repeat prompt decodes THERE on warm pages — token-
     exact against the first answer at every step.  (slow: the same
-    re-home contract runs every CI as disagg_smoke, stage 16.)"""
+    re-home contract runs every CI as disagg_smoke, stage 15.)"""
     import tempfile
     from dtf_tpu.obs.watchdog import Heartbeat, heartbeat_path
     from dtf_tpu.serve.router import Router

@@ -190,23 +190,6 @@ def test_eval_batch_rejects_tiny_images():
     assert not ok[0]
 
 
-def test_decode_crop_resize_batch_fast_dct_close():
-    """JDCT_IFAST is a throughput opt-in: same shapes, pixel values
-    within a couple of LSB of the default ISLOW decode."""
-    from dtf_tpu.native import jpeg
-    rng = np.random.default_rng(13)
-    bufs = [_jpeg(rng.integers(0, 256, (64, 80, 3), dtype=np.uint8))
-            for _ in range(3)]
-    crops = [(0, 0, 48, 64)] * 3
-    sub = np.zeros(3, np.float32)
-    slow, ok1 = jpeg.decode_crop_resize_batch(bufs, crops, [0] * 3, 32,
-                                              32, sub)
-    fast, ok2 = jpeg.decode_crop_resize_batch(bufs, crops, [0] * 3, 32,
-                                              32, sub, fast_dct=True)
-    assert ok1.all() and ok2.all()
-    np.testing.assert_allclose(fast, slow, atol=12.0)
-
-
 def test_decode_crop_resize_batch_scaled_decode():
     """--input_scaled_decode: crops larger than the output decode at
     the smallest N/8 DCT-space scale keeping the scaled crop >= the

@@ -703,21 +703,32 @@ def test_profile_steps_routes_to_trace_dir(tmp_path):
 
 # --- MFU/cost ledger -------------------------------------------------------
 
-def test_ledger_peak_tables_match_bench_scripts():
-    """obs/ledger.py duplicates the bench scripts' public-spec peak
-    tables (obs must import without the repo root on sys.path) — the
-    copies must stay identical."""
-    import sys as _sys
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    _sys.path.insert(0, repo)
-    try:
-        import bench
-        import bench_profile
-        from dtf_tpu.obs import ledger as ledger_mod
-        assert ledger_mod.PEAK_BF16_TFLOPS == bench.PEAK_BF16_TFLOPS
-        assert ledger_mod.PEAK_HBM_GBPS == bench_profile.HBM_GBPS
-    finally:
-        _sys.path.remove(repo)
+def _benchmark_peaks():
+    from benchmark.lib.peaks import PEAKS
+    return [pytest.param(kind, q, row[q], id=f"{kind}-{q}")
+            for kind, row in PEAKS.items()
+            for q in ("bf16_flops_per_s", "hbm_bytes_per_s")]
+
+
+@pytest.mark.parametrize("kind,quantity,want", _benchmark_peaks())
+def test_ledger_peaks_agree_with_the_benchmarks_table(kind, quantity, want,
+                                                      monkeypatch):
+    """The program keeps one table of peaks (obs/ledger.py) and the
+    benchmark keeps its own (a program module does not import
+    ``benchmark/``): for every device kind the benchmark knows, the
+    ledger's lookup gives the same figure.  This is where the two meet."""
+    import types
+
+    import jax
+
+    from dtf_tpu.obs import ledger as ledger_mod
+    monkeypatch.delenv("DTF_PEAK_TFLOPS", raising=False)
+    monkeypatch.delenv("DTF_PEAK_HBM_GBPS", raising=False)
+    monkeypatch.setattr(
+        jax, "devices", lambda *a: [types.SimpleNamespace(device_kind=kind)])
+    peak_f, peak_b = ledger_mod.device_peaks()
+    got = {"bf16_flops_per_s": peak_f, "hbm_bytes_per_s": peak_b}[quantity]
+    assert got == want
 
 
 @pytest.mark.parametrize("op_name,want", [
@@ -741,7 +752,7 @@ def test_ledger_names_a_kernel_by_the_scope_in_front_of_its_call(op_name,
 def test_ledger_mfu_crosschecked_against_cost_analysis(tmp_path,
                                                        monkeypatch):
     """The acceptance bar: the ledger's MFU for the compiled train
-    step equals the bench_profile.py formula — flops from the SAME
+    step equals the formula — flops from the SAME
     compiled executable's cost_analysis, divided by wall time and the
     (env-pinned) peak — to float precision when both use the same
     wall time, and the e2e fit() number lands within the documented
@@ -775,7 +786,7 @@ def test_ledger_mfu_crosschecked_against_cost_analysis(tmp_path,
     wall = 0.0125
     ledger.observe("train_step", wall)
     mfu_ledger = reg.get("ledger_train_step_mfu").value
-    mfu_ref = (flops / wall) / (0.5e12)     # bench_profile's formula
+    mfu_ref = (flops / wall) / (0.5e12)     # the formula, by hand
     np.testing.assert_allclose(mfu_ledger, mfu_ref, rtol=1e-9)
     hbm_ref = (nbytes / wall) / (10e9)
     np.testing.assert_allclose(

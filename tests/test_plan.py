@@ -898,23 +898,26 @@ def test_calibration_within_contract():
         f"memory model off: predicted/live = {factor:.2f}")
 
 
-def test_bench_plan_smoke(tmp_path):
-    """bench_plan.py (the docs example's reproducible source) runs
-    analytically — no accelerator work — and its artifact loads as a
-    plan file."""
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    try:
-        import bench_plan
-    finally:
-        sys.path.pop(0)
+def test_plan_main_out_writes_the_ranked_plan_artifact(tmp_path):
+    """``plan_main --out`` (ci_check stage 6, the docs example's
+    reproducible source) runs analytically — no accelerator work — its
+    artifact loads as a plan file, and the property the worked example
+    shows holds of its best plan: ZeRO-1 cuts predicted peak memory."""
+    from dtf_tpu.cli import plan_main
+
     out = tmp_path / "PLAN.json"
-    rc = bench_plan.main(["--out", str(out), "--model",
-                          "transformer_small", "--mesh", "cpu",
-                          "--batch", "8", "--seq", "64"])
+    rc = plan_main.main(["--model", "transformer_small", "--dataset", "lm",
+                         "--seq_len", "64", "--batch_size", "8",
+                         "--plan_mesh", "cpu", "--plan", "auto",
+                         "--out", str(out)])
     assert rc == 0
     plan = load_plan_file(str(out))
     assert plan.num_devices == PRESETS["cpu"].num_devices
+    stats = characterize("transformer_small", seq_len=64)
+    mesh = mesh_spec("cpu")
+    c0, c1 = (predict(dataclasses.replace(plan, zero=z), stats, mesh, 8)
+              for z in (0, 1))
+    assert c1.peak_bytes < c0.peak_bytes
 
 
 def test_plan_cache_calibration_feedback_loop(tmp_path):

@@ -19,7 +19,7 @@ Three contracts, in rising order of expense:
      SLO, TP-vs-replicas at a fixed chip budget, page-pool size vs
      shed rate — answered from a RECORDED trace, pinned (the
      acceptance criterion); plus the calibration contract against a
-     live traced engine run (slow-marked; ci_check stage 11 runs the
+     live traced engine run (slow-marked; ci_check stage 10 runs the
      same contract via the CLI).
 """
 
@@ -757,7 +757,7 @@ def test_calibration_refuses_empty_measurement():
 
 @pytest.mark.slow
 def test_calibration_contract_live_engine(tmp_path):
-    """The ci_check stage-11 contract in-process: record a real traced
+    """The ci_check stage-10 contract in-process: record a real traced
     engine run, reconstruct workload + profile from the trace alone,
     replay, and land inside the 2× ratio bar — with the gauges in the
     default obs registry."""

@@ -165,6 +165,41 @@ def test_refcount_high_water_concurrent_burst(model_and_params):
         eng.stop(drain=False)
 
 
+def test_sharing_fits_twice_the_concurrent_sequences_at_equal_pages(
+        model_and_params):
+    """At ONE page budget too small for N unshared copies of a system
+    prompt, the peak of concurrently admitted sequences with
+    ``prefix_sharing`` on is at least twice that with it off: admission
+    is then bounded by a request's TAIL pages, not its whole prompt."""
+    n, sys_pages, budget = 4, 4, 16
+    sys_prompt = np.arange(1, sys_pages * PS + 1, dtype=np.int32) % VOCAB
+    tails = [np.array([t, t + 1, t + 2, t + 3], np.int32)
+             for t in (3, 7, 11, 15)]
+    total = -(-(sys_pages * PS + 4 + budget) // PS)       # pages a request
+    tail_pages = total - sys_pages
+    # scratch + one whole copy + the other requests' tails: every
+    # request fits when the prompt is shared, two whole copies otherwise
+    pool = 1 + total + (n - 1) * tail_pages
+    assert n * total > pool - 1 >= 2 * total
+    peak = {}
+    for sharing in (True, False):
+        eng = make_engine(model_and_params, max_batch=n, kv_pool_pages=pool,
+                          prefix_sharing=sharing)
+        try:
+            eng.submit(sys_prompt, max_new_tokens=2).result(timeout=120)
+            _settle(eng)
+            eng.reset_measurement()
+            handles = [eng.submit(np.concatenate([sys_prompt, t]),
+                                  max_new_tokens=budget) for t in tails]
+            for h in handles:
+                h.result(timeout=120)
+            peak[sharing] = eng.max_concurrent
+        finally:
+            eng.stop(drain=False)
+    assert peak[False] >= 1
+    assert peak[True] >= 2 * peak[False], peak
+
+
 # ---------------------------------------------------------------------------
 # copy-on-write
 # ---------------------------------------------------------------------------

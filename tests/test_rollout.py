@@ -2,7 +2,7 @@
 drain/replace mechanics, resume-from-persisted-state, and rollout
 chaos — all tier-1 over the jax-free fake replica tier from
 test_router (the real-checkpoint, real-subprocess path is pinned by
-tools/rollout_smoke.py, ci_check stage 12).
+tools/rollout_smoke.py, ci_check stage 11).
 
 The fake models checkpoints as an oracle SALT: ``ckpt_old`` and
 ``ckpt_new_same`` answer identically (a re-exported identical
@@ -440,7 +440,7 @@ def test_resume_done_is_noop(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# the real-subprocess + real-checkpoint matrix (ci_check stage 12)
+# the real-subprocess + real-checkpoint matrix (ci_check stage 11)
 # ---------------------------------------------------------------------------
 
 @pytest.mark.slow

@@ -262,6 +262,39 @@ def test_router_prefix_affinity_routes_shared_prompts_together(tmp_path):
         stop_tier(router, reps)
 
 
+def test_affinity_pays_one_first_touch_per_group_random_one_per_pair(
+        tmp_path):
+    """The count behind prefix-affine placement's registry win: over the
+    same shared-prompt traffic (four groups, two replicas, one request
+    at a time so no spill), ``affinity`` serves each group from ONE
+    replica — one cold prefix registration a group — while ``random``
+    scatters a group over both, a cold registration for every (group,
+    replica) pair it touches."""
+    pairs = {}
+    for arm in ("affinity", "random"):
+        router, reps = make_tier(tmp_path / arm, 2,
+                                 router_kw=dict(placement=arm, seed=3))
+        try:
+            ps = router.page_size
+            rng = np.random.default_rng(31)
+            groups = [rng.integers(0, 97, (4 * ps,)).astype(np.int32)
+                      for _ in range(4)]
+            touched = 0
+            for g in groups:
+                before = [r.engine.submitted for r in reps]
+                for _ in range(8):
+                    tail = rng.integers(0, 97, (5,)).astype(np.int32)
+                    router.submit(np.concatenate([g, tail]),
+                                  max_new_tokens=4).result(timeout=10)
+                touched += sum(r.engine.submitted > b
+                               for r, b in zip(reps, before))
+            pairs[arm] = touched
+        finally:
+            stop_tier(router, reps)
+    assert pairs["affinity"] == 4, pairs
+    assert pairs["random"] > pairs["affinity"], pairs
+
+
 def test_placement_literal_parity_with_config():
     """config/flags.py validates router_placement against a LITERAL
     copy of PLACEMENTS (Config must not import the serve stack) —

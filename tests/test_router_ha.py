@@ -7,7 +7,7 @@ in-process by freezing the dying router exactly the way a SIGKILL
 leaves it — loops stopped, sockets dropped, nothing resolved, journal
 unsynced tail intact.  The real-subprocess path (leader SIGKILLed
 mid-burst, standby process takes over) is pinned by
-tools/router_ha_smoke.py (ci_check stage 17) and its slow-marked
+tools/router_ha_smoke.py (ci_check stage 16) and its slow-marked
 wrapper below.
 """
 
@@ -619,7 +619,7 @@ def test_takeover_resumes_mid_rollout(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# the real-subprocess contract (ci_check stage 17)
+# the real-subprocess contract (ci_check stage 16)
 # ---------------------------------------------------------------------------
 
 @pytest.mark.slow

@@ -318,7 +318,7 @@ def test_zero_with_dynamic_loss_scale(eight_devices):
     assert np.isfinite(stats["loss"])
 
 
-@pytest.mark.slow  # ZeRO e2e CLI equivalence runs every CI as zero_smoke (stage 14)
+@pytest.mark.slow  # ZeRO e2e CLI equivalence runs every CI as zero_smoke (stage 13)
 def test_zero_e2e_cli():
     stats = run(Config(model="resnet20", dataset="cifar10", batch_size=8,
                        train_steps=2, use_synthetic_data=True,
@@ -328,7 +328,7 @@ def test_zero_e2e_cli():
     assert np.isfinite(stats["loss"])
 
 
-@pytest.mark.slow  # ZeRO e2e CLI equivalence runs every CI as zero_smoke (stage 14)
+@pytest.mark.slow  # ZeRO e2e CLI equivalence runs every CI as zero_smoke (stage 13)
 def test_zero2_e2e_cli():
     """--zero_stage 2 (sharded grads) through the full run() path."""
     stats = run(Config(model="resnet20", dataset="cifar10", batch_size=8,

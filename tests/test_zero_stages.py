@@ -243,7 +243,7 @@ def test_zero3_killed_at_k_resumes_bit_identical(tmp_path):
 
 @pytest.mark.slow
 def test_zero_smoke_tool():
-    """tools/zero_smoke.py — the ci_check stage-14 contract — as a
+    """tools/zero_smoke.py — the ci_check stage-13 contract — as a
     slow-marked test so the suite exercises it too."""
     import subprocess
     import sys

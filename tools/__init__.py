@@ -1,2 +1,2 @@
 # makes `python -m tools.dtflint` resolvable; the scripts in this
-# directory stay directly runnable (`python tools/bench_gate.py`)
+# directory stay directly runnable (`python tools/serve_smoke.py`)

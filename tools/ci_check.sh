@@ -29,9 +29,9 @@
 #      (The long kill-matrix variants live in tests/test_chaos.py,
 #      marked `slow`.)
 #   6. the parallelism-planner contract (dtf_tpu/plan):
-#      bench_plan.py reproduces the docs' ranked-plan artifact (exits
-#      nonzero if the worked example loses feasibility or ZeRO-1 stops
-#      cutting peak memory); `plan_main --check` compiles one smoke
+#      `plan_main --plan auto --out` reproduces the docs' ranked-plan
+#      artifact (exits nonzero if the worked example loses
+#      feasibility); `plan_main --check` compiles one smoke
 #      train step per top feasible-marked plan on the LM and cifar
 #      smoke configs (a cost model that blesses un-constructible plans
 #      fails HERE, not on a pod); and a calibration smoke records
@@ -49,7 +49,7 @@
 #   8. tools/serve_smoke.py — the distributed-serving contract
 #      (dtf_tpu/serve) on a 4-virtual-device CPU mesh: TP=2 decode
 #      (Megatron params + head-sharded KV page pool under shard_map)
-#      is token-exact vs TP=1, and the shared-prefix bench scenario's
+#      is token-exact vs TP=1, and the shared-prefix scenario's
 #      bars hold — prefix sharing fits >= 2x the concurrent sequences
 #      of the no-sharing pool at equal page budget, and the first
 #      STREAMED token lands before full retire.
@@ -63,13 +63,7 @@
 #      `trace_main --check --allow injected_fault --allow
 #      replica_lost` proves the chaos run contained the injected fault
 #      + the router's reaction and nothing else.
-#  10. tools/bench_gate.py --smoke — the perf-regression gate's own
-#      contract: the committed BENCH_r*/BENCH_serve* history passes
-#      its noise-aware thresholds AND a synthetically degraded copy of
-#      the newest artifact exits nonzero (a gate that can't catch a 2x
-#      regression is decoration).  Gate a fresh run's artifact with
-#      `python tools/bench_gate.py --candidate NEW.json`.
-#  11. the capacity-simulator contract (dtf_tpu/plan/serve_model.py):
+#  10. the capacity-simulator contract (dtf_tpu/plan/serve_model.py):
 #      `plan_serve_main --calibrate` records a live traced engine run,
 #      reconstructs the workload + service profile FROM THAT TRACE
 #      ALONE (the trace-replay parser end to end), replays it through
@@ -77,7 +71,7 @@
 #      tokens/s or p99 latency leave the 2x ratio bar — with the
 #      plan_serve_*_ratio gauges exported to metric.log like stage
 #      6's plan_step_time_ratio.
-#  12. tools/rollout_smoke.py — the zero-downtime-rollout contract
+#  11. tools/rollout_smoke.py — the zero-downtime-rollout contract
 #      (serve/rollout.py over real replica subprocesses + real
 #      exported checkpoints): a mid-traffic rollout to a re-exported
 #      IDENTICAL checkpoint completes (DONE) with zero shed / lost /
@@ -88,8 +82,8 @@
 #      and a truncated NEW checkpoint both resolve to ROLLED_BACK with
 #      the fleet token-exact on the old model; and `trace_main
 #      --check` with the rollout allowlist is green.
-#  13. python -m tools.dtflint — the project-wide static-analysis
-#      ratchet (bench_gate's correctness-side twin): the lock-
+#  12. python -m tools.dtflint — the project-wide static-analysis
+#      ratchet: the lock-
 #      discipline race detector (_GUARDED_BY), determinism/JAX-hazard
 #      lint (wall-clock/RNG/set-order in bit-exactness modules,
 #      unaccounted host syncs in step loops), vocabulary closure
@@ -99,7 +93,7 @@
 #      audit folded in as the test-marker rule.  Fails on any NEW
 #      finding vs the committed (EMPTY) baseline; suppressions
 #      require a written reason.
-#  14. tools/zero_smoke.py — the fully-sharded data-parallelism
+#  13. tools/zero_smoke.py — the fully-sharded data-parallelism
 #      contract (--zero_stage 2/3, train/zero.py): ZeRO-2/3 per-step
 #      loss ≡ replicated within the documented float tolerance; the
 #      planner marks a transformer config replicated-INFEASIBLE on a
@@ -107,10 +101,8 @@
 #      ZeRO-3 matching a smaller-mesh replicated oracle; the measured
 #      --zero_probe gauges show exposed comm strictly below the
 #      serialized collective wall (the overlap is real, not modeled);
-#      plan_main --calibrate holds the 2x contract for zero ∈ {2,3};
-#      and the fresh BENCH_zero artifact gates against the committed
-#      history via tools/bench_gate.py.
-#  15. tools/elastic_smoke.py — the elastic-training contract
+#      and plan_main --calibrate holds the 2x contract for zero ∈ {2,3}.
+#  14. tools/elastic_smoke.py — the elastic-training contract
 #      (train/elastic.py + the launch.py --elastic supervisor): a run
 #      losing a host mid-training (host_loss chaos — an unprompted
 #      SIGKILL) under --elastic resumes on HALF the devices at the
@@ -121,7 +113,7 @@
 #      back to N; device_loss (exit 76) classifies + reshards too; and
 #      `trace_main --check --allow injected_fault --allow
 #      host_loss/device_loss` is green.
-#  16. tools/disagg_smoke.py — the disaggregated-serving contract
+#  15. tools/disagg_smoke.py — the disaggregated-serving contract
 #      (prefill/decode pool split + wire KV-page migration,
 #      serve/migrate.py + router pool roles): a 1p:1d tier is
 #      TOKEN-EXACT vs a colocated oracle with chains migrating their
@@ -131,7 +123,7 @@
 #      fail over); and a page_fetch_stall chaos arm proves a
 #      congested fabric is an efficiency loss, never a correctness
 #      event.
-#  17. tools/router_ha_smoke.py — the router high-availability
+#  16. tools/router_ha_smoke.py — the router high-availability
 #      contract (serve/ha.py + the request journal, over real replica
 #      subprocesses): the leader router is SIGKILLed mid-burst
 #      (router_kill chaos — dispatches in flight, journal tail
@@ -144,7 +136,7 @@
 #      GC-paused leader discovers it is fenced instead of resuming.
 #
 # Usage: tools/ci_check.sh            # the full contract
-#        CI_CHECK_SKIP_TESTS=1 tools/ci_check.sh   # stages 2-17 only
+#        CI_CHECK_SKIP_TESTS=1 tools/ci_check.sh   # stages 2-16 only
 
 set -euo pipefail
 cd "$(dirname "$0")/.."
@@ -152,7 +144,7 @@ cd "$(dirname "$0")/.."
 export JAX_PLATFORMS=${JAX_PLATFORMS:-cpu}
 
 if [ "${CI_CHECK_SKIP_TESTS:-0}" != "1" ]; then
-    echo "== ci_check [1/17]: tier-1 test suite =="
+    echo "== ci_check [1/16]: tier-1 test suite =="
     # the shape of the driver's command (commands[0] of
     # /root/TESTS_LAST_RUN.json): six xdist workers, a file to a
     # worker, 1,470 s
@@ -160,13 +152,13 @@ if [ "${CI_CHECK_SKIP_TESTS:-0}" != "1" ]; then
         --continue-on-collection-errors -p no:cacheprovider \
         -p xdist -n 6 --dist loadfile -p no:randomly
 else
-    echo "== ci_check [1/17]: SKIPPED (CI_CHECK_SKIP_TESTS=1) =="
+    echo "== ci_check [1/16]: SKIPPED (CI_CHECK_SKIP_TESTS=1) =="
 fi
 
-echo "== ci_check [2/17]: marker audit (test-budget contract) =="
+echo "== ci_check [2/16]: marker audit (test-budget contract) =="
 python tools/marker_audit.py
 
-echo "== ci_check [3/17]: traced smoke run =="
+echo "== ci_check [3/16]: traced smoke run =="
 TRACE_DIR=$(mktemp -d)
 trap 'rm -rf "$TRACE_DIR"' EXIT
 python -m dtf_tpu.cli.lm_main --use_synthetic_data --train_steps 3 \
@@ -174,14 +166,17 @@ python -m dtf_tpu.cli.lm_main --use_synthetic_data --train_steps 3 \
     --model_dir "$TRACE_DIR/run" --skip_checkpoint \
     --trace_dir "$TRACE_DIR" >/dev/null
 
-echo "== ci_check [4/17]: anomaly cleanliness =="
+echo "== ci_check [4/16]: anomaly cleanliness =="
 python -m dtf_tpu.cli.trace_main "$TRACE_DIR" --check
 
-echo "== ci_check [5/17]: chaos smoke (kill -> resume -> exactness) =="
+echo "== ci_check [5/16]: chaos smoke (kill -> resume -> exactness) =="
 python tools/chaos_smoke.py
 
-echo "== ci_check [6/17]: parallelism planner (check + calibration) =="
-python bench_plan.py --out "$TRACE_DIR/PLAN_4x4.json" >/dev/null
+echo "== ci_check [6/16]: parallelism planner (check + calibration) =="
+python -m dtf_tpu.cli.plan_main --model transformer_tpu --dataset lm \
+    --seq_len 2048 --batch_size 256 --dtype bf16 --optimizer adamw \
+    --plan_mesh 4x4 --plan auto --top 12 \
+    --out "$TRACE_DIR/PLAN_4x4.json" >/dev/null
 python -m dtf_tpu.cli.plan_main --devices 8 --model transformer_small \
     --dataset lm --use_synthetic_data --seq_len 64 --batch_size 8 \
     --check --check_top 2 --top 0 >/dev/null
@@ -194,39 +189,36 @@ python -m dtf_tpu.cli.plan_main --model transformer_small --dataset lm \
     --benchmark_log_dir "$TRACE_DIR/plan_bench"
 grep -q plan_step_time_ratio "$TRACE_DIR/plan_bench/metric.log"
 
-echo "== ci_check [7/17]: data-service smoke (sharded determinism + imagenet resume exactness) =="
+echo "== ci_check [7/16]: data-service smoke (sharded determinism + imagenet resume exactness) =="
 python tools/data_service_smoke.py
 
-echo "== ci_check [8/17]: multi-device serve smoke (TP exactness + prefix-sharing/streaming bars) =="
+echo "== ci_check [8/16]: multi-device serve smoke (TP exactness + prefix-sharing/streaming bars) =="
 python tools/serve_smoke.py
 
-echo "== ci_check [9/17]: router smoke (replica tier: kill/partition/slow chaos -> token-exact failover) =="
+echo "== ci_check [9/16]: router smoke (replica tier: kill/partition/slow chaos -> token-exact failover) =="
 python tools/router_smoke.py
 
-echo "== ci_check [10/17]: perf-regression gate (committed history passes, injected regression fails) =="
-python tools/bench_gate.py --smoke
-
-echo "== ci_check [11/17]: capacity-simulator smoke (record -> replay -> calibrate) =="
+echo "== ci_check [10/16]: capacity-simulator smoke (record -> replay -> calibrate) =="
 python -m dtf_tpu.cli.plan_serve_main --calibrate --calibrate_tolerance 2.0 \
     --benchmark_log_dir "$TRACE_DIR/serve_plan_bench"
 grep -q plan_serve_tokens_ratio "$TRACE_DIR/serve_plan_bench/metric.log"
 
-echo "== ci_check [12/17]: rollout smoke (zero-downtime rollout: canary gate, rollback, rollout chaos) =="
+echo "== ci_check [11/16]: rollout smoke (zero-downtime rollout: canary gate, rollback, rollout chaos) =="
 python tools/rollout_smoke.py
 
-echo "== ci_check [13/17]: dtflint (static analysis: lock discipline, determinism, vocab closure, flag wiring) =="
+echo "== ci_check [12/16]: dtflint (static analysis: lock discipline, determinism, vocab closure, flag wiring) =="
 python -m tools.dtflint
 
-echo "== ci_check [14/17]: zero smoke (ZeRO-2/3 ≡ replicated, infeasible-replicated config trains, measured overlap, 2x calibration) =="
+echo "== ci_check [13/16]: zero smoke (ZeRO-2/3 ≡ replicated, infeasible-replicated config trains, measured overlap, 2x calibration) =="
 python tools/zero_smoke.py
 
-echo "== ci_check [15/17]: elastic smoke (host/device loss -> shrink resume oracle-exact -> grow back) =="
+echo "== ci_check [14/16]: elastic smoke (host/device loss -> shrink resume oracle-exact -> grow back) =="
 python tools/elastic_smoke.py
 
-echo "== ci_check [16/17]: disagg smoke (prefill/decode split: migrate -> re-home token-exact, kill prefill replica -> zero lost, stalled fabric) =="
+echo "== ci_check [15/16]: disagg smoke (prefill/decode split: migrate -> re-home token-exact, kill prefill replica -> zero lost, stalled fabric) =="
 python tools/disagg_smoke.py
 
-echo "== ci_check [17/17]: router HA smoke (leader kill -> journal takeover exactly-once, split brain fenced, lease stall) =="
+echo "== ci_check [16/16]: router HA smoke (leader kill -> journal takeover exactly-once, split brain fenced, lease stall) =="
 python tools/router_ha_smoke.py
 
 echo "ci_check: OK"

@@ -24,7 +24,6 @@ from __future__ import annotations
 
 import argparse
 import glob
-import io
 import json
 import os
 import shutil
@@ -37,29 +36,6 @@ sys.path.insert(0, REPO)
 
 NUM_SHARDS = 2
 IMAGES_PER_SHARD = 48
-
-
-def make_shards(root: str) -> str:
-    """Small synthetic ImageNet-shaped JPEG shards (48x64 sources keep
-    decode cheap; the determinism contract does not care about pixels)."""
-    import numpy as np
-    from PIL import Image
-    from dtf_tpu.data import records
-    os.makedirs(root, exist_ok=True)
-    rng = np.random.default_rng(0)
-    for shard in range(NUM_SHARDS):
-        recs = []
-        for i in range(IMAGES_PER_SHARD):
-            arr = rng.integers(0, 256, (48, 64, 3), dtype=np.uint8)
-            buf = io.BytesIO()
-            Image.fromarray(arr).save(buf, format="JPEG", quality=85)
-            recs.append(records.build_example({
-                "image/encoded": buf.getvalue(),
-                "image/class/label": [1 + i % 1000],
-            }))
-        records.write_tfrecord_file(
-            os.path.join(root, f"train-{shard:05d}-of-01024"), recs)
-    return root
 
 
 def check_worker_invariance(data: str) -> None:
@@ -134,7 +110,12 @@ def main(argv=None) -> int:
     base = args.keep or tempfile.mkdtemp(prefix="data_service_smoke_")
     os.makedirs(base, exist_ok=True)
     try:
-        data = make_shards(os.path.join(base, "shards"))
+        from dtf_tpu.testing.shards import make_shards
+        # 48x64 sources keep decode cheap; the determinism contract does
+        # not care about pixels
+        data = make_shards(os.path.join(base, "shards"), NUM_SHARDS,
+                           IMAGES_PER_SHARD, height=(48, 49),
+                           width=(64, 65))
 
         print("== data_service_smoke [1/4]: 2-worker merged stream == "
               "inline stream ==")

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """CI disaggregated-serving smoke: the prefill/decode pool-split
 contract, driven through REAL replica subprocesses (ci_check.sh
-stage 16).
+stage 15).
 
 Four stages, every assertion fatal (nonzero exit):
 

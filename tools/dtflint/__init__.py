@@ -1,6 +1,6 @@
 """dtflint — project-wide AST static analysis for the dtf_tpu tree.
 
-bench_gate (ci_check stage 10) is the no-silent-drift discipline for
+The driver's benchmark is the no-silent-drift discipline for
 PERFORMANCE; this is its correctness-side twin: the invariants
 DESIGN.md states in prose — "under the router lock", "batch n is a
 pure function of (seed, pid, n)", "every kind in KNOWN_EVENT_KINDS" —
@@ -36,6 +36,9 @@ Rule families (one module per family; ids are stable):
                                     that exists nowhere
                   plan-owned        PLAN_OWNED_FLAGS out of sync with
                                     config/flags.py
+  cmd_rules.py    cmd-doc           a taught command or a slash path
+                                    that names no file or module of
+                                    the checkout
   markers.py      test-marker       unmarked test over the tier-1
                                     per-test time ceiling
   (core)          bad-suppression   a disable comment without a reason
@@ -78,8 +81,8 @@ BASELINE_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                              "baseline.json")
 
 #: directories whose .py files are scanned (repo-relative); root-level
-#: scripts (bench*.py, run_record.py) join via ROOT_GLOBS for the
-#: usage-side scans (flag reads, doc flags)
+#: scripts (chip_smoke.py, __graft_entry__.py) join via ROOT_GLOBS for
+#: the usage-side scans (flag reads, doc flags)
 SCAN_DIRS = ("dtf_tpu", "tools")
 ROOT_GLOBS = (".py",)
 
@@ -256,7 +259,7 @@ def discover_py_files(repo_root: str) -> List[str]:
             for f in sorted(files):
                 if f.endswith(".py"):
                     out.append(os.path.join(root, f))
-    # root-level scripts (bench*.py & co) join the usage-side scans
+    # root-level scripts join the usage-side scans
     if os.path.isdir(repo_root):
         for f in sorted(os.listdir(repo_root)):
             if f.endswith(ROOT_GLOBS) and \
@@ -268,12 +271,13 @@ def discover_py_files(repo_root: str) -> List[str]:
 def run_rules(ctx: Context) -> List[Finding]:
     """All rule families over ``ctx``; suppressions applied; findings
     sorted by (path, line)."""
-    from tools.dtflint import (determinism, flag_rules, locks, markers,
-                               vocab_rules)
+    from tools.dtflint import (cmd_rules, determinism, flag_rules, locks,
+                               markers, vocab_rules)
     findings: List[Finding] = list(ctx.parse_errors)
     for s in ctx.sources:
         findings.extend(s.bad_suppressions)
-    for mod in (locks, determinism, vocab_rules, flag_rules, markers):
+    for mod in (locks, determinism, vocab_rules, flag_rules, cmd_rules,
+                markers):
         findings.extend(mod.check(ctx))
     kept = []
     for f in findings:

@@ -28,7 +28,7 @@ Two rule groups:
    a fixed set of them (that sync IS its measurement point).  Every
    sync site must carry ``# dtflint: sync-point (reason)`` — so adding
    an unaccounted sync to the hot loop is a lint failure, not a silent
-   MFU regression the bench gate catches three PRs later.
+   MFU regression the benchmark catches three PRs later.
 """
 
 from __future__ import annotations

@@ -42,6 +42,8 @@ DOC_FLAG_ALLOWLIST = {
     "num_gpus",
     # placeholders in flag-syntax prose ("--name value", "--flag=x")
     "name", "flag",
+    # the benchmark's own CLI (benchmark/run.py, outside the scanned tree)
+    "workload", "seconds",
 }
 
 

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Elastic-training smoke — the ci_check stage-15 gate.
+"""Elastic-training smoke — the ci_check stage-14 gate.
 
 The headline contract, every bar enforced by nonzero exit: losing
 capacity turns preemption into a THROUGHPUT DIP, not an outage.

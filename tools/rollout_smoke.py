@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """CI rollout smoke: the zero-downtime model-rollout contract, driven
 through REAL replica subprocesses serving REAL exported checkpoints
-(ci_check.sh stage 12).
+(ci_check.sh stage 11).
 
 One tier, five stages, every assertion fatal (nonzero exit):
 

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """CI router-HA smoke: crash-exact takeover via request journal,
 fenced leader lease, and in-flight re-adoption, driven through REAL
-replica subprocesses (ci_check.sh stage 17).
+replica subprocesses (ci_check.sh stage 16).
 
 Four stages, every assertion fatal (nonzero exit):
 

@@ -8,9 +8,8 @@ the tier-1 suite uses for a TPU pod slice):
      page pool sharded on its head dim, every step under shard_map)
      produces token streams IDENTICAL to the TP=1 engine for a burst
      of varied-length prompts spanning the page-geometry edges.
-  2. SHARED-PREFIX + STREAMING BARS — bench_serve.py's shared-prefix
-     scenario at smoke scale: N concurrent requests over one system
-     prompt against a pool too small for N unshared copies must fit
+  2. SHARED-PREFIX + STREAMING BARS — N concurrent requests over one
+     system prompt against a pool too small for N unshared copies must fit
      ≥ 2× the concurrent sequences of the sharing-off pool at equal
      page budget, and the first STREAMED token must land before full
      retire (p50).
@@ -18,8 +17,11 @@ the tier-1 suite uses for a TPU pod slice):
 Usage: python tools/serve_smoke.py          (ci_check.sh stage 8)
 """
 
+import concurrent.futures as cf
 import os
 import sys
+import threading
+import time
 
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=4")
@@ -33,12 +35,85 @@ import jax.numpy as jnp       # noqa: E402
 import numpy as np            # noqa: E402
 
 PS = 16
+# the shared-prefix scenario's shape: the pool sizing must agree with
+# the traffic the scenario generates, or the >=2x bar measures a wrong
+# page budget
+PREFIX_TAIL_LEN = 8        # per-request tokens after the system prompt
+PREFIX_BUDGET = 24         # per-request max_new_tokens
+
+
+def prefix_pool_pages(batch: int, sys_pages: int, page_size: int) -> int:
+    """Total pool pages (incl. scratch) sized so ONE full prompt copy
+    plus per-request tails fit, but `batch` unshared copies cannot."""
+    tail_pages = (-(-(sys_pages * page_size + PREFIX_TAIL_LEN
+                      + PREFIX_BUDGET) // page_size) - sys_pages)
+    return 1 + (sys_pages + tail_pages) + (batch - 1) * tail_pages
+
+
+def shared_prefix_scenario(model, params, *, batch: int, seq: int,
+                           requests: int, kv_page_size: int,
+                           kv_pool_pages: int, sys_pages: int,
+                           prefix_sharing: bool):
+    """N concurrent requests sharing one system prompt, against a pool
+    deliberately too small to hold N unshared copies.
+
+    The warm request writes + registers the system prefix (sharing
+    arm) and compiles every shape; the burst then admits with
+    ``sys_pages`` of each prompt shared — so concurrency is bounded by
+    the per-request TAIL pages, not the full prompt.  Every handle is
+    consumed through its token STREAM by a client thread, recording
+    first-streamed-token latency next to full-retire latency.
+
+    Returns (max_concurrent, ttft_stream_p50, full_latency_p50)."""
+    from dtf_tpu.serve import ServeEngine
+    eng = ServeEngine(model, params, max_batch=batch, max_seq_len=seq,
+                      max_delay_s=0.0, queue_size=max(64, 2 * requests),
+                      kv_page_size=kv_page_size,
+                      kv_pool_pages=kv_pool_pages,
+                      prefix_sharing=prefix_sharing)
+    rng = np.random.default_rng(5)
+    sys_prompt = rng.integers(0, model.vocab_size,
+                              (sys_pages * kv_page_size,)).astype(np.int32)
+    eng.submit(sys_prompt, max_new_tokens=2).result(timeout=600)
+    eng.reset_measurement()
+    first_times = {}
+    lock = threading.Lock()
+
+    def _consume(rid, handle, t_submit):
+        for _ in handle.stream(timeout=600):
+            with lock:
+                if rid not in first_times:
+                    first_times[rid] = time.perf_counter() - t_submit
+
+    handles = []
+    with cf.ThreadPoolExecutor(max_workers=requests) as ex:
+        consumers = []
+        for r in range(requests):
+            tail = rng.integers(0, model.vocab_size,
+                                (PREFIX_TAIL_LEN,)).astype(np.int32)
+            h = eng.submit(np.concatenate([sys_prompt, tail]),
+                           max_new_tokens=PREFIX_BUDGET)
+            handles.append(h)
+            consumers.append(ex.submit(_consume, r, h,
+                                       time.perf_counter()))
+        results = [h.result(timeout=600) for h in handles]
+        for c in consumers:
+            c.result()       # propagate consumer-thread failures loudly
+    maxc = eng.max_concurrent
+    eng.stop()
+    lat = sorted(r.latency_s for r in results)
+    ttft = sorted(first_times.values())
+    if not ttft:
+        # a 0.0 default would pass the ttft < full-retire bar VACUOUSLY
+        raise SystemExit(
+            "shared-prefix scenario: no first-token times recorded — "
+            "the streaming path produced no tokens")
+    return maxc, ttft[len(ttft) // 2], lat[len(lat) // 2]
 
 
 def main() -> int:
     from dtf_tpu.models.transformer import TransformerLM
     from dtf_tpu.serve import ServeEngine, place_for_serving, serving_mesh
-    import bench_serve
 
     assert jax.device_count() >= 4, (
         f"expected 4 virtual CPU devices, got {jax.device_count()}")
@@ -73,15 +148,13 @@ def main() -> int:
 
     # -- 2. shared-prefix + streaming bars ------------------------------
     sys_pages = 8
-    pool = bench_serve.prefix_pool_pages(8, sys_pages, PS)
-    _, c_share, _, ttft, full = bench_serve.shared_prefix_scenario(
+    pool = prefix_pool_pages(8, sys_pages, PS)
+    c_share, ttft, full = shared_prefix_scenario(
         model, params, batch=8, seq=256, requests=8, kv_page_size=PS,
-        kv_pool_pages=pool, sys_pages=sys_pages, prefix_sharing=True,
-        label="smoke_sharing")
-    _, c_noshare, _, _, _ = bench_serve.shared_prefix_scenario(
+        kv_pool_pages=pool, sys_pages=sys_pages, prefix_sharing=True)
+    c_noshare, _, _ = shared_prefix_scenario(
         model, params, batch=8, seq=256, requests=8, kv_page_size=PS,
-        kv_pool_pages=pool, sys_pages=sys_pages, prefix_sharing=False,
-        label="smoke_nosharing")
+        kv_pool_pages=pool, sys_pages=sys_pages, prefix_sharing=False)
     if c_share < 2 * c_noshare:
         print(f"serve smoke FAILED: prefix sharing fits {c_share} "
               f"concurrent sequences vs {c_noshare} without — below the "
