@@ -119,6 +119,20 @@ _REGISTRY = {
             summary_window=64, summary_chunk=4, norm_unit_offset=True,
             num_dense_layers=4, dense_width=1024, activation="silu"),
         32_768, 0.0),
+    # and block-sparse attention that chooses blocks through pooled keys
+    # beside lightning linear-attention layers (a constant decay a head, no
+    # write gate), dense MLPs, muP scalars: blocks of 8 tokens, pooled keys
+    # over 4 at stride 2, 4 chosen beside 1 + 4 forced, dense up to 128
+    "routed_decoder_sparse": (
+        functools.partial(
+            routed_decoder.RoutedDecoderLM, num_layers=4, d_model=512,
+            num_heads=8, num_kv_heads=2, head_dim=64,
+            layer_mixer=("sparse_block", "lightning", "lightning",
+                         "sparse_block"),
+            sparse=(8, 4, 2, 4, 32, 1, 128, 3.0), lightning=(8, 64, 0, 4),
+            mup=(12.0, 1.4, 4, 2.0), num_dense_layers=4, dense_width=1024,
+            activation="silu"),
+        32_768, 0.0),
     # pipeline-stacked LM family (pipeline stages over 'model')
     "pipeline_transformer": (pipeline_lm.PipelinedTransformerLM,
                              32_768, 0.0),

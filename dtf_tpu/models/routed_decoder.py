@@ -92,6 +92,21 @@ beside EITHER attention kind: ``layer_mixer`` decides a layer,
 ``kv_lora_rank`` what its attention layers are (``short_conv`` alone still
 wants whole heads).
 
+Two further kinds, each with its sizes in ONE tuple.  ``sparse_block``
+(:class:`SparseBlockAttention`, ``sparse``; ``ops/block_select.py``):
+grouped-query attention — normed q and k, no positions, an output gate —
+that reads a SUBSET of the row's cache, chosen query by query: past
+``dense_len`` the first block, the window's blocks and the ``top`` best of
+the others by the query heads' scores against POOLED keys, a third cache
+leaf (``pooled_key``, one row a ``stride`` tokens); at or under it
+everything.  The decode step copies the chosen blocks alone (a table ``[B,
+Hkv, W]`` of blocks that are parts of a page); a chunk gives every token
+its own.  ``lightning`` (:class:`LightningAttention`, ``lightning``):
+linear attention with a constant decay a head and no write gate — the
+no-erase forms of ``ops/linear_state.py`` — whose matrix state is
+``linear_delta``'s leaf under the same contract.  ``mup`` scales the
+embedding, every residual branch and the final hidden rows.
+
 **MLP kind.**  The first ``num_dense_layers`` layers have a dense gated
 MLP of ``dense_width`` and no router.  The others route: ``num_experts``
 gated experts of ``expert_width`` (``activation`` relu | silu) of which
@@ -146,7 +161,10 @@ HERE (their expert is held), and ``experts_touched`` / ``expert_load_max``
 run over the held experts; a model with state counts the tokens its state
 layers mixed and the rows whose entries went to a page of their own
 (``conv_tokens`` or ``linear_tokens``, ``state_rows_advanced``), beside
-either attention kind's counts.
+either attention kind's counts; a model with ``sparse_block`` layers
+counts, in this order after the three expert counts, ``kv_blocks_visible``,
+``kv_blocks_read``, ``pooled_keys_scored``, ``rows_dense_path``,
+``linear_tokens``, ``state_rows_advanced`` (``SPARSE_STATS``).
 
 Every apply also yields counts (``stats_names``; summed over layers) in
 the ``"stats"`` collection when the caller makes it mutable: the serving
@@ -163,8 +181,11 @@ import jax
 import jax.numpy as jnp
 
 from dtf_tpu.models.transformer import paged_cache_attention
-from dtf_tpu.ops import linear_state, window_summary
-from dtf_tpu.ops.paged_attention import cached_attention, expand_kv_heads
+from dtf_tpu.ops import block_select, linear_state, window_summary
+from dtf_tpu.ops.flash_attention import flash_attention
+from dtf_tpu.ops.paged_attention import (cached_attention, expand_kv_heads,
+                                         paged_attention_auto,
+                                         paged_block_attention, write_pages)
 
 # what ``"stats"/"counts"`` holds, in order: with whole heads, and with the
 # latent cache (its one row a token, summed over rows and layers)
@@ -183,7 +204,17 @@ STATE_STATS = STATS + ("conv_tokens", "state_rows_advanced")
 # with delta-rule linear-attention layers: the tokens those layers mixed
 # and the rows whose entries went to a page of their own, as above
 LINEAR_STATS = ("linear_tokens", "state_rows_advanced")
-MIXERS = ("attention", "short_conv", "linear_delta")
+# with block-sparse attention layers (``sparse_block``), summed over the
+# call's real queries: the 64-token blocks a (query, KV head, layer) could
+# see and those it reads (all of them at or under ``dense_len``, the forced
+# and the chosen past it), the pooled keys a (query, layer) scores to
+# choose, and the queries (once, not a layer) that took the dense path
+SPARSE_STATS = ("kv_blocks_visible", "kv_blocks_read", "pooled_keys_scored",
+                "rows_dense_path")
+MIXERS = ("attention", "short_conv", "linear_delta", "sparse_block",
+          "lightning")
+# the kinds whose cache leaf is a running state entry a page
+STATE_MIXERS = ("short_conv", "linear_delta", "lightning")
 
 # VMEM the grouped product's blocks may take: the compiler's scoped limit
 # on the v5e is 16 MiB — compiled for it, a tile of (64, 2048, 1792) is
@@ -905,6 +936,300 @@ class LinearDelta(nn.Module):
         return y, advanced
 
 
+def _need_table(module, cache_index, block_table):
+    if module.kv_page_size is None:
+        raise ValueError("decode mode needs kv_page_size and kv_pool_pages")
+    if cache_index is None or block_table is None:
+        raise ValueError("decode mode needs cache_index [B] and block_table "
+                         "[B, M], both int32")
+
+
+class SparseBlockAttention(nn.Module):
+    """Grouped-query attention that READS A SUBSET of a row's cache, chosen
+    query by query through pooled keys (``ops/block_select.py``): ``q, k``
+    normed a head (gains initialised at ``sizes[7]``), NO positions, a
+    query at ``t`` past ``dense_len`` attends the first block, the window's
+    blocks and the ``top`` best of the others a KV head, at or under it
+    everything; ``out = W_o (o * sigmoid(h W_gate))``.
+
+    ``sizes``: (block, pool, stride, top, window, init blocks, dense_len,
+    q/k norm gain) — ``block_select.Sizes`` and the initialiser.
+
+    Decode mode keeps three leaves a layer: ``paged_key`` and
+    ``paged_value`` ``[P, page, Hkv, Dh]`` and ``pooled_key`` ``[P, page /
+    stride, Hkv, Dh]`` (a ``kv_pool`` leaf whose rows a page are pooled
+    keys, not tokens).  One token: the row's pooled pages are scored
+    (kernel ``block_select``), the table of blocks ``[B, Hkv, W]`` built,
+    and ``paged_flash_decode`` copies those blocks alone, a (row, KV head)
+    a row of it.  A chunk gives every TOKEN its own choice, a (token, KV
+    head) a row of the same call — a gather a token; a chunk that ends at
+    or under ``dense_len`` goes through the kernels every dense model uses
+    (the first through the flash kernel).  Outside decode mode the choice
+    is a mask on plain attention."""
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    sizes: Tuple
+    rms_eps: float
+    dtype: Any
+    param_dtype: Any
+    use_pallas: Any = None
+    decode: bool = False
+    kv_page_size: Optional[int] = None
+    kv_pool_pages: Optional[int] = None
+
+    @nn.compact
+    def __call__(self, h, cache_index=None, block_table=None,
+                 flash_prefill: bool = False,
+                 window_pages: Optional[int] = None):
+        b, s, d = h.shape
+        hq, hkv, dh = self.num_heads, self.num_kv_heads, self.head_dim
+        sizes, gain = block_select.Sizes(*self.sizes[:7]), self.sizes[7]
+        pdt = self.param_dtype
+        w_qkv = self.param("qkv", _normal(0.02), (d, (hq + 2 * hkv) * dh),
+                           pdt)
+        w_gate = self.param("gate", _normal(0.02), (d, hq * dh), pdt)
+        w_out = self.param("out", _normal(0.02), (hq * dh, d), pdt)
+        g_q = self.param("q_norm", nn.initializers.constant(gain), (dh,),
+                         pdt)
+        g_k = self.param("k_norm", nn.initializers.constant(gain), (dh,),
+                         pdt)
+
+        def mm(x, w_):
+            return jnp.einsum("bsd,dn->bsn", x.astype(self.dtype),
+                              w_.astype(self.dtype),
+                              preferred_element_type=jnp.float32)
+        qkv = mm(h, w_qkv).astype(self.dtype)
+        q = rms_norm(qkv[..., :hq * dh].reshape(b, s, hq, dh), g_q,
+                     self.rms_eps)
+        k = rms_norm(qkv[..., hq * dh:(hq + hkv) * dh].reshape(
+            b, s, hkv, dh), g_k, self.rms_eps)
+        v = qkv[..., (hq + hkv) * dh:].reshape(b, s, hkv, dh)
+        scale = dh ** -0.5
+        if not self.decode:
+            # the whole sequence at once (tests, the toy): the choice as
+            # a mask [B, S, Hkv, S] on plain attention
+            mask = block_select.plain_mask(q, k, sizes, scale)
+            qg = q.astype(jnp.float32).reshape(b, s, hkv, hq // hkv, dh)
+            sc = jnp.einsum("bqhgd,bkhd->bqhgk", qg,
+                            k.astype(jnp.float32)) * scale
+            sc = jnp.where(mask[:, :, :, None, :], sc, -1e30)
+            o = jnp.einsum("bqhgk,bkhd->bqhgd", jax.nn.softmax(sc, -1),
+                           v.astype(jnp.float32)).astype(q.dtype)
+        else:
+            _need_table(self, cache_index, block_table)
+            page = self.kv_page_size
+            sizes.check(page)
+            shape = (self.kv_pool_pages, page, hkv, dh)
+            keys = self.variable("cache", "paged_key", jnp.zeros, shape,
+                                 self.dtype)
+            values = self.variable("cache", "paged_value", jnp.zeros, shape,
+                                   self.dtype)
+            pooled = self.variable(
+                "cache", "pooled_key", jnp.zeros,
+                (self.kv_pool_pages, page // sizes.stride, hkv, dh),
+                self.dtype)
+            if self.is_initializing():
+                o = jnp.zeros_like(q)
+            else:
+                o = self._paged(q, k, v, keys, values, pooled, cache_index,
+                                block_table, sizes, scale, flash_prefill,
+                                window_pages)
+        gate = jax.nn.sigmoid(mm(h, w_gate))
+        return mm(o.reshape(b, s, hq * dh) * gate, w_out)
+
+    def _paged(self, q, k, v, keys, values, pooled, cache_index,
+               block_table, sizes, scale, flash_prefill, window_pages):
+        b, s, hq, dh = q.shape
+        page = self.kv_page_size
+        use_pallas = self.use_pallas
+        if use_pallas is None:
+            use_pallas = jax.default_backend() == "tpu"
+        aligned = s > 1 and s % page == 0
+        keys.value = write_pages(keys.value, k, block_table, cache_index,
+                                 page_aligned=aligned)
+        values.value = write_pages(values.value, v, block_table,
+                                   cache_index, page_aligned=aligned)
+        pooled.value = block_select.write_pooled(
+            pooled.value, k, keys.value, block_table, cache_index, sizes)
+        t = cache_index[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
+
+        def read(q_, t_, r):        # [B, T, Hq, Dh], [B, T], [B, T, Hkv, J]
+            n = q_.shape[1]
+            blocks, count = block_select.choose(r, t_, sizes)
+            ids = block_select.physical(blocks, block_table, page,
+                                        sizes.block)
+            o = paged_block_attention(
+                q_.reshape(b * n, hq, dh), keys.value, values.value,
+                ids.reshape((b * n,) + ids.shape[2:]), count.reshape(-1),
+                (t_ % sizes.block).reshape(-1), block=sizes.block,
+                use_pallas=use_pallas)
+            return o.reshape(b, n, hq, dh)
+        if s == 1:
+            if use_pallas:
+                r = block_select.decode_scores(
+                    q[:, 0], pooled.value, block_table, cache_index,
+                    sizes=sizes, scale=scale,
+                    interpret=use_pallas == "interpret")[:, None]
+            else:
+                r = block_select.scores(q, pooled.value, block_table, t,
+                                        sizes, scale)
+            return read(q, t, r)
+        if flash_prefill:
+            if s > sizes.dense_len:
+                raise ValueError(f"a first chunk of {s} tokens passes "
+                                 f"dense_len {sizes.dense_len}")
+            return flash_attention(q, *expand_kv_heads(k, v, hq),
+                                   causal=True, use_pallas=self.use_pallas)
+
+        def dense():
+            return paged_attention_auto(
+                q, keys.value, values.value, block_table, cache_index,
+                window_pages=window_pages, use_pallas=self.use_pallas)
+
+        def sparse():
+            # every token its own choice, _CHUNK_TILE tokens a call: a
+            # (token, KV head) is a row of the paged kernel, whose tables
+            # are prefetched into SMEM (1 MiB on the v5e)
+            tile = math.gcd(s, _CHUNK_TILE)
+
+            def tiles(x):
+                return jnp.moveaxis(
+                    x.reshape((b, s // tile, tile) + x.shape[2:]), 1, 0)
+
+            def some(xs):
+                q_, t_ = xs
+                return read(q_, t_, block_select.scores(
+                    q_, pooled.value, block_table, t_, sizes, scale))
+            o = jax.lax.map(some, (tiles(q), tiles(t)))
+            return jnp.moveaxis(o, 0, 1).reshape(b, s, hq, dh)
+        return jax.lax.cond(jnp.all(cache_index + s <= sizes.dense_len),
+                            dense, sparse)
+
+
+# tokens of a chunk past ``dense_len`` that choose and read in one call
+_CHUNK_TILE = 256
+
+
+def lightning_log_decay(heads: int, layer: int, depth: int):
+    """[heads] the log of a head's constant decay: ``-2**(-8 (h + 1) /
+    heads) * (1 - layer / (depth - 1) + 1e-5)``, ``layer`` the layer's index
+    in the PUBLISHED stack of ``depth`` layers."""
+    slopes = 2.0 ** (-8.0 * jnp.arange(1, heads + 1, dtype=jnp.float32)
+                     / heads)
+    return -slopes * (1.0 - layer / max(depth - 1, 1) + 1e-5)
+
+
+class LightningAttention(nn.Module):
+    """Linear attention with a CONSTANT decay a head and no write gate
+    (``ops/linear_state.py``, the no-erase forms): ``q, k, v = h W``,
+    ``heads`` of ``head_dim``; ``q, k`` normed a head, rotated (rotate-half,
+    ``rope_theta``) at the true position; ``S_t = lambda_h S_{t-1} + k_t
+    v_t^T``, ``o_t = head_dim**-0.5 S_t^T q_t``, ``lambda_h`` from
+    :func:`lightning_log_decay`; ``out = W_o (RMSNorm_head(o) * sigmoid(h
+    W_gate))``.
+
+    Decode mode: ONE leaf ``linear_state`` ``[P, heads, head_dim,
+    head_dim]`` (the matrices, transposed, in ``dtype``), a ``page_state``
+    entry a page by :class:`LinearDelta`'s contract; one token through the
+    kernel ``linear_state_decode_noerase_*`` or its oracle, a chunk of
+    whole pages through the blocked form, tokens past ``last_pos`` no-ops
+    (``k`` = 0, log decay 0).  Returns (output, rows whose entry went to a
+    page other than the scratch page)."""
+    heads: int
+    head_dim: int
+    layer: int
+    depth: int
+    rope_theta: float
+    rms_eps: float
+    dtype: Any
+    param_dtype: Any
+    use_pallas: Any = None
+    decode: bool = False
+    kv_page_size: Optional[int] = None
+    kv_pool_pages: Optional[int] = None
+
+    @nn.compact
+    def __call__(self, h, positions, cache_index=None, block_table=None,
+                 last_pos=None):
+        b, s, d = h.shape
+        hn, dh = self.heads, self.head_dim
+        n = hn * dh
+        pdt, ones = self.param_dtype, nn.initializers.ones
+        w_qkv = self.param("qkv", _normal(0.02), (d, 3 * n), pdt)
+        w_gate = self.param("gate", _normal(0.02), (d, n), pdt)
+        w_out = self.param("out", _normal(0.02), (n, d), pdt)
+        g_q = self.param("q_norm", ones, (dh,), pdt)
+        g_k = self.param("k_norm", ones, (dh,), pdt)
+        g_out = self.param("out_norm", ones, (dh,), pdt)
+
+        def mm(x, w_):
+            return jnp.einsum("bsd,dn->bsn", x.astype(self.dtype),
+                              w_.astype(self.dtype),
+                              preferred_element_type=jnp.float32)
+        qkv = mm(h, w_qkv)
+        q, k, v = (qkv[..., i * n:(i + 1) * n].reshape(b, s, hn, dh)
+                   for i in range(3))
+        q = rotate_half_rope(rms_norm(q, g_q, self.rms_eps), positions,
+                             self.rope_theta) * dh ** -0.5
+        k = rotate_half_rope(rms_norm(k, g_k, self.rms_eps), positions,
+                             self.rope_theta)
+        a = jnp.broadcast_to(
+            lightning_log_decay(hn, self.layer, self.depth)[:, None],
+            (b, s, hn, dh))
+        advanced = jnp.zeros((), jnp.int32)
+        if self.decode:
+            _need_table(self, cache_index, block_table)
+            state = self.variable(
+                "cache", "linear_state", jnp.zeros,
+                (self.kv_pool_pages, hn, dh, dh), self.dtype)
+        if not self.decode or self.is_initializing():
+            o, _ = linear_state.recurrent(q, k, v, a)
+        else:
+            page = self.kv_page_size
+            if s > 1 and s % page:
+                raise ValueError(
+                    f"a call of {s} tokens is neither one token nor whole "
+                    f"pages of {page}: a page it crossed would keep a "
+                    f"stale state entry")
+            if s > 1 and last_pos is not None:
+                real = (jnp.arange(s, dtype=jnp.int32)[None, :]
+                        <= last_pos[:, None])[..., None, None]
+                a, k = jnp.where(real, a, 0.0), jnp.where(real, k, 0.0)
+            ends = _entry_ends(last_pos, b, s, page)
+            pages = _entry_pages(cache_index, ends, block_table, page)
+            advanced = jnp.sum(pages[:, 0] != 0, dtype=jnp.int32)
+            if s == 1:
+                use_pallas = self.use_pallas
+                if use_pallas is None:
+                    use_pallas = jax.default_backend() == "tpu"
+                one = (q[:, 0], k[:, 0], v[:, 0], a[:, 0], None,
+                       block_table, cache_index)
+                if use_pallas:
+                    o, state.value = linear_state.linear_state_decode(
+                        state.value, *one, page_size=page,
+                        interpret=use_pallas == "interpret")
+                else:
+                    o, state.value = linear_state.paged_step(
+                        state.value, *one, page_size=page)
+                o = o[:, None]
+            else:
+                before = _carry_page(cache_index, block_table, page)
+                start = jnp.where(
+                    (cache_index > 0)[:, None, None, None],
+                    state.value[before].astype(jnp.float32), 0.0)
+                o, states = linear_state.chunked(
+                    q, k, v, a, None, start,
+                    block=math.gcd(page, linear_state.NOERASE_BLOCK),
+                    emit_every=page)
+                state.value = state.value.at[pages.reshape(-1)].set(
+                    states.reshape((-1,) + states.shape[2:]
+                                   ).astype(state.value.dtype))
+        o = rms_norm(o, g_out, self.rms_eps).reshape(b, s, n)
+        y = mm(o * jax.nn.sigmoid(mm(h, w_gate)), w_out)
+        return y, advanced
+
+
 # lanes a latent cache row is stored in: the TPU tiles the last axis by
 # 128, so a row of 576 values occupies 640 in HBM however it is declared
 # (Mosaic refuses a page DMA of 576 lanes); the pad lanes hold zeros
@@ -1079,6 +1404,11 @@ class RoutedBlock(nn.Module):
     experts_held: Optional[Tuple[int, int]] = None      # (first id, count)
     summary: Optional[Tuple[int, int]] = None           # (window, chunk)
     norm_unit_offset: bool = False
+    sparse: Optional[Tuple] = None      # SparseBlockAttention's sizes
+    # (heads, head dim, the layer's published index, the published depth,
+    # rotary theta)
+    lightning: Optional[Tuple] = None
+    residual_scale: float = 1.0         # on both branches of the layer
 
     @nn.compact
     def __call__(self, x, positions, cache_index=None, block_table=None,
@@ -1130,6 +1460,21 @@ class RoutedBlock(nn.Module):
                 kv_page_size=self.kv_page_size,
                 kv_pool_pages=self.kv_pool_pages, name="linear")(
                     h, cache_index, block_table, last_pos)
+        elif self.mixer == "sparse_block":
+            attn = SparseBlockAttention(
+                self.num_heads, self.num_kv_heads, self.head_dim,
+                self.sparse, self.rms_eps, self.dtype, pdt,
+                use_pallas=self.use_pallas, decode=self.decode,
+                kv_page_size=self.kv_page_size,
+                kv_pool_pages=self.kv_pool_pages, name="attn")(
+                    h, cache_index, block_table, flash_prefill, window_pages)
+        elif self.mixer == "lightning":
+            attn, advanced = LightningAttention(
+                *self.lightning, self.rms_eps, self.dtype, pdt,
+                use_pallas=self.use_pallas, decode=self.decode,
+                kv_page_size=self.kv_page_size,
+                kv_pool_pages=self.kv_pool_pages, name="linear")(
+                    h, positions, cache_index, block_table, last_pos)
         elif self.latent is None:
             attn = GroupedQueryAttention(
                 self.num_heads, self.num_kv_heads, self.head_dim,
@@ -1151,6 +1496,8 @@ class RoutedBlock(nn.Module):
                 q_head_norm=self.q_head_norm,
                 head_gate=self.attention_head_gate, name="attn")(
                     h, positions, cache_index, block_table, window_pages)
+        if self.residual_scale != 1.0:
+            attn = attn * self.residual_scale
         x = x + attn
         h2 = rms_norm(x, g2, self.rms_eps, offset).reshape(b * s, d)
         if not routed:
@@ -1161,6 +1508,8 @@ class RoutedBlock(nn.Module):
                 self.param("dense_down", _normal(0.02),
                            (self.dense_width, d), pdt).astype(self.dtype),
                 self.activation)
+            if self.residual_scale != 1.0:
+                y = y * self.residual_scale
             return x + y.reshape(b, s, d), None, advanced
         if self.router_input != "pre_attention":
             idx, weights = choose(h2)
@@ -1179,6 +1528,8 @@ class RoutedBlock(nn.Module):
                 self.param("shared_down", _normal(0.02), (fs, d),
                            pdt).astype(self.dtype),
                 self.activation)
+        if self.residual_scale != 1.0:
+            y = y * self.residual_scale
         return x + y.reshape(b, s, d), sizes, advanced
 
 
@@ -1269,6 +1620,18 @@ class RoutedDecoderLM(nn.Module):
     summary_window: Optional[int] = None
     summary_chunk: int = 16
     norm_unit_offset: bool = False
+    # sparse_block layers (SparseBlockAttention; num_heads over num_kv_heads
+    # of head_dim, no positions): (block, pool, stride, top, window, init
+    # blocks, dense_len, q/k norm gain).  lightning layers
+    # (LightningAttention; rope_theta): (heads, head dim, the first layer's
+    # index in the published stack, the published depth) — a layer's decay
+    # follows from ITS published index.  mup: (embedding scale, depth
+    # scale, published depth, hidden / base width): the embedding is
+    # multiplied by the first, every residual branch by depth scale /
+    # sqrt(published depth), the final hidden rows divided by the last
+    sparse: Optional[Tuple] = None
+    lightning: Optional[Tuple] = None
+    mup: Optional[Tuple] = None
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
     use_pallas: Any = None
@@ -1285,6 +1648,8 @@ class RoutedDecoderLM(nn.Module):
         names = STATS if self.kv_lora_rank is None else LATENT_STATS
         if self.summary_window is not None:
             return SUMMARY_STATS
+        if "sparse_block" in self.layer_mixers():
+            return STATS[:3] + SPARSE_STATS + LINEAR_STATS
         if "linear_delta" in self.layer_mixers():
             return names + LINEAR_STATS
         return STATE_STATS if self.carries_state else names
@@ -1297,11 +1662,11 @@ class RoutedDecoderLM(nn.Module):
     @property
     def carries_state(self) -> bool:
         """Whether the cache holds running state beside pages of history
-        (a short-convolution or a linear_delta layer): a state entry is
+        (a short-convolution, linear_delta or lightning layer): a state entry is
         already past its page's newest token, so that token cannot be
         replayed on a copy of the page, and a chunk's call has to be told
         its real length (``last_pos``)."""
-        return bool(set(self.layer_mixers()) - {"attention"})
+        return bool(set(self.layer_mixers()) & set(STATE_MIXERS))
 
     def layer_kinds(self):
         """[(window or None, rope_theta or None)] a layer."""
@@ -1378,6 +1743,10 @@ class RoutedDecoderLM(nn.Module):
         embed = self.param("embed", _normal(0.02),
                            (self.vocab_size, self.d_model), pdt)
         x = embed[tokens].astype(jnp.float32)      # the stream is f32
+        residual_scale = 1.0
+        if self.mup is not None:
+            x = x * float(self.mup[0])
+            residual_scale = float(self.mup[1]) / math.sqrt(self.mup[2])
         offset = jnp.arange(s, dtype=jnp.int32)[None, :]
         if self.decode:
             if cache_index is None:
@@ -1418,7 +1787,12 @@ class RoutedDecoderLM(nn.Module):
                 route_groups=self.route_groups,
                 route_groups_kept=self.route_groups_kept,
                 experts_held=self.experts_held, summary=summary,
-                norm_unit_offset=self.norm_unit_offset, name=f"layer{i}")(
+                norm_unit_offset=self.norm_unit_offset, sparse=self.sparse,
+                lightning=(None if self.lightning is None else (
+                    self.lightning[0], self.lightning[1],
+                    self.lightning[2] + i, self.lightning[3],
+                    float(self.rope_theta))),
+                residual_scale=residual_scale, name=f"layer{i}")(
                     x, positions, cache_index, block_table, flash_prefill,
                     window_pages, last_pos)
             if sizes is not None:
@@ -1437,7 +1811,28 @@ class RoutedDecoderLM(nn.Module):
         if self.experts_held is not None:
             assignments = computed      # the pairs computed HERE
         n_state = len(kinds) - mixers.count("attention")
-        if latent is not None:
+        if "sparse_block" in mixers:
+            sizes = block_select.Sizes(*self.sparse[:7])
+            # the call's real queries: not tail padding, not an idle row
+            real = jnp.ones((b, s), bool)
+            if last_pos is not None:
+                real &= offset <= last_pos[:, None]
+            if self.decode and block_table is not None:
+                real &= (block_table[:, :1] != 0)
+            own = positions // sizes.block + 1
+            dense = positions + 1 <= sizes.dense_len
+            pairs = mixers.count("sparse_block") * self.num_kv_heads
+
+            def over(x):
+                return jnp.sum(jnp.where(real, x, 0), dtype=jnp.int32)
+            counts = jnp.stack([
+                assignments, touched, load_max, pairs * over(own),
+                pairs * over(jnp.where(dense, own, sizes.read)),
+                mixers.count("sparse_block") * over(jnp.where(
+                    dense, 0, block_select.pooled_exist(positions, sizes))),
+                over(dense.astype(jnp.int32)),
+                mixers.count("lightning") * over(1), advanced])
+        elif latent is not None:
             counts = [assignments, touched, load_max,
                       mixers.count("attention") * jnp.sum(live)]
             if n_state:
@@ -1474,6 +1869,8 @@ class RoutedDecoderLM(nn.Module):
                                    _norm_init(self.norm_unit_offset),
                                    (self.d_model,), pdt),
                      self.rms_eps, self.norm_unit_offset)
+        if self.mup is not None:
+            x = x / float(self.mup[3])
         if self.tie_head:
             return jnp.einsum("bsd,vd->bsv", x.astype(self.dtype),
                               embed.astype(self.dtype),

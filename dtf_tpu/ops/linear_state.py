@@ -49,6 +49,17 @@ residue.  **The form follows ``pool.dtype``**: a bfloat16 pool's matrix
 goes to the MXU as stored (kernel ``linear_state_decode_mxu1x3``); any
 other pool's is cut in three as well, nine exact products
 (``..._mxu3x3``).  No flag chooses.
+
+**No erase term** (``beta`` None in every form: a linear-attention layer
+with a constant decay a head and no write gate):
+
+    S_t = Diag(exp(a_t)) S_{t-1} + k_t v_t^T,    o_t = S_t^T q_t
+
+is the same arithmetic with ``u = 0`` (so ``w = v``) and ``kb = k``; the
+blocked form needs no triangular inverse and the kernel ONE reduction, ``M
+(alpha . q)``, where the delta rule has two (kernel
+``linear_state_decode_noerase_mxu1x3`` / ``..._mxu3x3``).  A token with
+``k = 0`` and ``a = 0`` leaves the state as it was.
 """
 
 from __future__ import annotations
@@ -63,21 +74,27 @@ from jax.experimental.pallas import tpu as pltpu
 
 _HI = jax.lax.Precision.HIGHEST
 BLOCK = 16      # tokens a block of the chunked form: 16 x 5 = 80 < 87
+# ... and of its no-erase form, whose block costs no inverse: a log decay
+# of at most 1 a token (lightning attention's slopes) is 32 from the middle
+NOERASE_BLOCK = 64
 
 
-def step(state, q, k, v, a, beta):
+def step(state, q, k, v, a, beta=None):
     """One token.  state [..., dv, dk] f32; q, k, a [..., dk]; v [..., dv];
-    beta [...].  Returns (o [..., dv], new state)."""
+    beta [...] (None: no erase term).  Returns (o [..., dv], new state)."""
     md = state * jnp.exp(a)[..., None, :]
+    if beta is None:
+        new = md + v[..., None] * k[..., None, :]
+        return jnp.einsum("...vk,...k->...v", new, q, precision=_HI), new
     u = jnp.einsum("...vk,...k->...v", md, k, precision=_HI)
     new = md + (beta[..., None] * (v - u))[..., None] * k[..., None, :]
     return jnp.einsum("...vk,...k->...v", new, q, precision=_HI), new
 
 
-def recurrent(q, k, v, a, beta, state=None):
+def recurrent(q, k, v, a, beta=None, state=None):
     """The definition.  q, k, a [B, S, H, dk]; v [B, S, H, dv]; beta
-    [B, S, H]; state [B, H, dv, dk] (None: zeros).  Returns (o
-    [B, S, H, dv], the state after the last token)."""
+    [B, S, H] (None: no erase term); state [B, H, dv, dk] (None: zeros).
+    Returns (o [B, S, H, dv], the state after the last token)."""
     b, _, h, dk = q.shape
     if state is None:
         state = jnp.zeros((b, h, v.shape[-1], dk), jnp.float32)
@@ -85,9 +102,10 @@ def recurrent(q, k, v, a, beta, state=None):
     def one(m, xs):
         o, m = step(m, *xs)
         return m, o
+    xs = (q, k, v, a) if beta is None else (q, k, v, a, beta)
     state, o = jax.lax.scan(
         one, state, tuple(jnp.moveaxis(x.astype(jnp.float32), 1, 0)
-                          for x in (q, k, v, a, beta)))
+                          for x in xs))
     return jnp.moveaxis(o, 0, 1), state
 
 
@@ -105,11 +123,14 @@ def _unit_lower_inverse(low):
     return inv
 
 
-def chunked(q, k, v, a, beta, state=None, *, block: int = BLOCK,
+def chunked(q, k, v, a, beta=None, state=None, *, block: int = BLOCK,
             emit_every: int = 0):
     """The blocked form of :func:`recurrent` for ``S`` a multiple of
     ``block``.  ``emit_every`` (tokens, a multiple of ``block`` that
     divides S; 0: S) also returns the state after every that many tokens.
+    ``beta`` None: no erase term — a block's tokens meet through ``seen``
+    alone, no triangular inverse, and ``block`` may be as long as its
+    summed log decay stays inside float32 from the middle.
     Returns (o [B, S, H, dv], states [B, S // emit_every, H, dv, dk]); the
     last of ``states`` is the state after the call."""
     b, s, h, dk = q.shape
@@ -125,16 +146,21 @@ def chunked(q, k, v, a, beta, state=None, *, block: int = BLOCK,
     def blocks(x):      # [B, S, H, ...] -> [N, B, H, C, ...]
         x = x.astype(jnp.float32).reshape((b, n, c, h) + x.shape[3:])
         return jnp.moveaxis(jnp.moveaxis(x, 3, 2), 1, 0)
-    q, k, v, a, beta = (blocks(x) for x in (q, k, v, a, beta))
+    erase = beta is not None
+    q, k, v, a = (blocks(x) for x in (q, k, v, a))
     run = jnp.cumsum(a, axis=3)                     # log decay, block start
     mid = run - run[:, :, :, c // 2:c // 2 + 1]     # ... from the middle
     k_up, k_down = k * jnp.exp(mid), k * jnp.exp(-mid)
     lower = jnp.tril(jnp.ones((c, c), jnp.float32), -1)
-    meet = jnp.einsum("nbhik,nbhjk->nbhij", k_up, k_down, precision=_HI)
-    inv = _unit_lower_inverse(beta[..., None] * meet * lower)
     gamma = jnp.exp(run)
-    w = jnp.matmul(inv, beta[..., None] * k * gamma, precision=_HI)
-    u0 = jnp.matmul(inv, beta[..., None] * v, precision=_HI)
+    if erase:
+        beta = blocks(beta)
+        meet = jnp.einsum("nbhik,nbhjk->nbhij", k_up, k_down, precision=_HI)
+        inv = _unit_lower_inverse(beta[..., None] * meet * lower)
+        w = jnp.matmul(inv, beta[..., None] * k * gamma, precision=_HI)
+        u0 = jnp.matmul(inv, beta[..., None] * v, precision=_HI)
+    else:
+        u0 = v
     seen = jnp.einsum("nbhik,nbhjk->nbhij", q * jnp.exp(mid), k_down,
                       precision=_HI) * (lower + jnp.eye(c))
     q_in = q * gamma
@@ -142,8 +168,9 @@ def chunked(q, k, v, a, beta, state=None, *, block: int = BLOCK,
     k_out = k * jnp.exp(total[:, :, :, None] - run)
 
     def one(m, xs):
-        w_, u0_, seen_, q_, k_, total_ = xs
-        u = u0_ - jnp.einsum("bhck,bhvk->bhcv", w_, m, precision=_HI)
+        *w_, u, seen_, q_, k_, total_ = xs
+        if erase:
+            u = u - jnp.einsum("bhck,bhvk->bhcv", w_[0], m, precision=_HI)
         o = (jnp.einsum("bhck,bhvk->bhcv", q_, m, precision=_HI)
              + jnp.matmul(seen_, u, precision=_HI))
         m = (m * jnp.exp(total_)[:, :, None, :]
@@ -156,7 +183,8 @@ def chunked(q, k, v, a, beta, state=None, *, block: int = BLOCK,
     per = emit_every // block
     _, (o, states) = jax.lax.scan(
         page, state, tuple(x.reshape((n // per, per) + x.shape[1:])
-                           for x in (w, u0, seen, q_in, k_out, total)))
+                           for x in ((w,) if erase else ())
+                           + (u0, seen, q_in, k_out, total)))
     o = o.reshape((n,) + o.shape[2:])               # [N, B, H, C, dv]
     o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3).reshape(b, s, h, dv)
     return o, jnp.moveaxis(states, 0, 1)
@@ -180,14 +208,14 @@ def paged_step(pool, q, k, v, a, beta, block_table, index, *,
     whose entry for page ``p`` is the state at the newest token written in
     ``p``: a row's carry is the entry of the page that holds ``index - 1``
     (zeros at ``index`` 0), its new state goes to the page that holds
-    ``index``.  q, k, a [B, H, dk]; v [B, H, dv]; beta [B, H].  Returns (o
-    [B, H, dv] f32, the pool).  The oracle of
+    ``index``.  q, k, a [B, H, dk]; v [B, H, dv]; beta [B, H] (None: no
+    erase term).  Returns (o [B, H, dv] f32, the pool).  The oracle of
     :func:`linear_state_decode`."""
     src, dst = _pages(block_table, index, page_size)
     carry = jnp.where((index > 0)[:, None, None, None],
                       pool[src].astype(jnp.float32), 0.0)
-    o, new = step(carry, *(x.astype(jnp.float32)
-                           for x in (q, k, v, a, beta)))
+    xs = (q, k, v, a) if beta is None else (q, k, v, a, beta)
+    o, new = step(carry, *(x.astype(jnp.float32) for x in xs))
     return o, pool.at[dst].set(new.astype(pool.dtype))
 
 
@@ -354,6 +382,51 @@ def _decode_kernel(tbl_ref, idx_ref, q_ref, k_ref, kb_ref, alpha_ref, v_ref,
                   sem_out, advance, page_size=page_size)
 
 
+def _decode_kernel_noerase(tbl_ref, idx_ref, q_ref, k_ref, alpha_ref, v_ref,
+                           pool_hbm, o_ref, pool_out, sbuf, obuf, sem_in,
+                           sem_out, *, page_size: int, split: bool):
+    """:func:`_decode_kernel` without the erase term (``u = 0``, so ``w =
+    v``, and ``kb = k``): a tile's ONE reduction ``M (alpha . q)`` is the
+    ``[3 th, dk]`` bfloat16 pieces of ``alpha . q`` against every head of
+    the tile; ``o = M (alpha . q) + v (k . q)``, ``new = M . alpha + v
+    k^T``.  The same tiles, the same pipeline."""
+    heads, dv = sbuf.shape[1:3]
+    th = math.gcd(heads, 8)
+    tiles = 1 if heads // th % 2 else 2
+    sub = jax.lax.broadcasted_iota(jnp.int32, (th, dv), 0)
+    f32 = jnp.float32
+
+    def advance(slot):
+        def tile(t):
+            at = pl.multiple_of(t * th, th)
+            rows = pl.ds(at, th)
+            k, q, v = k_ref[rows, :], q_ref[rows, :], v_ref[rows, :]
+            lhs = jnp.concatenate(_pieces(alpha_ref[rows, :] * q), 0)
+            p = jnp.zeros((th, dv), f32)
+            for j in range(th):
+                m = sbuf[slot, at + j]
+                res = sum(jax.lax.dot_general(lhs, part, _NT,
+                                              preferred_element_type=f32)
+                          for part in (_pieces(m) if split else (m,)))
+                res = res.reshape(3, th, dv)
+                p = jnp.where(sub == j, res[0] + res[1] + res[2], p)
+            o_ref[rows, :] = p + v * jnp.sum(k * q, axis=1, keepdims=True)
+            vt = jnp.transpose(v)                       # [dv, th]
+            for j in range(th):
+                row = pl.ds(at + j, 1)
+                new = (sbuf[slot, at + j].astype(f32) * alpha_ref[row, :]
+                       + vt[:, j:j + 1] * k_ref[row, :])
+                obuf[slot, at + j] = new.astype(obuf.dtype)
+
+        def group(i, carry):
+            for j in range(tiles):
+                tile(i * tiles + j)
+            return carry
+        jax.lax.fori_loop(0, heads // (th * tiles), group, 0)
+    _row_pipeline(tbl_ref, idx_ref, pool_hbm, pool_out, sbuf, obuf, sem_in,
+                  sem_out, advance, page_size=page_size)
+
+
 @functools.partial(jax.jit, static_argnames=("page_size", "interpret"))
 def linear_state_decode(pool, q, k, v, a, beta, block_table, index, *,
                         page_size: int, interpret: bool = False):
@@ -361,15 +434,26 @@ def linear_state_decode(pool, q, k, v, a, beta, block_table, index, *,
     aliased to the result; the serving body donates its cache).  Rows with
     an all-zero table read and write the scratch page 0.  Jitted, so that
     the layers of a model share one lowering.  The kernel's name says which
-    form ``pool.dtype`` chose (the module's docstring)."""
+    form ``pool.dtype`` chose, and ``noerase`` where ``beta`` is None (the
+    module's docstring)."""
     b, h, dk = q.shape
     dv = v.shape[-1]
     f32 = jnp.float32
-    q, k, v, a, beta = (x.astype(f32) for x in (q, k, v, a, beta))
     split = pool.dtype != jnp.bfloat16
 
     def rows(lanes):
         return pl.BlockSpec((None, h, lanes), lambda r, tbl, idx: (r, 0, 0))
+    if beta is None:
+        q, k, v, a = (x.astype(f32) for x in (q, k, v, a))
+        return _state_call(
+            functools.partial(_decode_kernel_noerase, page_size=page_size,
+                              split=split),
+            pool, block_table, index, (q, k, jnp.exp(a), v),
+            [rows(dk)] * 3 + [rows(dv)], rows(dv), (b, h, dv),
+            name="linear_state_decode_noerase_mxu" + ("3x3" if split
+                                                      else "1x3"),
+            interpret=interpret)
+    q, k, v, a, beta = (x.astype(f32) for x in (q, k, v, a, beta))
     return _state_call(
         functools.partial(_decode_kernel, page_size=page_size, split=split),
         pool, block_table, index,

@@ -760,6 +760,41 @@ def paged_flash_decode(q, pool_k, pool_v, block_table, index, *,
     return out[..., d // 2:] if one_pool else out
 
 
+def paged_block_attention(q, pool_k, pool_v, blocks, count, last, *,
+                          block: int, use_pallas=None):
+    """Attention of ONE query a (row, KV head) over a table of BLOCKS that
+    are parts of a page, the table a (row, KV head)'s own: q [B, Hq, Dh];
+    pools [P, page, Hkv, Dh]; ``blocks`` [B, Hkv, W] int32 ids into the
+    pools seen as ``[P * page / block, block, Hkv, Dh]`` (a page's blocks
+    are contiguous, so the reshape is free), of which the first ``count``
+    [B] are read, in order; the last of them is read up to its row
+    ``last`` [B] (the query's own block, up to the query), the others
+    whole.  Returns [B, Hq, Dh].
+
+    The read blocks are a PREFIX of the table, so a (row, KV head) is a row
+    of :func:`paged_flash_decode` at pages of ``block`` tokens whose index
+    is ``(count - 1) * block + last``: the kernel copies ``count`` blocks
+    and nothing else, whatever the row's length.  A block as stored holds
+    every KV head (two bfloat16 heads share a 32-bit word), so a copy
+    brings the other heads' keys along and each KV head's queries are a
+    row of their own, the other heads' query rows zeros."""
+    b, hq, d = q.shape
+    p, page, hkv, _ = pool_k.shape
+    g = hq // hkv
+    pool_k = pool_k.reshape(p * (page // block), block, hkv, d)
+    pool_v = pool_v.reshape(p * (page // block), block, hkv, d)
+    own = (jnp.arange(hq, dtype=jnp.int32)[None, :] // g
+           == jnp.arange(hkv, dtype=jnp.int32)[:, None])        # [Hkv, Hq]
+    rows = jnp.where(own[None, :, :, None], q[:, None], 0).reshape(
+        b * hkv, 1, hq, d)
+    index = jnp.repeat((count - 1) * block + last, hkv)
+    o = paged_attention_auto(rows, pool_k, pool_v,
+                             blocks.reshape(b * hkv, -1), index,
+                             use_pallas=use_pallas)
+    o = o.reshape(b, hkv, hkv, g, d)
+    return jnp.stack([o[:, h, h] for h in range(hkv)], 1).reshape(b, hq, d)
+
+
 def paged_flash_decode_reference(q, pool_k, pool_v, block_table, index, *,
                                  scale=None):
     """Plain-JAX block-by-block accumulation — the kernel's portable

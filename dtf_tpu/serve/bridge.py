@@ -176,8 +176,10 @@ def serving_memory_plan(model, *, num_slots: int, max_seq_len: int,
 
     shapes = trace_paged_init(model, kv_page_size, 2)[0]
     kinds, pools = zip(*cache_leaves(shapes, KV_POOL, LATENT_POOL))
-    per_token = sum(int(np.prod(p.shape[2:])) * np.dtype(p.dtype).itemsize
-                    for p in pools)
+    # a pool's bytes a page over the page's tokens: a token's row, or its
+    # share of the rows a page holds (a block-sparse layer's pooled keys)
+    per_token = sum(int(np.prod(p.shape[1:])) * np.dtype(p.dtype).itemsize
+                    // kv_page_size for p in pools)
     per_page_state = state_bytes_per_page(shapes)
     # [P, page, H, Dh] a K or V pool; [P, page, W] a pool of latent rows
     kv_heads = pools[0].shape[2] if kinds[0] == KV_POOL else 1

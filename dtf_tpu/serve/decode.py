@@ -95,6 +95,10 @@ KV_POOL, LATENT_POOL, PAGE_STATE = "kv_pool", "latent_pool", "page_state"
 CACHE_LEAF_KINDS = {
     "paged_key": KV_POOL, "paged_value": KV_POOL,   # [P, page, H, Dh]
     "paged_kv": KV_POOL,            # [P, page, H, 2 * Dh]: rows of [k | v]
+    # [P, page / stride, H, Dh]: a block-sparse layer's POOLED keys, one row
+    # a ``stride`` tokens of the page — a pool by rows a page, which its
+    # bytes a page say, not its second axis
+    "pooled_key": KV_POOL,
     "paged_latent": LATENT_POOL,    # [P, page, W]: one row a token
     # [P, W] (ShortConv) or [P, sublanes, W / sublanes] (LinearDelta: the
     # entry as whole tiles of its own): one running entry a page

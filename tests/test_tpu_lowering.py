@@ -710,6 +710,101 @@ def test_window_summary_serve_bodies_compile_for_v5e(v5e, summary_decoder,
     assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
 
 
+def _kernel_calls(text, name):
+    """Custom calls of the Pallas kernel ``name`` in an optimized HLO."""
+    return len(re.findall(r"^\s*%" + name + r"(\.\d+)? = [^\n]*custom-call\(",
+                          text, re.M))
+
+
+@pytest.fixture(scope="module")
+def sparse_decoder():
+    """MiniCPM-SALA's stage of the benchmark at its published widths (8
+    layers S L L L L L L S: 32 query heads over 2 KV heads of 128 that
+    choose 64-token blocks through pooled keys, 32 lightning heads of 128
+    x 128, dense MLPs of 16,384, 73,448 vocabulary rows; shapes only), 24
+    slots of 133,120 tokens, PAGES OF 2,048 in a 705-page pool."""
+    from dtf_tpu.models import build_model
+    model, _ = build_model(
+        "routed_decoder", num_classes=73448, dtype=jnp.bfloat16,
+        num_layers=8, d_model=4096, num_heads=32, num_kv_heads=2,
+        head_dim=128, layer_mixer=["sparse_block"] + ["lightning"] * 6
+        + ["sparse_block"], sparse=[64, 32, 16, 64, 2048, 1, 8192, 2.0],
+        lightning=[32, 128, 9, 32], mup=[12.0, 1.4, 32, 16.0],
+        rope_theta=1e4, num_dense_layers=8, dense_width=16384,
+        activation="silu", max_seq_len=524288, param_dtype="bfloat16")
+    params = jax.eval_shape(model.clone(use_pallas=False).init,
+                            jax.random.key(0),
+                            jnp.zeros((1, 2048), jnp.int32))["params"]
+    return _shapes_only_decoder(model, params, num_slots=24,
+                                max_seq_len=133120, kv_page_size=2048,
+                                kv_pool_pages=705)
+
+
+@pytest.mark.parametrize("body", ["chunk_first", "chunk", "decode"])
+def test_sparse_lightning_serve_bodies_compile_for_v5e(v5e, sparse_decoder,
+                                                       body):
+    """Row by row, what a body of the block-sparse + lightning decoder
+    holds.  The DECODE body: ONE ``block_select`` call and ONE paged call a
+    sparse layer (a (row, KV head) a row of ``paged_flash_decode`` at
+    blocks of 64 tokens), ONE no-erase state-kernel call a lightning layer
+    in the bfloat16 pool's form, no ``while`` over rows, and every
+    pooled-key write ONE scatter a layer (the leaf's row is ``[2, 128]``,
+    a tile of its own: PR 40's serial loop does not come back).  A chunk of
+    one page compiles at 32 query heads over 2 KV heads: the first through
+    the flash kernel, a later one with both branches — whole pages at or
+    under ``dense_len``, a choice and a gather a token past it."""
+    from dtf_tpu.serve import decode as sd
+    i32, f32 = jnp.int32, jnp.float32
+    dec = sparse_decoder
+    assert dec.carries_state and not dec.decode_all_heads
+    assert dec.state_bytes_per_page == 6 * 32 * 128 * 128 * 2
+    if body == "decode":
+        compiled = _compile_decode_body(dec, v5e)
+    else:
+        s, m = jax.ShapeDtypeStruct, dec.pages_per_slot
+        args = _on_chip((dec.params, jax.eval_shape(dec.fresh_cache),
+                         s((1, 2048), i32), s((1, m), i32), s((), i32),
+                         s((), f32),
+                         jax.eval_shape(lambda: sd.position_key(0, 0)),
+                         s((), i32)), v5e)
+        compiled = jax.jit(
+            dec._chunk_impl, donate_argnums=(1,), static_argnums=(8, 9),
+            compiler_options=sd.TPU_BODY_OPTIONS).lower(
+                *args, None, body == "chunk_first").compile()
+    text = compiled.as_text()
+    pooled_scatters = len(re.findall(
+        r"= bf16\[90240,2,128\]\S* scatter\(", text))
+    if body == "decode":
+        assert _kernel_calls(text, "block_select") == 2
+        assert _kernel_calls(text, "paged_flash_decode") == 2
+        assert _kernel_calls(text,
+                             "linear_state_decode_noerase_mxu1x3") == 6
+        assert "linear_state_decode_mxu" not in text
+        assert " while(" not in text
+        assert pooled_scatters == 2
+        # K and V: a token a row, one scatter a pool a layer
+        assert len(re.findall(r"= bf16\[1443840,2,128\]\S* scatter\(",
+                              text)) == 4
+        assert compiled.memory_analysis().temp_size_in_bytes < 0.05e9
+    elif body == "chunk_first":
+        assert text.count("flash_fwd") >= 2
+        assert _kernel_calls(text, "paged_flash_decode") == 0
+        assert pooled_scatters == 2
+    else:
+        # a layer: the dense branch's call and the sparse branch's
+        assert _kernel_calls(text, "paged_flash_decode") == 4
+        assert pooled_scatters == 2
+    if body != "decode":
+        # the six state entries of the chunk's page: written in place
+        assert len(re.findall(
+            r"= bf16\[705,32,128,128\]\S* dynamic-update-slice\(",
+            text)) == 6
+        assert not [ln for ln in text.splitlines()
+                    if " while(" in ln and "/scatter" in ln]
+        # 7.49e9 B of pool are donated and updated in place
+        assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
 def test_dense_decode_body_compiles_for_v5e(v5e):
     """The dense cells' whole decode body with ``TPU_BODY_OPTIONS``, not
     the kernel alone (what a kernel may take of VMEM depends on the body
