@@ -12,7 +12,12 @@ the logits the engine's own bodies give when they are replayed — held
 to the plain reference the configuration names; then the load runs for
 ``ramp_s`` before the window opens.  The load keeps running after the
 window until every request that fell due inside it has finished (at most
-``drain_s``), so the tail sees the same system as the head.
+``drain_s``), so the tail sees the same system as the head.  A traced
+open-loop run stops the profiler while that load still arrives, and the
+stop takes as long as the window's events are many: its schedule gets one
+more phase, ``STOP_PHASE_S`` long, behind the untraced run's three, so the
+prepared requests outlast the stop and the first three phases are the
+untraced run's to the request.
 """
 
 from __future__ import annotations
@@ -118,6 +123,9 @@ def _reset_histograms(engine, names):
 
 
 QUANTILES = (50, 90, 95, 99)
+# the profiler's stop took 11.0-13.3 s after a 6 s window of the loaded cell
+# (my chip runs, PR 37) and 30-36 s after a closed loop's 36 s (PR 45)
+STOP_PHASE_S = 90.0
 
 
 def _histogram_read(engine, names):
@@ -263,11 +271,23 @@ def setup(ctx: RunContext):
     return engine, m.mix, m.vocab, agreement
 
 
+def load_phases(mix: dict, seconds: float, traced: bool) -> list:
+    """The lengths of the load's phases: ramp, window, tail — and behind
+    them, in a traced open-loop run alone, the arrivals that fall due while
+    the profiler stops.  A phase is drawn from its own number
+    (``traffic.phase_draw``) and the token ids in order, so the first
+    three phases are the untraced run's to the request."""
+    phases = [mix["ramp_s"], seconds, mix["drain_s"] + 5]
+    if traced and mix["arrivals"] != "closed":
+        phases.append(STOP_PHASE_S)
+    return phases
+
+
 def measure(ctx: RunContext, engine, mix: dict, vocab: int, seconds: float,
             traced: TracedWindow = None) -> dict:
     """Ramp, window, drain under ``mix``; what the clients saw."""
     requests = traffic.make_requests(
-        mix, ctx.seed, [mix["ramp_s"], seconds, mix["drain_s"] + 5], vocab)
+        mix, ctx.seed, load_phases(mix, seconds, traced is not None), vocab)
     load = _Load(engine, requests, mix)
     load.start()
     time.sleep(mix["ramp_s"])
@@ -286,6 +306,7 @@ def measure(ctx: RunContext, engine, mix: dict, vocab: int, seconds: float,
     decode_steps = histograms["serve_decode_step_s"]["count"]
     if traced is not None:
         traced.stop()
+    trace_stop_s = time.monotonic() - t_close
     pool_high_water = engine.pool.high_water
     outstanding_close = engine.outstanding
 
@@ -367,6 +388,8 @@ def measure(ctx: RunContext, engine, mix: dict, vocab: int, seconds: float,
         compiles_in_window=compiles_in_window,
         compiled_in_window=compiled_in_window,
         live_pages_per_step=histograms["serve_decode_live_pages"]["mean"],
+        trace_stop_s=trace_stop_s if traced is not None else None,
+        requests_prepared=len(requests),
         offered_per_s=len(mine) / window_s, requests_total=len(sent),
         serve_tok_s=end_to_end["serve_tok_s"])
     ctx.note(phase="serve_window", **note)
@@ -380,7 +403,12 @@ def measure(ctx: RunContext, engine, mix: dict, vocab: int, seconds: float,
             "note": note,
             "readers": {"decode_steps": decode_steps, "window_s": window_s,
                         "histograms": histograms, "client": client,
-                        "window_wall": (wall_open, wall_close)}}
+                        "window_wall": (wall_open, wall_close),
+                        # the sizes the run really had (a rehearsal's are
+                        # the toy's), for readers/span_mfu.py
+                        "engine": {"max_batch": engine.max_batch,
+                                   "page_size": engine.page_size},
+                        "prompt_lens": mix["prompt_len"].get("snap_to")}}
 
 
 def run(ctx: RunContext) -> dict:
@@ -411,5 +439,10 @@ def run(ctx: RunContext) -> dict:
     result["readers"].update(
         records=records, profile_dir=traced.trace_dir if traced else None)
     result.update(correct=not reasons,
-                  memory_peak_bytes=memory_peak_bytes())
+                  memory_peak_bytes=memory_peak_bytes(),
+                  # each number the agreement compared, beside its limit
+                  compared={"logit_rms": [agreement.get("logit_rms"),
+                                          agreement.get("logit_rms_limit")],
+                            "worst_gap": [agreement["worst_gap"],
+                                          agreement["allowed_gap"]]})
     return result

@@ -169,6 +169,9 @@ def run(ctx: RunContext) -> dict:
     result = {
         "correct": not reasons, "reasons": reasons,
         "attempted": steps, "failed": bad_steps,
+        # the one number compared: the window's last loss under the first
+        "compared": {"loss_last": [in_window[-1] if in_window else None,
+                                   losses[0][1] if losses else None]},
         "setup_s": clock.t_open - ctx.t_process,
         "memory_peak_bytes": memory_peak_bytes(),
         "readers": {"steps": steps, "window_s": window_s,

@@ -14,7 +14,16 @@ A family's file exports
 ``COUNTED_COSTS`` (likewise)
     name -> ``f(config, count) -> (FLOPs, bytes)`` of a kernel's calls
     over ``count`` units the program counted, all layers together
-    (``readers/trace_kernel_counted.py``).
+    (``readers/trace_kernel_counted.py``);
+``SPAN_COSTS`` (likewise)
+    name -> ``f(config, span) -> (FLOPs, bytes)`` of a kernel's calls in
+    the one compiled call a span of the program records, from the counts
+    on the span (``readers/trace_kernel_spans.py``); and, in a family
+    that is served, ``model_flops``: ``f(config, call) -> FLOPs`` the
+    configuration's mathematics needs for that call, whatever the
+    implementation does (``readers/span_mfu.py``, the metric
+    ``serve_mfu``; a new serving cell adds its name to that metric's
+    ``workloads``).
 
 The family's plain reference is the file a configuration names under
 ``reference`` (a path from the repo's root): a module with

@@ -15,6 +15,8 @@ family is served, not trained, so ``train_flops_per_sample`` is what
 
 from __future__ import annotations
 
+from benchmark.lib import costs
+
 
 def head_dim(cfg: dict) -> int:
     return cfg["hidden_size"] // cfg["num_attention_heads"]
@@ -82,8 +84,46 @@ def window_compactions(cfg: dict, span: dict):
             float(windows * (w + w // c) * kv_bytes_per_row(cfg)))
 
 
+def rows_seen(cfg: dict, start: int, tokens: int) -> int:
+    """Cached rows the ``tokens`` queries at positions ``start ...`` must
+    see between them in ONE layer: a query's own aligned window up to
+    itself, exact, and one summary a ``chunk_size`` positions of every
+    window before it."""
+    w, per = cfg["window_size"], cfg["window_size"] // cfg["chunk_size"]
+    return sum(p - p // w * w + 1 + p // w * per
+               for p in range(start, start + tokens))
+
+
+def model_flops(cfg: dict, call: dict):
+    """FLOPs the configuration's mathematics needs for one compiled call of
+    the serving engine (``readers/span_mfu.py`` says what ``call`` holds):
+    2 a matmul parameter a REAL token, the head at the one position a
+    chunk samples and at one a decoding row, attention as 4 x heads x head
+    width a layer a cached row a query must see — exact rows of its own
+    window and the summaries before it (``rows_seen``) in a chunk; in a
+    decode step what the program counted (``kv_exact_rows_read`` +
+    ``kv_summary_rows_read``) less the one row it counts a layer for each
+    idle row.  The closes' own work (a score and two weighted sums a row a
+    window) is not counted: it reads low.  None for a step whose rows
+    nobody counted."""
+    head = cfg["hidden_size"] * cfg["vocab_size"]
+    body = matmul_params(cfg) - head
+    layers = cfg["num_hidden_layers"]
+    per_key = 2 * 2 * cfg["num_attention_heads"] * head_dim(cfg)
+    if "tokens" in call:
+        n = call["real_tokens"]
+        return (2.0 * body * n + 2.0 * head
+                + layers * per_key * rows_seen(cfg, call["start"], n))
+    if not call.get("rows"):
+        return None
+    keys = costs.step_keys(call, ("kv_exact_rows_read",
+                                  "kv_summary_rows_read"), layers)
+    return 2.0 * (body + head) * call["rows"] + per_key * keys
+
+
 SPAN_COSTS = {"paged_attention_reads": paged_attention_reads,
-              "window_compactions": window_compactions}
+              "window_compactions": window_compactions,
+              "model_flops": model_flops}
 
 # rehearse.py's sizes: the shape of the thing — four dense layers, 4 heads
 # of 16, a window of 32 positions with a summary every 4 (8 summaries a

@@ -58,8 +58,31 @@ def paged_decode_context(cfg: dict, context_tokens: float) -> tuple:
     return cfg["n_layer"] * flops, cfg["n_layer"] * nbytes
 
 
+def model_flops(cfg: dict, call: dict):
+    """FLOPs the configuration's mathematics needs for one compiled call of
+    the serving engine (``readers/span_mfu.py`` says what ``call`` holds):
+    2 a matmul parameter of the blocks a REAL token, the head at the one
+    position a chunk samples and at one a decoding row, attention as 4 x
+    width a layer a key a query must see.  A chunk's keys are the causal
+    rule's; a decode step's the lower bound ``context_tokens`` (none: no
+    attention counted).  None for a step whose rows nobody counted."""
+    d = cfg["n_embd"]
+    head = d * cfg["vocab_size"]
+    body = gpt_matmul_params(cfg) - head
+    per_key = cfg["n_layer"] * 2 * 2 * d
+    if "tokens" in call:
+        n = call["real_tokens"]
+        return (2.0 * body * n + 2.0 * head
+                + per_key * costs.causal_keys(call["start"], n))
+    if not call.get("rows"):
+        return None
+    return (2.0 * (body + head) * call["rows"]
+            + per_key * call.get("context_tokens", 0.0))
+
+
 STEP_COSTS = {"flash_train_step": flash_train_step}
 COUNTED_COSTS = {"paged_decode_context": paged_decode_context}
+SPAN_COSTS = {"model_flops": model_flops}
 
 # rehearse.py's sizes: the same block, small enough for the CPU
 _TOY_MODEL = {"num_layers": 2, "d_model": 128, "num_heads": 1, "d_ff": 256}

@@ -15,6 +15,8 @@ reads yet.
 
 from __future__ import annotations
 
+from benchmark.lib import costs
+
 LANES = 128     # the cache row is stored in whole lane tiles
 
 
@@ -89,8 +91,40 @@ def latent_attention_reads(cfg: dict, span: dict):
     return per_pair * seen * q_len, 2.0 * tokens * row_lanes(cfg)
 
 
+def model_flops(cfg: dict, call: dict):
+    """FLOPs the configuration's mathematics needs for one compiled call of
+    the serving engine (``readers/span_mfu.py`` says what ``call`` holds):
+    2 a matmul parameter a REAL token activates (the experts it is sent to
+    and the shared one), the head at the one position a chunk samples and
+    at one a decoding row, and attention.  A decode step's query meets a
+    cached latent row over ITS width — a score over ``kv_lora_rank +
+    qk_rope_head_dim``, a value sum over ``kv_lora_rank``, every head: no
+    form of a step is cheaper, since expanding a row's cached keys again
+    costs more — over ``latent_tokens_read`` less the one position the
+    program counts for each idle row.  A chunk's queries meet a key
+    expanded once (the token's own projection, among its parameters): nope
+    + rope + v a head a key the causal rule shows, as
+    ``train_flops_per_sample`` counts it.  None for a step whose rows
+    nobody counted."""
+    head = cfg["hidden_size"] * cfg["vocab_size"]
+    body = active_matmul_params(cfg) - head
+    hq, layers = cfg["num_attention_heads"], cfg["num_hidden_layers"]
+    if "tokens" in call:
+        n = call["real_tokens"]
+        per_key = 2 * hq * (cfg["qk_nope_head_dim"]
+                            + cfg["qk_rope_head_dim"] + cfg["v_head_dim"])
+        return (2.0 * body * n + 2.0 * head + layers * per_key
+                * costs.causal_keys(call["start"], n))
+    if not call.get("rows"):
+        return None
+    per_key = 2 * hq * (2 * cfg["kv_lora_rank"] + cfg["qk_rope_head_dim"])
+    keys = costs.step_keys(call, ("latent_tokens_read",), layers)
+    return 2.0 * (body + head) * call["rows"] + per_key * keys
+
+
 SPAN_COSTS = {"expert_matmuls": expert_matmuls,
-              "latent_attention_reads": latent_attention_reads}
+              "latent_attention_reads": latent_attention_reads,
+              "model_flops": model_flops}
 
 # rehearse.py's sizes: the shape of the thing — one dense layer then three
 # routed ones, 4 heads of nope/rope/v 16/8/16 over latents of rank 32 (q)

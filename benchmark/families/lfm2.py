@@ -15,6 +15,8 @@ family is served, not trained, so ``train_flops_per_sample`` is what
 
 from __future__ import annotations
 
+from benchmark.lib import costs
+
 LANES = 128     # a pool row is stored in whole lane tiles
 
 
@@ -111,8 +113,34 @@ def paged_attention_reads(cfg: dict, span: dict):
             2.0 * tokens * cfg["num_key_value_heads"] * kv_row_lanes(cfg))
 
 
+def model_flops(cfg: dict, call: dict):
+    """FLOPs the configuration's mathematics needs for one compiled call of
+    the serving engine (``readers/span_mfu.py`` says what ``call`` holds):
+    2 a matmul parameter a REAL token activates (the experts it is sent to,
+    not those held), the tied head at the one position a chunk samples and
+    at one a decoding row, attention in the attention layers as 4 x query
+    heads x head width a key a query must see — the causal rule in a chunk;
+    in a decode step what the program counted (``kv_tokens_read_global``:
+    the rows' histories, those layers only) less the one position it
+    counts for each idle row.  The convolution's taps are elementwise: not
+    counted.  None for a step whose rows nobody counted."""
+    head = cfg["hidden_size"] * cfg["vocab_size"]
+    body = active_matmul_params(cfg) - head
+    attends = layer_types(cfg).count("full_attention")
+    per_key = 2 * 2 * cfg["num_attention_heads"] * head_dim(cfg)
+    if "tokens" in call:
+        n = call["real_tokens"]
+        return (2.0 * body * n + 2.0 * head + attends * per_key
+                * costs.causal_keys(call["start"], n))
+    if not call.get("rows"):
+        return None
+    keys = costs.step_keys(call, ("kv_tokens_read_global",), attends)
+    return 2.0 * (body + head) * call["rows"] + per_key * keys
+
+
 SPAN_COSTS = {"expert_matmuls": expert_matmuls,
-              "paged_attention_reads": paged_attention_reads}
+              "paged_attention_reads": paged_attention_reads,
+              "model_flops": model_flops}
 
 # rehearse.py's sizes: the shape of the thing — two dense and six routed
 # layers in the order c c a c c c a c, 4 query over 2 KV heads of 64 with

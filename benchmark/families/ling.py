@@ -20,6 +20,8 @@ reads yet.
 
 from __future__ import annotations
 
+from benchmark.lib import costs
+
 LANES = 128     # a cache row is stored in whole lane tiles
 
 
@@ -146,9 +148,57 @@ def linear_state_steps(cfg: dict, span: dict):
             * rows, 2.0 * matrix_bytes_per_page(cfg) * rows)
 
 
+def model_flops(cfg: dict, call: dict):
+    """FLOPs the configuration's mathematics needs for one compiled call of
+    the serving engine (``readers/span_mfu.py`` says what ``call`` holds):
+    2 a matmul parameter a REAL token meets outside the chosen experts
+    (mixers, dense MLPs, the router over the published count, the shared
+    expert), the head onto the rows held at the one position a chunk
+    samples and at one a decoding row, and of a token's chosen experts the
+    pairs COMPUTED HERE (``assignments``: the router's, not an
+    implementation's; it runs over every row and padded position of the
+    call, so the real tokens take their share of it; no count: none).  A
+    linear layer's state: three products of ``head_dim x head_dim`` a head
+    a real token.  The latent layer as ``joyai``'s: a decode step's query
+    meets a cached row over its stored width (``latent_tokens_read`` less
+    the one position counted for each idle row), a chunk's queries a key
+    expanded once under the causal rule.  A chunk's real tokens are the
+    program's own count (``linear_tokens`` over the linear layers) where
+    the span carries it.  None for a step whose rows nobody counted."""
+    d, hq, dh = (cfg["hidden_size"], cfg["num_attention_heads"],
+                 cfg["head_dim"])
+    kinds = layer_types(cfg)
+    expert = 3 * d * cfg["moe_intermediate_size"]
+    routed = len(kinds) - cfg["first_k_dense_replace"]
+    head = d * cfg["vocab_size"]
+    body = (active_matmul_params(cfg) - head
+            - routed * cfg["num_experts_per_tok"] * expert)
+    state = kinds.count("linear") * 3 * 2 * hq * dh * dh
+    if "tokens" in call:
+        n = call["real_tokens"]
+        if "linear_tokens" in call:
+            n = call["linear_tokens"] // kinds.count("linear")
+        pairs = call.get("assignments", 0) * n / call["tokens"]
+        per_key = 2 * hq * (cfg["qk_nope_head_dim"]
+                            + cfg["qk_rope_head_dim"] + cfg["v_head_dim"])
+        return ((2.0 * body + state) * n + 2.0 * head + 2.0 * expert * pairs
+                + kinds.count("full") * per_key
+                * costs.causal_keys(call["start"], n))
+    if not call.get("rows"):
+        return None
+    rows = call["rows"]
+    pairs = call.get("assignments", 0) * rows / call["slots"]
+    per_key = 2 * hq * (2 * cfg["kv_lora_rank"] + cfg["qk_rope_head_dim"])
+    keys = costs.step_keys(call, ("latent_tokens_read",),
+                           kinds.count("full"))
+    return ((2.0 * (body + head) + state) * rows + 2.0 * expert * pairs
+            + per_key * keys)
+
+
 SPAN_COSTS = {"expert_matmuls": expert_matmuls,
               "latent_attention_reads": latent_attention_reads,
-              "linear_state_steps": linear_state_steps}
+              "linear_state_steps": linear_state_steps,
+              "model_flops": model_flops}
 
 # rehearse.py's sizes: the shape of the thing — two dense and six routed
 # layers in the order K K K K K M K K, 4 linear heads of 8 with four-tap

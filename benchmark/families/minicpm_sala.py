@@ -51,12 +51,11 @@ def state_bytes_per_page(cfg: dict) -> int:
         cfg)
 
 
-def block_copy_bytes(cfg: dict) -> int:
-    """bf16 bytes one copy of a selection block brings: its tokens' K and V
-    rows of EVERY KV head, as stored (two bfloat16 heads share a 32-bit
-    word, so one head's block is not a region of its own)."""
-    return (sparse_sizes(cfg)["block_size"] * 2
-            * cfg["num_key_value_heads"] * cfg["head_dim"] * 2)
+def block_kv_bytes(cfg: dict, kv_heads: int) -> int:
+    """bf16 bytes of one selection block's K and V rows of ``kv_heads`` KV
+    heads in one sparse layer: 32,768 a head."""
+    return (sparse_sizes(cfg)["block_size"] * 2 * kv_heads
+            * cfg["head_dim"] * 2)
 
 
 def matmul_params(cfg: dict) -> int:
@@ -102,27 +101,35 @@ def block_select_scores(cfg: dict, span: dict):
 
 
 def paged_block_reads(cfg: dict, span: dict):
-    """(FLOPs, bytes) of the paged kernel's calls of one compiled call.  A
-    decode step, and a chunk past ``dense_len`` (no query of it took the
-    dense path): every block a (query, KV head, sparse layer) reads
-    (``kv_blocks_read``) is COPIED once as stored — ``block_copy_bytes``,
-    the other KV head's rows with it — and its keys of the query's own KV
-    head meet the head's query heads in a score and a value sum.  A chunk
-    at or under ``dense_len`` streams the row's pages through the same
-    kernel, many queries a copy: FLOPs alone, every visible block's keys
-    against every query head.  A first chunk goes through the flash
-    kernel: nothing of this.  None where the span carries no count."""
+    """(FLOPs, bytes) of the sparse layers' attention over the cache in one
+    compiled call: the LEAST work any implementation does, not the copies
+    one makes.  FLOPs: the keys of every block a (query, KV head, sparse
+    layer) reads (``kv_blocks_read``: the chosen blocks are the model's)
+    meet the head's query heads in a score and a value sum.  Bytes, what
+    must move at least once: in a decode step the K and V rows of the
+    query's OWN KV head, 32,768 B a block read; in a chunk past
+    ``dense_len`` (no query of it took the dense path) the distinct blocks
+    its queries can see, ``ceil((start + tokens) / block)``, every KV head's
+    K and V a sparse layer, ONCE — and never more than the decode form's
+    count for the same ``kv_blocks_read``.  A chunk at or under
+    ``dense_len`` streams the row's pages, many queries a block: FLOPs
+    alone.  A first chunk goes through the flash kernel: nothing of this.
+    None where the span carries no count."""
     if "kv_blocks_read" not in span:
         return None
     block = sparse_sizes(cfg)["block_size"]
-    group = cfg["num_attention_heads"] // cfg["num_key_value_heads"]
-    per_block = 2 * 2.0 * block * group * cfg["head_dim"]
-    if span.get("tokens", 1) > 1 and span.get("rows_dense_path"):
-        if span.get("start", 0) == 0:
-            return None
-        return per_block * span["kv_blocks_read"], 0.0
-    return (per_block * span["kv_blocks_read"],
-            float(span["kv_blocks_read"] * block_copy_bytes(cfg)))
+    kv_heads = cfg["num_key_value_heads"]
+    group = cfg["num_attention_heads"] // kv_heads
+    flops = 2 * 2.0 * block * group * cfg["head_dim"] * span["kv_blocks_read"]
+    by_query = float(span["kv_blocks_read"] * block_kv_bytes(cfg, 1))
+    if span.get("tokens", 1) <= 1:
+        return flops, by_query
+    if span.get("rows_dense_path"):
+        return None if span.get("start", 0) == 0 else (flops, 0.0)
+    visible = -(-(span.get("start", 0) + span["tokens"]) // block)
+    once = float(visible * mixer_types(cfg).count("minicpm4")
+                 * block_kv_bytes(cfg, kv_heads))
+    return flops, min(once, by_query)
 
 
 def linear_state_steps(cfg: dict, span: dict):
@@ -139,9 +146,54 @@ def linear_state_steps(cfg: dict, span: dict):
             * rows, 2.0 * matrix_bytes_per_page(cfg) * rows)
 
 
+def model_flops(cfg: dict, call: dict):
+    """FLOPs the configuration's mathematics needs for one compiled call of
+    the serving engine (``readers/span_mfu.py`` says what ``call`` holds):
+    2 a matmul parameter of the layers a REAL token, the head at the one
+    position a chunk samples and at one a decoding row; a lightning layer's
+    two products of ``head_dim x head_dim`` a head a real token
+    (``linear_tokens``: the program's count over those layers); the choice:
+    every pooled key a (query, sparse layer) scores meets every query head
+    (``pooled_keys_scored``); the sparse layers' attention as 4 x the
+    group's query heads x head width a key a (query, KV head, layer) must
+    see — 64 a block of ``kv_blocks_read`` (all of a row's at or under
+    ``dense_len``, the forced and the chosen past it) less the keys of the
+    query's own block that lie after it: exactly in a chunk, whose
+    positions are known, 63 a (row, KV head, layer) in a decode step, the
+    most they can be.  It counts what the MODEL reads, whichever kernel
+    reads it and however often.  None where the span carries no counts or
+    nobody counted a step's rows."""
+    if "kv_blocks_read" not in call:
+        return None
+    kinds, block = mixer_types(cfg), sparse_sizes(cfg)["block_size"]
+    head = cfg["hidden_size"] * cfg["vocab_size"]
+    body = matmul_params(cfg) - 2 * head        # the embedding is a look-up
+    pairs = kinds.count("minicpm4") * cfg["num_key_value_heads"]
+    state = (2 * 2 * cfg["lightning_nh"] * cfg["lightning_head_dim"] ** 2
+             * call["linear_tokens"])
+    choice = (2.0 * call["pooled_keys_scored"] * cfg["num_attention_heads"]
+              * cfg["head_dim"])
+    if "tokens" in call:
+        n = call["linear_tokens"] // kinds.count("lightning-attn")
+        after = sum(block - 1 - p % block
+                    for p in range(call["start"], call["start"] + n))
+        sampled = 1
+    else:
+        n = call.get("rows")
+        if not n:
+            return None
+        after, sampled = (block - 1) * n, n
+    keys = max(call["kv_blocks_read"] * block - pairs * after, 0)
+    per_key = (2 * 2 * cfg["num_attention_heads"]
+               // cfg["num_key_value_heads"] * cfg["head_dim"])
+    return (2.0 * body * n + 2.0 * head * sampled + state + choice
+            + per_key * keys)
+
+
 SPAN_COSTS = {"block_select_scores": block_select_scores,
               "paged_block_reads": paged_block_reads,
-              "linear_state_steps": linear_state_steps}
+              "linear_state_steps": linear_state_steps,
+              "model_flops": model_flops}
 
 # rehearse.py's sizes: the shape of the thing — S L L S, 8 query heads over
 # 2 KV heads of 16, blocks of 8 tokens chosen through pooled keys over 4 at

@@ -11,6 +11,8 @@ family is served, not trained, so ``train_flops_per_sample`` is what
 
 from __future__ import annotations
 
+from benchmark.lib import costs
+
 
 def _layers(cfg: dict) -> list:
     """[is window layer] of the layers the configuration runs."""
@@ -79,8 +81,37 @@ def paged_attention_reads(cfg: dict, span: dict):
             2 * 2.0 * tokens * hkv * dh)
 
 
+def model_flops(cfg: dict, call: dict):
+    """FLOPs the configuration's mathematics needs for one compiled call of
+    the serving engine (``readers/span_mfu.py`` says what ``call`` holds):
+    2 a matmul parameter a REAL token activates (the experts it is sent to,
+    not those held), the head at the one position a chunk samples and at
+    one a decoding row, attention as 4 x query heads x head width a layer a
+    key a query must see — the causal rule in a chunk, in a window layer at
+    most the window; in a decode step what the program counted
+    (``kv_tokens_read_*``: a row's history, or the window's reach) less the
+    one position it counts for each idle row.  None for a step whose rows
+    nobody counted."""
+    head = cfg["hidden_size"] * cfg["vocab_size"]
+    body = active_matmul_params(cfg) - head
+    per_key = 2 * 2 * cfg["num_attention_heads"] * cfg["head_dim"]
+    layers = _layers(cfg)
+    if "tokens" in call:
+        n, start = call["real_tokens"], call["start"]
+        keys = ((len(layers) - sum(layers)) * costs.causal_keys(start, n)
+                + sum(layers) * costs.causal_keys(
+                    start, n, cfg["sliding_window_size"]))
+        return 2.0 * body * n + 2.0 * head + per_key * keys
+    if not call.get("rows"):
+        return None
+    keys = costs.step_keys(call, ("kv_tokens_read_global",
+                                  "kv_tokens_read_window"), len(layers))
+    return 2.0 * (body + head) * call["rows"] + per_key * keys
+
+
 SPAN_COSTS = {"expert_matmuls": expert_matmuls,
-              "paged_attention_reads": paged_attention_reads}
+              "paged_attention_reads": paged_attention_reads,
+              "model_flops": model_flops}
 
 # rehearse.py's sizes: one period of the pattern, query/KV heads 4/2, a
 # window shorter than the prompts, 8 experts of which a token takes 3

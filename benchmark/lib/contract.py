@@ -38,10 +38,13 @@ def check_line(line, benchmark: dict, cell: str, traced: bool) -> list:
                  if w["name"] == cell)
     if not isinstance(line["correct"], bool):
         faults.append("'correct' is not true or false")
-    for key in ("attempted", "failed"):
+    for key, least in (("attempted", 1), ("failed", 0)):
         v = line[key]
-        if not isinstance(v, int) or isinstance(v, bool) or v < 0:
-            faults.append(f"{key!r} is not a count: {v!r}")
+        if not isinstance(v, int) or isinstance(v, bool) or v < least:
+            # a window in which nothing fell due measured nothing: the
+            # driver's check refuses attempted 0 too
+            faults.append(f"{key!r} is not a count of at least {least}: "
+                          f"{v!r}")
     want = declared_metrics(benchmark, cell, traced)
     got = line["metrics"]
     if not isinstance(got, dict):

@@ -41,3 +41,26 @@ def paged_decode(context_tokens: int, heads: int, head_dim: int,
     flops = 2 * 2.0 * context_tokens * heads * head_dim
     nbytes = 2.0 * context_tokens * heads * head_dim * itemsize
     return flops, nbytes
+
+
+def step_keys(call: dict, counters: tuple, layers: int) -> int:
+    """Cached positions the decoding rows' queries of one decode step must
+    see, summed over ``layers`` layers: what the program counted on the
+    span (``counters``, summed over ALL the step's rows and those layers)
+    less the one position it counts a layer for each idle or prefilling
+    row (``slots - rows``).  No count on the span: 0, attention reads
+    low."""
+    if counters[0] not in call:
+        return 0
+    idle = call["slots"] - call["rows"]
+    return max(sum(call[c] for c in counters) - layers * idle, 0)
+
+
+def causal_keys(start: int, tokens: int, window: int = None) -> int:
+    """Keys the ``tokens`` queries of a chunk at positions ``start ...``
+    MUST see between them under the causal rule: the query at position p
+    sees p + 1 keys, itself among them, and in a window layer at most
+    ``window``."""
+    if window is None:
+        return tokens * start + tokens * (tokens + 1) // 2
+    return sum(min(p + 1, window) for p in range(start, start + tokens))

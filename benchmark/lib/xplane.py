@@ -31,9 +31,12 @@ from typing import Dict, List, Optional, Tuple
 
 DEVICE_PLANE_RE = re.compile(r"^/device:TPU:(\d+)$")
 OP_LINE = "XLA Ops"
+# the TPU's op line spells the ZeRO step's scatter ``reduce_scatter.N``
+# and the others with hyphens (recorded on the v5e, PR 35): both spellings
 COLLECTIVE_RE = re.compile(
-    r"^(all-gather|all-reduce|reduce-scatter|all-to-all|"
-    r"collective-permute|collective-broadcast|ragged-all-to-all)")
+    r"^(all[-_]gather|all[-_]reduce|reduce[-_]scatter|all[-_]to[-_]all|"
+    r"collective[-_]permute|collective[-_]broadcast|"
+    r"ragged[-_]all[-_]to[-_]all)")
 
 
 def first_device(plane_names) -> str:

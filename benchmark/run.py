@@ -20,6 +20,7 @@ _T_PROCESS = time.monotonic()       # before the heavy imports: set-up counts
 import argparse  # noqa: E402
 import importlib  # noqa: E402
 import json  # noqa: E402
+import math  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
@@ -117,8 +118,19 @@ def main(argv=None) -> int:
             benchmark, cell.name,
             dict(result["end_to_end"], setup_s=result["setup_s"])),
             device=device)
+    # why a run is not correct, and each number compared beside its limit,
+    # last in the line: what the driver's record keeps of a run at fault (a
+    # reading that is not finite would not be JSON: null)
+    line["reasons"] = [str(r)[:600] for r in result["reasons"]]
+    line["compared"] = {
+        name: [v if isinstance(v, (int, float)) and math.isfinite(v)
+               else None for v in pair]
+        for name, pair in result["compared"].items()}
     if result["reasons"]:
         print(f"benchmark: not correct: {result['reasons']}",
+              file=sys.stderr)
+    for name, (value, limit) in result["compared"].items():
+        print(f"benchmark: compared {name} {value!r} limit {limit!r}",
               file=sys.stderr)
     faults = contract.check_line(line, benchmark, cell.name,
                                  bool(args.trace))

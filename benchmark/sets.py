@@ -72,10 +72,14 @@ def main(argv=None) -> int:
         src = os.path.join(ROOT, "benchmark", "out", cell)
         dst = os.path.join(OUT, tag)
         os.makedirs(dst, exist_ok=True)
+        # the program's spans with them: a traced run's counts are what
+        # the span readers' numbers are reckoned from by hand
         for name in ("notes.jsonl", "trace_described.txt",
-                     f"last_line.trace{traced}.json", "refused_line.json"):
-            if os.path.exists(os.path.join(src, name)):
-                shutil.copy(os.path.join(src, name), dst)
+                     f"last_line.trace{traced}.json", "refused_line.json",
+                     "idle_by_lap.json", "spans/trace_rank0.jsonl"):
+            path = os.path.join(src, name)
+            if os.path.exists(path) and os.path.getsize(path) < 16e6:
+                shutil.copy(path, dst)
         if traced == "1" and args.keep_trace and rc == 0:
             # reading the trace needs jax's reader, not a device: a child
             # held to the CPU, after the run's process has gone

@@ -352,9 +352,16 @@ def test_nothing_traced_reads_nothing(metric):
     entry = next(m for m in BENCH["per_layer"] if m["name"] == metric)
     assert entry["layer"] == "serving engine (serve/engine.py)"
     assert entry["moves"] == "serve_tok_s" and entry["better"] == "lower"
-    # the serving cells whose per-layer lists no older check pins (a
-    # `benchmark` PR appends longctx, manyrows and longprompt-closed)
-    assert len(entry["workloads"]) == (3 if "chunk_device" in metric else 4)
+    # PR 36's four serving cells, and since PR 46 longctx, manyrows,
+    # longprompt-closed and longgen; the chunk's device time in the four
+    # dense cells (bytedocs and longdoc declare laps under their own names)
+    cells = ["gpt13b-serve-loaded", "gpt13b-serve-longprompt",
+             "gpt13b-serve-batch", "smallthinker-serve-mixedctx",
+             "joyai-serve-longctx", "lfm2-serve-manyrows",
+             "gpt13b-serve-longprompt-closed", "ling-serve-longgen"]
+    if "chunk_device" in metric:
+        cells = [c for c in cells if c.startswith("gpt13b")]
+    assert entry["workloads"] == cells
     for driver in ({"window_wall": (0.0, 1.0)},
                    {"window_wall": (0.0, 1.0), "records": [],
                     "profile_dir": None}):

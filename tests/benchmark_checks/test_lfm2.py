@@ -199,9 +199,13 @@ def test_the_closed_long_prompt_cell_is_files_only():
                     "prepare_per_s": 64.0, "prepare_block_per_s": 64.0,
                     "base_seed": 20261102, "ramp_s": 20, "drain_s": 10}
     assert closed.chips == 1
-    assert closed.per_layer == ["decode_step_ms.longclosed",
-                                "prefill_chunk_ms.longclosed",
-                                "device_idle_pct.longclosed"]
+    # its own three, then what PR 46 declared here too: the turn's six laps,
+    # the chunk's device time and the whole window's share of the peak
+    assert closed.per_layer == [
+        "decode_step_ms.longclosed", "prefill_chunk_ms.longclosed",
+        "device_idle_pct.longclosed", "host_admit_ms", "host_chunk_ms",
+        "host_launch_ms", "host_emit_ms", "idle_host_pct", "idle_wait_pct",
+        "prefill_chunk_device_ms", "serve_mfu"]
 
 
 @pytest.mark.parametrize("name,trace", [(CELL, "0"), (CELL, "1"),
@@ -499,15 +503,22 @@ def test_kernel_time_is_per_decode_step(cell, metric, kernel):
     assert read_metric(_spec(metric), run) == pytest.approx(15.0)
 
 
-@pytest.mark.parametrize("name,count", [(CELL, 9), (CLOSED, 3)])
-def test_serve_tok_s_is_judged_in_the_new_cells(name, count):
+@pytest.mark.parametrize("name,suffix,count,shared",
+                         [(CELL, ".manyrows", 9, 7),
+                          (CLOSED, ".longclosed", 3, 8)])
+def test_serve_tok_s_is_judged_in_the_new_cells(name, suffix, count, shared):
     tok = next(m for m in BENCH["end_to_end"] if m["name"] == "serve_tok_s")
     assert name in tok["workloads"]
     mine = [m for m in BENCH["per_layer"] if name in m["workloads"]]
     assert [m["name"] for m in mine] == load_cell(BENCH, name).per_layer
-    assert len(mine) == count
-    for m in mine:
-        assert m["moves"] == "serve_tok_s" and m["workloads"] == [name]
+    # a metric named for the cell is its alone; those shared by several
+    # cells (the turn's six laps and serve_mfu since PR 46; the chunk's
+    # device time in the dense cell) list it among them
+    own = [m for m in mine if m["workloads"] == [name]]
+    assert len(own) == count and len(mine) == count + shared
+    assert all(m["name"].endswith(suffix) for m in own)
+    assert not any(m["name"].endswith(suffix) for m in mine if m not in own)
+    assert all(m["moves"] == "serve_tok_s" for m in mine)
     entry = next(w for w in BENCH["workloads"] if w["name"] == name)
     assert entry["chips"] == 1 and len(entry["why"]) <= 200
 

@@ -279,8 +279,11 @@ def test_serve_tok_s_is_judged_in_the_new_cell(cell):
     # membership, not the last place: every later cell is appended there
     assert CELL in tok["workloads"]
     mine = [m for m in BENCH["per_layer"] if CELL in m.get("workloads", [])]
-    assert [m["name"] for m in mine] == PER_LAYER
-    assert set(PER_LAYER) <= set(cell.per_layer)
+    # its own thirteen, then the one it shares with every serving cell
+    assert [m["name"] for m in mine] == PER_LAYER + ["serve_mfu"]
+    assert [m["name"] for m in mine] == cell.per_layer
+    assert cell.family.SPAN_COSTS["model_flops"] is cell.family.model_flops
+    mine = mine[:-1]
     for m in mine:
         assert m["moves"] == "serve_tok_s" and m["workloads"] == [CELL]
         spec = _spec(m["name"])
@@ -414,16 +417,22 @@ def _run(cell, records, kernels, decode_steps=2):
                 "decode_steps": decode_steps, "histograms": {}})
 
 
-def test_the_costs_count_what_is_copied_as_stored(cell):
+def test_the_costs_count_the_least_work_as_stored(cell):
+    """Since PR 46 the paged kernel's bytes are what must move at least
+    once, not the copies one implementation makes (65,536 B a block COPIED
+    before): ``test_serve_mfu.py`` holds the cases."""
     cfg, costs = cell.config, cell.family.SPAN_COSTS
-    assert cell.family.block_copy_bytes(cfg) == 65_536
+    assert cell.family.block_kv_bytes(cfg, 1) == 32_768
+    assert cell.family.block_kv_bytes(cfg, cfg["num_key_value_heads"]) \
+        == 65_536
     flops, nbytes = costs["paged_block_reads"](cfg, {"kv_blocks_read": 970})
-    assert nbytes == 970 * 65_536
+    assert nbytes == 970 * 32_768
     assert flops == 970 * 2 * 2 * 64 * 16 * 128
-    # a chunk past dense_len: every token a row of the same kernel
+    # a chunk past dense_len: its 160 visible blocks once, both KV heads,
+    # two layers — under the decode form's count for the same blocks
     assert costs["paged_block_reads"](
         cfg, {"kv_blocks_read": 970, "tokens": 2048, "start": 8192,
-              "rows_dense_path": 0}) == (flops, nbytes)
+              "rows_dense_path": 0}) == (flops, 160 * 2 * 65_536.0)
     # a chunk at or under it: FLOPs alone; the first: the flash kernel's
     assert costs["paged_block_reads"](
         cfg, {"kv_blocks_read": 970, "tokens": 2048, "start": 2048,
