@@ -100,8 +100,11 @@ that reads a SUBSET of the row's cache, chosen query by query: past
 the others by the query heads' scores against POOLED keys, a third cache
 leaf (``pooled_key``, one row a ``stride`` tokens); at or under it
 everything.  The decode step copies the chosen blocks alone (a table ``[B,
-Hkv, W]`` of blocks that are parts of a page); a chunk gives every token
-its own.  ``lightning`` (:class:`LightningAttention`, ``lightning``):
+Hkv, W]`` of blocks that are parts of a page).  A chunk gives every token
+its own choice as MEMBERSHIP — a small integer a (query, KV head, unit of
+blocks) — and a tile of queries streams the blocks its queries read once,
+each query masking what it did not choose (``paged_tile_attention``).
+``lightning`` (:class:`LightningAttention`, ``lightning``):
 linear attention with a constant decay a head and no write gate — the
 no-erase forms of ``ops/linear_state.py`` — whose matrix state is
 ``linear_delta``'s leaf under the same contract.  ``mup`` scales the
@@ -163,8 +166,9 @@ layers mixed and the rows whose entries went to a page of their own
 (``conv_tokens`` or ``linear_tokens``, ``state_rows_advanced``), beside
 either attention kind's counts; a model with ``sparse_block`` layers
 counts, in this order after the three expert counts, ``kv_blocks_visible``,
-``kv_blocks_read``, ``pooled_keys_scored``, ``rows_dense_path``,
-``linear_tokens``, ``state_rows_advanced`` (``SPARSE_STATS``).
+``kv_blocks_read``, ``pooled_keys_scored``, ``rows_dense_path``
+(``SPARSE_STATS``), ``linear_tokens``, ``state_rows_advanced``, and last
+``kv_blocks_streamed`` (``STREAMED_STATS``).
 
 Every apply also yields counts (``stats_names``; summed over layers) in
 the ``"stats"`` collection when the caller makes it mutable: the serving
@@ -185,7 +189,9 @@ from dtf_tpu.ops import block_select, linear_state, window_summary
 from dtf_tpu.ops.flash_attention import flash_attention
 from dtf_tpu.ops.paged_attention import (cached_attention, expand_kv_heads,
                                          paged_attention_auto,
-                                         paged_block_attention, write_pages)
+                                         paged_block_attention,
+                                         paged_tile_attention, tile_keys,
+                                         write_pages)
 
 # what ``"stats"/"counts"`` holds, in order: with whole heads, and with the
 # latent cache (its one row a token, summed over rows and layers)
@@ -211,6 +217,13 @@ LINEAR_STATS = ("linear_tokens", "state_rows_advanced")
 # choose, and the queries (once, not a layer) that took the dense path
 SPARSE_STATS = ("kv_blocks_visible", "kv_blocks_read", "pooled_keys_scored",
                 "rows_dense_path")
+# ... and, last of such a model's counts, what the IMPLEMENTATION copied
+# where the counts above say what the model reads: the blocks a chunk's
+# tiles streamed, summed over (tile of queries, KV head, sparse layer) — a
+# tile copies a unit of blocks once if any of its queries reads in it, so
+# against the blocks its tiles could see this says how often the skip
+# engages; 0 on a decode step and on a chunk at or under ``dense_len``
+STREAMED_STATS = ("kv_blocks_streamed",)
 MIXERS = ("attention", "short_conv", "linear_delta", "sparse_block",
           "lightning")
 # the kinds whose cache leaf is a running state entry a page
@@ -961,11 +974,15 @@ class SparseBlockAttention(nn.Module):
     keys, not tokens).  One token: the row's pooled pages are scored
     (kernel ``block_select``), the table of blocks ``[B, Hkv, W]`` built,
     and ``paged_flash_decode`` copies those blocks alone, a (row, KV head)
-    a row of it.  A chunk gives every TOKEN its own choice, a (token, KV
-    head) a row of the same call — a gather a token; a chunk that ends at
-    or under ``dense_len`` goes through the kernels every dense model uses
-    (the first through the flash kernel).  Outside decode mode the choice
-    is a mask on plain attention."""
+    a row of it.  A chunk gives every TOKEN its own choice, kept as packed
+    membership ``[B, Hkv, S, units]`` (``block_select.chunk_members``; no
+    table, no rank): the tile kernel ``paged_flash_decode_tiles`` streams,
+    a tile of queries, the units any of them reads ONCE and each query
+    masks what it did not choose; a chunk that ends at or under
+    ``dense_len`` goes through the kernels every dense model uses (the
+    first through the flash kernel).  Outside decode mode the choice is a
+    mask on plain attention.  Returns ``(out, the blocks the chunk's tiles
+    copied)``."""
     num_heads: int
     num_kv_heads: int
     head_dim: int
@@ -1006,6 +1023,7 @@ class SparseBlockAttention(nn.Module):
             b, s, hkv, dh), g_k, self.rms_eps)
         v = qkv[..., (hq + hkv) * dh:].reshape(b, s, hkv, dh)
         scale = dh ** -0.5
+        o, streamed = jnp.zeros_like(q), jnp.zeros((), jnp.int32)
         if not self.decode:
             # the whole sequence at once (tests, the toy): the choice as
             # a mask [B, S, Hkv, S] on plain attention
@@ -1029,14 +1047,12 @@ class SparseBlockAttention(nn.Module):
                 "cache", "pooled_key", jnp.zeros,
                 (self.kv_pool_pages, page // sizes.stride, hkv, dh),
                 self.dtype)
-            if self.is_initializing():
-                o = jnp.zeros_like(q)
-            else:
-                o = self._paged(q, k, v, keys, values, pooled, cache_index,
-                                block_table, sizes, scale, flash_prefill,
-                                window_pages)
+            if not self.is_initializing():
+                o, streamed = self._paged(
+                    q, k, v, keys, values, pooled, cache_index, block_table,
+                    sizes, scale, flash_prefill, window_pages)
         gate = jax.nn.sigmoid(mm(h, w_gate))
-        return mm(o.reshape(b, s, hq * dh) * gate, w_out)
+        return mm(o.reshape(b, s, hq * dh) * gate, w_out), streamed
 
     def _paged(self, q, k, v, keys, values, pooled, cache_index,
                block_table, sizes, scale, flash_prefill, window_pages):
@@ -1054,61 +1070,48 @@ class SparseBlockAttention(nn.Module):
             pooled.value, k, keys.value, block_table, cache_index, sizes)
         t = cache_index[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
 
-        def read(q_, t_, r):        # [B, T, Hq, Dh], [B, T], [B, T, Hkv, J]
-            n = q_.shape[1]
-            blocks, count = block_select.choose(r, t_, sizes)
-            ids = block_select.physical(blocks, block_table, page,
-                                        sizes.block)
-            o = paged_block_attention(
-                q_.reshape(b * n, hq, dh), keys.value, values.value,
-                ids.reshape((b * n,) + ids.shape[2:]), count.reshape(-1),
-                (t_ % sizes.block).reshape(-1), block=sizes.block,
-                use_pallas=use_pallas)
-            return o.reshape(b, n, hq, dh)
+        none = jnp.zeros((), jnp.int32)
         if s == 1:
             if use_pallas:
                 r = block_select.decode_scores(
                     q[:, 0], pooled.value, block_table, cache_index,
                     sizes=sizes, scale=scale,
-                    interpret=use_pallas == "interpret")[:, None]
+                    interpret=use_pallas == "interpret")
             else:
                 r = block_select.scores(q, pooled.value, block_table, t,
-                                        sizes, scale)
-            return read(q, t, r)
+                                        sizes, scale)[:, 0]
+            blocks, count = block_select.choose(r, t[:, 0], sizes)
+            ids = block_select.physical(blocks, block_table, page,
+                                        sizes.block)
+            o = paged_block_attention(
+                q[:, 0], keys.value, values.value, ids, count,
+                t[:, 0] % sizes.block, block=sizes.block,
+                use_pallas=use_pallas)
+            return o[:, None], none
         if flash_prefill:
             if s > sizes.dense_len:
                 raise ValueError(f"a first chunk of {s} tokens passes "
                                  f"dense_len {sizes.dense_len}")
             return flash_attention(q, *expand_kv_heads(k, v, hq),
-                                   causal=True, use_pallas=self.use_pallas)
+                                   causal=True,
+                                   use_pallas=self.use_pallas), none
 
         def dense():
             return paged_attention_auto(
                 q, keys.value, values.value, block_table, cache_index,
-                window_pages=window_pages, use_pallas=self.use_pallas)
+                window_pages=window_pages, use_pallas=self.use_pallas), none
 
         def sparse():
-            # every token its own choice, _CHUNK_TILE tokens a call: a
-            # (token, KV head) is a row of the paged kernel, whose tables
-            # are prefetched into SMEM (1 MiB on the v5e)
-            tile = math.gcd(s, _CHUNK_TILE)
-
-            def tiles(x):
-                return jnp.moveaxis(
-                    x.reshape((b, s // tile, tile) + x.shape[2:]), 1, 0)
-
-            def some(xs):
-                q_, t_ = xs
-                return read(q_, t_, block_select.scores(
-                    q_, pooled.value, block_table, t_, sizes, scale))
-            o = jax.lax.map(some, (tiles(q), tiles(t)))
-            return jnp.moveaxis(o, 0, 1).reshape(b, s, hq, dh)
+            # every token its own choice, as membership: a tile of queries
+            # streams the blocks its queries read once
+            per = tile_keys(page, sizes.block) // sizes.block
+            bits = block_select.chunk_members(
+                q, pooled.value, block_table, t, sizes, scale, per)
+            return paged_tile_attention(
+                q, keys.value, values.value, block_table, cache_index, bits,
+                block=sizes.block, use_pallas=use_pallas)
         return jax.lax.cond(jnp.all(cache_index + s <= sizes.dense_len),
                             dense, sparse)
-
-
-# tokens of a chunk past ``dense_len`` that choose and read in one call
-_CHUNK_TILE = 256
 
 
 def lightning_log_decay(heads: int, layer: int, depth: int):
@@ -1415,7 +1418,8 @@ class RoutedBlock(nn.Module):
                  flash_prefill: bool = False,
                  window_pages: Optional[int] = None, last_pos=None):
         """-> (x, rows an expert held here [E] or None for a dense layer,
-        state rows advanced or None for an attention layer)."""
+        state rows advanced or None for an attention layer, the blocks a
+        ``sparse_block`` layer's chunk tiles copied or None)."""
         b, s, d = x.shape
         e, f = self.num_experts, self.expert_width
         held = (e if self.experts_held is None else self.experts_held[1])
@@ -1446,7 +1450,7 @@ class RoutedBlock(nn.Module):
         h = rms_norm(x, g1, self.rms_eps, offset)
         if routed and self.router_input == "pre_attention":
             idx, weights = choose(h)
-        advanced = None
+        advanced = streamed = None
         if self.mixer == "short_conv":
             attn, advanced = ShortConv(
                 self.conv_taps, self.dtype, pdt, decode=self.decode,
@@ -1461,7 +1465,7 @@ class RoutedBlock(nn.Module):
                 kv_pool_pages=self.kv_pool_pages, name="linear")(
                     h, cache_index, block_table, last_pos)
         elif self.mixer == "sparse_block":
-            attn = SparseBlockAttention(
+            attn, streamed = SparseBlockAttention(
                 self.num_heads, self.num_kv_heads, self.head_dim,
                 self.sparse, self.rms_eps, self.dtype, pdt,
                 use_pallas=self.use_pallas, decode=self.decode,
@@ -1510,7 +1514,7 @@ class RoutedBlock(nn.Module):
                 self.activation)
             if self.residual_scale != 1.0:
                 y = y * self.residual_scale
-            return x + y.reshape(b, s, d), None, advanced
+            return x + y.reshape(b, s, d), None, advanced, streamed
         if self.router_input != "pre_attention":
             idx, weights = choose(h2)
         y, sizes = routed_experts(h2.astype(self.dtype), idx, weights,
@@ -1530,7 +1534,7 @@ class RoutedBlock(nn.Module):
                 self.activation)
         if self.residual_scale != 1.0:
             y = y * self.residual_scale
-        return x + y.reshape(b, s, d), sizes, advanced
+        return x + y.reshape(b, s, d), sizes, advanced, streamed
 
 
 class RoutedDecoderLM(nn.Module):
@@ -1649,7 +1653,8 @@ class RoutedDecoderLM(nn.Module):
         if self.summary_window is not None:
             return SUMMARY_STATS
         if "sparse_block" in self.layer_mixers():
-            return STATS[:3] + SPARSE_STATS + LINEAR_STATS
+            return (STATS[:3] + SPARSE_STATS + LINEAR_STATS
+                    + STREAMED_STATS)
         if "linear_delta" in self.layer_mixers():
             return names + LINEAR_STATS
         return STATE_STATS if self.carries_state else names
@@ -1761,11 +1766,12 @@ class RoutedDecoderLM(nn.Module):
                       self.qk_nope_head_dim, self.qk_rope_head_dim,
                       self.v_head_dim)
         n_routed = len(kinds) - self.num_dense_layers
-        touched = load_max = advanced = computed = jnp.zeros((), jnp.int32)
+        touched = load_max = advanced = computed = streamed = jnp.zeros(
+            (), jnp.int32)
         linear = (self.linear_heads, self.linear_head_dim,
                   self.linear_conv_taps, self.linear_decay_floor)
         for i, (window, theta) in enumerate(kinds):
-            x, sizes, rows = RoutedBlock(
+            x, sizes, rows, copied = RoutedBlock(
                 self.num_heads, self.num_kv_heads, self.head_dim,
                 self.num_experts, self.experts_per_token, self.expert_width,
                 window, theta, self.rms_eps, self.dtype, pdt,
@@ -1802,6 +1808,8 @@ class RoutedDecoderLM(nn.Module):
                     computed += jnp.sum(sizes, dtype=jnp.int32)
             if rows is not None:
                 advanced += rows
+            if copied is not None:
+                streamed += copied
         # what the attention of this call has to read of the cache: a
         # row's whole history in a full layer (K and V, or the one latent
         # row a token), the window's reach in a window layer
@@ -1831,7 +1839,7 @@ class RoutedDecoderLM(nn.Module):
                 mixers.count("sparse_block") * over(jnp.where(
                     dense, 0, block_select.pooled_exist(positions, sizes))),
                 over(dense.astype(jnp.int32)),
-                mixers.count("lightning") * over(1), advanced])
+                mixers.count("lightning") * over(1), advanced, streamed])
         elif latent is not None:
             counts = [assignments, touched, load_max,
                       mixers.count("attention") * jnp.sum(live)]

@@ -30,9 +30,16 @@ whose row ``j % (page / stride)`` of the page that holds position ``stride
                      once, every score kept in VMEM, the softmax taken over
                      all of them at the end — exact, no second read;
 ``choose``           ``r`` -> the table of blocks ``[.., Hkv, W]`` in
-                     ascending order and how many of them count;
+                     ascending order and how many of them count (a decode
+                     step's form: a row copies its own blocks);
 ``physical``         logical blocks -> ids ``page * (page / block) + block
-                     % (page / block)`` into a pool seen as blocks.
+                     % (page / block)`` into a pool seen as blocks;
+``members``          ``r`` -> the same choice as membership ``[.., Hkv,
+                     blocks]``, no rank and no table (a chunk's form);
+``pack_members``     membership -> one small integer a unit of blocks;
+``chunk_members``    a chunk's q -> packed membership ``[B, Hkv, S,
+                     units]``, a tile of queries' ``r`` at a time: what
+                     ``paged_attention.paged_tile_attention`` masks by.
 """
 
 from __future__ import annotations
@@ -314,15 +321,14 @@ def decode_scores(q, pooled, block_table, t, *, sizes: Sizes, scale: float,
     return jnp.swapaxes(r, 1, 2).reshape(b, hkv, -1)[..., :m * rows]
 
 
-def top_ids(x, k: int):
-    """The indices of the ``k`` largest of ``x`` [..., N] (non-negative, or
-    -1 where an entry cannot be chosen; at least ``k`` can), ties to the
-    lower index, IN ASCENDING ORDER — what ``sort(top_k(x, k)[1])`` gives,
-    without the sort: the TPU's ``top_k`` sorts all N values a row, 60 ms
-    of a 2,048-token chunk's 267 at N = 2,080 (my chip run, PR 45).  The
-    k-th largest value is found bit by bit (a non-negative float's bits
-    order as an integer: 31 counts), ties at it are taken from the left,
-    and the members' indices are gathered by their rank."""
+def top_members(x, k: int):
+    """Which ``k`` of ``x`` [..., N] are its largest (non-negative, or -1
+    where an entry cannot be chosen; at least ``k`` can), ties to the lower
+    index: bool [..., N] with ``k`` True a row — ``top_k`` without the sort
+    (the TPU's ``top_k`` sorts all N values a row, 60 ms of a 2,048-token
+    chunk's 267 at N = 2,080: my chip run, PR 45).  The k-th largest value
+    is found bit by bit (a non-negative float's bits order as an integer:
+    31 counts) and ties at it are taken from the left."""
     bits = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.int32)
     kth = jnp.zeros(x.shape[:-1] + (1,), jnp.int32)
     for bit in range(30, -1, -1):
@@ -331,12 +337,40 @@ def top_ids(x, k: int):
         kth = jnp.where(enough, trial, kth)
     above, at = bits > kth, bits == kth
     spare = k - jnp.sum(above, -1, keepdims=True)
-    member = above | (at & (jnp.cumsum(at, -1) <= spare))
+    return above | (at & (jnp.cumsum(at, -1) <= spare))
+
+
+def top_ids(x, k: int):
+    """The indices of :func:`top_members` IN ASCENDING ORDER — what
+    ``sort(top_k(x, k)[1])`` gives: the members' indices gathered by their
+    rank."""
+    member = top_members(x, k)
     rank = jnp.cumsum(member, -1) - 1
     ids = jnp.arange(x.shape[-1], dtype=jnp.int32)
     return jnp.sum(jnp.where(
         member[..., None] & (rank[..., None] == jnp.arange(k)), ids[:, None],
         0), axis=-2, dtype=jnp.int32)
+
+
+def _free_scores(r, t, sizes: Sizes):
+    """``R`` [..., Hkv, blocks] of ``r`` [..., Hkv, J] for queries at ``t``
+    [...], -1.0 at the blocks a query does not choose among (the first
+    ``init`` and everything from its window's first block on), and that
+    first block ``[..., 1, 1]``."""
+    n = sizes.block // sizes.stride
+    if r.shape[-1] < sizes.width * n:       # a short table: no such keys
+        r = jnp.pad(r, [(0, 0)] * (r.ndim - 1)
+                    + [(0, sizes.width * n - r.shape[-1])])
+    blocks = r.shape[-1] // n
+    groups = r[..., :blocks * n].reshape(r.shape[:-1] + (blocks, n))
+    before = jnp.pad(groups[..., :-1, -1],              # r at j = n b - 1
+                     [(0, 0)] * (r.ndim - 1) + [(1, 0)])
+    big_r = jnp.maximum(jnp.max(groups, -1), before)
+    ids = jnp.arange(blocks, dtype=jnp.int32)
+    first_window = (t // sizes.block - sizes.window // sizes.block
+                    + 1)[..., None, None]
+    free = (ids >= sizes.init) & (ids < first_window)
+    return jnp.where(free, big_r, -1.0), first_window
 
 
 def choose(r, t, sizes: Sizes):
@@ -346,21 +380,10 @@ def choose(r, t, sizes: Sizes):
     its own at or under ``dense_len``, else the forced and the chosen —
     and the last of them is the query's own block.  Entries past ``count``
     are 0."""
-    n = sizes.block // sizes.stride
-    if r.shape[-1] < sizes.width * n:       # a short table: no such keys
-        r = jnp.pad(r, [(0, 0)] * (r.ndim - 1)
-                    + [(0, sizes.width * n - r.shape[-1])])
-    blocks = r.shape[-1] // n
     own = t // sizes.block                              # b_t
     wb = sizes.window // sizes.block
-    groups = r[..., :blocks * n].reshape(r.shape[:-1] + (blocks, n))
-    before = jnp.pad(groups[..., :-1, -1],              # r at j = n b - 1
-                     [(0, 0)] * (r.ndim - 1) + [(1, 0)])
-    big_r = jnp.maximum(jnp.max(groups, -1), before)
-    ids = jnp.arange(blocks, dtype=jnp.int32)
-    first_window = (own - wb + 1)[..., None, None]
-    free = (ids >= sizes.init) & (ids < first_window)
-    best = top_ids(jnp.where(free, big_r, -1.0), sizes.top)
+    free, first_window = _free_scores(r, t, sizes)
+    best = top_ids(free, sizes.top)
     lead = r.shape[:-1]
     sparse = jnp.concatenate([
         jnp.broadcast_to(jnp.arange(sizes.init, dtype=jnp.int32),
@@ -376,6 +399,59 @@ def choose(r, t, sizes: Sizes):
     table = jnp.where(dense[..., None, None], every, sparse)
     table = jnp.where(every < count[..., None, None], table, 0)
     return table, count
+
+
+def members(r, t, sizes: Sizes):
+    """:func:`choose` as MEMBERSHIP: bool [..., Hkv, blocks] (``blocks`` the
+    table's, ``J / (block / stride)``, at least ``sizes.width``), True at
+    the blocks the query at ``t`` reads — the same set a (query, KV head)
+    as the first ``count`` entries of :func:`choose`'s table, with no rank
+    gather and no table: what a chunk's tile kernel masks by."""
+    free, first_window = _free_scores(r, t, sizes)
+    ids = jnp.arange(free.shape[-1], dtype=jnp.int32)
+    own = (t // sizes.block)[..., None, None]
+    chosen = (top_members(free, sizes.top) | (ids < sizes.init)
+              | (ids >= first_window))
+    dense = (t + 1 <= sizes.dense_len)[..., None, None]
+    return (chosen | dense) & (ids <= own)
+
+
+def pack_members(member, per: int):
+    """bool [..., blocks] -> int32 [..., ceil(blocks / per)]: bit ``i`` of
+    entry ``u`` is block ``per * u + i`` (``per`` <= 16, so that an entry
+    is a small non-negative integer)."""
+    if not 1 <= per <= 16:
+        raise ValueError(f"{per} blocks do not pack into a small integer")
+    blocks = member.shape[-1]
+    member = jnp.pad(member, [(0, 0)] * (member.ndim - 1)
+                     + [(0, -blocks % per)])
+    units = member.reshape(member.shape[:-1] + (-1, per))
+    return jnp.sum(units.astype(jnp.int32)
+                   << jnp.arange(per, dtype=jnp.int32), -1, dtype=jnp.int32)
+
+
+def chunk_members(q, pooled, block_table, t, sizes: Sizes, scale: float,
+                  per: int, tile: int = 256):
+    """A chunk's choice as packed membership: q [B, S, Hq, D] at positions
+    ``t`` [B, S] -> int32 [B, Hkv, S, units] (:func:`scores`,
+    :func:`members`, :func:`pack_members` at ``per`` blocks a unit), the
+    queries ``tile`` at a time so that the float32 ``r`` of a tile, not of
+    the chunk, is what exists (``[S, Hkv, J]`` is 136e6 B at 2,048 tokens
+    over 133,120 positions)."""
+    b, s, hq, d = q.shape
+
+    def some(xs):                   # [B, T, Hq, D], [B, T]
+        q_, t_ = xs
+        r = scores(q_, pooled, block_table, t_, sizes, scale, tile=tile)
+        return jnp.swapaxes(pack_members(members(r, t_, sizes), per), 1, 2)
+    if s <= tile or s % tile:
+        return some((q, t))
+    tiles = s // tile
+    bits = jax.lax.map(
+        some, (jnp.moveaxis(q.reshape(b, tiles, tile, hq, d), 1, 0),
+               jnp.moveaxis(t.reshape(b, tiles, tile), 1, 0)))
+    # [tiles, B, Hkv, T, U] -> [B, Hkv, S, U]
+    return jnp.moveaxis(bits, 0, 2).reshape(b, bits.shape[2], s, -1)
 
 
 def plain_mask(q, k, sizes: Sizes, scale: float):

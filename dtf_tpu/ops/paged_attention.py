@@ -63,6 +63,12 @@ Two formulations of attention-over-pages coexist:
       the two, how many pages make a block, and how many heads share a
       grid point, follows from the static shapes (``_plan``).
 
+A third kernel with a body of its own (``paged_flash_decode_tiles``,
+behind ``paged_tile_attention``) serves the one caller whose CHUNK reads
+a subset of the row's blocks, the subset a (query, KV head)'s own
+(block-sparse attention): a tile of queries streams the blocks its
+queries read once and each query masks what it did not choose.
+
 ``paged_attention_auto`` dispatches between them: the kernel by default
 on TPU, the gather oracle elsewhere; ``use_pallas="interpret"`` runs
 the kernel through the Pallas interpreter on CPU (how tier-1 pins
@@ -72,6 +78,7 @@ kernel ≡ oracle).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -762,8 +769,10 @@ def paged_flash_decode(q, pool_k, pool_v, block_table, index, *,
 
 def paged_block_attention(q, pool_k, pool_v, blocks, count, last, *,
                           block: int, use_pallas=None):
-    """Attention of ONE query a (row, KV head) over a table of BLOCKS that
-    are parts of a page, the table a (row, KV head)'s own: q [B, Hq, Dh];
+    """A DECODE STEP's read of chosen blocks (a chunk's is
+    :func:`paged_tile_attention`): attention of ONE query a (row, KV head)
+    over a table of BLOCKS that are parts of a page, the table a (row, KV
+    head)'s own: q [B, Hq, Dh];
     pools [P, page, Hkv, Dh]; ``blocks`` [B, Hkv, W] int32 ids into the
     pools seen as ``[P * page / block, block, Hkv, Dh]`` (a page's blocks
     are contiguous, so the reshape is free), of which the first ``count``
@@ -793,6 +802,299 @@ def paged_block_attention(q, pool_k, pool_v, blocks, count, last, *,
                              use_pallas=use_pallas)
     o = o.reshape(b, hkv, hkv, g, d)
     return jnp.stack([o[:, h, h] for h in range(hkv)], 1).reshape(b, hq, d)
+
+
+# ---------------------------------------------------------------------------
+# Pallas tile kernel: a chunk whose queries each read a subset of the blocks
+# ---------------------------------------------------------------------------
+
+# queries a grid point holds (each with the query heads of its KV head) and
+# keys a product scores — also what one DMA copies and what a tile skips by.
+# Kernel alone and inside the chunk body on the v5e, 32 query heads over 2 KV
+# heads of 128, bf16, pages of 2,048: docs/pr47_sparse_chunk_sweep.jsonl
+_TILE_QUERIES = 128
+_TILE_KEYS = 256
+_TILE_UNITS = 4
+_TILE_ROWS = 512
+# VMEM the tile kernel asks for, stated: the f32 carry of 16 heads x 128
+# queries is 1 MiB of output and 2 MiB of lane-padded max and sum, beside the
+# q / out / membership blocks (two each), two K and two V units and a head's
+# score tiles — more than the decode kernel's shared 4 MiB plan, well under
+# the chip's 128 MiB
+_TILE_VMEM_BYTES = 48 * 2 ** 20
+
+
+def tile_keys(page_size: int, block: int) -> int:
+    """Keys a unit of the tile kernel holds at these sizes: ``_TILE_KEYS``
+    where a page is whole units of it, else the page; whole blocks, at most
+    16 (a unit's membership is one small integer a query)."""
+    unit = _TILE_KEYS if page_size % _TILE_KEYS == 0 else page_size
+    unit = min(unit, 16 * block)
+    if unit % block or page_size % unit:
+        raise ValueError(f"a page of {page_size} is not whole units of "
+                         f"{unit} keys of whole blocks of {block}")
+    return unit
+
+
+def _kv_head_rows(flat_ref, head, h, t):
+    """Rows ``[t, d]`` of the ONE head ``head`` (traced) out of a
+    ``[t * h, d]`` block in the pool's stored order (:func:`_head_rows`,
+    which gives a bf16 word's two heads at a static parity)."""
+    if flat_ref.dtype.itemsize == 4:
+        return flat_ref[pl.ds(head, t, stride=h), :]
+    words = flat_ref.bitcast(jnp.uint32)[pl.ds(head // 2, t, stride=h // 2), :]
+    up = (16 * (1 - head % 2)).astype(jnp.uint32)
+    return pltpu.bitcast((words << up) & jnp.uint32(0xFFFF0000),
+                         jnp.float32).astype(flat_ref.dtype)
+
+
+def _paged_tiles_kernel(tbl_ref, idx_ref, list_ref, cnt_ref, q_ref, bits_ref,
+                        k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, oacc_ref, m_ref,
+                        l_ref, *, scale, block, per_page, tile_q, rows):
+    """Grid (B, KV heads, query tiles): a tile of ``Tq`` queries, each with
+    the ``G`` query heads of the KV head, streams ONCE the units of keys
+    that any of its queries reads, and each query masks what it did not
+    choose.
+
+    Scalar-prefetched: ``tbl_ref`` [B, M] the page table, ``idx_ref`` [B]
+    the chunk's first position, ``list_ref`` [B * Hkv * tiles, U] the
+    logical units a (row, KV head, tile) streams, ascending, of which the
+    first ``cnt_ref`` [B * Hkv * tiles] count.  ``q_ref`` / ``o_ref`` [G *
+    Tq, D]: row ``x * Tq + i`` is query ``i`` of the tile in the KV head's
+    query head ``x`` — the heads share the head's K and V rows, so they are
+    rows of ONE product, ``rows`` of them at a time (whole heads: the MXU
+    holds a 128 x 128 piece of K while the rows stream past, and a head's
+    ``Tq`` alone would be as many rows as the piece takes to load).
+    ``bits_ref`` [Tq, U] int32 — bit ``i`` of entry ``u`` of a query's row
+    says that it reads block ``i`` of unit ``u``.  ``k_hbm`` / ``v_hbm``
+    the pools seen as units ``[P * per_page, unit, Hkv, D]`` (a page's
+    units are contiguous); ``kbuf`` / ``vbuf`` [2, W, unit, Hkv, D] the
+    double buffers: a step copies the list's next ``W`` units, one DMA
+    each, and scores them as ONE product of ``W * unit`` keys (the carry's
+    rescale costs a step what it costs whatever the keys, so a step wants
+    many; a copy and a skip want few), step ``j + 1``'s copies in flight
+    during step ``j``'s products; the last step's missing units are zeroed
+    and masked.  A step's bias is built once — membership and ``key
+    position <= query position``, NEG_INF where either fails, so a key a
+    query did not choose is out of its maximum and adds an exact 0 — and
+    the KV head's ``[W * unit, D]`` rows are pulled out of the stored order
+    once (full MXU rows, no zero heads).  The carry is the decode kernel's:
+    un-normalized f32 o, running max, denominator."""
+    b, g, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    d = q_ref.shape[-1]
+    _, w, unit, h, _ = kbuf.shape
+    row = (b * pl.num_programs(1) + g) * pl.num_programs(2) + i
+    n = cnt_ref[row]
+    steps = pl.cdiv(n, w)
+    qpos = (idx_ref[b] + i * tile_q
+            + jax.lax.broadcasted_iota(jnp.int32, (tile_q, 1), 0))
+    key = jax.lax.broadcasted_iota(jnp.int32, (1, unit), 1)
+    key_bit = jnp.left_shift(1, key // block)
+    entry = jax.lax.broadcasted_iota(jnp.int32, (1, bits_ref.shape[1]), 1)
+
+    def for_units(lo, hi, fn):
+        def body(p, carry):
+            fn(p)
+            return carry
+        jax.lax.fori_loop(lo, hi, body, 0)
+
+    def live(j):
+        return jnp.clip(n - j * w, 0, w)
+
+    def copies(j, slot, p):
+        u = list_ref[row, j * w + p]
+        pid = tbl_ref[b, u // per_page] * per_page + u % per_page
+        return (pltpu.make_async_copy(k_hbm.at[pid], kbuf.at[slot, p],
+                                      sem.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[pid], vbuf.at[slot, p],
+                                      sem.at[1, slot]))
+
+    def start(j, slot):
+        def copy(p):
+            for c in copies(j, slot, p):
+                c.start()
+
+        def zero(p):
+            kbuf[slot, p] = jnp.zeros(kbuf.shape[2:], kbuf.dtype)
+            vbuf[slot, p] = jnp.zeros(vbuf.shape[2:], vbuf.dtype)
+        for_units(0, live(j), copy)
+        for_units(live(j), w, zero)
+
+    def wait(j, slot):
+        def done(p):
+            for c in copies(j, slot, p):
+                c.wait()
+        for_units(0, live(j), done)
+
+    oacc_ref[...] = jnp.zeros_like(oacc_ref)
+    m_ref[...] = jnp.full_like(m_ref, bw.NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(steps > 0)
+    def _first():
+        start(0, 0)
+
+    def stream(j, carry):
+        slot = j % 2
+
+        @pl.when(j + 1 < steps)
+        def _prefetch():
+            start(j + 1, 1 - slot)
+
+        wait(j, slot)
+        k = _kv_head_rows(kbuf.at[slot].reshape(w * unit * h, d), g, h,
+                          w * unit)
+        v = _kv_head_rows(vbuf.at[slot].reshape(w * unit * h, d), g, h,
+                          w * unit)
+        seen = []
+        for p in range(w):
+            at = jnp.minimum(j * w + p, list_ref.shape[1] - 1)
+            u = list_ref[row, at]
+            mine = jnp.sum(jnp.where(entry == u, bits_ref[...], 0), axis=1,
+                           keepdims=True)                       # [Tq, 1]
+            seen.append(((mine & key_bit) != 0) & (u * unit + key <= qpos)
+                        & (j * w + p < n))
+        bias = jnp.where(jnp.concatenate(seen, axis=1), 0.0, bw.NEG_INF)
+        bias = jnp.concatenate([bias] * (rows // tile_q), axis=0)
+
+        def some(x, c):
+            at = pl.ds(pl.multiple_of(x * rows, rows), rows)
+            o, m, l = bw.block_accumulate(
+                oacc_ref[at, :], m_ref[at, :][:, 0], l_ref[at, :][:, 0],
+                q_ref[at, :], k, v, scale, bias)
+            oacc_ref[at, :] = o
+            m_ref[at, :] = m[:, None]
+            l_ref[at, :] = l[:, None]
+            return c
+        return jax.lax.fori_loop(0, q_ref.shape[0] // rows, some, carry)
+
+    jax.lax.fori_loop(0, steps, stream, 0)
+    o_ref[...] = bw.finalize(
+        oacc_ref[...], l_ref[...][..., 0]).astype(o_ref.dtype)
+
+
+def tile_lists(bits, tile_q: int):
+    """What each (row, KV head, tile of ``tile_q`` queries) streams: bits
+    [B, Hkv, S, U] -> (units [B, Hkv, S / tile_q, U] int32, the union of
+    its queries' units in ascending order, then zeros; count [B, Hkv, S /
+    tile_q])."""
+    b, hkv, s, u = bits.shape
+    read = jnp.any(bits.reshape(b, hkv, s // tile_q, tile_q, u) != 0, axis=3)
+    ids = jnp.arange(u, dtype=jnp.int32)
+    count = jnp.sum(read, -1, dtype=jnp.int32)
+    units = jnp.sort(jnp.where(read, ids, u), axis=-1)
+    return jnp.where(ids < count[..., None], units, 0), count
+
+
+@functools.partial(jax.jit, static_argnames=("block", "unit", "tile_q",
+                                             "interpret", "rows", "width"))
+def paged_flash_decode_tiles(q, pool_k, pool_v, block_table, index, bits,
+                             units, count, *, block: int, unit: int,
+                             tile_q: int, interpret: bool = False,
+                             rows: int = _TILE_ROWS,
+                             width: int = _TILE_UNITS):
+    """The tile kernel's call: q [B, S, Hq, D]; pools [P, page, Hkv, D];
+    ``bits`` [B, Hkv, S, U]; ``units``, ``count`` of :func:`tile_lists` at
+    ``tile_q`` -> [B, S, Hq, D].  Jitted, so that a model's layers share
+    one lowering."""
+    b, s, hq, d = q.shape
+    p, page, hkv, _ = pool_k.shape
+    if _tiled_heads(hkv, pool_k.dtype.itemsize) != hkv:
+        raise ValueError(f"a pool of {hkv} {pool_k.dtype} heads does not "
+                         f"tile (1, 2, 4 or a multiple of 8 words of heads)")
+    group, tiles, per_page = hq // hkv, s // tile_q, page // unit
+    # whole heads a product: the most that divide the group within ``rows``
+    heads = max(x for x in range(1, group + 1)
+                if group % x == 0 and (x == 1 or x * tile_q <= rows))
+
+    def tiled(x):           # [B, S, Hq, D] -> [B, Hkv, tiles, G * Tq, D]
+        x = x.reshape(b, tiles, tile_q, hkv, group, d)
+        return jnp.transpose(x, (0, 3, 1, 4, 2, 5)).reshape(
+            b, hkv, tiles, group * tile_q, d)
+    u_pad = -bits.shape[-1] % 128
+    bits = jnp.pad(bits, ((0, 0),) * 3 + ((0, u_pad),))
+    units = jnp.pad(units, ((0, 0),) * 3 + ((0, u_pad),))
+    qo_spec = pl.BlockSpec((None, None, None, group * tile_q, d),
+                           lambda b_, g_, i_, *_: (b_, g_, i_, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(b, hkv, tiles),
+        in_specs=[qo_spec,
+                  pl.BlockSpec((None, None, tile_q, bits.shape[-1]),
+                               lambda b_, g_, i_, *_: (b_, g_, i_, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=qo_spec,
+        scratch_shapes=[
+            pltpu.VMEM((2, width, unit, hkv, d), pool_k.dtype),
+            pltpu.VMEM((2, width, unit, hkv, d), pool_v.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((group * tile_q, d), jnp.float32),
+            pltpu.VMEM((group * tile_q, 1), jnp.float32),
+            pltpu.VMEM((group * tile_q, 1), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_paged_tiles_kernel, scale=1.0 / (d ** 0.5),
+                          block=block, per_page=per_page, tile_q=tile_q,
+                          rows=heads * tile_q),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, hkv, tiles, group * tile_q, d),
+                                       q.dtype),
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_TILE_VMEM_BYTES),
+        name="paged_flash_decode_tiles",
+    )(jnp.asarray(block_table, jnp.int32), jnp.asarray(index, jnp.int32),
+      units.reshape(-1, units.shape[-1]), count.reshape(-1), tiled(q), bits,
+      pool_k.reshape(p * per_page, unit, hkv, d),
+      pool_v.reshape(p * per_page, unit, hkv, d))
+    out = out.reshape(b, hkv, tiles, group, tile_q, d)
+    return jnp.transpose(out, (0, 2, 4, 1, 3, 5)).reshape(b, s, hq, d)
+
+
+def paged_tile_attention(q, pool_k, pool_v, block_table, index, bits, *,
+                         block: int, use_pallas=None, tile_q=None):
+    """Attention of a CHUNK whose queries each read a subset of the row's
+    blocks, the subset a (query, KV head)'s own: q [B, S, Hq, Dh] at
+    positions ``index[b] + i``; pools [P, page, Hkv, Dh], the chunk's own K
+    and V already written; ``bits`` [B, Hkv, S, U] int32 the membership
+    (``block_select.chunk_members`` at ``tile_keys(page, block) / block``
+    blocks a unit): query ``i`` reads the keys of its blocks at positions
+    ``<= index[b] + i`` and no other.  Returns (o [B, S, Hq, Dh], the
+    blocks the call's tiles copied — units streamed x blocks a unit,
+    summed over (row, KV head, tile)).
+
+    On the TPU the tile kernel (:func:`paged_flash_decode_tiles`: a tile of
+    queries streams the union of its queries' units once); elsewhere the
+    whole window gathered and masked, the oracle."""
+    b, s, hq, d = q.shape
+    page = pool_k.shape[1]
+    unit = tile_keys(page, block)
+    if use_pallas is None:
+        use_pallas = jax.default_backend() == "tpu"
+    if tile_q is None:
+        tile_q = math.gcd(s, _TILE_QUERIES)
+    units, count = tile_lists(bits, tile_q)
+    copied = jnp.sum(count) * (unit // block)
+    if use_pallas:
+        return paged_flash_decode_tiles(
+            q, pool_k, pool_v, block_table, index, bits, units, count,
+            block=block, unit=unit, tile_q=tile_q,
+            interpret=use_pallas == "interpret"), copied
+    k = gather_pages(pool_k, block_table)                   # [B, L, Hkv, D]
+    v = gather_pages(pool_v, block_table)
+    pos = jnp.arange(k.shape[1], dtype=jnp.int32)
+    entry = jnp.minimum(pos // unit, bits.shape[-1] - 1)
+    read = (bits[..., entry] >> (pos % unit // block)) & 1   # [B, Hkv, S, L]
+    mask = (read != 0) & _seen(q, k, index)[:, None]
+    qg = q.astype(jnp.float32).reshape(b, s, k.shape[2], -1, d)
+    sc = jnp.einsum("bqhgd,bkhd->bhgqk", qg,
+                    k.astype(jnp.float32)) * (1.0 / (d ** 0.5))
+    sc = jnp.where(mask[:, :, None], sc, -1e30)
+    o = jnp.einsum("bhgqk,bkhd->bqhgd", jax.nn.softmax(sc, -1),
+                   v.astype(jnp.float32))
+    return o.reshape(b, s, hq, d).astype(q.dtype), copied
 
 
 def paged_flash_decode_reference(q, pool_k, pool_v, block_table, index, *,

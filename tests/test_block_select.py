@@ -26,7 +26,8 @@ if ROOT not in sys.path:
 from dtf_tpu.models import build_model  # noqa: E402
 from dtf_tpu.ops import block_select as bs  # noqa: E402
 from dtf_tpu.ops import linear_state as ls  # noqa: E402
-from dtf_tpu.ops.paged_attention import paged_block_attention  # noqa: E402
+from dtf_tpu.ops.paged_attention import (  # noqa: E402
+    paged_block_attention, paged_tile_attention, tile_keys)
 from dtf_tpu.serve.bridge import serving_memory_plan  # noqa: E402
 from dtf_tpu.serve.decode import (KV_POOL, PAGE_STATE, Decoder,  # noqa: E402
                                   cache_leaves)
@@ -308,6 +309,145 @@ def test_a_row_reads_its_blocks_and_nothing_else(use_pallas):
             np.testing.assert_allclose(got[row, head], want, atol=2e-5)
 
 
+def test_the_membership_is_the_tables_set():
+    """``members`` against ``choose``: the same set of blocks a (query, KV
+    head) — ``count`` members, each in the table's first ``count`` entries
+    — for queries at and under ``dense_len``, one past it, deep past it and
+    under ties everywhere; and ``pack_members`` keeps every bit."""
+    rng = np.random.default_rng(13)
+    j_all = 256 // SIZES.stride
+    t = np.asarray([0, 7, 63, 64, 65, 130, 200, 255], np.int32)
+    r = rng.uniform(size=(len(t), 2, j_all)).astype(np.float32)
+    r[5, 0, :] = 0.5                                    # ties everywhere
+    table, count = bs.choose(jnp.asarray(r), jnp.asarray(t), SIZES)
+    member = np.asarray(bs.members(jnp.asarray(r), jnp.asarray(t), SIZES))
+    assert member.shape == (len(t), 2, 256 // SIZES.block)
+    for row in range(len(t)):
+        for g in range(2):
+            want = sorted(table[row, g, :int(count[row])].tolist())
+            assert np.flatnonzero(member[row, g]).tolist() == want
+    assert member[5, 0].tolist() != member[5, 1].tolist()
+    for per in (1, 4, 16):
+        bits = np.asarray(bs.pack_members(jnp.asarray(member), per))
+        assert bits.shape[-1] == -(-member.shape[-1] // per)
+        back = (bits[..., None] >> np.arange(per)) & 1
+        assert (back.reshape(bits.shape[:-1] + (-1,))[..., :member.shape[-1]]
+                == member).all()
+    with pytest.raises(ValueError, match="pack"):
+        bs.pack_members(jnp.asarray(member), 32)
+
+
+def _sequence(rng, n, dtype, agree=False, hq=8, hkv=2, d=16):
+    """One row of ``n`` tokens written into a pool through a shuffled table:
+    q [1, n, Hq, D], k, v [1, n, Hkv, D] and the three pools.  ``agree``:
+    the pooled keys are PLANTED — blocks 2 and 5 of KV head 0 (3 and 9 of
+    head 1) carry a large common direction that every query shares, so
+    every query past them chooses the same two."""
+    pages = 1 + -(-n // PAGE)
+    table = np.arange(1, pages, dtype=np.int32)
+    rng.shuffle(table)
+    q = rng.normal(size=(1, n, hq, d)).astype(np.float32)
+    k = rng.normal(size=(1, n, hkv, d)).astype(np.float32)
+    v = rng.normal(size=(1, n, hkv, d)).astype(np.float32)
+    if agree:
+        q[..., 0] = 6.0
+        k[..., 0] = -3.0
+        for g, planted in enumerate([(2, 5), (3, 9)]):
+            for blk in planted:
+                k[0, blk * 8:blk * 8 + 8, g, 0] = 6.0
+    q, k, v = (jnp.asarray(x, dtype) for x in (q, k, v))
+    pad = (pages - 1) * PAGE - n
+
+    def pool(x):
+        rows = jnp.pad(x[0], ((0, pad), (0, 0), (0, 0))).reshape(
+            pages - 1, PAGE, hkv, d)
+        return jnp.zeros((pages, PAGE, hkv, d), dtype).at[table].set(rows)
+    k_pool = pool(k)
+    pooled = jnp.zeros((pages, PAGE // SIZES.stride, hkv, d), dtype)
+    for start in range(0, (pages - 1) * PAGE, PAGE):
+        pooled = bs.write_pooled(
+            pooled, jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))[
+                :, start:start + PAGE], k_pool, jnp.asarray(table)[None],
+            jnp.asarray([start], jnp.int32), SIZES)
+    return q, k, v, k_pool, pool(v), pooled, jnp.asarray(table)[None]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("case,n,start,s,tile_q,agree", [
+    ("past_dense_len", 192, 128, 64, 16, False),
+    ("straddles_dense_len", 128, 32, 64, 32, False),
+    ("own_block_cut_at_the_query", 104, 96, 8, 8, False),
+    ("last_chunk_with_a_padded_tail", 170, 128, 64, 16, False),
+    ("queries_that_agree_skip_blocks", 256, 192, 64, 16, True),
+    ("queries_that_differ_skip_none", 256, 192, 64, 64, False)])
+def test_a_chunks_tiles_read_what_each_query_chose(dtype, case, n, start, s,
+                                                   tile_q, agree):
+    """The tile kernel (interpret mode) over a chunk's packed membership
+    against BOTH other forms of the same read: ``paged_block_attention`` a
+    token over ``choose``'s tables (what a chunk ran before PR 47), and
+    plain attention under ``plain_mask`` over the whole sequence.  The two
+    KV heads choose different blocks; a padded tail (the pool holds zeros
+    past ``n``) changes no real query; ``kv_blocks_streamed`` counts whole
+    units of 4 blocks and falls under what the tiles could see only where
+    the tile's queries agree."""
+    rng = np.random.default_rng(14)
+    q, k, v, k_pool, v_pool, pooled, table = _sequence(rng, n, dtype, agree)
+    scale, real = 0.25, min(s, n - start)
+    index = jnp.asarray([start], jnp.int32)
+    t = start + jnp.arange(s, dtype=jnp.int32)[None, :]
+    q_chunk = jnp.pad(q[:, start:start + s],
+                      ((0, 0), (0, s - real), (0, 0), (0, 0)))
+    unit = tile_keys(PAGE, SIZES.block)
+    assert unit == PAGE
+    bits = bs.chunk_members(q_chunk, pooled, table, t, SIZES, scale,
+                            unit // SIZES.block, tile=32)
+    got, streamed = paged_tile_attention(
+        q_chunk, k_pool, v_pool, table, index, bits, block=SIZES.block,
+        use_pallas="interpret", tile_q=tile_q)
+    oracle, same = paged_tile_attention(
+        q_chunk, k_pool, v_pool, table, index, bits, block=SIZES.block,
+        use_pallas=False, tile_q=tile_q)
+    assert int(same) == int(streamed)
+    # a token: the table of blocks a (query, KV head) and a row of the
+    # paged kernel's oracle each
+    r = bs.scores(q_chunk, pooled, table, t, SIZES, scale)
+    blocks, count = bs.choose(r[0], t[0], SIZES)
+    past = (np.asarray(t[0]) + 1 > SIZES.dense_len)[:real]
+    if past.any():
+        chose = np.asarray(blocks)[:real][past]
+        assert (chose[:, 0] != chose[:, 1]).any()
+    ids = bs.physical(blocks[None], table, PAGE, SIZES.block)[0]
+    by_token = paged_block_attention(
+        q_chunk[0], k_pool, v_pool, ids, count, t[0] % SIZES.block,
+        block=SIZES.block, use_pallas=False)
+    # plain attention over the whole sequence under the choice as a mask
+    f32 = jnp.float32
+    mask = bs.plain_mask(q, k, SIZES, scale)                # [1,n,Hkv,n]
+    sc = jnp.einsum("bqhgd,bkhd->bqhgk",
+                    q.astype(f32).reshape(1, n, 2, 4, 16),
+                    k.astype(f32)) * scale
+    sc = jnp.where(mask[:, :, :, None, :], sc, -1e30)
+    plain = jnp.einsum("bqhgk,bkhd->bqhgd", jax.nn.softmax(sc, -1),
+                       v.astype(f32)).reshape(1, n, 8, 16)
+    tol = 2e-5 if dtype == jnp.float32 else 3e-2
+    # (a bfloat16 pooled leaf rounds the pooled keys that ``plain_mask``
+    # keeps in float32, and a rounded score flips a choice: float32 only)
+    others = (oracle[0, :real], by_token[:real]) + (
+        (plain[0, start:start + real],) if dtype == jnp.float32 else ())
+    for other in others:
+        np.testing.assert_allclose(np.asarray(got[0, :real], np.float32),
+                                   np.asarray(other, np.float32), atol=tol)
+    per = unit // SIZES.block
+    seen = 2 * sum((start + i + tile_q - 1) // SIZES.block + 1
+                   for i in range(0, s, tile_q))
+    assert int(streamed) % per == 0
+    if agree:
+        assert int(streamed) < seen
+    elif case == "queries_that_differ_skip_none":
+        assert int(streamed) >= seen
+
+
 def test_sizes_that_do_not_fit_a_page_are_refused():
     with pytest.raises(ValueError, match="pool = 2 x stride"):
         bs.Sizes(8, 3, 2, 2, 16, 1, 64).check(32)
@@ -425,7 +565,8 @@ def test_the_spans_counts_are_what_a_call_reads(toy):
     assert model.stats_names == (
         "assignments", "experts_touched", "expert_load_max",
         "kv_blocks_visible", "kv_blocks_read", "pooled_keys_scored",
-        "rows_dense_path", "linear_tokens", "state_rows_advanced")
+        "rows_dense_path", "linear_tokens", "state_rows_advanced",
+        "kv_blocks_streamed")
     dec = Decoder(model.clone(use_pallas=False), params, num_slots=2,
                   max_seq_len=256, kv_page_size=PAGE, kv_pool_pages=17)
     cache = dec.fresh_cache()
@@ -442,7 +583,8 @@ def test_the_spans_counts_are_what_a_call_reads(toy):
         "assignments": 0, "experts_touched": 0, "expert_load_max": 0,
         "kv_blocks_visible": 4 * blocks, "kv_blocks_read": 4 * blocks,
         "pooled_keys_scored": 0, "rows_dense_path": 64,
-        "linear_tokens": 128, "state_rows_advanced": 2}
+        "linear_tokens": 128, "state_rows_advanced": 2,
+        "kv_blocks_streamed": 0}
     _, cache, _ = dec.prefill_chunk(cache, tokens, table, CHUNK, 9, 0.0,
                                     seed=0)
     got = counts()
@@ -452,6 +594,11 @@ def test_the_spans_counts_are_what_a_call_reads(toy):
     assert got["pooled_keys_scored"] == 2 * sum(
         (t - 3) // 2 + 1 for t in range(64, 74))
     assert (got["rows_dense_path"], got["linear_tokens"]) == (0, 20)
+    # one tile a (KV head, layer): the first unit of 4 blocks (the forced
+    # block), the chunk's own two, and the one between if any query of the
+    # 64 (the padded tail's too: their rows are computed) chose in it
+    assert 4 * 12 <= got["kv_blocks_streamed"] <= 4 * 16
+    assert got["kv_blocks_streamed"] % 4 == 0
     tables = np.zeros((2, dec.pages_per_slot), np.int32)
     tables[0] = table
     _, cache, _ = dec.decode_step(
@@ -462,6 +609,7 @@ def test_the_spans_counts_are_what_a_call_reads(toy):
     assert (got["kv_blocks_visible"], got["kv_blocks_read"]) == (4 * 10,
                                                                  4 * 5)
     assert (got["linear_tokens"], got["state_rows_advanced"]) == (2, 2)
+    assert got["kv_blocks_streamed"] == 0       # a step copies by a table
 
 
 def test_three_kinds_of_leaf_in_one_pool(toy):

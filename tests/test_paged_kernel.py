@@ -492,3 +492,143 @@ def test_engine_generation_interpret_kernel_token_exact():
         finally:
             eng.stop(drain=False)
     assert results["kernel"] == results["gather"]
+
+
+# ---------------------------------------------------------------------------
+# The tile kernel: a chunk whose queries each read a subset of the blocks
+# ---------------------------------------------------------------------------
+
+def _tile_case(seed, dtype, *, b=2, s=32, hq=8, hkv=2, page=16, block=4,
+               m=6, starts=(32, 48), density=0.3):
+    """Random pools under shuffled tables, a chunk of ``s`` queries a row
+    at ``starts`` and a RANDOM membership a (query, KV head) — any set of
+    blocks up to the query's own, the first always — packed at the unit
+    ``tile_keys`` names."""
+    rng = np.random.default_rng(seed)
+    pages = 1 + b * m
+    table = rng.permutation(np.arange(1, pages)).reshape(b, m).astype(
+        np.int32)
+    pk = jnp.asarray(rng.standard_normal((pages, page, hkv, D)), dtype)
+    pv = jnp.asarray(rng.standard_normal((pages, page, hkv, D)), dtype)
+    q = jnp.asarray(rng.standard_normal((b, s, hq, D)), dtype)
+    index = np.asarray(starts, np.int32)
+    unit = pa.tile_keys(page, block)
+    blocks = m * page // block
+    member = rng.uniform(size=(b, hkv, s, blocks)) < density
+    member[..., 0] = True
+    own = (index[:, None] + np.arange(s)[None, :]) // block     # [B, S]
+    member &= np.arange(blocks)[None, None, None, :] <= own[:, None, :,
+                                                            None]
+    member[np.arange(b)[:, None], :, np.arange(s)[None, :], own] = True
+    per = unit // block
+    bits = np.sum(member.reshape(b, hkv, s, -1, per).astype(np.int32)
+                  << np.arange(per), -1).astype(np.int32)
+    return (q, pk, pv, jnp.asarray(table), jnp.asarray(index),
+            jnp.asarray(bits)), member, block
+
+
+def _tile_expected(args, member, block):
+    """Softmax attention, written out in float64: query ``i`` of row ``b``
+    over the keys of ITS blocks at positions up to its own."""
+    q, pk, pv, table, index, _ = (np.asarray(x, np.float64) for x in args)
+    table, index = table.astype(int), index.astype(int)
+    b, s, hq, d = q.shape
+    page, hkv = pk.shape[1], pk.shape[2]
+    out = np.zeros((b, s, hq, d))
+    for r in range(b):
+        k = pk[table[r]].reshape(-1, hkv, d)
+        v = pv[table[r]].reshape(-1, hkv, d)
+        for i in range(s):
+            t = index[r] + i
+            for head in range(hq):
+                g = head // (hq // hkv)
+                pos = [p for p in range(t + 1) if member[r, g, i, p // block]]
+                sc = k[pos, g] @ q[r, i, head] / np.sqrt(d)
+                w = np.exp(sc - sc.max())
+                out[r, i, head] = (w / w.sum()) @ v[pos, g]
+    return out
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("tile_q,kw", [
+    (16, {}),                                   # two tiles a (row, KV head)
+    (32, {}),                                   # one
+    (8, dict(hkv=4, hq=8)),                     # two words of bf16 heads
+    (16, dict(block=8, page=32, m=3)),          # a page of one unit
+    (16, dict(starts=(0, 64))),                 # a chunk from position 0
+    (16, dict(density=0.004, m=8, starts=(64, 96)))],    # tiles skip units
+    ids=["two_tiles", "one_tile", "four_kv_heads", "blocks_of_8",
+         "from_zero", "few_blocks"])
+def test_tile_kernel_reads_each_querys_blocks(dtype, tile_q, kw):
+    """The tile kernel (interpret mode) = the softmax over exactly the keys
+    a query's membership names, up to the query: the KV heads of a row hold
+    different sets, a unit no query of the tile reads is not streamed
+    (NaN there would show: the interpreter's scratch is NaN-filled, and the
+    count falls under the table's), the gathered oracle agrees, and both
+    report the same count of copied blocks."""
+    args, member, block = _tile_case(5, dtype, **kw)
+    assert (member[:, 0] != member[:, 1]).any()
+    got, streamed = pa.paged_tile_attention(
+        *args, block=block, use_pallas="interpret", tile_q=tile_q)
+    oracle, same = pa.paged_tile_attention(
+        *args, block=block, use_pallas=False, tile_q=tile_q)
+    want = _tile_expected(args, member, block)
+    tol = 1e-5 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(np.asarray(got, np.float64), want, atol=tol)
+    np.testing.assert_allclose(np.asarray(oracle, np.float64), want,
+                               atol=tol)
+    b, hkv, s, _ = member.shape
+    per = pa.tile_keys(args[1].shape[1], block) // block
+    units = member.reshape(b, hkv, s // tile_q, tile_q, -1, per).any(
+        axis=(3, 5))
+    assert int(streamed) == int(same) == units.sum() * per
+    if kw.get("density", 1) < 0.1:
+        unit = per * block
+        visible = hkv * sum((int(start) + i + tile_q - 1) // unit + 1
+                            for start in args[4]
+                            for i in range(0, s, tile_q))
+        assert units.sum() < 0.75 * visible      # units ARE skipped
+
+
+def test_tile_kernel_never_reads_a_unit_no_query_chose():
+    """NaN in every unit outside the tiles' unions: the output stays
+    finite and equal, so those units are neither copied nor multiplied."""
+    args, member, block = _tile_case(6, jnp.float32, density=0.004, m=8,
+                                     starts=(64, 96))
+    q, pk, pv, table, index, bits = args
+    unit = pa.tile_keys(pk.shape[1], block)
+    per = unit // block
+    units_read = member.reshape(member.shape[:3] + (-1, per)).any(
+        axis=(1, 2, 4))                                     # [B, units]
+    spoiled_k, spoiled_v = np.asarray(pk).copy(), np.asarray(pv).copy()
+    per_page = pk.shape[1] // unit
+    for r in range(len(table)):
+        for u in np.flatnonzero(~units_read[r]):
+            page, off = int(table[r, u // per_page]), (u % per_page) * unit
+            spoiled_k[page, off:off + unit] = np.nan
+            spoiled_v[page, off:off + unit] = np.nan
+    assert np.isnan(spoiled_k).any()
+    want, _ = pa.paged_tile_attention(*args, block=block,
+                                      use_pallas="interpret", tile_q=32)
+    got, _ = pa.paged_tile_attention(
+        q, jnp.asarray(spoiled_k), jnp.asarray(spoiled_v), table, index,
+        bits, block=block, use_pallas="interpret", tile_q=32)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("page,block,want", [
+    (2048, 64, 256), (32, 8, 32), (16, 4, 16), (4096, 8, 128),
+    (1024, 128, 256)])
+def test_tile_keys_is_whole_blocks_of_a_page(page, block, want):
+    assert pa.tile_keys(page, block) == want
+
+
+def test_tile_kernel_refuses_heads_that_do_not_tile():
+    args, _, block = _tile_case(7, jnp.bfloat16, hkv=1, hq=4)
+    with pytest.raises(ValueError, match="does not tile"):
+        pa.paged_tile_attention(*args, block=block, use_pallas="interpret",
+                                tile_q=16)
+    with pytest.raises(ValueError, match="whole units"):
+        pa.tile_keys(48, 32)

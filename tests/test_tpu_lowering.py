@@ -752,7 +752,11 @@ def test_sparse_lightning_serve_bodies_compile_for_v5e(v5e, sparse_decoder,
     a tile of its own: PR 40's serial loop does not come back).  A chunk of
     one page compiles at 32 query heads over 2 KV heads: the first through
     the flash kernel, a later one with both branches — whole pages at or
-    under ``dense_len``, a choice and a gather a token past it."""
+    under ``dense_len`` through ``paged_flash_decode``; past it the choice
+    as packed membership (the float32 scores a TILE of 256 queries at a
+    time) and ONE call a layer of the tile kernel
+    ``paged_flash_decode_tiles``, which streams a tile's blocks once: no
+    table a token, no gather a token."""
     from dtf_tpu.serve import decode as sd
     i32, f32 = jnp.int32, jnp.float32
     dec = sparse_decoder
@@ -791,9 +795,17 @@ def test_sparse_lightning_serve_bodies_compile_for_v5e(v5e, sparse_decoder,
         assert _kernel_calls(text, "paged_flash_decode") == 0
         assert pooled_scatters == 2
     else:
-        # a layer: the dense branch's call and the sparse branch's
-        assert _kernel_calls(text, "paged_flash_decode") == 4
+        # a layer: the dense branch's call, and the sparse branch's ONE
+        # call of the tile kernel (its name still matches the readers'
+        # regex ``paged_flash_decode``)
+        assert _kernel_calls(text, "paged_flash_decode") == 2
+        assert _kernel_calls(text, "paged_flash_decode_tiles") == 2
         assert pooled_scatters == 2
+        # the membership a layer is [1, 2, 2048, 520] int32, 8.5e6 B; the
+        # choice's float32 r exists a tile of queries at a time (whole it
+        # would be [2048, 2, 8320] float32, 136e6 B a layer)
+        assert "s32[1,2,2048,520]" in text
+        assert "f32[1,2048,2,8320]" not in text
     if body != "decode":
         # the six state entries of the chunk's page: written in place
         assert len(re.findall(
@@ -803,6 +815,37 @@ def test_sparse_lightning_serve_bodies_compile_for_v5e(v5e, sparse_decoder,
                     if " while(" in ln and "/scatter" in ln]
         # 7.49e9 B of pool are donated and updated in place
         assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
+@pytest.mark.parametrize("positions", [65536, 131072])
+def test_sparse_tile_kernel_compiles_for_v5e_alone(v5e, positions):
+    """The tile kernel alone at the cell's shapes — a chunk of 2,048
+    queries, 32 heads over 2 KV heads of 128, bfloat16 pages of 2,048 —
+    under a table that reaches 65,536 and 131,072 positions: its carry (1
+    MiB of float32 output and 2 MiB of lane-padded max and sum a grid
+    point) and its membership block grow past the decode kernel's shared
+    plan, so it states its own VMEM need, and an overrun shows here."""
+    import importlib
+    pa = importlib.import_module("dtf_tpu.ops.paged_attention")
+    i32, bf16 = jnp.int32, jnp.bfloat16
+    m = positions // 2048
+    unit = pa.tile_keys(2048, 64)
+    u = m * 2048 // unit
+
+    def call(q, pk, pv, table, index, bits):
+        return pa.paged_tile_attention(q, pk, pv, table, index, bits,
+                                       block=64, use_pallas=True)
+    s = jax.ShapeDtypeStruct
+    args = _on_chip((s((1, 2048, 32, 128), bf16),
+                     s((705, 2048, 2, 128), bf16),
+                     s((705, 2048, 2, 128), bf16), s((1, m), i32),
+                     s((1,), i32), s((1, 2, 2048, u), i32)), v5e)
+    compiled = jax.jit(call).lower(*args).compile()
+    assert _kernel_calls(compiled.as_text(),
+                         "paged_flash_decode_tiles") == 1
+    assert pa._TILE_VMEM_BYTES <= 64 * 2 ** 20      # of the chip's 128 MiB
+    # q, o and their head-major copies, the lists: no pool is copied
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
 
 
 def test_dense_decode_body_compiles_for_v5e(v5e):
