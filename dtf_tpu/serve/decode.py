@@ -183,8 +183,9 @@ def position_key(seed, position):
         jnp.asarray(position, jnp.int32))
 
 
-# [B] seeds + [B] positions -> [B] typed keys (jitted once; the engine
-# calls this every decode step, so it must not re-trace)
+# [B] seeds + [B] positions -> [B] typed keys, a program of its own: what
+# the replay and lowering checks make a step's keys with (a decode step
+# makes its own inside ``_step_operands``)
 _seed_row_keys = jax.jit(jax.vmap(position_key))
 
 
@@ -214,7 +215,8 @@ def _chunk_operands(packed, pages: int):
     eager primitives (1.8 ms) and a ``jnp.asarray`` an operand (1.7 ms)
     were 3.4 of the 4.8 ms a dense chunk's dispatch took, and this is 0.7
     (PERF.md §6 PR 37).  Retraced by a chunk's SHAPE alone, with the body
-    and in the same warm-up; no value compiles."""
+    and in the same warm-up; no value compiles.  A decode step's sibling,
+    for the same reason: ``_step_operands``."""
     c = packed.shape[0] - pages - 4
     sample_pos, start, seed, temperature = packed[c + pages:]
     return (packed[None, :c], packed[None, c:c + pages], sample_pos,
@@ -222,6 +224,34 @@ def _chunk_operands(packed, pages: int):
             position_key(jax.lax.bitcast_convert_type(seed, jnp.uint32),
                          start + sample_pos),
             start)
+
+
+def _pack_step_operands(tokens, index, temperature, seeds, block_tables):
+    """The one host array ``_step_operands`` splits: int32 ``[B × (M + 4)]``
+    — tokens [B], index [B], the BITS of temperature (float32) [B] and of
+    seeds (uint32: seeds reach 2**32 − 1) [B], then the block tables
+    [B, M] row by row."""
+    return np.concatenate([
+        np.asarray(tokens, np.int32).reshape(-1),
+        np.asarray(index, np.int32).reshape(-1),
+        np.asarray(temperature, np.float32).reshape(-1).view(np.int32),
+        np.asarray(seeds, np.uint32).reshape(-1).view(np.int32),
+        np.asarray(block_tables, np.int32).reshape(-1)])
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _step_operands(packed, rows: int):
+    """Everything a decode step's body takes besides the parameters and the
+    cache, from ONE host array (``_pack_step_operands``) in ONE cached
+    program (why: ``_chunk_operands``).  Returns the body's operands in its
+    own order and avals: (tokens [B, 1], index [B], block_tables [B, M],
+    temperature [B], the row keys ``position_key(seeds[b], index[b])``).
+    Retraced by (B, M) alone, with the body and in the same warm-up."""
+    tokens, index, temperature, seeds = packed[:4 * rows].reshape(4, rows)
+    return (tokens[:, None], index, packed[4 * rows:].reshape(rows, -1),
+            jax.lax.bitcast_convert_type(temperature, jnp.float32),
+            jax.vmap(position_key)(
+                jax.lax.bitcast_convert_type(seeds, jnp.uint32), index))
 
 
 # How the TPU's compiler is asked to build the two bodies.  Left alone it
@@ -694,23 +724,23 @@ class Decoder:
         ints, block_tables [B, M] (all-zeros rows for slots not
         decoding) → (tokens [B], cache, logits [B, V]).  Row b samples
         with ``fold_in(key(seeds[b]), index[b])``, a pure function of
-        the request's seed and position."""
-        positions = index       # as handed in: the engine's are the host's
-        tokens = jnp.asarray(tokens, jnp.int32).reshape(-1, 1)
-        index = jnp.asarray(index, jnp.int32)
-        temperature = jnp.asarray(temperature, jnp.float32)
-        rowkeys = _seed_row_keys(jnp.asarray(seeds, jnp.uint32), index)
-        # laps of the engine's serve_iteration span, where one is open
-        # on this thread: the arguments, the closes of the rows that start
-        # a new window (``compact``, one a row), then the body's call
-        # returning
+        the request's seed and position.
+
+        Laps of the engine's ``serve_iteration`` span, where one is open
+        on this thread: ``launch_args`` closes when the step's operands
+        are on their way — one host array packed, ONE transfer and ONE
+        small program (``_step_operands``) enqueued; then the closes of
+        the rows that start a new window (``compact``, one a row);
+        ``launch_call`` closes when the body's call returns."""
+        block_tables = np.asarray(block_tables, np.int32)
+        operands = _step_operands(
+            _pack_step_operands(tokens, index, temperature, seeds,
+                                block_tables), block_tables.shape[0])
         trace.lap("launch_args")
         if self.summary is not None:
-            cache = self._close_windows(cache, np.asarray(positions),
-                                        np.asarray(block_tables))
-        dyn = (self.params, cache, tokens, index,
-               jnp.asarray(block_tables, jnp.int32), temperature,
-               rowkeys)
+            cache = self._close_windows(cache, np.asarray(index),
+                                        block_tables)
+        dyn = (self.params, cache) + operands
         fn = self._execs.get("decode")
         if fn is None:
             fn = (self._aot("serve_decode_step", self._decode, dyn)

@@ -1403,9 +1403,11 @@ class ServeEngine:
 
     def _step(self):
         """One decode step of every decoding row.  Laps of the turn's span:
-        ``build`` (the step's arrays), ``launch_args`` and ``launch_call``
-        (Decoder.decode_step closes them), ``ready`` (blocked on the
-        step's tokens: the device's time), ``emit`` (the walk after)."""
+        ``build`` (the step's arrays), ``launch_args`` (they are packed
+        into one host array and sent through the one operands program) and
+        ``launch_call`` (the body's call returning; Decoder.decode_step
+        closes both), ``ready`` (blocked on the step's tokens: the
+        device's time), ``emit`` (the walk after)."""
         now = time.perf_counter()
         if self._last_step_t is not None:
             self._m_decode_gap.observe(now - self._last_step_t)
