@@ -133,6 +133,24 @@ _REGISTRY = {
             mup=(12.0, 1.4, 4, 2.0), num_dense_layers=4, dense_width=1024,
             activation="silu"),
         32_768, 0.0),
+    # and learned sparse attention over the latent cache: a lightning
+    # indexer with parameters of its own (4 heads of 32, rotary on 16)
+    # chooses the 64 rows a query attends in the full layers, and the
+    # shared ones attend over the choice of the full layer below; a leading
+    # dense layer, then a held quarter of 16 sigmoid-routed experts
+    "routed_decoder_indexed": (
+        functools.partial(
+            routed_decoder.RoutedDecoderLM, num_layers=5, d_model=512,
+            num_heads=8, q_lora_rank=192, kv_lora_rank=128,
+            qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=32,
+            rope_interleave=True, indexer=(4, 32, 64, 16),
+            layer_indexer=("full", "shared", "shared", "shared", "full"),
+            num_dense_layers=1, dense_width=1024, num_experts=16,
+            experts_per_token=4, expert_width=128, shared_expert_width=128,
+            routing="sigmoid_bias", routed_scale=2.5,
+            router_bias_stddev=0.05, activation="silu",
+            router_input="post_attention", experts_held=(4, 4)),
+        32_768, 0.0),
     # pipeline-stacked LM family (pipeline stages over 'model')
     "pipeline_transformer": (pipeline_lm.PipelinedTransformerLM,
                              32_768, 0.0),

@@ -48,6 +48,15 @@ unchanged, while RoPE keeps the true position.  The caller closes a window
 ``window_compact`` call a layer) before the first write into the next one;
 a row then grows by the summaries' pages a window, not by the window's.
 
+``indexer`` set (latent attention, every layer) — LEARNED SPARSE ATTENTION:
+``layer_indexer[l]`` is ``full`` — the layer has a lightning indexer with
+parameters of its own (:class:`LightningIndexer`, ``ops/index_select.py``:
+index queries off the query latent, ONE index key a token in a pool leaf
+of its own, ``index_key``) and its queries attend the ``top`` rows it
+scores highest — or ``shared``: the layer attends over the choice of the
+nearest ``full`` layer below.  That choice (``chosen``) is the one value
+that flows BETWEEN layers: a block takes it and hands it on.
+
 **Mixer kind.**  ``layer_mixer[l]`` (one entry a layer; shorter tuples
 repeat) is ``attention`` — the kind above — or ``short_conv``
 (:class:`ShortConv`): a double-gated depthwise causal convolution of
@@ -168,7 +177,8 @@ either attention kind's counts; a model with ``sparse_block`` layers
 counts, in this order after the three expert counts, ``kv_blocks_visible``,
 ``kv_blocks_read``, ``pooled_keys_scored``, ``rows_dense_path``
 (``SPARSE_STATS``), ``linear_tokens``, ``state_rows_advanced``, and last
-``kv_blocks_streamed`` (``STREAMED_STATS``).
+``kv_blocks_streamed`` (``STREAMED_STATS``); a model with an ``indexer``
+counts, after the three expert counts, ``INDEX_STATS``.
 
 Every apply also yields counts (``stats_names``; summed over layers) in
 the ``"stats"`` collection when the caller makes it mutable: the serving
@@ -185,9 +195,14 @@ import jax
 import jax.numpy as jnp
 
 from dtf_tpu.models.transformer import paged_cache_attention
-from dtf_tpu.ops import block_select, linear_state, window_summary
+from dtf_tpu.ops import (block_select, index_select, linear_state,
+                         window_summary)
 from dtf_tpu.ops.flash_attention import flash_attention
 from dtf_tpu.ops.paged_attention import (cached_attention, expand_kv_heads,
+                                         gather_pages,
+                                         latent_sparse_attention,
+                                         latent_sparse_chunk,
+                                         latent_sparse_decode,
                                          paged_attention_auto,
                                          paged_block_attention,
                                          paged_tile_attention, tile_keys,
@@ -224,6 +239,15 @@ SPARSE_STATS = ("kv_blocks_visible", "kv_blocks_read", "pooled_keys_scored",
 # against the blocks its tiles could see this says how often the skip
 # engages; 0 on a decode step and on a chunk at or under ``dense_len``
 STREAMED_STATS = ("kv_blocks_streamed",)
+# with a lightning indexer (``indexer``) over the latent cache, summed over
+# the call's real queries: the index keys a (query, ``full`` layer) scores
+# to choose (none while it sees ``top`` rows or fewer: it chooses nothing),
+# the cache rows a (query, layer) could attend and those it does — COUNTED
+# from the membership the layer went by, not reckoned from positions —
+# (over ALL the layers, ``shared`` ones too), and the queries (once, not a
+# layer) that attend everything they see
+INDEX_STATS = ("index_keys_scored", "latent_rows_visible",
+               "latent_rows_selected", "rows_dense_path")
 MIXERS = ("attention", "short_conv", "linear_delta", "sparse_block",
           "lightning")
 # the kinds whose cache leaf is a running state entry a page
@@ -277,8 +301,22 @@ def gmm_tile(pairs: int, groups: int, k: int, n: int):
                if n % 128 == 0 else [n])
 
     def widest(tm, tk):
+        # beside a long contraction the compiler keeps more than
+        # ``gmm_blocks_bytes`` counts: compiled for the v5e, (128, 6144,
+        # 512) is 15.81 MiB by that count and is refused, for 16.16 in
+        # the expert layer and for 16.95 alone (PR 49: 16 experts of 6144
+        # x 4096) — 15.50 of double-buffered blocks, which is exactly what
+        # the compiler reports where those alone pass 16, and the rest
+        # accumulator and the body's temporaries.  Their rule is not
+        # known; this GUARD, half a byte a (row, contraction) element, is
+        # fitted to that one refusal and moves no call of a contraction
+        # up to 2,560 (``tests/test_gmm_tile.py``); the compile of both
+        # GLM bodies in ``tests/test_tpu_lowering.py`` holds it to the
+        # compiler
         return next((c for c in columns
-                     if gmm_blocks_bytes(tm, tk, c) <= _GMM_VMEM), 0)
+                     if gmm_blocks_bytes(tm, tk, c) <= _GMM_VMEM
+                     and gmm_blocks_bytes(tm, tk, c) + tm * tk // 2
+                     <= 16 * 2 ** 20), 0)
 
     tk = k
     while not widest(64, tk) and tk % 256 == 0:
@@ -1243,6 +1281,152 @@ def latent_row_lanes(kv_lora_rank: int, qk_rope_head_dim: int) -> int:
     return -(-(kv_lora_rank + qk_rope_head_dim) // _LANES) * _LANES
 
 
+def layer_norm(x, scale, bias, eps: float):
+    """LayerNorm over the last axis in f32, returned in x's dtype."""
+    x32 = x.astype(jnp.float32)
+    mean = jnp.mean(x32, -1, keepdims=True)
+    var = jnp.mean((x32 - mean) ** 2, -1, keepdims=True)
+    return ((x32 - mean) * jax.lax.rsqrt(var + eps)
+            * scale.astype(jnp.float32) + bias.astype(jnp.float32)
+            ).astype(x.dtype)
+
+
+class LightningIndexer(nn.Module):
+    """The CHOOSER of a ``full`` layer of learned sparse attention
+    (``ops/index_select.py``), with parameters of its own: ``heads`` index
+    queries of ``head_dim`` a token off the attention's QUERY LATENT, ``q_tj
+    = c_q W_q[j]``; ONE index key a token, ``k_s = LayerNorm(h W_k)``;
+    rotary positions on the first ``rope_dim`` values of both; a weight a
+    head, ``w_t = h W_w * heads**-0.5 * head_dim**-0.5``.  A query at ``t``
+    attends the ``top`` positions with the largest ``I(t, s) = sum_j w_tj
+    relu(q_tj . k_s)`` over ``s <= t`` (all of them while ``t < top``).
+
+    Decode mode keeps the index keys as a THIRD kind of row in the pool:
+    the leaf ``index_key`` ``[P, page, head_dim]`` (a ``latent_pool``: one
+    row a token, all heads').  The call writes the call's keys and returns
+    what the choice is made from — ``(q, w, the pool)`` — and
+    :func:`indexed_latent_attention` makes it where a chunk needs one.
+    Outside decode mode (and at init) it returns the choice itself: bool
+    ``[B, S, S]``."""
+    heads: int
+    head_dim: int
+    top: int
+    rope_dim: int
+    rope_theta: float
+    rope_interleave: bool
+    dtype: Any
+    param_dtype: Any
+    decode: bool = False
+    kv_page_size: Optional[int] = None
+    kv_pool_pages: Optional[int] = None
+    norm_eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, h, c_q, positions, cache_index=None, block_table=None):
+        b, s, d = h.shape
+        hn, dh, pdt = self.heads, self.head_dim, self.param_dtype
+        w_q = self.param("q", _normal(0.02), (c_q.shape[-1], hn * dh), pdt)
+        w_k = self.param("k", _normal(0.02), (d, dh), pdt)
+        g_k = self.param("k_norm", nn.initializers.ones, (dh,), pdt)
+        b_k = self.param("k_norm_bias", nn.initializers.zeros, (dh,), pdt)
+        w_w = self.param("weights", _normal(0.02), (d, hn), pdt)
+        rope = interleaved_rope if self.rope_interleave else rotate_half_rope
+
+        def mm(x, w_):
+            return jnp.einsum("bsd,dn->bsn", x.astype(self.dtype),
+                              w_.astype(self.dtype),
+                              preferred_element_type=jnp.float32)
+
+        def turned(x):      # [B, S, H, D]: the first rope_dim values turn
+            r = self.rope_dim
+            return jnp.concatenate(
+                [rope(x[..., :r], positions, self.rope_theta), x[..., r:]],
+                -1).astype(self.dtype)
+        q = turned(mm(c_q, w_q).reshape(b, s, hn, dh))
+        k = turned(layer_norm(mm(h, w_k), g_k, b_k, self.norm_eps
+                              )[:, :, None])[:, :, 0]
+        w = mm(h, w_w) * (hn ** -0.5 * dh ** -0.5)
+        if not self.decode:
+            return index_select.members(
+                index_select.scores(q, w, k, positions), self.top)
+        keys = self.variable("cache", "index_key", jnp.zeros,
+                             (self.kv_pool_pages, self.kv_page_size, dh),
+                             self.dtype)
+        if self.is_initializing():
+            return None
+        keys.value = write_pages(
+            keys.value, k, block_table, cache_index,
+            page_aligned=s > 1 and s % self.kv_page_size == 0)
+        return q, w, keys.value
+
+
+def indexed_latent_attention(q_abs, pool, block_table, cache_index, *, top,
+                             picked, chosen, scale, value_lanes,
+                             use_pallas):
+    """Absorbed latent attention of a decode-mode call over the rows each
+    query CHOSE: q_abs [B, S, H, W], the call's rows already in ``pool``.
+    ``picked`` — a ``full`` layer's :class:`LightningIndexer` output ``(q,
+    w, index keys)`` — makes the choice; None (a ``shared`` layer) takes
+    ``chosen``, the choice of the nearest ``full`` layer below.  Returns
+    ``(o [B, S, H, value_lanes], chosen)``.
+
+    The choice's form follows the path.  The kernels' (a chunk of whole
+    tiles, or one token, on the TPU or interpreted): tiled membership
+    (``index_select.chunk_select`` / ``decode_select``) that
+    ``latent_sparse_chunk`` / ``latent_sparse_decode`` mask by; a chunk no
+    query of which sees more than ``top`` rows goes through the dense
+    kernel every latent model uses and chooses nothing (zeros).  Elsewhere:
+    bool ``[B, S, L]`` on the gather oracle."""
+    b, s = q_abs.shape[:2]
+    if use_pallas is None:
+        use_pallas = jax.default_backend() == "tpu"
+    if s > 1 and s % index_select.CHUNK_QUERIES:
+        use_pallas = False
+    t = cache_index[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
+    if not use_pallas:
+        if picked is not None:
+            q_i, w_i, keys = picked
+            chosen = index_select.members(index_select.scores(
+                q_i, w_i, gather_pages(keys, block_table), t),
+                top)
+        return latent_sparse_attention(
+            q_abs, pool, block_table, chosen, value_lanes=value_lanes,
+            scale=scale), chosen
+    interpret = use_pallas == "interpret"
+    if s == 1:
+        if picked is not None:
+            q_i, w_i, keys = picked
+            chosen = index_select.decode_select(
+                q_i[:, 0], w_i[:, 0], keys, block_table, cache_index,
+                k=top, interpret=interpret)
+        o = latent_sparse_decode(
+            q_abs[:, 0], pool, block_table, cache_index, chosen,
+            value_lanes=value_lanes, scale=scale, interpret=interpret)
+        return o[:, None], chosen
+    blocks = index_select.member_blocks(block_table.shape[1], pool.shape[1])
+    tile = index_select.CHUNK_QUERIES
+    shape = (b, s // tile, blocks, tile, index_select.MEMBER_BLOCK)
+
+    def dense():
+        return (paged_attention_auto(
+            q_abs, pool, None, block_table, cache_index,
+            use_pallas=use_pallas, scale=scale, value_lanes=value_lanes),
+            jnp.zeros(shape, jnp.int8) if picked is not None else chosen)
+
+    def sparse():
+        member = chosen
+        if picked is not None:
+            q_i, w_i, keys = picked
+            member = index_select.chunk_select(
+                q_i, w_i, keys, block_table, cache_index, k=top,
+                interpret=interpret)
+        return latent_sparse_chunk(
+            q_abs, pool, block_table, cache_index, member,
+            value_lanes=value_lanes, scale=scale,
+            interpret=interpret), member
+    return jax.lax.cond(jnp.all(cache_index + s <= top), dense, sparse)
+
+
 class LatentAttention(nn.Module):
     """Multi-head latent attention.  A token's keys and values, for every
     head, come from one latent ``c_kv`` [kv_lora_rank] (after its own
@@ -1266,7 +1450,14 @@ class LatentAttention(nn.Module):
     head, one learned scale, before the rotation (the key side's norm is
     ``kv_norm``: a norm on expanded keys would forbid the absorbed form).
     ``head_gate``: a head's attended output is scaled by ``sigmoid(h
-    W_gate)`` of that head before ``out``."""
+    W_gate)`` of that head before ``out``.
+
+    ``indexer`` (kind, heads, head dim, top, rotary dims) — LEARNED SPARSE
+    ATTENTION over the same cache: a query attends the ``top`` rows a
+    lightning indexer chose (:class:`LightningIndexer`, off this layer's
+    query latent) and no other.  Kind ``full``: the layer has an indexer
+    (``attn/indexer``) and chooses; ``shared``: it attends over ``chosen``,
+    the choice handed in.  The call then returns ``(out, chosen)``."""
     num_heads: int
     q_lora_rank: Optional[int]
     kv_lora_rank: int
@@ -1284,10 +1475,11 @@ class LatentAttention(nn.Module):
     kv_pool_pages: Optional[int] = None
     q_head_norm: bool = False
     head_gate: bool = False
+    indexer: Optional[Tuple] = None
 
     @nn.compact
     def __call__(self, h, positions, cache_index=None, block_table=None,
-                 window_pages: Optional[int] = None):
+                 window_pages: Optional[int] = None, chosen=None):
         b, s, d = h.shape
         hq, rq, r = self.num_heads, self.q_lora_rank, self.kv_lora_rank
         dn, dr, dv = (self.qk_nope_head_dim, self.qk_rope_head_dim,
@@ -1318,6 +1510,17 @@ class LatentAttention(nn.Module):
         if self.q_head_norm:
             q = rms_norm(q, self.param("q_head_norm", ones, (dn + dr,), pdt),
                          self.rms_eps)
+        picked = None
+        if self.indexer is not None and self.indexer[0] == "full":
+            if rq is None:
+                raise ValueError("the indexer's queries come off the query "
+                                 "latent: q_lora_rank is needed")
+            picked = LightningIndexer(
+                *self.indexer[1:], self.rope_theta, self.rope_interleave,
+                self.dtype, pdt, decode=self.decode,
+                kv_page_size=self.kv_page_size,
+                kv_pool_pages=self.kv_pool_pages, name="indexer")(
+                    h, c_q, positions, cache_index, block_table)
         q_nope = q[..., :dn].astype(self.dtype)
         q_rope = rope(q[..., dn:], positions, self.rope_theta
                       ).astype(self.dtype)
@@ -1342,9 +1545,25 @@ class LatentAttention(nn.Module):
                                ).astype(self.dtype)
             q_abs = jnp.concatenate(
                 [q_abs, q_rope, jnp.zeros((b, s, hq, pad), self.dtype)], -1)
-            o = paged_cache_attention(
-                self, q_abs, row, None, cache_index, block_table,
-                window_pages=window_pages, scale=scale, value_lanes=r)
+            if self.indexer is None:
+                o = paged_cache_attention(
+                    self, q_abs, row, None, cache_index, block_table,
+                    window_pages=window_pages, scale=scale, value_lanes=r)
+            else:
+                pool = self.variable(
+                    "cache", "paged_latent", jnp.zeros,
+                    (self.kv_pool_pages, self.kv_page_size, row.shape[-1]),
+                    self.dtype)
+                o = jnp.zeros(q_abs.shape[:-1] + (r,), self.dtype)
+                if not self.is_initializing():
+                    pool.value = write_pages(
+                        pool.value, row, block_table, cache_index,
+                        page_aligned=s > 1 and s % self.kv_page_size == 0)
+                    o, chosen = indexed_latent_attention(
+                        q_abs, pool.value, block_table, cache_index,
+                        top=self.indexer[3], picked=picked, chosen=chosen,
+                        scale=scale, value_lanes=r,
+                        use_pallas=self.use_pallas)
             o = jnp.einsum("bshr,rhv->bshv", o, w_kvb[..., dn:],
                            preferred_element_type=jnp.float32
                            ).astype(self.dtype)
@@ -1357,15 +1576,19 @@ class LatentAttention(nn.Module):
                 [kv_all[..., :dn],
                  jnp.broadcast_to(k_rope[:, :, None], (b, s, hq, dr))], -1)
             i = jnp.arange(s)
+            mask = jnp.broadcast_to(i[None, :] <= i[:, None], (b, s, s))
+            if self.indexer is not None:
+                chosen = mask = picked if picked is not None else chosen
             o = cached_attention(
                 jnp.concatenate([q_nope, q_rope], -1), k, kv_all[..., dn:],
-                jnp.broadcast_to(i[None, :] <= i[:, None], (b, s, s)))
+                mask)
         if self.head_gate:
             gate = jax.nn.sigmoid(mm(
                 "bsd,dh->bsh", h, self.param("gate", _normal(0.02), (d, hq),
                                              pdt)))
             o = (o * gate[..., None]).astype(self.dtype)
-        return mm("bsn,nd->bsd", o.reshape(b, s, hq * dv), w_out)
+        out = mm("bsn,nd->bsd", o.reshape(b, s, hq * dv), w_out)
+        return out if self.indexer is None else (out, chosen)
 
 
 class RoutedBlock(nn.Module):
@@ -1412,14 +1635,19 @@ class RoutedBlock(nn.Module):
     # rotary theta)
     lightning: Optional[Tuple] = None
     residual_scale: float = 1.0         # on both branches of the layer
+    # LatentAttention's: (full | shared, heads, head dim, top, rotary dims)
+    indexer: Optional[Tuple] = None
 
     @nn.compact
     def __call__(self, x, positions, cache_index=None, block_table=None,
                  flash_prefill: bool = False,
-                 window_pages: Optional[int] = None, last_pos=None):
+                 window_pages: Optional[int] = None, last_pos=None,
+                 chosen=None):
         """-> (x, rows an expert held here [E] or None for a dense layer,
         state rows advanced or None for an attention layer, the blocks a
-        ``sparse_block`` layer's chunk tiles copied or None)."""
+        ``sparse_block`` layer's chunk tiles copied or None, the rows an
+        ``indexer`` layer's queries chose — its own choice or the one handed
+        in as ``chosen`` — or None)."""
         b, s, d = x.shape
         e, f = self.num_experts, self.expert_width
         held = (e if self.experts_held is None else self.experts_held[1])
@@ -1498,8 +1726,12 @@ class RoutedBlock(nn.Module):
                 kv_page_size=self.kv_page_size,
                 kv_pool_pages=self.kv_pool_pages,
                 q_head_norm=self.q_head_norm,
-                head_gate=self.attention_head_gate, name="attn")(
-                    h, positions, cache_index, block_table, window_pages)
+                head_gate=self.attention_head_gate, indexer=self.indexer,
+                name="attn")(
+                    h, positions, cache_index, block_table, window_pages,
+                    chosen)
+            if self.indexer is not None:
+                attn, chosen = attn
         if self.residual_scale != 1.0:
             attn = attn * self.residual_scale
         x = x + attn
@@ -1514,7 +1746,7 @@ class RoutedBlock(nn.Module):
                 self.activation)
             if self.residual_scale != 1.0:
                 y = y * self.residual_scale
-            return x + y.reshape(b, s, d), None, advanced, streamed
+            return x + y.reshape(b, s, d), None, advanced, streamed, chosen
         if self.router_input != "pre_attention":
             idx, weights = choose(h2)
         y, sizes = routed_experts(h2.astype(self.dtype), idx, weights,
@@ -1534,7 +1766,7 @@ class RoutedBlock(nn.Module):
                 self.activation)
         if self.residual_scale != 1.0:
             y = y * self.residual_scale
-        return x + y.reshape(b, s, d), sizes, advanced, streamed
+        return x + y.reshape(b, s, d), sizes, advanced, streamed, chosen
 
 
 class RoutedDecoderLM(nn.Module):
@@ -1636,6 +1868,13 @@ class RoutedDecoderLM(nn.Module):
     sparse: Optional[Tuple] = None
     lightning: Optional[Tuple] = None
     mup: Optional[Tuple] = None
+    # learned sparse attention over the latent cache: indexer (heads, head
+    # dim, top, rotary dims) and layer_indexer, one entry a layer — full:
+    # the layer has a LightningIndexer and chooses the top rows a query
+    # attends; shared: it attends over the choice of the nearest full
+    # layer below, the one value that crosses layers
+    indexer: Optional[Tuple] = None
+    layer_indexer: Tuple[str, ...] = ()
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
     use_pallas: Any = None
@@ -1650,6 +1889,8 @@ class RoutedDecoderLM(nn.Module):
     def stats_names(self):
         """What ``"stats"/"counts"`` holds, in order."""
         names = STATS if self.kv_lora_rank is None else LATENT_STATS
+        if self.indexer is not None:
+            return STATS[:3] + INDEX_STATS
         if self.summary_window is not None:
             return SUMMARY_STATS
         if "sparse_block" in self.layer_mixers():
@@ -1743,6 +1984,16 @@ class RoutedDecoderLM(nn.Module):
                 or sum(self.experts_held) > self.num_experts):
             raise ValueError(f"experts_held {self.experts_held!r} is no "
                              f"block of the {self.num_experts} experts")
+        kinds_i = tuple(self.layer_indexer)
+        if self.indexer is not None and (
+                self.kv_lora_rank is None or set(mixers) != {"attention"}
+                or len(kinds_i) != self.num_layers
+                or set(kinds_i) - {"full", "shared"}
+                or kinds_i[0] != "full"):
+            raise ValueError(
+                f"an indexer chooses rows of the latent cache: every layer "
+                f"latent attention, layer_indexer {self.layer_indexer!r} "
+                f"one of full | shared a layer, the first of them full")
         b, s = tokens.shape
         pdt = jnp.dtype(self.param_dtype)
         embed = self.param("embed", _normal(0.02),
@@ -1770,8 +2021,9 @@ class RoutedDecoderLM(nn.Module):
             (), jnp.int32)
         linear = (self.linear_heads, self.linear_head_dim,
                   self.linear_conv_taps, self.linear_decay_floor)
+        chosen, chosen_rows, picked_rows = None, 0, 0
         for i, (window, theta) in enumerate(kinds):
-            x, sizes, rows, copied = RoutedBlock(
+            x, sizes, rows, copied, chosen = RoutedBlock(
                 self.num_heads, self.num_kv_heads, self.head_dim,
                 self.num_experts, self.experts_per_token, self.expert_width,
                 window, theta, self.rms_eps, self.dtype, pdt,
@@ -1798,9 +2050,19 @@ class RoutedDecoderLM(nn.Module):
                     self.lightning[0], self.lightning[1],
                     self.lightning[2] + i, self.lightning[3],
                     float(self.rope_theta))),
-                residual_scale=residual_scale, name=f"layer{i}")(
+                residual_scale=residual_scale,
+                indexer=(None if self.indexer is None
+                         else (kinds_i[i],) + tuple(self.indexer)),
+                name=f"layer{i}")(
                     x, positions, cache_index, block_table, flash_prefill,
-                    window_pages, last_pos)
+                    window_pages, last_pos, chosen)
+            if chosen is not None:
+                # what the layer's queries attended, counted from the
+                # membership it went by: a full layer's own, a shared
+                # layer's the count of the layer it took it from
+                if kinds_i[i] == "full":
+                    chosen_rows = index_select.rows_chosen(chosen, positions)
+                picked_rows = picked_rows + chosen_rows
             if sizes is not None:
                 touched += jnp.sum(sizes > 0, dtype=jnp.int32)
                 load_max += jnp.max(sizes)
@@ -1840,6 +2102,27 @@ class RoutedDecoderLM(nn.Module):
                     dense, 0, block_select.pooled_exist(positions, sizes))),
                 over(dense.astype(jnp.int32)),
                 mixers.count("lightning") * over(1), advanced, streamed])
+        elif self.indexer is not None:
+            # the call's real queries: not tail padding, not an idle row
+            real = jnp.ones((b, s), bool)
+            if last_pos is not None:
+                real &= offset <= last_pos[:, None]
+            if self.decode and block_table is not None:
+                real &= (block_table[:, :1] != 0)
+            top = self.indexer[2]
+            seen = positions + 1
+
+            def over(x):
+                return jnp.sum(jnp.where(real, x, 0), dtype=jnp.int32)
+            counts = jnp.stack([
+                assignments, touched, load_max,
+                kinds_i.count("full") * over(jnp.where(seen > top, seen, 0)),
+                len(kinds) * over(seen),
+                # a query that sees top rows or fewer attends them all,
+                # through the dense kernel where the whole chunk does (a
+                # membership of zeros: nothing was chosen)
+                over(jnp.where(seen <= top, len(kinds) * seen, picked_rows)),
+                over((seen <= top).astype(jnp.int32))])
         elif latent is not None:
             counts = [assignments, touched, load_max,
                       mixers.count("attention") * jnp.sum(live)]

@@ -1097,6 +1097,221 @@ def paged_tile_attention(q, pool_k, pool_v, block_table, index, bits, *,
     return o.reshape(b, s, hq, d).astype(q.dtype), copied
 
 
+# ---------------------------------------------------------------------------
+# latent attention over CHOSEN rows (a lightning indexer's choice)
+# ---------------------------------------------------------------------------
+
+# query heads a grid point of a chunk holds (with the 32 queries of a tile:
+# 1,024 rows, the dense latent call's), and tokens a step streams
+_SPARSE_HEADS = 32
+_SPARSE_BLOCK_TOKENS = 1024
+_SPARSE_VMEM_BYTES = 64 * 2 ** 20
+
+
+def latent_sparse_attention(q, pool, block_table, member, *, value_lanes,
+                            scale):
+    """The oracle of :func:`latent_sparse_chunk` and
+    :func:`latent_sparse_decode`: :func:`latent_paged_attention` where a
+    query attends the rows ``member`` [B, S, L] (bool) names and no other
+    (the causal rule is the membership's: it names no row after the
+    query)."""
+    rows = gather_pages(pool, block_table).astype(jnp.float32)  # [B, L, W]
+    scores = jnp.einsum("bqhw,bkw->bhqk", q.astype(jnp.float32),
+                        rows) * scale
+    scores = jnp.where(member[:, None, :, :rows.shape[1]], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1)
+    o = jnp.einsum("bhqk,bkv->bqhv", probs, rows[..., :value_lanes])
+    return o.astype(q.dtype)
+
+
+def page_stream(tbl_ref, row, pool_hbm, buf, sem, n_pages):
+    """``(start, wait)`` of a double-buffered stream of row ``row``'s first
+    ``n_pages`` pages from ``pool_hbm`` ``[P, page, lanes]``: ``buf`` ``[2,
+    ppb, page, lanes]``, ``sem`` two DMA semaphores.  ``start(step, slot)``
+    starts the copies of step ``step``'s live pages (ids from the table)
+    and zeroes the slot's dead ones — a masked position multiplies finite
+    values —; ``wait(step, slot)`` waits for those copies."""
+    ppb = buf.shape[1]
+
+    def for_pages(lo, hi, fn):
+        def body(p, carry):
+            fn(p)
+            return carry
+        jax.lax.fori_loop(lo, hi, body, 0)
+
+    def live(step):
+        return jnp.clip(n_pages - step * ppb, 0, ppb)
+
+    def copy(step, slot, p):
+        return pltpu.make_async_copy(
+            pool_hbm.at[tbl_ref[row, step * ppb + p]], buf.at[slot, p],
+            sem.at[slot])
+
+    def start(step, slot):
+        def zero(p):
+            buf[slot, p] = jnp.zeros(buf.shape[2:], buf.dtype)
+        for_pages(0, live(step), lambda p: copy(step, slot, p).start())
+        for_pages(live(step), ppb, zero)
+
+    def wait(step, slot):
+        for_pages(0, live(step), lambda p: copy(step, slot, p).wait())
+    return start, wait
+
+
+def tile_rows(x, tile: int):
+    """[B, S, H, ...] -> [B, S / tile, H * tile, ...]: head ``h`` of a
+    tile's query ``i`` in row ``h * tile + i`` — the rows of one grid point
+    of the kernels that take a tile of queries."""
+    b, s, h = x.shape[:3]
+    x = x.reshape((b, s // tile, tile, h) + x.shape[3:])
+    return jnp.swapaxes(x, 2, 3).reshape((b, s // tile, h * tile)
+                                         + x.shape[4:])
+
+
+def _latent_sparse_kernel(tbl_ref, idx_ref, q_ref, k_hbm, m_ref, o_ref, kbuf,
+                          sem, oacc_ref, mx_ref, l_ref, *, scale, tile,
+                          member_block):
+    """Grid (B, tiles of queries, groups of heads): the latent form of
+    :func:`_paged_decode_kernel` — a row's pages streamed block by block
+    into a double buffer, each block ``[T, W]`` the keys of every row of
+    ``q_ref`` and its first lanes their values — where what a query sees of
+    a block is its MEMBERSHIP: ``m_ref`` [blocks, tile rows, member_block]
+    (non-zero: attended), a block of ``member_block`` keys an entry, the
+    queries of the tile its rows.  ``q_ref`` [heads * tile, W] holds head
+    ``h`` of query ``i`` in row ``h * tile + i`` (``tile`` 0: a decode
+    step, one query a row, the membership's first row)."""
+    b, g = pl.program_id(0), pl.program_id(1)
+    _, ppb, page_size, w = kbuf.shape
+    rows_n = q_ref.shape[0]
+    t = ppb * page_size
+    idx = idx_ref[b]
+    n_live = jnp.minimum(idx + ((g + 1) * tile if tile else 1),
+                         tbl_ref.shape[1] * page_size)
+    n_blocks = pl.cdiv(n_live, t)
+    start, wait = page_stream(tbl_ref, b, k_hbm, kbuf, sem,
+                              pl.cdiv(n_live, page_size))
+    oacc_ref[...] = jnp.zeros_like(oacc_ref)
+    mx_ref[...] = jnp.full_like(mx_ref, bw.NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    start(0, 0)
+    per = t // member_block
+
+    def block(blk, carry):
+        slot = blk % 2
+
+        @pl.when(blk + 1 < n_blocks)
+        def _prefetch():
+            start(blk + 1, 1 - slot)
+
+        wait(blk, slot)
+        rows = kbuf.at[slot].reshape(t, w)[...]
+        pieces = [m_ref[blk * per + i][:max(tile, 1)] for i in range(per)]
+        seen = (pieces[0] if per == 1
+                else jnp.concatenate(pieces, axis=1)).astype(jnp.int32) != 0
+        # the membership's blocks past the tile's last visible key were
+        # never written
+        seen &= blk * t + jax.lax.broadcasted_iota(
+            jnp.int32, (1, t), 1) < n_live
+        bias = jnp.where(seen, 0.0, bw.NEG_INF)
+        if tile:
+            bias = jnp.tile(bias, (rows_n // tile, 1))
+        o, m, l = bw.block_accumulate(
+            oacc_ref[...], mx_ref[...][:, 0], l_ref[...][:, 0], q_ref[...],
+            rows, rows[:, :oacc_ref.shape[-1]], scale, bias)
+        oacc_ref[...] = o
+        mx_ref[...] = m[:, None]
+        l_ref[...] = l[:, None]
+        return carry
+
+    jax.lax.fori_loop(0, n_blocks, block, 0)
+    o_ref[...] = bw.finalize(
+        oacc_ref[...], l_ref[...][:, 0]).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "value_lanes", "tile",
+                                             "interpret"))
+def _latent_sparse(q, pool, block_table, index, member, *, scale,
+                   value_lanes, tile, interpret):
+    """q [B, G, H * max(tile, 1), W] (head ``h`` of a tile's query ``i`` in
+    row ``h * tile + i``) -> [B, G, rows, value_lanes]."""
+    b, g, rows, w = q.shape
+    page_size = pool.shape[1]
+    m_pages = block_table.shape[1]
+    _, _, blocks, mrows, mb = member.shape
+    # whole pages, whole entries of the membership
+    hg = min(rows, _SPARSE_HEADS * tile) if tile else rows
+    t = max(page_size, mb)
+    while (t * 2 <= _SPARSE_BLOCK_TOKENS
+           and t * 2 * hg <= _LATENT_SCORE_ELEMS):
+        t *= 2
+    ppb = t // page_size
+    if blocks * mb < -(-m_pages * page_size // t) * t:
+        raise ValueError(
+            f"a membership of {blocks} blocks of {mb} keys does not cover "
+            f"a table of {m_pages} pages of {page_size} in steps of {t}")
+    groups = rows // hg
+    table = jnp.pad(jnp.asarray(block_table, jnp.int32),
+                    ((0, 0), (0, -m_pages % ppb)))
+
+    def spec(lanes):
+        return pl.BlockSpec((None, None, hg, lanes),
+                            lambda b_, g_, h_, tbl, idx: (b_, g_, h_, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(b, g, groups),
+        in_specs=[spec(w), pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec((None, None, blocks, mrows, mb),
+                               lambda b_, g_, h_, tbl, idx:
+                               (b_, g_, 0, 0, 0))],
+        out_specs=spec(value_lanes),
+        scratch_shapes=[
+            pltpu.VMEM((2, ppb, page_size, w), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((hg, value_lanes), jnp.float32),
+            pltpu.VMEM((hg, 1), jnp.float32),
+            pltpu.VMEM((hg, 1), jnp.float32)])
+    return pl.pallas_call(
+        functools.partial(_latent_sparse_kernel, scale=float(scale),
+                          tile=tile, member_block=mb),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, g, rows, value_lanes), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=_SPARSE_VMEM_BYTES),
+        interpret=interpret,
+        name="latent_sparse_chunk" if tile else "latent_sparse_decode",
+    )(table, jnp.asarray(index, jnp.int32), q, pool, member)
+
+
+def latent_sparse_chunk(q, pool, block_table, index, member, *, value_lanes,
+                        scale, interpret: bool = False):
+    """Latent attention of a CHUNK whose queries each attend rows of their
+    own choice: q [B, S, H, W] (absorbed) at positions ``index[b] + i``;
+    pool [P, page, W], the chunk's rows already written; ``member`` [B, S /
+    tile, blocks, tile, member_block] the tiled membership
+    (``index_select.chunk_select``).  A tile of queries streams the row's
+    pages once and masks what each query did not choose: the work is the
+    visible rows', the result the chosen rows'.  Returns [B, S, H,
+    value_lanes]."""
+    b, s, h, _ = q.shape
+    tile = member.shape[3]
+    o = _latent_sparse(tile_rows(q, tile), pool, block_table, index, member,
+                       scale=float(scale), value_lanes=int(value_lanes),
+                       tile=tile, interpret=interpret)
+    o = jnp.swapaxes(o.reshape(b, s // tile, h, tile, value_lanes), 2, 3)
+    return o.reshape(b, s, h, value_lanes)
+
+
+def latent_sparse_decode(q, pool, block_table, index, member, *, value_lanes,
+                         scale, interpret: bool = False):
+    """The same for ONE query a row: q [B, H, W] at position ``index``;
+    ``member`` [B, 1, blocks, rows, member_block] whose first row is the
+    query's (``index_select.decode_select``).  Returns [B, H,
+    value_lanes]."""
+    return _latent_sparse(q[:, None], pool, block_table, index, member,
+                          scale=float(scale), value_lanes=int(value_lanes),
+                          tile=0, interpret=interpret)[:, 0]
+
+
 def paged_flash_decode_reference(q, pool_k, pool_v, block_table, index, *,
                                  scale=None):
     """Plain-JAX block-by-block accumulation — the kernel's portable

@@ -181,9 +181,13 @@ def serving_memory_plan(model, *, num_slots: int, max_seq_len: int,
     per_token = sum(int(np.prod(p.shape[1:])) * np.dtype(p.dtype).itemsize
                     // kv_page_size for p in pools)
     per_page_state = state_bytes_per_page(shapes)
-    # [P, page, H, Dh] a K or V pool; [P, page, W] a pool of latent rows
-    kv_heads = pools[0].shape[2] if kinds[0] == KV_POOL else 1
-    head_dim = pools[0].shape[-1]
+    # [P, page, H, Dh] a K or V pool; [P, page, W] a pool of latent rows:
+    # the geometry reported is the widest pool's (the first of them; an
+    # indexer's keys lie beside wider latent rows)
+    kind, widest = max(zip(kinds, pools),
+                       key=lambda kp: int(np.prod(kp[1].shape[1:])))
+    kv_heads = widest.shape[2] if kind == KV_POOL else 1
+    head_dim = widest.shape[-1]
     if params is None:
         params = jax.eval_shape(
             model.init, jax.random.key(0),

@@ -100,6 +100,10 @@ CACHE_LEAF_KINDS = {
     # bytes a page say, not its second axis
     "pooled_key": KV_POOL,
     "paged_latent": LATENT_POOL,    # [P, page, W]: one row a token
+    # [P, page, D]: a lightning indexer's INDEX KEY, one row a token beside
+    # the layer's latent row (a ``full`` layer alone keeps one): a pool by
+    # tokens, copied, shared and migrated with its page like any other
+    "index_key": LATENT_POOL,
     # [P, W] (ShortConv) or [P, sublanes, W / sublanes] (LinearDelta: the
     # entry as whole tiles of its own): one running entry a page
     "conv_state": PAGE_STATE,
@@ -162,6 +166,16 @@ def state_bytes_per_page(cache_shapes) -> int:
     alone)."""
     return sum(math.prod(p.shape[1:]) * jnp.dtype(p.dtype).itemsize
                for _, p in cache_leaves(cache_shapes, PAGE_STATE))
+
+
+def index_bytes_per_token(cache_shapes) -> int:
+    """Bytes of INDEX KEYS a cached token occupies over the layers that
+    keep them (the ``index_key`` leaves of a model with a lightning
+    indexer; 0 for every other cache)."""
+    return sum(
+        math.prod(leaf.shape[2:]) * jnp.dtype(leaf.dtype).itemsize
+        for path, leaf in jax.tree_util.tree_leaves_with_path(cache_shapes)
+        if getattr(path[-1], "key", None) == "index_key")
 
 
 def _sample(logits, temperature, key):
@@ -537,6 +551,10 @@ class Decoder:
     @property
     def state_bytes_per_page(self) -> int:
         return state_bytes_per_page(self._init_trace[0])
+
+    @property
+    def index_bytes_per_token(self) -> int:
+        return index_bytes_per_token(self._init_trace[0])
 
     @functools.cached_property
     def decode_all_heads(self) -> bool:

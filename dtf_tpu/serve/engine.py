@@ -633,6 +633,10 @@ class ServeEngine:
         # running-state entries (0 for a model whose layers all attend)
         self.metrics.gauge("serve_state_bytes_per_page", unit="bytes").set(
             self.decoder.state_bytes_per_page)
+        # what a token holds beside its cache rows: a lightning indexer's
+        # keys over the layers that choose (0 for every other model)
+        self.metrics.gauge("serve_index_bytes_per_token", unit="bytes").set(
+            self.decoder.index_bytes_per_token)
         # pages that closed windows gave back to their rows (a model that
         # keeps summaries; 0 for every other)
         self._m_pages_reclaimed = self.metrics.gauge(

@@ -48,6 +48,37 @@ WANT = {
 }
 
 
+# GLM-5.2's share (PR 49): 16 held experts of 2,048 behind a width of 6,144,
+# top 8 — the first contraction of 6,144.  A decode step of 16 rows streams
+# weights (the tile of before); a chunk of 2,048 tokens and a prompt's last
+# chunk of 256 bring 128 rows an expert and more, and beside that
+# contraction the guard of ``gmm_tile`` leaves row tiles of 64
+GLM = (16, 8, 6144, 2048)
+WANT_GLM = {
+    16: ((128, 6144, 512), (128, 2048, 768)),
+    256: ((64, 6144, 512), (128, 2048, 1536)),
+    2048: ((64, 6144, 512), (128, 2048, 1536)),
+}
+
+
+def _tile_pr42(pairs, groups, k, n):
+    """``gmm_tile`` as it stood from PR 42 to PR 48: no guard beside a long
+    contraction."""
+    if pairs < rd._GMM_ARITHMETIC_FROM * groups:
+        return _tile_before(k, n)
+    columns = ([c for c in range(n, 0, -128) if n % c == 0]
+               if n % 128 == 0 else [n])
+
+    def widest(tm, tk):
+        return next((c for c in columns
+                     if rd.gmm_blocks_bytes(tm, tk, c) <= rd._GMM_VMEM), 0)
+    tk = k
+    while not widest(64, tk) and tk % 256 == 0:
+        tk //= 2
+    tm = 64 if widest(64, tk) > widest(128, tk) else 128
+    return tm, tk, widest(tm, tk) or columns[-1]
+
+
 def _tile_before(k, n):
     """What ``routed_experts`` handed the kernel in every call up to PR 41."""
     return 128, k, next(c for c in (1280, 768, 512, 256, 128, n)
@@ -70,6 +101,47 @@ def test_the_rule_returns_a_tile_the_kernel_and_the_chip_can_take(
         assert (tm, tk, tn) == _tile_before(kk, n)
     else:
         assert tn >= 1024
+
+
+@pytest.mark.parametrize("product", [0, 1], ids=["gate_up", "down"])
+@pytest.mark.parametrize("config,tokens", list(WANT))
+def test_the_guard_beside_a_long_contraction_moves_no_call_there_was(
+        config, tokens, product):
+    """PR 49's clause in ``gmm_tile`` (half a byte a (row, contraction)
+    element beside the blocks) was written for a contraction of 6,144: every
+    call of the four configurations the benchmark had gets the tile it got
+    from PR 42 to PR 48, so their kernels are the parent's."""
+    groups, k, d, f = CONFIGS[config]
+    kk, n = ((d, 2 * f), (f, d))[product]
+    assert rd.gmm_tile(tokens * k, groups, kk, n) \
+        == _tile_pr42(tokens * k, groups, kk, n)
+
+
+@pytest.mark.parametrize("product", [0, 1], ids=["gate_up", "down"])
+@pytest.mark.parametrize("tokens", list(WANT_GLM))
+def test_a_contraction_of_6144_takes_row_tiles_of_64(tokens, product):
+    """GLM-5.2's calls.  Compiled for the v5e, the kernel alone at (128,
+    6144, 512) is refused for 16.95 MiB: 15.50 of the pipeline's
+    double-buffered blocks — which is what the compiler reports, to the
+    0.01 MiB, wherever those alone pass 16 (19.00 at (256, 6144, 512),
+    28.00 at (128, 6144, 1024), 16.75 at (128, 2048, 1792)) — and 1.45 of
+    accumulator and of the body's own temporaries, where
+    ``gmm_blocks_bytes`` counts 0.31 for them.  The guard is fitted to that
+    refusal, on the safe side of it; what holds it to the compiler is
+    ``tests/test_tpu_lowering.py``'s compile of both GLM bodies."""
+    groups, k, d, f = GLM
+    kk, n = ((d, 2 * f), (f, d))[product]
+    tile = rd.gmm_tile(tokens * k, groups, kk, n)
+    assert tile == WANT_GLM[tokens][product]
+    arithmetic = tokens * k >= rd._GMM_ARITHMETIC_FROM * groups
+    assert arithmetic == (tokens >= 256)
+    if arithmetic and product == 0:
+        assert _tile_pr42(tokens * k, groups, kk, n) == (128, 6144, 512)
+        assert tile[0] == 64
+    elif arithmetic:
+        assert tile == _tile_pr42(tokens * k, groups, kk, n)
+    assert n % tile[2] == 0 and tile[1] == kk
+    assert rd.gmm_blocks_bytes(*tile) <= rd._GMM_VMEM
 
 
 # the longest chunk each of the three cells' engines cuts: 1,024, 2,048

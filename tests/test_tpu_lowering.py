@@ -979,3 +979,86 @@ def test_zero3_step_scatters_every_leaf_and_all_reduces_none(data4):
     assert got["reduce-scatter"]["bytes"] == padded, got
     assert got.get("all-reduce", {"bytes": 0})["bytes"] < 1024, got
     assert got["all-gather"]["ops"] >= len(leaves), got
+
+
+@pytest.fixture(scope="module")
+def indexed_decoder():
+    """GLM-5.2's share of the benchmark at its published widths (published
+    layers 2-6: latent attention of 64 heads over the rows a lightning
+    indexer of 32 x 128 chose, F S S S F; a dense MLP of 12,288, then 16
+    held of 256 experts of 2,048 beside a shared one; 19,360 vocabulary
+    rows; shapes only), 16 slots of 33,536 tokens, pages of 256 in a
+    3,281-page pool."""
+    import json
+    import os
+    from dtf_tpu.models import build_model
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                           "configs", "glm-5.2.json")) as f:
+        cfg = json.load(f)
+    model, _ = build_model(cfg["build_model"]["name"],
+                           num_classes=cfg["num_classes"],
+                           dtype=jnp.bfloat16, **cfg["build_model"]["kwargs"])
+    params = jax.eval_shape(model.clone(use_pallas=False).init,
+                            jax.random.key(0),
+                            jnp.zeros((1, 256), jnp.int32))["params"]
+    return _shapes_only_decoder(model, params, num_slots=16,
+                                max_seq_len=33536, kv_page_size=256,
+                                kv_pool_pages=3281)
+
+
+@pytest.mark.parametrize("body", ["chunk", "decode"])
+def test_indexed_latent_serve_bodies_compile_for_v5e(v5e, indexed_decoder,
+                                                     body):
+    """Row by row, what a body of the decoder with a lightning indexer
+    holds.  The DECODE body: ONE ``index_select`` call a ``full`` layer
+    (two) and ONE ``latent_sparse_decode`` call a layer (five, the three
+    ``shared`` ones over the choice of the layer below), no dense latent
+    call, no ``sort`` and no ``while`` over rows.  A CHUNK of 2,048 tokens:
+    both branches a layer — whole pages through ``paged_flash_decode``
+    while every query sees 2,048 rows or fewer; past that ONE
+    ``index_select`` a ``full`` layer and ONE ``latent_sparse_chunk`` a
+    layer — and the choice crosses layers as tiled membership ``[1, 64,
+    blocks, 32, 512]`` int8: no ``sort`` (``lax.top_k`` lowers to one a
+    query) and no ``while`` over queries.  The pools — five of latent rows,
+    two of index keys — are donated and updated in place."""
+    from dtf_tpu.serve import decode as sd
+    i32, f32 = jnp.int32, jnp.float32
+    dec = indexed_decoder
+    assert not dec.carries_state and not dec.decode_all_heads
+    assert dec.index_bytes_per_token == 2 * 128 * 2
+    if body == "decode":
+        compiled = _compile_decode_body(dec, v5e)
+    else:
+        s, m = jax.ShapeDtypeStruct, dec.pages_per_slot
+        args = _on_chip((dec.params, jax.eval_shape(dec.fresh_cache),
+                         s((1, 2048), i32), s((1, m), i32), s((), i32),
+                         s((), f32),
+                         jax.eval_shape(lambda: sd.position_key(0, 0)),
+                         s((), i32)), v5e)
+        compiled = jax.jit(
+            dec._chunk_impl, donate_argnums=(1,), static_argnums=(8, 9),
+            compiler_options=sd.TPU_BODY_OPTIONS).lower(
+                *args, None, False).compile()
+    text = compiled.as_text()
+    assert _kernel_calls(text, "index_select") == 2
+    # no loop over rows or queries: the only ones are the grouped
+    # product's own search for its groups' tiles
+    assert all("jit(gmm)" in ln for ln in text.splitlines()
+               if " while(" in ln)
+    # the only sorts are the four routers' top 8 of 256 scores a token:
+    # none over a query's keys
+    sorts = [re.search(r"= \(?\w+\[([\d,]+)\]", ln).group(1).split(",")
+             for ln in text.splitlines() if " sort(" in ln]
+    # ... and their pairs' order by expert, one list a routed layer
+    assert sorts and all(len(dims) == 1 or dims[-1] == "256"
+                         for dims in sorts), sorts
+    if body == "decode":
+        assert _kernel_calls(text, "latent_sparse_decode") == 5
+        assert _kernel_calls(text, "paged_flash_decode") == 0
+        assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
+    else:
+        assert _kernel_calls(text, "latent_sparse_chunk") == 5
+        assert _kernel_calls(text, "paged_flash_decode") == 5
+        assert "s8[1,64,72,32,512]" in text
+        # 5.8e9 B of pools are donated and updated in place
+        assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
