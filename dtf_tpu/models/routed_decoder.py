@@ -194,7 +194,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from dtf_tpu.models.transformer import paged_cache_attention
+from dtf_tpu.models.transformer import paged_cache_attention, rows_at
 from dtf_tpu.ops import (block_select, index_select, linear_state,
                          window_summary)
 from dtf_tpu.ops.flash_attention import flash_attention
@@ -1773,7 +1773,10 @@ class RoutedDecoderLM(nn.Module):
     """``__call__(tokens [B, S] int32) -> logits [B, S, vocab]`` f32; in
     decode mode with ``cache_index`` [B], ``block_table`` [B, M] and the
     two statics of ``TransformerLM`` (``flash_prefill``,
-    ``window_pages``), which ``serve.decode.Decoder`` drives alike."""
+    ``window_pages``), which ``serve.decode.Decoder`` drives alike.  With
+    ``head_pos`` [B] int32 (a prefill chunk's sampled offset) the final
+    norm and the head run on that one position a row: logits [B, 1,
+    vocab]."""
 
     vocab_size: int
     num_layers: int = 4
@@ -1952,7 +1955,8 @@ class RoutedDecoderLM(nn.Module):
     @nn.compact
     def __call__(self, tokens, train: bool = False, cache_index=None,
                  block_table=None, flash_prefill: bool = False,
-                 window_pages: Optional[int] = None, last_pos=None):
+                 window_pages: Optional[int] = None, last_pos=None,
+                 head_pos=None):
         del train
         if self.model_axis is not None:
             raise ValueError("the routed decoder has no tensor-parallel "
@@ -2156,6 +2160,8 @@ class RoutedDecoderLM(nn.Module):
         self.sow("stats", "counts", counts,
                  reduce_fn=lambda _, new: new,
                  init_fn=lambda: jnp.zeros((n_counts,), jnp.int32))
+        if head_pos is not None:
+            x = rows_at(x, head_pos)
         x = rms_norm(x, self.param("norm_f",
                                    _norm_init(self.norm_unit_offset),
                                    (self.d_model,), pdt),

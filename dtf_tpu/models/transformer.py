@@ -305,6 +305,23 @@ class Block(nn.Module):
         return x + h
 
 
+def rows_at(x, pos):
+    """x [B, S, d], pos [B] int32 -> [B, 1, d]: row ``pos[b]`` of each
+    ``x[b]`` (clamped into the call, as ``dynamic_slice`` clamps).  What a
+    model's final norm and head take when handed ``head_pos``: both are a
+    position's own, so one row in is that row's logits out.
+
+    The row is read as a sum over S under a mask (exact: one term and
+    zeros), not as a ``dynamic_slice``: the TPU compiler moves a slice back
+    through the residual adds and keeps the last layers' [S, d] products
+    alive to the program's end for it (seven of 16.8e6 B in a 1,024-token
+    chunk of width 4,096); a reduction ends the last layer's product as the
+    norms' sums of squares end every other layer's."""
+    pos = jnp.clip(pos, 0, x.shape[1] - 1)
+    hit = jnp.arange(x.shape[1])[None, :, None] == pos[:, None, None]
+    return jnp.sum(jnp.where(hit, x, 0), axis=1, keepdims=True)
+
+
 class TransformerLM(nn.Module):
     """Next-token LM.  __call__(tokens [B, S] int32, train) -> logits
     [B, S, vocab] (f32 — softmax precision, like the ResNets' fp32
@@ -345,6 +362,9 @@ class TransformerLM(nn.Module):
     # parallelism: heads + KV pool sharded over 'model', run inside
     # shard_map); incompatible with seq_axis sharding and shard_vocab.
     # decode=True requires both page fields.
+    # `head_pos` [B] int32 (a prefill chunk's sampled offset within the
+    # call): the final norm and the head run on that one position a row
+    # and the logits are [B, 1, vocab].
     decode: bool = False
     kv_page_size: Optional[int] = None
     kv_pool_pages: Optional[int] = None
@@ -352,7 +372,7 @@ class TransformerLM(nn.Module):
     @nn.compact
     def __call__(self, tokens, train: bool = False, cache_index=None,
                  block_table=None, flash_prefill: bool = False,
-                 window_pages: Optional[int] = None):
+                 window_pages: Optional[int] = None, head_pos=None):
         del train  # no dropout/BN: LN only, same train/eval behavior
         b, s_local = tokens.shape
         x = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype,
@@ -398,6 +418,8 @@ class TransformerLM(nn.Module):
                       kv_pool_pages=self.kv_pool_pages,
                       name=f"block{i}")(x, cache_index, block_table,
                                         flash_prefill, window_pages)
+        if head_pos is not None:
+            x = rows_at(x, head_pos)
         x = nn.LayerNorm(dtype=self.dtype, name="ln_f")(x)
         vocab = self.vocab_size
         if self.shard_vocab and self.model_axis is not None:

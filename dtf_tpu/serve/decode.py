@@ -473,13 +473,16 @@ class Decoder:
         return P(None, None, self._model_axis, None)
 
     def _apply_model(self, params, cache, tokens, index, block_table,
-                     flash_prefill, window_pages, last_pos=None):
+                     flash_prefill, window_pages, last_pos=None,
+                     head_pos=None):
         """model.apply with mutable cache — direct on one device,
         shard_mapped over the mesh under TP (tokens/index/tables
         replicated in, logits replicated out, cache specs on the pool
         head dim; flash_prefill/window_pages are trace-time statics
         closed over).  ``last_pos`` [B] goes to a model whose cache
-        carries state (``carries_state``) and to no other."""
+        carries state (``carries_state``) and to no other; ``head_pos``
+        [B] (a chunk's sampled offset: logits [B, 1, V] of that position
+        alone) to every model, from the chunk body and no other."""
         if self.tp > 1 and last_pos is not None:
             raise NotImplementedError(
                 "a cache that carries state a page has no tensor-parallel "
@@ -491,23 +494,24 @@ class Decoder:
                 {"params": params, "cache": cache}, tokens,
                 cache_index=index, block_table=block_table,
                 flash_prefill=flash_prefill, window_pages=window_pages,
-                mutable=["cache", "stats"], **state_kw)
+                head_pos=head_pos, mutable=["cache", "stats"], **state_kw)
         from jax.sharding import PartitionSpec as P
 
         cspec = jax.tree_util.tree_map(lambda _: self._cache_pspec(),
                                        cache)
 
-        def body(p, c, t, i, bt):
+        def body(p, c, t, i, bt, hp):
             return self.model.apply(
                 {"params": p, "cache": c}, t, cache_index=i,
                 block_table=bt, flash_prefill=flash_prefill,
-                window_pages=window_pages, mutable=["cache"])
+                window_pages=window_pages, head_pos=hp, mutable=["cache"])
 
         return jax.shard_map(
             body, mesh=self.mesh,
-            in_specs=(self._pspecs, cspec, P(), P(), P()),
+            in_specs=(self._pspecs, cspec, P(), P(), P(), P()),
             out_specs=(P(), {"cache": cspec}),
-            check_vma=False)(params, cache, tokens, index, block_table)
+            check_vma=False)(params, cache, tokens, index, block_table,
+                             head_pos)
 
     @functools.cached_property
     def _init_trace(self):
@@ -652,15 +656,15 @@ class Decoder:
         position) is a traced scalar; ``window_pages`` (pages covering
         [0, start + C), gather path — None under the kernel) and
         ``flash_prefill`` (start == 0: causal-only via the flash
-        kernel) are static.  Returns (token, cache, sampled-position
-        logits)."""
+        kernel) are static.  The model computes its head at ``sample_pos``
+        alone.  Returns (token, cache, sampled-position logits)."""
+        pos = jnp.reshape(sample_pos, (1,))
         logits, mut = self._apply_model(
             params, cache, tokens,
             jnp.broadcast_to(jnp.asarray(start, jnp.int32), (1,)),
             block_row, flash_prefill, window_pages,
-            jnp.reshape(sample_pos, (1,)) if self.carries_state else None)
-        last = jax.lax.dynamic_slice_in_dim(
-            logits[0], sample_pos, 1, axis=0)[0]           # [V]
+            pos if self.carries_state else None, pos)
+        last = logits[0, 0]                                # [V]
         tok = _sample(last, temperature, key)
         return tok, mut["cache"], last, mut.get("stats")
 

@@ -710,6 +710,52 @@ def test_window_summary_serve_bodies_compile_for_v5e(v5e, summary_decoder,
     assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
 
 
+def test_chunk_body_computes_no_head_over_the_chunk_for_v5e(v5e):
+    """A continuation chunk of 384 tokens of a toy routed decoder (two
+    layers of width 256, 4,096 vocabulary rows; shapes only): the optimized
+    program holds no ``convolution`` or ``dot`` whose result is 384 rows by
+    4,096 columns, since the model takes the sampled row before its head.
+    The compiler does not do that by itself: the model's head at every
+    position with the row sliced after it, which is what the body was,
+    keeps the whole product."""
+    from dtf_tpu.models import build_model
+    from dtf_tpu.serve import decode as sd
+    i32, f32, c, vocab = jnp.int32, jnp.float32, 384, 4096
+    model, _ = build_model(
+        "routed_decoder", num_classes=vocab, dtype=jnp.bfloat16,
+        num_layers=2, d_model=256, num_heads=4, num_kv_heads=2, head_dim=64,
+        num_experts=4, experts_per_token=2, expert_width=128,
+        max_seq_len=1024, param_dtype="bfloat16")
+    params = jax.eval_shape(model.clone(use_pallas=False).init,
+                            jax.random.key(0), jnp.zeros((1, 64), i32)
+                            )["params"]
+    dec = _shapes_only_decoder(model, params, num_slots=4, max_seq_len=1024,
+                               kv_page_size=64, kv_pool_pages=33)
+    s, m = jax.ShapeDtypeStruct, dec.pages_per_slot
+    args = _on_chip((dec.params, jax.eval_shape(dec.fresh_cache),
+                     s((1, c), i32), s((1, m), i32), s((), i32), s((), f32),
+                     jax.eval_shape(lambda: sd.position_key(0, 0)),
+                     s((), i32)), v5e)
+
+    def sliced_after(params, cache, tokens, block_row, sample_pos, *_):
+        logits, mut = dec._apply_model(
+            params, cache, tokens, jnp.zeros((1,), i32), block_row, False,
+            None)
+        return jax.lax.dynamic_slice_in_dim(logits[0], sample_pos, 1)[0], mut
+
+    def products_over_the_chunk(fn, *statics):
+        text = jax.jit(
+            fn, donate_argnums=(1,), static_argnums=(8, 9)[:len(statics)],
+            compiler_options=sd.TPU_BODY_OPTIONS).lower(
+                *args, *statics).compile().as_text()
+        results = re.findall(r"= \w+\[([\d,]+)\]\S* (?:convolution|dot)\(",
+                             text)
+        return [r for r in results
+                if {str(c), str(vocab)} <= set(r.split(","))]
+    assert products_over_the_chunk(dec._chunk_impl, None, False) == []
+    assert products_over_the_chunk(sliced_after) != []
+
+
 def _kernel_calls(text, name):
     """Custom calls of the Pallas kernel ``name`` in an optimized HLO."""
     return len(re.findall(r"^\s*%" + name + r"(\.\d+)? = [^\n]*custom-call\(",
