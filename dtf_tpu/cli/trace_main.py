@@ -6,6 +6,9 @@ prints per-span-name timing aggregates (count, total, mean, p50/p99,
 max), event counts, and every anomaly record.  A span that keeps laps
 (the serving engine's ``serve_iteration``) also gets one line a lap name:
 its total seconds, its share of the span's, and how many spans closed it;
+one line for the clock anchors a traced engine ran under it (the
+``clock_anchor`` spans, whose round trips lie inside ``launch_args`` laps:
+how many, their total and their median);
 and one line for each whole-number attribute such spans carry (the turn's
 counts: rows by phase, admissions, retirements, queue depth, pages in use,
 and the ordinals of its launches): spans carrying it, total, mean, min, max.
@@ -224,6 +227,7 @@ def summarize(files: List[str]) -> dict:
     spans: Dict[str, Histogram] = {}
     laps: Dict[str, Dict[str, List[float]]] = {}   # span → lap → [s, spans]
     counts: Dict[str, Dict[str, List[int]]] = {}    # span → attribute → values
+    anchors: Dict[str, List[float]] = {}    # lap-keeping span → anchors' dur_s
     events: CCounter = CCounter()
     anomalies: List[dict] = []
     ranks = set()
@@ -239,6 +243,9 @@ def summarize(files: List[str]) -> dict:
                 if h is None:
                     h = spans[name] = Histogram(name, unit="s")
                 h.observe(float(rec.get("dur_s", 0.0)))
+                if name == "clock_anchor" and "parent" in rec:
+                    anchors.setdefault(rec["parent"], []).append(
+                        float(rec.get("dur_s", 0.0)))
                 closed = set()
                 for lap, seconds in rec.get("laps", ()):
                     row = laps.setdefault(name, {}).setdefault(lap,
@@ -277,6 +284,11 @@ def summarize(files: List[str]) -> dict:
                       "share": sec / total if total else 0.0}
                 for lap, (sec, n) in sorted(laps[name].items(),
                                             key=lambda kv: -kv[1][0])}
+        if name in anchors:
+            durs = sorted(anchors[name])
+            span_rows[name]["anchors"] = {
+                "count": len(durs), "total_s": sum(durs),
+                "median_s": durs[len(durs) // 2]}
         if name in counts:
             span_rows[name]["counts"] = {
                 key: {"spans": len(v), "total": sum(v),
@@ -311,6 +323,11 @@ def print_summary(summary: dict, allowed=()) -> None:
             for lap, row in r.get("laps", {}).items():
                 print(f"  {'lap ' + lap:<22}{row['spans']:>8}"
                       f"{row['total_s']:>10.3f}{row['share']:>10.1%}")
+            if "anchors" in r:
+                row = r["anchors"]
+                print(f"  {'clock anchors':<22}{row['count']:>8}"
+                      f"{row['total_s']:>10.3f}  median "
+                      f"{1e3 * row['median_s']:.3f} ms, inside launch_args")
             for key, row in r.get("counts", {}).items():
                 print(f"  {'count ' + key:<22}{row['spans']:>8}"
                       f"  total {row['total']}  mean {row['mean']:.2f}"

@@ -8,9 +8,13 @@ in their own ad-hoc format.  This package gives every subsystem one
 structured, near-zero-overhead vocabulary:
 
   trace     — JSONL span/event emitter (step, compile, checkpoint
-              save/restore, PS push/pull, serve batch-form/
-              prefill-chunk/decode) with wall time, rank, and step
-              attributes.  Summarize with
+              save/restore, PS push/pull; on the serving path one
+              `serve_iteration` a turn of the engine thread, cut into
+              named laps, with `serve_batch_form`, `serve_prefill_chunk`,
+              `serve_decode`, `serve_close_window` and `clock_anchor`
+              under it, each launch's span naming its program and its
+              ordinal) with wall time, rank, and step attributes.
+              Summarize with
               `python -m dtf_tpu.cli.trace_main <trace_dir>`.
   registry  — counters / gauges / histograms with percentile
               snapshots, exported in the existing BenchmarkMetric

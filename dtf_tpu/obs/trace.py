@@ -9,7 +9,10 @@ record kinds:
             the enclosing spans visible up to the crash point).  A span
             opened with :func:`lap_span` also keeps LAPS: "laps":
             [[name, seconds], ...], contiguous from ``ts``, in order,
-            adding up to ``dur_s`` — see :class:`_Span`
+            adding up to ``dur_s`` — see :class:`_Span`, which also
+            says which clock a span reads (ONE for a lap-keeping span
+            and every span opened under it: the serving engine's turn,
+            its launches and its ``clock_anchor`` stamps)
   event   — a point-in-time marker: {"kind":"event","name":...,
             "ts":..., ...attrs} (e.g. "heartbeat")
   anomaly — an event that means the run is unhealthy: same shape with
@@ -147,10 +150,17 @@ class _Span:
     reads ONE clock: ``ts`` is ``time.time()`` at entry as on every span,
     the laps and ``dur_s`` are ``time.perf_counter()`` offsets from that
     entry.  While it is open it is its thread's lap-keeping span, which
-    the module's :func:`lap` marks from any callee."""
+    the module's :func:`lap` marks from any callee — and THE CLOCK OF
+    EVERY SPAN OPENED UNDER IT on that thread: such a span's ``ts`` is the
+    lap-keeping span's ``ts`` plus a ``perf_counter`` offset and its
+    ``dur_s`` a ``perf_counter`` difference, so a launch's span, a
+    ``clock_anchor``'s two edges and the laps round them are one clock's
+    readings to the microsecond (a reader of the device's timeline adds
+    ONE offset to all of them).  A plain span under no such span reads
+    ``time.time()`` at both ends."""
 
     __slots__ = ("_tracer", "name", "attrs", "t0", "span_id",
-                 "_laps", "_mark", "_outer")
+                 "_laps", "_mark", "_outer", "_p0")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any],
                  laps: bool = False):
@@ -162,12 +172,19 @@ class _Span:
     def __enter__(self):
         self.span_id = self._tracer._next_span_id()
         self._tracer._stack().append((self.name, self.span_id))
-        self.t0 = time.time()
+        local = self._tracer._local
+        outer = getattr(local, "lap_span", None)
         if self._laps is not None:
-            self._mark = time.perf_counter()
-            local = self._tracer._local
-            self._outer = getattr(local, "lap_span", None)
+            self.t0 = time.time()
+            self._p0 = self._mark = time.perf_counter()
+            self._outer = outer
             local.lap_span = self
+        elif outer is not None:
+            self._p0 = time.perf_counter()
+            self.t0 = outer.t0 + (self._p0 - outer._p0)
+        else:
+            self._p0 = None
+            self.t0 = time.time()
         return self
 
     def lap(self, name: str) -> None:
@@ -179,7 +196,8 @@ class _Span:
 
     def __exit__(self, exc_type, exc, tb):
         if self._laps is None:
-            dur = time.time() - self.t0
+            dur = (time.time() - self.t0 if self._p0 is None
+                   else time.perf_counter() - self._p0)
         else:
             self.lap("rest")
             self._tracer._local.lap_span = self._outer

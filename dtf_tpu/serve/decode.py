@@ -268,6 +268,30 @@ def _step_operands(packed, rows: int):
                 jax.lax.bitcast_convert_type(seeds, jnp.uint32), index))
 
 
+@jax.jit
+def _clock_anchor(x):
+    """The stamp a traced engine lays in both clocks at once
+    (``Decoder.clock_anchor``): one resident ``[8, 128]`` float32 operand,
+    no parameter, no cache leaf.  The function's name is the compiled
+    program's — ``jit__clock_anchor`` on a profile's module line, where a
+    reader counts its runs — and is kept for that.  So is the arithmetic:
+    the compile cache finds a program by its computation, not its name,
+    and an executable found there bears the name of whoever compiled it
+    first — as ``x + 1`` this ran under the name of the benchmark's window
+    marker, a jitted lambda of the same shape (my chip run, PR 52)."""
+    return x * 0.5 + 1.0
+
+
+def program_name(fn) -> str:
+    """What a profile's module line calls the runs of ``fn``, an AOT
+    executable or the jitted function it stands in for: the compiled
+    module's own name, read off the executable (``jit_<function>``)."""
+    try:
+        return fn.runtime_executable().hlo_modules()[0].name
+    except AttributeError:      # the plain jit path: no executable in hand
+        return f"jit_{fn.__name__}"
+
+
 # How the TPU's compiler is asked to build the two bodies.  Left alone it
 # prefetches a body's weights into VMEM with each prefetch cut in four
 # slices: 339 async copies a decode step of the 1.3B dense block, two
@@ -351,6 +375,12 @@ class Decoder:
         # decode_step alone (what the engine puts on that call's span)
         self.windows_closed = 0
         self.last_closed = 0
+        # traced runs only: the clock anchor's executable and operand
+        # (clock_anchor), how many ran, and each body's name on a profile's
+        # module line (program)
+        self._anchor = None
+        self.anchors_run = 0
+        self._program_names = {}
         self.pool_pages = int(
             kv_pool_pages or 1 + self.num_slots * window_summary.row_pages(
                 model, self.max_seq_len, self.page_size))
@@ -434,7 +464,9 @@ class Decoder:
         (``starts`` [B] positions, ``block_rows`` [B, M]): one launch of
         the ``serve_close_window`` program a row, before the body's, each a
         ``compact`` lap of the engine's turn (``so_far``: the lap that
-        takes the caller's time up to the first of them).  ``last_closed``
+        takes the caller's time up to the first of them) and, traced, a
+        ``serve_close_window`` span round the launch (``close``: its
+        ordinal, ``windows_closed``; ``program``; ``row``).  ``last_closed``
         is what was launched here, the one count of it."""
         window = self.summary[0]
         rows = np.flatnonzero((starts > 0) & (starts % window == 0))
@@ -442,18 +474,69 @@ class Decoder:
         if rows.size and so_far:
             trace.lap(so_far)
         for r in rows:
-            dyn = (self.params, cache,
-                   jnp.asarray(block_rows[r], jnp.int32),
-                   jnp.asarray(starts[r] // window - 1, jnp.int32))
-            fn = self._execs.get("close")
-            if fn is None:
-                fn = (self._aot("serve_close_window", self._close, dyn)
-                      or self._close)
-                self._execs["close"] = fn
-            cache = fn(*dyn)
+            attrs = {}
+            if trace.enabled():
+                attrs = dict(close=self.windows_closed + 1, row=int(r))
+            # the span holds the launch whole: its two scalar operands'
+            # own small program runs before the close's
+            with trace.span("serve_close_window", **attrs) as span:
+                dyn = (self.params, cache,
+                       jnp.asarray(block_rows[r], jnp.int32),
+                       jnp.asarray(starts[r] // window - 1, jnp.int32))
+                fn = self._execs.get("close")
+                if fn is None:
+                    fn = (self._aot("serve_close_window", self._close, dyn)
+                          or self._close)
+                    self._execs["close"] = fn
+                cache = fn(*dyn)
+                if attrs:
+                    span.attrs["program"] = self.program("close")
             self.windows_closed += 1
             trace.lap("compact")
         return cache
+
+    # -- what a traced run says of its launches -------------------------
+    def program(self, body: str) -> str:
+        """The name on a profile's module line of the runs of ``body``
+        ("decode", "chunk" — one name whatever the chunk's shape —
+        "close", or the clock's "anchor"), for the span of a launch to
+        carry: a reader pairs the
+        n-th run of that name with the n-th such span.  Read once off an
+        executable of the body that ``_execs`` holds (the anchor's: the one
+        ``clock_anchor`` built); before there is one, the jitted function's
+        own name."""
+        name = self._program_names.get(body)
+        if name is None and body == "anchor":
+            name = self._program_names[body] = program_name(
+                self._anchor_program()[0])
+        if name is None:
+            compiled = [fn for key, fn in self._execs.items()
+                        if (key if isinstance(key, str) else key[0]) == body
+                        and hasattr(fn, "runtime_executable")]
+            if not compiled:
+                return program_name({"decode": self._decode,
+                                     "chunk": self._chunk,
+                                     "close": self._close}[body])
+            name = self._program_names[body] = program_name(compiled[0])
+        return name
+
+    def clock_anchor(self) -> None:
+        """Run the anchor program (``_clock_anchor``) and wait for it
+        (``anchors_run`` counts them).  The caller reads the host's clock on both
+        sides: the run on the device's timeline lies between the two
+        readings, whatever else the two clocks owe each other.  Built at
+        its first call — which ``decode_step`` makes where it compiles the
+        decode body, in a traced run's warm-up — and never in an untraced
+        run, which calls neither."""
+        fn, x = self._anchor_program()
+        fn(x).block_until_ready()
+        self.anchors_run += 1
+
+    def _anchor_program(self):
+        if self._anchor is None:
+            x = jnp.zeros((8, 128), jnp.float32)
+            self._anchor = (_clock_anchor.lower(x).compile(), x)
+        return self._anchor
 
     # -- tensor-parallel plumbing --------------------------------------
     def _shard_params(self, params):
@@ -768,6 +851,8 @@ class Decoder:
             fn = (self._aot("serve_decode_step", self._decode, dyn)
                   or self._decode)
             self._execs["decode"] = fn
+            if trace.enabled():
+                self.clock_anchor()     # compiled here, with the body
         toks, cache, last, self.last_stats = fn(*dyn)
         trace.lap("launch_call")
         return toks, cache, last

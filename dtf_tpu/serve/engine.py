@@ -115,6 +115,12 @@ log = logging.getLogger("dtf_tpu")
 # size, and a page multiple at ANY page size)
 DEFAULT_PREFILL_PAGES = 4
 
+# a TRACED engine stamps the two clocks against each other (_clock_anchor)
+# before every ANCHOR_TURNS-th decode launch: one anchor a 0.3–0.8 s of a
+# busy window, each a tiny synced program (PERF.md §6 PR 52 has what it
+# costs).  An untraced engine never does
+ANCHOR_TURNS = 32
+
 
 def chunk_plan(plen: int, prefill_chunk: int, page_size: int,
                start: int = 0):
@@ -653,8 +659,7 @@ class ServeEngine:
         self._m_step_time = self.metrics.histogram("serve_decode_step_s",
                                                    unit="s")
         # 1 where the decode body's paged kernel scores a block all
-        # heads at once, 0 head by head (Decoder.decode_all_heads);
-        # also on the serve_decode spans, for the reader of a trace
+        # heads at once, 0 head by head (Decoder.decode_all_heads)
         self.metrics.gauge("serve_paged_decode_allheads", unit="bool").set(
             int(self.decoder.decode_all_heads))
         # pages the paged decode kernel is asked to attend in a step:
@@ -1062,7 +1067,12 @@ class ServeEngine:
         ``gauges``, then _step's ``build``, ``launch_args`` and
         ``launch_call`` (closed by Decoder.decode_step), ``ready`` and
         ``emit``.  Traced, it also carries the turn's counts
-        (_iteration_counts)."""
+        (_iteration_counts), and a traced run adds no lap name of its own
+        but two stretches to these: a non-final chunk's wait for the
+        model's counts is a ``chunk_sync`` (_model_counts), and every
+        ANCHOR_TURNS-th ``launch_args`` begins with a clock anchor's round
+        trip (_clock_anchor: the ``clock_anchor`` span inside the lap says
+        how much of it)."""
         rec = trace.enabled()
         if rec:
             before = (self._m_completed.value, self._m_cancelled.value,
@@ -1361,9 +1371,16 @@ class ServeEngine:
         t0 = time.perf_counter()
         pre_compiled = self.decoder.compiled_count
         self._chunk_launches += 1
+        attrs = _tctx(req.trace_id, req.trace_parent)
+        if trace.enabled():
+            # which launch this is, of which program, and how much of the
+            # padded chunk is prompt: a reader of the device's timeline
+            # pairs the program's n-th run with ``chunk``
+            attrs.update(chunk=self._chunk_launches,
+                         program=self.decoder.program("chunk"),
+                         real_tokens=sample_pos + 1)
         with trace.span("serve_prefill_chunk", slot=slot_idx, start=start,
-                        tokens=clen, last=is_last,
-                        **_tctx(req.trace_id, req.trace_parent)) as span:
+                        tokens=clen, last=is_last, **attrs) as span:
             tok, self._cache, _ = self.decoder.prefill_chunk(
                 self._cache, slot.prompt_padded[start:start + clen],
                 slot.block_row, start, sample_pos, req.temperature,
@@ -1411,7 +1428,12 @@ class ServeEngine:
         into one host array and sent through the one operands program) and
         ``launch_call`` (the body's call returning; Decoder.decode_step
         closes both), ``ready`` (blocked on the step's tokens: the
-        device's time), ``emit`` (the walk after)."""
+        device's time), ``emit`` (the walk after).  Traced, the
+        ``serve_decode`` span says what it launched (``step``: the
+        launch's ordinal, the turn's; ``program``: the body's name on a
+        profile's module line; ``rows`` in phase decode and
+        ``context_tokens``, the positions their queries see), and every
+        ANCHOR_TURNS-th launch is preceded by a clock anchor."""
         now = time.perf_counter()
         if self._last_step_t is not None:
             self._m_decode_gap.observe(now - self._last_step_t)
@@ -1437,13 +1459,20 @@ class ServeEngine:
                     and s.handle.request.trace_id]
             if tids:
                 attrs["traces"] = tids
-            attrs["allheads"] = int(self.decoder.decode_all_heads)
+            decoding = [s.index for s in self._slots
+                        if s is not None and s.phase == "decode"]
+            attrs.update(step=self._step_launches + 1,
+                         program=self.decoder.program("decode"),
+                         rows=len(decoding),
+                         context_tokens=sum(decoding) + len(decoding))
         self._m_live_pages.observe(
             int((self.decoder.table_index(index) // self.page_size
                  + 1).sum()))
         pre_compiled = self.decoder.compiled_count
         self._step_launches += 1
         trace.lap("build")
+        if trace.enabled() and self._step_launches % ANCHOR_TURNS == 0:
+            self._clock_anchor()
         with trace.span("serve_decode", **attrs) as span:
             out, self._cache, _ = self.decoder.decode_step(
                 self._cache, tokens, index, temps, seeds=seeds,
@@ -1485,6 +1514,30 @@ class ServeEngine:
         trace.lap("emit")
         self._last_step_t = time.perf_counter()
 
+    def _clock_anchor(self):
+        """One stamp in both clocks (traced runs only): a tiny program
+        launched AND waited for under a ``clock_anchor`` span (``n``: its
+        ordinal; ``program``: its name on a profile's module line), so
+        that the run lies between the span's two edges whatever else the
+        host's clock and the device's owe each other — the offset between
+        them to the program's round trip, with no appeal to the order the
+        engine does things in.  Both edges are readings of the turn's own
+        clock (obs/trace.py ``_Span``), the one its laps are offsets on.
+
+        WHERE: after ``build``, inside the turn's ``launch_args`` lap and
+        before the step's operands are sent — the synchronous loop holds
+        the last step's tokens and the chip is idle unless a chunk of this
+        turn is still running (the anchor then waits behind it and bounds
+        loosely; the window's other anchors bound tightly).  Not at the
+        turn's end, where the chip is as idle: the benchmark's causal join
+        (``benchmark/readers/host_laps.py`` ``pair``) holds the first
+        program to run after a body to start no sooner than the first
+        ``chunk_host`` or ``launch_args`` lap after that body's ``ready``,
+        and a program launched under any other lap name breaks it."""
+        with trace.span("clock_anchor", n=self.decoder.anchors_run + 1,
+                        program=self.decoder.program("anchor")):
+            self.decoder.clock_anchor()
+
     def _model_counts(self, span, out=None):
         """``out`` on the host.  With tracing on, what the model counted
         in the call that produced it (``Decoder.last_stats``: one small
@@ -1494,7 +1547,10 @@ class ServeEngine:
         becomes attributes of ``span``.  In a traced run this makes a
         non-final prefill chunk wait for the device, which an untraced
         one does not: that wait is the turn's ``chunk_sync`` lap (a
-        step's is inside its ``ready``, which _step closes)."""
+        step's is inside its ``ready``, which _step closes).  It is one
+        of the two stretches of lap time only a traced run has; the other
+        is a clock anchor's round trip at the head of every
+        ANCHOR_TURNS-th ``launch_args`` (_clock_anchor)."""
         stats = self.decoder.last_stats if trace.enabled() else None
         if stats is None:
             return None if out is None else np.asarray(out)

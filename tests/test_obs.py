@@ -135,6 +135,58 @@ def test_laps_are_contiguous_ordered_and_add_up_to_the_span(tmp_path):
     assert abs(rec["ts"] - time.time()) < 60
 
 
+def test_a_span_under_a_lap_keeping_span_reads_its_clock(tmp_path,
+                                                        monkeypatch):
+    """A turn's laps and its children's edges are ONE clock's readings:
+    the child's ``ts`` is the outer ``ts`` plus a ``perf_counter`` offset,
+    whatever ``time.time()`` does meanwhile; a span under no lap-keeping
+    span reads ``time.time()`` as before."""
+    t = trace.configure(str(tmp_path), rank=0)
+    with trace.lap_span("turn") as outer:
+        time.sleep(0.001)
+        outer.lap("before")
+        # the wall clock steps (NTP, a suspended VM): the child must not see it
+        real = time.time
+        monkeypatch.setattr(time, "time", lambda: real() + 3600.0)
+        with trace.span("child", n=1):
+            time.sleep(0.002)
+        monkeypatch.setattr(time, "time", real)
+        outer.lap("child")
+    with trace.span("plain"):
+        time.sleep(0.001)
+    (turn,), (child,), (plain,) = (_spans(t, n) for n in ("turn", "child",
+                                                          "plain"))
+    before, during = turn["laps"][0][1], turn["laps"][1][1]
+    assert turn["ts"] + before <= child["ts"]
+    assert child["ts"] + child["dur_s"] <= turn["ts"] + before + during
+    assert 0.002 <= child["dur_s"] <= during
+    assert child["parent_span"] == turn["span_id"] and child["n"] == 1
+    assert plain["dur_s"] >= 0.001 and abs(plain["ts"] - time.time()) < 60
+
+
+def test_trace_main_counts_the_clock_anchors_under_a_turn(tmp_path, capsys):
+    t = trace.configure(str(tmp_path), rank=0)
+    for n in range(5):
+        with trace.lap_span("serve_iteration", step=n + 1) as sp:
+            sp.lap("build")
+            if n % 2 == 0:
+                with trace.span("clock_anchor", n=n // 2 + 1,
+                                program="jit__clock_anchor"):
+                    time.sleep(0.001)
+            sp.lap("launch_args")
+    t.flush()
+    trace.disable()
+    assert trace_main([str(tmp_path), "--json"]) == 0
+    spans = json.loads(capsys.readouterr().out)["spans"]
+    row = spans["serve_iteration"]["anchors"]
+    assert row["count"] == 3 == spans["clock_anchor"]["count"]
+    assert 0.001 <= row["median_s"] <= row["total_s"] \
+        <= spans["serve_iteration"]["laps"]["launch_args"]["total_s"]
+    assert trace_main([str(tmp_path)]) == 0
+    out = capsys.readouterr().out
+    assert "clock anchors" in out and "inside launch_args" in out
+
+
 def test_a_callees_lap_lands_on_its_own_threads_open_span(tmp_path):
     import threading
     t = trace.configure(str(tmp_path), rank=0)

@@ -285,23 +285,26 @@ def test_decode_steps_record_their_live_pages(model_and_params):
         eng.stop(drain=False)
 
 
-def _served(model, params, trace_dir=None):
+SERVED = ((19, 0.0), (3, 0.8), (4, 0.0), (1, 0.0), (19, 0.8), (7, 0.0))
+
+
+def _served(model, params, trace_dir=None, **engine_kw):
     """Six requests through a 4-slot engine (so two wait for a slot and
-    chunks run beside decode steps): the tokens served and, traced, the
-    span records."""
+    chunks run beside decode steps): the tokens served, the decoder and,
+    traced, the span records."""
     from dtf_tpu.obs import trace
     tracer = trace.configure(trace_dir) if trace_dir else None
     try:
-        with paged_engine(model, params) as eng:
+        with paged_engine(model, params, **engine_kw) as eng:
             handles = [eng.submit(np.arange(1, n + 1, dtype=np.int32) % 60,
                                   max_new_tokens=6, temperature=t)
-                       for n, t in ((19, 0.0), (3, 0.8), (4, 0.0), (1, 0.0),
-                                    (19, 0.8), (7, 0.0))]
+                       for n, t in SERVED]
             tokens = [h.result(timeout=300).tokens for h in handles]
     finally:
         trace.disable()
     records = trace.read_records(tracer.path) if tracer else []
-    return tokens, [r for r in records if r.get("kind") == "span"]
+    return tokens, [r for r in records
+                    if r.get("kind") == "span"], eng.decoder
 
 
 def test_an_iteration_is_one_record_of_named_laps(model_and_params,
@@ -311,8 +314,8 @@ def test_an_iteration_is_one_record_of_named_laps(model_and_params,
     older spans its children and otherwise as they were — and the tokens
     served those of an untraced engine."""
     model, params = model_and_params
-    untraced, none = _served(model, params)
-    tokens, spans = _served(model, params, str(tmp_path))
+    untraced, none, _ = _served(model, params)
+    tokens, spans, _ = _served(model, params, str(tmp_path))
     assert tokens == untraced and not none
     turns = [r for r in spans if r["name"] == "serve_iteration"]
     by_id = {r["span_id"]: r for r in turns}
@@ -347,9 +350,13 @@ def test_an_iteration_is_one_record_of_named_laps(model_and_params,
     # each the child of the turn that made it
     plain = {"kind", "name", "ts", "dur_s", "span_id", "parent",
              "parent_span", "rank"}
-    attrs = {"serve_decode": {"allheads", "traces"},
+    # (``allheads`` went with PR 52: no reader; the gauge
+    # ``serve_paged_decode_allheads`` says it once an engine)
+    attrs = {"serve_decode": {"traces", "step", "program", "rows",
+                              "context_tokens"},
              "serve_prefill_chunk": {"slot", "start", "tokens", "last",
-                                     "trace"},
+                                     "trace", "chunk", "program",
+                                     "real_tokens"},
              "serve_batch_form": {"admitted", "traces"}}
     for name, want in attrs.items():
         mine = [r for r in spans if r["name"] == name]
@@ -361,6 +368,73 @@ def test_an_iteration_is_one_record_of_named_laps(model_and_params,
         == len(stepped)
     assert len([r for r in spans if r["name"] == "serve_prefill_chunk"]) \
         == len(chunked)
+
+
+@pytest.mark.parametrize("sharing", [True, False],
+                         ids=["prefixes_shared", "every_prompt_prefilled"])
+def test_a_traced_engine_anchors_its_clock_and_names_its_launches(
+        model_and_params, tmp_path, monkeypatch, sharing):
+    """Tracing ON: before every ANCHOR_TURNS-th decode launch a
+    ``clock_anchor`` span (consecutive ``n``) inside the turn's
+    ``launch_args`` lap, which adds no lap name of its own; a
+    ``serve_decode`` span carries its turn's ``step``, the body's name,
+    the rows in phase decode and the positions their queries see; a
+    ``serve_prefill_chunk`` span its turn's ``chunk`` and the prompt's
+    remainder on a last chunk.  Tracing OFF: the decoder builds no anchor
+    and names no program, and serves the same tokens."""
+    from dtf_tpu.serve import engine
+    model, params = model_and_params
+    monkeypatch.setattr(engine, "ANCHOR_TURNS", 3)
+    untraced, none, off = _served(model, params, prefix_sharing=sharing)
+    assert off._anchor is None and off.anchors_run == 0 and not none
+    assert off._program_names == {}
+    tokens, spans, dec = _served(model, params, str(tmp_path),
+                                 prefix_sharing=sharing)
+    assert tokens == untraced
+    turns = {r["span_id"]: r for r in spans if r["name"] == "serve_iteration"}
+    steps = [r for r in spans if r["name"] == "serve_decode"]
+    chunks = [r for r in spans if r["name"] == "serve_prefill_chunk"]
+    anchors = [r for r in spans if r["name"] == "clock_anchor"]
+    # the anchors: one built with the decode body (its run has no span),
+    # then one before every third launch
+    assert dec.anchors_run == 1 + len(anchors) == 1 + len(steps) // 3
+    assert [r["n"] for r in anchors] == list(range(2, 2 + len(anchors)))
+    order = ["sweep", "admit", "sweep", "gauges", "build", "launch_args",
+             "launch_call", "ready", "emit", "rest"]
+    for r in anchors:
+        turn = turns[r["parent_span"]]
+        assert turn["step"] % 3 == 0 and r["program"] == "jit__clock_anchor"
+        laps = turn["laps"]
+        assert [n for n, _ in laps if not n.startswith("chunk_")] == order
+        at = [n for n, _ in laps].index("launch_args")
+        began = turn["ts"] + sum(s for _, s in laps[:at])
+        assert began <= r["ts"]
+        assert r["ts"] + r["dur_s"] <= began + laps[at][1]
+    # the steps
+    for r in steps:
+        turn = turns[r["parent_span"]]
+        assert r["step"] == turn["step"] and r["rows"] == turn["decoding"]
+        assert r["program"] == dec.program("decode") \
+            == "jit__decode_paged_impl"
+        assert r["rows"] <= r["context_tokens"]
+    # a request of p prompt tokens and m new ones feeds token j at
+    # position p + j - 1, whose query sees p + j positions, j = 1 .. m - 1
+    assert sum(r["context_tokens"] for r in steps) == sum(
+        p + j for p, _ in SERVED for j in range(1, 6))
+    assert sum(r["rows"] for r in steps) == 6 * 5
+    # the chunks
+    lens = sorted(p for p, _ in SERVED)
+    for r in chunks:
+        assert r["chunk"] == turns[r["parent_span"]]["chunk"]
+        assert r["program"] == dec.program("chunk") == "jit__chunk_impl"
+        if r["last"]:
+            assert r["start"] + r["real_tokens"] in lens
+            assert 0 <= r["tokens"] - r["real_tokens"] < PAGE
+        else:
+            assert r["real_tokens"] == r["tokens"]
+    if not sharing:
+        assert sum(r["real_tokens"] for r in chunks) == sum(lens)
+        assert sum(r["last"] for r in chunks) == 6
 
 
 def test_begin_drain_racing_inflight_prefill_chunk(model_and_params):

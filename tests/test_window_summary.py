@@ -255,6 +255,19 @@ def test_a_traced_turn_names_the_closes_the_decoder_launched(toy, tmp_path):
             if n == "compact":
                 assert names[i - 1] in ("launch_args", "chunk_host",
                                         "compact"), names
+    # and one ``serve_close_window`` span a close, round its launch, under
+    # the span of the call that needed it
+    closes = [r for r in spans if r["name"] == "serve_close_window"]
+    assert [r["close"] for r in closes] == list(range(1, closed + 1))
+    by_id = {r["span_id"]: r for r in calls}
+    for r in closes:
+        assert r["program"] == eng.decoder.program("close")
+        assert 0 <= r["row"] < 3
+        call = by_id[r["parent_span"]]
+        assert call["windows_closed"] >= 1
+        assert call["ts"] <= r["ts"] and r["ts"] + r["dur_s"] \
+            <= call["ts"] + call["dur_s"]
+    assert sum(r["parent"] == "serve_prefill_chunk" for r in closes) == 3
 
 
 def test_what_the_kind_refuses(toy):
