@@ -27,8 +27,10 @@ latent attention (:class:`LatentAttention`) in every ATTENTION layer, over
 the whole history: the cache is ONE pool ``[P, page, W]`` a layer whose row is a
 token's normed latent and its one rotary key (``kv_lora_rank +
 qk_rope_head_dim`` values in ``latent_row_lanes`` stored lanes), read once
-a call for scores and values; decode mode attends absorbed, every chunk
-(the first too) through the paged kernel.  ``q_lora_rank`` None beside it
+a call for scores and values; in decode mode a step attends ABSORBED
+through the paged kernel and a chunk long enough to repay it EXPANDED —
+its own keys, and the pages under its start, through ``kv_b`` once and a
+flash forward (:class:`LatentAttention`).  ``q_lora_rank`` None beside it
 projects the queries directly; ``q_head_norm`` norms each query head
 before the rotation; ``attention_head_gate`` scales a head's output by a
 sigmoid gate.
@@ -182,7 +184,9 @@ counts, after the three expert counts, ``INDEX_STATS``.
 
 Every apply also yields counts (``stats_names``; summed over layers) in
 the ``"stats"`` collection when the caller makes it mutable: the serving
-engine puts them on its spans when tracing is on.
+engine puts them on its spans when tracing is on, under
+``call_stats_names`` of the call's shape (an expanded chunk's
+``latent_tokens_expanded`` where a step has ``latent_tokens_read``).
 """
 
 from __future__ import annotations
@@ -199,7 +203,8 @@ from dtf_tpu.ops import (block_select, index_select, linear_state,
                          window_summary)
 from dtf_tpu.ops.flash_attention import flash_attention
 from dtf_tpu.ops.paged_attention import (cached_attention, expand_kv_heads,
-                                         gather_pages,
+                                         gather_pages, latent_expands,
+                                         latent_rows_expanded,
                                          latent_sparse_attention,
                                          latent_sparse_chunk,
                                          latent_sparse_decode,
@@ -1436,13 +1441,23 @@ class LatentAttention(nn.Module):
     k_nope_h,j + q_rope_h,i . k_rope_j) / sqrt(nope + rope)``.
 
     The cache holds, a token a layer, the row ``[c_kv | k_rope | 0]``
-    (bf16 in serving; ``latent_row_lanes`` lanes) and decode mode runs
-    ABSORBED: ``q~_h = q_nope_h kv_b[K, h]^T`` meets ``c_kv`` directly,
+    (bf16 in serving; ``latent_row_lanes`` lanes).  Decode mode takes one
+    of two forms of the same product, by the call's static shape and the
+    layer's widths alone (``ops.paged_attention.latent_expands``).
+    ABSORBED — a decode step, and any call too short to repay an
+    expansion: ``q~_h = q_nope_h kv_b[K, h]^T`` meets ``c_kv`` directly,
     the values are ``c_kv`` itself and ``kv_b[V, h]`` is applied to the
     attended sum — equal in exact arithmetic, 32 heads over one cached
-    row, ``kv_b`` used as held.  Outside decode mode (tests, the toy's
-    teacher-forced forward) K and V of every token are expanded from the
-    definition.
+    row, ``kv_b`` used as held; the cheapest form for ONE query a row
+    (1,088 multiply-adds a (query, key, head) at nope / rope / v 128 / 64
+    / 128 over rank 512).  EXPANDED — a prefill chunk (from 158 queries at
+    those widths): a visible key goes through ``kv_b`` once a chunk and
+    meets the chunk's queries at ``nope + rope + v`` = 320 a head
+    (``latent_chunk_attention``: the chunk against itself causally, the
+    pages under its start in a walk as long as the start asks); the rows
+    are written to the pages as ever.  An ``indexer`` layer is always
+    absorbed.  Outside decode mode (tests, the toy's teacher-forced
+    forward) K and V of every token are expanded from the definition.
 
     ``q_lora_rank`` None: the queries come from ``h`` directly (one
     projection ``q``, no query latent and no norm of one).
@@ -1540,33 +1555,44 @@ class LatentAttention(nn.Module):
             pad = latent_row_lanes(r, dr) - r - dr
             row = jnp.concatenate(
                 [c_kv, k_rope, jnp.zeros((b, s, pad), self.dtype)], -1)
-            q_abs = jnp.einsum("bshn,rhn->bshr", q_nope, w_kvb[..., :dn],
+            if self.indexer is None and latent_expands(
+                    s, hq, row.shape[-1], r, dn, dr, dv):
+                # a chunk: its KEYS go through kv_b, once each, and not
+                # its queries; o comes back a head's own [.., hq, dv]
+                o = paged_cache_attention(
+                    self, jnp.concatenate([q_nope, q_rope], -1), row, None,
+                    cache_index, block_table, scale=scale, value_lanes=r,
+                    expand=(w_kvb, dn))
+            else:
+                q_abs = jnp.einsum("bshn,rhn->bshr", q_nope, w_kvb[..., :dn],
+                                   preferred_element_type=jnp.float32
+                                   ).astype(self.dtype)
+                q_abs = jnp.concatenate(
+                    [q_abs, q_rope, jnp.zeros((b, s, hq, pad), self.dtype)],
+                    -1)
+                if self.indexer is None:
+                    o = paged_cache_attention(
+                        self, q_abs, row, None, cache_index, block_table,
+                        window_pages=window_pages, scale=scale,
+                        value_lanes=r)
+                else:
+                    pool = self.variable(
+                        "cache", "paged_latent", jnp.zeros,
+                        (self.kv_pool_pages, self.kv_page_size,
+                         row.shape[-1]), self.dtype)
+                    o = jnp.zeros(q_abs.shape[:-1] + (r,), self.dtype)
+                    if not self.is_initializing():
+                        pool.value = write_pages(
+                            pool.value, row, block_table, cache_index,
+                            page_aligned=s > 1 and s % self.kv_page_size == 0)
+                        o, chosen = indexed_latent_attention(
+                            q_abs, pool.value, block_table, cache_index,
+                            top=self.indexer[3], picked=picked, chosen=chosen,
+                            scale=scale, value_lanes=r,
+                            use_pallas=self.use_pallas)
+                o = jnp.einsum("bshr,rhv->bshv", o, w_kvb[..., dn:],
                                preferred_element_type=jnp.float32
                                ).astype(self.dtype)
-            q_abs = jnp.concatenate(
-                [q_abs, q_rope, jnp.zeros((b, s, hq, pad), self.dtype)], -1)
-            if self.indexer is None:
-                o = paged_cache_attention(
-                    self, q_abs, row, None, cache_index, block_table,
-                    window_pages=window_pages, scale=scale, value_lanes=r)
-            else:
-                pool = self.variable(
-                    "cache", "paged_latent", jnp.zeros,
-                    (self.kv_pool_pages, self.kv_page_size, row.shape[-1]),
-                    self.dtype)
-                o = jnp.zeros(q_abs.shape[:-1] + (r,), self.dtype)
-                if not self.is_initializing():
-                    pool.value = write_pages(
-                        pool.value, row, block_table, cache_index,
-                        page_aligned=s > 1 and s % self.kv_page_size == 0)
-                    o, chosen = indexed_latent_attention(
-                        q_abs, pool.value, block_table, cache_index,
-                        top=self.indexer[3], picked=picked, chosen=chosen,
-                        scale=scale, value_lanes=r,
-                        use_pallas=self.use_pallas)
-            o = jnp.einsum("bshr,rhv->bshv", o, w_kvb[..., dn:],
-                           preferred_element_type=jnp.float32
-                           ).astype(self.dtype)
         else:
             # the whole sequence at once, from the definition
             kv_all = jnp.einsum("bsr,rhn->bshn", c_kv, w_kvb,
@@ -1903,6 +1929,28 @@ class RoutedDecoderLM(nn.Module):
             return names + LINEAR_STATS
         return STATE_STATS if self.carries_state else names
 
+    def latent_expanded(self, s: int) -> bool:
+        """Whether the latent layers of a decode-mode call of ``s`` queries
+        a row attend EXPANDED (:class:`LatentAttention`: by the call's shape
+        and the layers' widths)."""
+        return (self.decode and self.kv_lora_rank is not None
+                and self.indexer is None and latent_expands(
+                    s, self.num_heads,
+                    latent_row_lanes(self.kv_lora_rank,
+                                     self.qk_rope_head_dim),
+                    self.kv_lora_rank, self.qk_nope_head_dim,
+                    self.qk_rope_head_dim, self.v_head_dim))
+
+    def call_stats_names(self, s: int):
+        """``stats_names`` of a call of ``s`` queries a row: where its
+        latent layers attend expanded they READ no cached row through the
+        paged kernel — the count in that place is of the rows they carried
+        through ``kv_b``, the chunk's own and the cached ones in whole
+        steps of the walk, under a name of its own."""
+        return tuple("latent_tokens_expanded"
+                     if n == "latent_tokens_read" and self.latent_expanded(s)
+                     else n for n in self.stats_names)
+
     def layer_mixers(self):
         """The mixer kind of every layer."""
         lm = tuple(self.layer_mixer)
@@ -2128,6 +2176,9 @@ class RoutedDecoderLM(nn.Module):
                 over(jnp.where(seen <= top, len(kinds) * seen, picked_rows)),
                 over((seen <= top).astype(jnp.int32))])
         elif latent is not None:
+            if self.latent_expanded(s):
+                live = latent_rows_expanded(
+                    cache_index, s, self.kv_page_size, block_table.shape[1])
             counts = [assignments, touched, load_max,
                       mixers.count("attention") * jnp.sum(live)]
             if n_state:

@@ -50,6 +50,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from dtf_tpu.ops.flash_attention import flash_attention
 from dtf_tpu.ops.paged_attention import (expand_kv_heads,
+                                         latent_chunk_attention,
                                          paged_attention_auto, write_pages)
 from dtf_tpu.parallel.collectives import tp_psum, tp_region
 from dtf_tpu.parallel.ring_attention import ring_attention
@@ -80,7 +81,7 @@ def paged_cache_attention(module, q, k, v, cache_index, block_table, *,
                           window: Optional[int] = None,
                           scale: Optional[float] = None,
                           value_lanes: Optional[int] = None,
-                          one_row: bool = False):
+                          one_row: bool = False, expand=None):
     """Write-then-attend against the shared page pool — what every
     decoder family's attention does with the paged cache, called from
     inside the attention module's ``@nn.compact`` body (``module`` owns
@@ -92,7 +93,11 @@ def paged_cache_attention(module, q, k, v, cache_index, block_table, *,
     first ``value_lanes`` lanes are the value, ``scale`` the score's; one
     pool ``[P, page, W]``, written once and read once a call, every chunk
     (the first too) through the paged kernel; returns
-    [B, S, Hq, value_lanes].
+    [B, S, Hq, value_lanes].  ``expand`` ``(kv_b [value_lanes, Hq, nope +
+    Dv], nope)``: the call attends EXPANDED instead
+    (``ops.paged_attention.latent_chunk_attention``) — q [B, S, Hq, nope +
+    rope] is then each head's own query, not absorbed, and the result
+    [B, S, Hq, Dv]; the pool is written as ever.
 
     q [B, S, Hq, Dh]; k, v [B, S, Hkv, Dh] with ``Hq`` a multiple of
     ``Hkv`` (grouped-query heads: query head ``i`` reads KV head
@@ -115,10 +120,17 @@ def paged_cache_attention(module, q, k, v, cache_index, block_table, *,
         paged_latent = module.variable(
             "cache", "paged_latent", jnp.zeros, pool_shape, k.dtype)
         if module.is_initializing():
-            return jnp.zeros(q.shape[:-1] + (value_lanes,), q.dtype)
+            return jnp.zeros(q.shape[:-1] + (
+                value_lanes if expand is None
+                else expand[0].shape[-1] - expand[1],), q.dtype)
         paged_latent.value = write_pages(
             paged_latent.value, k, block_table, cache_index,
             page_aligned=aligned)
+        if expand is not None:
+            return latent_chunk_attention(
+                q, k, expand[0], paged_latent.value, block_table,
+                cache_index, rank=value_lanes, nope=expand[1], scale=scale,
+                use_pallas=module.use_pallas)
         return paged_attention_auto(
             q, paged_latent.value, None, block_table, cache_index,
             window_pages=window_pages, use_pallas=module.use_pallas,

@@ -46,6 +46,13 @@ def block_accumulate(o, m, l, q, k, v, scale: float, bias=None):
                    preferred_element_type=jnp.float32) * scale
     if bias is not None:
         s = s + bias
+    return fold_scores(o, m, l, s, v)
+
+
+def fold_scores(o, m, l, s, v):
+    """:func:`block_accumulate` past its scores: fold ``s`` [.., Sq, Sk]
+    (f32, scaled and biased) and the block's ``v`` into the carry — for a
+    caller whose score is not ONE product of q and k."""
     m_new = jnp.maximum(m, jnp.max(s, axis=-1))
     # m_new can be NEG_INF only while every block so far was fully
     # masked; clamp the subtrahend so exp() sees finite arguments.
@@ -67,6 +74,12 @@ def finalize(o, l):
     """Normalize the accumulated output; fully-masked rows become 0."""
     denom = jnp.where(l == 0.0, 1.0, l)
     return o / denom[..., None]
+
+
+def log_sum_exp(m, l):
+    """The carry's log-sum-exp, ``m + log l``; a row that saw nothing
+    (``m`` NEG_INF, ``l`` 0) reads NEG_INF."""
+    return jnp.maximum(m, NEG_INF) + jnp.log(jnp.where(l == 0.0, 1.0, l))
 
 
 def causal_bias(q_pos, k_pos):

@@ -49,6 +49,12 @@ ceil(contraction/128) passes under every head-packing construction,
 and 2× heads means 2× softmax score elements.  Its size is not
 measured on this installation.
 
+`flash_forward` is a second, forward-only entry for serving (a prefill
+chunk over keys expanded from a latent cache): values of a width of their
+own, a part of the score whose key all heads share, a live key count the
+kernel prefetches, `(o, lse)` out and optionally in.  `flash_attention`
+does not go through it and lowers as it did.
+
 On non-TPU backends `flash_attention` transparently falls back to the
 differentiable `ops.blockwise.blockwise_attention` (same math), so the
 API is portable and testable on the CPU mesh.  Pass
@@ -83,6 +89,13 @@ from dtf_tpu.ops import blockwise as bw
 # ordering, not the absolute times, is what they establish
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
+# flash_forward's blocks: the same 1024 x 1024, swept again with the carry
+# and the second product in the kernel (v5e, a chunk of 2,048 x 32 heads
+# over 8k / 32k keys, docs/pr53_latent_chunk_sweep.jsonl's `tune` lines):
+# 1024 x 1024 4.17 / 14.64 ms, 512 x 1024 4.36 / 15.31, 1024 x 512 5.02 /
+# 18.26, 512 x 512 5.47 / 21.08, 2048 x 512 6.09 / 22.02; 2048 x 1024 runs
+# out of VMEM
+CHUNK_BLOCK_K = 1024
 
 # base-2 softmax folding (bwd kernels): exp(x) lowers to
 # exp2(x·log2 e), so folding log2 e into the score scale deletes one
@@ -206,6 +219,196 @@ def _pallas_forward(q, k, v, scale, causal, block_q, block_k, interpret):
     )(q, k, v)
     o, lse = out
     return o, lse[..., 0]
+
+
+# ---------------------------------------------------------------------------
+# Forward only, serving's own: a prefill chunk's queries against keys
+# EXPANDED from a latent cache (ops.paged_attention.latent_chunk_attention)
+# ---------------------------------------------------------------------------
+
+def _chunk_fwd_kernel(*refs, scale, causal, live, carried):
+    """Grid (B, H, Sq/block_q, Sk/block_k): :func:`_fwd_kernel`'s traversal
+    and carry, for a score of TWO products — a head's own ``q . k`` plus
+    ``q_shared . k_shared``, the second key ONE a token for every head of
+    the row (a latent cache's rotary key: never copied a head) — and values
+    of a width of their own.  ``live``: the first ref is ``kv_len`` [B]
+    (scalar prefetch), row ``b`` sees keys ``< kv_len[b]``: blocks at and
+    past it are skipped (their copies too: the index maps stay on the last
+    live block), the block it cuts is masked.  ``carried``: the carry
+    starts from an earlier call's ``(o, lse)`` — ``o`` normalised is the
+    un-normalised sum at ``m = lse, l = 1`` — so a caller that walks the
+    keys in blocks of its own merges nothing outside the kernel."""
+    refs = list(refs)
+    len_ref = refs.pop(0) if live else None
+    q_ref, qs_ref, k_ref, ks_ref, v_ref = refs[:5]
+    o_in_ref, lse_in_ref = refs[5:7] if carried else (None, None)
+    o_ref, lse_ref, oacc_ref, m_ref, l_ref = refs[-5:]
+    block_q, block_k = q_ref.shape[0], k_ref.shape[0]
+    iq, jk = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(jk == 0)
+    def _init():
+        if carried:
+            oacc_ref[...] = o_in_ref[...]
+            m_ref[...] = lse_in_ref[...]
+            l_ref[...] = jnp.ones_like(l_ref)
+        else:
+            oacc_ref[...] = jnp.zeros_like(oacc_ref)
+            m_ref[...] = jnp.full_like(m_ref, bw.NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
+
+    # run: the block holds a key some query of the tile sees; whole: every
+    # query sees every key of it (no mask: see _fwd_kernel)
+    run = whole = jnp.bool_(True)
+    if causal:
+        run &= jk * block_k <= (iq + 1) * block_q - 1
+        whole &= jk * block_k + block_k - 1 <= iq * block_q
+    if live:
+        n = len_ref[pl.program_id(0)]
+        run &= jk * block_k < n
+        whole &= (jk + 1) * block_k <= n
+
+    def _accumulate(bias):
+        dims = (((1,), (1,)), ((), ()))
+        s = (jax.lax.dot_general(q_ref[...], k_ref[...], dims,
+                                 preferred_element_type=jnp.float32)
+             + jax.lax.dot_general(qs_ref[...], ks_ref[...], dims,
+                                   preferred_element_type=jnp.float32)
+             ) * scale
+        if bias is not None:
+            s = s + bias
+        o, m, l = bw.fold_scores(oacc_ref[...], m_ref[...][:, 0],
+                                 l_ref[...][:, 0], s, v_ref[...])
+        oacc_ref[...] = o
+        m_ref[...] = m[:, None]
+        l_ref[...] = l[:, None]
+
+    @pl.when(run & whole)
+    def _compute_unmasked():
+        _accumulate(None)
+
+    @pl.when(run & jnp.logical_not(whole))
+    def _compute_masked():
+        k_pos = jk * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_k), 1)
+        seen = jnp.bool_(True)
+        if causal:
+            seen &= k_pos <= iq * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, 1), 0)
+        if live:
+            seen &= k_pos < n
+        _accumulate(jnp.where(seen, 0.0, bw.NEG_INF))
+
+    @pl.when(jk == pl.num_programs(3) - 1)
+    def _finalize():
+        o_ref[...] = bw.finalize(oacc_ref[...], l_ref[...][:, 0]
+                                 ).astype(o_ref.dtype)
+        lse_ref[...] = bw.log_sum_exp(m_ref[...], l_ref[...])
+
+
+def flash_forward(q, k, v, *, q_shared, k_shared, scale: float,
+                  causal: bool = False, kv_len=None, carry=None,
+                  use_pallas=None):
+    """Attention forward and nothing else (no gradient is defined), for
+    serving.  Heads-major: q [B, H, Sq, D], k [B, H, Sk, D], v
+    [B, H, Sk, Dv] (``Dv`` need not be ``D``), and a part of the score all
+    heads of a row share one key for: q_shared [B, H, Sq, E], k_shared
+    [B, Sk, E]; ``score = (q . k + q_shared . k_shared) * scale``.
+
+    ``causal``: query ``i`` sees keys ``j <= i`` (a chunk against itself).
+    ``kv_len`` [B] int32: row ``b`` sees keys ``j < kv_len[b]`` — traced, so
+    one compile serves every count; dead blocks cost a grid step and no
+    copy.  ``carry``: ``(o, lse)`` of an earlier call over OTHER keys of
+    the same queries; the result is then the attention over both sets.
+
+    Returns ``(o [B, H, Sq, Dv] float32, lse [B, H, Sq, 1] float32)``, a
+    row that saw nothing as ``(0, NEG_INF)``.  ``use_pallas`` as
+    :func:`flash_attention`: off the TPU the same arithmetic in plain JAX
+    through ``ops.blockwise``, the keys as one block."""
+    if use_pallas is None:
+        use_pallas = jax.default_backend() == "tpu"
+    b, h, sq, _ = q.shape
+    sk, dv = k.shape[2], v.shape[-1]
+    if not use_pallas:
+        return _forward_plain(q, k, v, q_shared, k_shared, scale, causal,
+                              kv_len, carry)
+    block_q = math.gcd(DEFAULT_BLOCK_Q, sq)
+    block_k = math.gcd(CHUNK_BLOCK_K, sk)
+    live, carried = kv_len is not None, carry is not None
+    num_kv = sk // block_k
+
+    def last(b_, i, pre):
+        """The last block of keys the tile ``i`` of row ``b_`` sees."""
+        j = num_kv - 1
+        if causal:
+            j = jnp.minimum(j, ((i + 1) * block_q - 1) // block_k)
+        if live:
+            j = jnp.minimum(j, jnp.maximum(pre[0][b_] - 1, 0) // block_k)
+        return j
+
+    def q_spec(lanes):
+        return pl.BlockSpec((None, None, block_q, lanes),
+                            lambda b_, h_, i, j, *pre: (b_, h_, i, 0))
+
+    def k_spec(lanes):
+        return pl.BlockSpec(
+            (None, None, block_k, lanes),
+            lambda b_, h_, i, j, *pre: (b_, h_,
+                                        jnp.minimum(j, last(b_, i, pre)), 0))
+    ks_spec = pl.BlockSpec(
+        (None, block_k, k_shared.shape[-1]),
+        lambda b_, h_, i, j, *pre: (b_, jnp.minimum(j, last(b_, i, pre)), 0))
+    operands = [q, q_shared, k, k_shared, v]
+    in_specs = [q_spec(q.shape[-1]), q_spec(q_shared.shape[-1]),
+                k_spec(k.shape[-1]), ks_spec, k_spec(dv)]
+    if carried:
+        operands += [carry[0], carry[1]]
+        in_specs += [q_spec(dv), q_spec(1)]
+    if live:
+        operands.insert(0, jnp.asarray(kv_len, jnp.int32))
+    return pl.pallas_call(
+        functools.partial(_chunk_fwd_kernel, scale=scale, causal=causal,
+                          live=live, carried=carried),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=int(live),
+            grid=(b, h, sq // block_q, num_kv),
+            in_specs=in_specs,
+            out_specs=[q_spec(dv), q_spec(1)],
+            scratch_shapes=[pltpu.VMEM((block_q, dv), jnp.float32),
+                            pltpu.VMEM((block_q, 1), jnp.float32),
+                            pltpu.VMEM((block_q, 1), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((b, h, sq, dv), jnp.float32),
+                   jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32)],
+        interpret=use_pallas == "interpret",
+        name="flash_fwd_chunk",
+    )(*operands)
+
+
+def _forward_plain(q, k, v, q_shared, k_shared, scale, causal, kv_len,
+                   carry):
+    """:func:`flash_forward` in plain JAX: the keys as ONE block of the
+    online softmax (``ops.blockwise``)."""
+    sq, sk = q.shape[2], k.shape[2]
+    s = (jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                    preferred_element_type=jnp.float32)
+         + jnp.einsum("bhqe,bke->bhqk", q_shared, k_shared,
+                      preferred_element_type=jnp.float32)) * scale
+    k_pos = jnp.arange(sk, dtype=jnp.int32)
+    seen = jnp.ones((1, 1, sq, sk), bool)
+    if causal:
+        seen &= k_pos <= jnp.arange(sq, dtype=jnp.int32)[:, None]
+    if kv_len is not None:
+        seen &= k_pos < kv_len[:, None, None, None]
+    if carry is None:
+        o = jnp.zeros(q.shape[:3] + v.shape[-1:], jnp.float32)
+        m = jnp.full(q.shape[:3], bw.NEG_INF, jnp.float32)
+        l = jnp.zeros(q.shape[:3], jnp.float32)
+    else:
+        o, m = carry[0], carry[1][..., 0]
+        l = jnp.ones_like(m)
+    o, m, l = bw.fold_scores(o, m, l, s + jnp.where(seen, 0.0, bw.NEG_INF),
+                             v)
+    return bw.finalize(o, l), bw.log_sum_exp(m, l)[..., None]
 
 
 # ---------------------------------------------------------------------------

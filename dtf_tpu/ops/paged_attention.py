@@ -69,6 +69,13 @@ a subset of the row's blocks, the subset a (query, KV head)'s own
 (block-sparse attention): a tile of queries streams the blocks its
 queries read once and each query masks what it did not choose.
 
+Over a LATENT pool (one row ``[c_kv | k_rope | 0]`` a token, every query
+head over it) both take absorbed queries; a prefill chunk long enough to
+repay it (``latent_expands``) attends EXPANDED instead
+(``latent_chunk_attention``): its own rows and, in a walk whose length
+follows the chunk's start, the pages under it go through ``kv_b`` once and
+meet the queries in ``ops.flash_attention.flash_forward``.
+
 ``paged_attention_auto`` dispatches between them: the kernel by default
 on TPU, the gather oracle elsewhere; ``use_pallas="interpret"`` runs
 the kernel through the Pallas interpreter on CPU (how tier-1 pins
@@ -86,6 +93,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from dtf_tpu.ops import blockwise as bw
+from dtf_tpu.ops.flash_attention import flash_forward
 
 
 def cached_attention(q, k, v, mask):
@@ -249,6 +257,112 @@ def latent_paged_attention(q, pool, block_table, index, *, value_lanes,
     probs = jax.nn.softmax(scores, axis=-1)
     o = jnp.einsum("bhqk,bkv->bqhv", probs, rows[..., :value_lanes])
     return o.astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# A chunk over a latent pool, EXPANDED
+# ---------------------------------------------------------------------------
+
+# cached rows a step of the expanded chunk's walk over its prefix gathers
+# and carries through ``kv_b`` at once (whole pages; a row's whole table
+# where that is shorter)
+EXPAND_KEYS = 2048
+
+
+def latent_expands(s: int, heads: int, lanes: int, rank: int, nope: int,
+                   rope: int, v: int) -> bool:
+    """Whether a call of ``s`` queries a row over a latent cache takes the
+    EXPANDED form, by its multiply-adds a visible key: absorbed, every
+    (query, head) meets the stored row over ``lanes`` and its value over
+    ``rank``; expanded, the key goes through ``kv_b`` once (``rank * (nope
+    + v)`` a head) and a (query, head) meets it at ``nope + rope + v``.
+    One query a row is always absorbed."""
+    return (s * heads * (lanes + rank - (nope + rope + v))
+            > rank * heads * (nope + v))
+
+
+def _expand_pages(page_size: int, m_pages: int) -> int:
+    return max(1, min(EXPAND_KEYS // page_size, m_pages))
+
+
+def latent_rows_expanded(index, s: int, page_size: int, m_pages: int):
+    """The rows :func:`latent_chunk_attention` carries through ``kv_b`` for
+    a row whose chunk of ``s`` starts at ``index``: the cached ones in
+    whole steps of its walk, and the chunk's own."""
+    t = _expand_pages(page_size, m_pages) * page_size
+    return -(-index // t) * t + s
+
+
+def latent_chunk_attention(q, rows, w_kvb, pool, block_table, index, *,
+                           rank: int, nope: int, scale: float,
+                           use_pallas=None):
+    """A chunk's attention over a latent pool with its keys EXPANDED: the
+    same product as :func:`latent_paged_attention` over absorbed queries,
+    taken the other way round — where :func:`latent_expands`.
+
+    q [B, S, H, nope + rope] (each head's query as projected, rotated; NOT
+    absorbed); rows [B, S, W] the chunk's own cache rows ``[c_kv | k_rope |
+    0]``, already written to ``pool`` [P, page, W] (write-then-attend;
+    they are attended from here and not read back); w_kvb [rank, H, nope +
+    Dv]; block_table [B, M]; index [B] the chunk's first position, a whole
+    number of pages.  Returns [B, S, H, Dv] in q's dtype.
+
+    The chunk against ITSELF is one causal :func:`flash_forward`.  The
+    keys under ``index`` — every query sees all of them — are walked in
+    steps of ``EXPAND_KEYS``: a step gathers whole pages by the row's
+    table, expands them and continues the same online softmax unmasked
+    (``kv_len``: the step ``index`` cuts).  The walk's length follows the
+    traced ``index``, never the table's: one compile a chunk length, work
+    in proportion to what the chunk sees."""
+    if use_pallas is None:
+        use_pallas = jax.default_backend() == "tpu"
+    return _latent_chunk_walk(
+        q, rows, w_kvb, pool, block_table, index, rank=rank, nope=nope,
+        scale=scale, use_pallas=use_pallas,
+        ppb=_expand_pages(pool.shape[1], block_table.shape[1]))
+
+
+@functools.partial(jax.jit, static_argnames=("rank", "nope", "scale",
+                                             "use_pallas", "ppb"))
+def _latent_chunk_walk(q, rows, w_kvb, pool, block_table, index, *, rank,
+                       nope, scale, use_pallas, ppb):
+    """:func:`latent_chunk_attention` at ``ppb`` pages a step of the walk.
+    Jitted, so that a model's layers share one lowering (two kernels a
+    layer: a second and more of a traced body's set-up each)."""
+    b, s, h, _ = q.shape
+    m_pages = block_table.shape[1]
+    t = ppb * pool.shape[1]
+    rope = q.shape[-1] - nope
+    qh = jnp.swapaxes(q, 1, 2)                       # [B, H, S, nope + rope]
+    # the shared part of the score in whole lane tiles, zeros past the
+    # rotary key (the stored row's own pad where its lanes end there)
+    pad = -rope % 128
+    q_rope = jnp.pad(qh[..., nope:], ((0, 0),) * 3 + ((0, pad),))
+    w_k, w_v = w_kvb[..., :nope], w_kvb[..., nope:]
+    attend = functools.partial(flash_forward, qh[..., :nope],
+                               q_shared=q_rope, scale=scale,
+                               use_pallas=use_pallas)
+
+    def expand(rows):
+        c = rows[..., :rank]
+        k, v = (jnp.einsum("btr,rhn->bhtn", c, w,
+                           preferred_element_type=jnp.float32
+                           ).astype(rows.dtype) for w in (w_k, w_v))
+        k_rope = rows[..., rank:rank + rope + pad]
+        short = rope + pad - k_rope.shape[-1]
+        return k, v, jnp.pad(k_rope, ((0, 0), (0, 0), (0, short)))
+
+    k, v, k_rope = expand(rows)
+    carry = attend(k, v, k_shared=k_rope, causal=True)
+    table = jnp.pad(block_table, ((0, 0), (0, -m_pages % ppb)))
+
+    def step(i, carry):
+        pages = jax.lax.dynamic_slice_in_dim(table, i * ppb, ppb, axis=1)
+        k, v, k_rope = expand(pool[pages].reshape(b, t, pool.shape[-1]))
+        return tuple(attend(k, v, k_shared=k_rope, carry=carry,
+                            kv_len=jnp.clip(index - i * t, 0, t)))
+    o, _ = jax.lax.fori_loop(0, jnp.max(-(-index // t)), step, tuple(carry))
+    return jnp.swapaxes(o, 1, 2).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -1561,8 +1561,10 @@ class ServeEngine:
         out, counts = jax.device_get((out, stats["counts"]))
         if chunk:
             trace.lap("chunk_sync")
-        span.attrs.update(zip(self.decoder.model.stats_names,
-                              (int(c) for c in counts)))
+        span.attrs.update(zip(
+            self.decoder.model.call_stats_names(
+                span.attrs["tokens"] if chunk else 1),
+            (int(c) for c in counts)))
         if self.decoder.summary is not None:
             # what the decoder launched before this call's body
             span.attrs["windows_closed"] = self.decoder.last_closed

@@ -159,6 +159,139 @@ def test_absorbed_equals_expanded_attention():
                                rtol=1e-5, atol=1e-6)
 
 
+@pytest.fixture(scope="module")
+def chunk_logits(toy):
+    """A prompt of 61 tokens prefilled in chunks of 16 (starts 0 / 16 / 32
+    / 48, the last chunk 13 real tokens and 3 of padding) with the walk
+    over the prefix in steps of 32 keys: the logits at every chunk's
+    sampled position and the counts of its call, by (``use_pallas``, whether
+    the rule was left on)."""
+    model, params = toy
+    prompt = np.random.default_rng(7).integers(0, VOCAB, 61, dtype=np.int32)
+    table = np.zeros((16,), np.int32)
+    table[:8] = 3 + np.random.default_rng(8).permutation(8)
+    out = {"prompt": prompt}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(pa, "EXPAND_KEYS", 32)
+        for use_pallas in (False, "interpret"):
+            for expanded in (True, False):
+                with mp.context() as off:
+                    if not expanded:
+                        off.setattr(rd, "latent_expands", lambda *a: False)
+                    dec = Decoder(model.clone(use_pallas=use_pallas), params,
+                                  num_slots=1, max_seq_len=128,
+                                  kv_page_size=PAGE, kv_pool_pages=12)
+                    cache, rows = dec.fresh_cache(), []
+                    for start in range(0, 64, 16):
+                        chunk = np.zeros((16,), np.int32)
+                        real = prompt[start:start + 16]
+                        chunk[:len(real)] = real
+                        _, cache, last = dec.prefill_chunk(
+                            cache, chunk, table, start, len(real) - 1, 0.0,
+                            seed=0)
+                        rows.append((np.asarray(last), np.asarray(
+                            dec.last_stats["counts"])))
+                    out[use_pallas, expanded] = rows
+    return out
+
+
+@pytest.mark.parametrize("use_pallas", [False, "interpret"],
+                         ids=["blockwise", "kernel"])
+@pytest.mark.parametrize("chunk", range(4), ids=[
+    "first_chunk", "start_half_a_step", "start_whole_steps", "padded_tail"])
+def test_an_expanded_chunk_equals_the_absorbed_one(toy, reference,
+                                                   chunk_logits, chunk,
+                                                   use_pallas):
+    """The chunk whose keys go through ``kv_b`` (its own causally, the
+    pages under its start in steps of 32 keys: none, half a step, one
+    whole step, one and a half) against the chunk whose queries do, and
+    both against the reference's full forward; through ``ops.blockwise``
+    and through the kernel in interpret mode.  The expanded call counts the
+    rows it expanded — the walk's whole steps and its own 16 — in the
+    place where the absorbed one counts the rows it read."""
+    model, _ = toy
+    prompt = chunk_logits["prompt"]
+    got, counts = chunk_logits[use_pallas, True][chunk]
+    absorbed, read = chunk_logits[use_pallas, False][chunk]
+    start = 16 * chunk
+    at = min(start + 15, len(prompt) - 1)
+    want = _ref_logits(reference, toy[1], prompt[None])[0, at]
+    scale = np.abs(want).max()
+    assert np.abs(got - want).max() <= 1e-4 * scale
+    assert np.abs(got - absorbed).max() <= 1e-4 * scale
+    names = model.clone(decode=True).call_stats_names(16)
+    assert names[3] == "latent_tokens_expanded"
+    assert "latent_tokens_read" not in names
+    assert counts[3] == 4 * (-(-start // 32) * 32 + 16)
+    assert read[3] == 4 * (start + 16)
+    assert (counts[:3] == read[:3]).all()
+
+
+@pytest.mark.parametrize("name,widths,first", [
+    ("joyai", (32, 640, 512, 128, 64, 128), 158),
+    ("ling", (32, 640, 512, 128, 64, 128), 158),
+    ("toy", (4, 128, 24, 16, 8, 16), 7)])
+def test_the_form_follows_the_calls_shape(name, widths, first):
+    """Expanded from the first chunk length at which a key through ``kv_b``
+    once costs less than every query meeting the stored row: about 160
+    queries at JoyAI's and Ling's widths, 7 at the toy's; one query a row
+    (a decode step) is absorbed at any widths."""
+    if name != "toy":
+        import json
+        cfg = json.load(open(os.path.join(
+            ROOT, "benchmark", "configs",
+            {"joyai": "joyai-llm-flash.json",
+             "ling": "ling-3.0-flash-vl.json"}[name])))
+        assert widths == (
+            cfg["num_attention_heads"],
+            rd.latent_row_lanes(cfg["kv_lora_rank"], cfg["qk_rope_head_dim"]),
+            cfg["kv_lora_rank"], cfg["qk_nope_head_dim"],
+            cfg["qk_rope_head_dim"], cfg["v_head_dim"])
+    assert not pa.latent_expands(1, *widths)
+    assert not pa.latent_expands(first - 1, *widths)
+    assert pa.latent_expands(first, *widths)
+    assert pa.latent_expands(2048, *widths)
+
+
+def test_a_chunk_span_names_what_it_expanded_and_a_step_what_it_read(
+        toy, tmp_path):
+    """Tracing on: a ``serve_prefill_chunk`` span of a chunk that attended
+    expanded carries ``latent_tokens_expanded`` and no ``latent_tokens_read``
+    (``latent_attention_roofline`` prices every span with that name at the
+    absorbed cost over the paged kernel's time); a ``serve_decode`` span the
+    reverse."""
+    from dtf_tpu.obs import trace
+    from dtf_tpu.serve.engine import ServeEngine
+    model, params = toy
+    rng = np.random.default_rng(4)
+    tracer = trace.configure(str(tmp_path))
+    try:
+        eng = ServeEngine(model, params, max_batch=2, max_seq_len=128,
+                          kv_page_size=PAGE, kv_pool_pages=33,
+                          prefill_chunk=16)
+        try:
+            for h in [eng.submit(rng.integers(0, VOCAB, p, dtype=np.int32),
+                                 max_new_tokens=4) for p in (40, 16)]:
+                h.result(timeout=300)
+        finally:
+            eng.stop(drain=True, timeout=30)
+    finally:
+        trace.disable()
+    spans = [r for r in trace.read_records(tracer.path)
+             if r.get("kind") == "span"]
+    chunks = [r for r in spans if r["name"] == "serve_prefill_chunk"]
+    steps = [r for r in spans if r["name"] == "serve_decode"]
+    assert len(chunks) == 4 and steps
+    for r in chunks:
+        # a table of 16 pages of 8 is one step of the walk
+        assert r["latent_tokens_expanded"] == 4 * (
+            -(-r["start"] // 128) * 128 + r["tokens"])
+        assert "latent_tokens_read" not in r
+    for r in steps:
+        assert r["latent_tokens_read"] > 0
+        assert "latent_tokens_expanded" not in r
+
+
 @pytest.mark.parametrize("heads", [32, 4], ids=["group32", "group4"])
 @pytest.mark.parametrize("batch,s,lens", [(3, 1, [5, 17, 40]),
                                           (2, 16, [8, 32])],

@@ -336,7 +336,7 @@ def _compile_decode_body(dec, v5e):
         compiler_options=sd.TPU_BODY_OPTIONS).lower(*args).compile()
 
 
-@pytest.mark.parametrize("body", ["chunk", "decode"])
+@pytest.mark.parametrize("body", ["chunk", "chunk_2048", "decode"])
 def test_latent_serve_bodies_compile_for_v5e(v5e, body):
     """The whole compiled body, not the kernel alone: beside the body's
     own use of VMEM (XLA's weight prefetches under ``TPU_BODY_OPTIONS``)
@@ -346,7 +346,12 @@ def test_latent_serve_bodies_compile_for_v5e(v5e, body):
     chip and here alike (PR 32).  Shapes: the latent-attention decoder at
     its benchmark widths (5 layers, 256 experts, vocabulary 129,280;
     shapes only, nothing materialised), 24 slots of 34,816 tokens, pages
-    of 64 in a 393,216-token pool."""
+    of 64 in a 393,216-token pool.  A chunk (1,024 queries, and the cell's
+    2,048: over the rule's 158) attends EXPANDED — the forward-only flash
+    entry twice a layer, the chunk against itself and the step of the
+    walk over its prefix, at (queries x 32 heads, 192 / 128) beside the
+    body — and calls the paged kernel nowhere; the decode step is the
+    absorbed one it was."""
     from dtf_tpu.models import build_model
     from dtf_tpu.serve import decode as sd
     i32, f32 = jnp.int32, jnp.float32
@@ -366,10 +371,11 @@ def test_latent_serve_bodies_compile_for_v5e(v5e, body):
     dec = _shapes_only_decoder(model, params, num_slots=24,
                                max_seq_len=34816, kv_page_size=64,
                                kv_pool_pages=6145)
-    if body == "chunk":
+    if body != "decode":
         s, m = jax.ShapeDtypeStruct, dec.pages_per_slot
+        queries = 2048 if body == "chunk_2048" else 1024
         args = _on_chip((dec.params, jax.eval_shape(dec.fresh_cache),
-                         s((1, 1024), i32), s((1, m), i32), s((), i32),
+                         s((1, queries), i32), s((1, m), i32), s((), i32),
                          s((), f32),
                          jax.eval_shape(lambda: sd.position_key(0, 0)),
                          s((), i32)), v5e)
@@ -377,10 +383,14 @@ def test_latent_serve_bodies_compile_for_v5e(v5e, body):
             dec._chunk_impl, donate_argnums=(1,), static_argnums=(8, 9),
             compiler_options=sd.TPU_BODY_OPTIONS).lower(
                 *args, None, False).compile()
+        text = compiled.as_text()
+        assert _kernel_calls(text, "flash_fwd_chunk") == 2 * 5
+        assert _kernel_calls(text, "paged_flash_decode") == 0
     else:
         compiled = _compile_decode_body(dec, v5e)
-    text = compiled.as_text()
-    assert text.count("paged_flash_decode") >= 5        # a call a layer
+        text = compiled.as_text()
+        assert _kernel_calls(text, "paged_flash_decode") == 5   # a layer
+        assert _kernel_calls(text, "flash_fwd_chunk") == 0
     # every pool is donated and updated in place: no second pool exists
     assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9
 
@@ -589,7 +599,14 @@ def test_linear_state_serve_bodies_compile_for_v5e(v5e, body):
             compiler_options=sd.TPU_BODY_OPTIONS).lower(
                 *args, None, body == "chunk_first").compile()
     text = compiled.as_text()
-    assert text.count("paged_flash_decode") >= 1        # the latent layer
+    if body == "decode":
+        assert _kernel_calls(text, "paged_flash_decode") == 1
+        assert _kernel_calls(text, "flash_fwd_chunk") == 0
+    else:
+        # the latent layer's chunk of a page (1,024 queries: over the
+        # rule) attends expanded: itself, and the step of its walk
+        assert _kernel_calls(text, "flash_fwd_chunk") == 2
+        assert _kernel_calls(text, "paged_flash_decode") == 0
     if body == "decode":
         assert text.count("linear_state_decode") >= 7   # a call a layer
         # ... in the form of a bfloat16 pool, every one of them
