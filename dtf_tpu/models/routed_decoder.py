@@ -203,7 +203,9 @@ from dtf_tpu.ops import (block_select, index_select, linear_state,
                          window_summary)
 from dtf_tpu.ops.flash_attention import flash_attention
 from dtf_tpu.ops.paged_attention import (cached_attention, expand_kv_heads,
-                                         gather_pages, latent_expands,
+                                         gather_pages,
+                                         latent_chunk_attention,
+                                         latent_expands,
                                          latent_rows_expanded,
                                          latent_sparse_attention,
                                          latent_sparse_chunk,
@@ -1365,37 +1367,51 @@ class LightningIndexer(nn.Module):
         return q, w, keys.value
 
 
-def indexed_latent_attention(q_abs, pool, block_table, cache_index, *, top,
+def indexed_latent_attention(q, pool, block_table, cache_index, *, top,
                              picked, chosen, scale, value_lanes,
-                             use_pallas):
-    """Absorbed latent attention of a decode-mode call over the rows each
-    query CHOSE: q_abs [B, S, H, W], the call's rows already in ``pool``.
-    ``picked`` — a ``full`` layer's :class:`LightningIndexer` output ``(q,
-    w, index keys)`` — makes the choice; None (a ``shared`` layer) takes
-    ``chosen``, the choice of the nearest ``full`` layer below.  Returns
-    ``(o [B, S, H, value_lanes], chosen)``.
+                             use_pallas, expand=None):
+    """Latent attention of a decode-mode call over the rows each query
+    CHOSE, the call's rows already in ``pool``.  ``picked`` — a ``full``
+    layer's :class:`LightningIndexer` output ``(q, w, index keys)`` — makes
+    the choice; None (a ``shared`` layer) takes ``chosen``, the choice of
+    the nearest ``full`` layer below.  ABSORBED (``expand`` None): q
+    [B, S, H, W] carried into the row's space, returns ``(o [B, S, H,
+    value_lanes], chosen)``.  EXPANDED (a chunk where ``latent_expands``):
+    q [B, S, H, nope + rope] as projected and rotated and ``expand`` ``(the
+    call's rows [B, S, W], kv_b [value_lanes, H, nope + Dv], nope)``,
+    returns ``(o [B, S, H, Dv], chosen)``.
 
     The choice's form follows the path.  The kernels' (a chunk of whole
     tiles, or one token, on the TPU or interpreted): tiled membership
     (``index_select.chunk_select`` / ``decode_select``) that
-    ``latent_sparse_chunk`` / ``latent_sparse_decode`` mask by; a chunk no
-    query of which sees more than ``top`` rows goes through the dense
-    kernel every latent model uses and chooses nothing (zeros).  Elsewhere:
-    bool ``[B, S, L]`` on the gather oracle."""
-    b, s = q_abs.shape[:2]
+    ``latent_sparse_chunk`` / ``latent_sparse_decode`` mask by, and that an
+    expanded chunk walks its keys under (``latent_chunk_attention``); a
+    chunk no query of which sees more than ``top`` rows goes through the
+    kernel every latent model uses and chooses nothing (zeros).
+    Elsewhere: bool ``[B, S, L]`` on the gather oracle (absorbed) or the
+    walk in plain JAX (expanded)."""
+    b, s = q.shape[:2]
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
     if s > 1 and s % index_select.CHUNK_QUERIES:
         use_pallas = False
     t = cache_index[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
+
+    def expanded(member=None):
+        rows, w_kvb, nope = expand
+        return latent_chunk_attention(
+            q, rows, w_kvb, pool, block_table, cache_index, rank=value_lanes,
+            nope=nope, scale=scale, member=member, use_pallas=use_pallas)
     if not use_pallas:
         if picked is not None:
             q_i, w_i, keys = picked
             chosen = index_select.members(index_select.scores(
                 q_i, w_i, gather_pages(keys, block_table), t),
                 top)
+        if expand is not None:
+            return expanded(chosen), chosen
         return latent_sparse_attention(
-            q_abs, pool, block_table, chosen, value_lanes=value_lanes,
+            q, pool, block_table, chosen, value_lanes=value_lanes,
             scale=scale), chosen
     interpret = use_pallas == "interpret"
     if s == 1:
@@ -1405,7 +1421,7 @@ def indexed_latent_attention(q_abs, pool, block_table, cache_index, *, top,
                 q_i[:, 0], w_i[:, 0], keys, block_table, cache_index,
                 k=top, interpret=interpret)
         o = latent_sparse_decode(
-            q_abs[:, 0], pool, block_table, cache_index, chosen,
+            q[:, 0], pool, block_table, cache_index, chosen,
             value_lanes=value_lanes, scale=scale, interpret=interpret)
         return o[:, None], chosen
     blocks = index_select.member_blocks(block_table.shape[1], pool.shape[1])
@@ -1413,10 +1429,11 @@ def indexed_latent_attention(q_abs, pool, block_table, cache_index, *, top,
     shape = (b, s // tile, blocks, tile, index_select.MEMBER_BLOCK)
 
     def dense():
-        return (paged_attention_auto(
-            q_abs, pool, None, block_table, cache_index,
-            use_pallas=use_pallas, scale=scale, value_lanes=value_lanes),
-            jnp.zeros(shape, jnp.int8) if picked is not None else chosen)
+        o = expanded() if expand is not None else paged_attention_auto(
+            q, pool, None, block_table, cache_index, use_pallas=use_pallas,
+            scale=scale, value_lanes=value_lanes)
+        return o, (jnp.zeros(shape, jnp.int8) if picked is not None
+                   else chosen)
 
     def sparse():
         member = chosen
@@ -1425,8 +1442,10 @@ def indexed_latent_attention(q_abs, pool, block_table, cache_index, *, top,
             member = index_select.chunk_select(
                 q_i, w_i, keys, block_table, cache_index, k=top,
                 interpret=interpret)
+        if expand is not None:
+            return expanded(member), member
         return latent_sparse_chunk(
-            q_abs, pool, block_table, cache_index, member,
+            q, pool, block_table, cache_index, member,
             value_lanes=value_lanes, scale=scale,
             interpret=interpret), member
     return jax.lax.cond(jnp.all(cache_index + s <= top), dense, sparse)
@@ -1455,9 +1474,15 @@ class LatentAttention(nn.Module):
     meets the chunk's queries at ``nope + rope + v`` = 320 a head
     (``latent_chunk_attention``: the chunk against itself causally, the
     pages under its start in a walk as long as the start asks); the rows
-    are written to the pages as ever.  An ``indexer`` layer is always
-    absorbed.  Outside decode mode (tests, the toy's teacher-forced
-    forward) K and V of every token are expanded from the definition.
+    are written to the pages as ever.  An ``indexer`` layer goes by the
+    same rule: its decode step and a chunk too short to repay an expansion
+    attend absorbed under the membership (``latent_sparse_decode``,
+    ``latent_sparse_chunk``), a longer chunk EXPANDED with the membership,
+    a row a query, as the mask of every block of the walk (512 lane-products
+    a (query, key, head) at nope / rope / v 192 / 64 / 256, the rotary key
+    in each head's row, against the absorbed 1,152).  Outside decode mode
+    (tests, the toy's teacher-forced forward) K and V of every token are
+    expanded from the definition.
 
     ``q_lora_rank`` None: the queries come from ``h`` directly (one
     projection ``q``, no query latent and no norm of one).
@@ -1555,41 +1580,41 @@ class LatentAttention(nn.Module):
             pad = latent_row_lanes(r, dr) - r - dr
             row = jnp.concatenate(
                 [c_kv, k_rope, jnp.zeros((b, s, pad), self.dtype)], -1)
-            if self.indexer is None and latent_expands(
-                    s, hq, row.shape[-1], r, dn, dr, dv):
+            expands = latent_expands(s, hq, row.shape[-1], r, dn, dr, dv)
+            if expands:
                 # a chunk: its KEYS go through kv_b, once each, and not
                 # its queries; o comes back a head's own [.., hq, dv]
-                o = paged_cache_attention(
-                    self, jnp.concatenate([q_nope, q_rope], -1), row, None,
-                    cache_index, block_table, scale=scale, value_lanes=r,
-                    expand=(w_kvb, dn))
+                q_att = jnp.concatenate([q_nope, q_rope], -1)
             else:
-                q_abs = jnp.einsum("bshn,rhn->bshr", q_nope, w_kvb[..., :dn],
+                q_att = jnp.einsum("bshn,rhn->bshr", q_nope, w_kvb[..., :dn],
                                    preferred_element_type=jnp.float32
                                    ).astype(self.dtype)
-                q_abs = jnp.concatenate(
-                    [q_abs, q_rope, jnp.zeros((b, s, hq, pad), self.dtype)],
+                q_att = jnp.concatenate(
+                    [q_att, q_rope, jnp.zeros((b, s, hq, pad), self.dtype)],
                     -1)
-                if self.indexer is None:
-                    o = paged_cache_attention(
-                        self, q_abs, row, None, cache_index, block_table,
-                        window_pages=window_pages, scale=scale,
-                        value_lanes=r)
-                else:
-                    pool = self.variable(
-                        "cache", "paged_latent", jnp.zeros,
-                        (self.kv_pool_pages, self.kv_page_size,
-                         row.shape[-1]), self.dtype)
-                    o = jnp.zeros(q_abs.shape[:-1] + (r,), self.dtype)
-                    if not self.is_initializing():
-                        pool.value = write_pages(
-                            pool.value, row, block_table, cache_index,
-                            page_aligned=s > 1 and s % self.kv_page_size == 0)
-                        o, chosen = indexed_latent_attention(
-                            q_abs, pool.value, block_table, cache_index,
-                            top=self.indexer[3], picked=picked, chosen=chosen,
-                            scale=scale, value_lanes=r,
-                            use_pallas=self.use_pallas)
+            if self.indexer is None:
+                o = paged_cache_attention(
+                    self, q_att, row, None, cache_index, block_table,
+                    window_pages=window_pages, scale=scale, value_lanes=r,
+                    expand=(w_kvb, dn) if expands else None)
+            else:
+                pool = self.variable(
+                    "cache", "paged_latent", jnp.zeros,
+                    (self.kv_pool_pages, self.kv_page_size, row.shape[-1]),
+                    self.dtype)
+                o = jnp.zeros(q_att.shape[:-1] + (dv if expands else r,),
+                              self.dtype)
+                if not self.is_initializing():
+                    pool.value = write_pages(
+                        pool.value, row, block_table, cache_index,
+                        page_aligned=s > 1 and s % self.kv_page_size == 0)
+                    o, chosen = indexed_latent_attention(
+                        q_att, pool.value, block_table, cache_index,
+                        top=self.indexer[3], picked=picked, chosen=chosen,
+                        scale=scale, value_lanes=r,
+                        use_pallas=self.use_pallas,
+                        expand=(row, w_kvb, dn) if expands else None)
+            if not expands:
                 o = jnp.einsum("bshr,rhv->bshv", o, w_kvb[..., dn:],
                                preferred_element_type=jnp.float32
                                ).astype(self.dtype)
@@ -1934,7 +1959,7 @@ class RoutedDecoderLM(nn.Module):
         a row attend EXPANDED (:class:`LatentAttention`: by the call's shape
         and the layers' widths)."""
         return (self.decode and self.kv_lora_rank is not None
-                and self.indexer is None and latent_expands(
+                and latent_expands(
                     s, self.num_heads,
                     latent_row_lanes(self.kv_lora_rank,
                                      self.qk_rope_head_dim),
@@ -1946,9 +1971,13 @@ class RoutedDecoderLM(nn.Module):
         latent layers attend expanded they READ no cached row through the
         paged kernel — the count in that place is of the rows they carried
         through ``kv_b``, the chunk's own and the cached ones in whole
-        steps of the walk, under a name of its own."""
-        return tuple("latent_tokens_expanded"
-                     if n == "latent_tokens_read" and self.latent_expanded(s)
+        steps of the walk, under a name of its own (with an ``indexer``:
+        one count more, behind the ``INDEX_STATS`` a step has too)."""
+        if not self.latent_expanded(s):
+            return self.stats_names
+        if self.indexer is not None:
+            return self.stats_names + ("latent_tokens_expanded",)
+        return tuple("latent_tokens_expanded" if n == "latent_tokens_read"
                      else n for n in self.stats_names)
 
     def layer_mixers(self):
@@ -2166,7 +2195,7 @@ class RoutedDecoderLM(nn.Module):
 
             def over(x):
                 return jnp.sum(jnp.where(real, x, 0), dtype=jnp.int32)
-            counts = jnp.stack([
+            counts = [
                 assignments, touched, load_max,
                 kinds_i.count("full") * over(jnp.where(seen > top, seen, 0)),
                 len(kinds) * over(seen),
@@ -2174,7 +2203,12 @@ class RoutedDecoderLM(nn.Module):
                 # through the dense kernel where the whole chunk does (a
                 # membership of zeros: nothing was chosen)
                 over(jnp.where(seen <= top, len(kinds) * seen, picked_rows)),
-                over((seen <= top).astype(jnp.int32))])
+                over((seen <= top).astype(jnp.int32))]
+            if self.latent_expanded(s):
+                counts.append(len(kinds) * jnp.sum(latent_rows_expanded(
+                    cache_index, s, self.kv_page_size,
+                    block_table.shape[1])))
+            counts = jnp.stack(counts)
         elif latent is not None:
             if self.latent_expanded(s):
                 live = latent_rows_expanded(
@@ -2207,7 +2241,7 @@ class RoutedDecoderLM(nn.Module):
                 counts += [jnp.asarray(
                     b * s * (len(kinds) - len(attends)), jnp.int32), advanced]
             counts = jnp.stack(counts)
-        n_counts = len(self.stats_names)
+        n_counts = counts.shape[0]
         self.sow("stats", "counts", counts,
                  reduce_fn=lambda _, new: new,
                  init_fn=lambda: jnp.zeros((n_counts,), jnp.int32))

@@ -226,23 +226,33 @@ def _pallas_forward(q, k, v, scale, causal, block_q, block_k, interpret):
 # EXPANDED from a latent cache (ops.paged_attention.latent_chunk_attention)
 # ---------------------------------------------------------------------------
 
-def _chunk_fwd_kernel(*refs, scale, causal, live, carried):
+def _chunk_fwd_kernel(*refs, scale, causal, live, carried, shared, masked):
     """Grid (B, H, Sq/block_q, Sk/block_k): :func:`_fwd_kernel`'s traversal
-    and carry, for a score of TWO products — a head's own ``q . k`` plus
-    ``q_shared . k_shared``, the second key ONE a token for every head of
-    the row (a latent cache's rotary key: never copied a head) — and values
-    of a width of their own.  ``live``: the first ref is ``kv_len`` [B]
-    (scalar prefetch), row ``b`` sees keys ``< kv_len[b]``: blocks at and
+    and carry, for values of a width of their own and, ``shared``, a score
+    of TWO products — a head's own ``q . k`` plus ``q_shared . k_shared``,
+    the second key ONE a token for every head of the row (a latent cache's
+    rotary key: never copied a head).  ``live``: the first ref is ``kv_len``
+    [B] (scalar prefetch), row ``b`` sees keys ``< kv_len[b]``: blocks at and
     past it are skipped (their copies too: the index maps stay on the last
     live block), the block it cuts is masked.  ``carried``: the carry
     starts from an earlier call's ``(o, lse)`` — ``o`` normalised is the
     un-normalised sum at ``m = lse, l = 1`` — so a caller that walks the
-    keys in blocks of its own merges nothing outside the kernel."""
+    keys in blocks of its own merges nothing outside the kernel.
+    ``masked``: a ref after the carry holds the tile's MEMBERSHIP
+    [block_q, block_k], or [block_q / tile, block_k / block, tile, block]
+    (non-zero: attended); a query sees a key the other rules show it AND
+    its membership names, so no block is whole."""
     refs = list(refs)
     len_ref = refs.pop(0) if live else None
-    q_ref, qs_ref, k_ref, ks_ref, v_ref = refs[:5]
-    o_in_ref, lse_in_ref = refs[5:7] if carried else (None, None)
-    o_ref, lse_ref, oacc_ref, m_ref, l_ref = refs[-5:]
+    q_ref = refs.pop(0)
+    qs_ref = refs.pop(0) if shared else None
+    k_ref = refs.pop(0)
+    ks_ref = refs.pop(0) if shared else None
+    v_ref = refs.pop(0)
+    o_in_ref, lse_in_ref = (refs.pop(0), refs.pop(0)) if carried else (
+        None, None)
+    member_ref = refs.pop(0) if masked else None
+    o_ref, lse_ref, oacc_ref, m_ref, l_ref = refs
     block_q, block_k = q_ref.shape[0], k_ref.shape[0]
     iq, jk = pl.program_id(2), pl.program_id(3)
 
@@ -259,7 +269,7 @@ def _chunk_fwd_kernel(*refs, scale, causal, live, carried):
 
     # run: the block holds a key some query of the tile sees; whole: every
     # query sees every key of it (no mask: see _fwd_kernel)
-    run = whole = jnp.bool_(True)
+    run, whole = jnp.bool_(True), jnp.bool_(not masked)
     if causal:
         run &= jk * block_k <= (iq + 1) * block_q - 1
         whole &= jk * block_k + block_k - 1 <= iq * block_q
@@ -268,15 +278,16 @@ def _chunk_fwd_kernel(*refs, scale, causal, live, carried):
         run &= jk * block_k < n
         whole &= (jk + 1) * block_k <= n
 
-    def _accumulate(bias):
+    def _accumulate(seen):
         dims = (((1,), (1,)), ((), ()))
-        s = (jax.lax.dot_general(q_ref[...], k_ref[...], dims,
-                                 preferred_element_type=jnp.float32)
-             + jax.lax.dot_general(qs_ref[...], ks_ref[...], dims,
-                                   preferred_element_type=jnp.float32)
-             ) * scale
-        if bias is not None:
-            s = s + bias
+        s = jax.lax.dot_general(q_ref[...], k_ref[...], dims,
+                                preferred_element_type=jnp.float32)
+        if shared:
+            s = s + jax.lax.dot_general(qs_ref[...], ks_ref[...], dims,
+                                        preferred_element_type=jnp.float32)
+        s = s * scale
+        if seen is not None:
+            s = s + jnp.where(seen, 0.0, bw.NEG_INF)
         o, m, l = bw.fold_scores(oacc_ref[...], m_ref[...][:, 0],
                                  l_ref[...][:, 0], s, v_ref[...])
         oacc_ref[...] = o
@@ -292,12 +303,19 @@ def _chunk_fwd_kernel(*refs, scale, causal, live, carried):
         k_pos = jk * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_k), 1)
         seen = jnp.bool_(True)
+        if masked:
+            named = member_ref[...]
+            if named.ndim == 4:         # tiles of queries x blocks of keys
+                named = jnp.concatenate(
+                    [named[:, i].reshape(block_q, -1)
+                     for i in range(named.shape[1])], axis=1)
+            seen &= named.astype(jnp.int32) != 0
         if causal:
             seen &= k_pos <= iq * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, 1), 0)
         if live:
             seen &= k_pos < n
-        _accumulate(jnp.where(seen, 0.0, bw.NEG_INF))
+        _accumulate(seen)
 
     @pl.when(jk == pl.num_programs(3) - 1)
     def _finalize():
@@ -306,20 +324,37 @@ def _chunk_fwd_kernel(*refs, scale, causal, live, carried):
         lse_ref[...] = bw.log_sum_exp(m_ref[...], l_ref[...])
 
 
-def flash_forward(q, k, v, *, q_shared, k_shared, scale: float,
+# VMEM a call states as its limit where it is masked or its values are
+# wider than a lane tile: beside the narrow call's blocks (the compiler's 16
+# MiB default holds those) a [1024, 1024] membership tile, its int32 image
+# and the mask of it, or a carry of twice the width — which alone compiles
+# under the default and INSIDE a serve body, beside kernels that state 64
+# MiB, is refused for 64.17 MiB of scoped VMEM unless it states a limit too
+# (tests/test_tpu_lowering.py, GLM-5.2's chunk body)
+_WIDE_VMEM_BYTES = 64 * 2 ** 20
+
+
+def flash_forward(q, k, v, *, q_shared=None, k_shared=None, scale: float,
                   causal: bool = False, kv_len=None, carry=None,
+                  member=None, name: str = "flash_fwd_chunk",
                   use_pallas=None):
     """Attention forward and nothing else (no gradient is defined), for
     serving.  Heads-major: q [B, H, Sq, D], k [B, H, Sk, D], v
-    [B, H, Sk, Dv] (``Dv`` need not be ``D``), and a part of the score all
-    heads of a row share one key for: q_shared [B, H, Sq, E], k_shared
-    [B, Sk, E]; ``score = (q . k + q_shared . k_shared) * scale``.
+    [B, H, Sk, Dv] (``Dv`` need not be ``D``), and optionally a part of
+    the score all heads of a row share one key for: q_shared [B, H, Sq, E],
+    k_shared [B, Sk, E]; ``score = (q . k + q_shared . k_shared) * scale``.
 
     ``causal``: query ``i`` sees keys ``j <= i`` (a chunk against itself).
     ``kv_len`` [B] int32: row ``b`` sees keys ``j < kv_len[b]`` — traced, so
     one compile serves every count; dead blocks cost a grid step and no
-    copy.  ``carry``: ``(o, lse)`` of an earlier call over OTHER keys of
-    the same queries; the result is then the attention over both sets.
+    copy.  ``member`` (non-zero: attended): a query's OWN choice of keys,
+    the same for every head — it sees a key the two rules above show it AND
+    ``member`` names — a row a query [B, Sq, Sk], or in tiles of queries and
+    blocks of keys [B, Sq / tile, Sk / block, tile, block] (whole tiles and
+    blocks a block of the grid: one DMA each, no relayout).  ``carry``:
+    ``(o, lse)`` of an earlier call over OTHER keys of the same queries;
+    the result is then the attention over both sets.  ``name``: the
+    kernel's, on the device's timeline.
 
     Returns ``(o [B, H, Sq, Dv] float32, lse [B, H, Sq, 1] float32)``, a
     row that saw nothing as ``(0, NEG_INF)``.  ``use_pallas`` as
@@ -331,10 +366,11 @@ def flash_forward(q, k, v, *, q_shared, k_shared, scale: float,
     sk, dv = k.shape[2], v.shape[-1]
     if not use_pallas:
         return _forward_plain(q, k, v, q_shared, k_shared, scale, causal,
-                              kv_len, carry)
+                              kv_len, carry, member)
     block_q = math.gcd(DEFAULT_BLOCK_Q, sq)
     block_k = math.gcd(CHUNK_BLOCK_K, sk)
     live, carried = kv_len is not None, carry is not None
+    shared, masked = q_shared is not None, member is not None
     num_kv = sk // block_k
 
     def last(b_, i, pre):
@@ -355,20 +391,47 @@ def flash_forward(q, k, v, *, q_shared, k_shared, scale: float,
             (None, None, block_k, lanes),
             lambda b_, h_, i, j, *pre: (b_, h_,
                                         jnp.minimum(j, last(b_, i, pre)), 0))
-    ks_spec = pl.BlockSpec(
-        (None, block_k, k_shared.shape[-1]),
-        lambda b_, h_, i, j, *pre: (b_, jnp.minimum(j, last(b_, i, pre)), 0))
-    operands = [q, q_shared, k, k_shared, v]
-    in_specs = [q_spec(q.shape[-1]), q_spec(q_shared.shape[-1]),
-                k_spec(k.shape[-1]), ks_spec, k_spec(dv)]
+    # (the live count is prefetched: an operand, the first, with no spec)
+    operands = [jnp.asarray(kv_len, jnp.int32)] if live else []
+    operands.append(q)
+    in_specs = [q_spec(q.shape[-1])]
+    if shared:
+        operands.append(q_shared)
+        in_specs.append(q_spec(q_shared.shape[-1]))
+    operands.append(k)
+    in_specs.append(k_spec(k.shape[-1]))
+    if shared:
+        operands.append(k_shared)
+        in_specs.append(pl.BlockSpec(
+            (None, block_k, k_shared.shape[-1]),
+            lambda b_, h_, i, j, *pre: (
+                b_, jnp.minimum(j, last(b_, i, pre)), 0)))
+    operands.append(v)
+    in_specs.append(k_spec(dv))
+    aliases = {}
     if carried:
+        # the carry is updated in place: a caller's loop over steps of keys
+        # holds ONE (o, lse), not a copy of it a step
+        aliases = {len(operands): 0, len(operands) + 1: 1}
         operands += [carry[0], carry[1]]
         in_specs += [q_spec(dv), q_spec(1)]
-    if live:
-        operands.insert(0, jnp.asarray(kv_len, jnp.int32))
+    if masked:
+        operands.append(member)
+        if member.ndim == 3:
+            in_specs.append(pl.BlockSpec(
+                (None, block_q, block_k),
+                lambda b_, h_, i, j, *pre: (
+                    b_, i, jnp.minimum(j, last(b_, i, pre)))))
+        else:
+            tile, mb = member.shape[3:]
+            in_specs.append(pl.BlockSpec(
+                (None, block_q // tile, block_k // mb, tile, mb),
+                lambda b_, h_, i, j, *pre: (
+                    b_, i, jnp.minimum(j, last(b_, i, pre)), 0, 0)))
     return pl.pallas_call(
         functools.partial(_chunk_fwd_kernel, scale=scale, causal=causal,
-                          live=live, carried=carried),
+                          live=live, carried=carried, shared=shared,
+                          masked=masked),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=int(live),
             grid=(b, h, sq // block_q, num_kv),
@@ -379,26 +442,34 @@ def flash_forward(q, k, v, *, q_shared, k_shared, scale: float,
                             pltpu.VMEM((block_q, 1), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct((b, h, sq, dv), jnp.float32),
                    jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32)],
+        compiler_params=(pltpu.CompilerParams(
+            vmem_limit_bytes=_WIDE_VMEM_BYTES) if masked or dv > 128
+            else None),
+        input_output_aliases=aliases,
         interpret=use_pallas == "interpret",
-        name="flash_fwd_chunk",
+        name=name,
     )(*operands)
 
 
 def _forward_plain(q, k, v, q_shared, k_shared, scale, causal, kv_len,
-                   carry):
+                   carry, member):
     """:func:`flash_forward` in plain JAX: the keys as ONE block of the
     online softmax (``ops.blockwise``)."""
     sq, sk = q.shape[2], k.shape[2]
-    s = (jnp.einsum("bhqd,bhkd->bhqk", q, k,
-                    preferred_element_type=jnp.float32)
-         + jnp.einsum("bhqe,bke->bhqk", q_shared, k_shared,
-                      preferred_element_type=jnp.float32)) * scale
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                   preferred_element_type=jnp.float32)
+    if q_shared is not None:
+        s = s + jnp.einsum("bhqe,bke->bhqk", q_shared, k_shared,
+                           preferred_element_type=jnp.float32)
+    s = s * scale
     k_pos = jnp.arange(sk, dtype=jnp.int32)
     seen = jnp.ones((1, 1, sq, sk), bool)
     if causal:
         seen &= k_pos <= jnp.arange(sq, dtype=jnp.int32)[:, None]
     if kv_len is not None:
         seen &= k_pos < kv_len[:, None, None, None]
+    if member is not None:
+        seen &= (member != 0)[:, None]
     if carry is None:
         o = jnp.zeros(q.shape[:3] + v.shape[-1:], jnp.float32)
         m = jnp.full(q.shape[:3], bw.NEG_INF, jnp.float32)

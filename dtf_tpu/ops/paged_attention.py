@@ -74,7 +74,11 @@ head over it) both take absorbed queries; a prefill chunk long enough to
 repay it (``latent_expands``) attends EXPANDED instead
 (``latent_chunk_attention``): its own rows and, in a walk whose length
 follows the chunk's start, the pages under it go through ``kv_b`` once and
-meet the queries in ``ops.flash_attention.flash_forward``.
+meet the queries in ``ops.flash_attention.flash_forward`` — under each
+query's own choice of rows where the layer has one (``member``: the form a
+chunk of chosen-row latent attention takes where it is long enough;
+``latent_sparse_chunk`` and ``latent_sparse_decode`` are the absorbed
+forms of that attention, a short chunk's and a decode step's).
 
 ``paged_attention_auto`` dispatches between them: the kernel by default
 on TPU, the gather oracle elsewhere; ``use_pallas="interpret"`` runs
@@ -293,9 +297,23 @@ def latent_rows_expanded(index, s: int, page_size: int, m_pages: int):
     return -(-index // t) * t + s
 
 
+def rope_in_head(nope: int, rope: int) -> bool:
+    """Whether an expanded key carries the rotary key IN each head's row
+    (one score product over ``nope + rope`` lanes) and not as a second
+    product all heads share, padded to a lane tile: where the MXU's
+    128-lane passes over ``nope + rope`` are those over ``nope`` alone, the
+    second product is a whole pass more and the copy a head costs nothing
+    (192 + 64: 512 lane-products a (query, key, head) with 256 of values
+    against 640; 128 + 64 keeps the shared product).  By the sweep
+    (docs/pr56_latent_sparse_expanded_sweep.jsonl: 2,048 queries x 64
+    heads under a membership, v5e, ms a layer at 8k / 32k / 64k keys — in
+    the head's row 9.63 / 36.92 / 73.38, shared 10.83 / 41.71 / 82.74)."""
+    return -(-(nope + rope) // 128) == -(-nope // 128)
+
+
 def latent_chunk_attention(q, rows, w_kvb, pool, block_table, index, *,
                            rank: int, nope: int, scale: float,
-                           use_pallas=None):
+                           member=None, use_pallas=None):
     """A chunk's attention over a latent pool with its keys EXPANDED: the
     same product as :func:`latent_paged_attention` over absorbed queries,
     taken the other way round — where :func:`latent_expands`.
@@ -313,54 +331,124 @@ def latent_chunk_attention(q, rows, w_kvb, pool, block_table, index, *,
     table, expands them and continues the same online softmax unmasked
     (``kv_len``: the step ``index`` cuts).  The walk's length follows the
     traced ``index``, never the table's: one compile a chunk length, work
-    in proportion to what the chunk sees."""
+    in proportion to what the chunk sees.
+
+    ``member`` (non-zero: attended): the rows each query CHOSE
+    (:func:`latent_sparse_attention`'s product) — every call of the walk
+    then also takes its keys' part of it, and the kernels run under the
+    name ``latent_sparse_chunk_expanded``.  Either a row a query, [B, S, L]
+    (``L`` the table's keys or more, by logical position), or the kernels'
+    tiled form [B, S / tile, blocks, tile, member_block]
+    (``index_select.chunk_select``), which a step of whole blocks reads as
+    it lies: only the chunk's own keys, which start at a page and not at a
+    block, are laid out a row a query."""
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
     return _latent_chunk_walk(
-        q, rows, w_kvb, pool, block_table, index, rank=rank, nope=nope,
-        scale=scale, use_pallas=use_pallas,
-        ppb=_expand_pages(pool.shape[1], block_table.shape[1]))
+        q, rows, w_kvb, pool, block_table, index, member, rank=rank,
+        nope=nope, scale=scale, use_pallas=use_pallas,
+        ppb=_expand_pages(pool.shape[1], block_table.shape[1]),
+        in_head=rope_in_head(nope, q.shape[-1] - nope))
 
 
 @functools.partial(jax.jit, static_argnames=("rank", "nope", "scale",
-                                             "use_pallas", "ppb"))
-def _latent_chunk_walk(q, rows, w_kvb, pool, block_table, index, *, rank,
-                       nope, scale, use_pallas, ppb):
-    """:func:`latent_chunk_attention` at ``ppb`` pages a step of the walk.
-    Jitted, so that a model's layers share one lowering (two kernels a
-    layer: a second and more of a traced body's set-up each)."""
+                                             "use_pallas", "ppb", "in_head"))
+def _latent_chunk_walk(q, rows, w_kvb, pool, block_table, index, member, *,
+                       rank, nope, scale, use_pallas, ppb, in_head):
+    """:func:`latent_chunk_attention` at ``ppb`` pages a step of the walk,
+    the rotary key ``in_head`` or shared.  Jitted, so that a model's layers
+    share one lowering (two kernels a layer: a second and more of a traced
+    body's set-up each)."""
     b, s, h, _ = q.shape
     m_pages = block_table.shape[1]
     t = ppb * pool.shape[1]
     rope = q.shape[-1] - nope
     qh = jnp.swapaxes(q, 1, 2)                       # [B, H, S, nope + rope]
+    w_k, w_v = w_kvb[..., :nope], w_kvb[..., nope:]
     # the shared part of the score in whole lane tiles, zeros past the
     # rotary key (the stored row's own pad where its lanes end there)
     pad = -rope % 128
-    q_rope = jnp.pad(qh[..., nope:], ((0, 0),) * 3 + ((0, pad),))
-    w_k, w_v = w_kvb[..., :nope], w_kvb[..., nope:]
-    attend = functools.partial(flash_forward, qh[..., :nope],
-                               q_shared=q_rope, scale=scale,
-                               use_pallas=use_pallas)
+    if in_head:
+        attend = functools.partial(flash_forward, qh)
+        # ... or the rotary key written INTO each head's key by the product
+        # that expands it: the stored row whole against [kv_b[K] | 0] over
+        # [0 | I] — a key passes the ones exactly, and no copy a head is
+        # made outside the product
+        w_k = jnp.zeros((rows.shape[-1], h, nope + rope), w_kvb.dtype)
+        w_k = w_k.at[:rank, :, :nope].set(w_kvb[..., :nope])
+        w_k = w_k.at[rank:rank + rope, :, nope:].set(
+            jnp.eye(rope, dtype=w_kvb.dtype)[:, None])
+    else:
+        attend = functools.partial(
+            flash_forward, qh[..., :nope],
+            q_shared=jnp.pad(qh[..., nope:], ((0, 0),) * 3 + ((0, pad),)))
+    attend = functools.partial(attend, scale=scale, use_pallas=use_pallas)
+    table = jnp.pad(block_table, ((0, 0), (0, -m_pages % ppb)))
+    tiled = member is not None and member.ndim == 5
+    if tiled and t % member.shape[-1]:
+        # pages too small for a step of whole blocks: a row a query
+        member, tiled = member_rows(member), False
+    if member is not None:
+        attend = functools.partial(attend,
+                                   name="latent_sparse_chunk_expanded")
+        # every step of the walk a slice of its own, whole
+        keys = table.shape[1] * pool.shape[1]
+        if tiled and member.shape[2] * member.shape[4] < keys:
+            raise ValueError(
+                f"a membership of {member.shape[2]} blocks of "
+                f"{member.shape[4]} keys does not cover {keys} keys")
+        if not tiled:
+            member = jnp.pad(member, ((0, 0), (0, 0), (0, max(
+                0, keys - member.shape[2]))))
 
     def expand(rows):
-        c = rows[..., :rank]
-        k, v = (jnp.einsum("btr,rhn->bhtn", c, w,
-                           preferred_element_type=jnp.float32
-                           ).astype(rows.dtype) for w in (w_k, w_v))
+        """``(k, v, the keys' shared part or None)`` of cache rows."""
+        def through(x, w):
+            return jnp.einsum("btr,rhn->bhtn", x, w,
+                              preferred_element_type=jnp.float32
+                              ).astype(rows.dtype)
+        v = through(rows[..., :rank], w_v)
+        if in_head:
+            return through(rows, w_k), v, None
         k_rope = rows[..., rank:rank + rope + pad]
         short = rope + pad - k_rope.shape[-1]
-        return k, v, jnp.pad(k_rope, ((0, 0), (0, 0), (0, short)))
+        return (through(rows[..., :rank], w_k), v,
+                jnp.pad(k_rope, ((0, 0), (0, 0), (0, short))))
+
+    def named(at, keys):
+        """``member``'s part for ``keys`` keys from ``at``: a step's [] —
+        of the tiled form whole blocks as they lie — or the chunk's own
+        [B], which start at a page and not at a block."""
+        if member is None:
+            return None
+        if not tiled:
+            if jnp.ndim(at) == 0:
+                return jax.lax.dynamic_slice_in_dim(member, at, keys, axis=2)
+            return jax.vmap(lambda m, a: jax.lax.dynamic_slice_in_dim(
+                m, a, keys, axis=1))(member, at)
+        blocks, mb = member.shape[2], member.shape[4]
+        if jnp.ndim(at) == 0:
+            return jax.lax.dynamic_slice_in_dim(member, at // mb, keys // mb,
+                                                axis=2)
+        n = min(blocks, -(-keys // mb) + 1)
+
+        def own(m, a):
+            first = jnp.clip(a // mb, 0, blocks - n)
+            part = jax.lax.dynamic_slice_in_dim(m, first, n, axis=1)
+            return jax.lax.dynamic_slice_in_dim(
+                member_rows(part[None])[0], a - first * mb, keys, axis=1)
+        return jax.vmap(own)(member, at)
 
     k, v, k_rope = expand(rows)
-    carry = attend(k, v, k_shared=k_rope, causal=True)
-    table = jnp.pad(block_table, ((0, 0), (0, -m_pages % ppb)))
+    carry = attend(k, v, k_shared=k_rope, causal=True,
+                   member=named(index, s))
 
     def step(i, carry):
         pages = jax.lax.dynamic_slice_in_dim(table, i * ppb, ppb, axis=1)
         k, v, k_rope = expand(pool[pages].reshape(b, t, pool.shape[-1]))
         return tuple(attend(k, v, k_shared=k_rope, carry=carry,
-                            kv_len=jnp.clip(index - i * t, 0, t)))
+                            kv_len=jnp.clip(index - i * t, 0, t),
+                            member=named(i * t, t)))
     o, _ = jax.lax.fori_loop(0, jnp.max(-(-index // t)), step, tuple(carry))
     return jnp.swapaxes(o, 1, 2).astype(q.dtype)
 
@@ -1280,6 +1368,13 @@ def tile_rows(x, tile: int):
     x = x.reshape((b, s // tile, tile, h) + x.shape[3:])
     return jnp.swapaxes(x, 2, 3).reshape((b, s // tile, h * tile)
                                          + x.shape[4:])
+
+
+def member_rows(member):
+    """The tiled membership [B, tiles, blocks, tile, member_block] as ONE
+    row a query, [B, tiles * tile, blocks * member_block]."""
+    b, g, blocks, tile, mb = member.shape
+    return jnp.swapaxes(member, 2, 3).reshape(b, g * tile, blocks * mb)
 
 
 def _latent_sparse_kernel(tbl_ref, idx_ref, q_ref, k_hbm, m_ref, o_ref, kbuf,

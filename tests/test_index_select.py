@@ -260,6 +260,186 @@ def test_latent_sparse_decode_is_the_masked_oracle():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6)
 
 
+def _latent_layer(rng, dtype, heads, rank, nope, rope, dv, rows):
+    """A latent pool whose rows are ``[c_kv | k_rope | 0]`` in 128 lanes,
+    ``rows`` tables over it, and ``kv_b``."""
+    lanes = 128
+    pool = np.zeros((POOL, PAGE, lanes), np.float32)
+    pool[..., :rank + rope] = rng.normal(size=(POOL, PAGE, rank + rope)) * 0.5
+    w_kvb = rng.normal(size=(rank, heads, nope + dv)) * 0.3
+    return (jnp.asarray(pool, dtype), _tables(rng, rows),
+            jnp.asarray(w_kvb, dtype))
+
+
+def _absorbed_oracle(q, w_kvb, pool, table, member, nope, rank, scale):
+    """``latent_sparse_attention`` over the absorbed image of ``q`` [B, S,
+    H, nope + rope], carried back through ``kv_b``'s value half: float32
+    throughout, from the inputs as stored."""
+    f32 = jnp.float32
+    q, w_kvb, pool = q.astype(f32), w_kvb.astype(f32), pool.astype(f32)
+    hi = jax.lax.Precision.HIGHEST
+    q_abs = jnp.einsum("bshn,rhn->bshr", q[..., :nope], w_kvb[..., :nope],
+                       precision=hi)
+    pad = pool.shape[-1] - rank - (q.shape[-1] - nope)
+    q_abs = jnp.concatenate(
+        [q_abs, q[..., nope:], jnp.zeros(q.shape[:3] + (pad,), f32)], -1)
+    o = pa.latent_sparse_attention(q_abs, pool, table, jnp.asarray(member),
+                                   value_lanes=rank, scale=scale)
+    return jnp.einsum("bshr,rhv->bshv", o, w_kvb[..., nope:], precision=hi)
+
+
+# (the chunks' first positions a row, queries a chunk): with the walk in
+# steps of 64 keys, none of it / two whole steps and one / a page and a
+# half step / one step a row and none
+_EXPANDED_STARTS = {"start_0_past_top": ((0, 0), 64),
+                    "deep_in_the_walk": ((128, 64), 64),
+                    "a_page_not_a_step": ((96, 32), 64),
+                    "rows_apart": ((64, 0), 32)}
+
+
+@pytest.mark.parametrize("form", ["rope_in_head", "rope_shared"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("case", list(_EXPANDED_STARTS))
+def test_the_expanded_masked_chunk_is_the_masked_oracle(case, dtype, form,
+                                                        monkeypatch):
+    """A chunk that attends EXPANDED under its membership
+    (``latent_chunk_attention`` with ``member``, the kernel in interpret
+    mode and the same walk in plain JAX) against the gather oracle over the
+    absorbed queries under the same membership — at both forms of the
+    score, the rotary key in each head's row (16 + 8 lanes: one pass of the
+    MXU with it or without) or a second product all heads share (128 + 8:
+    a pass more), by the widths alone."""
+    monkeypatch.setattr(pa, "EXPAND_KEYS", 64)
+    rng = np.random.default_rng(11)
+    heads, rank, rope, dv = 2, 24, 8, 16
+    nope = 16 if form == "rope_in_head" else 128
+    assert pa.rope_in_head(nope, rope) == (form == "rope_in_head")
+    starts, s = _EXPANDED_STARTS[case]
+    pool, table, w_kvb = _latent_layer(rng, dtype, heads, rank, nope, rope,
+                                       dv, len(starts))
+    index = jnp.asarray(starts, jnp.int32)
+    t = np.asarray(starts)[:, None] + np.arange(s)[None]
+    at = (np.asarray(table)[:, :, None] * PAGE + np.arange(PAGE)
+          ).reshape(len(starts), -1)                    # flat pool rows
+    rows = pool.reshape(-1, pool.shape[-1])[
+        np.take_along_axis(at, t, axis=1)]              # [B, S, W]
+    q = jnp.asarray(rng.normal(size=(len(starts), s, heads, nope + rope))
+                    * 0.4, dtype)
+    member = rng.random((len(starts), s, M * PAGE)) < 0.2
+    member[0, :, 64:128] = False        # a whole step of the walk unnamed
+    member[:, 5, :] = False             # a query that names ONE row
+    member &= np.arange(M * PAGE) <= t[..., None]
+    member[:, :, 0] = True              # nobody attends nothing
+    member[np.arange(len(starts))[:, None], np.arange(s)[None], t] |= (
+        rng.random(t.shape) < 0.7)      # ... and not everybody itself
+    member[:, 5, 1:] = False
+    scale = (nope + rope) ** -0.5
+    want = np.asarray(_absorbed_oracle(q, w_kvb, pool, table, member, nope,
+                                       rank, scale))
+    tiled = _tiled(member, ix.CHUNK_QUERIES, ix.member_blocks(M, PAGE))
+    tol = 3e-2 if dtype == jnp.bfloat16 else 1e-5
+    # pages of 32: no step of the walk is whole blocks of 512 keys, and the
+    # tiled membership is laid out a row a query inside
+    for use_pallas, named in (("interpret", tiled),
+                              (False, jnp.asarray(member))):
+        got = pa.latent_chunk_attention(
+            q, rows, w_kvb, pool, table, index, rank=rank, nope=nope,
+            scale=scale, member=named, use_pallas=use_pallas)
+        assert got.shape == (len(starts), s, heads, dv)
+        np.testing.assert_allclose(np.asarray(got, np.float32), want,
+                                   atol=tol * np.abs(want).max())
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("starts", [(1152, 640), (1024, 0)],
+                         ids=["inside_a_block", "whole_steps"])
+def test_the_expanded_walk_reads_the_tiled_membership_as_it_lies(
+        starts, dtype, monkeypatch):
+    """Pages of 128 and steps of 512 keys: a step of the walk is whole
+    blocks of the tiled membership and the kernel takes them as they lie
+    (a ``BlockSpec`` over tiles of queries x blocks of keys); the chunk's
+    own keys start at a page INSIDE a block (1,152 = 2 x 512 + 128) and
+    are laid out a row a query.  Against the gather oracle."""
+    monkeypatch.setattr(pa, "EXPAND_KEYS", 512)
+    rng = np.random.default_rng(14)
+    heads, rank, nope, rope, dv, page, m, s = 2, 24, 16, 8, 16, 128, 12, 64
+    pool = np.zeros((25, page, 128), np.float32)
+    pool[..., :rank + rope] = rng.normal(size=(25, page, rank + rope)) * 0.5
+    pool = jnp.asarray(pool, dtype)
+    w_kvb = jnp.asarray(rng.normal(size=(rank, heads, nope + dv)) * 0.3,
+                        dtype)
+    table = jnp.asarray(np.stack([rng.permutation(np.arange(1, 25))[:m]
+                                  for _ in starts]), jnp.int32)
+    index = jnp.asarray(starts, jnp.int32)
+    t = np.asarray(starts)[:, None] + np.arange(s)[None]
+    at = (np.asarray(table)[:, :, None] * page + np.arange(page)
+          ).reshape(len(starts), -1)
+    rows = pool.reshape(-1, 128)[np.take_along_axis(at, t, axis=1)]
+    q = jnp.asarray(rng.normal(size=(len(starts), s, heads, nope + rope))
+                    * 0.4, dtype)
+    member = rng.random((len(starts), s, m * page)) < 0.1
+    member[0, :, 512:1024] = False      # a whole step of the walk unnamed
+    member &= np.arange(m * page) <= t[..., None]
+    member[np.arange(len(starts))[:, None], np.arange(s)[None], t] = True
+    scale = (nope + rope) ** -0.5
+    want = np.asarray(_absorbed_oracle(q, w_kvb, pool, table, member, nope,
+                                       rank, scale))
+    tiled = _tiled(member, ix.CHUNK_QUERIES, ix.member_blocks(m, page))
+    got = pa.latent_chunk_attention(
+        q, rows, w_kvb, pool, table, index, rank=rank, nope=nope,
+        scale=scale, member=tiled, use_pallas="interpret")
+    tol = 3e-2 if dtype == jnp.bfloat16 else 1e-5
+    np.testing.assert_allclose(np.asarray(got, np.float32), want,
+                               atol=tol * np.abs(want).max())
+
+
+@pytest.mark.parametrize("use_pallas", [False, "interpret"],
+                         ids=["blockwise", "kernel"])
+@pytest.mark.parametrize("carried", [True, False])
+def test_a_block_the_membership_names_nothing_in_is_inert(use_pallas,
+                                                          carried):
+    """A block of keys in which no query's membership names anything leaves
+    the carry as it came, to the bit, and a query that has seen nothing yet
+    stays at ``(0, NEG_INF)``: no NaN from an all-masked block."""
+    from dtf_tpu.ops import blockwise as bw
+    from dtf_tpu.ops.flash_attention import flash_forward
+    rng = np.random.default_rng(12)
+    q, k, v = (jnp.asarray(rng.normal(size=(1, 2, 32, d)), jnp.float32)
+               for d in (24, 24, 16))
+    carry = None
+    if carried:
+        carry = (jnp.asarray(rng.normal(size=(1, 2, 32, 16)), jnp.float32),
+                 jnp.asarray(rng.normal(size=(1, 2, 32, 1)), jnp.float32))
+    o, lse = flash_forward(q, k, v, scale=0.2, carry=carry,
+                           member=jnp.zeros((1, 32, 32), jnp.int8),
+                           use_pallas=use_pallas)
+    if carried:
+        assert (np.asarray(o) == np.asarray(carry[0])).all()
+        assert (np.asarray(lse) == np.asarray(carry[1])).all()
+    else:
+        assert np.isfinite(np.asarray(o)).all()
+        assert (np.asarray(lse) <= bw.NEG_INF).all()
+        # ... and the first key it does see is all it attends
+        member = jnp.zeros((1, 32, 32), jnp.int8).at[:, :, 7].set(1)
+        o2, _ = flash_forward(q, k, v, scale=0.2, carry=(o, lse),
+                              member=member, use_pallas=use_pallas)
+        np.testing.assert_allclose(
+            np.asarray(o2), np.broadcast_to(np.asarray(v)[:, :, 7:8],
+                                            o2.shape), atol=1e-6)
+
+
+def test_member_rows_is_the_tiled_membership_a_row_a_query():
+    rng = np.random.default_rng(13)
+    member = rng.random((2, 64, M * PAGE)) < 0.3
+    blocks = ix.member_blocks(M, PAGE)
+    got = np.asarray(pa.member_rows(_tiled(member, ix.CHUNK_QUERIES, blocks)))
+    assert got.shape == (2, 64, blocks * ix.MEMBER_BLOCK)
+    assert (got[..., :M * PAGE] != 0).tolist() == member.tolist()
+    assert not got[..., M * PAGE:].any()
+
+
 def test_a_choice_of_everything_is_dense_latent_attention():
     """While a query sees ``k`` rows or fewer it attends all of them: the
     sparse path under that membership is the dense oracle."""
@@ -318,3 +498,95 @@ def test_the_indexed_model_serves_through_the_engine():
         logits = forward(jnp.asarray([list(p) + got], jnp.int32))[0]
         chose = jnp.argmax(logits[len(p) - 1:len(p) - 1 + len(got)], -1)
         assert chose.tolist() == got
+
+
+_CHUNKS = ["first_chunk_past_top", "start_a_whole_step", "one_real_token"]
+
+
+@pytest.fixture(scope="module")
+def indexed_chunks():
+    """A prompt of 65 tokens prefilled in chunks of 32 (starts 0 / 32 / 64,
+    the last chunk ONE real token and 31 of padding; ``top`` 24, so the
+    first chunk is past it), the walk in steps of 32 keys, by a model whose
+    second layer is ``full`` or ``shared``: every chunk's logits at its
+    sampled position and its call's counts, by (second layer, ``use_pallas``,
+    whether the rule was left on), and the whole-sequence forward's."""
+    from dtf_tpu.models import build_model
+    from dtf_tpu.models import routed_decoder as rd
+    from dtf_tpu.serve.decode import Decoder
+    prompt = np.random.default_rng(21).integers(0, 128, 65, dtype=np.int32)
+    table = np.zeros((8,), np.int32)
+    table[:6] = 2 + np.random.default_rng(22).permutation(6)
+    out = {"prompt": prompt}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(pa, "EXPAND_KEYS", 32)
+        for second in ("full", "shared"):
+            model, _ = build_model(
+                "routed_decoder", num_classes=128, dtype=jnp.float32,
+                num_layers=2, d_model=64, num_heads=2, q_lora_rank=32,
+                kv_lora_rank=24, qk_nope_head_dim=16, qk_rope_head_dim=8,
+                v_head_dim=16, rope_interleave=True, indexer=(2, 16, 24, 8),
+                layer_indexer=("full", second), num_dense_layers=2,
+                dense_width=96, activation="silu")
+            params = model.init(jax.random.key(3),
+                                jnp.zeros((1, 16), jnp.int32))["params"]
+            out[second] = model
+            with jax.default_matmul_precision("highest"):
+                out[second, "forward"] = np.asarray(model.apply(
+                    {"params": params}, prompt[None]))[0]
+            for use_pallas in (False, "interpret"):
+                for expanded in (True, False):
+                    with mp.context() as off:
+                        if not expanded:
+                            off.setattr(rd, "latent_expands",
+                                        lambda *a: False)
+                        dec = Decoder(model.clone(use_pallas=use_pallas),
+                                      params, num_slots=1, max_seq_len=128,
+                                      kv_page_size=16, kv_pool_pages=9)
+                        cache, rows = dec.fresh_cache(), []
+                        with jax.default_matmul_precision("highest"):
+                            for start in (0, 32, 64):
+                                chunk = np.zeros((32,), np.int32)
+                                real = prompt[start:start + 32]
+                                chunk[:len(real)] = real
+                                _, cache, last = dec.prefill_chunk(
+                                    cache, chunk, table, start,
+                                    len(real) - 1, 0.0, seed=0)
+                                rows.append((np.asarray(last), np.asarray(
+                                    dec.last_stats["counts"])))
+                        out[second, use_pallas, expanded] = rows
+    return out
+
+
+@pytest.mark.parametrize("use_pallas", [False, "interpret"],
+                         ids=["blockwise", "kernel"])
+@pytest.mark.parametrize("second", ["full", "shared"])
+@pytest.mark.parametrize("chunk", range(3), ids=_CHUNKS)
+def test_an_indexer_layers_chunk_attends_expanded_under_its_membership(
+        indexed_chunks, chunk, second, use_pallas):
+    """A chunk of an indexer layer long enough to repay it attends EXPANDED
+    (its keys through ``kv_b``, the membership the mask of every block of
+    the walk) and serves what the ABSORBED chunk serves and what the
+    whole-sequence forward computes — the first chunk already past ``top``,
+    a chunk a whole step into the walk, a last chunk entered by one real
+    token; a ``shared`` layer by the choice of the ``full`` layer below.
+    Its call counts the rows it carried through ``kv_b`` behind the counts
+    a step has, which are the absorbed chunk's to the row."""
+    model = indexed_chunks[second]
+    prompt = indexed_chunks["prompt"]
+    got, counts = indexed_chunks[second, use_pallas, True][chunk]
+    absorbed, read = indexed_chunks[second, use_pallas, False][chunk]
+    start = 32 * chunk
+    at = min(start + 31, len(prompt) - 1)
+    want = indexed_chunks[second, "forward"][at]
+    scale = np.abs(want).max()
+    assert np.abs(got - want).max() <= 1e-4 * scale
+    assert np.abs(got - absorbed).max() <= 1e-4 * scale
+    dm = model.clone(decode=True)
+    assert dm.latent_expanded(32) and not dm.latent_expanded(1)
+    names = dm.call_stats_names(32)
+    assert names == dm.stats_names + ("latent_tokens_expanded",)
+    assert dm.call_stats_names(1) == dm.stats_names
+    assert len(counts) == len(names) and len(read) == len(dm.stats_names)
+    assert counts[-1] == 2 * (start + 32)
+    assert (counts[:-1] == read).all()

@@ -385,7 +385,11 @@ def test_latent_serve_bodies_compile_for_v5e(v5e, body):
                 *args, None, False).compile()
         text = compiled.as_text()
         assert _kernel_calls(text, "flash_fwd_chunk") == 2 * 5
+        # q, the shared q, k, the shared k, v; the walk's step the live
+        # count and the carry besides: no membership, the kernel they had
+        assert _kernel_operands(text, "flash_fwd_chunk") == [5] * 5 + [8] * 5
         assert _kernel_calls(text, "paged_flash_decode") == 0
+        assert "latent_sparse" not in text
     else:
         compiled = _compile_decode_body(dec, v5e)
         text = compiled.as_text()
@@ -606,6 +610,7 @@ def test_linear_state_serve_bodies_compile_for_v5e(v5e, body):
         # the latent layer's chunk of a page (1,024 queries: over the
         # rule) attends expanded: itself, and the step of its walk
         assert _kernel_calls(text, "flash_fwd_chunk") == 2
+        assert _kernel_operands(text, "flash_fwd_chunk") == [5, 8]
         assert _kernel_calls(text, "paged_flash_decode") == 0
     if body == "decode":
         assert text.count("linear_state_decode") >= 7   # a call a layer
@@ -777,6 +782,14 @@ def _kernel_calls(text, name):
     """Custom calls of the Pallas kernel ``name`` in an optimized HLO."""
     return len(re.findall(r"^\s*%" + name + r"(\.\d+)? = [^\n]*custom-call\(",
                           text, re.M))
+
+
+def _kernel_operands(text, name):
+    """How many operands each custom call of the Pallas kernel ``name``
+    takes, in ascending order."""
+    return sorted(len(re.findall(r"%[\w.\-]+", args)) for args in re.findall(
+        r"^\s*%" + name + r"(?:\.\d+)? = [^\n]*?custom-call\(([^)]*)\)",
+        text, re.M))
 
 
 @pytest.fixture(scope="module")
@@ -1076,14 +1089,19 @@ def test_indexed_latent_serve_bodies_compile_for_v5e(v5e, indexed_decoder,
     holds.  The DECODE body: ONE ``index_select`` call a ``full`` layer
     (two) and ONE ``latent_sparse_decode`` call a layer (five, the three
     ``shared`` ones over the choice of the layer below), no dense latent
-    call, no ``sort`` and no ``while`` over rows.  A CHUNK of 2,048 tokens:
-    both branches a layer — whole pages through ``paged_flash_decode``
-    while every query sees 2,048 rows or fewer; past that ONE
-    ``index_select`` a ``full`` layer and ONE ``latent_sparse_chunk`` a
-    layer — and the choice crosses layers as tiled membership ``[1, 64,
-    blocks, 32, 512]`` int8: no ``sort`` (``lax.top_k`` lowers to one a
-    query) and no ``while`` over queries.  The pools — five of latent rows,
-    two of index keys — are donated and updated in place."""
+    call, no ``sort`` and no ``while`` over rows.  A CHUNK of 2,048 tokens
+    (over the rule's 359 queries at these widths) attends EXPANDED in both
+    branches a layer — the chunk against itself and the step of its walk,
+    the rotary key IN each head's row (192 + 64 lanes: q, k, v and no
+    shared product): ``flash_fwd_chunk`` while every query sees 2,048 rows
+    or fewer; past that ONE ``index_select`` a ``full`` layer and the same
+    two calls under the membership as ``latent_sparse_chunk_expanded`` —
+    the chunk's own keys' part a row a query, ``[1, 2048, 2048]`` int8, a
+    step's four blocks as they lie, ``[1, 64, 4, 32, 512]``; no absorbed
+    kernel — and the choice crosses layers as tiled membership ``[1, 64,
+    blocks, 32, 512]`` int8: no ``sort`` (``lax.top_k`` lowers to one a query) and no
+    ``while`` over queries but the walk's.  The pools — five of latent
+    rows, two of index keys — are donated and updated in place."""
     from dtf_tpu.serve import decode as sd
     i32, f32 = jnp.int32, jnp.float32
     dec = indexed_decoder
@@ -1105,9 +1123,12 @@ def test_indexed_latent_serve_bodies_compile_for_v5e(v5e, indexed_decoder,
     text = compiled.as_text()
     assert _kernel_calls(text, "index_select") == 2
     # no loop over rows or queries: the only ones are the grouped
-    # product's own search for its groups' tiles
-    assert all("jit(gmm)" in ln for ln in text.splitlines()
-               if " while(" in ln)
+    # product's own search for its groups' tiles and, in a chunk, the
+    # walk over the pages under its start (two branches a layer)
+    loops = [ln for ln in text.splitlines()
+             if " while(" in ln and "jit(gmm)" not in ln]
+    assert all("_latent_chunk_walk" in ln for ln in loops)
+    assert len(loops) == (0 if body == "decode" else 2 * 5)
     # the only sorts are the four routers' top 8 of 256 scores a token:
     # none over a query's keys
     sorts = [re.search(r"= \(?\w+\[([\d,]+)\]", ln).group(1).split(",")
@@ -1120,8 +1141,13 @@ def test_indexed_latent_serve_bodies_compile_for_v5e(v5e, indexed_decoder,
         assert _kernel_calls(text, "paged_flash_decode") == 0
         assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
     else:
-        assert _kernel_calls(text, "latent_sparse_chunk") == 5
-        assert _kernel_calls(text, "paged_flash_decode") == 5
+        assert _kernel_calls(text, "latent_sparse_chunk") == 0
+        assert _kernel_calls(text, "paged_flash_decode") == 0
+        assert _kernel_operands(text, "flash_fwd_chunk") == [3] * 5 + [6] * 5
+        assert _kernel_operands(text, "latent_sparse_chunk_expanded"
+                                ) == [4] * 5 + [7] * 5
         assert "s8[1,64,72,32,512]" in text
+        assert "s8[1,2048,2048]" in text and "s8[1,64,4,32,512]" in text
+        assert "s8[1,2048,36864]" not in text   # never the whole of it
         # 5.8e9 B of pools are donated and updated in place
         assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9

@@ -13,8 +13,16 @@ stream of the row's pages under a mask (``latent_sparse_*``, the form the
 model runs), the same attention as A GATHER A QUERY (XLA's gather of the
 chosen rows, 64 queries at a time: the form it is measured against) and the
 dense latent kernel over every visible row; and it holds the kernels'
-results to their oracles at the first context.  It needs the TPU; nothing
-here runs in the tests and nothing a cell runs imports it.
+results to their oracles at the first context.  ``expanded`` lines (PR 56):
+the same chunk attending EXPANDED under its membership
+(``latent_chunk_attention`` with ``member``: kernel + the keys' expansion
+through ``kv_b``), at both score forms — the rotary key in each head's row
+or as a shared second product —, beside the same walk with no membership
+(what the mask costs), the membership's relayout to a row a query alone,
+and the membership fed both ways: tiled through a ``BlockSpec`` (the
+program's: a step's blocks as they lie) or as that row a query, the whole
+walk and ONE step of it.  It needs the TPU; nothing here
+runs in the tests and nothing a cell runs imports it.
 """
 
 from __future__ import annotations
@@ -36,9 +44,12 @@ if REPO not in sys.path:
 
 from dtf_tpu.ops import index_select as ix  # noqa: E402
 
+from dtf_tpu.ops.flash_attention import flash_forward  # noqa: E402
+
 pa = importlib.import_module("dtf_tpu.ops.paged_attention")
 bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
 H, W, V, HI, DI, TOP, S, ROWS = 64, 640, 512, 32, 128, 2048, 2048, 16
+NOPE, ROPE, DV = 192, 64, 256
 SCALE = 256 ** -0.5
 
 
@@ -95,12 +106,88 @@ def untiled(tiled, s: int, n: int):
         b, g * tile, blocks * mb)[:, :s, :n] != 0)
 
 
+def expanded_lines(say, context, check, latent, table, index, member, sparse,
+                   ks):
+    """The ``expanded`` lines at one context; ``check``: the masked stream
+    (``sparse``) over the ABSORBED image of the queries drawn here is the
+    same attention, and the two are held together."""
+    w_kvb = (jax.random.normal(ks[0], (V, H, NOPE + DV), f32) * 0.04
+             ).astype(bf16)
+    q = (jax.random.normal(ks[1], (1, S, H, NOPE + ROPE), f32) * 0.5
+         ).astype(bf16)
+    page = latent.shape[1]
+    # the chunk's own rows as the pool holds them
+    at = index[0] // page + jnp.arange(S // page, dtype=i32)
+    rows = latent[table[0, at]].reshape(1, S, W)
+    ppb = pa.EXPAND_KEYS // page
+    flat = jax.jit(pa.member_rows)
+    named = flat(member)
+    ms = {"member_rows_ms": timed(flat, member)}
+    walk = {}
+    for form, in_head in (("in_head", True), ("shared", False)):
+        walk[form] = jax.jit(
+            lambda q, rows, w, pool, table, index, member, in_head=in_head:
+            pa._latent_chunk_walk(
+                q, rows, w, pool, table, index, member, rank=V, nope=NOPE,
+                scale=SCALE, use_pallas=True, ppb=ppb, in_head=in_head))
+        # the membership tiled, as the program feeds it (a step's blocks
+        # as they lie), and laid out a row a query beforehand
+        ms[f"expanded_{form}_ms"] = timed(
+            walk[form], q, rows, w_kvb, latent, table, index, member)
+        ms[f"expanded_{form}_rows_ms"] = timed(
+            walk[form], q, rows, w_kvb, latent, table, index, named)
+        ms[f"expanded_{form}_no_member_ms"] = timed(
+            walk[form], q, rows, w_kvb, latent, table, index, None)
+    say(what="chunk_expanded", context=context, **ms)
+    # ONE step of the walk, 2,048 expanded keys, the membership fed two ways
+    t = pa.EXPAND_KEYS
+    kk = (jax.random.normal(ks[2], (1, H, t, NOPE + ROPE), f32) * 0.5
+          ).astype(bf16)
+    vv = kk[..., :DV]
+    qh = jnp.swapaxes(q, 1, 2)
+    o0 = jnp.zeros((1, H, S, DV), f32)
+    lse0 = jnp.zeros((1, H, S, 1), f32)
+    n_live = jnp.full((1,), t, i32)
+    step = jax.jit(lambda qh, kk, vv, member, o, lse: flash_forward(
+        qh, kk, vv, scale=SCALE, kv_len=n_live, carry=(o, lse),
+        member=member, name="latent_sparse_chunk_expanded"))
+    blocks = t // ix.MEMBER_BLOCK
+    say(what="expanded_step_feed", context=context, keys=t,
+        a_row_a_query_ms=timed(step, qh, kk, vv, named[:, :, :t], o0, lse0,
+                               calls=6),
+        tiled_blockspec_ms=timed(step, qh, kk, vv, member[:, :, :blocks],
+                                 o0, lse0, calls=6))
+    if check:
+        # the absorbed stream over the absorbed image of the same queries
+        q_img = jnp.einsum("bshn,rhn->bshr", q[..., :NOPE],
+                           w_kvb[..., :NOPE], preferred_element_type=f32)
+        q_img = jnp.concatenate(
+            [q_img.astype(bf16), q[..., NOPE:],
+             jnp.zeros((1, S, H, W - V - ROPE), bf16)], -1)
+        o_abs = jnp.einsum("bshr,rhv->bshv",
+                           sparse(q_img, latent, table, index, member),
+                           w_kvb[..., NOPE:], preferred_element_type=f32)
+        o_abs = np.asarray(o_abs)
+        for form in walk:
+            o = np.asarray(walk[form](q, rows, w_kvb, latent, table, index,
+                                      member).astype(f32))
+            say(what="expanded_agreement", context=context, form=form,
+                o_max_diff=float(np.abs(o - o_abs).max()),
+                o_rms_diff=float(np.sqrt(np.mean((o - o_abs) ** 2))),
+                o_rms=float(np.sqrt(np.mean(o_abs ** 2))))
+
+
 def main(argv=None) -> int:
     p = argparse.ArgumentParser()
     p.add_argument("--out", default="chiprun_out/index_sweep.jsonl")
     p.add_argument("--page", type=int, default=256)
     p.add_argument("--contexts", default="8192,32768,65536")
+    p.add_argument("--what", default="chunk,expanded,rest",
+                   help="which lines: chunk (the choice, the masked stream, "
+                        "the dense kernel), expanded, rest (the gather a "
+                        "query, the decode step)")
     args = p.parse_args(argv)
+    what = set(args.what.split(","))
     if jax.default_backend() != "tpu":
         raise SystemExit("the sweep needs the TPU")
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
@@ -134,10 +221,17 @@ def main(argv=None) -> int:
                                                 value_lanes=V, scale=SCALE))
         dense = jax.jit(lambda q, pool, table, index: pa.paged_flash_decode(
             q, pool, None, table, index, scale=SCALE, value_lanes=V))
-        say(what="chunk", context=context,
-            index_select_ms=timed(select, qi, wi, keys, table, index),
-            latent_sparse_ms=timed(sparse, q, latent, table, index, member),
-            dense_latent_ms=timed(dense, q, latent, table, index))
+        if "chunk" in what:
+            say(what="chunk", context=context,
+                index_select_ms=timed(select, qi, wi, keys, table, index),
+                latent_sparse_ms=timed(sparse, q, latent, table, index,
+                                       member),
+                dense_latent_ms=timed(dense, q, latent, table, index))
+        if "expanded" in what:
+            expanded_lines(say, context, n == 0, latent, table, index,
+                           member, sparse, ks[5:8])
+        if "rest" not in what:
+            continue
         # the gather a query, 64 queries of the chunk at a time
         tq = 64
         t = index[:, None] + jnp.arange(tq, dtype=i32)[None] + S - tq
