@@ -151,6 +151,25 @@ _REGISTRY = {
             router_bias_stddev=0.05, activation="silu",
             router_input="post_attention", experts_held=(4, 4)),
         32_768, 0.0),
+    # and the GATED forms: the delta rule with ONE decay a head and 4 key
+    # heads under 8 value heads behind a silu(z) gate, three layers in four,
+    # beside gated grouped-query attention (a partial rotary, zero-centred
+    # norms of q and k, an output gate on the query projection); every
+    # layer top-4 of 16 softmax-routed experts, of which this device holds
+    # 4, beside a shared expert under a scalar gate
+    "routed_decoder_gated": (
+        functools.partial(
+            routed_decoder.RoutedDecoderLM, num_layers=8, d_model=512,
+            num_heads=8, num_kv_heads=2, head_dim=64, rotary_dim=16,
+            layer_mixer=("linear_delta",) * 3 + ("attention",),
+            layer_window=(False,), layer_rope=(True,), rope_theta=1e7,
+            qk_norm=True, attention_output_gate=True, norm_unit_offset=True,
+            linear_heads=8, linear_key_heads=4, linear_head_dim=64,
+            linear_decay="head", linear_gate="silu", num_experts=16,
+            experts_per_token=4, expert_width=128, shared_expert_width=128,
+            shared_expert_gate=True, experts_held=(0, 4), activation="silu",
+            router_input="post_attention"),
+        32_768, 0.0),
     # pipeline-stacked LM family (pipeline stages over 'model')
     "pipeline_transformer": (pipeline_lm.PipelinedTransformerLM,
                              32_768, 0.0),

@@ -22,7 +22,12 @@ num_kv_heads)``), and two tuples, one entry a layer, say
                        on q and k, applied to k BEFORE it is written to
                        the cache; False: no positional signal at all.
 
-The cache is K and V pools ``[P, page, Hkv, Dh]``.  ``kv_lora_rank`` set —
+The cache is K and V pools ``[P, page, Hkv, Dh]``.  ``rotary_dim`` set:
+only the first that many lanes of a head turn, the others carry no
+position; ``attention_output_gate``: the query projection carries a gate a
+(head, lane) — columns ``[q | k | v | gate]`` — and the heads' outputs are
+multiplied by its sigmoid before ``out``; ``qk_norm``'s two norms follow
+``norm_unit_offset``.  ``kv_lora_rank`` set —
 latent attention (:class:`LatentAttention`) in every ATTENTION layer, over
 the whole history: the cache is ONE pool ``[P, page, W]`` a layer whose row is a
 token's normed latent and its one rotary key (``kv_lora_rank +
@@ -98,7 +103,19 @@ returns ``o`` so: a head's two reductions are one MXU product of its
 matrix AS STORED against the bfloat16 pieces of ``exp(a) k`` and ``exp(a)
 q``, float32 arithmetic whose form follows the pool's dtype), a chunk of
 whole pages through the blocked form, the full forward outside decode
-mode through the token-by-token recurrence.  Mixers of different kinds stand
+mode through the token-by-token recurrence.  The GATED form of the same
+layer is three fields: ``linear_decay`` ``head`` — the log decay is ONE
+SCALAR a head, ``-exp(a_log) * softplus(h W_a + dt_bias)``, no floor, ``[b
+| a]`` one projection; ``linear_key_heads`` — fewer key heads than value
+heads, value head ``i`` reading the q and k of key head ``i //
+(linear_heads // linear_key_heads)`` (the projection, the filter and the
+``conv_state`` entry are ``2 * key + value`` channels wide, not ``3 n``);
+``linear_gate`` ``silu`` — the gate's input ``z`` rides the one projection
+``[q | k | v | z]`` and ``silu(z)`` multiplies the plain-weight norm.  The
+state forms and the kernel take a decay a channel and a key row a value
+head: such a layer hands them the broadcast decay and the repeated rows, so
+its decode step is the same kernel under the same name.  Mixers of
+different kinds stand
 beside EITHER attention kind: ``layer_mixer`` decides a layer,
 ``kv_lora_rank`` what its attention layers are (``short_conv`` alone still
 wants whole heads).
@@ -134,8 +151,10 @@ every token takes its ``experts_per_token`` best, by ``routing``
 
 from the norm ``router_input`` names (``pre_attention``: the layer's
 first norm, before attention runs; ``post_attention``: the second), and
-add a shared expert of ``shared_expert_width`` (0: none) for every token.
-``route_groups`` > 1 puts a GROUP LIMIT on the ``sigmoid_bias`` choice: the
+add a shared expert of ``shared_expert_width`` (0: none) for every token
+(``shared_expert_gate``: its output times ``sigmoid(h2 w)``, ONE scalar a
+token).  ``route_groups`` > 1 puts a GROUP LIMIT on the ``sigmoid_bias``
+choice: the
 experts are that many runs of consecutive ids, a group's score is the sum
 of its 2 largest biased scores, and a token chooses its
 ``experts_per_token`` within its ``route_groups_kept`` best groups.
@@ -607,6 +626,17 @@ class GroupedQueryAttention(nn.Module):
     # (window, chunk): exact keys of the query's own aligned window beside
     # one learned summary a chunk of every closed one (ops/window_summary)
     summary: Optional[Tuple[int, int]] = None
+    # the first rotary_dim lanes of a head turn, the others carry no
+    # position (None: the whole head)
+    rotary_dim: Optional[int] = None
+    # the query projection carries a gate a (head, lane) as well: the
+    # heads' outputs are multiplied by its sigmoid before ``out``
+    output_gate: bool = False
+    qk_norm_unit_offset: bool = False   # q_norm, k_norm scale by 1 + w
+    # the scale q_norm and k_norm START at (1: the identity).  Under random
+    # weights a gain of 1 leaves the scores' spread at 1 and a softmax over
+    # thousands of keys near flat; a served model's is not
+    qk_norm_gain: float = 1.0
 
     @nn.compact
     def __call__(self, h, positions, cache_index=None, block_table=None,
@@ -624,26 +654,42 @@ class GroupedQueryAttention(nn.Module):
             phi = self.param("summary_phi", init, (hkv, dh),
                              self.param_dtype)
             mu = self.param("summary_mu", init, (hkv, dh), self.param_dtype)
-        w_qkv = self.param("qkv", _normal(0.02), (d, (hq + 2 * hkv) * dh),
+        # columns [q | k | v] and, with an output gate, [.. | gate]
+        gated = hq * dh if self.output_gate else 0
+        w_qkv = self.param("qkv", _normal(0.02),
+                           (d, (hq + 2 * hkv) * dh + gated),
                            self.param_dtype)
         w_out = self.param("out", _normal(0.02), (hq * dh, d),
                            self.param_dtype)
         qkv = jnp.einsum("bsd,dn->bsn", h.astype(self.dtype),
                          w_qkv.astype(self.dtype),
-                         preferred_element_type=jnp.float32
-                         ).astype(self.dtype)
+                         preferred_element_type=jnp.float32)
+        if self.output_gate:
+            qkv, gate = (qkv[..., :(hq + 2 * hkv) * dh],
+                         qkv[..., (hq + 2 * hkv) * dh:])
+        qkv = qkv.astype(self.dtype)
         q = qkv[..., :hq * dh].reshape(b, s, hq, dh)
         k = qkv[..., hq * dh:(hq + hkv) * dh].reshape(b, s, hkv, dh)
         v = qkv[..., (hq + hkv) * dh:].reshape(b, s, hkv, dh)
         if self.qk_norm_eps is not None:
-            ones = nn.initializers.ones
-            q = rms_norm(q, self.param("q_norm", ones, (dh,),
-                                       self.param_dtype), self.qk_norm_eps)
-            k = rms_norm(k, self.param("k_norm", ones, (dh,),
-                                       self.param_dtype), self.qk_norm_eps)
+            offset = self.qk_norm_unit_offset
+            init = nn.initializers.constant(self.qk_norm_gain - float(offset))
+            q = rms_norm(q, self.param("q_norm", init, (dh,),
+                                       self.param_dtype), self.qk_norm_eps,
+                         offset)
+            k = rms_norm(k, self.param("k_norm", init, (dh,),
+                                       self.param_dtype), self.qk_norm_eps,
+                         offset)
         if self.rope_theta is not None:
-            q = rotate_half_rope(q, positions, self.rope_theta)
-            k = rotate_half_rope(k, positions, self.rope_theta)
+            r = self.rotary_dim
+            if r is None:
+                q = rotate_half_rope(q, positions, self.rope_theta)
+                k = rotate_half_rope(k, positions, self.rope_theta)
+            else:
+                q, k = (jnp.concatenate(
+                    [rotate_half_rope(x[..., :r], positions,
+                                      self.rope_theta), x[..., r:]], -1)
+                    for x in (q, k))
         if self.decode:
             if self.kv_page_size is None:
                 raise ValueError("decode mode needs kv_page_size and "
@@ -690,8 +736,10 @@ class GroupedQueryAttention(nn.Module):
                      < first[:, None]], axis=1)
             o = cached_attention(q, kr, vr,
                                  jnp.broadcast_to(mask, (b,) + mask.shape))
-        return jnp.einsum("bsn,nd->bsd", o.reshape(b, s, hq * dh),
-                          w_out.astype(self.dtype),
+        o = o.reshape(b, s, hq * dh)
+        if self.output_gate:
+            o = (o * jax.nn.sigmoid(gate)).astype(self.dtype)
+        return jnp.einsum("bsn,nd->bsd", o, w_out.astype(self.dtype),
                           preferred_element_type=jnp.float32)
 
 
@@ -832,6 +880,18 @@ class LinearDelta(nn.Module):
     W_beta)`` a head; state and output as ``linear_state`` defines them;
     then ``(RMSNorm_head(o) * sigmoid(h W_gate)) W_out``.
 
+    The GATED form, by three fields.  ``decay`` ``head``: ``[b | a] = h
+    W_ba`` (one projection of ``2 heads``), ``beta = sigmoid(b)`` and the
+    log decay ``-exp(a_log) * softplus(a + dt_bias)``, ONE SCALAR a head
+    with no floor (``decay_floor`` is not read), handed to the state forms
+    broadcast over the head's channels.  ``key_heads``: ``q`` and ``k`` have
+    that many heads, fewer than ``heads``; value head ``i`` reads key head
+    ``i // (heads // key_heads)``, the rows repeated for the state forms;
+    the filter runs over ``2 * key_heads * head_dim + heads * head_dim``
+    channels and ``conv_state`` holds as many.  ``gate`` ``silu``: the one
+    projection is ``[q | k | v | z]`` (parameter ``qkvz``) and the output
+    is ``(RMSNorm_head(o) * silu(z)) W_out``.
+
     Matmuls take ``dtype`` inputs and accumulate in f32; the filters'
     inputs are rounded to ``dtype`` before the taps in every form, because
     that is what their state holds (as :class:`ShortConv`); the gates, the
@@ -841,8 +901,9 @@ class LinearDelta(nn.Module):
     page id and each holding, for page ``p``, the running state at the
     newest token written in ``p`` — ``linear_state`` ``[P, heads, head_dim,
     head_dim]`` (the matrices, transposed, in ``dtype``) and
-    ``conv_state`` ``[P, sublanes, (taps - 1) * 3 * heads * head_dim /
-    sublanes]``: the last ``taps - 1`` inputs ``[u_{t-2} | u_{t-1} | u_t]``,
+    ``conv_state`` ``[P, sublanes, (taps - 1) * channels / sublanes]``
+    (``channels`` ``3 * heads * head_dim``, or the gated form's): the last
+    ``taps - 1`` inputs ``[u_{t-2} | u_{t-1} | u_t]``,
     oldest first, row-major, laid out as the page's own whole tiles
     (``sublanes`` what one tile of ``dtype`` holds: 16 in bfloat16, 8 in
     float32).  One token
@@ -863,35 +924,62 @@ class LinearDelta(nn.Module):
     decode: bool = False
     kv_page_size: Optional[int] = None
     kv_pool_pages: Optional[int] = None
+    key_heads: Optional[int] = None     # None: as many as ``heads``
+    decay: str = "channel"              # | head
+    gate: str = "sigmoid"               # | silu
 
     @nn.compact
     def __call__(self, h, cache_index=None, block_table=None, last_pos=None):
         b, s, d = h.shape
         hn, dh, keep = self.heads, self.head_dim, self.taps - 1
-        n = hn * dh
+        kh = hn if self.key_heads is None else self.key_heads
+        if hn % kh:
+            raise ValueError(f"{hn} value heads do not share {kh} key heads")
+        if (self.decay not in ("channel", "head")
+                or self.gate not in ("sigmoid", "silu")):
+            raise ValueError(f"decay {self.decay!r} (channel | head), gate "
+                             f"{self.gate!r} (sigmoid | silu)")
+        n, kn = hn * dh, kh * dh
+        c = 2 * kn + n                      # the filters' channels
         pdt = self.param_dtype
-        w_qkv = self.param("qkv", _normal(0.02), (d, 3 * n), pdt)
-        w_decay = self.param("decay", _normal(0.02), (d, n), pdt)
-        w_gate = self.param("gate", _normal(0.02), (d, n), pdt)
-        w_beta = self.param("beta", _normal(0.02), (d, hn), pdt)
+        by_head = self.decay == "head"
+        # a parameter's draw follows its place in this order: the forms'
+        # parameters stand where the first form's stood, so that a seed
+        # gives that form the weights it always gave
+        if self.gate == "silu":
+            # the output gate's input z rides the one projection
+            w_qkv = self.param("qkvz", _normal(0.02), (d, c + n), pdt)
+        else:
+            w_qkv = self.param("qkv", _normal(0.02), (d, c), pdt)
+        if by_head:
+            w_ba = self.param("ba", _normal(0.02), (d, 2 * hn), pdt)
+        else:
+            w_decay = self.param("decay", _normal(0.02), (d, n), pdt)
+        if self.gate == "sigmoid":
+            w_gate = self.param("gate", _normal(0.02), (d, n), pdt)
+        if not by_head:
+            w_beta = self.param("beta", _normal(0.02), (d, hn), pdt)
         w_out = self.param("out", _normal(0.02), (n, d), pdt)
-        w = self.param("taps", _normal(0.3), (3 * n, self.taps),
+        w = self.param("taps", _normal(0.3), (c, self.taps),
                        pdt).astype(jnp.float32)
         # f32 whatever param_dtype: they meet f32 gates.  Time constants
         # from a token to a few thousand, spread evenly in the logarithm
         a_log = self.param("a_log", _log_uniform(1.0, 2.0), (hn,),
                            jnp.float32)
-        dt_bias = self.param("dt_bias", _uniform(-8.0, 0.0), (n,),
-                             jnp.float32)
+        dt_bias = self.param("dt_bias", _uniform(-8.0, 0.0),
+                             (hn if by_head else n,), jnp.float32)
         g_out = self.param("out_norm", nn.initializers.ones, (dh,), pdt)
 
         def mm(x, w_):
             return jnp.einsum("bsd,dn->bsn", x.astype(self.dtype),
                               w_.astype(self.dtype),
                               preferred_element_type=jnp.float32)
-        pre = mm(h, w_qkv).astype(self.dtype)
+        pre = mm(h, w_qkv)
+        if self.gate == "silu":
+            pre, z = pre[..., :c], pre[..., c:]
+        pre = pre.astype(self.dtype)
         paged = self.decode and not self.is_initializing()
-        carry = jnp.zeros((b, keep, 3 * n), self.dtype)
+        carry = jnp.zeros((b, keep, c), self.dtype)
         if self.decode:
             if self.kv_page_size is None:
                 raise ValueError("decode mode needs kv_page_size and "
@@ -906,13 +994,13 @@ class LinearDelta(nn.Module):
             # pages share, and its scatter compiles to a serial loop of
             # dynamic-update-slice, one trip a row
             sublanes = 32 // jnp.dtype(self.dtype).itemsize
-            if keep * 3 * n % sublanes:
+            if keep * c % sublanes:
                 raise ValueError(
-                    f"the filter inputs' state entry of {keep} x 3 x {hn} "
-                    f"x {dh} = {keep * 3 * n} {jnp.dtype(self.dtype).name}"
+                    f"the filter inputs' state entry of {keep} x {c} = "
+                    f"{keep * c} {jnp.dtype(self.dtype).name}"
                     f" values does not divide into the {sublanes} "
                     f"sublanes of one tile")
-            entry = (sublanes, keep * 3 * n // sublanes)
+            entry = (sublanes, keep * c // sublanes)
             conv_state = self.variable(
                 "cache", "conv_state", jnp.zeros,
                 (self.kv_pool_pages,) + entry, self.dtype)
@@ -929,22 +1017,35 @@ class LinearDelta(nn.Module):
             has_carry = (cache_index > 0)[:, None, None]
             before = _carry_page(cache_index, block_table, page)
             carry = jnp.where(
-                has_carry, conv_state.value[before].reshape(b, keep, 3 * n),
+                has_carry, conv_state.value[before].reshape(b, keep, c),
                 carry)
-        full = jnp.concatenate([carry, pre], axis=1)    # [B, keep+S, 3n]
+        full = jnp.concatenate([carry, pre], axis=1)    # [B, keep+S, c]
         qkv = jax.nn.silu(sum(w[:, j] * full[:, j:j + s].astype(jnp.float32)
                               for j in range(self.taps)))
-        q, k, v = (qkv[..., i * n:(i + 1) * n].reshape(b, s, hn, dh)
-                   for i in range(3))
+        q = qkv[..., :kn].reshape(b, s, kh, dh)
+        k = qkv[..., kn:2 * kn].reshape(b, s, kh, dh)
+        v = qkv[..., 2 * kn:].reshape(b, s, hn, dh)
 
         def unit(x):
             return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True)
                                      + 1e-6)
         q, k = unit(q) * dh ** -0.5, unit(k)
-        a = self.decay_floor * jax.nn.sigmoid(
-            jnp.repeat(jnp.exp(a_log), dh) * (mm(h, w_decay) + dt_bias)
-        ).reshape(b, s, hn, dh)
-        beta = jax.nn.sigmoid(mm(h, w_beta))            # [B, S, H]
+        if kh != hn:
+            # value head i reads key head i // (hn // kh): the state forms
+            # take one q and k row a value head
+            q, k = (jnp.repeat(x, hn // kh, axis=2) for x in (q, k))
+        if by_head:
+            # one scalar a head, no floor; the state forms take a channel's
+            ba = mm(h, w_ba)
+            a = jnp.broadcast_to(
+                (-jnp.exp(a_log) * jax.nn.softplus(ba[..., hn:] + dt_bias)
+                 )[..., None], (b, s, hn, dh))
+            beta = jax.nn.sigmoid(ba[..., :hn])         # [B, S, H]
+        else:
+            a = self.decay_floor * jax.nn.sigmoid(
+                jnp.repeat(jnp.exp(a_log), dh) * (mm(h, w_decay) + dt_bias)
+            ).reshape(b, s, hn, dh)
+            beta = jax.nn.sigmoid(mm(h, w_beta))        # [B, S, H]
         if paged and s > 1 and last_pos is not None:
             real = (jnp.arange(s, dtype=jnp.int32)[None, :]
                     <= last_pos[:, None])
@@ -990,7 +1091,8 @@ class LinearDelta(nn.Module):
                     states.reshape((b * pages_n,) + states.shape[2:]
                                    ).astype(state.value.dtype))
         o = rms_norm(o, g_out, self.rms_eps).reshape(b, s, n)
-        y = mm(o * jax.nn.sigmoid(mm(h, w_gate)), w_out)
+        y = mm(o * (jax.nn.silu(z) if self.gate == "silu"
+                    else jax.nn.sigmoid(mm(h, w_gate))), w_out)
         return y, advanced
 
 
@@ -1673,7 +1775,8 @@ class RoutedBlock(nn.Module):
     conv_taps: int = 3
     qk_norm: bool = False
     routing_sum_eps: float = 0.0
-    linear: Optional[Tuple] = None      # (heads, head dim, taps, decay floor)
+    # (heads, head dim, taps, decay floor, key heads, decay, gate)
+    linear: Optional[Tuple] = None
     q_head_norm: bool = False
     attention_head_gate: bool = False
     route_groups: int = 1
@@ -1688,6 +1791,12 @@ class RoutedBlock(nn.Module):
     residual_scale: float = 1.0         # on both branches of the layer
     # LatentAttention's: (full | shared, heads, head dim, top, rotary dims)
     indexer: Optional[Tuple] = None
+    # GroupedQueryAttention's rotary_dim and output_gate; the shared
+    # expert's output times sigmoid(h2 w), one scalar a token
+    rotary_dim: Optional[int] = None
+    attention_output_gate: bool = False
+    shared_expert_gate: bool = False
+    qk_norm_gain: float = 1.0
 
     @nn.compact
     def __call__(self, x, positions, cache_index=None, block_table=None,
@@ -1737,11 +1846,13 @@ class RoutedBlock(nn.Module):
                 kv_pool_pages=self.kv_pool_pages, name="conv")(
                     h, cache_index, block_table, last_pos)
         elif self.mixer == "linear_delta":
+            *widths, key_heads, decay, gate = self.linear
             attn, advanced = LinearDelta(
-                *self.linear, self.rms_eps, self.dtype, pdt,
+                *widths, self.rms_eps, self.dtype, pdt,
                 use_pallas=self.use_pallas, decode=self.decode,
                 kv_page_size=self.kv_page_size,
-                kv_pool_pages=self.kv_pool_pages, name="linear")(
+                kv_pool_pages=self.kv_pool_pages, key_heads=key_heads,
+                decay=decay, gate=gate, name="linear")(
                     h, cache_index, block_table, last_pos)
         elif self.mixer == "sparse_block":
             attn, streamed = SparseBlockAttention(
@@ -1766,7 +1877,10 @@ class RoutedBlock(nn.Module):
                 kv_page_size=self.kv_page_size,
                 kv_pool_pages=self.kv_pool_pages,
                 qk_norm_eps=self.rms_eps if self.qk_norm else None,
-                summary=self.summary, name="attn")(
+                summary=self.summary, rotary_dim=self.rotary_dim,
+                output_gate=self.attention_output_gate,
+                qk_norm_unit_offset=offset,
+                qk_norm_gain=self.qk_norm_gain, name="attn")(
                     h, positions, cache_index, block_table, flash_prefill,
                     window_pages)
         else:
@@ -1808,13 +1922,20 @@ class RoutedBlock(nn.Module):
                                   held=self.experts_held)
         if self.shared_expert_width:
             fs = self.shared_expert_width
-            y = y + gated_mlp(
+            shared = gated_mlp(
                 h2.astype(self.dtype),
                 self.param("shared_gate_up", _normal(0.02), (d, 2 * fs),
                            pdt).astype(self.dtype),
                 self.param("shared_down", _normal(0.02), (fs, d),
                            pdt).astype(self.dtype),
                 self.activation)
+            if self.shared_expert_gate:
+                shared = shared * jax.nn.sigmoid(jnp.einsum(
+                    "td,dn->tn", h2.astype(self.dtype),
+                    self.param("shared_gate", _normal(0.02), (d, 1),
+                               pdt).astype(self.dtype),
+                    preferred_element_type=jnp.float32))
+            y = y + shared
         if self.residual_scale != 1.0:
             y = y * self.residual_scale
         return x + y.reshape(b, s, d), sizes, advanced, streamed, chosen
@@ -1929,6 +2050,27 @@ class RoutedDecoderLM(nn.Module):
     # layer below, the one value that crosses layers
     indexer: Optional[Tuple] = None
     layer_indexer: Tuple[str, ...] = ()
+    # the GATED forms.  linear_delta layers: linear_key_heads (None: as
+    # many as linear_heads) key heads, each met by linear_heads //
+    # linear_key_heads value heads' states; linear_decay channel | head —
+    # head: the log decay is ONE SCALAR a head, -exp(a_log) * softplus(h
+    # W_a + dt_bias), and linear_decay_floor is not read; linear_gate
+    # sigmoid (a projection of its own) | silu (z, part of the one
+    # projection, on a plain-weight norm).  Whole-head attention layers:
+    # rotary_dim (None: the whole head) lanes of a head turn;
+    # attention_output_gate: the query projection carries a gate a (head,
+    # lane) whose sigmoid multiplies the heads' outputs; q_norm and k_norm
+    # follow norm_unit_offset and START at qk_norm_gain (an initialiser:
+    # what random weights need for a softmax that is not flat).
+    # shared_expert_gate: the shared expert's output times sigmoid(h2 w),
+    # one scalar a token
+    linear_key_heads: Optional[int] = None
+    linear_decay: str = "channel"
+    linear_gate: str = "sigmoid"
+    rotary_dim: Optional[int] = None
+    attention_output_gate: bool = False
+    shared_expert_gate: bool = False
+    qk_norm_gain: float = 1.0
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
     use_pallas: Any = None
@@ -2101,7 +2243,9 @@ class RoutedDecoderLM(nn.Module):
         touched = load_max = advanced = computed = streamed = jnp.zeros(
             (), jnp.int32)
         linear = (self.linear_heads, self.linear_head_dim,
-                  self.linear_conv_taps, self.linear_decay_floor)
+                  self.linear_conv_taps, self.linear_decay_floor,
+                  self.linear_key_heads, self.linear_decay,
+                  self.linear_gate)
         chosen, chosen_rows, picked_rows = None, 0, 0
         for i, (window, theta) in enumerate(kinds):
             x, sizes, rows, copied, chosen = RoutedBlock(
@@ -2134,6 +2278,10 @@ class RoutedDecoderLM(nn.Module):
                 residual_scale=residual_scale,
                 indexer=(None if self.indexer is None
                          else (kinds_i[i],) + tuple(self.indexer)),
+                rotary_dim=self.rotary_dim,
+                attention_output_gate=self.attention_output_gate,
+                shared_expert_gate=self.shared_expert_gate,
+                qk_norm_gain=self.qk_norm_gain,
                 name=f"layer{i}")(
                     x, positions, cache_index, block_table, flash_prefill,
                     window_pages, last_pos, chosen)

@@ -172,14 +172,12 @@ def serving_memory_plan(model, *, num_slots: int, max_seq_len: int,
 
     from dtf_tpu.ops import window_summary
     from dtf_tpu.serve.decode import (KV_POOL, LATENT_POOL, cache_leaves,
+                                      kv_bytes_per_token,
                                       state_bytes_per_page, trace_paged_init)
 
     shapes = trace_paged_init(model, kv_page_size, 2)[0]
     kinds, pools = zip(*cache_leaves(shapes, KV_POOL, LATENT_POOL))
-    # a pool's bytes a page over the page's tokens: a token's row, or its
-    # share of the rows a page holds (a block-sparse layer's pooled keys)
-    per_token = sum(int(np.prod(p.shape[1:])) * np.dtype(p.dtype).itemsize
-                    // kv_page_size for p in pools)
+    per_token = kv_bytes_per_token(shapes, kv_page_size)
     per_page_state = state_bytes_per_page(shapes)
     # [P, page, H, Dh] a K or V pool; [P, page, W] a pool of latent rows:
     # the geometry reported is the widest pool's (the first of them; an

@@ -168,6 +168,16 @@ def state_bytes_per_page(cache_shapes) -> int:
                for _, p in cache_leaves(cache_shapes, PAGE_STATE))
 
 
+def kv_bytes_per_token(cache_shapes, kv_page_size: int) -> int:
+    """Bytes a cached token occupies over every pool of every layer (the
+    ``KV_POOL`` and ``LATENT_POOL`` leaves): a pool's bytes a page over the
+    page's tokens — a token's row, or its share of the rows a page holds (a
+    block-sparse layer's pooled keys)."""
+    return sum(math.prod(p.shape[1:]) * jnp.dtype(p.dtype).itemsize
+               // kv_page_size
+               for _, p in cache_leaves(cache_shapes, KV_POOL, LATENT_POOL))
+
+
 def index_bytes_per_token(cache_shapes) -> int:
     """Bytes of INDEX KEYS a cached token occupies over the layers that
     keep them (the ``index_key`` leaves of a model with a lightning
@@ -638,6 +648,10 @@ class Decoder:
     @property
     def state_bytes_per_page(self) -> int:
         return state_bytes_per_page(self._init_trace[0])
+
+    @property
+    def kv_bytes_per_token(self) -> int:
+        return kv_bytes_per_token(self._init_trace[0], self.page_size)
 
     @property
     def index_bytes_per_token(self) -> int:

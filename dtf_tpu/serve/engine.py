@@ -639,6 +639,11 @@ class ServeEngine:
         # running-state entries (0 for a model whose layers all attend)
         self.metrics.gauge("serve_state_bytes_per_page", unit="bytes").set(
             self.decoder.state_bytes_per_page)
+        # what a token holds in the pools of every layer that keeps one: K
+        # and V, latent rows, index keys (with the gauge above, the two
+        # kinds of bytes a model of pools BESIDE state entries lays out)
+        self.metrics.gauge("serve_kv_bytes_per_token", unit="bytes").set(
+            self.decoder.kv_bytes_per_token)
         # what a token holds beside its cache rows: a lightning indexer's
         # keys over the layers that choose (0 for every other model)
         self.metrics.gauge("serve_index_bytes_per_token", unit="bytes").set(

@@ -22,7 +22,7 @@ from dtf_tpu.models import build_model
 from dtf_tpu.serve.decode import Decoder
 
 FAMILIES = ("gpt2", "smallthinker", "joyai", "lfm2", "ling", "evabyte",
-            "minicpm_sala", "glm_dsa")
+            "minicpm_sala", "glm_dsa", "qwen3_next")
 
 
 @functools.lru_cache(maxsize=None)

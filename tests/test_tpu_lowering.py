@@ -1151,3 +1151,82 @@ def test_indexed_latent_serve_bodies_compile_for_v5e(v5e, indexed_decoder,
         assert "s8[1,2048,36864]" not in text   # never the whole of it
         # 5.8e9 B of pools are donated and updated in place
         assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+@pytest.fixture(scope="module")
+def gated_decoder():
+    """Qwen3-Next's share of the benchmark at its published widths (layers
+    0-7, L L L A twice: the gated delta rule of 32 value heads over 16 key
+    heads of 128 beside gated grouped-query attention of 16 / 2 heads of
+    256; 128 held of 512 experts of 512 beside a gated shared one; 37,984
+    vocabulary rows; shapes only), the cell's engine: 32 slots of 67,072
+    tokens, pages of 1,024 in a 622-page pool."""
+    import json
+    import os
+    from dtf_tpu.models import build_model
+    root = os.path.join(os.path.dirname(__file__), "..", "benchmark")
+    with open(os.path.join(root, "configs", "qwen3-next-80b-a3b.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(root, "workloads",
+                           "qwen3next-serve-hybriddoc.json")) as f:
+        engine = json.load(f)["engine"]
+    model, _ = build_model(cfg["build_model"]["name"],
+                           num_classes=cfg["num_classes"],
+                           dtype=jnp.bfloat16, **cfg["build_model"]["kwargs"])
+    params = jax.eval_shape(model.clone(use_pallas=False).init,
+                            jax.random.key(0),
+                            jnp.zeros((1, engine["kv_page_size"]), jnp.int32)
+                            )["params"]
+    dec = _shapes_only_decoder(
+        model, params, num_slots=engine["max_batch"],
+        max_seq_len=engine["max_seq_len"],
+        kv_page_size=engine["kv_page_size"],
+        kv_pool_pages=engine["kv_pool_pages"])
+    return dec, engine
+
+
+@pytest.mark.parametrize("body", ["chunk_first", "chunk", "decode"])
+def test_gated_delta_serve_bodies_compile_for_v5e(v5e, gated_decoder, body):
+    """The bodies of the decoder that keeps K and V pools of 256-WIDE heads
+    BESIDE matrix-a-head state entries, at the cell's own engine settings:
+    a first chunk through the flash forward at heads of 256 (two layers),
+    a continuation chunk and the decode step through ``paged_flash_decode``
+    over pages of 1,024 x 2 x 256 (8 query heads a KV head), the step's six
+    state layers through the one kernel every ``linear_delta`` model runs
+    (fed the broadcast decay and the repeated key rows), sixteen grouped
+    products over the 128 held experts; 14.04e9 B of weights, pools and
+    entries donated and updated in place.  (A page of 2,048 is REFUSED for
+    the chunk — 18.9 MiB of scoped VMEM where 16 are allowed: my compile,
+    PR 57 — which is why the cell's page is 1,024.)"""
+    from dtf_tpu.serve import decode as sd
+    i32, f32 = jnp.int32, jnp.float32
+    dec, engine = gated_decoder
+    assert dec.carries_state and not dec.decode_all_heads
+    assert dec.kv_bytes_per_token == 2 * 2 * 2 * 256 * 2
+    assert dec.state_bytes_per_page == 6 * (32 * 128 * 128 + 3 * 8192) * 2
+    if body == "decode":
+        compiled = _compile_decode_body(dec, v5e)
+    else:
+        s, m = jax.ShapeDtypeStruct, dec.pages_per_slot
+        args = _on_chip((dec.params, jax.eval_shape(dec.fresh_cache),
+                         s((1, engine["prefill_chunk"]), i32), s((1, m), i32),
+                         s((), i32), s((), f32),
+                         jax.eval_shape(lambda: sd.position_key(0, 0)),
+                         s((), i32)), v5e)
+        compiled = jax.jit(
+            dec._chunk_impl, donate_argnums=(1,), static_argnums=(8, 9),
+            compiler_options=sd.TPU_BODY_OPTIONS).lower(
+                *args, None, body == "chunk_first").compile()
+    text = compiled.as_text()
+    assert _kernel_calls(text, "paged_flash_decode") \
+        == (0 if body == "chunk_first" else 2)
+    assert _kernel_calls(text, "flash_fwd") \
+        == (2 if body == "chunk_first" else 0)
+    assert _kernel_calls(text, "gmm") == 16
+    if body == "decode":
+        assert text.count("linear_state_decode_mxu1x3") >= 6
+        assert "linear_state_decode_mxu3x3" not in text
+        assert _scatter_loops(text) == (0, 0)
+    memory = compiled.memory_analysis()
+    assert 13.9e9 < memory.argument_size_in_bytes < 14.2e9
+    assert memory.temp_size_in_bytes < 0.7e9
