@@ -222,6 +222,7 @@ from dtf_tpu.ops import (block_select, index_select, linear_state,
                          window_summary)
 from dtf_tpu.ops.flash_attention import flash_attention
 from dtf_tpu.ops.paged_attention import (cached_attention, expand_kv_heads,
+                                         chunk_rows_walked, chunk_walks,
                                          gather_pages,
                                          latent_chunk_attention,
                                          latent_expands,
@@ -2108,13 +2109,36 @@ class RoutedDecoderLM(nn.Module):
                     self.kv_lora_rank, self.qk_nope_head_dim,
                     self.qk_rope_head_dim, self.v_head_dim))
 
+    def layers_walking(self, s: int) -> int:
+        """How many layers of a decode-mode CONTINUATION call of ``s``
+        queries a row attend through the walk over their K and V pools
+        (``ops.paged_attention.chunk_walks``: by the call's shape and the
+        layers' heads and windows; the layers of :class:`GroupedQueryAttention`
+        under ``STATS``' own counts)."""
+        if (not self.decode or self.kv_lora_rank is not None
+                or self.summary_window is not None
+                or "sparse_block" in self.layer_mixers()):
+            return 0
+        pools = 1 if 2 * self.head_dim == _LANES else 2
+        return sum(m == "attention" and chunk_walks(
+            s, self.num_heads, self.num_kv_heads, window=w, pools=pools)
+                   for (w, _), m in zip(self.layer_kinds(),
+                                        self.layer_mixers()))
+
     def call_stats_names(self, s: int):
         """``stats_names`` of a call of ``s`` queries a row: where its
         latent layers attend expanded they READ no cached row through the
         paged kernel — the count in that place is of the rows they carried
         through ``kv_b``, the chunk's own and the cached ones in whole
         steps of the walk, under a name of its own (with an ``indexer``:
-        one count more, behind the ``INDEX_STATS`` a step has too)."""
+        one count more, behind the ``INDEX_STATS`` a step has too).  Where
+        layers WALK their K and V pools (``layers_walking``) a
+        continuation chunk counts one thing more, last: ``kv_tokens_walked``,
+        the keys the walks' steps gathered and the chunk's own, summed over
+        those layers (a first chunk walks nothing and its counts end before
+        it)."""
+        if self.layers_walking(s):
+            return self.stats_names + ("kv_tokens_walked",)
         if not self.latent_expanded(s):
             return self.stats_names
         if self.indexer is not None:
@@ -2388,6 +2412,13 @@ class RoutedDecoderLM(nn.Module):
             if self.carries_state:
                 counts += [jnp.asarray(
                     b * s * (len(kinds) - len(attends)), jnp.int32), advanced]
+            walking = 0 if flash_prefill else self.layers_walking(s)
+            if walking:
+                counts.append(walking * jnp.sum(
+                    chunk_rows_walked(
+                        cache_index, s, self.kv_page_size,
+                        block_table.shape[1], self.num_kv_heads
+                        * self.head_dim * jnp.dtype(self.dtype).itemsize)))
             counts = jnp.stack(counts)
         n_counts = counts.shape[0]
         self.sow("stats", "counts", counts,

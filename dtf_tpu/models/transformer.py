@@ -49,9 +49,10 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from dtf_tpu.ops.flash_attention import flash_attention
-from dtf_tpu.ops.paged_attention import (expand_kv_heads,
+from dtf_tpu.ops.paged_attention import (chunk_walks, expand_kv_heads,
                                          latent_chunk_attention,
-                                         paged_attention_auto, write_pages)
+                                         paged_attention_auto,
+                                         paged_chunk_attention, write_pages)
 from dtf_tpu.parallel.collectives import tp_psum, tp_region
 from dtf_tpu.parallel.ring_attention import ring_attention
 
@@ -181,6 +182,13 @@ def paged_cache_attention(module, q, k, v, cache_index, block_table, *,
         # O(S·D) HBM traffic instead of an [S, L] gather
         # (a chunk no longer than the window sees all of itself)
         return flash(k, v)
+    if chunk_walks(s, q.shape[2], k.shape[2], window=window):
+        # a continuation chunk whose rows a KV head fill a tile of the
+        # flash forward: its pages walked through that kernel, a page
+        # read once a tile (ops.paged_attention.paged_chunk_attention)
+        return paged_chunk_attention(
+            q, k, v, paged_key.value, paged_value.value, block_table,
+            cache_index, use_pallas=module.use_pallas)
     # paged_attention_auto: the Pallas flash-decode
     # kernel on TPU (default-on — each row's live pages
     # streamed from the pool as stored, ids from the

@@ -63,6 +63,20 @@ Two formulations of attention-over-pages coexist:
       the two, how many pages make a block, and how many heads share a
       grid point, follows from the static shapes (``_plan``).
 
+      It serves every decode step, every chunk over ``[k | v]`` rows or
+      under a window, and a chunk of whole heads or of few rows a KV
+      head.  A CONTINUATION chunk of grouped heads whose rows a KV head
+      fill a tile of the flash forward (``chunk_walks``: there the
+      kernel would halve a head's rows into blocks that each stream the
+      row's pages again) attends through a WALK instead
+      (``paged_chunk_attention``): the chunk against itself one causal
+      ``flash_forward``, the pages under its start gathered by the row's
+      table ``EXPAND_KEYS`` keys a step, laid heads-major and met by the
+      same kernel through its carried ``(o, lse)`` — a page is read once
+      a 1,024-row tile from a contiguous copy.  Those calls are named
+      ``paged_flash_decode_chunk``: on the device's timeline the paged
+      kernel's work, whichever kernel does it.
+
 A third kernel with a body of its own (``paged_flash_decode_tiles``,
 behind ``paged_tile_attention``) serves the one caller whose CHUNK reads
 a subset of the row's blocks, the subset a (query, KV head)'s own
@@ -78,7 +92,9 @@ meet the queries in ``ops.flash_attention.flash_forward`` — under each
 query's own choice of rows where the layer has one (``member``: the form a
 chunk of chosen-row latent attention takes where it is long enough;
 ``latent_sparse_chunk`` and ``latent_sparse_decode`` are the absorbed
-forms of that attention, a short chunk's and a decode step's).
+forms of that attention, a short chunk's and a decode step's).  Both walks
+are ONE loop (``_walk_under``); what a step's gathered pages become —
+expanded through ``kv_b``, or laid heads-major — is the caller's.
 
 ``paged_attention_auto`` dispatches between them: the kernel by default
 on TPU, the gather oracle elsewhere; ``use_pallas="interpret"`` runs
@@ -292,9 +308,37 @@ def _expand_pages(page_size: int, m_pages: int) -> int:
 def latent_rows_expanded(index, s: int, page_size: int, m_pages: int):
     """The rows :func:`latent_chunk_attention` carries through ``kv_b`` for
     a row whose chunk of ``s`` starts at ``index``: the cached ones in
-    whole steps of its walk, and the chunk's own."""
+    whole steps of its walk, and the chunk's own — of
+    :func:`paged_chunk_attention`, the keys its walk's steps gathered and
+    the chunk's own."""
     t = _expand_pages(page_size, m_pages) * page_size
     return -(-index // t) * t + s
+
+
+def _walk_under(index, block_table, pools, ppb: int, carry, attend):
+    """The walk of a chunk's queries over the keys UNDER the chunk's start:
+    ``carry`` — ``(o, lse)`` of the chunk against itself — continued, ``ppb``
+    pages a step, over each row's pages below ``index`` [B].  A step
+    gathers its pages of every pool of ``pools`` by the row's table
+    ``block_table`` [B, M], each as ``[B, ppb * page, ...]`` in logical
+    order, and hands them to ``attend(rows, at, carry, kv_len)``: ``at`` the
+    first key's position, ``kv_len`` [B] how many of the step's keys lie
+    under the row's ``index`` (all others are not the row's, or not yet:
+    masked, whatever the pages hold); it returns the new ``(o, lse)`` —
+    what a step's gathered pages BECOME (expanded through ``kv_b``; laid
+    heads-major) is the caller's.  The trip count follows the traced
+    ``index``, never the table's width: one compile a chunk length, work in
+    proportion to what the chunk sees."""
+    b, m_pages = block_table.shape
+    t = ppb * pools[0].shape[1]
+    table = jnp.pad(block_table, ((0, 0), (0, -m_pages % ppb)))
+
+    def step(i, carry):
+        pages = jax.lax.dynamic_slice_in_dim(table, i * ppb, ppb, axis=1)
+        rows = tuple(p[pages].reshape(b, t, *p.shape[2:]) for p in pools)
+        return tuple(attend(rows, i * t, carry,
+                            jnp.clip(index - i * t, 0, t)))
+    return jax.lax.fori_loop(0, jnp.max(-(-index // t)), step, tuple(carry))
 
 
 def rope_in_head(nope: int, rope: int) -> bool:
@@ -359,7 +403,7 @@ def _latent_chunk_walk(q, rows, w_kvb, pool, block_table, index, member, *,
     the rotary key ``in_head`` or shared.  Jitted, so that a model's layers
     share one lowering (two kernels a layer: a second and more of a traced
     body's set-up each)."""
-    b, s, h, _ = q.shape
+    _, s, h, _ = q.shape
     m_pages = block_table.shape[1]
     t = ppb * pool.shape[1]
     rope = q.shape[-1] - nope
@@ -383,7 +427,6 @@ def _latent_chunk_walk(q, rows, w_kvb, pool, block_table, index, member, *,
             flash_forward, qh[..., :nope],
             q_shared=jnp.pad(qh[..., nope:], ((0, 0),) * 3 + ((0, pad),)))
     attend = functools.partial(attend, scale=scale, use_pallas=use_pallas)
-    table = jnp.pad(block_table, ((0, 0), (0, -m_pages % ppb)))
     tiled = member is not None and member.ndim == 5
     if tiled and t % member.shape[-1]:
         # pages too small for a step of whole blocks: a row a query
@@ -392,7 +435,7 @@ def _latent_chunk_walk(q, rows, w_kvb, pool, block_table, index, member, *,
         attend = functools.partial(attend,
                                    name="latent_sparse_chunk_expanded")
         # every step of the walk a slice of its own, whole
-        keys = table.shape[1] * pool.shape[1]
+        keys = -(-m_pages // ppb) * t
         if tiled and member.shape[2] * member.shape[4] < keys:
             raise ValueError(
                 f"a membership of {member.shape[2]} blocks of "
@@ -443,14 +486,148 @@ def _latent_chunk_walk(q, rows, w_kvb, pool, block_table, index, member, *,
     carry = attend(k, v, k_shared=k_rope, causal=True,
                    member=named(index, s))
 
-    def step(i, carry):
-        pages = jax.lax.dynamic_slice_in_dim(table, i * ppb, ppb, axis=1)
-        k, v, k_rope = expand(pool[pages].reshape(b, t, pool.shape[-1]))
-        return tuple(attend(k, v, k_shared=k_rope, carry=carry,
-                            kv_len=jnp.clip(index - i * t, 0, t),
-                            member=named(i * t, t)))
-    o, _ = jax.lax.fori_loop(0, jnp.max(-(-index // t)), step, tuple(carry))
+    def step(rows, at, carry, kv_len):
+        k, v, k_rope = expand(rows[0])
+        return attend(k, v, k_shared=k_rope, carry=carry, kv_len=kv_len,
+                      member=named(at, t))
+    o, _ = _walk_under(index, block_table, (pool,), ppb, carry, step)
     return jnp.swapaxes(o, 1, 2).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# A chunk over K and V pools, WALKED through the flash forward
+# ---------------------------------------------------------------------------
+
+# query rows a KV head meets (the chunk's length times the query heads that
+# share the head) from which a continuation chunk walks: a full tile of the
+# flash forward (``flash_attention.DEFAULT_BLOCK_Q``) — the count; measured
+# (docs/pr58_paged_chunk_walk_sweep.jsonl, `rows` lines: v5e, bf16, 8k keys
+# under the chunk, kernel -> walk, ms a layer) the walk breaks even between
+# 512 and 1,024 rows: 8 query heads a KV head of 256, pages of 1,024, at 256
+# / 512 / 1,024 / 2,048 rows 0.41 -> 0.49, 0.38 -> 0.38, 0.62 -> 0.48, 0.91
+# -> 0.55; 7 a head of 128, pages of 64, at 448 / 896 / 1,792 rows 0.44 ->
+# 0.63, 0.56 -> 0.51, 0.78 -> 0.64.  At the cells' shapes (16,384 and 7,168
+# rows) 6.45 -> 2.70 and 2.58 -> 1.37, at 64k keys 39.0 -> 15.8 and 16.2 ->
+# 8.2: a step of 2,048 keys 0.47 ms where the MXU's least is 0.35
+CHUNK_WALK_ROWS = 1024
+
+
+def chunk_walks(s: int, hq: int, h: int, *, window=None,
+                pools: int = 2) -> bool:
+    """Whether a CONTINUATION chunk of ``s`` queries a row, ``hq`` query
+    heads over ``h`` KV heads, attends through
+    :func:`paged_chunk_attention` and not :func:`paged_flash_decode` — by
+    what the call can see: K and V in ``pools`` = 2 pools (not ``[k | v]``
+    rows, not a latent pool), no ``window`` (under one the kernel starts at
+    the first visible block and a walk from 0 would not), grouped query
+    heads whose rows a KV head, ``hq // h * s``, fill a tile of the flash
+    forward.  That is where the kernel's ``_tiling`` halves a head's rows
+    into ``row_blocks`` blocks that EACH stream the row's pages again (64
+    blocks of 256 rows at 2,048 x 8 rows of 256 lanes), every block a
+    strided load a head; the walk meets a page once a 1,024-row tile, from
+    a contiguous copy.  One query a row (a decode step) never walks."""
+    return (pools == 2 and window is None and s > 1 and hq > h
+            and hq // h * s >= CHUNK_WALK_ROWS)
+
+
+# bytes of ONE pool a gathered slice holds at most.  XLA's gather of whole
+# pages relays out the WHOLE pool first where a slice is larger (compiled
+# for the v5e, PR 58: two pages of 1,024 x 2 x 256 bf16 a step, 512 KiB a
+# slice, 1.36e9 B of temporaries beside a 622-page pool; 33.8e6 at 256 KiB
+# and under, the walk's carry — and on the chip 23.0 ms a layer at 8k keys
+# where parts of a page take 2.64: the sweep's `walk_whole_pages_ms`): a
+# larger page is gathered in equal parts
+_GATHER_SLICE_BYTES = 256 * 1024
+
+
+def _gather_parts(page_size: int, row_bytes: int) -> int:
+    """Equal parts a page of ``page_size`` tokens of ``row_bytes`` is
+    gathered in (``_GATHER_SLICE_BYTES``): a power of two."""
+    parts = 1
+    while (page_size % (2 * parts) == 0
+           and page_size // parts * row_bytes > _GATHER_SLICE_BYTES):
+        parts *= 2
+    return parts
+
+
+def chunk_rows_walked(index, s: int, page_size: int, m_pages: int,
+                      row_bytes: int):
+    """The keys :func:`paged_chunk_attention` meets for a row whose chunk of
+    ``s`` starts at ``index``, a token of one pool ``row_bytes`` wide: those
+    its walk's steps gathered, in whole steps, and the chunk's own."""
+    parts = _gather_parts(page_size, row_bytes)
+    return latent_rows_expanded(index, s, page_size // parts,
+                                m_pages * parts)
+
+
+def paged_chunk_attention(q, k, v, pool_k, pool_v, block_table, index, *,
+                          use_pallas=None):
+    """A continuation chunk's attention over K and V pools as a WALK of
+    :func:`ops.flash_attention.flash_forward` calls: the same product as
+    :func:`paged_attention` (every visible key attended, f32 scores and
+    carry), where :func:`chunk_walks`.
+
+    q [B, S, Hq, Dh]; k, v [B, S, Hkv, Dh] the chunk's own keys and values,
+    already written to the pools [P, page, Hkv, Dh] (write-then-attend;
+    they are attended from here and not read back); block_table [B, M];
+    index [B] the chunk's first position.  Returns [B, S, Hq, Dh] in q's
+    dtype.
+
+    The chunk against ITSELF is one causal ``flash_forward``, a KV head
+    repeated for its query heads.  The keys under ``index`` — every query
+    sees all of them — are walked in steps of ``EXPAND_KEYS``
+    (:func:`_walk_under`): a step's pages laid heads-major, the ``Hq //
+    Hkv`` query heads of a KV head ``G * S`` rows of that head, one
+    unmasked call continuing the same online softmax.  The kernels run
+    under the name ``paged_flash_decode_chunk``: on the device's timeline
+    they are the paged kernel's work."""
+    if use_pallas is None:
+        use_pallas = jax.default_backend() == "tpu"
+    pages, page_size, h, d = pool_k.shape
+    parts = _gather_parts(page_size, h * d * pool_k.dtype.itemsize)
+    if parts > 1:
+        # the pools and the table in parts of a page (a view: no copy)
+        pool_k, pool_v = (p.reshape(pages * parts, page_size // parts, h, d)
+                          for p in (pool_k, pool_v))
+        block_table = (block_table[:, :, None] * parts + jnp.arange(
+            parts, dtype=block_table.dtype)).reshape(len(block_table), -1)
+    return _paged_chunk_walk(
+        q, k, v, pool_k, pool_v, block_table, index, use_pallas=use_pallas,
+        ppb=_expand_pages(pool_k.shape[1], block_table.shape[1]))
+
+
+@functools.partial(jax.jit, static_argnames=("use_pallas", "ppb"))
+def _paged_chunk_walk(q, k, v, pool_k, pool_v, block_table, index, *,
+                      use_pallas, ppb):
+    """:func:`paged_chunk_attention` at ``ppb`` pages a step of the walk.
+    Jitted, so that a model's layers share one lowering."""
+    b, s, hq, d = q.shape
+    h = k.shape[2]
+    group = hq // h
+    attend = functools.partial(flash_forward, scale=1.0 / (d ** 0.5),
+                               use_pallas=use_pallas,
+                               name="paged_flash_decode_chunk")
+    qh = jnp.swapaxes(q, 1, 2)                       # [B, Hq, S, D]
+    o, lse = attend(qh, *(jnp.swapaxes(x, 1, 2)
+                          for x in expand_kv_heads(k, v, hq)), causal=True)
+    # the G query heads of a KV head as G * S rows of that head
+    qh = qh.reshape(b, h, group * s, d)
+
+    def step(rows, at, carry, kv_len):
+        del at
+        # a gathered row at or past the row's ``index`` is not the row's
+        # (the scratch page, another request's old page): zeros, so that
+        # the block ``kv_len`` cuts multiplies finite values under its
+        # mask (0 x NaN is NaN); the select rides the relayout's copy
+        dead = (jnp.arange(rows[0].shape[1], dtype=jnp.int32)[None, :]
+                >= kv_len[:, None])[:, None, :, None]
+        return attend(qh, *(jnp.where(dead, 0, jnp.swapaxes(x, 1, 2))
+                            for x in rows), carry=carry, kv_len=kv_len)
+    o, _ = _walk_under(
+        index, block_table, (pool_k, pool_v), ppb,
+        (o.reshape(b, h, group * s, d), lse.reshape(b, h, group * s, 1)),
+        step)
+    return jnp.swapaxes(o.reshape(b, hq, s, d), 1, 2).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------

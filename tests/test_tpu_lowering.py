@@ -336,6 +336,23 @@ def _compile_decode_body(dec, v5e):
         compiler_options=sd.TPU_BODY_OPTIONS).lower(*args).compile()
 
 
+def _compile_chunk_body(dec, tokens, first, v5e):
+    """The decoder's chunk body of ``tokens`` tokens — a FIRST chunk's
+    (``flash_prefill``) or a continuation's — compiled for the v5e as
+    ``Decoder`` builds it."""
+    from dtf_tpu.serve import decode as sd
+    s, i32, m = jax.ShapeDtypeStruct, jnp.int32, dec.pages_per_slot
+    args = _on_chip((dec.params, jax.eval_shape(dec.fresh_cache),
+                     s((1, tokens), i32), s((1, m), i32), s((), i32),
+                     s((), jnp.float32),
+                     jax.eval_shape(lambda: sd.position_key(0, 0)),
+                     s((), i32)), v5e)
+    return jax.jit(
+        dec._chunk_impl, donate_argnums=(1,), static_argnums=(8, 9),
+        compiler_options=sd.TPU_BODY_OPTIONS).lower(
+            *args, None, first).compile()
+
+
 @pytest.mark.parametrize("body", ["chunk", "chunk_2048", "decode"])
 def test_latent_serve_bodies_compile_for_v5e(v5e, body):
     """The whole compiled body, not the kernel alone: beside the body's
@@ -1161,14 +1178,19 @@ def gated_decoder():
     256; 128 held of 512 experts of 512 beside a gated shared one; 37,984
     vocabulary rows; shapes only), the cell's engine: 32 slots of 67,072
     tokens, pages of 1,024 in a 622-page pool."""
+    return _cell_decoder("qwen3-next-80b-a3b", "qwen3next-serve-hybriddoc")
+
+
+def _cell_decoder(config, cell):
+    """(a shapes-only ``Decoder``, the engine's settings) of a benchmark
+    cell: the configuration's model at the cell's slots, pages and pool."""
     import json
     import os
     from dtf_tpu.models import build_model
     root = os.path.join(os.path.dirname(__file__), "..", "benchmark")
-    with open(os.path.join(root, "configs", "qwen3-next-80b-a3b.json")) as f:
+    with open(os.path.join(root, "configs", config + ".json")) as f:
         cfg = json.load(f)
-    with open(os.path.join(root, "workloads",
-                           "qwen3next-serve-hybriddoc.json")) as f:
+    with open(os.path.join(root, "workloads", cell + ".json")) as f:
         engine = json.load(f)["engine"]
     model, _ = build_model(cfg["build_model"]["name"],
                            num_classes=cfg["num_classes"],
@@ -1190,7 +1212,9 @@ def test_gated_delta_serve_bodies_compile_for_v5e(v5e, gated_decoder, body):
     """The bodies of the decoder that keeps K and V pools of 256-WIDE heads
     BESIDE matrix-a-head state entries, at the cell's own engine settings:
     a first chunk through the flash forward at heads of 256 (two layers),
-    a continuation chunk and the decode step through ``paged_flash_decode``
+    a continuation chunk through the WALK over its pages
+    (``paged_flash_decode_chunk``: ``chunk_walks`` at 2,048 x 8 rows a KV
+    head), the decode step through ``paged_flash_decode``
     over pages of 1,024 x 2 x 256 (8 query heads a KV head), the step's six
     state layers through the one kernel every ``linear_delta`` model runs
     (fed the broadcast decay and the repeated key rows), sixteen grouped
@@ -1198,8 +1222,6 @@ def test_gated_delta_serve_bodies_compile_for_v5e(v5e, gated_decoder, body):
     entries donated and updated in place.  (A page of 2,048 is REFUSED for
     the chunk — 18.9 MiB of scoped VMEM where 16 are allowed: my compile,
     PR 57 — which is why the cell's page is 1,024.)"""
-    from dtf_tpu.serve import decode as sd
-    i32, f32 = jnp.int32, jnp.float32
     dec, engine = gated_decoder
     assert dec.carries_state and not dec.decode_all_heads
     assert dec.kv_bytes_per_token == 2 * 2 * 2 * 256 * 2
@@ -1207,21 +1229,23 @@ def test_gated_delta_serve_bodies_compile_for_v5e(v5e, gated_decoder, body):
     if body == "decode":
         compiled = _compile_decode_body(dec, v5e)
     else:
-        s, m = jax.ShapeDtypeStruct, dec.pages_per_slot
-        args = _on_chip((dec.params, jax.eval_shape(dec.fresh_cache),
-                         s((1, engine["prefill_chunk"]), i32), s((1, m), i32),
-                         s((), i32), s((), f32),
-                         jax.eval_shape(lambda: sd.position_key(0, 0)),
-                         s((), i32)), v5e)
-        compiled = jax.jit(
-            dec._chunk_impl, donate_argnums=(1,), static_argnums=(8, 9),
-            compiler_options=sd.TPU_BODY_OPTIONS).lower(
-                *args, None, body == "chunk_first").compile()
+        compiled = _compile_chunk_body(dec, engine["prefill_chunk"],
+                                       body == "chunk_first", v5e)
     text = compiled.as_text()
     assert _kernel_calls(text, "paged_flash_decode") \
-        == (0 if body == "chunk_first" else 2)
+        == (2 if body == "decode" else 0)
     assert _kernel_calls(text, "flash_fwd") \
         == (2 if body == "chunk_first" else 0)
+    assert _kernel_calls(text, "flash_fwd_chunk") == 0
+    # a continuation chunk WALKS its pages (16,384 rows a KV head): the
+    # chunk against itself (q, k, v) and a step of the walk (the live
+    # count and the carry besides) a gated-attention layer, in ONE loop a
+    # layer, and the paged kernel nowhere
+    assert _kernel_operands(text, "paged_flash_decode_chunk") \
+        == ([3] * 2 + [6] * 2 if body == "chunk" else [])
+    loops = [ln for ln in text.splitlines() if " while(" in ln
+             and "_paged_chunk_walk" in ln]
+    assert len(loops) == (2 if body == "chunk" else 0)
     assert _kernel_calls(text, "gmm") == 16
     if body == "decode":
         assert text.count("linear_state_decode_mxu1x3") >= 6
@@ -1230,3 +1254,34 @@ def test_gated_delta_serve_bodies_compile_for_v5e(v5e, gated_decoder, body):
     memory = compiled.memory_analysis()
     assert 13.9e9 < memory.argument_size_in_bytes < 14.2e9
     assert memory.temp_size_in_bytes < 0.7e9
+
+
+@pytest.mark.parametrize("body", ["chunk_first", "chunk", "decode"])
+def test_window_and_global_serve_bodies_compile_for_v5e(v5e, body):
+    """SmallThinker's share of the benchmark at its published widths (12
+    layers, one GLOBAL in four: 28 query heads over 4 KV heads of 128, a
+    window of 4,096 in the others; shapes only), the cell's engine: 16
+    slots of 16,384 tokens, pages of 64, chunks of 1,024.  A continuation
+    chunk's three global layers WALK their pages (7,168 rows a KV head:
+    ``paged_flash_decode_chunk``, the chunk against itself and a step of
+    the walk a layer), its nine window layers stay in
+    ``paged_flash_decode``, which starts at the first visible block; a
+    first chunk is the flash forward's in every layer (a chunk of 1,024
+    sees all of itself under a window of 4,096); the decode step the
+    paged kernel's in all twelve."""
+    dec, engine = _cell_decoder("smallthinker-21b-a3b",
+                                "smallthinker-serve-mixedctx")
+    if body == "decode":
+        compiled = _compile_decode_body(dec, v5e)
+    else:
+        compiled = _compile_chunk_body(dec, engine["prefill_chunk"],
+                                       body == "chunk_first", v5e)
+    text = compiled.as_text()
+    assert _kernel_calls(text, "paged_flash_decode") \
+        == {"chunk_first": 0, "chunk": 9, "decode": 12}[body]
+    assert _kernel_calls(text, "flash_fwd") \
+        == (12 if body == "chunk_first" else 0)
+    assert _kernel_operands(text, "paged_flash_decode_chunk") \
+        == ([3] * 3 + [6] * 3 if body == "chunk" else [])
+    # the pools are donated and updated in place, and no walk relays one out
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
