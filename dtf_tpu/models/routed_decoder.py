@@ -10,6 +10,21 @@ position table: a position is the row's
 ``cache_index`` plus the offset in the chunk, so ``max_seq_len`` bounds
 only what the serving engine admits.
 
+The fields are read in ONE place, ``RoutedDecoderLM.layer_specs()``, which
+describes every layer (:class:`LayerSpec`): its mixer's KIND with that
+kind's class's arguments by name, and its MLP.  The kinds (``_KINDS``) are
+``attention`` (whole heads), ``summary`` (whole heads under
+``summary_window``), ``latent`` (latent attention), ``indexed_latent``
+(latent attention under an ``indexer``) — the four a configuration's
+``layer_mixer`` calls ``attention``; which one follows from
+``kv_lora_rank``, ``summary_window`` and ``indexer`` — and ``short_conv``,
+``linear_delta``, ``sparse_block``, ``lightning``.  The block builds the
+kind's module from the description, the counts go by it (``COUNTS``), and
+the form a call's static shape chooses is the kind's own to answer
+(``GroupedQueryAttention.walks``, ``LatentAttention.expands``): the layer
+goes by the answer where it attends and the model asks the same question to
+name a count.
+
 **Attention kind.**  ``kv_lora_rank`` None — whole heads: ``num_heads``
 query heads share ``num_kv_heads`` KV heads of ``head_dim`` (grouped-query
 attention: query head ``i`` reads KV head ``i // (num_heads //
@@ -189,29 +204,22 @@ flips alone were 0.008-0.019 of the 0.014-0.029 the served logits read
 against it (v5e, 12 seeds, whole heads), and it costs [tokens, d_model]
 words a layer beside 755e6 bytes of experts.
 
-Under ``experts_held`` the count ``assignments`` is the pairs COMPUTED
-HERE (their expert is held), and ``experts_touched`` / ``expert_load_max``
-run over the held experts; a model with state counts the tokens its state
-layers mixed and the rows whose entries went to a page of their own
-(``conv_tokens`` or ``linear_tokens``, ``state_rows_advanced``), beside
-either attention kind's counts; a model with ``sparse_block`` layers
-counts, in this order after the three expert counts, ``kv_blocks_visible``,
-``kv_blocks_read``, ``pooled_keys_scored``, ``rows_dense_path``
-(``SPARSE_STATS``), ``linear_tokens``, ``state_rows_advanced``, and last
-``kv_blocks_streamed`` (``STREAMED_STATS``); a model with an ``indexer``
-counts, after the three expert counts, ``INDEX_STATS``.
-
-Every apply also yields counts (``stats_names``; summed over layers) in
-the ``"stats"`` collection when the caller makes it mutable: the serving
-engine puts them on its spans when tracing is on, under
-``call_stats_names`` of the call's shape (an expanded chunk's
-``latent_tokens_expanded`` where a step has ``latent_tokens_read``).
+Every apply also yields counts, summed over layers, in the ``"stats"``
+collection when the caller makes it mutable: the serving engine puts them
+on its spans when tracing is on, under ``call_stats_names`` of the call's
+shape (an expanded chunk's ``latent_tokens_expanded`` where a step has
+``latent_tokens_read``).  What a model counts — the names in order and the
+arithmetic, side by side — is its row of ``COUNTS``, by what its attention
+layers read; ``stats_names`` looks it up.  Under ``experts_held`` the count
+``assignments`` is the pairs COMPUTED HERE (their expert is held), and
+``experts_touched`` / ``expert_load_max`` run over the held experts.
 """
 
 from __future__ import annotations
 
+import collections
 import math
-from typing import Any, Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -611,6 +619,14 @@ def _clipped_normal(scale):
     return init
 
 
+def _need_table(module, cache_index, block_table):
+    if module.kv_page_size is None:
+        raise ValueError("decode mode needs kv_page_size and kv_pool_pages")
+    if cache_index is None or block_table is None:
+        raise ValueError("decode mode needs cache_index [B] and block_table "
+                         "[B, M], both int32")
+
+
 class GroupedQueryAttention(nn.Module):
     num_heads: int
     num_kv_heads: int
@@ -639,6 +655,26 @@ class GroupedQueryAttention(nn.Module):
     # thousands of keys near flat; a served model's is not
     qk_norm_gain: float = 1.0
 
+    @property
+    def one_row(self) -> bool:
+        """K and V of a head share ONE pool row ``[k | v]``, all of it
+        payload: a K or V row of half a lane tile is stored in a whole one
+        (two pools of 64 lanes do not even lower on the TPU; narrower
+        heads are sizes of the CPU's tests alone)."""
+        return 2 * self.head_dim == _LANES
+
+    @nn.nowrap
+    def walks(self, s: int) -> bool:
+        """Whether a decode-mode CONTINUATION call of ``s`` queries a row
+        attends through the walk over its K and V pools
+        (``ops.paged_attention.chunk_walks``: by the call's shape, the
+        layer's heads and its window); a compact table's rows (``summary``)
+        are the paged kernel's.  ``__call__`` goes by it and the model
+        names a count by it (``RoutedDecoderLM.layers_walking``)."""
+        return (self.decode and self.summary is None and chunk_walks(
+            s, self.num_heads, self.num_kv_heads, window=self.window,
+            pools=1 if self.one_row else 2))
+
     @nn.compact
     def __call__(self, h, positions, cache_index=None, block_table=None,
                  flash_prefill: bool = False,
@@ -646,7 +682,7 @@ class GroupedQueryAttention(nn.Module):
         b, s, d = h.shape
         hq, hkv, dh = self.num_heads, self.num_kv_heads, self.head_dim
         if self.summary is not None:
-            if 2 * dh == _LANES or self.window is not None:
+            if self.one_row or self.window is not None:
                 raise ValueError(
                     "summaries go in K and V pools of their own beside the "
                     "window's tokens: no [k | v] rows (head_dim 64) and no "
@@ -692,12 +728,7 @@ class GroupedQueryAttention(nn.Module):
                                       self.rope_theta), x[..., r:]], -1)
                     for x in (q, k))
         if self.decode:
-            if self.kv_page_size is None:
-                raise ValueError("decode mode needs kv_page_size and "
-                                 "kv_pool_pages")
-            if cache_index is None or block_table is None:
-                raise ValueError("decode mode needs cache_index [B] "
-                                 "and block_table [B, M], both int32")
+            _need_table(self, cache_index, block_table)
             if self.summary is not None:
                 # the attended rows are a prefix of the table in table
                 # order: summaries first, then the open window's tokens
@@ -706,12 +737,8 @@ class GroupedQueryAttention(nn.Module):
             o = paged_cache_attention(
                 self, q, k, v, cache_index, block_table,
                 flash_prefill=flash_prefill, window_pages=window_pages,
-                window=self.window,
-                # a K or V row of half a lane tile is stored in a whole
-                # one: such heads keep [k | v] in ONE pool row, all of it
-                # payload (two pools of 64 lanes do not even lower on the
-                # TPU; narrower heads are sizes of the CPU's tests alone)
-                one_row=2 * dh == _LANES)
+                window=self.window, one_row=self.one_row,
+                walks=self.walks(s))
         else:
             # the whole sequence at once (tests, the toy): a plain mask
             summaries = None
@@ -811,12 +838,7 @@ class ShortConv(nn.Module):
         carry = jnp.zeros((b, keep, d), self.dtype)
         advanced = jnp.zeros((), jnp.int32)
         if self.decode:
-            if self.kv_page_size is None:
-                raise ValueError("decode mode needs kv_page_size and "
-                                 "kv_pool_pages")
-            if cache_index is None or block_table is None:
-                raise ValueError("decode mode needs cache_index [B] "
-                                 "and block_table [B, M], both int32")
+            _need_table(self, cache_index, block_table)
             state = self.variable("cache", "conv_state", jnp.zeros,
                                   (self.kv_pool_pages, keep * d), self.dtype)
         if self.decode and not self.is_initializing():
@@ -982,12 +1004,7 @@ class LinearDelta(nn.Module):
         paged = self.decode and not self.is_initializing()
         carry = jnp.zeros((b, keep, c), self.dtype)
         if self.decode:
-            if self.kv_page_size is None:
-                raise ValueError("decode mode needs kv_page_size and "
-                                 "kv_pool_pages")
-            if cache_index is None or block_table is None:
-                raise ValueError("decode mode needs cache_index [B] "
-                                 "and block_table [B, M], both int32")
+            _need_table(self, cache_index, block_table)
             # a page's entry as that page's OWN whole tiles (as many rows
             # as one tile of ``dtype`` holds sublanes): one contiguous
             # block, which the TPU compiler's scatter writes in one op —
@@ -1095,14 +1112,6 @@ class LinearDelta(nn.Module):
         y = mm(o * (jax.nn.silu(z) if self.gate == "silu"
                     else jax.nn.sigmoid(mm(h, w_gate))), w_out)
         return y, advanced
-
-
-def _need_table(module, cache_index, block_table):
-    if module.kv_page_size is None:
-        raise ValueError("decode mode needs kv_page_size and kv_pool_pages")
-    if cache_index is None or block_table is None:
-        raise ValueError("decode mode needs cache_index [B] and block_table "
-                         "[B, M], both int32")
 
 
 class SparseBlockAttention(nn.Module):
@@ -1620,6 +1629,17 @@ class LatentAttention(nn.Module):
     head_gate: bool = False
     indexer: Optional[Tuple] = None
 
+    @nn.nowrap
+    def expands(self, s: int) -> bool:
+        """Whether a decode-mode call of ``s`` queries a row attends
+        EXPANDED (``ops.paged_attention.latent_expands``: by the call's
+        shape and the layer's widths).  ``__call__`` goes by it and the
+        model names a count by it (``RoutedDecoderLM.latent_expanded``)."""
+        r, dr = self.kv_lora_rank, self.qk_rope_head_dim
+        return self.decode and latent_expands(
+            s, self.num_heads, latent_row_lanes(r, dr), r,
+            self.qk_nope_head_dim, dr, self.v_head_dim)
+
     @nn.compact
     def __call__(self, h, positions, cache_index=None, block_table=None,
                  window_pages: Optional[int] = None, chosen=None):
@@ -1674,16 +1694,11 @@ class LatentAttention(nn.Module):
         w_kvb = w_kvb.astype(self.dtype).reshape(r, hq, dn + dv)
         scale = 1.0 / ((dn + dr) ** 0.5)
         if self.decode:
-            if self.kv_page_size is None:
-                raise ValueError("decode mode needs kv_page_size and "
-                                 "kv_pool_pages")
-            if cache_index is None or block_table is None:
-                raise ValueError("decode mode needs cache_index [B] "
-                                 "and block_table [B, M], both int32")
+            _need_table(self, cache_index, block_table)
             pad = latent_row_lanes(r, dr) - r - dr
             row = jnp.concatenate(
                 [c_kv, k_rope, jnp.zeros((b, s, pad), self.dtype)], -1)
-            expands = latent_expands(s, hq, row.shape[-1], r, dn, dr, dv)
+            expands = self.expands(s)
             if expands:
                 # a chunk: its KEYS go through kv_b, once each, and not
                 # its queries; o comes back a head's own [.., hq, dv]
@@ -1745,15 +1760,104 @@ class LatentAttention(nn.Module):
         return out if self.indexer is None else (out, chosen)
 
 
+class Mixer(NamedTuple):
+    """A layer's mixer: a kind of ``_KINDS`` and that kind's class's
+    constructor arguments BY NAME (all but the common ones)."""
+    kind: str
+    args: Tuple[Tuple[str, Any], ...]
+
+    @property
+    def cls(self):
+        return _KINDS[self.kind].cls
+
+    def arg(self, name: str):
+        return dict(self.args)[name]
+
+    def module(self, common, **more):
+        """The kind's module: these arguments and those of ``common``
+        (:func:`_common`'s pairs) that the class declares.  Unbound, it
+        answers for its layer (``walks``, ``expands``) where nothing is
+        applied."""
+        return self.cls(**dict(self.args), **more, **{
+            k: v for k, v in common if k in self.cls.__dataclass_fields__})
+
+
+def _common(owner):
+    """The arguments every mixer of a model takes, read off a block or the
+    model (they call them the same), as hashable pairs."""
+    return tuple((k, getattr(owner, k)) for k in (
+        "rms_eps", "dtype", "param_dtype", "use_pallas", "decode",
+        "kv_page_size", "kv_pool_pages"))
+
+
+def _layers_that(specs, common, answer: str, s: int) -> int:
+    """How many of ``specs``' mixers say ``answer(s)``, each distinct one
+    asked once, as an unbound module — a kind that has no such form has no
+    such method and never does."""
+    asked = collections.Counter(spec.mixer for spec in specs)
+    return sum(n for mixer, n in asked.items() if hasattr(mixer.cls, answer)
+               and getattr(mixer.module(common, parent=None), answer)(s))
+
+
+def _mixer(kind: str, **args) -> Mixer:
+    return Mixer(kind, tuple(args.items()))
+
+
+# a routed MLP (the module's docstring, **MLP kind**), under the model's own
+# names; ``experts_held`` (first id, count) or None
+Routed = collections.namedtuple("Routed", (
+    "num_experts experts_per_token expert_width routing routed_scale "
+    "router_bias_stddev routing_sum_eps route_groups route_groups_kept "
+    "experts_held router_input shared_expert_width shared_expert_gate"))
+
+
+class Mlp(NamedTuple):
+    dense_width: Optional[int]      # set: a dense gated MLP, no router
+    routed: Optional[Tuple]         # a ``Routed`` where that is None
+    activation: str
+
+
+class LayerSpec(NamedTuple):
+    """What one layer is (``RoutedDecoderLM.layer_specs``).  ``window``:
+    the model's sliding window in tokens whether or not THIS layer slides —
+    ``kv_tokens_read_window`` is clipped by it at weight 0 too, a term the
+    recorded decode bodies hold."""
+    mixer: Mixer
+    mlp: Mlp
+    window: int
+
+
+class _Kind(NamedTuple):
+    cls: Any
+    name: str           # the mixer's place in the layer's parameter tree
+    takes: str          # of RoutedBlock's call arguments, in the class's order
+    beside: Optional[str] = None    # what it returns beside the output
+
+
+_HEADS = "positions cache_index block_table flash_prefill window_pages"
+_LATENT = "positions cache_index block_table window_pages chosen"
+_STATE = "cache_index block_table last_pos"
+# every kind a layer's mixer can be.  The first four are what a
+# configuration calls ``attention`` (whole heads, whole heads under
+# ``summary_window``, the latent cache, the latent cache under an
+# ``indexer``); the others are ``MIXERS``' own words
+_KINDS = {
+    "attention": _Kind(GroupedQueryAttention, "attn", _HEADS),
+    "summary": _Kind(GroupedQueryAttention, "attn", _HEADS),
+    "latent": _Kind(LatentAttention, "attn", _LATENT),
+    "indexed_latent": _Kind(LatentAttention, "attn", _LATENT, "chosen"),
+    "short_conv": _Kind(ShortConv, "conv", _STATE, "advanced"),
+    "linear_delta": _Kind(LinearDelta, "linear", _STATE, "advanced"),
+    "sparse_block": _Kind(
+        SparseBlockAttention, "attn",
+        "cache_index block_table flash_prefill window_pages", "streamed"),
+    "lightning": _Kind(LightningAttention, "linear", "positions " + _STATE,
+                       "advanced"),
+}
+
+
 class RoutedBlock(nn.Module):
-    num_heads: int
-    num_kv_heads: int
-    head_dim: int
-    num_experts: int
-    experts_per_token: int
-    expert_width: int
-    window: Optional[int]
-    rope_theta: Optional[float]
+    spec: LayerSpec
     rms_eps: float
     dtype: Any
     param_dtype: Any
@@ -1761,43 +1865,8 @@ class RoutedBlock(nn.Module):
     decode: bool = False
     kv_page_size: Optional[int] = None
     kv_pool_pages: Optional[int] = None
-    # what the layer is, beyond the defaults (see RoutedDecoderLM)
-    latent: Optional[Tuple[int, ...]] = None   # (q rank, kv rank, nope,
-    #                                             rope, v) or None
-    rope_interleave: bool = False
-    dense_width: Optional[int] = None          # set: a dense gated MLP
-    shared_expert_width: int = 0
-    routing: str = "softmax_topk"
-    routed_scale: float = 1.0
-    router_bias_stddev: float = 0.0
-    activation: str = "relu"
-    router_input: str = "pre_attention"
-    mixer: str = "attention"                   # | short_conv
-    conv_taps: int = 3
-    qk_norm: bool = False
-    routing_sum_eps: float = 0.0
-    # (heads, head dim, taps, decay floor, key heads, decay, gate)
-    linear: Optional[Tuple] = None
-    q_head_norm: bool = False
-    attention_head_gate: bool = False
-    route_groups: int = 1
-    route_groups_kept: int = 1
-    experts_held: Optional[Tuple[int, int]] = None      # (first id, count)
-    summary: Optional[Tuple[int, int]] = None           # (window, chunk)
     norm_unit_offset: bool = False
-    sparse: Optional[Tuple] = None      # SparseBlockAttention's sizes
-    # (heads, head dim, the layer's published index, the published depth,
-    # rotary theta)
-    lightning: Optional[Tuple] = None
     residual_scale: float = 1.0         # on both branches of the layer
-    # LatentAttention's: (full | shared, heads, head dim, top, rotary dims)
-    indexer: Optional[Tuple] = None
-    # GroupedQueryAttention's rotary_dim and output_gate; the shared
-    # expert's output times sigmoid(h2 w), one scalar a token
-    rotary_dim: Optional[int] = None
-    attention_output_gate: bool = False
-    shared_expert_gate: bool = False
-    qk_norm_gain: float = 1.0
 
     @nn.compact
     def __call__(self, x, positions, cache_index=None, block_table=None,
@@ -1810,136 +1879,208 @@ class RoutedBlock(nn.Module):
         ``indexer`` layer's queries chose — its own choice or the one handed
         in as ``chosen`` — or None)."""
         b, s, d = x.shape
-        e, f = self.num_experts, self.expert_width
-        held = (e if self.experts_held is None else self.experts_held[1])
+        mlp, routed = self.spec.mlp, self.spec.mlp.routed
         pdt, offset = self.param_dtype, self.norm_unit_offset
         g1 = self.param("norm1", _norm_init(offset), (d,), pdt)
         g2 = self.param("norm2", _norm_init(offset), (d,), pdt)
-        routed = self.dense_width is None
-        if routed:
+        score_bias = None
+        if routed is not None:
+            e, f = routed.num_experts, routed.expert_width
+            held = e if routed.experts_held is None else routed.experts_held[1]
             w_router = self.param("router", _normal(0.02), (d, e), pdt)
             w_gate_up = self.param("gate_up", _normal(0.02),
                                    (held, d, 2 * f), pdt)
             w_down = self.param("down", _normal(0.02), (held, f, d), pdt)
-        if self.routing not in ("softmax_topk", "sigmoid_bias"):
-            raise ValueError(f"routing {self.routing!r}: softmax_topk or "
-                             f"sigmoid_bias")
-        score_bias = None
-        if routed and self.routing == "sigmoid_bias":
-            # f32 whatever param_dtype: it meets f32 scores
-            score_bias = self.param(
-                "router_bias", _normal(self.router_bias_stddev), (e,),
-                jnp.float32)
+            if routed.routing == "sigmoid_bias":
+                # f32 whatever param_dtype: it meets f32 scores
+                score_bias = self.param(
+                    "router_bias", _normal(routed.router_bias_stddev), (e,),
+                    jnp.float32)
 
         def choose(hh):
             return route(hh.reshape(b * s, d), w_router,
-                         self.experts_per_token, score_bias,
-                         self.routed_scale, self.routing_sum_eps,
-                         self.route_groups, self.route_groups_kept)
+                         routed.experts_per_token, score_bias,
+                         routed.routed_scale, routed.routing_sum_eps,
+                         routed.route_groups, routed.route_groups_kept)
         h = rms_norm(x, g1, self.rms_eps, offset)
-        if routed and self.router_input == "pre_attention":
+        if routed is not None and routed.router_input == "pre_attention":
             idx, weights = choose(h)
-        advanced = streamed = None
-        if self.mixer == "short_conv":
-            attn, advanced = ShortConv(
-                self.conv_taps, self.dtype, pdt, decode=self.decode,
-                kv_page_size=self.kv_page_size,
-                kv_pool_pages=self.kv_pool_pages, name="conv")(
-                    h, cache_index, block_table, last_pos)
-        elif self.mixer == "linear_delta":
-            *widths, key_heads, decay, gate = self.linear
-            attn, advanced = LinearDelta(
-                *widths, self.rms_eps, self.dtype, pdt,
-                use_pallas=self.use_pallas, decode=self.decode,
-                kv_page_size=self.kv_page_size,
-                kv_pool_pages=self.kv_pool_pages, key_heads=key_heads,
-                decay=decay, gate=gate, name="linear")(
-                    h, cache_index, block_table, last_pos)
-        elif self.mixer == "sparse_block":
-            attn, streamed = SparseBlockAttention(
-                self.num_heads, self.num_kv_heads, self.head_dim,
-                self.sparse, self.rms_eps, self.dtype, pdt,
-                use_pallas=self.use_pallas, decode=self.decode,
-                kv_page_size=self.kv_page_size,
-                kv_pool_pages=self.kv_pool_pages, name="attn")(
-                    h, cache_index, block_table, flash_prefill, window_pages)
-        elif self.mixer == "lightning":
-            attn, advanced = LightningAttention(
-                *self.lightning, self.rms_eps, self.dtype, pdt,
-                use_pallas=self.use_pallas, decode=self.decode,
-                kv_page_size=self.kv_page_size,
-                kv_pool_pages=self.kv_pool_pages, name="linear")(
-                    h, positions, cache_index, block_table, last_pos)
-        elif self.latent is None:
-            attn = GroupedQueryAttention(
-                self.num_heads, self.num_kv_heads, self.head_dim,
-                self.window, self.rope_theta, self.dtype, pdt,
-                use_pallas=self.use_pallas, decode=self.decode,
-                kv_page_size=self.kv_page_size,
-                kv_pool_pages=self.kv_pool_pages,
-                qk_norm_eps=self.rms_eps if self.qk_norm else None,
-                summary=self.summary, rotary_dim=self.rotary_dim,
-                output_gate=self.attention_output_gate,
-                qk_norm_unit_offset=offset,
-                qk_norm_gain=self.qk_norm_gain, name="attn")(
-                    h, positions, cache_index, block_table, flash_prefill,
-                    window_pages)
-        else:
-            attn = LatentAttention(
-                self.num_heads, *self.latent, self.rope_theta,
-                self.rope_interleave, self.rms_eps, self.dtype, pdt,
-                use_pallas=self.use_pallas, decode=self.decode,
-                kv_page_size=self.kv_page_size,
-                kv_pool_pages=self.kv_pool_pages,
-                q_head_norm=self.q_head_norm,
-                head_gate=self.attention_head_gate, indexer=self.indexer,
-                name="attn")(
-                    h, positions, cache_index, block_table, window_pages,
-                    chosen)
-            if self.indexer is not None:
-                attn, chosen = attn
+        kind = _KINDS[self.spec.mixer.kind]
+        call = dict(positions=positions, cache_index=cache_index,
+                    block_table=block_table, flash_prefill=flash_prefill,
+                    window_pages=window_pages, last_pos=last_pos,
+                    chosen=chosen)
+        attn = self.spec.mixer.module(_common(self), name=kind.name)(
+            h, *(call[a] for a in kind.takes.split()))
+        beside = dict(advanced=None, streamed=None, chosen=chosen)
+        if kind.beside is not None:
+            attn, beside[kind.beside] = attn
         if self.residual_scale != 1.0:
             attn = attn * self.residual_scale
         x = x + attn
         h2 = rms_norm(x, g2, self.rms_eps, offset).reshape(b * s, d)
-        if not routed:
-            y = gated_mlp(
+
+        def mlp_of(name, width):        # the dense layers' and the shared
+            return gated_mlp(
                 h2.astype(self.dtype),
-                self.param("dense_gate_up", _normal(0.02),
-                           (d, 2 * self.dense_width), pdt).astype(self.dtype),
-                self.param("dense_down", _normal(0.02),
-                           (self.dense_width, d), pdt).astype(self.dtype),
-                self.activation)
-            if self.residual_scale != 1.0:
-                y = y * self.residual_scale
-            return x + y.reshape(b, s, d), None, advanced, streamed, chosen
-        if self.router_input != "pre_attention":
-            idx, weights = choose(h2)
-        y, sizes = routed_experts(h2.astype(self.dtype), idx, weights,
-                                  w_gate_up.astype(self.dtype),
-                                  w_down.astype(self.dtype),
-                                  use_pallas=self.use_pallas,
-                                  activation=self.activation,
-                                  held=self.experts_held)
-        if self.shared_expert_width:
-            fs = self.shared_expert_width
-            shared = gated_mlp(
-                h2.astype(self.dtype),
-                self.param("shared_gate_up", _normal(0.02), (d, 2 * fs),
+                self.param(name + "_gate_up", _normal(0.02), (d, 2 * width),
                            pdt).astype(self.dtype),
-                self.param("shared_down", _normal(0.02), (fs, d),
+                self.param(name + "_down", _normal(0.02), (width, d),
                            pdt).astype(self.dtype),
-                self.activation)
-            if self.shared_expert_gate:
-                shared = shared * jax.nn.sigmoid(jnp.einsum(
-                    "td,dn->tn", h2.astype(self.dtype),
-                    self.param("shared_gate", _normal(0.02), (d, 1),
-                               pdt).astype(self.dtype),
-                    preferred_element_type=jnp.float32))
-            y = y + shared
+                mlp.activation)
+        sizes = None
+        if routed is None:
+            y = mlp_of("dense", mlp.dense_width)
+        else:
+            if routed.router_input != "pre_attention":
+                idx, weights = choose(h2)
+            y, sizes = routed_experts(h2.astype(self.dtype), idx, weights,
+                                      w_gate_up.astype(self.dtype),
+                                      w_down.astype(self.dtype),
+                                      use_pallas=self.use_pallas,
+                                      activation=mlp.activation,
+                                      held=routed.experts_held)
+            if routed.shared_expert_width:
+                shared = mlp_of("shared", routed.shared_expert_width)
+                if routed.shared_expert_gate:
+                    shared = shared * jax.nn.sigmoid(jnp.einsum(
+                        "td,dn->tn", h2.astype(self.dtype),
+                        self.param("shared_gate", _normal(0.02), (d, 1),
+                                   pdt).astype(self.dtype),
+                        preferred_element_type=jnp.float32))
+                y = y + shared
         if self.residual_scale != 1.0:
             y = y * self.residual_scale
-        return x + y.reshape(b, s, d), sizes, advanced, streamed, chosen
+        return (x + y.reshape(b, s, d), sizes, beside["advanced"],
+                beside["streamed"], beside["chosen"])
+
+
+# what a call hands the arithmetic of ``COUNTS``: its shape and arguments
+# (``offset`` [1, S] a token's place in the call, ``live`` [B] the positions a
+# row holds after it, ``page`` the page's tokens, ``itemsize`` a cached
+# value's bytes), the forms its shape chose (``latent_expanded(s)``,
+# ``layers_walking(s)`` — 0 on a first chunk) and what the layers handed up
+_Call = collections.namedtuple("_Call", (
+    "b s positions offset live last_pos cache_index block_table decode page "
+    "itemsize expanded walking advanced streamed picked_rows"))
+
+
+def _of(specs, kind: str):
+    return [spec.mixer for spec in specs if spec.mixer.kind == kind]
+
+
+def _over_real_queries(c):
+    """``x -> sum of x [B, S] over the call's real queries``: not tail
+    padding, not an idle row."""
+    real = jnp.ones((c.b, c.s), bool)
+    if c.last_pos is not None:
+        real &= c.offset <= c.last_pos[:, None]
+    if c.decode and c.block_table is not None:
+        real &= (c.block_table[:, :1] != 0)
+    return lambda x: jnp.sum(jnp.where(real, x, 0), dtype=jnp.int32)
+
+
+def _sparse_counts(specs, c):
+    sparse = _of(specs, "sparse_block")
+    sizes = block_select.Sizes(*sparse[0].arg("sizes")[:7])
+    over = _over_real_queries(c)
+    own = c.positions // sizes.block + 1
+    dense = c.positions + 1 <= sizes.dense_len
+    pairs = len(sparse) * sparse[0].arg("num_kv_heads")
+    return [pairs * over(own),
+            pairs * over(jnp.where(dense, own, sizes.read)),
+            len(sparse) * over(jnp.where(
+                dense, 0, block_select.pooled_exist(c.positions, sizes))),
+            over(dense.astype(jnp.int32)),
+            len(_of(specs, "lightning")) * over(1), c.advanced, c.streamed]
+
+
+def _indexed_counts(specs, c):
+    indexers = [spec.mixer.arg("indexer") for spec in specs]
+    over = _over_real_queries(c)
+    top = indexers[0][3]
+    seen = c.positions + 1
+    counts = [
+        sum(i[0] == "full" for i in indexers) * over(
+            jnp.where(seen > top, seen, 0)),
+        len(specs) * over(seen),
+        # a query that sees top rows or fewer attends them all, through
+        # the dense kernel where the whole chunk does (a membership of
+        # zeros: nothing was chosen)
+        over(jnp.where(seen <= top, len(specs) * seen, c.picked_rows)),
+        over((seen <= top).astype(jnp.int32))]
+    if c.expanded:
+        counts.append(len(specs) * jnp.sum(latent_rows_expanded(
+            c.cache_index, c.s, c.page, c.block_table.shape[1])))
+    return counts
+
+
+def _summary_counts(specs, c):
+    window, chunk = specs[0].mixer.arg("summary")
+    closed = c.positions[:, -1] // window
+    exact = len(specs) * jnp.sum(c.positions[:, -1] - closed * window + 1)
+    return [exact, len(specs) * jnp.sum(closed) * (window // chunk)]
+
+
+def _latent_counts(specs, c):
+    live = c.live
+    if c.expanded:
+        live = latent_rows_expanded(c.cache_index, c.s, c.page,
+                                    c.block_table.shape[1])
+    latent = len(_of(specs, "latent"))
+    counts = [latent * jnp.sum(live)]
+    if len(specs) > latent:
+        # the tokens of the call that are not tail padding
+        real = (c.b * c.s if c.last_pos is None
+                else jnp.sum(c.last_pos.astype(jnp.int32) + 1))
+        counts += [real * (len(specs) - latent), c.advanced]
+    return counts
+
+
+def _heads_counts(specs, c):
+    heads = _of(specs, "attention")
+    n_window = sum(m.arg("window") is not None for m in heads)
+    counts = [(len(heads) - n_window) * jnp.sum(c.live),
+              n_window * jnp.sum(jnp.minimum(
+                  c.live, specs[0].window + c.s - 1))]
+    if len(specs) > len(heads):
+        counts += [jnp.asarray(c.b * c.s * (len(specs) - len(heads)),
+                               jnp.int32), c.advanced]
+    if c.walking:
+        counts.append(c.walking * jnp.sum(chunk_rows_walked(
+            c.cache_index, c.s, c.page, c.block_table.shape[1],
+            heads[0].arg("num_kv_heads") * heads[0].arg("head_dim")
+            * c.itemsize)))
+    return counts
+
+
+# what a model counts by what its attention layers READ, the first kind of
+# these that it has a layer of (whole heads where it has none): the names
+# behind ``STATS[:3]``' three expert counts, whether the state mixers' two
+# counts follow them (``_counted``), and the arithmetic — ``(specs,
+# call) -> the counts``, a count a KIND's layers times a sum, in the names'
+# order (and, where a call's shape chose another form, in
+# ``call_stats_names``')
+COUNTS = {
+    "sparse_block": (STATS[:3] + SPARSE_STATS + LINEAR_STATS
+                     + STREAMED_STATS, False, _sparse_counts),
+    "indexed_latent": (STATS[:3] + INDEX_STATS, False, _indexed_counts),
+    "summary": (SUMMARY_STATS, False, _summary_counts),
+    "latent": (LATENT_STATS, True, _latent_counts),
+    "attention": (STATS, True, _heads_counts),
+}
+
+
+def _counted(specs):
+    """-> (the counts' names of a model of these layers — its row's of
+    ``COUNTS`` and, where the row says so, the state mixers' two — and the
+    row's arithmetic)."""
+    kinds = {spec.mixer.kind for spec in specs}
+    names, state, arithmetic = COUNTS[
+        next((k for k in COUNTS if k in kinds), "attention")]
+    if state and kinds & set(STATE_MIXERS):
+        names += LINEAR_STATS if "linear_delta" in kinds else STATE_STATS[-2:]
+    return names, arithmetic
 
 
 class RoutedDecoderLM(nn.Module):
@@ -2082,48 +2223,142 @@ class RoutedDecoderLM(nn.Module):
     # tensor-parallel layout yet
     model_axis: Optional[str] = None
 
+    @nn.nowrap
+    def layer_specs(self) -> Tuple[LayerSpec, ...]:
+        """What every layer is, one :class:`LayerSpec` a layer: THE reader
+        of the mixer and MLP fields above (the block, the counts and the
+        forms a call's shape chooses all go by its result), and where a
+        configuration that describes no layer is refused — before anything
+        is traced; callable on an unbound model."""
+        n = self.num_layers
+        latent = self.kv_lora_rank is not None
+        if not latent and self.num_heads % self.num_kv_heads:
+            raise ValueError(f"num_heads {self.num_heads} is no multiple of "
+                             f"num_kv_heads {self.num_kv_heads}")
+        lm = tuple(self.layer_mixer)
+        mixers = [lm[i % len(lm)] for i in range(n)]
+        if set(mixers) - set(MIXERS):
+            raise ValueError(f"layer_mixer {self.layer_mixer!r}: each one of "
+                             f"{MIXERS}")
+        if "short_conv" in mixers and latent:
+            raise ValueError("short_conv layers go with whole heads, not "
+                             "with the latent cache")
+        lw, lr = tuple(self.layer_window), tuple(self.layer_rope)
+        windows = [None if latent or not lw[i % len(lw)] else int(self.window)
+                   for i in range(n)]
+        summary = None
+        if self.summary_window is not None:
+            if latent or set(mixers) & set(STATE_MIXERS) or any(
+                    w is not None for w in windows):
+                raise ValueError(
+                    "summary_window goes with whole heads in every layer: "
+                    "no latent cache, no state layer, no sliding window")
+            if self.summary_window % self.summary_chunk:
+                raise ValueError(
+                    f"summary_window {self.summary_window} is not whole "
+                    f"chunks of {self.summary_chunk}")
+            summary = (int(self.summary_window), int(self.summary_chunk))
+        if self.experts_held is not None and (
+                self.experts_held[0] < 0 or self.experts_held[1] < 1
+                or sum(self.experts_held) > self.num_experts):
+            raise ValueError(f"experts_held {self.experts_held!r} is no "
+                             f"block of the {self.num_experts} experts")
+        kinds_i = tuple(self.layer_indexer)
+        if self.indexer is not None and (
+                not latent or set(mixers) != {"attention"}
+                or len(kinds_i) != n or set(kinds_i) - {"full", "shared"}
+                or kinds_i[0] != "full"):
+            raise ValueError(
+                f"an indexer chooses rows of the latent cache: every layer "
+                f"latent attention, layer_indexer {self.layer_indexer!r} "
+                f"one of full | shared a layer, the first of them full")
+        if self.routing not in ("softmax_topk", "sigmoid_bias"):
+            raise ValueError(f"routing {self.routing!r}: softmax_topk or "
+                             f"sigmoid_bias")
+        theta = float(self.rope_theta)
+
+        def same(*names):       # the class calls them what the model does
+            return {k: getattr(self, k) for k in names}
+
+        def mixer(i):
+            word = mixers[i]
+            if word == "short_conv":
+                return _mixer(word, taps=self.conv_taps)
+            if word == "linear_delta":
+                return _mixer(
+                    word, heads=self.linear_heads,
+                    head_dim=self.linear_head_dim, taps=self.linear_conv_taps,
+                    decay_floor=self.linear_decay_floor,
+                    key_heads=self.linear_key_heads, decay=self.linear_decay,
+                    gate=self.linear_gate)
+            if word == "sparse_block":
+                return _mixer(
+                    word, sizes=tuple(self.sparse),
+                    **same("num_heads", "num_kv_heads", "head_dim"))
+            if word == "lightning":
+                # a layer's decay follows from ITS published index
+                heads, head_dim, first, depth = self.lightning
+                return _mixer(word, heads=heads, head_dim=head_dim,
+                              layer=first + i, depth=depth, rope_theta=theta)
+            if latent:
+                indexed = self.indexer is not None
+                return _mixer(
+                    "indexed_latent" if indexed else "latent",
+                    rope_theta=theta, head_gate=self.attention_head_gate,
+                    indexer=((kinds_i[i],) + tuple(self.indexer)
+                             if indexed else None),
+                    **same("num_heads", "q_lora_rank", "kv_lora_rank",
+                           "qk_nope_head_dim", "qk_rope_head_dim",
+                           "v_head_dim", "rope_interleave", "q_head_norm"))
+            return _mixer(
+                "attention" if summary is None else "summary",
+                window=windows[i],
+                rope_theta=theta if lr[i % len(lr)] else None,
+                qk_norm_eps=self.rms_eps if self.qk_norm else None,
+                summary=summary, output_gate=self.attention_output_gate,
+                qk_norm_unit_offset=self.norm_unit_offset,
+                **same("num_heads", "num_kv_heads", "head_dim", "rotary_dim",
+                       "qk_norm_gain"))
+        dense = Mlp(self.dense_width, None, self.activation)
+        routed = Mlp(None, Routed(**dict(
+            same(*Routed._fields), experts_held=self.experts_held
+            and tuple(self.experts_held))), self.activation)
+        return tuple(
+            LayerSpec(mixer(i), dense if i < self.num_dense_layers else routed,
+                      self.window) for i in range(n))
+
+    def layer_mixers(self):
+        """The mixer kind of every layer, in a configuration's words
+        (``MIXERS``)."""
+        return [spec.mixer.kind if spec.mixer.kind in MIXERS else "attention"
+                for spec in self.layer_specs()]
+
+    @property
+    def carries_state(self) -> bool:
+        """Whether the cache holds running state beside pages of history
+        (a short-convolution, linear_delta or lightning layer): a state entry is
+        already past its page's newest token, so that token cannot be
+        replayed on a copy of the page, and a chunk's call has to be told
+        its real length (``last_pos``)."""
+        return bool(set(self.layer_mixers()) & set(STATE_MIXERS))
+
     @property
     def stats_names(self):
-        """What ``"stats"/"counts"`` holds, in order."""
-        names = STATS if self.kv_lora_rank is None else LATENT_STATS
-        if self.indexer is not None:
-            return STATS[:3] + INDEX_STATS
-        if self.summary_window is not None:
-            return SUMMARY_STATS
-        if "sparse_block" in self.layer_mixers():
-            return (STATS[:3] + SPARSE_STATS + LINEAR_STATS
-                    + STREAMED_STATS)
-        if "linear_delta" in self.layer_mixers():
-            return names + LINEAR_STATS
-        return STATE_STATS if self.carries_state else names
+        """What ``"stats"/"counts"`` holds, in order (``COUNTS``)."""
+        return _counted(self.layer_specs())[0]
 
     def latent_expanded(self, s: int) -> bool:
         """Whether the latent layers of a decode-mode call of ``s`` queries
-        a row attend EXPANDED (:class:`LatentAttention`: by the call's shape
-        and the layers' widths)."""
-        return (self.decode and self.kv_lora_rank is not None
-                and latent_expands(
-                    s, self.num_heads,
-                    latent_row_lanes(self.kv_lora_rank,
-                                     self.qk_rope_head_dim),
-                    self.kv_lora_rank, self.qk_nope_head_dim,
-                    self.qk_rope_head_dim, self.v_head_dim))
+        a row attend EXPANDED (``LatentAttention.expands``)."""
+        return bool(_layers_that(self.layer_specs(), _common(self),
+                                 "expands", s))
 
     def layers_walking(self, s: int) -> int:
         """How many layers of a decode-mode CONTINUATION call of ``s``
         queries a row attend through the walk over their K and V pools
-        (``ops.paged_attention.chunk_walks``: by the call's shape and the
-        layers' heads and windows; the layers of :class:`GroupedQueryAttention`
-        under ``STATS``' own counts)."""
-        if (not self.decode or self.kv_lora_rank is not None
-                or self.summary_window is not None
-                or "sparse_block" in self.layer_mixers()):
-            return 0
-        pools = 1 if 2 * self.head_dim == _LANES else 2
-        return sum(m == "attention" and chunk_walks(
-            s, self.num_heads, self.num_kv_heads, window=w, pools=pools)
-                   for (w, _), m in zip(self.layer_kinds(),
-                                        self.layer_mixers()))
+        (``GroupedQueryAttention.walks``; layers under ``STATS``' own
+        counts)."""
+        return _layers_that(self.layer_specs(), _common(self), "walks", s)
 
     def call_stats_names(self, s: int):
         """``stats_names`` of a call of ``s`` queries a row: where its
@@ -2137,37 +2372,16 @@ class RoutedDecoderLM(nn.Module):
         the keys the walks' steps gathered and the chunk's own, summed over
         those layers (a first chunk walks nothing and its counts end before
         it)."""
-        if self.layers_walking(s):
-            return self.stats_names + ("kv_tokens_walked",)
-        if not self.latent_expanded(s):
-            return self.stats_names
-        if self.indexer is not None:
-            return self.stats_names + ("latent_tokens_expanded",)
+        specs, common = self.layer_specs(), _common(self)
+        names = _counted(specs)[0]
+        if _layers_that(specs, common, "walks", s):
+            return names + ("kv_tokens_walked",)
+        if not _layers_that(specs, common, "expands", s):
+            return names
+        if "latent_tokens_read" not in names:       # under an indexer
+            return names + ("latent_tokens_expanded",)
         return tuple("latent_tokens_expanded" if n == "latent_tokens_read"
-                     else n for n in self.stats_names)
-
-    def layer_mixers(self):
-        """The mixer kind of every layer."""
-        lm = tuple(self.layer_mixer)
-        return [lm[i % len(lm)] for i in range(self.num_layers)]
-
-    @property
-    def carries_state(self) -> bool:
-        """Whether the cache holds running state beside pages of history
-        (a short-convolution, linear_delta or lightning layer): a state entry is
-        already past its page's newest token, so that token cannot be
-        replayed on a copy of the page, and a chunk's call has to be told
-        its real length (``last_pos``)."""
-        return bool(set(self.layer_mixers()) & set(STATE_MIXERS))
-
-    def layer_kinds(self):
-        """[(window or None, rope_theta or None)] a layer."""
-        if self.kv_lora_rank is not None:
-            return [(None, float(self.rope_theta))] * self.num_layers
-        lw, lr = tuple(self.layer_window), tuple(self.layer_rope)
-        return [(int(self.window) if lw[i % len(lw)] else None,
-                 float(self.rope_theta) if lr[i % len(lr)] else None)
-                for i in range(self.num_layers)]
+                     else n for n in names)
 
     def close_windows(self, params, cache, block_row, window):
         """Close window ``window`` (a traced int32) of ONE row in every
@@ -2179,10 +2393,11 @@ class RoutedDecoderLM(nn.Module):
         (``ops.window_summary.compact_window``, one call a layer).  The
         caller runs it before the first write into the next window.
         Returns the cache."""
-        page, chunk = self.kv_page_size, self.summary_chunk
-        n = self.summary_window // chunk // page
+        summary_window, chunk = self.layer_specs()[0].mixer.arg("summary")
+        page = self.kv_page_size
+        n = summary_window // chunk // page
         pages = jax.lax.dynamic_slice(block_row, (n * window,),
-                                      (self.summary_window // page,))
+                                      (summary_window // page,))
         cache = dict(cache)
         for i in range(self.num_layers):
             attn = params[f"layer{i}"]["attn"]
@@ -2204,43 +2419,7 @@ class RoutedDecoderLM(nn.Module):
         if self.model_axis is not None:
             raise ValueError("the routed decoder has no tensor-parallel "
                              "layout (serve it on one device)")
-        if self.kv_lora_rank is None and self.num_heads % self.num_kv_heads:
-            raise ValueError(f"num_heads {self.num_heads} is no multiple of "
-                             f"num_kv_heads {self.num_kv_heads}")
-        mixers = self.layer_mixers()
-        if set(mixers) - set(MIXERS):
-            raise ValueError(f"layer_mixer {self.layer_mixer!r}: each one of "
-                             f"{MIXERS}")
-        if "short_conv" in mixers and self.kv_lora_rank is not None:
-            raise ValueError("short_conv layers go with whole heads, not "
-                             "with the latent cache")
-        summary = None
-        if self.summary_window is not None:
-            if self.kv_lora_rank is not None or self.carries_state or any(
-                    w is not None for w, _ in self.layer_kinds()):
-                raise ValueError(
-                    "summary_window goes with whole heads in every layer: "
-                    "no latent cache, no state layer, no sliding window")
-            if self.summary_window % self.summary_chunk:
-                raise ValueError(
-                    f"summary_window {self.summary_window} is not whole "
-                    f"chunks of {self.summary_chunk}")
-            summary = (int(self.summary_window), int(self.summary_chunk))
-        if self.experts_held is not None and (
-                self.experts_held[0] < 0 or self.experts_held[1] < 1
-                or sum(self.experts_held) > self.num_experts):
-            raise ValueError(f"experts_held {self.experts_held!r} is no "
-                             f"block of the {self.num_experts} experts")
-        kinds_i = tuple(self.layer_indexer)
-        if self.indexer is not None and (
-                self.kv_lora_rank is None or set(mixers) != {"attention"}
-                or len(kinds_i) != self.num_layers
-                or set(kinds_i) - {"full", "shared"}
-                or kinds_i[0] != "full"):
-            raise ValueError(
-                f"an indexer chooses rows of the latent cache: every layer "
-                f"latent attention, layer_indexer {self.layer_indexer!r} "
-                f"one of full | shared a layer, the first of them full")
+        specs = self.layer_specs()
         b, s = tokens.shape
         pdt = jnp.dtype(self.param_dtype)
         embed = self.param("embed", _normal(0.02),
@@ -2257,69 +2436,30 @@ class RoutedDecoderLM(nn.Module):
             positions = cache_index[:, None] + offset
         else:
             positions = jnp.broadcast_to(offset, (b, s))
-        kinds = self.layer_kinds()
-        latent = None
-        if self.kv_lora_rank is not None:
-            latent = (self.q_lora_rank, self.kv_lora_rank,
-                      self.qk_nope_head_dim, self.qk_rope_head_dim,
-                      self.v_head_dim)
-        n_routed = len(kinds) - self.num_dense_layers
         touched = load_max = advanced = computed = streamed = jnp.zeros(
             (), jnp.int32)
-        linear = (self.linear_heads, self.linear_head_dim,
-                  self.linear_conv_taps, self.linear_decay_floor,
-                  self.linear_key_heads, self.linear_decay,
-                  self.linear_gate)
         chosen, chosen_rows, picked_rows = None, 0, 0
-        for i, (window, theta) in enumerate(kinds):
+        for i, spec in enumerate(specs):
             x, sizes, rows, copied, chosen = RoutedBlock(
-                self.num_heads, self.num_kv_heads, self.head_dim,
-                self.num_experts, self.experts_per_token, self.expert_width,
-                window, theta, self.rms_eps, self.dtype, pdt,
+                spec, self.rms_eps, self.dtype, pdt,
                 use_pallas=self.use_pallas, decode=self.decode,
                 kv_page_size=self.kv_page_size,
-                kv_pool_pages=self.kv_pool_pages, latent=latent,
-                rope_interleave=self.rope_interleave,
-                dense_width=(self.dense_width
-                             if i < self.num_dense_layers else None),
-                shared_expert_width=self.shared_expert_width,
-                routing=self.routing, routed_scale=self.routed_scale,
-                router_bias_stddev=self.router_bias_stddev,
-                activation=self.activation, router_input=self.router_input,
-                mixer=mixers[i], conv_taps=self.conv_taps,
-                qk_norm=self.qk_norm,
-                routing_sum_eps=self.routing_sum_eps, linear=linear,
-                q_head_norm=self.q_head_norm,
-                attention_head_gate=self.attention_head_gate,
-                route_groups=self.route_groups,
-                route_groups_kept=self.route_groups_kept,
-                experts_held=self.experts_held, summary=summary,
-                norm_unit_offset=self.norm_unit_offset, sparse=self.sparse,
-                lightning=(None if self.lightning is None else (
-                    self.lightning[0], self.lightning[1],
-                    self.lightning[2] + i, self.lightning[3],
-                    float(self.rope_theta))),
-                residual_scale=residual_scale,
-                indexer=(None if self.indexer is None
-                         else (kinds_i[i],) + tuple(self.indexer)),
-                rotary_dim=self.rotary_dim,
-                attention_output_gate=self.attention_output_gate,
-                shared_expert_gate=self.shared_expert_gate,
-                qk_norm_gain=self.qk_norm_gain,
-                name=f"layer{i}")(
+                kv_pool_pages=self.kv_pool_pages,
+                norm_unit_offset=self.norm_unit_offset,
+                residual_scale=residual_scale, name=f"layer{i}")(
                     x, positions, cache_index, block_table, flash_prefill,
                     window_pages, last_pos, chosen)
             if chosen is not None:
                 # what the layer's queries attended, counted from the
                 # membership it went by: a full layer's own, a shared
                 # layer's the count of the layer it took it from
-                if kinds_i[i] == "full":
+                if spec.mixer.arg("indexer")[0] == "full":
                     chosen_rows = index_select.rows_chosen(chosen, positions)
                 picked_rows = picked_rows + chosen_rows
             if sizes is not None:
                 touched += jnp.sum(sizes > 0, dtype=jnp.int32)
                 load_max += jnp.max(sizes)
-                if self.experts_held is not None:
+                if spec.mlp.routed.experts_held is not None:
                     computed += jnp.sum(sizes, dtype=jnp.int32)
             if rows is not None:
                 advanced += rows
@@ -2329,97 +2469,19 @@ class RoutedDecoderLM(nn.Module):
         # row's whole history in a full layer (K and V, or the one latent
         # row a token), the window's reach in a window layer
         live = positions[:, -1] + 1
+        routed = [spec.mlp.routed for spec in specs if spec.mlp.routed]
         assignments = jnp.asarray(
-            b * s * self.experts_per_token * n_routed, jnp.int32)
-        if self.experts_held is not None:
+            b * s * sum(r.experts_per_token for r in routed), jnp.int32)
+        if any(r.experts_held is not None for r in routed):
             assignments = computed      # the pairs computed HERE
-        n_state = len(kinds) - mixers.count("attention")
-        if "sparse_block" in mixers:
-            sizes = block_select.Sizes(*self.sparse[:7])
-            # the call's real queries: not tail padding, not an idle row
-            real = jnp.ones((b, s), bool)
-            if last_pos is not None:
-                real &= offset <= last_pos[:, None]
-            if self.decode and block_table is not None:
-                real &= (block_table[:, :1] != 0)
-            own = positions // sizes.block + 1
-            dense = positions + 1 <= sizes.dense_len
-            pairs = mixers.count("sparse_block") * self.num_kv_heads
-
-            def over(x):
-                return jnp.sum(jnp.where(real, x, 0), dtype=jnp.int32)
-            counts = jnp.stack([
-                assignments, touched, load_max, pairs * over(own),
-                pairs * over(jnp.where(dense, own, sizes.read)),
-                mixers.count("sparse_block") * over(jnp.where(
-                    dense, 0, block_select.pooled_exist(positions, sizes))),
-                over(dense.astype(jnp.int32)),
-                mixers.count("lightning") * over(1), advanced, streamed])
-        elif self.indexer is not None:
-            # the call's real queries: not tail padding, not an idle row
-            real = jnp.ones((b, s), bool)
-            if last_pos is not None:
-                real &= offset <= last_pos[:, None]
-            if self.decode and block_table is not None:
-                real &= (block_table[:, :1] != 0)
-            top = self.indexer[2]
-            seen = positions + 1
-
-            def over(x):
-                return jnp.sum(jnp.where(real, x, 0), dtype=jnp.int32)
-            counts = [
-                assignments, touched, load_max,
-                kinds_i.count("full") * over(jnp.where(seen > top, seen, 0)),
-                len(kinds) * over(seen),
-                # a query that sees top rows or fewer attends them all,
-                # through the dense kernel where the whole chunk does (a
-                # membership of zeros: nothing was chosen)
-                over(jnp.where(seen <= top, len(kinds) * seen, picked_rows)),
-                over((seen <= top).astype(jnp.int32))]
-            if self.latent_expanded(s):
-                counts.append(len(kinds) * jnp.sum(latent_rows_expanded(
-                    cache_index, s, self.kv_page_size,
-                    block_table.shape[1])))
-            counts = jnp.stack(counts)
-        elif latent is not None:
-            if self.latent_expanded(s):
-                live = latent_rows_expanded(
-                    cache_index, s, self.kv_page_size, block_table.shape[1])
-            counts = [assignments, touched, load_max,
-                      mixers.count("attention") * jnp.sum(live)]
-            if n_state:
-                # the tokens of the call that are not tail padding
-                real = (b * s if last_pos is None
-                        else jnp.sum(last_pos.astype(jnp.int32) + 1))
-                counts += [real * n_state, advanced]
-            counts = jnp.stack(counts)
-        elif summary is not None:
-            window, chunk = summary
-            closed = positions[:, -1] // window
-            exact = len(kinds) * jnp.sum(positions[:, -1] - closed * window
-                                         + 1)
-            summaries = len(kinds) * jnp.sum(closed) * (window // chunk)
-            counts = jnp.stack([assignments, touched, load_max, exact,
-                                summaries])
-        else:
-            attends = [w for (w, _), m in zip(kinds, mixers)
-                       if m == "attention"]
-            n_window = sum(w is not None for w in attends)
-            counts = [
-                assignments, touched, load_max,
-                (len(attends) - n_window) * jnp.sum(live),
-                n_window * jnp.sum(jnp.minimum(live, self.window + s - 1))]
-            if self.carries_state:
-                counts += [jnp.asarray(
-                    b * s * (len(kinds) - len(attends)), jnp.int32), advanced]
-            walking = 0 if flash_prefill else self.layers_walking(s)
-            if walking:
-                counts.append(walking * jnp.sum(
-                    chunk_rows_walked(
-                        cache_index, s, self.kv_page_size,
-                        block_table.shape[1], self.num_kv_heads
-                        * self.head_dim * jnp.dtype(self.dtype).itemsize)))
-            counts = jnp.stack(counts)
+        counts = _counted(specs)[1](
+            specs, _Call(
+                b, s, positions, offset, live, last_pos, cache_index,
+                block_table, self.decode, self.kv_page_size,
+                jnp.dtype(self.dtype).itemsize, self.latent_expanded(s),
+                0 if flash_prefill else self.layers_walking(s), advanced,
+                streamed, picked_rows))
+        counts = jnp.stack([assignments, touched, load_max] + counts)
         n_counts = counts.shape[0]
         self.sow("stats", "counts", counts,
                  reduce_fn=lambda _, new: new,

@@ -49,7 +49,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from dtf_tpu.ops.flash_attention import flash_attention
-from dtf_tpu.ops.paged_attention import (chunk_walks, expand_kv_heads,
+from dtf_tpu.ops.paged_attention import (expand_kv_heads,
                                          latent_chunk_attention,
                                          paged_attention_auto,
                                          paged_chunk_attention, write_pages)
@@ -82,7 +82,8 @@ def paged_cache_attention(module, q, k, v, cache_index, block_table, *,
                           window: Optional[int] = None,
                           scale: Optional[float] = None,
                           value_lanes: Optional[int] = None,
-                          one_row: bool = False, expand=None):
+                          one_row: bool = False, walks: bool = False,
+                          expand=None):
     """Write-then-attend against the shared page pool — what every
     decoder family's attention does with the paged cache, called from
     inside the attention module's ``@nn.compact`` body (``module`` owns
@@ -110,7 +111,12 @@ def paged_cache_attention(module, q, k, v, cache_index, block_table, *,
     ``one_row``: K and V of a head share ONE pool row, ``[k | v]`` — one
     pool ``[P, page, Hkv, 2 * Dh]`` a layer, for heads half a lane tile
     wide (64: two pools of 64-lane rows would each be stored in 128 lanes,
-    twice the HBM and twice the DMA of every page)."""
+    twice the HBM and twice the DMA of every page).  ``walks``: a
+    continuation chunk over K and V pools attends through the walk
+    (``ops.paged_attention.paged_chunk_attention``) — the caller's
+    decision, taken where its heads and window are known
+    (``GroupedQueryAttention.walks``; ``CausalSelfAttention``'s heads are
+    not grouped and never walk)."""
     s = q.shape[1]
     # paged cache: one shared pool per K/V, sized by the module
     # attrs (NOT by the init call's shapes — admission capacity
@@ -182,7 +188,7 @@ def paged_cache_attention(module, q, k, v, cache_index, block_table, *,
         # O(S·D) HBM traffic instead of an [S, L] gather
         # (a chunk no longer than the window sees all of itself)
         return flash(k, v)
-    if chunk_walks(s, q.shape[2], k.shape[2], window=window):
+    if walks:
         # a continuation chunk whose rows a KV head fill a tile of the
         # flash forward: its pages walked through that kernel, a page
         # read once a tile (ops.paged_attention.paged_chunk_attention)

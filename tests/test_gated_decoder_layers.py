@@ -249,12 +249,18 @@ def test_the_rotary_part_and_the_gate_change_the_layer():
 
 
 def _block(held=None, gate=True):
-    return rd.RoutedBlock(
-        4, 2, 16, 16, 4, 32, None, 1e7, 1e-6, F32, F32,
+    """One layer of a model of such layers: what the layer is comes from
+    the model's ``layer_specs()``, as the model's own loop takes it."""
+    model = rd.RoutedDecoderLM(
+        vocab_size=8, num_layers=1, num_heads=4, num_kv_heads=2, head_dim=16,
+        num_experts=16, experts_per_token=4, expert_width=32,
+        layer_window=(False,), layer_rope=(True,), rope_theta=1e7,
         shared_expert_width=32, activation="silu",
         router_input="post_attention", qk_norm=True, norm_unit_offset=True,
         rotary_dim=4, attention_output_gate=True, shared_expert_gate=gate,
         experts_held=held)
+    return rd.RoutedBlock(model.layer_specs()[0], 1e-6, F32, F32,
+                          norm_unit_offset=True)
 
 
 def test_the_four_shares_and_the_shared_expert_once_make_the_uncut_layer():
