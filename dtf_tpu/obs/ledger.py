@@ -143,7 +143,9 @@ def _pallas_kernels(hlo: str) -> Dict[str, int]:
     return counts
 
 
-COLLECTIVE_OPS = ("reduce-scatter", "all-reduce", "all-gather")
+# ``collective-permute``: a hop of the ZeRO scatter's ring (train/zero.py)
+COLLECTIVE_OPS = ("reduce-scatter", "all-reduce", "all-gather",
+                  "collective-permute")
 _HLO_DEF = re.compile(r"^\s*(?:ROOT )?(%[\w.-]+) = (.*?) ([a-z][\w-]*)\((.*)$")
 _HLO_ARRAY = re.compile(r"\b([a-z]+)(\d+)?\[([\d,]*)\]")
 

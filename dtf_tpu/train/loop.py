@@ -618,6 +618,7 @@ class Trainer:
         nd = mesh_shape[DATA_AXIS]
         zero_stage = self.zero_stage
         zero_wire = self.zero_wire
+        ring = zero_lib.ring_order(mesh) if self.zero else None
 
         def reduce_grads(grads):
             if param_specs is None:
@@ -717,6 +718,7 @@ class Trainer:
             zspecs = param_specs
             if zero:
                 idx = lax.axis_index(DATA_AXIS)
+                hops = zero_lib.ring_hops(ring, idx)
 
             # ZeRO-3: the params the model computes with are gathered
             # PER LEAF from their 1/nd slices at the top of the step —
@@ -745,7 +747,7 @@ class Trainer:
                 return jax.tree_util.tree_map(
                     lambda spec, g: zero_lib.scatter_leaf(
                         spec, g, nd, reduce_axes, mesh_shape, comm_off,
-                        idx, wire=zero_wire),
+                        idx, wire=zero_wire, ring=hops),
                     zspecs, grads, is_leaf=is_p)
 
             g_slices_acc = None
@@ -754,12 +756,14 @@ class Trainer:
                     model_params, state.batch_stats, images, labels)
             elif zero_stage >= 2:
                 # ZeRO-2/3 sharded gradient accumulation: each chunk's
-                # grads reduce-scatter into f32 slices AS THE BACKWARD
-                # PRODUCES THEM (per-leaf psum_scatter adjacent to its
-                # producing op — XLA can overlap the wire with compute
-                # and free each full grad immediately), so the scan
+                # grads scatter into f32 slices inside the scan's body
+                # (per leaf, right after its producing op), so the scan
                 # carry holds 1/nd-sized slices instead of a second
-                # full gradient buffer
+                # full gradient buffer.  What overlaps: a large leaf's
+                # ring of collective-permutes runs beside the weight-
+                # gradient matmuls under zero.TPU_STEP_OPTIONS; a
+                # reduce-scatter (small leaves) never does — the TPU
+                # compiler runs it synchronously (train/zero.py)
                 chunks = jax.tree_util.tree_map(
                     lambda x: x.reshape((accum, x.shape[0] // accum)
                                         + x.shape[1:]), (images, labels))
@@ -976,11 +980,18 @@ class Trainer:
             out_specs=(rep, rep, rep),
             check_vma=False)
 
+        # a step that scatters over the ring is built with the cap that
+        # lays the hops under the backward (zero.TPU_STEP_OPTIONS); the
+        # CPU's compiler does not know the option, and a step without a
+        # ZeRO stage compiles exactly as before
+        on_tpu = mesh.devices.flat[0].platform == "tpu"
+        xla = zero_lib.TPU_STEP_OPTIONS if ring and on_tpu else None
         if comm_off:
             # the --zero_probe timing twin: returned, never installed,
             # never donated (its caller reuses the live state)
             return jax.jit(train_sharded)
-        self.train_step = jax.jit(train_sharded, donate_argnums=(0,))
+        self.train_step = jax.jit(train_sharded, donate_argnums=(0,),
+                                  compiler_options=xla)
         self.eval_step = jax.jit(eval_sharded)
         return None
 
@@ -1030,15 +1041,18 @@ class Trainer:
         grad_slice_specs = jax.tree_util.tree_map(
             zero_lib.zero_leaf_spec, pspecs, is_leaf=zero_lib.is_spec)
         reduce_axes = (DATA_AXIS, SEQ_AXIS)
+        ring = zero_lib.ring_order(mesh)
 
         def scatter_local(p):
             idx = lax.axis_index(DATA_AXIS)
+            hops = zero_lib.ring_hops(ring, idx)
             # same wire dtype as the live step: the probe must price
             # the collectives the run actually emits (--zero_wire)
             return zero_lib.tree_map_specs(
                 lambda spec, g: zero_lib.scatter_leaf(
                     spec, g.astype(jnp.float32), nd, reduce_axes,
-                    mesh_shape, False, idx, wire=self.zero_wire),
+                    mesh_shape, False, idx, wire=self.zero_wire,
+                    ring=hops),
                 pspecs, p)
 
         def gather_local(s):

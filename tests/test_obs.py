@@ -868,6 +868,8 @@ ENTRY %main.1 (p0: f32[8,128], p1: bf16[4,256]) -> f32[8,128] {
   %agd.1 = bf16[4,1024]{1,0} all-gather-done(%ags.1)
   %ag.2 = bf16[4,1024]{1,0:T(4,128)(2,1)} all-gather(bf16[4,256]{1,0} %p1), channel_id=4, dimensions={1}
   %ds.1 = f32[8,32]{1,0} dynamic-slice(%p0, %div.709, %div.709), dynamic_slice_sizes={8,32}
+  %collective-permute-start.24 = (f32[8,32]{0,1:T(8,128)}, f32[8,32]{0,1:T(8,128)}, u32[]{:S(2)}, u32[]{:S(2)}) collective-permute-start(%ds.1), channel_id=5, source_target_pairs={{0,1},{1,3},{3,2},{2,0}}, metadata={op_name="jit(step)/shard_map/ppermute"}
+  %collective-permute-done.24 = f32[8,32]{0,1:T(8,128)} collective-permute-done(%collective-permute-start.24)
   ROOT %fusion.1 = f32[8,128]{1,0} fusion(%rs.1, %agd.1), kind=kLoop, calls=%region_0.1, metadata={op_name="jit(step)/all-reduce(not one)"}
 }
 """
@@ -876,8 +878,9 @@ ENTRY %main.1 (p0: f32[8,128], p1: bf16[4,256]) -> f32[8,128] {
 def test_ledger_counts_the_collectives_the_compiler_left(caplog):
     """``collectives`` reads call sites and operand bytes off the optimized
     HLO — one ``reduce-scatter`` of a 4 KiB operand, one ``all-reduce`` of
-    two scalars, two ``all-gather`` (an async pair once) of 2 KiB each —
-    and ``register`` puts them on the entry, the trace event and ONE log
+    two scalars, two ``all-gather`` (an async pair once) of 2 KiB each,
+    one ``collective-permute`` pair (a hop of the ZeRO scatter's ring) of
+    1 KiB — and ``register`` puts them on the entry, the trace event and ONE log
     line at compile."""
     import logging
     from dtf_tpu.obs.ledger import Ledger, collectives
@@ -892,7 +895,8 @@ def test_ledger_counts_the_collectives_the_compiler_left(caplog):
 
     want = {"reduce-scatter": {"ops": 1, "bytes": 8 * 128 * 4},
             "all-reduce": {"ops": 1, "bytes": 8},
-            "all-gather": {"ops": 2, "bytes": 2 * 4 * 256 * 2}}
+            "all-gather": {"ops": 2, "bytes": 2 * 4 * 256 * 2},
+            "collective-permute": {"ops": 1, "bytes": 8 * 32 * 4}}
     assert collectives(Compiled()) == want
     ledger = Ledger(MetricsRegistry())
     with caplog.at_level(logging.INFO, logger="dtf_tpu"):
@@ -901,7 +905,7 @@ def test_ledger_counts_the_collectives_the_compiler_left(caplog):
              if "train_step compiled" in r.getMessage()]
     assert len(lines) == 1
     assert "1 reduce-scatter (4096 B), 1 all-reduce (8 B), " \
-           "2 all-gather (4096 B)" in lines[0]
+           "2 all-gather (4096 B), 1 collective-permute (1024 B)" in lines[0]
     assert ledger.summary()["train_step"]["collectives"] == want
 
 

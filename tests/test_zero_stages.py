@@ -335,9 +335,9 @@ def test_whole_rows_have_no_large_prime_factor():
         assert whole_rows(got) == got
 
 
-def _data_mesh(devices, nd):
+def _data_mesh(devices, nd, seq=1):
     from dtf_tpu.runtime.mesh import make_mesh
-    return make_mesh(devices[:nd], data=nd)
+    return make_mesh(devices[:nd * seq], data=nd, seq=seq)
 
 
 @pytest.mark.parametrize("nd", [1, 2, 4])
@@ -421,28 +421,33 @@ def test_scatter_is_the_mean_and_comm_off_cuts_the_same_elements(
 class _MLP(nn.Module):
     """Leaves of every kind the layout knows: [192, 512] leaf-shaped at
     nd <= 4, [512, 24] flat and tail-padded, [24, 10] and the biases
-    tiny."""
+    tiny.  At ``width`` 2048 the first kernel (1.5 MiB) rides the
+    scatter's ring; everything else stays under the threshold."""
+    width: int = 512
 
     @nn.compact
     def __call__(self, x, train=False):
         x = x.reshape((x.shape[0], -1))
-        x = nn.relu(nn.Dense(512)(x))
+        x = nn.relu(nn.Dense(self.width)(x))
         x = nn.relu(nn.Dense(24)(x))
         return nn.Dense(10)(x)
 
 
-def _mlp_steps(stage, nd, accum, wire, steps=3):
+def _mlp_steps(stage, nd, accum, wire, steps=3, width=512, lowered=None):
+    """``lowered``: a list that receives the step's lowered text."""
     cfg = _cfg("", stage, steps, checkpoint_steps=0, skip_checkpoint=True)
     cfg = cfg.replace(num_devices=nd, grad_accum_steps=accum,
                       zero_wire=wire if stage >= 2 else "fp32")
     rt = initialize(cfg)
-    trainer = Trainer(cfg, rt, _MLP(), 1e-4, TINY,
+    trainer = Trainer(cfg, rt, _MLP(width), 1e-4, TINY,
                       schedule=lambda s: 0.05)
     rng = np.random.default_rng(2)
     images = rng.normal(0, 1, (8, 8, 8, 3)).astype(np.float32)
     labels = rng.integers(0, 10, (8,)).astype(np.int32)
     state = trainer.init_state(jax.random.key(0), (images, labels))
     batch = rt.shard_batch((images, labels))
+    if lowered is not None:
+        lowered.append(trainer.train_step.lower(state, *batch).as_text())
     losses = []
     for _ in range(steps):
         state, m = trainer.train_step(state, *batch)
@@ -475,3 +480,143 @@ def test_stages_match_plain_dp_on_every_leaf_kind(eight_devices, nd, accum,
                 np.asarray(params[path]), np.asarray(r), atol=2e-6,
                 rtol=1e-5, err_msg=f"stage {stage} "
                                    f"{jax.tree_util.keystr(path)}")
+
+
+# ---------------------------------------------------------------------------
+# the gradient scatter's ring (zero._ring_scatter): a leaf of RING_MIN_BYTES
+# or more reaches its owner by hops of ppermute and local adds
+# ---------------------------------------------------------------------------
+
+# (shape, wire, seq, ring order | None = the mesh's own, rides the ring)
+RING_CASES = {
+    # [512, 1024]: the leaf's own last dimension at nd <= 8, 2 MiB
+    "leaf_shaped": ((512, 1024), "fp32", 1, None, True),
+    # flat and tail-padded: a shard's block is ONE lane tile, cut by rows
+    "flat_128_columns": ((300_000,), "fp32", 1, None, True),
+    # 64 KB: under the threshold, still one psum_scatter
+    "under_threshold": ((16, 1024), "fp32", 1, None, False),
+    # hops over 'data' under a 'seq' axis, then the pmean over 'seq'
+    "seq_axis": ((512, 1024), "fp32", 2, None, True),
+    # the hops carry and sum bf16, as the collective does
+    "wire_bf16": ((1024, 1024), "bf16", 1, None, True),
+    # an order that is not the axis's: what ring_order gives a 2 x 2 host
+    "ring_0132": ((512, 1024), "fp32", 1, "twisted", True),
+}
+
+
+@pytest.mark.parametrize("nd", [2, 4, 8])
+@pytest.mark.parametrize("case", RING_CASES)
+def test_ring_scatter_is_psum_scatter_on_the_same_owner(eight_devices, case,
+                                                        nd):
+    """The ring's ``scatter_leaf`` equals ``psum_scatter``'s to the
+    re-association of an f32 sum of nd terms, shard i holds
+    ``own_columns(view, nd, i)`` of the mean, and the size rule alone
+    decides which of the two a leaf compiles to."""
+    import jax.numpy as jnp
+    from dtf_tpu.runtime.mesh import SEQ_AXIS
+    from dtf_tpu.train import zero as zero_lib
+    from jax import lax
+    shape, wire, seq, order, rides = RING_CASES[case]
+    if nd * seq > len(eight_devices):
+        nd = len(eight_devices) // seq      # 'seq' takes half the devices
+    mesh = _data_mesh(eight_devices, nd, seq)
+    ring = zero_lib.ring_order(mesh)
+    assert ring == tuple(range(nd))         # CPU devices have no coords
+    if order == "twisted":
+        # pairs swapped from the second on: 0-1-3-2 at nd 4
+        ring = tuple(i ^ (i >> 1 & 1) for i in range(nd))
+    wire_dt = jnp.bfloat16 if wire == "bf16" else jnp.float32
+    view_sds = jax.ShapeDtypeStruct(zero_lib.slice_view(shape, nd), wire_dt)
+    assert (zero_lib.ring_halves(view_sds, nd) is not None) == rides
+    if case == "flat_128_columns":
+        assert view_sds.shape[1] == nd * 128
+        assert zero_lib.ring_halves(view_sds, nd)[0] == 0   # cut by rows
+    g = np.random.default_rng(3).normal(
+        size=(nd, seq) + shape).astype(np.float32)
+
+    def local(g, ring):
+        idx = lax.axis_index(DATA_AXIS)
+        return zero_lib.scatter_leaf(
+            P(), g[0, 0], nd, (DATA_AXIS, SEQ_AXIS), dict(mesh.shape),
+            False, idx, wire=wire_dt, ring=zero_lib.ring_hops(ring, idx))
+
+    def scattered(ring):
+        fn = jax.jit(jax.shard_map(
+            lambda g: local(g, ring), mesh=mesh,
+            in_specs=(P(DATA_AXIS, SEQ_AXIS),),
+            out_specs=zero_lib.zero_leaf_spec(P()), check_vma=False))
+        hops = fn.lower(g).as_text().count("collective_permute")
+        return np.asarray(fn(g)), hops
+
+    got, hops = scattered(ring)
+    native, none = scattered(None)
+    assert none == 0
+    assert hops == (2 * (nd - 1) if rides else 0)
+    want = np.asarray(zero_lib.as_view(g.mean((0, 1)), nd))
+    tol = dict(rtol=2e-2, atol=2e-2) if wire == "bf16" else dict(
+        rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(got, native, **tol)
+    np.testing.assert_allclose(got, want, **tol)
+
+
+@pytest.mark.parametrize("nd,stage,accum", [(4, 2, 2), (2, 3, 2), (4, 1, 1)])
+def test_ring_inside_the_step_matches_plain_dp(eight_devices, nd, stage,
+                                               accum):
+    """The whole step with a leaf over the threshold: the hops sit in
+    the stage-2/3 accumulation scan's body (or, stage 1, after the
+    backward), and three steps end where plain data parallelism's do."""
+    texts = []
+    ref_losses, ref = _mlp_steps(0, nd, accum, "fp32", width=2048,
+                                 lowered=texts)
+    losses, params = _mlp_steps(stage, nd, accum, "fp32", width=2048,
+                                lowered=texts)
+    ref_text, text = texts
+    assert "collective_permute" not in ref_text
+    # one leaf rides: 2 x (nd - 1) hops at its one call site
+    assert text.count("collective_permute") == 2 * (nd - 1)
+    if accum > 1:
+        assert "collective_permute" in text[text.index("stablehlo.while"):]
+    np.testing.assert_allclose(losses, ref_losses, rtol=1e-5)
+    for path, r in ref.items():
+        np.testing.assert_allclose(
+            np.asarray(params[path]), np.asarray(r), atol=2e-6, rtol=1e-5,
+            err_msg=jax.tree_util.keystr(path))
+
+
+def test_ring_order_follows_the_chips_coords():
+    """A 2 x 2 host's data axis is the cycle 0-1-3-2 (every hop one ICI
+    link), CPU devices ring in axis order, and an axis of one or one
+    that leaves its slice has no ring."""
+    import types
+    from jax.sharding import Mesh
+    from dtf_tpu.runtime.mesh import MESH_AXES
+    from dtf_tpu.train import zero as zero_lib
+
+    def mesh_of(chips, **axes):
+        shape = [axes.get(a, 1) for a in MESH_AXES]
+        arr = np.empty(len(chips), object)
+        arr[:] = chips
+        return types.SimpleNamespace(devices=arr.reshape(shape),
+                                     axis_names=MESH_AXES)
+
+    def chip(x, y, slice_index=0):
+        return types.SimpleNamespace(coords=(x, y, 0),
+                                     slice_index=slice_index)
+
+    host = [chip(0, 0), chip(1, 0), chip(0, 1), chip(1, 1)]
+    assert zero_lib.ring_order(mesh_of(host, data=4)) == (0, 1, 3, 2)
+    # data 2 x model 2: the data axis's first column is chips 0 and 2
+    assert zero_lib.ring_order(mesh_of(host, data=2, model=2)) == (0, 1)
+    assert zero_lib.ring_order(mesh_of(host[:1], data=1)) is None
+    row = [chip(x, 0) for x in range(4)]        # no link closes a line
+    assert zero_lib.ring_order(mesh_of(row, data=4)) == (0, 1, 2, 3)
+    grid = [chip(x, y) for y in range(4) for x in range(4)]
+    ring = zero_lib.ring_order(mesh_of(grid, data=16))
+    assert sorted(ring) == list(range(16))
+    for a, b in zip(ring, ring[1:] + ring[:1]):
+        assert sum(abs(p - q) for p, q in
+                   zip(grid[a].coords, grid[b].coords)) == 1
+    across = [chip(0, 0), chip(1, 0), chip(0, 0, 1), chip(1, 0, 1)]
+    assert zero_lib.ring_order(mesh_of(across, data=4)) is None
+    cpu = Mesh(np.array(jax.devices()[:4]).reshape(4, 1, 1), MESH_AXES)
+    assert zero_lib.ring_order(cpu) == (0, 1, 2, 3)
