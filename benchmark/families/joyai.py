@@ -78,8 +78,8 @@ def latent_attention_reads(cfg: dict, span: dict):
     read once (``row_lanes`` bf16 values as stored: 1,280 bytes for the 576
     that carry something), and meets every query head of every query of
     the call in a score over ``kv_lora_rank + qk_rope_head_dim`` values and
-    a value sum over ``kv_lora_rank``.  Every chunk goes through the paged
-    kernel, the first too.  None where the span carries no count."""
+    a value sum over ``kv_lora_rank``.  The DECODE STEPS alone since PR 53 (a
+    chunk attends expanded).  None where the span carries no count."""
     if "latent_tokens_read" not in span:
         return None
     tokens = span["latent_tokens_read"]

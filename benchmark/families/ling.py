@@ -118,9 +118,9 @@ def latent_attention_reads(cfg: dict, span: dict):
     layers attend (``latent_tokens_read`` counts those layers only) is read
     once, ``row_lanes`` bf16 values as stored, and meets every query head
     of every query of the call in a score over ``kv_lora_rank +
-    qk_rope_head_dim`` values and a value sum over ``kv_lora_rank``.  Every
-    chunk goes through the paged kernel, the first too.  None where the
-    span carries no count."""
+    qk_rope_head_dim`` values and a value sum over ``kv_lora_rank``.  The
+    DECODE STEPS alone since PR 53 (a chunk attends expanded).  None where
+    the span carries no count."""
     if "latent_tokens_read" not in span:
         return None
     tokens = span["latent_tokens_read"]

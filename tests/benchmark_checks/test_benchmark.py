@@ -20,7 +20,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmark import families  # noqa: E402
+import structure  # noqa: E402  (beside this file)
+from benchmark import families, twins  # noqa: E402
 from benchmark.lib import (agreement, contract, costs, peaks, stats,  # noqa: E402
                            traffic, xplane)
 from benchmark.lib.runtime import load_benchmark, load_cell, load_json  # noqa: E402
@@ -32,6 +33,24 @@ GPT2 = families.load("gpt2", ROOT)
 RESNET50 = families.load("resnet50", ROOT)
 CELLS = [w["name"] for w in BENCH["workloads"]]
 END_TO_END = {m["name"] for m in BENCH["end_to_end"]}
+# the per-layer metrics the dense family's serving cells need (the other
+# families' are in their own test files; structure.py)
+_TURN = ["serve_mfu", "host_admit_ms", "host_chunk_ms", "host_launch_ms",
+         "host_emit_ms", "idle_host_pct", "idle_wait_pct",
+         "prefill_chunk_device_ms"]
+NEEDS = {
+    "gpt13b-serve-loaded": _TURN + [
+        "decode_step_ms.loaded", "device_idle_pct.loaded",
+        "prefill_chunk_ms.loaded", "paged_decode_kernel_ms.loaded",
+        "paged_decode_roofline", "queue_wait_p90_ms"],
+    "gpt13b-serve-longprompt": _TURN + [
+        "decode_step_ms", "device_idle_pct", "prefill_chunk_ms",
+        "paged_decode_kernel_ms", "flash_prefill_kernel_ms",
+        "queue_wait_p90_ms.longprompt", "ttft_p90_ms.longprompt",
+        "gap_p95_ms.longprompt"],
+    "gpt13b-serve-batch": _TURN + [
+        "decode_step_ms", "device_idle_pct", "paged_decode_roofline"],
+}
 
 
 # ------------------------------------------------------------ xplane.py --
@@ -320,22 +339,60 @@ def test_every_file_of_a_cell_is_found_by_name(cell):
     assert "setup_s" in e2e and len(e2e) >= 2
 
 
-@pytest.mark.parametrize("metric", [m["name"] for m in BENCH["per_layer"]])
-def test_layer_metric_has_a_reader_and_moves_what_its_cells_report(metric):
+PAIRS = [(m["name"], cell) for m in BENCH["per_layer"]
+         for cell in m["workloads"]]
+
+
+@pytest.mark.parametrize("metric,cell", PAIRS,
+                         ids=[f"{m}-{c}" for m, c in PAIRS])
+def test_layer_metric_has_a_reader_and_moves_what_its_cell_reports(metric,
+                                                                   cell):
+    """One case a (metric, cell) pair: an entry is ONE measurement with a
+    list of cells, and each cell it lists is held on its own."""
     entry = next(m for m in BENCH["per_layer"] if m["name"] == metric)
     spec = load_json(os.path.join(ROOT, "benchmark", "layer_metrics",
                                   metric + ".json"))
     assert os.path.exists(os.path.join(
         ROOT, "benchmark", "readers", spec["reader"] + ".py"))
-    assert spec["unit"] == entry["unit"] and spec["layer"] == entry["layer"]
+    assert (spec["name"], spec["unit"], spec["layer"], spec["moves"]) == (
+        metric, entry["unit"], entry["layer"], entry["moves"])
     assert entry["moves"] in END_TO_END and entry["moves"] != "setup_s"
-    for cell in entry.get("workloads", CELLS):
-        assert entry["moves"] in contract.declared_metrics(BENCH, cell, False)
+    assert entry["moves"] in contract.declared_metrics(BENCH, cell, False)
+    loaded = load_cell(BENCH, cell)
+    assert metric in loaded.per_layer
+    assert structure.cost_is_the_familys(loaded, spec)
     # a reader that finds nothing returns nothing
-    run = ReaderInput(cell=load_cell(BENCH, entry.get("workloads", CELLS)[0]),
-                      device_kind="TPU v5 lite", reduction=None,
+    run = ReaderInput(cell=loaded, device_kind="TPU v5 lite", reduction=None,
                       driver={"window_wall": (0.0, 1.0)})
     assert read_metric(spec, run) is None
+
+
+@pytest.mark.parametrize("cell", sorted(NEEDS))
+def test_a_dense_serving_cell_is_listed_by_what_it_needs(cell):
+    loaded, mine = structure.check_cell(BENCH, ROOT, cell, NEEDS[cell])
+    assert loaded.config_name == "cerebras-gpt-1.3b"
+    # an entry has ONE moves: the loaded cell's copies move its tails
+    moved = {m["moves"] for m in mine.values()}
+    assert moved == ({"serve_tok_s", "ttft_p90_ms", "gap_p95_ms"}
+                     if cell == "gpt13b-serve-loaded" else {"serve_tok_s"})
+
+
+def test_no_two_entries_are_one_measurement_under_two_names():
+    """``python3 -m benchmark.twins``: every entry has its file and its
+    ``workloads`` list, every file its entry, and two entries whose files
+    are equal but for ``name`` and ``note`` are ONE entry with both cells,
+    unless a note names the other and says what differs."""
+    entries, files, faults = twins.declared(ROOT)
+    assert faults == []
+    assert twins.twins(entries, files) == []
+    # and the tool sees twins where there are some: a copy under a suffix
+    name = "decode_step_ms"
+    entries[name + ".copy"] = dict(entries[name], name=name + ".copy")
+    files[name + ".copy"] = dict(files[name], name=name + ".copy",
+                                 note="the same, for one cell more")
+    assert twins.twins(entries, files) == [[name, name + ".copy"]]
+    files[name + ".copy"]["note"] = f"As {name}, but it moves nothing."
+    assert twins.twins(entries, files) == []
 
 
 def test_benchmark_json_keeps_to_the_contracts_shapes():
@@ -350,8 +407,13 @@ def test_benchmark_json_keeps_to_the_contracts_shapes():
     for m in BENCH["end_to_end"]:
         assert 0.01 <= m["bound"] <= 0.1
         assert m["source"] in ("host_clock", "device_trace")
+    # the contract's caps, held HERE and nowhere else: a family's test
+    # asserts what is its own (structure.py)
+    assert len(BENCH["per_layer"]) <= 128 and len(BENCH["end_to_end"]) <= 16
+    assert len(BENCH["workloads"]) <= 24 and len(BENCH["configs"]) <= 24
     assert sum(w["chips"] == 4 for w in BENCH["workloads"]) <= max(
         1, len(BENCH["workloads"]) // 4)
+    assert all(m.get("workloads") for m in BENCH["per_layer"])
     # 2 + 14 runs a cell, each run_seconds + 60 s, 180 s a cell to compile,
     # 1200 s spare, at the full 24 cells
     assert (2 + 14 * 24) * (BENCH["run_seconds"] + 60) + 24 * 180 + 1200 \
@@ -639,13 +701,20 @@ def _readme_example():
 
 
 def _bench_with(entries):
+    """BENCHMARK.json with the README's entries appended to its lists and
+    each new cell's name appended to the ``workloads`` of the entries the
+    README says it joins (``joins``: an edit of lists, not a key)."""
     bench = copy.deepcopy(BENCH)
+    entries = dict(entries)
+    joins = entries.pop("joins")
     for key, more in entries.items():
         bench[key].extend(more)
-    new_cells = [w["name"] for w in entries["workloads"]]
-    for m in bench["end_to_end"]:       # "a new cell adds its name there"
-        if "workloads" in m:
-            m["workloads"] = m["workloads"] + new_cells
+    assert sorted(joins) == sorted(w["name"] for w in entries["workloads"])
+    for cell, names in joins.items():
+        for name in names:
+            entry = next(m for m in bench["end_to_end"] + bench["per_layer"]
+                         if m["name"] == name)
+            entry["workloads"].append(cell)
     return bench
 
 
@@ -715,6 +784,46 @@ def test_a_new_family_rehearses_through_the_driver(tmp_path, driver):
 
 
 # ------------------------------------- the served-token check can fail ----
+def test_a_cell_that_joins_breaks_no_familys_claims(tmp_path):
+    """The next ``model_config`` PR, rehearsed: a made-up cell appends its
+    name to ``serve_tok_s``, ``serve_mfu``, ``decode_step_ms`` and
+    ``device_idle_pct``, one new per-layer entry is added, and every
+    family's own claims (the ``NEEDS`` of the test files beside this one,
+    through ``structure.check_cell``) hold on that copy as they do here."""
+    files, entries = _readme_example()
+    joined = entries["joins"]["gpt590m-serve-longoutput"]
+    assert {"serve_tok_s", "serve_mfu", "decode_step_ms",
+            "device_idle_pct"} <= set(joined)
+    bench = _bench_with(entries)
+    root = _copy_of_the_data(tmp_path, bench, files)
+    needs = structure.family_needs()
+    serving = next(m["workloads"] for m in BENCH["end_to_end"]
+                   if m["name"] == "serve_tok_s")
+    assert set(needs) == set(serving)   # a serving cell's file claims it
+    for cell, names in needs.items():
+        _, here = structure.check_cell(BENCH, ROOT, cell, names)
+        _, there = structure.check_cell(bench, root, cell, names)
+        assert sorted(here) == sorted(there)
+    # the made-up cells hold the same claims, and the entries grew by one
+    new, mine = structure.check_cell(
+        bench, root, "gpt590m-serve-longoutput",
+        [n for n in joined if n not in END_TO_END] + ["weight_cast_ms"])
+    assert sorted(mine) == sorted(new.per_layer)
+    structure.check_cell(bench, root, "gpt590m-train",
+                         ["step_ms.train", "device_idle_pct.train"],
+                         reports="train_mfu")
+    assert len(bench["per_layer"]) == len(BENCH["per_layer"]) + 1
+    for traced in (False, True):
+        for cell in ("gpt590m-serve-longoutput", "gpt590m-train"):
+            assert contract.check_line(good_line_of(bench, cell, traced),
+                                       bench, cell, traced) == []
+    # what arrived changed no line another cell prints
+    for cell in CELLS:
+        for traced in (False, True):
+            assert contract.declared_metrics(bench, cell, traced) \
+                == contract.declared_metrics(BENCH, cell, traced)
+
+
 def _toy_forward(params, tokens):
     """logits[b, s] = params[tokens[b, s]]: a 'model' the check can be shown
     on without a model."""

@@ -447,7 +447,7 @@ def test_a_profile_without_a_device_gives_no_devices_number(change, tmp_path,
 @pytest.mark.parametrize("metric", sorted(NEW))
 def test_nothing_traced_reads_nothing(metric):
     entry = next(m for m in BENCH["per_layer"] if m["name"] == metric)
-    assert entry["workloads"] == NEW[metric]
+    assert set(NEW[metric]) <= set(entry["workloads"])
     assert entry["moves"] == "serve_tok_s"
     assert entry["source"] == ("program_span" if metric in (
         "span_runs_paired_pct", "decode_dispatch_ms", "decode_readback_ms")
@@ -462,12 +462,15 @@ def test_nothing_traced_reads_nothing(metric):
         assert read_metric(_spec(metric), run) is None
 
 
-def test_the_nine_are_the_last_entries_and_list_the_four_alone():
-    tail = BENCH["per_layer"][-9:]
-    assert [m["name"] for m in tail] == list(NEW)
-    assert all(set(m["workloads"]) <= set(FOUR) for m in tail)
-    assert {m["layer"] for m in tail} <= {m["layer"]
-                                          for m in BENCH["per_layer"][:-9]}
+def test_the_nine_are_declared_in_serving_cells_under_layers_it_had():
+    """Their content, not their place: the order of ``per_layer`` carries
+    no meaning, and a later serving cell may append its name."""
+    nine = [m for m in BENCH["per_layer"] if m["name"] in NEW]
+    assert sorted(m["name"] for m in nine) == sorted(NEW)
+    tok = next(m for m in BENCH["end_to_end"] if m["name"] == "serve_tok_s")
+    assert all(set(m["workloads"]) <= set(tok["workloads"]) for m in nine)
+    assert {m["layer"] for m in nine} <= {
+        m["layer"] for m in BENCH["per_layer"] if m["name"] not in NEW}
 
 
 def test_the_programs_the_reader_names_are_the_decoders_own():

@@ -33,8 +33,21 @@ from benchmark.lib.runtime import (load_benchmark, load_cell,  # noqa: E402
 from benchmark.lib.xplane import Reduction  # noqa: E402
 from benchmark.readers import ReaderInput, read_metric  # noqa: E402
 
+import structure  # noqa: E402  (beside this file)
+
 CELL = "evabyte-serve-bytedocs"
 BENCH = load_benchmark()
+# the per-layer metrics the cell needs, each under the entry's own name (a
+# suffix says how an entry differs, never which cell reads it)
+NEEDS = {
+    CELL: [
+        "decode_step_ms", "device_idle_pct", "prefill_chunk_ms",
+        "paged_decode_kernel_ms", "paged_decode_roofline.by_span",
+        "host_launch_ms", "idle_host_pct", "idle_wait_pct",
+        "summary_rows_share.bytedocs", "window_compact_ms.bytedocs",
+        "window_compact_roofline.bytedocs", "host_compact_ms.bytedocs",
+        "serve_mfu"],
+}
 # https://huggingface.co/EvaByte/EvaByte/blob/main/config.json as the
 # catalog of architectures holds it
 PUBLISHED = {
@@ -166,24 +179,11 @@ def test_the_sample_reads_three_kinds_of_close(cell):
     assert lens[1] + toy["agreement"]["new_tokens"] > 3 * window
 
 
-def test_serve_tok_s_is_judged_in_the_new_cell(cell):
-    tok = next(m for m in BENCH["end_to_end"] if m["name"] == "serve_tok_s")
-    # membership, not the last place: every later cell is appended there
-    assert CELL in tok["workloads"]
-    mine = [m for m in BENCH["per_layer"] if CELL in m["workloads"]]
-    assert [m["name"] for m in mine] == cell.per_layer
-    assert len(mine) == 13         # its own twelve and serve_mfu
-    assert sum(m["name"].endswith(".bytedocs") for m in mine) == 12
-    for m in mine:
-        assert m["moves"] == "serve_tok_s"
-        # a metric named for the cell is its alone; one shared by several
-        # cells (the turn's laps, serve_mfu) lists it among them
-        own = m["name"].endswith(".bytedocs")
-        assert (m["workloads"] == [CELL]) == own
-        spec = _spec(m["name"])
-        assert (spec["unit"], spec["layer"]) == (m["unit"], m["layer"])
-    entry = next(w for w in BENCH["workloads"] if w["name"] == CELL)
-    assert entry["chips"] == 1 and len(entry["why"]) <= 200
+def test_serve_tok_s_is_judged_in_the_new_cell():
+    """The cell's own claims on BENCHMARK.json (``structure.py``): nothing
+    about its place in a list, or about what else lists an entry."""
+    _, mine = structure.check_cell(BENCH, ROOT, CELL, NEEDS[CELL])
+    assert all(m["moves"] == "serve_tok_s" for m in mine.values())
 
 
 @pytest.mark.parametrize("trace", ["0", "1"], ids=["trace0", "trace1"])
@@ -333,7 +333,7 @@ def test_rooflines_read_the_spans_and_the_share_reads_the_steps(cell):
     cfg, costs = cell.config, cell.family.SPAN_COSTS
     least = sum(peaks.least_seconds("TPU v5 lite", *costs[
         "paged_attention_reads"](cfg, r)) for r in records[:2])
-    assert read_metric(_spec("paged_decode_roofline.bytedocs"), run) \
+    assert read_metric(_spec("paged_decode_roofline.by_span"), run) \
         == pytest.approx(100 * least / 0.004)
     least = 2 * 8 * 2176 * 16384 / 819e9
     got = read_metric(_spec("window_compact_roofline.bytedocs"), run)
@@ -342,11 +342,11 @@ def test_rooflines_read_the_spans_and_the_share_reads_the_steps(cell):
         == pytest.approx(1 / 3)
     assert read_metric(_spec("window_compact_ms.bytedocs"), run) \
         == pytest.approx(1.0)
-    assert read_metric(_spec("paged_decode_kernel_ms.bytedocs"), run) \
+    assert read_metric(_spec("paged_decode_kernel_ms"), run) \
         == pytest.approx(2.0)
     # a program that counts none of it: nothing, and no error
     bare = _run(cell, [_span("serve_decode")], {"paged_flash_decode": 0.004})
-    for name in ("paged_decode_roofline.bytedocs",
+    for name in ("paged_decode_roofline.by_span",
                  "window_compact_roofline.bytedocs",
                  "window_compact_ms.bytedocs",
                  "summary_rows_share.bytedocs"):
@@ -365,13 +365,13 @@ def test_the_turns_laps_read_the_closes_host_time(cell, tmp_path):
     run.driver["profile_dir"] = str(tmp_path / "profile")
     assert read_metric(_spec("host_compact_ms.bytedocs"), run) \
         == pytest.approx(1.0)
-    assert read_metric(_spec("host_launch_ms.bytedocs"), run) \
+    assert read_metric(_spec("host_launch_ms"), run) \
         == pytest.approx(4.0)
     # no device in the profile: no device's number
-    assert read_metric(_spec("idle_host_pct.bytedocs"), run) is None
-    assert read_metric(_spec("idle_wait_pct.bytedocs"), run) is None
-    for name in ("host_compact_ms.bytedocs", "host_launch_ms.bytedocs",
-                 "idle_host_pct.bytedocs", "idle_wait_pct.bytedocs"):
+    assert read_metric(_spec("idle_host_pct"), run) is None
+    assert read_metric(_spec("idle_wait_pct"), run) is None
+    for name in ("host_compact_ms.bytedocs", "host_launch_ms",
+                 "idle_host_pct", "idle_wait_pct"):
         spec = _spec(name)
         assert spec["reader"] == "host_laps"
         assert read_metric(spec, _run(cell, [], {})) is None

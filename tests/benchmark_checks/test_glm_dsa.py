@@ -40,6 +40,8 @@ from benchmark.lib.runtime import (load_benchmark, load_cell,  # noqa: E402
 from benchmark.lib.xplane import Reduction  # noqa: E402
 from benchmark.readers import ReaderInput, read_metric  # noqa: E402
 
+import structure  # noqa: E402  (beside this file)
+
 CELL = "glm52-serve-sparsectx"
 BENCH = load_benchmark()
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
@@ -70,14 +72,15 @@ PUBLISHED = {
     "tie_word_embeddings": False, "topk_group": 1, "topk_method": "noaux_tc",
     "v_head_dim": 256, "vocab_size": 154880}
 REDUCED = ["num_hidden_layers", "n_routed_experts", "vocab_size"]
-PER_LAYER = [
-    "decode_step_ms.sparsectx", "prefill_chunk_ms.sparsectx",
-    "device_idle_pct.sparsectx", "host_launch_ms.sparsectx",
-    "idle_host_pct.sparsectx", "index_select_kernel_ms.sparsectx",
+# the per-layer metrics the cell needs, each under the entry's own name (a
+# suffix says how an entry differs, never which cell reads it)
+NEEDS = {CELL: [
+    "serve_mfu", "decode_step_ms", "prefill_chunk_ms", "device_idle_pct",
+    "host_launch_ms", "idle_host_pct", "index_select_kernel_ms.sparsectx",
     "index_select_roofline.sparsectx", "latent_sparse_kernel_ms.sparsectx",
     "latent_sparse_roofline.sparsectx", "keys_selected_share.sparsectx",
-    "moe_experts_ms.sparsectx", "moe_experts_roofline.sparsectx",
-    "expert_load_max_over_mean.sparsectx"]
+    "moe_experts_ms", "moe_experts_roofline",
+    "expert_load_max_over_mean.n_routed_experts"]}
 # the parameters, counted by hand: a layer's attention, a full layer's
 # indexer, the dense MLP, one expert, the router
 ATTENTION = (6144 * 2048 + 2048 * 64 * 256 + 6144 * 576 + 512 * 64 * 448
@@ -288,28 +291,14 @@ def test_the_sample_reads_the_choice_and_a_page_of_one_token(cell):
     assert lens[2] % toy["engine"]["prefill_chunk"] == 1
 
 
-def test_serve_tok_s_is_judged_in_the_new_cell(cell):
-    tok = next(m for m in BENCH["end_to_end"] if m["name"] == "serve_tok_s")
-    assert CELL in tok["workloads"]
-    mine = [m for m in BENCH["per_layer"] if CELL in m.get("workloads", [])]
-    assert [m["name"] for m in mine] == ["serve_mfu"] + PER_LAYER \
-        or [m["name"] for m in mine] == PER_LAYER + ["serve_mfu"]
-    assert sorted(m["name"] for m in mine) == sorted(cell.per_layer)
+def test_serve_tok_s_is_judged_in_the_new_cell():
+    """The cell's own claims on BENCHMARK.json (``structure.py``): nothing
+    about its place in a list, or about what else lists an entry."""
+    cell, mine = structure.check_cell(
+        BENCH, ROOT, CELL, NEEDS[CELL], config="glm-5.2",
+        traffic="sparsectx-closed-16")
     assert cell.family.SPAN_COSTS["model_flops"] is cell.family.model_flops
-    for m in mine:
-        if m["name"] == "serve_mfu":
-            continue
-        assert m["moves"] == "serve_tok_s" and m["workloads"] == [CELL]
-        spec = _spec(m["name"])
-        assert (spec["unit"], spec["layer"]) == (m["unit"], m["layer"])
-        if m["name"].endswith("_roofline.sparsectx"):
-            assert m["unit"] == "%" and spec["args"]["cost"] \
-                in cell.family.SPAN_COSTS
-    entry = next(w for w in BENCH["workloads"] if w["name"] == CELL)
-    assert entry["chips"] == 1 and len(entry["why"]) <= 200
-    assert (entry["config"], entry["traffic"]) == ("glm-5.2",
-                                                   "sparsectx-closed-16")
-    assert sum(w["chips"] == 4 for w in BENCH["workloads"]) == 1
+    assert all(m["moves"] == "serve_tok_s" for m in mine.values())
 
 
 @pytest.mark.parametrize("trace", ["0", "1"], ids=["trace0", "trace1"])
@@ -330,7 +319,7 @@ def test_the_cell_rehearses_through_the_serve_driver(trace):
     if trace == "1":
         read = done.stdout[done.stdout.index("readers without"):]
         for name in ("keys_selected_share.sparsectx",
-                     "expert_load_max_over_mean.sparsectx"):
+                     "expert_load_max_over_mean.n_routed_experts"):
             assert f"'{name}': None" not in read
 
 
@@ -788,28 +777,28 @@ def test_the_readers_read_the_spans(cell):
                                "index_select.1": 0.05, "gmm.2": 0.02})
     cfg, costs = cell.config, cell.family.SPAN_COSTS
     for metric, cost, total in (
-            ("latent_sparse_roofline", "latent_sparse_reads", 0.304),
-            ("index_select_roofline", "index_select_scores", 0.05),
+            ("latent_sparse_roofline.sparsectx", "latent_sparse_reads",
+             0.304),
+            ("index_select_roofline.sparsectx", "index_select_scores", 0.05),
             ("moe_experts_roofline", "expert_matmuls", 0.02)):
         least = sum(peaks.least_seconds("TPU v5 lite", *costs[cost](cfg, r))
                     for r in records[:2])
-        got = read_metric(_spec(metric + ".sparsectx"), run)
+        got = read_metric(_spec(metric), run)
         assert got == pytest.approx(100 * least / total) and 0 < got < 100
     assert read_metric(_spec("keys_selected_share.sparsectx"), run) \
         == pytest.approx(2048 / 30_000)
-    assert read_metric(_spec("expert_load_max_over_mean.sparsectx"), run) \
-        == pytest.approx(9 * 16 / 20)
+    assert read_metric(_spec("expert_load_max_over_mean.n_routed_experts"),
+                       run) == pytest.approx(9 * 16 / 20)
     assert read_metric(_spec("latent_sparse_kernel_ms.sparsectx"), run) \
         == pytest.approx(152.0)
     assert read_metric(_spec("index_select_kernel_ms.sparsectx"), run) \
         == pytest.approx(25.0)
-    assert read_metric(_spec("moe_experts_ms.sparsectx"), run) \
-        == pytest.approx(10.0)
+    assert read_metric(_spec("moe_experts_ms"), run) == pytest.approx(10.0)
     # a program that counts none of it (the parent): nothing, and no error
     bare = _run(cell, [_span("serve_decode"), _span("serve_prefill_chunk",
                                                    tokens=2048, start=0)],
                 {"paged_flash_decode.1": 0.1})
-    for name in PER_LAYER:
+    for name in NEEDS[CELL]:
         spec = _spec(name)
         if spec["reader"] in ("trace_kernel", "trace_kernel_spans",
                               "span_ratio"):

@@ -354,14 +354,17 @@ def test_nothing_traced_reads_nothing(metric):
     assert entry["moves"] == "serve_tok_s" and entry["better"] == "lower"
     # PR 36's four serving cells, and since PR 46 longctx, manyrows,
     # longprompt-closed and longgen; the chunk's device time in the four
-    # dense cells (bytedocs and longdoc declare laps under their own names)
+    # dense cells.  Later cells join by appending their names: the list
+    # holds these, and whatever it holds reports what the entry moves
     cells = ["gpt13b-serve-loaded", "gpt13b-serve-longprompt",
              "gpt13b-serve-batch", "smallthinker-serve-mixedctx",
              "joyai-serve-longctx", "lfm2-serve-manyrows",
              "gpt13b-serve-longprompt-closed", "ling-serve-longgen"]
     if "chunk_device" in metric:
         cells = [c for c in cells if c.startswith("gpt13b")]
-    assert entry["workloads"] == cells
+    assert set(cells) <= set(entry["workloads"])
+    tok = next(m for m in BENCH["end_to_end"] if m["name"] == "serve_tok_s")
+    assert set(entry["workloads"]) <= set(tok["workloads"])
     for driver in ({"window_wall": (0.0, 1.0)},
                    {"window_wall": (0.0, 1.0), "records": [],
                     "profile_dir": None}):

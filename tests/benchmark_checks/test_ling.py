@@ -38,8 +38,22 @@ from benchmark.lib.runtime import (load_benchmark, load_cell,  # noqa: E402
 from benchmark.lib.xplane import Reduction  # noqa: E402
 from benchmark.readers import ReaderInput, read_metric  # noqa: E402
 
+import structure  # noqa: E402  (beside this file)
+
 CELL = "ling-serve-longgen"
 BENCH = load_benchmark()
+# the per-layer metrics the cell needs, each under the entry's own name (a
+# suffix says how an entry differs, never which cell reads it)
+NEEDS = {
+    CELL: [
+        "decode_step_ms", "device_idle_pct", "prefill_chunk_ms",
+        "moe_experts_ms", "moe_experts_roofline",
+        "latent_attention_kernel_ms", "latent_attention_roofline",
+        "expert_load_max_over_mean.num_experts", "host_admit_ms",
+        "host_chunk_ms", "host_launch_ms", "host_emit_ms", "idle_host_pct",
+        "idle_wait_pct", "decode_rows_per_step.longgen",
+        "linear_state_kernel_ms", "linear_state_roofline", "serve_mfu"],
+}
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 # https://huggingface.co/inclusionAI/Ling-3.0-flash-VL/blob/main/config.json
 # as the catalog of architectures holds it (the language model's keys)
@@ -244,25 +258,11 @@ def test_the_sample_reads_a_carried_state_and_the_mix_never_draws_it(cell):
         == toy["engine"]["prefill_chunk"] * 3 + 1
 
 
-def test_serve_tok_s_is_judged_in_the_new_cell(cell):
-    tok = next(m for m in BENCH["end_to_end"] if m["name"] == "serve_tok_s")
-    # membership, not the last place: every later cell is appended there
-    assert CELL in tok["workloads"]
-    mine = [m for m in BENCH["per_layer"] if CELL in m["workloads"]]
-    assert [m["name"] for m in mine] == cell.per_layer
-    # its own eleven, the turn's six laps (PR 46) and serve_mfu
-    assert len(mine) == 18
-    assert sum(m["name"].endswith(".longgen") for m in mine) == 11
-    for m in mine:
-        assert m["moves"] == "serve_tok_s"
-        # a metric named for the cell is its alone; one shared by several
-        # cells (the turn's laps, serve_mfu) lists it among them
-        own = m["name"].endswith(".longgen")
-        assert (m["workloads"] == [CELL]) == own
-        spec = _spec(m["name"])
-        assert (spec["unit"], spec["layer"]) == (m["unit"], m["layer"])
-    entry = next(w for w in BENCH["workloads"] if w["name"] == CELL)
-    assert entry["chips"] == 1 and len(entry["why"]) <= 200
+def test_serve_tok_s_is_judged_in_the_new_cell():
+    """The cell's own claims on BENCHMARK.json (``structure.py``): nothing
+    about its place in a list, or about what else lists an entry."""
+    _, mine = structure.check_cell(BENCH, ROOT, CELL, NEEDS[CELL])
+    assert all(m["moves"] == "serve_tok_s" for m in mine.values())
 
 
 @pytest.mark.parametrize("trace", ["0", "1"], ids=["trace0", "trace1"])
@@ -283,7 +283,7 @@ def test_the_cell_rehearses_through_the_serve_driver(trace):
     if trace == "1":
         read = done.stdout[done.stdout.index("readers without"):]
         assert "'decode_rows_per_step.longgen': None" not in read
-        assert "'expert_load_max_over_mean.longgen': None" not in read
+        assert "'expert_load_max_over_mean.num_experts': None" not in read
 
 
 @pytest.fixture(scope="module")
@@ -445,12 +445,12 @@ def test_state_roofline_reads_the_decode_spans_alone(cell):
                                "linear_state_decode.9": 0.004})
     least = 2 * peaks.least_seconds(
         "TPU v5 lite", 2.0 * 3 * 32 * 128 * 128 * 630, 2.0 * 1048576 * 630)
-    got = read_metric(_spec("linear_state_roofline.longgen"), run)
+    got = read_metric(_spec("linear_state_roofline"), run)
     assert got == pytest.approx(100 * least / 0.008)
     assert 0 < got < 100
-    assert read_metric(_spec("linear_state_kernel_ms.longgen"), run) \
+    assert read_metric(_spec("linear_state_kernel_ms"), run) \
         == pytest.approx(4.0)
-    assert read_metric(_spec("linear_state_roofline.longgen"),
+    assert read_metric(_spec("linear_state_roofline"),
                        _run(cell, [_span("serve_decode")],
                             {"linear_state_decode": 0.004})) is None
 
@@ -464,14 +464,14 @@ def test_rows_per_step_and_load_read_the_spans(cell):
     assert read_metric(_spec("decode_rows_per_step.longgen"), run) \
         == pytest.approx(90.0)
     # the busiest held expert over the mean of the 128 held
-    assert read_metric(_spec("expert_load_max_over_mean.longgen"), run) \
+    assert read_metric(_spec("expert_load_max_over_mean.num_experts"), run) \
         == pytest.approx(128 * 72 / (6 * 384))
 
 
 @pytest.mark.parametrize("metric,kernel", [
-    ("moe_experts_ms.longgen", "gmm.12"),
-    ("latent_attention_kernel_ms.longgen", "paged_flash_decode.4"),
-    ("linear_state_kernel_ms.longgen", "linear_state_decode.2")])
+    ("moe_experts_ms", "gmm.12"),
+    ("latent_attention_kernel_ms", "paged_flash_decode.4"),
+    ("linear_state_kernel_ms", "linear_state_decode.2")])
 def test_kernel_time_is_per_decode_step(cell, metric, kernel):
     run = _run(cell, [], {kernel: 0.030, "gmm_like_fusion": 1.0})
     assert read_metric(_spec(metric), run) == pytest.approx(15.0)

@@ -35,6 +35,8 @@ from benchmark.lib.runtime import (load_benchmark, load_cell,  # noqa: E402
 from benchmark.lib.xplane import Reduction  # noqa: E402
 from benchmark.readers import ReaderInput, read_metric  # noqa: E402
 
+import structure  # noqa: E402  (beside this file)
+
 CELL = "minicpm-sala-serve-longdoc"
 BENCH = load_benchmark()
 _S, _L = "minicpm4", "lightning-attn"
@@ -55,14 +57,15 @@ PUBLISHED = {
     "dim_model_base": 256, "tie_word_embeddings": False,
     "use_output_gate": True, "use_output_norm": True,
     "attn_use_output_gate": True}
-PER_LAYER = [
-    "decode_step_ms.longdoc", "prefill_chunk_ms.longdoc",
-    "device_idle_pct.longdoc", "decode_rows_per_step.longdoc",
-    "host_launch_ms.longdoc", "idle_host_pct.longdoc",
+# the per-layer metrics the cell needs, each under the entry's own name (a
+# suffix says how an entry differs, never which cell reads it)
+NEEDS = {CELL: [
+    "serve_mfu", "decode_step_ms", "prefill_chunk_ms", "device_idle_pct",
+    "decode_rows_per_step.longdoc", "host_launch_ms", "idle_host_pct",
     "blocks_read_share.longdoc", "block_select_kernel_ms.longdoc",
-    "block_select_roofline.longdoc", "paged_decode_kernel_ms.longdoc",
-    "paged_decode_roofline.longdoc", "linear_state_kernel_ms.longdoc",
-    "linear_state_roofline.longdoc"]
+    "block_select_roofline.longdoc", "paged_decode_kernel_ms",
+    "paged_decode_roofline.longdoc", "linear_state_kernel_ms",
+    "linear_state_roofline"]}
 
 
 @pytest.fixture(scope="module")
@@ -274,30 +277,19 @@ def test_the_sample_reads_both_paths_and_a_page_of_one_token(cell):
     assert init + top + window // block < lens[1] // block
 
 
-def test_serve_tok_s_is_judged_in_the_new_cell(cell):
-    tok = next(m for m in BENCH["end_to_end"] if m["name"] == "serve_tok_s")
-    # membership, not the last place: every later cell is appended there
-    assert CELL in tok["workloads"]
-    mine = [m for m in BENCH["per_layer"] if CELL in m.get("workloads", [])]
-    # its own thirteen, then the one it shares with every serving cell
-    assert [m["name"] for m in mine] == PER_LAYER + ["serve_mfu"]
-    assert [m["name"] for m in mine] == cell.per_layer
+def test_serve_tok_s_is_judged_in_the_new_cell():
+    """The cell's own claims on BENCHMARK.json (``structure.py``): nothing
+    about its place in a list, or about what else lists an entry."""
+    cell, mine = structure.check_cell(
+        BENCH, ROOT, CELL, NEEDS[CELL], config="minicpm-sala-9b",
+        traffic="longdoc-closed-24")
     assert cell.family.SPAN_COSTS["model_flops"] is cell.family.model_flops
-    mine = mine[:-1]
-    for m in mine:
-        assert m["moves"] == "serve_tok_s" and m["workloads"] == [CELL]
-        spec = _spec(m["name"])
-        assert (spec["unit"], spec["layer"]) == (m["unit"], m["layer"])
-        if m["name"].endswith("_roofline.longdoc"):
-            assert m["unit"] == "%" and spec["args"]["cost"] \
-                in cell.family.SPAN_COSTS
-            assert m["name"].replace("_roofline", "_kernel_ms") in PER_LAYER
-    entry = next(w for w in BENCH["workloads"] if w["name"] == CELL)
-    assert entry["chips"] == 1 and len(entry["why"]) <= 200
-    assert (entry["config"], entry["traffic"]) == ("minicpm-sala-9b",
-                                                   "longdoc-closed-24")
-    assert len(BENCH["workloads"]) >= 12
-    assert sum(w["chips"] == 4 for w in BENCH["workloads"]) == 1
+    assert all(m["moves"] == "serve_tok_s" for m in mine.values())
+    # a kernel's share of its roofline stands beside the kernel's time
+    for name in mine:
+        if "_roofline" in name:
+            assert name.split(".")[0].replace("_roofline", "_kernel_ms") \
+                in {n.split(".")[0] for n in mine}
 
 
 @pytest.mark.parametrize("trace", ["0", "1"], ids=["trace0", "trace1"])
@@ -478,22 +470,22 @@ def test_the_readers_read_the_spans(cell):
         == pytest.approx(10.0)
     got = read_metric(_spec("block_select_roofline.longdoc"), run)
     assert got == pytest.approx(100 * 2 * 2046 * 10 * 512 / 819e9 / 0.001)
-    got = read_metric(_spec("linear_state_roofline.longdoc"), run)
+    got = read_metric(_spec("linear_state_roofline"), run)
     assert got == pytest.approx(100 * 60 * 2 * 1_048_576 / 819e9 / 0.004)
     assert 0 < got < 100
-    assert read_metric(_spec("linear_state_kernel_ms.longdoc"), run) \
+    assert read_metric(_spec("linear_state_kernel_ms"), run) \
         == pytest.approx(2.0)
     assert read_metric(_spec("block_select_kernel_ms.longdoc"), run) \
         == pytest.approx(0.5)
-    assert read_metric(_spec("paged_decode_kernel_ms.longdoc"), run) \
+    assert read_metric(_spec("paged_decode_kernel_ms"), run) \
         == pytest.approx(100.0)
     # a program that counts none of it (the parent): nothing, and no error
     bare = _run(cell, [_span("serve_decode")], {"paged_flash_decode": 0.004})
     for name in ("paged_decode_roofline.longdoc",
                  "block_select_roofline.longdoc",
                  "block_select_kernel_ms.longdoc",
-                 "linear_state_roofline.longdoc",
-                 "linear_state_kernel_ms.longdoc",
+                 "linear_state_roofline",
+                 "linear_state_kernel_ms",
                  "blocks_read_share.longdoc",
                  "decode_rows_per_step.longdoc"):
         assert read_metric(_spec(name), bare) is None

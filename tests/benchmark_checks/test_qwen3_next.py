@@ -34,6 +34,8 @@ from benchmark.lib.runtime import (load_benchmark, load_cell,  # noqa: E402
 from benchmark.lib.xplane import Reduction  # noqa: E402
 from benchmark.readers import ReaderInput, read_metric  # noqa: E402
 
+import structure  # noqa: E402  (beside this file)
+
 CELL = "qwen3next-serve-hybriddoc"
 BENCH = load_benchmark()
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
@@ -54,16 +56,17 @@ PUBLISHED = {
     "shared_expert_intermediate_size": 512, "tie_word_embeddings": False,
     "use_sliding_window": False, "vocab_size": 151936}
 REDUCED = ["num_hidden_layers", "num_experts", "vocab_size"]
-# fourteen of ISSUE 57's sixteen: BENCHMARK.json holds at most 128 per-layer
-# metrics and had 114 (host_launch_ms and flash_prefill_kernel_ms are left
-# out: the host's share is idle_host_pct's, the flash forward's its roofline's)
-PER_LAYER = [
-    "decode_step_ms", "prefill_chunk_ms", "device_idle_pct",
-    "idle_host_pct", "decode_rows_per_step",
-    "moe_experts_ms", "moe_experts_roofline", "expert_load_max_over_mean",
-    "experts_touched_share", "linear_state_kernel_ms",
+# the per-layer metrics the cell needs, each under the entry's own name:
+# ONE measurement lists many cells, and a suffix says how an entry differs
+# (another cost, another configuration key), never which cell reads it
+NEEDS = {CELL: [
+    "serve_mfu", "decode_step_ms", "prefill_chunk_ms", "device_idle_pct",
+    "host_launch_ms", "idle_host_pct", "decode_rows_per_step.hybriddoc",
+    "moe_experts_ms", "moe_experts_roofline",
+    "expert_load_max_over_mean.num_experts",
+    "experts_touched_share.hybriddoc", "linear_state_kernel_ms",
     "linear_state_roofline", "paged_decode_kernel_ms",
-    "paged_decode_roofline", "flash_attention_roofline"]
+    "paged_decode_roofline.by_span", "flash_attention_roofline.hybriddoc"]}
 LINEAR = 2048 * (2 * 2048 + 2 * 4096) + 2048 * 64 + 4096 * 2048
 FULL = 2048 * (2 * 16 + 2 * 2) * 256 + 4096 * 2048
 EXPERT, ROUTER = 3 * 2048 * 512, 2048 * 512
@@ -275,34 +278,14 @@ def _spec(name):
                                   name + ".json"))
 
 
-def test_serve_tok_s_is_judged_in_the_new_cell(cell):
-    tok = next(m for m in BENCH["end_to_end"] if m["name"] == "serve_tok_s")
-    assert tok["workloads"][-1] == CELL
-    mine = [m for m in BENCH["per_layer"] if CELL in m.get("workloads", [])]
-    assert [m["name"] for m in mine] == ["serve_mfu"] + [
-        n + ".hybriddoc" for n in PER_LAYER]
-    assert sorted(m["name"] for m in mine) == sorted(cell.per_layer)
+def test_serve_tok_s_is_judged_in_the_new_cell():
+    """The cell's own claims on BENCHMARK.json (``structure.py``): nothing
+    about its place in a list, or about what else lists an entry."""
+    cell, mine = structure.check_cell(
+        BENCH, ROOT, CELL, NEEDS[CELL], config="qwen3-next-80b-a3b",
+        traffic="hybriddoc-closed-32")
     assert cell.family.SPAN_COSTS["model_flops"] is cell.family.model_flops
-    for m in mine[1:]:
-        assert m["moves"] == "serve_tok_s" and m["workloads"] == [CELL]
-        spec = _spec(m["name"])
-        assert (spec["name"], spec["unit"], spec["layer"]) == (
-            m["name"], m["unit"], m["layer"])
-        if "_roofline" in m["name"]:
-            assert m["unit"] == "%" and m["better"] == "higher"
-            assert spec["args"]["cost"] in cell.family.SPAN_COSTS
-    # the BENCHMARK's layers are names it already had
-    had = {m["layer"] for m in BENCH["per_layer"]
-           if CELL not in m.get("workloads", [])}
-    assert {m["layer"] for m in mine[1:]} <= had
-    entry = next(w for w in BENCH["workloads"] if w["name"] == CELL)
-    assert BENCH["workloads"][-1] is entry
-    assert entry["chips"] == 1 and len(entry["why"]) <= 200
-    assert (entry["config"], entry["traffic"]) == ("qwen3-next-80b-a3b",
-                                                   "hybriddoc-closed-32")
-    assert sum(w["chips"] == 4 for w in BENCH["workloads"]) == 1
-    assert len(BENCH["configs"]) == 10 and len(BENCH["workloads"]) == 14
-    assert len(BENCH["per_layer"]) <= 128       # the contract's cap
+    assert all(m["moves"] == "serve_tok_s" for m in mine.values())
 
 
 def test_the_cell_rehearses_through_the_serve_driver():
@@ -321,11 +304,12 @@ def test_the_cell_rehearses_through_the_serve_driver():
     assert said["line"]["correct"] is True and said["reasons"] == []
     assert said["contract_refuses_it_for"]      # never a result
     read = done.stdout[done.stdout.index("readers without"):]
-    for name in ("decode_rows_per_step", "expert_load_max_over_mean",
-                 "experts_touched_share", "decode_step_ms",
-                 "prefill_chunk_ms"):
-        assert f"'{name}.hybriddoc': None" not in read
-        assert f"'{name}.hybriddoc'" in read
+    for name in ("decode_rows_per_step.hybriddoc",
+                 "expert_load_max_over_mean.num_experts",
+                 "experts_touched_share.hybriddoc", "decode_step_ms",
+                 "prefill_chunk_ms", "host_launch_ms"):
+        assert f"'{name}': None" not in read
+        assert f"'{name}'" in read
 
 
 # ------------------------------------------------- costs and readers ----
@@ -441,35 +425,36 @@ def test_the_readers_read_the_spans(cell):
             ("moe_experts_roofline", "expert_matmuls", 0.02, records[:3]),
             ("linear_state_roofline", "linear_state_steps", 0.0006,
              records[:1]),
-            ("paged_decode_roofline", "paged_attention_reads", 0.01,
+            ("paged_decode_roofline.by_span", "paged_attention_reads", 0.01,
              [records[0], records[2]]),
-            ("flash_attention_roofline", "flash_first_chunks", 0.002,
-             records[1:2])):
+            ("flash_attention_roofline.hybriddoc", "flash_first_chunks",
+             0.002, records[1:2])):
         least = sum(peaks.least_seconds("TPU v5 lite", *costs[cost](cfg, r))
                     for r in spans)
-        got = read_metric(_spec(metric + ".hybriddoc"), run)
+        got = read_metric(_spec(metric), run)
         assert got == pytest.approx(100 * least / total), metric
         assert 0 < got < 100, metric
     for metric, ms in (("moe_experts_ms", 10.0),
                        ("linear_state_kernel_ms", 0.3),
                        ("paged_decode_kernel_ms", 5.0)):
-        assert read_metric(_spec(metric + ".hybriddoc"), run) \
-            == pytest.approx(ms)
+        assert read_metric(_spec(metric), run) == pytest.approx(ms)
     assert read_metric(_spec("decode_rows_per_step.hybriddoc"), run) \
         == pytest.approx(30.0)
     assert read_metric(_spec("experts_touched_share.hybriddoc"), run) \
         == pytest.approx(100 * 450 / (128 * 8))
-    assert read_metric(_spec("expert_load_max_over_mean.hybriddoc"), run) \
+    assert read_metric(_spec("expert_load_max_over_mean.num_experts"), run) \
         == pytest.approx(128 * 32 / 600)
     # a program without the counters: nothing to read, and no raise
     bare = _run(cell, [_span("serve_decode"),
                        _span("serve_prefill_chunk", tokens=2048, start=0)],
                 {"gmm.2": 0.02})
     for name in ("moe_experts_roofline", "linear_state_roofline",
-                 "paged_decode_roofline", "decode_rows_per_step",
-                 "experts_touched_share", "expert_load_max_over_mean",
+                 "paged_decode_roofline.by_span",
+                 "decode_rows_per_step.hybriddoc",
+                 "experts_touched_share.hybriddoc",
+                 "expert_load_max_over_mean.num_experts",
                  "linear_state_kernel_ms"):
-        assert read_metric(_spec(name + ".hybriddoc"), bare) is None
+        assert read_metric(_spec(name), bare) is None
 
 
 READINGS = os.path.join(ROOT, "docs", "pr57_control_readings.jsonl")

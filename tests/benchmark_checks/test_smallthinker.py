@@ -24,8 +24,19 @@ from benchmark.lib.xplane import Reduction  # noqa: E402
 from benchmark.readers import ReaderInput, read_metric  # noqa: E402
 from benchmark.lib.runtime import load_json  # noqa: E402
 
+import structure  # noqa: E402  (beside this file)
+
 CELL = "smallthinker-serve-mixedctx"
 BENCH = load_benchmark()
+# the per-layer metrics the cell needs, each under the entry's own name (a
+# suffix says how an entry differs, never which cell reads it)
+NEEDS = {CELL: [
+    "serve_mfu", "decode_step_ms", "device_idle_pct", "prefill_chunk_ms",
+    "paged_decode_kernel_ms", "paged_decode_roofline.by_span",
+    "moe_experts_ms", "moe_experts_roofline", "expert_load_max_over_mean",
+    "host_admit_ms", "host_chunk_ms", "host_launch_ms", "host_emit_ms",
+    "idle_host_pct", "idle_wait_pct", "moe_experts_step_ms",
+    "moe_experts_chunk_ms"]}
 PERIOD = [0, 1, 1, 1]
 # https://huggingface.co/PowerInfer/SmallThinker-21BA3B-Instruct/blob/main/config.json
 # as the catalog of architectures holds it
@@ -109,6 +120,15 @@ def test_the_traffic_is_the_mix_the_cell_was_asked_for(cell):
     assert 0.0204 < agree["logit_rms_limit"] < 0.039
     assert agree["logit_rtol"] == 0.04
     assert cell.chips == 1
+
+
+def test_serve_tok_s_is_judged_in_the_cell():
+    """The cell's own claims on BENCHMARK.json (``structure.py``): nothing
+    about its place in a list, or about what else lists an entry."""
+    _, mine = structure.check_cell(
+        BENCH, ROOT, CELL, NEEDS[CELL], config="smallthinker-21b-a3b",
+        traffic="mixedctx-closed-16")
+    assert all(m["moves"] == "serve_tok_s" for m in mine.values())
 
 
 def test_the_cell_rehearses_through_the_serve_driver():
@@ -245,7 +265,7 @@ def _spec(name):
 @pytest.mark.parametrize("metric,kernel,attrs", [
     ("moe_experts_roofline", "gmm.3",
      {"assignments": 1152, "experts_touched": 612}),
-    ("paged_decode_roofline.mixedctx", "paged_flash_decode.7",
+    ("paged_decode_roofline.by_span", "paged_flash_decode.7",
      {"kv_tokens_read_global": 30000, "kv_tokens_read_window": 36864}),
 ])
 def test_span_roofline_reads_100_at_the_floor_and_none_without(
@@ -280,7 +300,7 @@ def test_expert_load_reads_max_over_mean(cell):
 
 @pytest.mark.parametrize("metric,kernel", [
     ("moe_experts_ms", "gmm.12"),
-    ("paged_decode_kernel_ms.mixedctx", "paged_flash_decode.4")])
+    ("paged_decode_kernel_ms", "paged_flash_decode.4")])
 def test_kernel_time_is_per_decode_step(cell, metric, kernel):
     run = _run(cell, [], {kernel: 0.030, "gmm_like_fusion": 1.0})
     assert read_metric(_spec(metric), run) == pytest.approx(15.0)
